@@ -9,8 +9,9 @@ compiled programs a durable artifact:
  - :mod:`fingerprint` — a stable content hash over the ProgramDesc + jit
    configuration + toolchain, invariant to variable-name noise;
  - :mod:`store` — an on-disk artifact store (atomic ``_SUCCESS`` commits,
-   LRU size budget, corruption-tolerant loads) that also hosts jax's
-   persistent compilation cache for the backend executables;
+   LRU size budget, corruption-tolerant loads), and the one function
+   that places jax's persistent compilation cache for the backend
+   executables (``store.backend_cache_dir``);
  - this module — process-level wiring: the env-driven singleton and the
    Executor-facing probe API.
 
@@ -28,11 +29,13 @@ import os
 from typing import Optional
 
 from .fingerprint import program_fingerprint, program_signature
-from .store import CompileCacheStore
+from .store import (CompileCacheStore, backend_cache_dir, checkout_root,
+                    place_backend_cache)
 
 __all__ = [
     "program_fingerprint", "program_signature", "CompileCacheStore",
     "get_store", "configure", "disable", "reset", "executor_probe",
+    "backend_cache_dir", "checkout_root",
 ]
 
 ENV_DIR = "PADDLE_COMPILE_CACHE_DIR"
@@ -53,12 +56,9 @@ def get_store() -> Optional[CompileCacheStore]:
         if not d:
             _store = None
         else:
-            budget = os.environ.get(ENV_BUDGET, "").strip() or None
-            try:
-                _store = CompileCacheStore(d, budget)
-                _store.enable_backend_cache()
-            except Exception:
-                _store = None  # an unusable cache dir must not fail runs
+            # an unusable root raises here: a cache that was asked for
+            # and silently is not there would pass for a cold start
+            configure(d, os.environ.get(ENV_BUDGET, "").strip() or None)
     return _store
 
 
@@ -67,7 +67,7 @@ def configure(root: str,
     """Enable programmatically (overrides the env)."""
     global _store
     _store = CompileCacheStore(root, budget_mb)
-    _store.enable_backend_cache()
+    place_backend_cache(True)
     return _store
 
 
@@ -81,12 +81,7 @@ def reset() -> None:
     the backend cache dir.  Test-harness hook."""
     global _store
     if _store not in (None, _UNSET):
-        try:
-            import jax
-
-            jax.config.update("jax_compilation_cache_dir", None)
-        except Exception:
-            pass
+        place_backend_cache(False)
     _store = _UNSET
 
 

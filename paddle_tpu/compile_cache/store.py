@@ -11,11 +11,15 @@ docs/PERFORMANCE.md)::
         _SUCCESS        commit marker, written LAST — the same durability
                         convention as the checkpoint subsystem
                         (trainer.save_checkpoint / multihost serials)
-    <root>/xla/         jax's persistent compilation cache (the backend
-                        XLA executables), wired via
-                        jax_compilation_cache_dir
     <root>/serving/     bucket manifests written by ServingEngine.warmup
     <root>/tmp/         staging dirs for atomic commits
+
+jax's persistent compilation cache (the backend XLA executables) is NOT
+under the root: :func:`backend_cache_dir` alone decides where it lives —
+``JAX_COMPILATION_CACHE_DIR`` where the operator set it (jax's own
+setting, left alone), else one fixed path inside the checkout.  The
+directory is part of what makes an entry hit, so it never follows a pid,
+a timestamp or a temp dir.
 
 Durability rules, mirrored from the checkpoint subsystem:
 
@@ -26,8 +30,9 @@ Durability rules, mirrored from the checkpoint subsystem:
    ``PADDLE_FAULT_CACHE_CORRUPT`` injection) quarantines the entry and
    returns a miss — a broken cache must never fail the run, only slow it;
  - a size budget (``PADDLE_COMPILE_CACHE_BUDGET_MB``) is enforced by LRU
-   eviction over entries AND backend xla files, keyed on last-use mtime
-   (hits ``touch`` their entry).
+   eviction over entries, keyed on last-use mtime (hits ``touch`` their
+   entry); the backend directory is jax's to bound
+   (``JAX_COMPILATION_CACHE_MAX_SIZE``).
 
 Telemetry flows through ``fluid.profiler.record_counter`` (always-on):
 ``compile_cache.hit`` / ``.miss`` / ``.put`` / ``.evict`` /
@@ -44,11 +49,11 @@ import shutil
 import time
 from typing import Dict, List, Optional
 
-__all__ = ["CompileCacheStore", "SUCCESS_MARK"]
+__all__ = ["CompileCacheStore", "SUCCESS_MARK", "backend_cache_dir",
+           "checkout_root", "place_backend_cache"]
 
 SUCCESS_MARK = "_SUCCESS"
 ENTRIES_DIR = "entries"
-XLA_DIR = "xla"
 SERVING_DIR = "serving"
 TMP_DIR = "tmp"
 MANIFEST_FILE = "manifest.json"
@@ -59,6 +64,43 @@ def _counter(name: str, inc=1, value=None) -> None:
     from ..fluid import profiler as _prof
 
     _prof.record_counter(f"compile_cache.{name}", inc=inc, value=value)
+
+
+_BACKEND_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def checkout_root() -> str:
+    """A fixed store root inside the checkout for tools that want the
+    cache on without being told where (``PADDLE_COMPILE_CACHE_DIR``
+    unset): the same path on every run, so the second run is warm."""
+    return os.path.join(_CHECKOUT, ".cache", "paddle")
+
+
+def _backend_env() -> str:
+    return os.environ.get(_BACKEND_ENV, "").strip()
+
+
+def backend_cache_dir() -> str:
+    """Where jax's persistent compilation cache lives — the one function
+    that decides it."""
+    return _backend_env() or os.path.join(_CHECKOUT, ".cache", "jax")
+
+
+def place_backend_cache(on: bool) -> None:
+    """Switch jax's persistent cache on at :func:`backend_cache_dir`, or
+    back off.  Where ``JAX_COMPILATION_CACHE_DIR`` is set jax has placed
+    the cache itself and its setting is not touched either way."""
+    import jax
+
+    if not _backend_env():
+        jax.config.update("jax_compilation_cache_dir",
+                          backend_cache_dir() if on else None)
+    if on:
+        # test-scale programs compile in <1s; without this the backend
+        # would skip persisting exactly the entries we want warm
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
 def _tree_bytes(path: str) -> int:
@@ -80,37 +122,15 @@ class CompileCacheStore:
         self.root = os.path.abspath(root)
         self.budget_bytes = (None if not budget_mb
                              else int(float(budget_mb) * (1 << 20)))
-        for d in (ENTRIES_DIR, XLA_DIR, SERVING_DIR, TMP_DIR):
+        for d in (ENTRIES_DIR, SERVING_DIR, TMP_DIR):
             os.makedirs(os.path.join(self.root, d), exist_ok=True)
 
     # -- paths --
     def entry_dir(self, fp: str) -> str:
         return os.path.join(self.root, ENTRIES_DIR, str(fp))
 
-    @property
-    def xla_dir(self) -> str:
-        return os.path.join(self.root, XLA_DIR)
-
     def serving_manifest_path(self, key: str) -> str:
         return os.path.join(self.root, SERVING_DIR, f"{key}.json")
-
-    # -- backend wiring --
-    def enable_backend_cache(self) -> None:
-        """Point jax's persistent compilation cache into this store so the
-        XLA executable itself round-trips across processes (our entries
-        layer carries the program/manifest above it).  Best-effort: some
-        backends/versions don't support it, and the framework-level cache
-        still works without."""
-        try:
-            import jax
-
-            jax.config.update("jax_compilation_cache_dir", self.xla_dir)
-            # test-scale programs compile in <1s; without this the backend
-            # would skip persisting exactly the entries we want warm
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-        except Exception:
-            pass
 
     # -- read path --
     def complete(self, fp: str) -> bool:
@@ -234,8 +254,7 @@ class CompileCacheStore:
 
     # -- eviction / maintenance --
     def _lru_items(self) -> List[tuple]:
-        """(mtime, kind, path, bytes) for every evictable unit: one entry
-        dir or one backend xla file."""
+        """(mtime, path, bytes) for every entry dir."""
         items = []
         ed = os.path.join(self.root, ENTRIES_DIR)
         for name in os.listdir(ed):
@@ -246,15 +265,7 @@ class CompileCacheStore:
                     marker if os.path.exists(marker) else d)
             except OSError:
                 continue
-            items.append((mtime, "entry", d, _tree_bytes(d)))
-        for dirpath, _dirs, files in os.walk(self.xla_dir):
-            for f in files:
-                p = os.path.join(dirpath, f)
-                try:
-                    items.append((os.path.getmtime(p), "xla", p,
-                                  os.path.getsize(p)))
-                except OSError:
-                    pass
+            items.append((mtime, d, _tree_bytes(d)))
         items.sort()
         return items
 
@@ -267,21 +278,14 @@ class CompileCacheStore:
         if budget is None:
             return 0
         items = self._lru_items()
-        total = sum(sz for _, _, _, sz in items)
+        total = sum(sz for _, _, sz in items)
         evicted = 0
-        for _mtime, kind, path, sz in items:
+        for _mtime, path, sz in items:
             if total <= budget:
                 break
-            if protect and kind == "entry" \
-                    and os.path.basename(path) == str(protect):
+            if protect and os.path.basename(path) == str(protect):
                 continue
-            if kind == "entry":
-                shutil.rmtree(path, ignore_errors=True)
-            else:
-                try:
-                    os.remove(path)
-                except OSError:
-                    continue
+            shutil.rmtree(path, ignore_errors=True)
             total -= sz
             evicted += 1
             _counter("evict")
@@ -342,7 +346,7 @@ class CompileCacheStore:
                 "stats": self.stats()}
 
     def clear(self) -> None:
-        for d in (ENTRIES_DIR, XLA_DIR, SERVING_DIR, TMP_DIR):
+        for d in (ENTRIES_DIR, SERVING_DIR, TMP_DIR):
             p = os.path.join(self.root, d)
             shutil.rmtree(p, ignore_errors=True)
             os.makedirs(p, exist_ok=True)
@@ -356,7 +360,8 @@ class CompileCacheStore:
             "entries": len(recs),
             "complete": sum(1 for r in recs if r["complete"]),
             "entry_bytes": sum(r["bytes"] for r in recs),
-            "xla_bytes": _tree_bytes(self.xla_dir),
+            "xla_dir": backend_cache_dir(),
+            "xla_bytes": _tree_bytes(backend_cache_dir()),
             "serving_manifests": len(os.listdir(
                 os.path.join(self.root, SERVING_DIR))),
         }

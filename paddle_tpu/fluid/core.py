@@ -153,27 +153,37 @@ def _jax():
 
 
 def get_jax_device(place: Place):
-    """Resolve a Place to a concrete jax.Device (best effort).
+    """Resolve a Place to a concrete jax.Device.
 
     Always a process-LOCAL device: under jax.distributed the global device
     list starts with process 0's devices, and committing feeds to another
     process's device would make every fetch non-addressable here (the
-    local-SGD runner hit exactly that)."""
+    local-SGD runner hit exactly that).
+
+    An accelerator place with no such accelerator raises, naming the
+    platforms jax does see — a trainer asked for the TPU never trains on
+    the host unnoticed.  The one exception is a process explicitly pinned
+    to the CPU (``JAX_PLATFORMS=cpu`` / ``jax_platforms == "cpu"``, which
+    is how the tests and ``--device CPU`` run): there the place resolves
+    onto the virtual host devices."""
     jax = _jax()
     kind = place.device_type
-
-    def local(k):
-        return [d for d in jax.local_devices() if d.platform == k]
-
+    local = jax.local_devices()
     if kind == "cpu":
-        devs = local("cpu") or jax.devices("cpu")
+        devs = [d for d in local if d.platform == "cpu"] or jax.devices("cpu")
     else:
-        # tpu / gpu: take the default backend's devices; on a TPU host this is
-        # the TPU chip, under forced-CPU tests it degrades to host devices.
-        try:
-            devs = local(kind) or jax.devices(kind)
-        except RuntimeError:
-            devs = jax.local_devices()
+        # CUDAPlace is accepted for API parity: it takes whatever
+        # accelerator jax has
+        devs = [d for d in local if d.platform == kind
+                or (kind == "gpu" and d.platform != "cpu")]
+        if not devs:
+            if jax.config.jax_platforms != "cpu":
+                seen = sorted({d.platform for d in local})
+                raise RuntimeError(
+                    f"{place!r} needs a {kind} device, but jax sees only "
+                    f"{seen}; pin the process to the CPU "
+                    f"(JAX_PLATFORMS=cpu) to run there on purpose")
+            devs = local
     return devs[place.device_id % len(devs)]
 
 

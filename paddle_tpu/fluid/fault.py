@@ -7,7 +7,7 @@ place that can deterministically reproduce the failures a production pod
 actually sees — preempted workers, checkpoints killed mid-write, slow/wedged
 storage, silent NaNs, stalled collectives — so the recovery paths in
 ``trainer``/``multihost``/``parallel.elastic`` are exercised by fast tests
-instead of discovered during multi-hour TPU wedges (VERDICT r5).
+instead of discovered in production.
 
 Faults are armed either programmatically (``install(FaultPlan(...))``) or
 via environment flags, which is how the elastic supervisor injects them into

@@ -20,7 +20,7 @@ policy actually asks:
     ``straggler.detected{rank=}`` record next to the watchdog's
     ``slo.breach`` events.
 
-Two halves, same state taxonomy:
+Two halves, same state classes:
 
 **Live accumulator** (:class:`GoodputAccumulator`, armed by
 ``PADDLE_GOODPUT``, default on): the executor/trainer/multihost/data hook
@@ -58,7 +58,7 @@ __all__ = [
     "build_ledger", "classify_intervals", "reset",
 ]
 
-#: the wall-clock taxonomy.  "idle" is never noted explicitly — it is
+#: the wall-clock classes.  "idle" is never noted explicitly — it is
 #: whatever the other states do not claim.
 STATES = ("device", "compile", "data_wait", "checkpoint", "barrier",
           "restart", "idle")

@@ -64,7 +64,7 @@ __all__ = [
     "Span", "span", "start_span", "emit_span", "current", "enabled",
     "trace_context", "set_trace_context", "new_span_id",
     "format_traceparent", "parse_traceparent", "thread_tid",
-    "cost_of", "device_peak_flops", "note_device_cost",
+    "cost_of", "device_peak_flops", "peak_tflops", "note_device_cost",
     "note_window_breakdown", "reset",
 ]
 
@@ -306,14 +306,26 @@ def emit_span(name: str, t0: float, t1: float,
 # device-time attribution: compiled cost -> flops/MFU/breakdown gauges
 # ---------------------------------------------------------------------------
 
-#: peak dense bf16 TFLOPs per chip by TPU generation (device_kind
-#: substrings, bench.py's table); CPU gets a nominal figure so MFU stays
-#: a defined diagnostic ratio on the test backend.
-PEAK_BF16_TFLOPS = (
-    ("v6", 918.0), ("v5p", 459.0), ("v5e", 197.0), ("v5 lite", 197.0),
-    ("v5litepod", 197.0), ("v4", 275.0), ("v3", 123.0), ("v2", 45.0),
-)
-CPU_NOMINAL_TFLOPS = 0.5  # per-core-class placeholder, documented nominal
+#: Peak dense bf16 TFLOP/s of one chip, keyed by ``device_kind`` exactly
+#: as jax reports it — the one such table in the repo (bench.py reads it
+#: too).  A kind that is not here is an error, never a default: add it
+#: with its source when such a chip is attached.
+#:  - "TPU v5 lite" (v5e): 197, Google Cloud documentation, "TPU v5e".
+#:  - "cpu": a documented NOMINAL figure, there only so that the
+#:    ``device.mfu`` ratio stays defined on the test backend; a ratio
+#:    against it is a diagnostic, not a device metric.
+PEAK_BF16_TFLOPS = {"TPU v5 lite": 197.0, "cpu": 0.5}
+
+
+def peak_tflops(device_kind: str) -> float:
+    """Peak bf16 TFLOP/s of one ``device_kind`` chip; unknown kinds raise."""
+    try:
+        return PEAK_BF16_TFLOPS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak rate recorded for device_kind {device_kind!r}; add "
+            f"it with its source to observe.trace.PEAK_BF16_TFLOPS "
+            f"(known: {sorted(PEAK_BF16_TFLOPS)})") from None
 
 
 def device_peak_flops(device=None) -> float:
@@ -322,13 +334,7 @@ def device_peak_flops(device=None) -> float:
 
     if device is None:
         device = jax.devices()[0]
-    if getattr(device, "platform", "cpu") == "cpu":
-        return CPU_NOMINAL_TFLOPS * 1e12
-    kind = (getattr(device, "device_kind", "") or "").lower()
-    for key, tflops in PEAK_BF16_TFLOPS:
-        if key in kind:
-            return tflops * 1e12
-    return 197.0 * 1e12  # unknown generation: assume v5e-class
+    return peak_tflops(device.device_kind) * 1e12
 
 
 def cost_of(stage) -> Optional[dict]:
@@ -358,6 +364,7 @@ def note_device_cost(cost: Optional[dict], wall_s: float, n_steps: int,
     peak.  Returns the MFU, or None when no cost is available."""
     if not cost or wall_s <= 0.0:
         return None
+    peak = device_peak_flops(device)  # an unknown chip is an error
     try:
         from . import registry
 
@@ -367,7 +374,7 @@ def note_device_cost(cost: Optional[dict], wall_s: float, n_steps: int,
                       labels=labels)
         reg.set_gauge("device.bytes_per_window", cost["bytes"],
                       labels=labels)
-        mfu = cost["flops"] / wall_s / device_peak_flops(device)
+        mfu = cost["flops"] / wall_s / peak
         reg.set_gauge("device.mfu", mfu, labels=labels)
         reg.set_gauge("device.flops_per_sec", cost["flops"] / wall_s,
                       labels=labels)

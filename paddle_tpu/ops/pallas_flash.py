@@ -43,6 +43,13 @@ DEFAULT_BLOCK_K = 256
 NEG_INF = -1e30
 
 
+def block_index(*idx):
+    """``BlockSpec`` index-map results, all int32.  The package runs jax in
+    x64 mode, where a Python literal in an index map becomes an i64 result
+    that Mosaic refuses to legalize (interpret mode never notices)."""
+    return tuple(jnp.asarray(i, jnp.int32) for i in idx)
+
+
 def _causal_mask(logits, q_off, k_off):
     qpos = q_off + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
     kpos = k_off + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
@@ -253,6 +260,26 @@ def _bias_2d(bias, b, h, t_kv):
     return bias
 
 
+def _index_maps(h):
+    """Index maps over a (batch*head, outer, inner) grid: ``resident``
+    tiles follow the outer grid dim, ``streamed`` tiles the inner
+    (sequential) one, and ``key_bias(dim)`` picks the [B, 1, Tk] bias tile
+    of this head's batch row at the key block that grid dim ``dim`` walks.
+    The batch row is ``lax.div``, not ``//``: floor-division's sign fix-up
+    does not lower in a Mosaic index map."""
+    def resident(i, j, s):
+        return block_index(i, j, 0)
+
+    def streamed(i, j, s):
+        return block_index(i, s, 0)
+
+    def key_bias(dim):
+        return lambda *g: block_index(
+            jax.lax.div(g[0], jnp.int32(h)), 0, g[dim])
+
+    return resident, streamed, key_bias
+
+
 def _flash_forward(q, k, v, bias, scale, causal, block_q, block_k,
                    interpret):
     from jax.experimental.pallas import tpu as pltpu
@@ -267,15 +294,15 @@ def _flash_forward(q, k, v, bias, scale, causal, block_q, block_k,
     qr = q.reshape(b * h, t, d)
     kr = k.reshape(b * h, t_kv, d)
     vr = v.reshape(b * h, t_kv, d)
+    resident, streamed, key_bias = _index_maps(h)
     in_specs = [
-        pl.BlockSpec((1, bq, d), lambda i, j, s: (i, j, 0)),
-        pl.BlockSpec((1, bk, d), lambda i, j, s: (i, s, 0)),
-        pl.BlockSpec((1, bk, d), lambda i, j, s: (i, s, 0)),
+        pl.BlockSpec((1, bq, d), resident),
+        pl.BlockSpec((1, bk, d), streamed),
+        pl.BlockSpec((1, bk, d), streamed),
     ]
     args = [qr, kr, vr]
     if bias is not None:
-        in_specs.append(pl.BlockSpec(
-            (1, 1, bk), lambda i, j, s, h=h: (i // h, 0, s)))
+        in_specs.append(pl.BlockSpec((1, 1, bk), key_bias(2)))
         args.append(bias.reshape(b, 1, t_kv))
     out, lse = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, causal=causal,
@@ -284,8 +311,8 @@ def _flash_forward(q, k, v, bias, scale, causal, block_q, block_k,
                    jax.ShapeDtypeStruct((b * h, t, 1), jnp.float32)],
         grid=grid,
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, bq, d), lambda i, j, s: (i, j, 0)),
-                   pl.BlockSpec((1, bq, 1), lambda i, j, s: (i, j, 0))],
+        out_specs=[pl.BlockSpec((1, bq, d), resident),
+                   pl.BlockSpec((1, bq, 1), resident)],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),   # running max
             pltpu.VMEM((bq, 1), jnp.float32),   # running denominator
@@ -315,37 +342,37 @@ def _flash_backward(q, k, v, bias, out, lse, do, scale, causal, block_q,
     has_bias = bias is not None
     bias_args = [bias.reshape(b, 1, t_kv)] if has_bias else []
 
+    resident, streamed, key_bias = _index_maps(h)
+
     # dQ: q-tile resident, k innermost
-    q_res = [pl.BlockSpec((1, bq, d), lambda i, j, s: (i, j, 0)),
-             pl.BlockSpec((1, bk, d), lambda i, j, s: (i, s, 0)),
-             pl.BlockSpec((1, bk, d), lambda i, j, s: (i, s, 0)),
-             pl.BlockSpec((1, bq, d), lambda i, j, s: (i, j, 0)),
-             pl.BlockSpec((1, bq, 1), lambda i, j, s: (i, j, 0)),
-             pl.BlockSpec((1, bq, 1), lambda i, j, s: (i, j, 0))]
+    q_res = [pl.BlockSpec((1, bq, d), resident),
+             pl.BlockSpec((1, bk, d), streamed),
+             pl.BlockSpec((1, bk, d), streamed),
+             pl.BlockSpec((1, bq, d), resident),
+             pl.BlockSpec((1, bq, 1), resident),
+             pl.BlockSpec((1, bq, 1), resident)]
     if has_bias:
-        q_res.append(pl.BlockSpec(
-            (1, 1, bk), lambda i, j, s, h=h: (i // h, 0, s)))
+        q_res.append(pl.BlockSpec((1, 1, bk), key_bias(2)))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           n_k=n_k, has_bias=has_bias),
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
         grid=(b * h, n_q, n_k),
         in_specs=q_res,
-        out_specs=pl.BlockSpec((1, bq, d), lambda i, j, s: (i, j, 0)),
+        out_specs=pl.BlockSpec((1, bq, d), resident),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
     )(qr, kr, vr, dor, lser, dr, *bias_args)
 
     # dK/dV: k-tile resident, q innermost
-    kv_res = [pl.BlockSpec((1, bq, d), lambda i, j, s: (i, s, 0)),
-              pl.BlockSpec((1, bk, d), lambda i, j, s: (i, j, 0)),
-              pl.BlockSpec((1, bk, d), lambda i, j, s: (i, j, 0)),
-              pl.BlockSpec((1, bq, d), lambda i, j, s: (i, s, 0)),
-              pl.BlockSpec((1, bq, 1), lambda i, j, s: (i, s, 0)),
-              pl.BlockSpec((1, bq, 1), lambda i, j, s: (i, s, 0))]
+    kv_res = [pl.BlockSpec((1, bq, d), streamed),
+              pl.BlockSpec((1, bk, d), resident),
+              pl.BlockSpec((1, bk, d), resident),
+              pl.BlockSpec((1, bq, d), streamed),
+              pl.BlockSpec((1, bq, 1), streamed),
+              pl.BlockSpec((1, bq, 1), streamed)]
     if has_bias:
-        kv_res.append(pl.BlockSpec(
-            (1, 1, bk), lambda i, j, s, h=h: (i // h, 0, j)))
+        kv_res.append(pl.BlockSpec((1, 1, bk), key_bias(1)))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           n_q=n_q, has_bias=has_bias),
@@ -353,8 +380,8 @@ def _flash_backward(q, k, v, bias, out, lse, do, scale, causal, block_q,
                    jax.ShapeDtypeStruct((b * h, t_kv, d), v.dtype)],
         grid=(b * h, n_k, n_q),
         in_specs=kv_res,
-        out_specs=[pl.BlockSpec((1, bk, d), lambda i, j, s: (i, j, 0)),
-                   pl.BlockSpec((1, bk, d), lambda i, j, s: (i, j, 0))],
+        out_specs=[pl.BlockSpec((1, bk, d), resident),
+                   pl.BlockSpec((1, bk, d), resident)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,

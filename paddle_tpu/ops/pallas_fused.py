@@ -42,12 +42,10 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:  # jax >= 0.8 moved shard_map to the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
+
+from .pallas_flash import block_index
 
 DEFAULT_BLOCK_R = 256    # rows (flattened batch) per grid step
 DEFAULT_BLOCK_V = 512    # vocab/class columns per grid step
@@ -116,10 +114,12 @@ def _interp(interpret):
 
 
 def _fit_block(size, block):
-    b = min(block, size)
-    while size % b:
-        b //= 2
-    return b
+    """Block extent along one dim: the whole dim when it fits in
+    ``block``, else ``block`` itself (a multiple of the 8x128 tile) over a
+    ``pl.cdiv`` grid.  The last block may then hang over the array's edge:
+    Mosaic drops the writes past it, and a kernel that reduces along that
+    dim masks the reads (see ``_xent_partial_kernel``)."""
+    return size if size <= block else block
 
 
 # ---------------------------------------------------------------------------
@@ -127,7 +127,7 @@ def _fit_block(size, block):
 # ---------------------------------------------------------------------------
 
 
-def _xent_partial_kernel(x_ref, lab_ref, *out_refs, bv, n_v, soft):
+def _xent_partial_kernel(x_ref, lab_ref, *out_refs, bv, n_v, v, soft):
     """Grid step (row-block, vocab-block): online-logsumexp state (m, l)
     plus the label accumulator(s) in fp32 VMEM scratch, carried across the
     (sequential, minormost) vocab dimension — VMEM holds one [br, bv]
@@ -154,6 +154,17 @@ def _xent_partial_kernel(x_ref, lab_ref, *out_refs, bv, n_v, soft):
             b_ref[:] = jnp.zeros_like(b_ref)
 
     x = x_ref[...].astype(jnp.float32)               # [br, bv]
+    ragged = v % bv != 0
+    if ragged or not soft:
+        # all index math in i32: under the package-wide x64 mode python
+        # ints promote to i64, which Mosaic's index ops reject
+        cols = j * jnp.int32(bv) + lax.broadcasted_iota(
+            jnp.int32, x.shape, 1)
+    if ragged:
+        # the last vocab block hangs over the array's edge and reads
+        # unspecified values there: they must not reach max/sum
+        live = cols < jnp.int32(v)
+        x = jnp.where(live, x, jnp.float32(NEG_INF))
     m = m_ref[:]
     m_new = jnp.maximum(m, jnp.max(x, axis=1, keepdims=True))
     p = jnp.exp(x - m_new)
@@ -162,13 +173,13 @@ def _xent_partial_kernel(x_ref, lab_ref, *out_refs, bv, n_v, soft):
     l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
     if soft:
         y = lab_ref[...].astype(jnp.float32)         # [br, bv]
-        a_ref[:] = a_ref[:] + jnp.sum(y * x, axis=1, keepdims=True)
+        yx = y * x
+        if ragged:
+            y = jnp.where(live, y, 0.0)
+            yx = jnp.where(live, yx, 0.0)
+        a_ref[:] = a_ref[:] + jnp.sum(yx, axis=1, keepdims=True)
         b_ref[:] = b_ref[:] + jnp.sum(y, axis=1, keepdims=True)
     else:
-        # all index math in i32: under the package-wide x64 mode python
-        # ints promote to i64, which Mosaic's index ops reject
-        cols = j * jnp.int32(bv) + lax.broadcasted_iota(
-            jnp.int32, x.shape, 1)
         lab = lab_ref[...]                           # [br, 1] int32
         a_ref[:] = a_ref[:] + jnp.sum(
             jnp.where(cols == lab, x, 0.0), axis=1, keepdims=True)
@@ -204,6 +215,16 @@ def _xent_bwd_kernel(x_ref, lab_ref, lse_ref, g1_ref, g2_ref, dx_ref, *,
     dx_ref[...] = (g1 * p - g2 * tgt).astype(dx_ref.dtype)
 
 
+def _tile(i, j):
+    """Index map of a [block_r, block_v] logits/label/dx tile."""
+    return block_index(i, j)
+
+
+def _row_col(i, j):
+    """Index map of a per-row [block_r, 1] column (labels, m/l/a/b, lse)."""
+    return block_index(i, 0)
+
+
 def _xent_partial(x2, lab2, soft, block_r, block_v, interpret):
     """Run the streaming kernel over ``x2 [R, V]``; returns per-row fp32
     ``(m, l, a, b)`` columns (``b`` is None for hard labels)."""
@@ -212,17 +233,18 @@ def _xent_partial(x2, lab2, soft, block_r, block_v, interpret):
     r, v = x2.shape
     br = _fit_block(r, block_r)
     bv = _fit_block(v, block_v)
-    n_v = v // bv
+    n_v = pl.cdiv(v, bv)
     col = jax.ShapeDtypeStruct((r, 1), jnp.float32)
-    lab_spec = (pl.BlockSpec((br, bv), lambda i, j: (i, j)) if soft
-                else pl.BlockSpec((br, 1), lambda i, j: (i, 0)))
-    out_spec = pl.BlockSpec((br, 1), lambda i, j: (i, 0))
+    lab_spec = (pl.BlockSpec((br, bv), _tile) if soft
+                else pl.BlockSpec((br, 1), _row_col))
+    out_spec = pl.BlockSpec((br, 1), _row_col)
     n_out = 4 if soft else 3
     outs = pl.pallas_call(
-        functools.partial(_xent_partial_kernel, bv=bv, n_v=n_v, soft=soft),
+        functools.partial(_xent_partial_kernel, bv=bv, n_v=n_v, v=v,
+                          soft=soft),
         out_shape=[col] * n_out,
-        grid=(r // br, n_v),
-        in_specs=[pl.BlockSpec((br, bv), lambda i, j: (i, j)), lab_spec],
+        grid=(pl.cdiv(r, br), n_v),
+        in_specs=[pl.BlockSpec((br, bv), _tile), lab_spec],
         out_specs=[out_spec] * n_out,
         scratch_shapes=[pltpu.VMEM((br, 1), jnp.float32)] * n_out,
         interpret=_interp(interpret),
@@ -239,16 +261,15 @@ def _xent_bwd_call(x2, lab2, lse, g1, g2, soft, block_r, block_v,
     r, v = x2.shape
     br = _fit_block(r, block_r)
     bv = _fit_block(v, block_v)
-    lab_spec = (pl.BlockSpec((br, bv), lambda i, j: (i, j)) if soft
-                else pl.BlockSpec((br, 1), lambda i, j: (i, 0)))
-    col = pl.BlockSpec((br, 1), lambda i, j: (i, 0))
+    lab_spec = (pl.BlockSpec((br, bv), _tile) if soft
+                else pl.BlockSpec((br, 1), _row_col))
+    col = pl.BlockSpec((br, 1), _row_col)
     return pl.pallas_call(
         functools.partial(_xent_bwd_kernel, bv=bv, soft=soft),
         out_shape=jax.ShapeDtypeStruct((r, v), x2.dtype),
-        grid=(r // br, v // bv),
-        in_specs=[pl.BlockSpec((br, bv), lambda i, j: (i, j)), lab_spec,
-                  col, col, col],
-        out_specs=pl.BlockSpec((br, bv), lambda i, j: (i, j)),
+        grid=(pl.cdiv(r, br), pl.cdiv(v, bv)),
+        in_specs=[pl.BlockSpec((br, bv), _tile), lab_spec, col, col, col],
+        out_specs=pl.BlockSpec((br, bv), _tile),
         interpret=_interp(interpret),
     )(x2, lab2, lse, g1, g2)
 
@@ -396,7 +417,7 @@ def _xent_sharded_fwd(logits2, label2, mesh, soft, ignore, block_r,
 
     loss, lse, b = _shard_map(
         body, mesh=mesh, in_specs=(xspec, lspec),
-        out_specs=(cspec, cspec, cspec), check_rep=False)(logits2, label2)
+        out_specs=(cspec, cspec, cspec), check_vma=False)(logits2, label2)
     return loss, lse, (logits2, label2, lse, b)
 
 
@@ -422,7 +443,7 @@ def _xent_sharded_bwd_vjp(mesh, soft, ignore, block_r, block_v, interpret,
 
     dx = _shard_map(
         body, mesh=mesh, in_specs=(xspec, lspec, cspec, cspec, cspec),
-        out_specs=xspec, check_rep=False)(logits2, label2, lse, g1, g2)
+        out_specs=xspec, check_vma=False)(logits2, label2, lse, g1, g2)
     return dx, _label_zeros(label2)
 
 
@@ -508,31 +529,46 @@ def _adam_kernel(p_ref, g_ref, m1_ref, m2_ref, lr_ref, po_ref, m1o_ref,
     m2o_ref[...] = m2o.astype(m2o_ref.dtype)
 
 
-def _sweep_shape(n: int):
-    """2-D view for the flat parameter sweep: lane-aligned rows when the
-    element count divides the 128-lane, a single row otherwise (interpret
-    mode and Mosaic both take it; huge non-aligned params are rejected by
-    :func:`opt_fusable` instead of blowing VMEM)."""
+def _sweep_view(shape):
+    """2-D view ``(rows, cols)`` of one parameter for the sweep.  A
+    lane-aligned last dim is kept and the leading dims collapse onto it,
+    which leaves the tiled HBM layout alone; other lane-aligned element
+    counts (1-D biases) become rows of 128; anything else is one ragged
+    row, which the sweep walks in column blocks."""
+    n = int(np.prod(shape, dtype=np.int64))
+    if len(shape) >= 2 and shape[-1] % LANE == 0:
+        return (n // shape[-1], shape[-1])
     if n % LANE == 0:
         return (n // LANE, LANE)
     return (1, n)
 
 
+def _sweep_blocks(rows, cols):
+    """Block ``(br, bc)`` of ``DEFAULT_BLOCK_N`` 128-lane rows (512 KiB of
+    fp32) whatever the view's aspect, so the seven operands of an Adam
+    sweep, double-buffered, stay far inside VMEM.  ``bc`` leaves room for
+    eight rows: a lone ragged row pads to eight sublanes there anyway."""
+    block = DEFAULT_BLOCK_N * LANE
+    bc = _fit_block(cols, block // 8)
+    br = _fit_block(rows, max(8, block // bc // 8 * 8))
+    return br, bc
+
+
 def _opt_sweep(kernel, arrays, lr, n_out, interpret):
     """One multi-tensor grid sweep: every tensor of the update (param,
-    grad, moments) flattens to the same 2-D view, one grid step updates
-    one row-block of ALL of them, and ``input_output_aliases`` writes the
+    grad, moments) takes the same 2-D view, one grid step updates one
+    block of ALL of them, and ``input_output_aliases`` writes the
     param/moment outputs back into their (donated) input buffers."""
     from jax.experimental.pallas import tpu as pltpu
 
     shape = arrays[0].shape
-    n = int(np.prod(shape, dtype=np.int64))
-    rows, cols = _sweep_shape(n)
-    br = _fit_block(rows, max(1, DEFAULT_BLOCK_N // max(1, cols // LANE)))
+    rows, cols = _sweep_view(shape)
+    br, bc = _sweep_blocks(rows, cols)
     flat = [a.reshape(rows, cols) for a in arrays]
     lr2 = jnp.asarray(lr, jnp.float32).reshape(1, 1)
-    blk = pl.BlockSpec((br, cols), lambda i: (i, 0))
-    scal = pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM)
+    blk = pl.BlockSpec((br, bc), lambda i, j: block_index(i, j))
+    scal = pl.BlockSpec((1, 1), lambda i, j: block_index(0, 0),
+                        memory_space=pltpu.SMEM)
     # outputs alias the param/moment INPUTS (grad at index 1 is read-only)
     aliases = {0: 0}
     for k in range(1, n_out):
@@ -541,7 +577,7 @@ def _opt_sweep(kernel, arrays, lr, n_out, interpret):
         kernel,
         out_shape=[jax.ShapeDtypeStruct((rows, cols), a.dtype)
                    for a in (arrays[:1] + arrays[2:2 + n_out - 1])],
-        grid=(rows // br,),
+        grid=(pl.cdiv(rows, br), pl.cdiv(cols, bc)),
         in_specs=[blk] * len(flat) + [scal],
         out_specs=[blk] * n_out,
         input_output_aliases=aliases,
@@ -557,8 +593,8 @@ def opt_fusable(p, g) -> bool:
     n = int(np.prod(p.shape, dtype=np.int64))
     if n == 0:
         return False
-    # a non-lane-aligned tensor runs as one [1, n] row; cap it so a huge
-    # ragged embedding cannot blow the VMEM budget
+    # a non-lane-aligned tensor runs as one [1, n] row that fills one
+    # sublane in eight; past this size the unfused lowering is the better
     if n % LANE and n > (1 << 17):
         return False
     return g is not None and g.shape == p.shape
@@ -618,7 +654,7 @@ def _run_opt(kernel, arrays, lr, n_out, var_name, interpret):
 
     outs = _shard_map(body, mesh=mesh,
                       in_specs=tuple([spec] * len(arrays)) + (P(),),
-                      out_specs=tuple([spec] * n_out), check_rep=False)(
+                      out_specs=tuple([spec] * n_out), check_vma=False)(
         *arrays, jnp.asarray(lr, jnp.float32).reshape(()))
     return list(outs)
 
@@ -694,6 +730,6 @@ def flash_attention_sharded(q, k, v, bias, scale, causal, mesh,
         ba = b_axis if (bias.ndim and bias.shape[0] > 1) else None
         in_specs.append(P(ba, *([None] * (bias.ndim - 1))))
     out = _shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=spec, check_rep=False)(*args)
+                     out_specs=spec, check_vma=False)(*args)
     _note("flash_attention")
     return out

@@ -11,8 +11,10 @@ one K/V page — ``BlockSpec`` index maps read ``pt[s, j]`` — and the
 ``[slots, L]`` score matrix never round-trips through a gathered HBM copy.
 
 Bitwise discipline (the PR 15 sequential-equivalence invariant): scores
-accumulate per page into a VMEM ``[1, L]`` scratch row and the softmax at
-the LAST page iteration replays ``jax.nn.softmax``'s exact sequence
+accumulate per page into a VMEM ``[n_pages, page_size, 1]`` scratch (one
+key per sublane, so every store is a whole leading-dim slot and nothing
+lands at an unaligned lane offset — Mosaic refuses those) and the softmax
+at the LAST page iteration replays ``jax.nn.softmax``'s exact sequence
 (max, exp(x - max), divide by sum) over the full row — NOT the online
 recurrence flash attention uses, which is numerically but not bitwise
 equal.  Validity masking arrives as the same additive ``-inf`` bias the
@@ -36,41 +38,43 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .pallas_flash import block_index
+
 
 def _paged_kernel(pt_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
-                  scores_ref, vbuf_ref, *, scale, n_pages, ps):
+                  scores_ref, vbuf_ref, *, scale, n_pages):
     """Grid step (slot, page): score ONE gathered K/V page against the
-    slot's single query row, park the partial score segment + fp32 V copy
+    slot's single query row, park the page's score column + fp32 V copy
     in VMEM scratch, and run the exact full-row softmax at the last page.
+
+    Keys run down the sublanes throughout (scores are ``[ps, 1]`` columns,
+    scratch is indexed by page on its leading dim), so no store lands at
+    an unaligned lane offset and nothing is relaid out between the page
+    loop and the flush; with one query row the two contractions are VPU
+    multiply-reduces, not MXU passes.
 
     ``pt_ref`` is the scalar-prefetched page table — it is consumed by the
     in_spec index maps (``pt[s, j]`` picks the cache block), not read here.
     """
     del pt_ref
     j = pl.program_id(1)
-    # all index math in i32: under the package-wide x64 mode python ints
-    # promote to i64, which Mosaic's index ops reject
-    off = j * jnp.int32(ps)
     q = q_ref[0].astype(jnp.float32)                    # [1, d]
     if scale != 1.0:
         q = q * jnp.float32(scale)
     k = k_ref[0].astype(jnp.float32)                    # [ps, d]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)             # [1, ps]
-    s = s + bias_ref[0].astype(jnp.float32)
-    scores_ref[:, pl.ds(off, ps)] = s
-    vbuf_ref[pl.ds(off, ps), :] = v_ref[0].astype(jnp.float32)
+    s = jnp.sum(k * q, axis=1, keepdims=True)           # [ps, 1]
+    scores_ref[j] = s + bias_ref[0, 0].astype(jnp.float32)
+    vbuf_ref[j] = v_ref[0].astype(jnp.float32)
 
     @pl.when(j == jnp.int32(n_pages - 1))
     def _flush():
-        z = scores_ref[:]                               # [1, L]
-        m = jnp.max(z, axis=-1, keepdims=True)
+        z = scores_ref[:]                               # [n_pages, ps, 1]
+        m = jnp.max(jnp.max(z, axis=0), axis=0, keepdims=True)
         e = jnp.exp(z - m)
-        p = e / jnp.sum(e, axis=-1, keepdims=True)
-        o_ref[0] = jax.lax.dot_general(
-            p, vbuf_ref[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+        p = e / jnp.sum(jnp.sum(e, axis=0), axis=0, keepdims=True)
+        o = jnp.sum(jnp.sum(p * vbuf_ref[:], axis=0), axis=0,
+                    keepdims=True)                      # [1, d]
+        o_ref[0] = o.astype(o_ref.dtype)
 
 
 def paged_attention(q, cache_k, cache_v, page_table, bias, scale=1.0,
@@ -96,25 +100,37 @@ def paged_attention(q, cache_k, cache_v, page_table, bias, scale=1.0,
             f"[{s_n}, 1, {ell}]; got {bias.shape}")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+
+    def slot(s, j, pt):
+        return block_index(s, 0, 0)
+
+    def page(s, j, pt):
+        return block_index(pt[s, j], 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(s_n, n_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, d), lambda s, j, pt: (s, 0, 0)),
-            pl.BlockSpec((1, ps, d), lambda s, j, pt: (pt[s, j], 0, 0)),
-            pl.BlockSpec((1, ps, d), lambda s, j, pt: (pt[s, j], 0, 0)),
-            pl.BlockSpec((1, 1, ps), lambda s, j, pt: (s, 0, j)),
+            pl.BlockSpec((1, 1, d), slot),
+            pl.BlockSpec((1, ps, d), page),
+            pl.BlockSpec((1, ps, d), page),
+            # one page's bias as a [ps, 1] column: a [1, ps] row block
+            # would have a lane extent that is neither 128-aligned nor
+            # the array's, which the TPU lowering refuses
+            pl.BlockSpec((1, 1, ps, 1),
+                         lambda s, j, pt: block_index(s, j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda s, j, pt: (s, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, d), slot),
         scratch_shapes=[
-            pltpu.VMEM((1, ell), jnp.float32),   # full score row
-            pltpu.VMEM((ell, d), jnp.float32),   # gathered fp32 V
+            pltpu.VMEM((n_pages, ps, 1), jnp.float32),   # full score row
+            pltpu.VMEM((n_pages, ps, d), jnp.float32),   # gathered fp32 V
         ],
     )
     return pl.pallas_call(
         functools.partial(_paged_kernel, scale=float(scale),
-                          n_pages=n_pages, ps=ps),
+                          n_pages=n_pages),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_n, 1, d), q.dtype),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), q, cache_k, cache_v, bias)
+    )(page_table.astype(jnp.int32), q, cache_k, cache_v,
+      bias.reshape(s_n, n_pages, ps, 1))

@@ -84,11 +84,8 @@ def init(coordinator_addr: Optional[str] = None,
             # backend") unless the gloo collectives implementation is
             # selected BEFORE backend init — without this, every
             # multi-process CPU test/run dies at its first collective
-            try:
-                jax.config.update("jax_cpu_collectives_implementation",
-                                  "gloo")
-            except Exception:
-                pass  # older jaxlib without the option: keep old behavior
+            jax.config.update("jax_cpu_collectives_implementation",
+                              "gloo")
         jax.distributed.initialize(coordinator_addr, num_processes,
                                    process_id, local_device_ids)
     except RuntimeError as exc:

@@ -26,37 +26,15 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-
-def _pvary(x, axis_names):
-    """Newer jax tracks varying-manual-axes types inside shard_map and
-    requires per-stage-written scan carries to be pcast to varying; older
-    jax has no vma tracking (and no ``lax.pcast``) — identity there."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axis_names, to="varying")
-    return x
-
-
-def _axis_size(axis_name):
-    """``lax.axis_size`` appeared in newer jax; ``psum(1, axis)`` of a
-    static scalar is the version-stable spelling (evaluates statically)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.8 moved shard_map to the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 def _pipeline_body(params, x, stage_fn, pp_axis, n_micro):
     """Runs inside shard_map: params carry a leading stage dim of local
     size 1; x is this dp-row's LOCAL batch [N, ...]."""
     params = jax.tree_util.tree_map(lambda p: p[0], params)
-    s_total = _axis_size(pp_axis)
+    s_total = lax.axis_size(pp_axis)
     stage = lax.axis_index(pp_axis)
     n = x.shape[0]
     mb = n // n_micro
@@ -85,8 +63,8 @@ def _pipeline_body(params, x, stage_fn, pp_axis, n_micro):
     # initial carries must be marked varying over the pp axis (the loop
     # writes per-stage values into them) or scan rejects the carry types;
     # zeros_like(xmb) inherits x's batch-axis vma, pcast adds pp
-    cur0 = _pvary(jnp.zeros_like(xmb[0]), (pp_axis,))
-    buf0 = _pvary(jnp.zeros_like(xmb), (pp_axis,))
+    cur0 = lax.pcast(jnp.zeros_like(xmb[0]), (pp_axis,), to="varying")
+    buf0 = lax.pcast(jnp.zeros_like(xmb), (pp_axis,), to="varying")
     (_, out_buf), _ = lax.scan(step, (cur0, buf0),
                                jnp.arange(n_micro + s_total - 1))
     # only the last stage holds real results; psum replicates them across pp

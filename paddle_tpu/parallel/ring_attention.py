@@ -25,21 +25,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-
-def _axis_size(axis_name):
-    """``lax.axis_size`` appeared in newer jax; ``psum(1, axis)`` of a
-    static scalar is the version-stable spelling (evaluates statically)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.8 moved shard_map to the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 def _amp_einsum(spec, a, b):
@@ -81,7 +68,7 @@ def _ring_body(q, k, v, bias, axis_name, causal, scale):
     """Runs inside shard_map: q/k/v are the LOCAL [B, H, T/S, D] blocks;
     bias (or None) is the LOCAL [B, 1, 1, T/S] key-bias block, which
     rotates around the ring together with its k/v block."""
-    n_dev = _axis_size(axis_name)
+    n_dev = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     t_local = q.shape[2]
     q_off = my * t_local

@@ -40,30 +40,8 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-
-def _pvary(x, axis_names):
-    """Newer jax tracks varying-manual-axes types inside shard_map and
-    requires per-stage-written scan carries to be pcast to varying; older
-    jax has no vma tracking (and no ``lax.pcast``) — identity there."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axis_names, to="varying")
-    return x
-
-
-def _axis_size(axis_name):
-    """``lax.axis_size`` appeared in newer jax; ``psum(1, axis)`` of a
-    static scalar is the version-stable spelling (evaluates statically)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.8 moved shard_map to the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from . import ring_attention as ra
 
@@ -251,7 +229,7 @@ def _gpipe_tree_body(params, xs: Dict[str, jnp.ndarray], *, stage_fn,
     together through the pipeline (activations + context like enc_out /
     biases); stage_fn(params, tree, t) -> tree updates ``out_slot`` and
     passes the rest through.  Returns the final ``out_slot`` stream."""
-    s_total = _axis_size(pp_axis)
+    s_total = lax.axis_size(pp_axis)
     stage = lax.axis_index(pp_axis)
     n = next(iter(xs.values())).shape[0]
     if n % n_micro:
@@ -281,9 +259,9 @@ def _gpipe_tree_body(params, xs: Dict[str, jnp.ndarray], *, stage_fn,
             out_buf)
         return (out, out_buf), None
 
-    cur0 = {k: _pvary(jnp.zeros_like(v[0]), (pp_axis,))
+    cur0 = {k: lax.pcast(jnp.zeros_like(v[0]), (pp_axis,), to="varying")
             for k, v in xmb.items()}
-    buf0 = _pvary(jnp.zeros_like(xmb[out_slot]), (pp_axis,))
+    buf0 = lax.pcast(jnp.zeros_like(xmb[out_slot]), (pp_axis,), to="varying")
     (_, out_buf), _ = lax.scan(step, (cur0, buf0),
                                jnp.arange(n_micro + s_total - 1))
     out_buf = lax.psum(

@@ -3,7 +3,7 @@ decisions out — no engines, no threads, no clocks.  Every test passes
 explicit ``now`` timestamps, so hysteresis and cooldown arithmetic is
 fully deterministic.
 
-The signal taxonomy under test (the policy's whole job is telling these
+The signal classes under test (the policy's whole job is telling these
 apart):
  - *queue pressure*  -> ``scale_out`` after ``hysteresis_ticks``;
  - *SLO breaches*    -> ``scale_out`` even with an empty queue (the

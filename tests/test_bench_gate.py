@@ -6,14 +6,12 @@ are diffed and any shared headline metric that dropped by more than the
 threshold FAILS the suite — a flat-regression round lands as a red test,
 not silently.
 
-Threshold: the tier-1 floor started at 30% (just above the committed
-r04→r05 -26.65% ResNet noise band on the CPU-fallback trajectory) and is
-now RATCHETED to 20% (ISSUE 12): the fused-kernel layer landed headroom
-and the newest committed rounds sit inside the tighter band, so a
-regression that size is a finding, not noise.  Keep ratcheting as BENCH
-stabilizes.  The gate itself is exercised against synthetic rounds
-(clear regression → exit 1) so a silently-broken gate cannot pass
-vacuously.
+Threshold: 20%.  The records it was tuned on were not from the attached
+chip and were deleted (PR 23), so on the committed tree the gate finds
+no rounds and reports ``skipped`` — the honest answer until the
+benchmark of ROADMAP S1 writes new ones.  The gate itself is exercised
+against synthetic rounds (clear regression → exit 1) so a
+silently-broken gate cannot pass vacuously.
 """
 
 import json

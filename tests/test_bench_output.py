@@ -1,9 +1,9 @@
-"""bench.py output-channel contract (ISSUE 9 satellite).
+"""bench.py output-channel and device contract.
 
-The BENCH driver parses stdout; round 5's JSON tail was polluted by
-``tpu_probe_*`` retry/wedge diagnostics interleaved with the metric
-lines.  Contract now: EVERY stdout line is a clean metric JSON line
-(the last one the combined record), and probe diagnostics go to stderr.
+The BENCH driver parses stdout: EVERY stdout line is a clean metric JSON
+line (the last one the combined record) that names the device it ran on,
+and anything else goes to stderr.  The bench runs on the CPU only when
+the process was pinned there on purpose; it never concedes to it.
 """
 
 import json
@@ -19,28 +19,29 @@ sys.path.insert(0, REPO)
 import bench  # noqa: E402  (repo-root module)
 
 
-def test_probe_diagnostics_go_to_stderr(monkeypatch, capsys):
-    """A wedged probe's retry/give-up records land on stderr as JSON;
-    stdout stays empty for the metric lines to come."""
-    monkeypatch.setattr(bench, "_probe_once", lambda timeout: "wedged")
-    monkeypatch.setenv("BENCH_PROBE_BUDGET", "2")
-    monkeypatch.setenv("BENCH_PROBE_PAUSE", "120")
-    platform, status = bench.probe_platform(timeout=0.1)
-    assert platform == "cpu" and status == "wedged_budget_exhausted"
-    out, err = capsys.readouterr()
-    assert out == ""  # the metric channel stays clean
-    events = [json.loads(line) for line in err.splitlines() if line]
-    assert events and events[-1]["event"] == "tpu_probe_gave_up"
+def test_bench_fails_without_accelerator_when_cpu_not_asked(monkeypatch,
+                                                            capsys):
+    """jax sees only the CPU and nobody pinned the process to it: the
+    bench exits non-zero and prints no metric line."""
+    import jax
+
+    monkeypatch.setattr(sys, "argv", ["bench.py", "--model", "mnist"])
+    old = jax.config.jax_platforms
+    jax.config.update("jax_platforms", None)
+    try:
+        with pytest.raises(SystemExit) as exc:
+            bench.main()
+    finally:
+        jax.config.update("jax_platforms", old)
+    assert exc.value.code not in (0, None)
+    assert "no accelerator" in str(exc.value.code)
+    assert capsys.readouterr().out == ""
 
 
-def test_probe_crash_diagnostics_go_to_stderr(monkeypatch, capsys):
-    monkeypatch.setattr(bench, "_probe_once", lambda timeout: "crashed")
-    platform, status = bench.probe_platform(timeout=0.1)
-    assert platform == "cpu" and status == "probe_crashed"
-    out, err = capsys.readouterr()
-    assert out == ""
-    events = [json.loads(line) for line in err.splitlines() if line]
-    assert events[-1]["event"] == "tpu_probe_crashed"
+def test_bench_lines_name_the_device():
+    line = bench.result_line("m", 1.0, "images/sec/chip", "mnist")
+    assert line["device"] == {"platform": "cpu", "kind": "cpu",
+                              "count": 8}
 
 
 @pytest.mark.slow
@@ -50,8 +51,7 @@ def test_bench_stdout_every_line_parses(tmp_path):
     non-JSON or diagnostic line again."""
     env = dict(os.environ)
     env.update({"JAX_PLATFORMS": "cpu", "BENCH_MODEL": "mnist",
-                "BENCH_MNIST_STEPS": "3", "BENCH_MNIST_BS": "16",
-                "BENCH_PROBE_TIMEOUT": "120"})
+                "BENCH_MNIST_STEPS": "3", "BENCH_MNIST_BS": "16"})
     r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
                        env=env, capture_output=True, text=True,
                        timeout=420, cwd=str(tmp_path))
@@ -62,6 +62,4 @@ def test_bench_stdout_every_line_parses(tmp_path):
     last = parsed[-1]
     assert last.get("metric", "").startswith("mnist")
     assert last.get("value", 0) > 0
-    # probe events, if any fired, are NOT in the metric stream
-    assert not any(str(p.get("event", "")).startswith("tpu_probe")
-                   for p in parsed)
+    assert last["device"]["platform"] == "cpu"

@@ -70,8 +70,8 @@ def _ref_hard(x, lab, ignore=-100):
 
 
 def test_xent_hard_matches_reference():
-    """Odd vocab (100) exercises the block-halving path; loss AND grad
-    within 1e-6 of the XLA logsumexp formulation."""
+    """Odd vocab (100) runs as one whole-dim block; loss AND grad within
+    1e-6 of the XLA logsumexp formulation."""
     rng = np.random.RandomState(0)
     x = jnp.asarray(rng.normal(size=(8, 100)).astype(np.float32))
     lab = jnp.asarray(rng.randint(0, 100, size=(8, 1)).astype(np.int32))
@@ -111,6 +111,39 @@ def test_xent_soft_labels_match_reference():
     g = jax.grad(lambda x: jnp.sum(pf.softmax_xent(x, y, True)[0]))(x)
     gr = jax.grad(lambda x: jnp.sum(
         -jnp.sum(y * jax.nn.log_softmax(x, -1), -1)))(x)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(gr),
+                               rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("soft", [False, True])
+def test_xent_blocks_hanging_over_the_edge(soft):
+    """300 rows in blocks of 256 and 700 classes in blocks of 512: the last
+    block of either dim hangs over the array's edge (Transformer-base's
+    30,000 classes do the same on the chip).  What is read past the edge is
+    unspecified and must not reach the loss or the gradient."""
+    rng = np.random.RandomState(12)
+    x = jnp.asarray(rng.normal(size=(300, 700)).astype(np.float32))
+    if soft:
+        lab = jax.nn.softmax(jnp.asarray(
+            rng.normal(size=(300, 700)).astype(np.float32)), axis=1)
+
+        def ref(a):
+            return -jnp.sum(lab * jax.nn.log_softmax(a, -1), -1,
+                            keepdims=True)
+    else:
+        lab = jnp.asarray(rng.randint(0, 700, size=(300, 1))
+                          .astype(np.int32))
+
+        def ref(a):
+            return _ref_hard(a, lab)
+
+    def fused(a):
+        return pf.softmax_xent(a, lab, soft, -100, 256, 512)[0]
+
+    np.testing.assert_allclose(np.asarray(fused(x)), np.asarray(ref(x)),
+                               rtol=1e-5, atol=1e-5)
+    g = jax.grad(lambda a: jnp.sum(fused(a)))(x)
+    gr = jax.grad(lambda a: jnp.sum(ref(a)))(x)
     np.testing.assert_allclose(np.asarray(g), np.asarray(gr),
                                rtol=1e-6, atol=1e-6)
 
@@ -167,9 +200,11 @@ def test_xent_softmax_output_path():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("shape", [(33, 7), (256, 128), (10,)])
+@pytest.mark.parametrize("shape", [(33, 7), (256, 128), (10,),
+                                   (1500, 256), (3, 40000)])
 def test_fused_adam_matches_formula(shape):
-    """Lane-aligned AND ragged shapes (the [1, n] single-row path)."""
+    """Lane-aligned AND ragged shapes (the [1, n] single-row path), and
+    two whose last row block / column block hangs over the edge."""
     rng = np.random.RandomState(6)
     p, g, m1, m2 = (jnp.asarray(rng.normal(size=shape).astype(np.float32))
                     for _ in range(4))

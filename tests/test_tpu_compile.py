@@ -1,0 +1,176 @@
+"""The Pallas kernels of the main path, compiled for the chip without the chip.
+
+The TPU's compiler is installed beside jax and compiles for a chip that is
+described, not attached (``topologies.get_topology_desc``): each case lowers
+one kernel with ``interpret=False`` at a shape the Transformer-base train
+step (``chip_smoke.py``: batch 64, length 256, 8 heads of 64, vocabulary
+30,000, bf16 AMP) or the decode engine really dispatches, and compiles it for
+one described v5e chip — or, for the tp-sharded lowering, a 2x2 mesh of them.
+What Mosaic refuses here it refuses on the chip: 64-bit index-map results
+under the package's x64 mode, blocks whose lane extent is neither
+128-aligned nor the array's, too much VMEM.  Interpret mode sees none of it.
+
+Nothing runs, so these say nothing about results (the interpret-mode tests
+do) or times (only a chip run does).  Skipped where the topology cannot be
+described.  jax's persistent compilation cache is off around them: an
+executable compiled for a described chip cannot be read back without one.
+"""
+
+import functools
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+# compile-only use of libtpu: no chip is held, so parallel test workers may
+# each load it (its lockfile otherwise lets one process in)
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+import paddle_tpu  # noqa: F401  (x64 mode on, as every user has it)
+from paddle_tpu.ops import pallas_flash, pallas_fused, pallas_paged
+
+B, H, T, D = 64, 8, 256, 64          # attention: [batch, heads, len, d_head]
+R, V = B * T, 30000                  # loss head: [batch*len, vocab]
+F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {exc}")
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+def _flash(causal, bias):
+    def fwd(q, k, v, *b):
+        return pallas_flash.flash_attention(
+            q, k, v, b[0] if b else None, None, causal, 256, 256, False)
+
+    qkv = [((B, H, T, D), BF16)] * 3
+    return fwd, qkv + ([((B, 1, 1, T), F32)] if bias else [])
+
+
+def _flash_bwd(causal, bias):
+    fwd, shapes = _flash(causal, bias)
+
+    def bwd(q, k, v, *b):
+        return jax.grad(lambda *a: fwd(*a, *b).astype(F32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    return bwd, shapes
+
+
+def _xent(soft):
+    def fwd(x, lab):
+        return pallas_fused.softmax_xent(x, lab, soft, -100, 256, 512,
+                                         False)
+
+    # the AMP step hands the kernel bf16 logits and fp32 smoothed labels;
+    # the hard-label cases keep fp32 logits (a run without AMP)
+    return fwd, ([((R, V), BF16), ((R, V), F32)] if soft
+                 else [((R, V), F32), ((R, 1), I32)])
+
+
+def _xent_bwd(soft):
+    fwd, shapes = _xent(soft)
+    return (lambda x, lab: jax.grad(lambda a: fwd(a, lab)[0].sum())(x),
+            shapes)
+
+
+def _sweep(kernel, n_arrays, shape):
+    def fn(*arrays_lr):
+        return pallas_fused._opt_sweep(kernel, list(arrays_lr[:-1]),
+                                       arrays_lr[-1], n_arrays - 1, False)
+
+    return fn, [(shape, F32)] * n_arrays + [((), F32)]
+
+
+_ADAM = functools.partial(pallas_fused._adam_kernel, b1=0.9, b2=0.98,
+                          eps=1e-9)
+_MOMENTUM = functools.partial(pallas_fused._momentum_kernel, mu=0.9,
+                              nesterov=False)
+
+
+def _paged():
+    # the engine phase of chip_smoke.py: 4 slots, max_len 32, page_size 4,
+    # decode_lm_config's d_model 16
+    s, d, ps, n = 4, 16, 4, 8
+
+    def fn(q, ck, cv, pt, bias):
+        return pallas_paged.paged_attention(q, ck, cv, pt, bias, 0.25,
+                                            False)
+
+    cache = ((s * n + 1, ps, d), F32)
+    return fn, [((s, 1, d), F32), cache, cache, ((s, n), I32),
+                ((s, 1, n * ps), F32)]
+
+
+#: name -> (builder, number of ``tpu_custom_call`` the compiled text holds)
+CASES = {
+    "flash_fwd_causal": (lambda: _flash(True, False), 1),     # decoder self
+    "flash_fwd_key_bias": (lambda: _flash(False, True), 1),   # encoder/cross
+    "flash_bwd_causal": (lambda: _flash_bwd(True, False), 3),
+    "flash_bwd_key_bias": (lambda: _flash_bwd(False, True), 3),
+    "xent_fwd_soft": (lambda: _xent(True), 1),    # label smoothing: the step's
+    "xent_bwd_soft": (lambda: _xent_bwd(True), 2),
+    "xent_fwd_hard": (lambda: _xent(False), 1),
+    "xent_bwd_hard": (lambda: _xent_bwd(False), 2),
+    "adam_largest_param": (lambda: _sweep(_ADAM, 4, (V, 512)), 1),
+    "adam_ragged_param": (lambda: _sweep(_ADAM, 4, (V,)), 1),  # out_proj bias
+    "momentum_largest_param": (lambda: _sweep(_MOMENTUM, 3, (V, 512)), 1),
+    "momentum_ragged_param": (lambda: _sweep(_MOMENTUM, 3, (V,)), 1),
+    "paged_step": (_paged, 1),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(topo, name):
+    build, n_calls = CASES[name]
+    fn, shapes = build()
+    chip = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= n_calls
+
+
+def test_sharded_xent_compiles_under_2x2_mesh(topo):
+    """The shard_map lowering of the fused loss head on the four-chip mesh
+    ``chip_smoke.py --chips 4`` builds: rows over dp, the vocabulary over
+    tp (15,000 a shard — ragged against the 512-wide block), and the
+    cross-shard logsumexp exchange the compiler has to place."""
+    import numpy as np
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "tp"))
+
+    def fn(x, lab):
+        def loss(a):
+            return pallas_fused.softmax_xent_sharded(
+                a, lab, mesh, True, -100, 256, 512, False)[0].sum()
+
+        return jax.value_and_grad(loss)(x)
+
+    spec = NamedSharding(mesh, P("dp", "tp"))
+    args = [jax.ShapeDtypeStruct((R, V), F32, sharding=spec)] * 2
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "all-reduce" in text

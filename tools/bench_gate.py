@@ -4,12 +4,11 @@ The BENCH trajectory (BENCH_r01.json, BENCH_r02.json, ...) records each
 round's headline throughputs; this tool diffs the two newest rounds and
 exits non-zero when any shared metric regressed by more than
 ``--threshold`` percent.  TIER-1 (ISSUE 11, ROADMAP item 2):
-``tests/test_bench_gate.py`` runs it as a blocking test — 30% at first
-(just above the committed r04→r05 -26.65% ResNet noise band), ratcheted
-to 20% once the fused-kernel layer landed (ISSUE 12) and the newest
-rounds stabilized inside the tighter band — so a flat-regression round
-fails a PR instead of landing silently.  Tighter thresholds remain
-available for pre-merge hooks and by-hand runs.  Every BENCH line since
+``tests/test_bench_gate.py`` runs it as a blocking test at 20%, so a
+flat-regression round fails a PR instead of landing silently; with
+fewer than two rounds in the directory (the committed tree today) it
+reports ``skipped`` and passes.  Tighter thresholds remain available
+for pre-merge hooks and by-hand runs.  Every BENCH line since
 ISSUE 12 also records the active kernel config (``flash``/``fused``), so
 a gate trip is attributable to the kernel change that caused it.
 

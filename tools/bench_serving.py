@@ -408,8 +408,10 @@ def run_router_bench(args) -> dict:
     # the shared compile store is what makes an R-replica fleet warm in
     # one compile's time; give the bench one even when the env has none
     if not os.environ.get("PADDLE_COMPILE_CACHE_DIR"):
+        from paddle_tpu import compile_cache
+
         os.environ["PADDLE_COMPILE_CACHE_DIR"] = \
-            tempfile.mkdtemp(prefix="bench_router_cache_")
+            compile_cache.checkout_root()
 
     models = [f"m{i}" for i in range(args.models)]
 

@@ -49,8 +49,10 @@ def main() -> dict:
     # the shared compile store is the POINT of the fleet's warm path:
     # replicas 2..N and every respawn must come up cache-hit-only
     if not os.environ.get("PADDLE_COMPILE_CACHE_DIR"):
+        from paddle_tpu import compile_cache
+
         os.environ["PADDLE_COMPILE_CACHE_DIR"] = \
-            tempfile.mkdtemp(prefix="router_smoke_cache_")
+            compile_cache.checkout_root()
 
     import numpy as np
 
