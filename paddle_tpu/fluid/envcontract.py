@@ -204,11 +204,12 @@ declare("PADDLE_OBSERVE_FLUSH_S", "float", 5.0, "observe",
 declare("PADDLE_OBSERVE_PORT", "int", None, "observe",
         "Serve /metrics + /healthz on 127.0.0.1:<port> (0 = ephemeral)")
 declare("PADDLE_TRACE", "bool", True, "observe",
-        "Span tracing master switch (0 disables all span emission; spans "
-        "only materialize when an observe dir is configured)")
+        "Spans reach the event log of a configured observe dir (0 keeps "
+        "them out of it; the in-memory ring and the profiler annotation "
+        "do not depend on it)")
 declare("PADDLE_TRACE_SAMPLE", "float", 1.0, "observe",
-        "Fraction of root spans recorded (deterministic every-Nth "
-        "sampling; children follow their root's decision)")
+        "Fraction of root spans written to the event log (deterministic "
+        "every-Nth sampling; children follow their root's decision)")
 declare("PADDLE_TRACEPARENT", "str", None, "observe",
         "Inherited trace context, W3C-style '00-<trace>-<span>-01' (the "
         "elastic supervisor sets it so worker spans join the run trace)")

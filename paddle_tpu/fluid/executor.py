@@ -735,32 +735,28 @@ class Executor:
                                       feed_per_step)
                 device = core.get_jax_device(self.place)
                 donate = self._donate_argnums(device, program)
-                # the trailing dict carries per-entry attribution state
-                # (compiled cost analysis, captured lazily under tracing)
-                entry = (plan, jax.jit(kfn, donate_argnums=donate), guard,
-                         {"cost": None})
+                entry = (plan, jax.jit(kfn, donate_argnums=donate), guard)
                 self._cache[key] = entry
             if program._params_grads is not None:
                 # host tracing/verification is compile-state wall-clock
                 # (the backend compile itself lands in the first dispatch,
                 # booked below)
                 _goodput.note("compile", _t.perf_counter() - t_trace0)
-        plan, fn, guard, entry_info = entry
+        plan, fn, guard = entry
 
-        import contextlib
         import time as _time
 
         from . import fault as _fault
         from . import profiler as _prof
         from ..observe import watchdog as _watchdog
 
-        with contextlib.ExitStack() as _tstack:
-            # the window span wraps the WHOLE dispatch cycle, so guardian
-            # trips / cache probes / slo breaches emitted inside it carry
-            # its span id; None (one dict lookup) when tracing is off
-            wspan = _tstack.enter_context(
-                _trace.span("executor.window", n_steps=n_steps,
-                            fresh=fresh_entry))
+        # the window span wraps the WHOLE dispatch cycle, so guardian
+        # trips / cache probes / slo breaches emitted inside it carry its
+        # span id; its children are stamped as they happen, and none of
+        # them waits for the device: the window's wait is the host copy
+        # of the fetches at its end
+        with _trace.span("executor.window", n_steps=n_steps,
+                         fresh=fresh_entry):
             t_host0 = _time.perf_counter()
             window_start = 0
             if program._params_grads is not None:
@@ -771,19 +767,20 @@ class Executor:
                 # aggregated health and apply policy BEFORE this window runs
                 g.on_boundary()
             t_stage0 = _time.perf_counter()
-            state_vals = self._gather_state(program, plan, scope)
-            mut_names = set(plan.state_out)
-            if plan.needs_rng:
-                mut_names.add(RNG_STATE_VAR)
-            if guard is not None and guard.scale_vars:
-                mut_names.update(guard.scale_vars)
-            mut_state = {k: v for k, v in state_vals.items()
-                         if k in mut_names}
-            const_state = {k: v for k, v in state_vals.items()
-                           if k not in mut_names}
-            device = core.get_jax_device(self.place)
-            feed_dev = {k: self._put_feed(k, v, device)
-                        for k, v in feed_arrays.items()}
+            with _trace.span("executor.stage"):
+                state_vals = self._gather_state(program, plan, scope)
+                mut_names = set(plan.state_out)
+                if plan.needs_rng:
+                    mut_names.add(RNG_STATE_VAR)
+                if guard is not None and guard.scale_vars:
+                    mut_names.update(guard.scale_vars)
+                mut_state = {k: v for k, v in state_vals.items()
+                             if k in mut_names}
+                const_state = {k: v for k, v in state_vals.items()
+                               if k not in mut_names}
+                device = core.get_jax_device(self.place)
+                feed_dev = {k: self._put_feed(k, v, device)
+                            for k, v in feed_arrays.items()}
             t_stage1 = _time.perf_counter()
             sentinel = None
             dump_state = None
@@ -805,103 +802,74 @@ class Executor:
                     dump_state = {k: (jnp.array(v, copy=True)
                                       if k in mut_names else v)
                                   for k, v in state_vals.items()}
-            if wspan is not None and entry_info.get("cost") is None:
-                # device-time + memory attribution (tracing only): the
-                # lowering costs one extra trace; reading memory_analysis
-                # additionally needs a compile, so the traced first window
-                # of an entry pays one extra backend compile (deduped by
-                # the persistent backend cache when enabled) — the price
-                # of the memory.peak_bytes truth gauge on this path
-                try:
-                    lowered = fn.lower(feed_dev, const_state, mut_state,
-                                       sentinel)
-                    entry_info["cost"] = _trace.cost_of(lowered) or False
-                    from ..observe import memory as _obsmem
-
-                    entry_info["memory"] = _obsmem.memory_stats(
-                        lowered.compile()) or False
-                    _obsmem.note_compiled_memory(
-                        entry_info["memory"] or None, kind="run_steps",
-                        n_steps=n_steps)
-                except Exception:
-                    entry_info.setdefault("cost", False)
-                    entry_info["memory"] = False
-
             agg = None
             t = _time.perf_counter()
-            if guard is not None:
-                fetches, new_state, agg = fn(feed_dev, const_state,
-                                             mut_state, sentinel)
-            else:
-                fetches, new_state = fn(feed_dev, const_state, mut_state,
-                                        None)
-            if wspan is not None or (_prof.is_profiling()
-                                     and guard is None):
-                # attribution needs the true device time; outside tracing/
-                # profiling the dispatch stays async as before
-                jax.block_until_ready((fetches, new_state))
+            # `compile`: the goodput ledger books a fresh entry's first
+            # dispatch (lazy jit: trace + compile on the host) as compile
+            with _trace.span("executor.dispatch", compile=fresh_entry):
+                if guard is not None:
+                    fetches, new_state, agg = fn(feed_dev, const_state,
+                                                 mut_state, sentinel)
+                else:
+                    fetches, new_state = fn(feed_dev, const_state,
+                                            mut_state, None)
+                if _prof.is_profiling() and guard is None:
+                    # fluid.profiler's timeline wants the device time; no
+                    # span, sink or PADDLE_TRACE setting ever waits here
+                    jax.block_until_ready((fetches, new_state))
             t_disp1 = _time.perf_counter()
-            if _prof.is_profiling():
-                _prof.record_event(
-                    f"executor_run[{len(plan.ops)}ops x{n_steps}steps]",
-                    t_disp1 - t, start=t)
-            # window visibility in the always-on counters (the smoke oracle
-            # counts dispatches; window_steps tracks amortization)
-            _prof.record_counter("executor.dispatches")
-            _prof.record_counter("executor.windows")
-            _prof.record_counter("executor.window_steps", inc=n_steps)
-            if probe is not None:
-                meta = {"kind": "run_steps", "n_steps": n_steps}
-                if isinstance(entry_info.get("memory"), dict):
-                    # per-executable memory table in the cache manifest:
-                    # a warm start re-reports it without re-lowering
-                    meta["memory"] = entry_info["memory"]
-                probe.finish(t_disp1 - t, program, meta=meta)
-            if _fault.active() is not None:
-                new_state = _fault.corrupt_state(new_state)
-            for name, val in new_state.items():
-                scope.set(name, val)
-            self._check_nan_inf(list(new_state.items())
-                                + list(zip(plan.fetch_names, fetches)))
-            if g is not None and agg is not None:
-                g.defer(guard, window_start, agg, {
-                    "program": program, "feeds": feed_arrays,
-                    "feed_lods": {}, "fetch_names": fetch_names,
-                    "state": dump_state, "sentinel": sentinel,
-                    "duration_s": t_disp1 - t,
-                    "window": {"start": window_start, "n_steps": n_steps,
-                               "feed_per_step": bool(feed_per_step)}})
-            if program._params_grads is not None:
-                from .. import observe
-                from ..observe import memory as _obsmem
+            with _trace.span("executor.observe"):
+                if _prof.is_profiling():
+                    _prof.record_event(
+                        f"executor_run[{len(plan.ops)}ops x{n_steps}steps]",
+                        t_disp1 - t, start=t)
+                # window visibility in the always-on counters (the smoke
+                # oracle counts dispatches; window_steps tracks
+                # amortization)
+                _prof.record_counter("executor.dispatches")
+                _prof.record_counter("executor.windows")
+                _prof.record_counter("executor.window_steps", inc=n_steps)
+                if probe is not None:
+                    probe.finish(t_disp1 - t, program,
+                                 meta={"kind": "run_steps",
+                                       "n_steps": n_steps})
+                if _fault.active() is not None:
+                    new_state = _fault.corrupt_state(new_state)
+                for name, val in new_state.items():
+                    scope.set(name, val)
+                self._check_nan_inf(list(new_state.items())
+                                    + list(zip(plan.fetch_names, fetches)))
+                if g is not None and agg is not None:
+                    g.defer(guard, window_start, agg, {
+                        "program": program, "feeds": feed_arrays,
+                        "feed_lods": {}, "fetch_names": fetch_names,
+                        "state": dump_state, "sentinel": sentinel,
+                        "duration_s": t_disp1 - t,
+                        "window": {"start": window_start,
+                                   "n_steps": n_steps,
+                                   "feed_per_step": bool(feed_per_step)}})
+                if program._params_grads is not None:
+                    from .. import observe
+                    from ..observe import memory as _obsmem
 
-                # events emitted after the window (checkpoint commits, cache
-                # probes) correlate to its LAST executed step, not its first
-                observe.note_step(window_start + n_steps - 1)
-                # live-buffer ledger: scope residency + watermark at the
-                # window boundary (gauges, high-water, watchdog feed)
-                _obsmem.note_scope_live(scope, scope_label="train",
-                                        step=window_start + n_steps - 1)
+                    # events emitted after the window (checkpoint commits,
+                    # cache probes) correlate to its LAST executed step,
+                    # not its first
+                    observe.note_step(window_start + n_steps - 1)
+                    # live-buffer ledger: scope residency + watermark at
+                    # the window boundary (gauges, high-water, watchdog
+                    # feed)
+                    _obsmem.note_scope_live(scope, scope_label="train",
+                                            step=window_start + n_steps - 1)
             t_obs1 = _time.perf_counter()
-            if wspan is not None:
-                # child spans: H2D staging / device dispatch / host observe
-                # tail — the step-time breakdown the trace view decomposes a
-                # window into (host_ms = everything not in the other three)
-                _trace.emit_span("executor.stage", t_stage0, t_stage1,
-                                 parent=wspan)
-                _trace.emit_span("executor.dispatch", t, t_disp1,
-                                 parent=wspan, compile=fresh_entry)
-                _trace.emit_span("executor.observe", t_disp1, t_obs1,
-                                 parent=wspan)
-                _trace.note_window_breakdown(
-                    host_ms=((t_stage0 - t_host0) + (t - t_stage1)) * 1e3,
-                    stage_ms=(t_stage1 - t_stage0) * 1e3,
-                    device_ms=(t_disp1 - t) * 1e3,
-                    observe_ms=(t_obs1 - t_disp1) * 1e3)
-                if entry_info.get("cost"):
-                    _trace.note_device_cost(entry_info["cost"],
-                                            t_disp1 - t, n_steps,
-                                            device=device)
+            # the host breakdown of this window (host_ms = everything not
+            # in the other three); dispatch_ms is the ENQUEUE, plus trace
+            # and compile on a fresh entry
+            _trace.note_window_breakdown(
+                host_ms=((t_stage0 - t_host0) + (t - t_stage1)) * 1e3,
+                stage_ms=(t_stage1 - t_stage0) * 1e3,
+                dispatch_ms=(t_disp1 - t) * 1e3,
+                observe_ms=(t_obs1 - t_disp1) * 1e3)
             if program._params_grads is not None:
                 # SLO watchdog: per-step time of this dispatch (no-op
                 # unless PADDLE_SLO is armed)
@@ -925,243 +893,292 @@ class Executor:
     def run(self, program=None, feed=None, fetch_list=None, feed_var_name="feed",
             fetch_var_name="fetch", scope=None, return_numpy=True,
             use_program_cache=True):
-        program = program or default_main_program()
-        feed = dict(feed or {})
-        fetch_list = fetch_list or []
-        scope = scope or global_scope()
+        from ..observe import trace as _trace
 
-        # host infeed: pop one batch per `read` op from its reader queue
-        # and make it this step's feed (ref: the C++ read op pulls from
-        # LoDTensorBlockingQueue inside the executor loop)
-        for op in program.global_block().ops:
-            if op.type != "read":
-                continue
-            from .layers import io as _io
-            from .lod_tensor import LoDTensor
+        # one root span per step, the same six children under
+        # ParallelExecutor.run: where the host time of a step goes
+        # (docs/OBSERVABILITY.md section 7).  None of them waits for the
+        # device; the one wait, the host copy that return_numpy asks for,
+        # is `fluid.run.fetch`, last.
+        with _trace.span("fluid.run", entry="executor") as root:
+            return self._run(root, program or default_main_program(),
+                             dict(feed or {}), fetch_list or [],
+                             scope or global_scope(), return_numpy,
+                             use_program_cache)
 
-            state = _io._reader_state(op.inputs["Reader"][0])
-            batch = state.next_batch()  # raises core.EOFException
-            for name, (arr, lod) in zip(op.outputs["Out"], batch):
-                feed[name] = LoDTensor(arr, lod) if lod else arr
-
-        fetch_names = [f.name if isinstance(f, Variable) else str(f)
-                       for f in fetch_list]
-        feed_arrays, feed_lods = {}, {}
-        for k, v in feed.items():
-            arr, lod = self._coerce_feed(program, k, v)
-            feed_arrays[k] = arr
-            if lod:
-                feed_lods[k] = lod
-
-        program = self._prune_for_unfed(program, feed_arrays, fetch_names,
-                                        scope)
-
-        # lods recorded on persistable state vars by earlier runs re-enter
-        # the trace as static metadata, exactly like feed lods
-        state_lods = {n: lod for n, lod in scope._lods.items()
-                      if lod and program.global_block()._has_var_recursive(n)}
-
-        from . import amp as _amp
-        from . import guardian as _guardian
-
-        # guarded training step: the numerics sentinel / dynamic loss
-        # scaler fold a health reduction + conditional state commit into
-        # the same jitted program (guardian.py module docstring)
-        guard = _guardian.for_program(program)
-
-        key = (program._cache_token, program._version, tuple(fetch_names),
-               tuple(sorted((k, tuple(v.shape), str(v.dtype))
-                            for k, v in feed_arrays.items())),
-               tuple(sorted(feed_lods.items())),
-               tuple(sorted(state_lods.items())),
-               self.place.device_type,
-               # execution-mode toggles invalidate compiled fns
-               _amp.compute_dtype(),
-               guard.cache_token() if guard is not None else None,
-               os.environ.get("PADDLE_TPU_FLASH", ""),
-               os.environ.get("PADDLE_TPU_FUSED", ""))
-        entry = self._cache.get(key) if use_program_cache else None
-        probe = None
-        fresh_run_entry = entry is None
-        if entry is None:
-            from .log import VLOG
-            from .. import analysis as _analysis
-            from .. import compile_cache as _cc
-
-            # pre-compile verifier (PADDLE_TPU_VERIFY=warn|strict|off):
-            # named diagnostics in milliseconds instead of an XLA trace
-            # error seconds into compile
-            _analysis.check_before_compile(
-                program, feed=feed_arrays, fetch_list=fetch_names,
-                kind="run")
-            # persistent-cache consult BEFORE tracing (hit/miss counters +
-            # backend warm start through the shared jax disk cache)
-            probe = _cc.executor_probe(
-                program, feed_arrays, fetch_names,
-                extra={"kind": "run",
-                       "feed_lods": tuple(sorted(feed_lods.items())),
-                       "state_lods": tuple(sorted(state_lods.items())),
-                       "platform": self.place.device_type,
-                       "amp": _amp.compute_dtype(),
-                       "guard": (guard.cache_token()
-                                 if guard is not None else None),
-                       "flash": os.environ.get("PADDLE_TPU_FLASH", ""),
-                           "fused": os.environ.get("PADDLE_TPU_FUSED", "")})
-            VLOG(1, f"Executor: compiling block "
-                    f"({len(program.global_block().ops)} ops, "
-                    f"fetches={fetch_names})")
-            plan_fetches = list(fetch_names)
-            if guard is not None:
-                plan_fetches += guard.extra_fetch_names()
-            plan = BlockPlan(program, 0, list(feed_arrays), plan_fetches)
-            if guard is not None and plan.needs_eager:
-                if guard.scale_vars is not None:
-                    raise RuntimeError(
-                        "dynamic fp16 loss scaling is not supported for "
-                        "programs with data-dependent eager ops")
-                warnings.warn(
-                    "guardian: program contains data-dependent eager ops; "
-                    "the numerics sentinel is disabled for it")
-                guard = None
-                plan = BlockPlan(program, 0, list(feed_arrays), fetch_names)
-            if guard is not None and guard.scale_vars:
-                # the good-steps counter is read/written only by the
-                # guarded wrapper (no IR op touches it), so liveness never
-                # saw it — gather it with the rest of the state
-                for n in guard.scale_vars:
-                    if n not in plan.state_in:
-                        plan.state_in.append(n)
-            lod_box = {}
-            all_lods = dict(state_lods)
-            all_lods.update(feed_lods)
-            fn = self._build(program, plan, all_lods, lod_box,
-                             guard=guard, n_user=len(fetch_names))
-            entry = (plan, fn, lod_box, guard)
-            if use_program_cache:
-                self._cache[key] = entry
-        plan, fn, lod_box, guard = entry
-
-        from . import fault as _fault
-
-        step_idx = 0
-        if program._params_grads is not None:
-            # training-step boundary (programs built via optimizer.minimize;
-            # hook points for fault injection + elastic liveness)
-            step_idx = self._step_boundary(_fault)
-        g = _guardian.current() if guard is not None else None
-        if g is not None:
-            # one-step-lag sentinel: observe the PREVIOUS step's health
-            # (its dispatch has retired — materializing two scalars is
-            # free) and apply policy BEFORE this step runs
-            g.on_boundary()
-        state_vals = self._gather_state(program, plan, scope)
-        device = core.get_jax_device(self.place)
-        feed_dev = {k: self._put_feed(k, v, device)
-                    for k, v in feed_arrays.items()}
-
-        # only vars that get rewritten are donated; read-only state (lr,
-        # params in eval programs) must keep its buffers alive in the scope
-        mut_names = set(plan.state_out)
-        if plan.needs_rng:
-            mut_names.add(RNG_STATE_VAR)
-        mut_state = {k: v for k, v in state_vals.items() if k in mut_names}
-        const_state = {k: v for k, v in state_vals.items()
-                       if k not in mut_names}
-        sentinel = None
-        dump_state = None
-        if guard is not None:
-            seed_mul, loss_mul = _fault.sentinel_injection(step_idx)
-            sentinel = {
-                "loss_cap": np.float32(g.loss_cap() if g is not None
-                                       else float("inf")),
-                "seed_mul": np.float32(seed_mul),
-                "loss_mul": np.float32(loss_mul),
-            }
-            dump_state = state_vals
-            if g is not None and g.config.policy == "dump_and_halt" \
-                    and self._donate_argnums(device, program):
-                # donation invalidates mutated input buffers after the
-                # dispatch; dump mode keeps pre-step device copies alive
-                dump_state = {k: (jnp.array(v, copy=True) if k in mut_names
-                                  else v)
-                              for k, v in state_vals.items()}
-        from . import profiler as _prof
-
-        health = None
+    def _run(self, root, program, feed, fetch_list, scope, return_numpy,
+             use_program_cache):
         import time as _time
 
+        from . import amp as _amp
+        from . import fault as _fault
+        from . import guardian as _guardian
+        from . import profiler as _prof
+        from ..observe import trace as _trace
+
+        with _trace.span("fluid.run.feed"):
+            # host infeed: pop one batch per `read` op from its reader
+            # queue and make it this step's feed (ref: the C++ read op
+            # pulls from LoDTensorBlockingQueue inside the executor loop)
+            for op in program.global_block().ops:
+                if op.type != "read":
+                    continue
+                from .layers import io as _io
+                from .lod_tensor import LoDTensor
+
+                state = _io._reader_state(op.inputs["Reader"][0])
+                batch = state.next_batch()  # raises core.EOFException
+                for name, (arr, lod) in zip(op.outputs["Out"], batch):
+                    feed[name] = LoDTensor(arr, lod) if lod else arr
+
+            fetch_names = [f.name if isinstance(f, Variable) else str(f)
+                           for f in fetch_list]
+            feed_arrays, feed_lods = {}, {}
+            for k, v in feed.items():
+                arr, lod = self._coerce_feed(program, k, v)
+                feed_arrays[k] = arr
+                if lod:
+                    feed_lods[k] = lod
+            device = core.get_jax_device(self.place)
+            feed_dev = {k: self._put_feed(k, v, device)
+                        for k, v in feed_arrays.items()}
+
+        with _trace.span("fluid.run.lookup"):
+            program = self._prune_for_unfed(program, feed_arrays,
+                                            fetch_names, scope)
+
+            # lods recorded on persistable state vars by earlier runs
+            # re-enter the trace as static metadata, exactly like feed lods
+            state_lods = {n: lod for n, lod in scope._lods.items()
+                          if lod
+                          and program.global_block()._has_var_recursive(n)}
+
+            # guarded training step: the numerics sentinel / dynamic loss
+            # scaler fold a health reduction + conditional state commit
+            # into the same jitted program (guardian.py module docstring)
+            guard = _guardian.for_program(program)
+
+            key = (program._cache_token, program._version,
+                   tuple(fetch_names),
+                   tuple(sorted((k, tuple(v.shape), str(v.dtype))
+                                for k, v in feed_arrays.items())),
+                   tuple(sorted(feed_lods.items())),
+                   tuple(sorted(state_lods.items())),
+                   self.place.device_type,
+                   # execution-mode toggles invalidate compiled fns
+                   _amp.compute_dtype(),
+                   guard.cache_token() if guard is not None else None,
+                   os.environ.get("PADDLE_TPU_FLASH", ""),
+                   os.environ.get("PADDLE_TPU_FUSED", ""))
+            entry = self._cache.get(key) if use_program_cache else None
+            probe = None
+            fresh = entry is None
+            root.set(fresh=fresh)
+            if fresh:
+                with _trace.span("fluid.run.build"):
+                    entry, probe = self._build_entry(
+                        program, feed_arrays, feed_lods, state_lods,
+                        fetch_names, guard)
+                if use_program_cache:
+                    self._cache[key] = entry
+            plan, fn, lod_box, guard = entry
+
+        with _trace.span("fluid.run.state"):
+            step_idx = 0
+            if program._params_grads is not None:
+                # training-step boundary (programs built via
+                # optimizer.minimize; hook points for fault injection +
+                # elastic liveness)
+                step_idx = self._step_boundary(_fault)
+            root.set(step=step_idx)
+            g = _guardian.current() if guard is not None else None
+            if g is not None:
+                # one-step-lag sentinel: observe the PREVIOUS step's health
+                # (its dispatch has retired, materializing two scalars is
+                # free) and apply policy BEFORE this step runs
+                g.on_boundary()
+            state_vals = self._gather_state(program, plan, scope)
+
+            # only vars that get rewritten are donated; read-only state
+            # (lr, params in eval programs) must keep its buffers alive in
+            # the scope
+            mut_names = set(plan.state_out)
+            if plan.needs_rng:
+                mut_names.add(RNG_STATE_VAR)
+            mut_state = {k: v for k, v in state_vals.items()
+                         if k in mut_names}
+            const_state = {k: v for k, v in state_vals.items()
+                           if k not in mut_names}
+            sentinel = None
+            dump_state = None
+            if guard is not None:
+                seed_mul, loss_mul = _fault.sentinel_injection(step_idx)
+                sentinel = {
+                    "loss_cap": np.float32(g.loss_cap() if g is not None
+                                           else float("inf")),
+                    "seed_mul": np.float32(seed_mul),
+                    "loss_mul": np.float32(loss_mul),
+                }
+                dump_state = state_vals
+                if g is not None and g.config.policy == "dump_and_halt" \
+                        and self._donate_argnums(device, program):
+                    # donation invalidates mutated input buffers after the
+                    # dispatch; dump mode keeps pre-step device copies
+                    # alive
+                    dump_state = {k: (jnp.array(v, copy=True)
+                                      if k in mut_names else v)
+                                  for k, v in state_vals.items()}
+
+        health = None
         t = _time.perf_counter()
-        if guard is not None:
-            fetches, new_state, health = fn(feed_dev, const_state,
-                                            mut_state, sentinel)
-        elif _prof.is_profiling():
-            fetches, new_state = fn(feed_dev, const_state, mut_state)
-            jax.block_until_ready(fetches)
-        else:
-            fetches, new_state = fn(feed_dev, const_state, mut_state)
-        if _prof.is_profiling():
-            _prof.record_event(
-                f"executor_run[{len(plan.ops)}ops]",
-                _time.perf_counter() - t, start=t)
-        _prof.record_counter("executor.dispatches")
-        if probe is not None:
-            # first dispatch of a fresh entry = trace + compile; commit the
-            # artifact (miss) / freshen it (hit) now that it exists
-            probe.finish(_time.perf_counter() - t, program,
-                         meta={"kind": "run",
-                               "ops": len(plan.ops),
-                               "fetches": len(plan.fetch_names)})
-        if _fault.active() is not None:
-            new_state = _fault.corrupt_state(new_state)
-        for name, val in new_state.items():
-            scope.set(name, val)
-            if name in lod_box:
-                scope._lods[name] = lod_box[name]
-        self._check_nan_inf(list(new_state.items())
-                            + list(zip(plan.fetch_names, fetches)))
-        if g is not None and health is not None:
-            g.defer(guard, step_idx, health, {
-                "program": program, "feeds": feed_arrays,
-                "feed_lods": feed_lods, "fetch_names": fetch_names,
-                "state": dump_state, "sentinel": sentinel,
-                "duration_s": _time.perf_counter() - t})
-        if program._params_grads is not None:
-            from ..observe import memory as _obsmem
-            from ..observe import watchdog as _watchdog
+        with _trace.span("fluid.run.call"):
+            if guard is not None:
+                fetches, new_state, health = fn(feed_dev, const_state,
+                                                mut_state, sentinel)
+            else:
+                fetches, new_state = fn(feed_dev, const_state, mut_state)
+                if _prof.is_profiling():
+                    # fluid.profiler's timeline wants the device time; no
+                    # span, sink or PADDLE_TRACE setting ever waits here
+                    jax.block_until_ready(fetches)
+        call_s = _time.perf_counter() - t
 
-            # SLO watchdog on the per-step training path (no-op unless
-            # PADDLE_SLO is armed); async dispatch means this measures
-            # submit-to-submit pacing, which is what regresses under load
-            _watchdog.observe_value("executor.step_time_s",
-                                    _time.perf_counter() - t, step=step_idx)
-            # ledger gauges only (quiet): per-step watermark EVENTS would
-            # flood the stream — windows own the event cadence
-            _obsmem.note_scope_live(scope, scope_label="train",
-                                    step=step_idx, emit_event=False)
-            from ..observe import goodput as _goodput
+        with _trace.span("fluid.run.commit"):
+            if _fault.active() is not None:
+                new_state = _fault.corrupt_state(new_state)
+            for name, val in new_state.items():
+                scope.set(name, val)
+                if name in lod_box:
+                    scope._lods[name] = lod_box[name]
+            # the arrays the scope just let go of (donated, or replaced)
+            # die with their last reference: here, in the span that
+            # replaced them, not when this frame ends (0.9 ms a step for
+            # the Transformer's 190 on the v5e's host)
+            del state_vals, mut_state, const_state
+            out = fetches
+            if not return_numpy:
+                from .lod_tensor import LoDTensor
 
-            # per-step training dispatch: a fresh entry's first dispatch
-            # is compile cost (lazy jit), everything after device compute
-            _goodput.note("compile" if fresh_run_entry else "device",
-                          _time.perf_counter() - t)
+                # keep fetches device-resident: conversion happens lazily
+                # on first numpy access, so a training loop that only
+                # inspects the loss occasionally is not throttled by one
+                # D2H sync per step.  A fetch that is ALSO a mutated state
+                # var aliases a buffer the next run will donate: copy
+                # those on device so the returned handle survives
+                # (donation would otherwise delete it under the caller).
+                donated = set(plan.state_out) | (
+                    {RNG_STATE_VAR} if plan.needs_rng else set())
+                out = []
+                for n, v in zip(plan.fetch_names, fetches):
+                    if n in donated and isinstance(v, jax.Array):
+                        v = jnp.array(v, copy=True)
+                    out.append(LoDTensor(v, lod_box.get(n)))
+
+        with _trace.span("fluid.run.observe"):
+            if _prof.is_profiling():
+                _prof.record_event(f"executor_run[{len(plan.ops)}ops]",
+                                   call_s, start=t)
+            _prof.record_counter("executor.dispatches")
+            if probe is not None:
+                # first dispatch of a fresh entry = trace + compile; commit
+                # the artifact (miss) / freshen it (hit) now that it exists
+                probe.finish(call_s, program,
+                             meta={"kind": "run",
+                                   "ops": len(plan.ops),
+                                   "fetches": len(plan.fetch_names)})
+            self._check_nan_inf(list(new_state.items())
+                                + list(zip(plan.fetch_names, fetches)))
+            if g is not None and health is not None:
+                g.defer(guard, step_idx, health, {
+                    "program": program, "feeds": feed_arrays,
+                    "feed_lods": feed_lods, "fetch_names": fetch_names,
+                    "state": dump_state, "sentinel": sentinel,
+                    "duration_s": _time.perf_counter() - t})
+            if program._params_grads is not None:
+                from ..observe import goodput as _goodput
+                from ..observe import memory as _obsmem
+                from ..observe import watchdog as _watchdog
+
+                # SLO watchdog on the per-step training path (no-op unless
+                # PADDLE_SLO is armed); async dispatch means this measures
+                # submit-to-submit pacing, which is what regresses under
+                # load
+                _watchdog.observe_value("executor.step_time_s",
+                                        _time.perf_counter() - t,
+                                        step=step_idx)
+                # ledger gauges only (quiet): per-step watermark EVENTS
+                # would flood the stream, windows own the event cadence
+                _obsmem.note_scope_live(scope, scope_label="train",
+                                        step=step_idx, emit_event=False)
+                # per-step training dispatch: a fresh entry's first
+                # dispatch is compile cost (lazy jit), everything after
+                # device compute
+                _goodput.note("compile" if fresh else "device",
+                              _time.perf_counter() - t)
+
         if return_numpy:
-            return [np.asarray(v) for v in fetches]
-        from .lod_tensor import LoDTensor
-
-        # keep fetches device-resident: conversion happens lazily on first
-        # numpy access, so a training loop that only inspects the loss
-        # occasionally is not throttled by one D2H sync per step.  A fetch
-        # that is ALSO a mutated state var aliases a buffer the next run
-        # will donate — copy those on device so the returned handle survives
-        # (donation would otherwise delete it under the caller).
-        donated = set(plan.state_out) | ({RNG_STATE_VAR} if plan.needs_rng
-                                         else set())
-        out = []
-        for n, v in zip(plan.fetch_names, fetches):
-            if n in donated and isinstance(v, jax.Array):
-                v = jnp.array(v, copy=True)
-            out.append(LoDTensor(v, lod_box.get(n)))
+            with _trace.span("fluid.run.fetch"):
+                out = [np.asarray(v) for v in fetches]
         return out
+
+    def _build_entry(self, program, feed_arrays, feed_lods, state_lods,
+                     fetch_names, guard):
+        """The executor's cache missed: verify, consult the persistent
+        compile cache, plan the block and build the (lazily compiled) jit.
+        Returns the cache entry and the compile-cache probe."""
+        from . import amp as _amp
+        from .log import VLOG
+        from .. import analysis as _analysis
+        from .. import compile_cache as _cc
+
+        # pre-compile verifier (PADDLE_TPU_VERIFY=warn|strict|off): named
+        # diagnostics in milliseconds instead of an XLA trace error seconds
+        # into compile
+        _analysis.check_before_compile(
+            program, feed=feed_arrays, fetch_list=fetch_names, kind="run")
+        # persistent-cache consult BEFORE tracing (hit/miss counters +
+        # backend warm start through the shared jax disk cache)
+        probe = _cc.executor_probe(
+            program, feed_arrays, fetch_names,
+            extra={"kind": "run",
+                   "feed_lods": tuple(sorted(feed_lods.items())),
+                   "state_lods": tuple(sorted(state_lods.items())),
+                   "platform": self.place.device_type,
+                   "amp": _amp.compute_dtype(),
+                   "guard": (guard.cache_token()
+                             if guard is not None else None),
+                   "flash": os.environ.get("PADDLE_TPU_FLASH", ""),
+                   "fused": os.environ.get("PADDLE_TPU_FUSED", "")})
+        VLOG(1, f"Executor: compiling block "
+                f"({len(program.global_block().ops)} ops, "
+                f"fetches={fetch_names})")
+        plan_fetches = list(fetch_names)
+        if guard is not None:
+            plan_fetches += guard.extra_fetch_names()
+        plan = BlockPlan(program, 0, list(feed_arrays), plan_fetches)
+        if guard is not None and plan.needs_eager:
+            if guard.scale_vars is not None:
+                raise RuntimeError(
+                    "dynamic fp16 loss scaling is not supported for "
+                    "programs with data-dependent eager ops")
+            warnings.warn(
+                "guardian: program contains data-dependent eager ops; "
+                "the numerics sentinel is disabled for it")
+            guard = None
+            plan = BlockPlan(program, 0, list(feed_arrays), fetch_names)
+        if guard is not None and guard.scale_vars:
+            # the good-steps counter is read/written only by the guarded
+            # wrapper (no IR op touches it), so liveness never saw it:
+            # gather it with the rest of the state
+            for n in guard.scale_vars:
+                if n not in plan.state_in:
+                    plan.state_in.append(n)
+        lod_box = {}
+        all_lods = dict(state_lods)
+        all_lods.update(feed_lods)
+        fn = self._build(program, plan, all_lods, lod_box,
+                         guard=guard, n_user=len(fetch_names))
+        return (plan, fn, lod_box, guard), probe
 
     def lower_step(self, program, feed, fetch_list, scope=None):
         """AOT-lower the SAME traced step ``Executor.run`` would jit for

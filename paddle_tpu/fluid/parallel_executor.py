@@ -132,78 +132,115 @@ class ParallelExecutor:
         return mesh_label(self._mesh)
 
     def run(self, fetch_list, feed=None, feed_dict=None, return_numpy=True):
-        feed = feed if feed is not None else feed_dict
-        if isinstance(feed, list):
-            # per-device feed dicts: concatenate along batch
-            merged: Dict[str, np.ndarray] = {}
-            for d in feed:
-                for k, v in d.items():
-                    merged.setdefault(k, []).append(np.asarray(v))
-            feed = {k: np.concatenate(v, 0) for k, v in merged.items()}
-        feed = feed or {}
+        from ..observe import trace as _trace
+
+        # the same root and children as Executor.run, so that one reader
+        # serves one chip and four (docs/OBSERVABILITY.md section 7)
+        with _trace.span("fluid.run", entry="parallel_executor") as root:
+            return self._run(root, fetch_list,
+                             feed if feed is not None else feed_dict,
+                             return_numpy)
+
+    def _run(self, root, fetch_list, feed, return_numpy):
+        from . import amp as _amp
+        from ..observe import trace as _trace
+
         fetch_names = [f.name if isinstance(f, Variable) else str(f)
                        for f in fetch_list]
-        # normalize dtypes BEFORE the cache key so float64-from-list feeds
-        # don't compile a duplicate executable
-        gb_ = self._program.global_block()
-        feed_arrays = {}
-        for k, v in feed.items():
-            arr = np.asarray(v)
-            if gb_._has_var_recursive(k):
-                want = core.np_dtype(gb_._var_recursive(k).dtype)
-                if arr.dtype != want:
-                    arr = arr.astype(want)
-            feed_arrays[k] = arr
+        with _trace.span("fluid.run.feed"):
+            if isinstance(feed, list):
+                # per-device feed dicts: concatenate along batch
+                merged: Dict[str, np.ndarray] = {}
+                for d in feed:
+                    for k, v in d.items():
+                        merged.setdefault(k, []).append(np.asarray(v))
+                feed = {k: np.concatenate(v, 0) for k, v in merged.items()}
+            feed = feed or {}
+            # normalize dtypes BEFORE the cache key so float64-from-list
+            # feeds don't compile a duplicate executable
+            gb_ = self._program.global_block()
+            feed_arrays = {}
+            for k, v in feed.items():
+                arr = np.asarray(v)
+                if gb_._has_var_recursive(k):
+                    want = core.np_dtype(gb_._var_recursive(k).dtype)
+                    if arr.dtype != want:
+                        arr = arr.astype(want)
+                feed_arrays[k] = arr
 
-        from . import amp as _amp
+        with _trace.span("fluid.run.lookup"):
+            key = (id(self._program), self._program._version,
+                   tuple(fetch_names),
+                   tuple(sorted((k, tuple(v.shape), str(v.dtype))
+                                for k, v in feed_arrays.items())),
+                   # execution-mode toggles invalidate compiled steps (same
+                   # contract as Executor.run's cache key)
+                   _amp.compute_dtype(),
+                   os.environ.get("PADDLE_TPU_FLASH", ""),
+                   os.environ.get("PADDLE_TPU_FUSED", ""))
+            step = self._cache.get(key)
+            root.set(fresh=step is None)
+            if step is None:
+                with _trace.span("fluid.run.build"):
+                    step = self._build_step(feed_arrays, fetch_names)
+                self._cache[key] = step
 
-        key = (id(self._program), self._program._version, tuple(fetch_names),
-               tuple(sorted((k, tuple(v.shape), str(v.dtype))
-                            for k, v in feed_arrays.items())),
-               # execution-mode toggles invalidate compiled steps (same
-               # contract as Executor.run's cache key)
-               _amp.compute_dtype(),
-               os.environ.get("PADDLE_TPU_FLASH", ""),
-               os.environ.get("PADDLE_TPU_FUSED", ""))
-        step = self._cache.get(key)
-        if step is None:
-            from .. import analysis as _analysis
+        # the feed's second half: its sharded placement needs the step,
+        # so it follows the lookup under the same name (a reader sums the
+        # two)
+        with _trace.span("fluid.run.feed"):
+            feed_dev = step.place_feed(feed_arrays)
 
-            # pre-compile verifier: turns the runtime rejects below (and
-            # the opaque GSPMD sharding errors) into named diagnostics
-            _analysis.check_before_compile(
-                self._program, feed=feed_arrays, fetch_list=fetch_names,
-                mesh=self._mesh, kind="pe_run")
-            if getattr(self._program, "_loss_scale_vars", None) is not None:
-                # the per-step sharded path has no guarded wrapper: the
-                # backward seed would go unscaled while append_unscale_ops
-                # still divides grads by the scale — silently wrong math
-                raise RuntimeError(
-                    "dynamic fp16 loss scaling requires the windowed "
-                    "sharded path: use ParallelExecutor.run_steps")
-            zero1 = (self._build_strategy.reduce_strategy ==
-                     BuildStrategy.ReduceStrategy.Reduce)
-            step = ShardedTrainStep(
-                self._program, list(feed_arrays), fetch_names, self._mesh,
-                zero1=zero1, multihost=self._multihost)
-            self._cache[key] = step
+        with _trace.span("fluid.run.state"):
+            self._check_initialized(step.plan)
+            state_vals = step.place_state(self._scope)
 
-        self._check_initialized(step.plan)
-        feed_dev = step.place_feed(feed_arrays)
-        state_vals = step.place_state(self._scope)
+        with _trace.span("fluid.run.call"):
+            fetches, new_state = step(feed_dev, state_vals)
 
-        fetches, new_state = step(feed_dev, state_vals)
-        for name, val in new_state.items():
-            self._scope.set(name, val)
-        if self._program._params_grads is not None:
-            from ..observe import memory as _obsmem
+        with _trace.span("fluid.run.commit"):
+            for name, val in new_state.items():
+                self._scope.set(name, val)
+            # the arrays the scope just let go of die with their last
+            # reference: here, not when this frame ends
+            del state_vals, feed_dev
 
-            # ledger gauges only — per-step events would flood the stream
-            _obsmem.note_scope_live(self._scope, scope_label="train",
-                                    mesh=self.mesh_label, emit_event=False)
+        with _trace.span("fluid.run.observe"):
+            if self._program._params_grads is not None:
+                from ..observe import memory as _obsmem
+
+                # ledger gauges only: per-step events would flood the
+                # stream
+                _obsmem.note_scope_live(self._scope, scope_label="train",
+                                        mesh=self.mesh_label,
+                                        emit_event=False)
         if return_numpy:
-            return [step.fetch_to_host(v) for v in fetches]
+            with _trace.span("fluid.run.fetch"):
+                return [step.fetch_to_host(v) for v in fetches]
         return list(fetches)
+
+    def _build_step(self, feed_arrays, fetch_names):
+        """The step cache missed: verify, then plan and jit the program
+        over the mesh (compiled lazily, by its first call)."""
+        from .. import analysis as _analysis
+
+        # pre-compile verifier: turns the runtime rejects below (and the
+        # opaque GSPMD sharding errors) into named diagnostics
+        _analysis.check_before_compile(
+            self._program, feed=feed_arrays, fetch_list=fetch_names,
+            mesh=self._mesh, kind="pe_run")
+        if getattr(self._program, "_loss_scale_vars", None) is not None:
+            # the per-step sharded path has no guarded wrapper: the
+            # backward seed would go unscaled while append_unscale_ops
+            # still divides grads by the scale: silently wrong math
+            raise RuntimeError(
+                "dynamic fp16 loss scaling requires the windowed "
+                "sharded path: use ParallelExecutor.run_steps")
+        zero1 = (self._build_strategy.reduce_strategy ==
+                 BuildStrategy.ReduceStrategy.Reduce)
+        return ShardedTrainStep(
+            self._program, list(feed_arrays), fetch_names, self._mesh,
+            zero1=zero1, multihost=self._multihost)
 
     def run_steps(self, fetch_list, feed=None, n_steps=1,
                   feed_per_step=False, return_numpy=True):

@@ -49,8 +49,8 @@ __all__ = [
     "note_mesh", "note_commit_step", "current_step", "current_program",
     "current_mesh", "current_commit_step",
     "http_server", "ENV_DIR", "ENV_FLUSH", "ENV_PORT",
-    # submodules re-exported for discoverability: observe.trace (span
-    # tracer + device-time attribution), observe.watchdog (SLO breaches),
+    # submodules re-exported for discoverability: observe.trace (the span
+    # primitive + the ring), observe.watchdog (SLO breaches),
     # observe.memory (HBM accounting + live-buffer ledger),
     # observe.goodput (wall-clock state accounting + straggler ledger)
     "trace", "watchdog", "memory", "goodput",
@@ -311,29 +311,10 @@ def emit(event: str, **fields) -> Optional[dict]:
         return None
 
 
-class _NullSpan:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-def span(event: str, **fields):
-    """Timed-region context manager (emits ``dur_s``); no-op without a
-    sink.  For PARENTED spans with trace identity use
-    :func:`paddle_tpu.observe.trace.span` — this one predates the tracer
-    and stays for plain flat timings."""
-    try:
-        sink = get_sink()
-        if sink is None:
-            return _NullSpan()
-        return sink.events.span(event, **fields)
-    except Exception:
-        return _NullSpan()
-
-
 # submodules imported last (they only import observe lazily, so there is
 # no cycle): observe.trace / observe.watchdog / observe.memory /
 # observe.goodput are part of the public API
 from . import goodput, memory, trace, watchdog  # noqa: E402,F401  (re-export)
+
+#: the one span primitive, under its short name
+span = trace.span

@@ -18,12 +18,11 @@ shared-file interleaving to get wrong.
 
 Schema contract (docs/OBSERVABILITY.md): ``ts`` (unix seconds), ``event``
 (dot-separated kind), the stamp fields above, then free-form JSON fields.
-``dur_s`` marks a span (emitted at close by :meth:`EventLog.span`).
+``dur_s`` marks a span (emitted when an ``observe.trace`` span ends).
 """
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import socket
@@ -88,10 +87,11 @@ class EventLog:
             from . import trace as _trace
 
             sp = _trace.current()
-            if sp is not None:
+            if sp is not None and sp.logged:
                 # trace stamp: any record emitted inside an open span
-                # (guardian trips, cache probes, slo breaches) joins the
-                # span tree.  Span records override via `fields` below.
+                # that is itself in this log (guardian trips, cache
+                # probes, slo breaches) joins the span tree.  Span
+                # records override via `fields` below.
                 rec["trace_id"] = sp.trace_id
                 rec["span_id"] = sp.span_id
         except Exception:
@@ -106,16 +106,6 @@ class EventLog:
         except (OSError, ValueError):
             pass
         return rec
-
-    @contextlib.contextmanager
-    def span(self, event: str, **fields):
-        """Timed region: emits one record with ``dur_s`` when it closes."""
-        t = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.emit(event, dur_s=round(time.perf_counter() - t, 6),
-                      **fields)
 
 
 def read_events(path: str) -> List[dict]:

@@ -7,8 +7,9 @@ pre-flight warning; now three tiers cover it:
 
  1. **Compiled truth** (:func:`memory_stats` / :func:`note_compiled_memory`)
     — ``compiled.memory_analysis()`` read at the existing AOT-lower points
-    (the PR 7 ``ShardedWindowRunner``, the PR 9 traced single-device
-    lowering, ``ServingEngine.warmup()``) into always-on gauges
+    (the PR 7 ``ShardedWindowRunner``, ``ServingEngine.warmup()``; for a
+    single-device program ``Executor.compiled_memory_stats`` when the
+    caller asks) into always-on gauges
     ``memory.peak_bytes{mesh=...}`` / ``memory.argument_bytes`` /
     ``memory.output_bytes`` / ``memory.temp_bytes`` /
     ``memory.generated_code_bytes`` plus one ``memory.profile`` run event
@@ -43,10 +44,11 @@ sampled by the profiler session (``registry.start_sampling``), so both
 HBM residency alongside the span timeline.
 
 Costs: reading ``memory_analysis()`` needs a *compiled* executable.  The
-sharded window runner already AOT-compiles (free); the traced
-single-device window pays one extra backend compile the first time a
-window entry is lowered under tracing (the persistent backend cache
-dedupes it when enabled); warmup is the precompile path by definition.
+sharded window runner already AOT-compiles (free); a single-device
+window is never lowered a second time for it (a span never lowers:
+``Executor.compiled_memory_stats`` costs one backend compile, which the
+persistent backend cache dedupes, and is the caller's decision); warmup
+is the precompile path by definition.
 The ledger is a sum of ``nbytes`` over scope entries per window — host
 arithmetic, no device sync.
 """

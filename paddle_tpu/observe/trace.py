@@ -1,71 +1,87 @@
-"""Distributed span tracer + device-time attribution (ISSUE 9 tentpole).
+"""The one span primitive: host regions on the profiler's clock.
 
-PR 5's run-event stream answers *what happened*; this module answers
-*where the time went*.  A span is one timed region with W3C-style
-identity — a 32-hex ``trace_id`` shared by everything in one logical
-run/request and a 16-hex ``span_id`` per region, with ``parent_span``
-links forming the tree — emitted into the SAME per-process run-event
-JSONL the fleet aggregator already merges, so one ``chrome://tracing``
-export shows supervisor generations, executor windows, prefetch staging
-on its worker thread, and per-request serving breakdowns as nested
-duration events.
+A span is one timed host region with W3C-style identity: a 32-hex
+``trace_id`` shared by everything in one logical run/request and a 16-hex
+``span_id`` per region, with ``parent_span`` links forming the tree.
+Every span does three things, always:
 
-API surface (all no-ops returning ``None`` when tracing is off):
+ - it enters a ``jax.profiler.TraceAnnotation`` of the same name, so it
+   lands in the host plane of whatever profiler session is open, beside
+   the device operations and on their clock (outside a session this is a
+   flag test);
+ - when it ends, ``(name, t0, t1, span_id, parent_id, tid, step)`` is
+   appended to ONE bounded in-memory ring (:data:`RING_SPANS` entries,
+   ``time.perf_counter`` stamps); :func:`recorded` reads it,
+   ``observe.reset()`` clears it.  Spans are kept in memory and read when
+   the run ends;
+ - it is written to the run-event JSONL (the per-process stream the fleet
+   aggregator merges, so one ``chrome://tracing`` export shows supervisor
+   generations, executor windows, prefetch staging on its worker thread
+   and per-request serving breakdowns) when, and only when, a sink exists
+   (``PADDLE_OBSERVE_DIR``), ``PADDLE_TRACE`` is on and the root is
+   sampled (:attr:`Span.logged`).
 
- - ``span(name, **attrs)`` — context manager; pushes the span onto the
+A span never waits for the device and never lowers anything: tracing does
+not change what it measures.
+
+API surface (every call returns a span):
+
+ - ``span(name, **attrs)``: context manager; pushes the span onto the
    calling thread's context stack so nested spans parent automatically
-   and every ``observe.emit`` record inside is stamped with
+   and every ``observe.emit`` record inside a logged span is stamped with
    (trace_id, span_id);
- - ``start_span(name, parent=..., **attrs)`` / ``Span.end(**attrs)`` —
+ - ``start_span(name, parent=..., **attrs)`` / ``Span.end(**attrs)``:
    explicit pair for async hand-offs (a serving request's span lives
    across the batcher thread; a prefetch stage span lives on the worker
    thread);
- - ``emit_span(name, t0, t1, parent=...)`` — record an already-measured
-   ``perf_counter`` interval as a child span (queue-wait spans are known
-   only after the fact).
+ - ``emit_span(name, t0, t1, parent=...)``: record an already-measured
+   ``perf_counter`` interval (queue waits are known only after the fact;
+   such a span is in the ring and the log, not in the profiler's trace).
 
-Enablement: ``PADDLE_TRACE`` (default on) gates everything, and spans
-only materialize when an observe sink exists (``PADDLE_OBSERVE_DIR``) —
-so production runs without an observe dir pay a single dict lookup per
-window, and ``PADDLE_TRACE=0`` forces the hot paths back to their exact
-pre-trace shape (no device sync, no extra lowering).
-``PADDLE_TRACE_SAMPLE`` keeps every Nth root span (deterministic
-counter-based sampling — no RNG on the hot path); children inherit their
-root's decision by construction (an unsampled root returns ``None`` and
-its would-be children become roots of their own sampling decision).
+``PADDLE_TRACE_SAMPLE`` keeps every Nth root span in the event log
+(deterministic counter-based sampling, no RNG on the hot path); children
+inherit their root's decision.
+
+The compile path from inside: one pair of ``jax.monitoring`` listeners,
+registered when this module is imported, turns jax's own duration events
+(jaxpr trace, jaxpr -> MLIR module, backend compile or persistent-cache
+load) into ``fluid.compile.trace`` / ``.lower`` / ``.backend`` spans
+under whatever span is open on that thread, and into the counters
+``compile.lowerings``, ``compile.backend_compiles`` and
+``executor.relowerings`` (see :func:`_on_jax_duration`).
 
 Cross-process stitching: ``PADDLE_TRACEPARENT`` (W3C ``traceparent``
 shape, ``00-<trace>-<span>-01``) seeds this process's trace id and
 default root parent.  The elastic supervisor mints ONE trace id per run,
 opens a span per generation, and hands each generation
-``PADDLE_TRACEPARENT`` pointing at its generation span — so a
+``PADDLE_TRACEPARENT`` pointing at its generation span, so a
 kill-and-resume run merges into one trace tree spanning processes.
 
-Device-time attribution: :func:`cost_of` reads ``cost_analysis()`` off a
-jax ``Lowered``/``Compiled`` (flops + bytes accessed of the whole fused
-window program) and :func:`note_device_cost` turns it into the
-``device.flops_per_window`` / ``device.mfu{mesh=...}`` gauges
-(model-flops-utilization = flops / wall / peak);
-:func:`note_window_breakdown` publishes the per-window
-``window.host_ms`` / ``window.stage_ms`` / ``window.device_ms`` /
-``window.observe_ms`` gauge family the step-time breakdown view reads.
+:func:`note_window_breakdown` publishes the per-window ``window.host_ms``
+/ ``window.stage_ms`` / ``window.dispatch_ms`` / ``window.observe_ms``
+gauge family: host times all four, the third the time to ENQUEUE the
+window, not the time the device took (the device trace has that).
 """
 
 from __future__ import annotations
 
-import contextlib
+import collections
 import itertools
 import os
 import threading
 import time
-from typing import Optional
+from typing import List, NamedTuple, Optional
+
+import jax.monitoring
+from jax.profiler import TraceAnnotation
+
+from . import current_step
 
 __all__ = [
-    "Span", "span", "start_span", "emit_span", "current", "enabled",
-    "trace_context", "set_trace_context", "new_span_id",
-    "format_traceparent", "parse_traceparent", "thread_tid",
-    "cost_of", "device_peak_flops", "peak_tflops", "note_device_cost",
-    "note_window_breakdown", "reset",
+    "Span", "Recorded", "RING_SPANS", "span", "start_span", "emit_span",
+    "recorded", "current", "enabled", "trace_context", "set_trace_context",
+    "new_span_id", "format_traceparent", "parse_traceparent", "thread_tid",
+    "peak_tflops", "note_window_breakdown", "reset",
 ]
 
 # one wall/perf anchor pair so perf_counter intervals map onto the event
@@ -80,6 +96,35 @@ def _wall(perf_t: float) -> float:
 
 def _gen_id(nbytes: int) -> str:
     return os.urandom(nbytes).hex()
+
+
+# ---------------------------------------------------------------------------
+# the ring: every ended span, in memory, bounded
+# ---------------------------------------------------------------------------
+
+
+class Recorded(NamedTuple):
+    """One ended span as the ring keeps it (``time.perf_counter`` stamps)."""
+    name: str
+    t0: float
+    t1: float
+    span_id: str
+    parent_id: Optional[str]
+    tid: int
+    step: Optional[int]
+
+
+#: Ring capacity.  A per-step ``run`` leaves 7 spans (root + six children),
+#: so a 34 s benchmark window of the quickest cell (157 ms a step, 216
+#: steps) leaves ~1,500 and set-up a few hundred: 40 times over.
+RING_SPANS = 1 << 16
+
+_ring: "collections.deque[Recorded]" = collections.deque(maxlen=RING_SPANS)
+
+
+def recorded() -> List[Recorded]:
+    """The ended spans still in the ring, oldest first (a copy)."""
+    return list(_ring)
 
 
 # ---------------------------------------------------------------------------
@@ -153,8 +198,15 @@ def set_trace_context(trace_id: Optional[str],
         _env_parent = parent_span
 
 
+#: span ids: 8 random hex digits drawn once per process, then a counter.
+#: A draw per span is a system call per span, and where that is slow (a
+#: sealed VM) it was most of a span's cost.
+_SPAN_PREFIX = _gen_id(4)
+_span_seq = itertools.count(1)
+
+
 def new_span_id() -> str:
-    return _gen_id(8)
+    return _SPAN_PREFIX + format(next(_span_seq) & 0xFFFFFFFF, "08x")
 
 
 def current() -> Optional["Span"]:
@@ -164,16 +216,16 @@ def current() -> Optional["Span"]:
 
 
 def enabled() -> bool:
-    """Tracing is on: ``PADDLE_TRACE`` truthy AND an observe sink exists
-    (spans land in the run-event stream; without a stream there is
-    nowhere to put them, so the hot paths skip all measurement)."""
-    from ..fluid import envcontract
-
-    if not envcontract.get("PADDLE_TRACE"):
-        return False
+    """Spans reach the EVENT LOG: an observe sink exists AND
+    ``PADDLE_TRACE`` is truthy.  The ring and the profiler annotation do
+    not depend on it."""
     from . import get_sink
 
-    return get_sink() is not None
+    if get_sink() is None:
+        return False
+    from ..fluid import envcontract
+
+    return bool(envcontract.get("PADDLE_TRACE"))
 
 
 def _sample_root() -> bool:
@@ -205,105 +257,217 @@ def _do_emit(emit_fn, event: str, **fields) -> None:
         pass  # telemetry must never fail the work it measures
 
 
+def _keep(name, t0, t1, trace_id, span_id, parent_id, tid, logged,
+          emit_fn, attrs) -> None:
+    """An ended span goes into the ring and, when logged, the event log."""
+    _ring.append(Recorded(name, t0, t1, span_id, parent_id, tid,
+                          current_step()))
+    if logged:
+        _do_emit(emit_fn, name, ts=_wall(t1),
+                 dur_s=round(max(0.0, t1 - t0), 6), trace_id=trace_id,
+                 span_id=span_id, parent_span=parent_id, tid=tid, **attrs)
+
+
+def _annotation(name: str, attrs: dict) -> TraceAnnotation:
+    """An entered ``TraceAnnotation``.  The attributes are formatted only
+    while a profiler session is recording: outside one the annotation is
+    a flag test and must stay one."""
+    ann = None
+    if attrs and TraceAnnotation.is_enabled():
+        try:
+            ann = TraceAnnotation(name, **{
+                k: v if isinstance(v, (str, int, float)) else repr(v)
+                for k, v in attrs.items()})
+        except Exception:
+            ann = None
+    if ann is None:
+        ann = TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
 class Span:
-    """One open timed region.  ``end()`` emits a single run-event record
-    carrying ``dur_s`` + the trace identity; it is idempotent, returns
-    the duration in seconds, and never raises."""
+    """One open timed region.  ``end()`` closes the profiler annotation,
+    appends the span to the ring and, when :attr:`logged`, emits a single
+    run-event record carrying ``dur_s`` + the trace identity; it is
+    idempotent, returns the duration in seconds, and never raises.  As a
+    context manager it also sits on its thread's context stack."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs",
-                 "tid", "ended", "_t0", "_emit")
+                 "tid", "ended", "logged", "t0", "_emit", "_ann")
 
     def __init__(self, name: str, trace_id: str, parent_id: Optional[str],
-                 attrs: dict, emit_fn=None):
+                 attrs: dict, logged: bool, emit_fn=None):
         self.name = name
         self.trace_id = trace_id
-        self.span_id = _gen_id(8)
+        self.span_id = new_span_id()
         self.parent_id = parent_id
         self.attrs = attrs
         self.tid = thread_tid()
         self.ended = False
-        self._t0 = time.perf_counter()
+        self.logged = logged
         self._emit = emit_fn
+        self._ann = _annotation(name, attrs)
+        self.t0 = time.perf_counter()
+
+    def set(self, **attrs) -> None:
+        """Attributes learned while the span is open (whether the
+        executor's cache missed is known only after the lookup)."""
+        self.attrs.update(attrs)
+        try:
+            if TraceAnnotation.is_enabled():
+                self._ann.set_metadata(**attrs)
+        except Exception:
+            pass
 
     def end(self, **extra) -> Optional[float]:
         if self.ended:
             return None
         self.ended = True
         t1 = time.perf_counter()
-        dur = t1 - self._t0
-        fields = dict(self.attrs)
-        fields.update(extra)
-        _do_emit(self._emit, self.name, ts=_wall(t1),
-                 dur_s=round(dur, 6), trace_id=self.trace_id,
-                 span_id=self.span_id, parent_span=self.parent_id,
-                 tid=self.tid, **fields)
-        return dur
+        try:
+            if extra:
+                self.set(**extra)
+            self._ann.__exit__(None, None, None)
+        except Exception:
+            pass  # telemetry must never fail the work it measures
+        _keep(self.name, self.t0, t1, self.trace_id, self.span_id,
+              self.parent_id, self.tid, self.logged, self._emit, self.attrs)
+        return t1 - self.t0
+
+    def __enter__(self) -> "Span":
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        stack.append(self)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        stack = getattr(_tls, "stack", None)
+        if stack and stack[-1] is self:
+            stack.pop()
+        self.end()
+        return False
+
+
+def _identity(parent: Optional[Span]):
+    """(trace_id, parent_id, logged) for a span under ``parent`` (default:
+    the thread's innermost open span).  A root takes the process trace
+    context and its own sampling decision; a child follows its parent."""
+    if parent is None:
+        parent = current()
+    if parent is not None:
+        return parent.trace_id, parent.span_id, parent.logged
+    trace_id, parent_id = trace_context()
+    return trace_id, parent_id, enabled() and _sample_root()
 
 
 def start_span(name: str, parent: Optional[Span] = None, emit_fn=None,
-               **attrs) -> Optional[Span]:
+               **attrs) -> Span:
     """Open a span WITHOUT touching the thread context stack (async
-    hand-off form — the opener and the closer may be different threads).
-    Returns None when tracing is off or the root sampler says skip."""
-    try:
-        if not enabled():
-            return None
-        if parent is None:
-            parent = current()
-        if parent is not None:
-            trace_id, parent_id = parent.trace_id, parent.span_id
-        else:
-            if not _sample_root():
-                return None
-            trace_id, parent_id = trace_context()
-        return Span(name, trace_id, parent_id, attrs, emit_fn)
-    except Exception:
-        return None
+    hand-off form: the opener and the closer may be different threads)."""
+    trace_id, parent_id, logged = _identity(parent)
+    return Span(name, trace_id, parent_id, attrs, logged, emit_fn)
 
 
-@contextlib.contextmanager
-def span(name: str, **attrs):
+def span(name: str, **attrs) -> Span:
     """Context-manager span: children opened inside parent to it, and
-    ``observe.emit`` records inside are stamped with its identity.
-    Yields the Span (or None when tracing is off/sampled out)."""
-    sp = start_span(name, **attrs)
-    if sp is None:
-        yield None
-        return
-    stack = getattr(_tls, "stack", None)
-    if stack is None:
-        stack = _tls.stack = []
-    stack.append(sp)
-    try:
-        yield sp
-    finally:
-        if stack and stack[-1] is sp:
-            stack.pop()
-        sp.end()
+    ``observe.emit`` records inside are stamped with its identity when it
+    is logged.  ``with span(...) as sp`` yields the Span."""
+    return start_span(name, **attrs)
 
 
 def emit_span(name: str, t0: float, t1: float,
               parent: Optional[Span] = None, emit_fn=None,
-              **attrs) -> Optional[str]:
-    """Record an already-measured ``perf_counter`` interval as a child of
-    ``parent`` (queue waits, H2D staging, dispatch segments — intervals
-    whose boundaries are only known after the fact).  Returns the new
-    span id, or None when there is no live parent to hang it off."""
-    if parent is None:
-        return None
-    try:
-        span_id = _gen_id(8)
-        _do_emit(emit_fn, name, ts=_wall(t1),
-                 dur_s=round(max(0.0, t1 - t0), 6),
-                 trace_id=parent.trace_id, span_id=span_id,
-                 parent_span=parent.span_id, tid=thread_tid(), **attrs)
-        return span_id
-    except Exception:
-        return None
+              **attrs) -> str:
+    """Record an already-measured ``perf_counter`` interval (queue waits,
+    jax's own compile phases: intervals whose boundaries are only known
+    after the fact) under ``parent``, default the thread's innermost open
+    span.  Goes to the ring and, under a logged parent, to the event log;
+    the profiler's trace cannot take a span after the fact.  Returns the
+    new span id."""
+    trace_id, parent_id, logged = _identity(parent)
+    span_id = new_span_id()
+    _keep(name, t0, t1, trace_id, span_id, parent_id, thread_tid(), logged,
+          emit_fn, attrs)
+    return span_id
 
 
 # ---------------------------------------------------------------------------
-# device-time attribution: compiled cost -> flops/MFU/breakdown gauges
+# the compile path from inside: jax's own duration events as spans
+# ---------------------------------------------------------------------------
+
+_JAX_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_JAX_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_JAX_EVENTS = {  # jax 0.9.0, jax/_src/dispatch.py
+    _JAX_TRACE: "fluid.compile.trace",
+    _JAX_LOWER: "fluid.compile.lower",
+    # pxla's compile_or_get_cached: the backend compile, or the load of
+    # the executable from jax's persistent cache
+    "/jax/core/compile/backend_compile_duration": "fluid.compile.backend",
+}
+
+#: the span a step's jit call runs under -> the root that says whether
+#: the executor's own cache held the entry
+_CALL_ROOTS = {"fluid.run.call": "fluid.run",
+               "executor.dispatch": "executor.window"}
+
+
+def _on_jax_start(event: str, _value, **_kw) -> None:
+    """jax stamps the START of each timed phase as a scalar event.  Only
+    the depth of open traces and lowerings is kept: tracing a step traces
+    every jitted function it calls, and lowering an interpreted Pallas
+    call traces its body (36,669 trace events in a Transformer rehearsal,
+    a few dozen outermost), and only an outermost trace becomes a span."""
+    if event == _JAX_TRACE or event == _JAX_LOWER:
+        _tls.compiling = getattr(_tls, "compiling", 0) + 1
+
+
+def _on_jax_duration(event: str, duration: float, **kw) -> None:
+    """One ended phase of jax's compile path -> a span under whatever is
+    open on this thread (``fluid.run.call``, usually), and the counters
+    ``compile.lowerings`` / ``compile.backend_compiles``.
+
+    ``executor.relowerings`` counts a lowering inside a ``fluid.run.call``
+    (or a window's ``executor.dispatch``) whose ``fluid.run`` (or
+    ``executor.window``) root has ``fresh`` false: the executor held a
+    compiled entry for that program and feed, and jax lowered it again."""
+    name = _JAX_EVENTS.get(event)
+    if name is None:
+        return
+    try:
+        if event == _JAX_TRACE or event == _JAX_LOWER:
+            depth = _tls.compiling = max(
+                0, getattr(_tls, "compiling", 1) - 1)
+            if depth and event == _JAX_TRACE:
+                return
+        t1 = time.perf_counter()
+        emit_span(name, t1 - duration, t1, fun=kw.get("fun_name"))
+        if event == _JAX_TRACE:
+            return
+        from . import registry
+
+        reg = registry()
+        if event != _JAX_LOWER:
+            reg.inc("compile.backend_compiles")
+            return
+        reg.inc("compile.lowerings")
+        stack = getattr(_tls, "stack", None)
+        root = _CALL_ROOTS.get(stack[-1].name) if stack else None
+        if root is not None and any(
+                s.name == root and s.attrs.get("fresh") is False
+                for s in reversed(stack)):
+            reg.inc("executor.relowerings")
+    except Exception:
+        pass  # telemetry must never fail the compile it measures
+
+
+jax.monitoring.register_scalar_listener(_on_jax_start)
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+# ---------------------------------------------------------------------------
+# the chip's published peak (bench.py), and the per-window host breakdown
 # ---------------------------------------------------------------------------
 
 #: Peak dense bf16 TFLOP/s of one chip, keyed by ``device_kind`` exactly
@@ -311,9 +475,9 @@ def emit_span(name: str, t0: float, t1: float,
 #: too).  A kind that is not here is an error, never a default: add it
 #: with its source when such a chip is attached.
 #:  - "TPU v5 lite" (v5e): 197, Google Cloud documentation, "TPU v5e".
-#:  - "cpu": a documented NOMINAL figure, there only so that the
-#:    ``device.mfu`` ratio stays defined on the test backend; a ratio
-#:    against it is a diagnostic, not a device metric.
+#:  - "cpu": a documented NOMINAL figure, there only so that bench.py's
+#:    ratio stays defined on the test backend; a ratio against it is a
+#:    diagnostic, not a device metric.
 PEAK_BF16_TFLOPS = {"TPU v5 lite": 197.0, "cpu": 0.5}
 
 
@@ -328,66 +492,12 @@ def peak_tflops(device_kind: str) -> float:
             f"(known: {sorted(PEAK_BF16_TFLOPS)})") from None
 
 
-def device_peak_flops(device=None) -> float:
-    """Peak FLOPs/s of ``device`` (default: the first jax device)."""
-    import jax
-
-    if device is None:
-        device = jax.devices()[0]
-    return peak_tflops(device.device_kind) * 1e12
-
-
-def cost_of(stage) -> Optional[dict]:
-    """``{"flops": f, "bytes": b}`` from a jax ``Lowered`` or ``Compiled``
-    stage's ``cost_analysis()`` (list-of-dict on some backends); None when
-    the backend exposes no cost model."""
-    try:
-        ca = stage.cost_analysis()
-    except Exception:
-        return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    if not isinstance(ca, dict):
-        return None
-    flops = float(ca.get("flops", 0.0) or 0.0)
-    nbytes = float(ca.get("bytes accessed", 0.0) or 0.0)
-    if flops <= 0.0:
-        return None
-    return {"flops": flops, "bytes": nbytes}
-
-
-def note_device_cost(cost: Optional[dict], wall_s: float, n_steps: int,
-                     mesh: Optional[str] = None, device=None) -> Optional[float]:
-    """Publish the device-attribution gauges for one executed window:
-    ``device.flops_per_window`` / ``device.bytes_per_window`` (the whole
-    fused program's cost) and ``device.mfu{mesh=...}`` = flops / wall /
-    peak.  Returns the MFU, or None when no cost is available."""
-    if not cost or wall_s <= 0.0:
-        return None
-    peak = device_peak_flops(device)  # an unknown chip is an error
-    try:
-        from . import registry
-
-        reg = registry()
-        labels = {"mesh": mesh} if mesh else None
-        reg.set_gauge("device.flops_per_window", cost["flops"],
-                      labels=labels)
-        reg.set_gauge("device.bytes_per_window", cost["bytes"],
-                      labels=labels)
-        mfu = cost["flops"] / wall_s / peak
-        reg.set_gauge("device.mfu", mfu, labels=labels)
-        reg.set_gauge("device.flops_per_sec", cost["flops"] / wall_s,
-                      labels=labels)
-        return mfu
-    except Exception:
-        return None
-
-
 def note_window_breakdown(host_ms: float, stage_ms: float,
-                          device_ms: float, observe_ms: float,
+                          dispatch_ms: float, observe_ms: float,
                           mesh: Optional[str] = None) -> None:
-    """The per-window step-time breakdown gauge family: host-side prep /
-    H2D staging / device execution / host observe tail, milliseconds."""
+    """The per-window host breakdown gauge family: host-side prep / H2D
+    staging / the enqueue of the window (trace + compile on a fresh
+    entry; NOT the device's time) / host observe tail, milliseconds."""
     try:
         from . import registry
 
@@ -395,7 +505,7 @@ def note_window_breakdown(host_ms: float, stage_ms: float,
         labels = {"mesh": mesh} if mesh else None
         for name, v in (("window.host_ms", host_ms),
                         ("window.stage_ms", stage_ms),
-                        ("window.device_ms", device_ms),
+                        ("window.dispatch_ms", dispatch_ms),
                         ("window.observe_ms", observe_ms)):
             reg.set_gauge(name, round(float(v), 3), labels=labels)
     except Exception:
@@ -403,9 +513,10 @@ def note_window_breakdown(host_ms: float, stage_ms: float,
 
 
 def reset() -> None:
-    """Re-arm env late-binding and clear this thread's context stack
-    (test-harness hook, called from ``observe.reset``)."""
+    """Clear the ring, re-arm env late-binding and clear this thread's
+    context stack (test-harness hook, called from ``observe.reset``)."""
     global _trace_id, _env_parent
+    _ring.clear()
     with _state_lock:
         _trace_id = None
         _env_parent = None

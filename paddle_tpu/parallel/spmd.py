@@ -776,7 +776,6 @@ class ShardedWindowRunner:
                             donate_argnums=(2,) if self.donate else ())
         self._compiled = None
         self.collectives: Optional[dict] = None
-        self.cost: Optional[dict] = None
         self.memory: Optional[dict] = None
 
     # -- placement --
@@ -814,16 +813,9 @@ class ShardedWindowRunner:
 
     def _note_collectives(self) -> None:
         """Read the optimized HLO of the just-compiled window executable
-        and publish what GSPMD inserted as mesh-labeled gauges — plus the
-        executable's cost analysis (flops / bytes accessed), which backs
-        the ``device.mfu{mesh=...}`` attribution gauges per dispatch."""
+        and publish what GSPMD inserted as mesh-labeled gauges."""
         from ..observe import memory as _obsmem
-        from ..observe import trace as _trace
 
-        try:
-            self.cost = _trace.cost_of(self._compiled)
-        except Exception:
-            self.cost = None
         # compiled memory truth: the AOT executable is already in hand, so
         # the memory.peak_bytes{mesh=} gauge family is free on this path
         self.memory = _obsmem.memory_stats(self._compiled)
@@ -848,8 +840,7 @@ class ShardedWindowRunner:
                          n_steps=self.n_steps,
                          collective_bytes=self.collectives["bytes"],
                          collective_count=self.collectives["count"],
-                         by_kind=self.collectives["by_kind"],
-                         flops=(self.cost or {}).get("flops"))
+                         by_kind=self.collectives["by_kind"])
         except Exception:
             pass  # accounting must never fail the run it measures
 
@@ -859,7 +850,6 @@ class ShardedWindowRunner:
         """One fused window: place, dispatch, commit state back to the
         scope.  Returns the LAST step's fetches (mirrors
         ``Executor.run_steps``)."""
-        import contextlib
         import time as _time
 
         from ..fluid import fault as _fault
@@ -872,11 +862,13 @@ class ShardedWindowRunner:
         from ..observe import watchdog as _watchdog
 
         scope = scope or global_scope()
-        _tstack = contextlib.ExitStack()
-        with _tstack:
-            wspan = _tstack.enter_context(
-                _trace.span("executor.window", n_steps=self.n_steps,
-                            mesh=self.label))
+        # the window span and its children (feed/state staging, dispatch,
+        # host observe tail), all mesh-labeled and stamped as they happen;
+        # none waits for the device.  Prefetch-staged feeds show ~zero
+        # stage time here; the staging span then lives on the prefetch
+        # worker's thread row.
+        with _trace.span("executor.window", n_steps=self.n_steps,
+                         mesh=self.label):
             t_host0 = _time.perf_counter()
             gb = self.program.global_block()
             feed_arrays = {}
@@ -891,180 +883,168 @@ class ShardedWindowRunner:
                         arr = arr.astype(want)
                 feed_arrays[k] = arr
             t_feed0 = _time.perf_counter()
-            feed_dev = self.place_feed_window(feed_arrays)
+            with _trace.span("executor.stage", what="feed"):
+                feed_dev = self.place_feed_window(feed_arrays)
             t_feed1 = _time.perf_counter()
-            return self._run_placed(
-                feed_arrays, feed_dev, scope, return_numpy, wspan,
-                t_host0, t_feed0, t_feed1, _time, _fault, _guardian,
-                _prof, Executor, _cc, observe, _trace, _watchdog)
 
-    def _run_placed(self, feed_arrays, feed_dev, scope, return_numpy,
-                    wspan, t_host0, t_feed0, t_feed1, _time, _fault,
-                    _guardian, _prof, Executor, _cc, observe, _trace,
-                    _watchdog):
-        window_start = 0
-        if self.program._params_grads is not None:
-            window_start = Executor._step_boundary(_fault, self.n_steps)
-        g = _guardian.current() if self.guard is not None else None
-        if g is not None:
-            # one-window-lag sentinel: observe the PREVIOUS dispatch's
-            # aggregated health and apply policy BEFORE this window runs
-            g.on_boundary()
-        t_state0 = _time.perf_counter()
-        state_vals = self.step.place_state(scope)
-        t_state1 = _time.perf_counter()
-        mut_names = set(self.plan.state_out)
-        if self.plan.needs_rng:
-            mut_names.add(RNG_STATE_VAR)
-        if self.guard is not None and self.guard.scale_vars:
-            mut_names.update(self.guard.scale_vars)
-        mut_state = {k: v for k, v in state_vals.items() if k in mut_names}
-        const_state = {k: v for k, v in state_vals.items()
-                       if k not in mut_names}
-        rep = NamedSharding(self.mesh, P())
-        sentinel = None
-        dump_state = None
-        if self.guard is not None:
-            seed_mul, loss_mul = _fault.sentinel_injection_window(
-                window_start, self.n_steps)
-            # sentinel inputs placed replicated explicitly: the AOT
-            # executable requires mesh-consistent input shardings
-            sentinel = {
-                "loss_cap": jax.device_put(
-                    jnp.float32(g.loss_cap() if g is not None
-                                else float("inf")), rep),
-                "seed_mul": jax.device_put(jnp.asarray(seed_mul), rep),
-                "loss_mul": jax.device_put(jnp.asarray(loss_mul), rep),
-            }
-            dump_state = state_vals
-            if g is not None and g.config.policy == "dump_and_halt" \
-                    and self.donate:
-                # donation invalidates mutated input buffers after the
-                # dispatch; dump mode keeps pre-window device copies alive
-                dump_state = {k: (jnp.array(v, copy=True) if k in mut_names
-                                  else v)
-                              for k, v in state_vals.items()}
+            window_start = 0
+            if self.program._params_grads is not None:
+                window_start = Executor._step_boundary(_fault, self.n_steps)
+            g = _guardian.current() if self.guard is not None else None
+            if g is not None:
+                # one-window-lag sentinel: observe the PREVIOUS dispatch's
+                # aggregated health and apply policy BEFORE this window
+                # runs
+                g.on_boundary()
+            t_state0 = _time.perf_counter()
+            with _trace.span("executor.stage", what="state"):
+                state_vals = self.step.place_state(scope)
+            t_state1 = _time.perf_counter()
+            mut_names = set(self.plan.state_out)
+            if self.plan.needs_rng:
+                mut_names.add(RNG_STATE_VAR)
+            if self.guard is not None and self.guard.scale_vars:
+                mut_names.update(self.guard.scale_vars)
+            mut_state = {k: v for k, v in state_vals.items()
+                         if k in mut_names}
+            const_state = {k: v for k, v in state_vals.items()
+                           if k not in mut_names}
+            rep = NamedSharding(self.mesh, P())
+            sentinel = None
+            dump_state = None
+            if self.guard is not None:
+                seed_mul, loss_mul = _fault.sentinel_injection_window(
+                    window_start, self.n_steps)
+                # sentinel inputs placed replicated explicitly: the AOT
+                # executable requires mesh-consistent input shardings
+                sentinel = {
+                    "loss_cap": jax.device_put(
+                        jnp.float32(g.loss_cap() if g is not None
+                                    else float("inf")), rep),
+                    "seed_mul": jax.device_put(jnp.asarray(seed_mul), rep),
+                    "loss_mul": jax.device_put(jnp.asarray(loss_mul), rep),
+                }
+                dump_state = state_vals
+                if g is not None and g.config.policy == "dump_and_halt" \
+                        and self.donate:
+                    # donation invalidates mutated input buffers after the
+                    # dispatch; dump mode keeps pre-window device copies
+                    # alive
+                    dump_state = {k: (jnp.array(v, copy=True)
+                                      if k in mut_names else v)
+                                  for k, v in state_vals.items()}
 
-        probe = None
-        t = _time.perf_counter()
-        fresh_compile = self._compiled is None
-        if self._compiled is None:
-            with _trace.span("executor.compile", mesh=self.label,
-                             n_steps=self.n_steps):
-                probe = _cc.executor_probe(
-                    self.program, feed_arrays, self.fetch_names,
-                    extra=self.step.cache_extra(
-                        kind="sharded_window", n_steps=self.n_steps,
-                        feed_per_step=self.feed_per_step,
-                        donate=self.donate,
-                        guard=(self.guard.cache_token()
-                               if self.guard is not None else None)),
-                    spec_table=table_signature(self.specs))
-                # AOT compile once; the same Compiled serves every window
-                # AND yields the optimized HLO for the collective gauges +
-                # the cost analysis behind device.mfu, with no second
-                # trace/compile through the jit dispatch path
-                self._compiled = self._jit.lower(
-                    feed_dev, const_state, mut_state, sentinel).compile()
-                self._note_collectives()
-        observe.note_mesh(self.label)
-        t_disp0 = _time.perf_counter()
-        agg = None
-        if self.guard is not None:
-            fetches, new_state, agg = self._compiled(
-                feed_dev, const_state, mut_state, sentinel)
-        else:
-            fetches, new_state = self._compiled(
-                feed_dev, const_state, mut_state, sentinel)
-        if wspan is not None or (_prof.is_profiling()
-                                 and self.guard is None):
-            # device-time attribution needs the dispatch retired; outside
-            # tracing/profiling it stays async as before
-            jax.block_until_ready((fetches, new_state))
-        t_disp1 = _time.perf_counter()
-        dt = t_disp1 - t
-        if _prof.is_profiling():
-            _prof.record_event(
-                f"executor_run[{len(self.plan.ops)}ops "
-                f"x{self.n_steps}steps mesh={self.label}]", dt, start=t)
-        _prof.record_counter("executor.dispatches")
-        _prof.record_counter("executor.windows")
-        _prof.record_counter("executor.window_steps", inc=self.n_steps)
-        reg = observe.registry()
-        labels = {"mesh": self.label}
-        reg.inc("executor.dispatches", labels=labels)
-        reg.inc("executor.windows", labels=labels)
-        reg.inc("executor.window_steps", self.n_steps, labels=labels)
-        if probe is not None:
-            meta = {"kind": "sharded_window", "n_steps": self.n_steps,
-                    "mesh": self.label}
-            if isinstance(self.memory, dict):
-                # per-executable memory table in the cache manifest, so a
-                # warm start re-reports HBM truth without re-lowering
-                meta["memory"] = self.memory
-            probe.finish(dt, self.program, meta=meta)
-        if _fault.active() is not None:
-            new_state = _fault.corrupt_state(new_state)
-        for name, val in new_state.items():
-            scope.set(name, val)
-        Executor._check_nan_inf(list(new_state.items())
-                                + list(zip(self.plan.fetch_names, fetches)))
-        if g is not None and agg is not None:
-            g.defer(self.guard, window_start, agg, {
-                "program": self.program, "feeds": feed_arrays,
-                "feed_lods": {}, "fetch_names": self.fetch_names,
-                "state": dump_state, "sentinel": sentinel,
-                "duration_s": dt,
-                "window": {"start": window_start, "n_steps": self.n_steps,
-                           "feed_per_step": self.feed_per_step}})
-        if self.program._params_grads is not None:
-            observe.note_step(window_start + self.n_steps - 1)
-            from ..observe import memory as _obsmem
+            probe = None
+            t = _time.perf_counter()
+            fresh_compile = self._compiled is None
+            if self._compiled is None:
+                with _trace.span("executor.compile", mesh=self.label,
+                                 n_steps=self.n_steps):
+                    probe = _cc.executor_probe(
+                        self.program, feed_arrays, self.fetch_names,
+                        extra=self.step.cache_extra(
+                            kind="sharded_window", n_steps=self.n_steps,
+                            feed_per_step=self.feed_per_step,
+                            donate=self.donate,
+                            guard=(self.guard.cache_token()
+                                   if self.guard is not None else None)),
+                        spec_table=table_signature(self.specs))
+                    # AOT compile once; the same Compiled serves every
+                    # window AND yields the optimized HLO for the
+                    # collective gauges, with no second trace/compile
+                    # through the jit dispatch path
+                    self._compiled = self._jit.lower(
+                        feed_dev, const_state, mut_state, sentinel).compile()
+                    self._note_collectives()
+            observe.note_mesh(self.label)
+            t_disp0 = _time.perf_counter()
+            agg = None
+            with _trace.span("executor.dispatch", mesh=self.label):
+                if self.guard is not None:
+                    fetches, new_state, agg = self._compiled(
+                        feed_dev, const_state, mut_state, sentinel)
+                else:
+                    fetches, new_state = self._compiled(
+                        feed_dev, const_state, mut_state, sentinel)
+                if _prof.is_profiling() and self.guard is None:
+                    # fluid.profiler's timeline wants the device time; no
+                    # span, sink or PADDLE_TRACE setting ever waits here
+                    jax.block_until_ready((fetches, new_state))
+            t_disp1 = _time.perf_counter()
+            dt = t_disp1 - t
+            with _trace.span("executor.observe"):
+                if _prof.is_profiling():
+                    _prof.record_event(
+                        f"executor_run[{len(self.plan.ops)}ops "
+                        f"x{self.n_steps}steps mesh={self.label}]", dt,
+                        start=t)
+                _prof.record_counter("executor.dispatches")
+                _prof.record_counter("executor.windows")
+                _prof.record_counter("executor.window_steps",
+                                     inc=self.n_steps)
+                reg = observe.registry()
+                labels = {"mesh": self.label}
+                reg.inc("executor.dispatches", labels=labels)
+                reg.inc("executor.windows", labels=labels)
+                reg.inc("executor.window_steps", self.n_steps, labels=labels)
+                if probe is not None:
+                    meta = {"kind": "sharded_window",
+                            "n_steps": self.n_steps, "mesh": self.label}
+                    if isinstance(self.memory, dict):
+                        # per-executable memory table in the cache
+                        # manifest, so a warm start re-reports HBM truth
+                        # without re-lowering
+                        meta["memory"] = self.memory
+                    probe.finish(dt, self.program, meta=meta)
+                if _fault.active() is not None:
+                    new_state = _fault.corrupt_state(new_state)
+                for name, val in new_state.items():
+                    scope.set(name, val)
+                Executor._check_nan_inf(
+                    list(new_state.items())
+                    + list(zip(self.plan.fetch_names, fetches)))
+                if g is not None and agg is not None:
+                    g.defer(self.guard, window_start, agg, {
+                        "program": self.program, "feeds": feed_arrays,
+                        "feed_lods": {}, "fetch_names": self.fetch_names,
+                        "state": dump_state, "sentinel": sentinel,
+                        "duration_s": dt,
+                        "window": {"start": window_start,
+                                   "n_steps": self.n_steps,
+                                   "feed_per_step": self.feed_per_step}})
+                if self.program._params_grads is not None:
+                    observe.note_step(window_start + self.n_steps - 1)
+                    from ..observe import memory as _obsmem
 
-            # live-buffer ledger: mesh-labeled scope residency + watermark
-            # at the window boundary
-            _obsmem.note_scope_live(scope, scope_label="train",
-                                    mesh=self.label,
-                                    step=window_start + self.n_steps - 1)
-        t_obs1 = _time.perf_counter()
-        if wspan is not None:
-            # per-window breakdown: feed/state staging, device dispatch,
-            # host observe tail — all mesh-labeled, all under the window
-            # span (prefetch-staged feeds show ~zero stage time here; the
-            # staging span then lives on the prefetch worker's thread row)
-            _trace.emit_span("executor.stage", t_feed0, t_feed1,
-                             parent=wspan, what="feed")
-            _trace.emit_span("executor.stage", t_state0, t_state1,
-                             parent=wspan, what="state")
-            _trace.emit_span("executor.dispatch", t_disp0, t_disp1,
-                             parent=wspan, mesh=self.label)
-            _trace.emit_span("executor.observe", t_disp1, t_obs1,
-                             parent=wspan)
+                    # live-buffer ledger: mesh-labeled scope residency +
+                    # watermark at the window boundary
+                    _obsmem.note_scope_live(
+                        scope, scope_label="train", mesh=self.label,
+                        step=window_start + self.n_steps - 1)
+            t_obs1 = _time.perf_counter()
             stage_ms = ((t_feed1 - t_feed0) + (t_state1 - t_state0)) * 1e3
             _trace.note_window_breakdown(
                 host_ms=max(0.0, (t_disp0 - t_host0) * 1e3 - stage_ms),
                 stage_ms=stage_ms,
-                device_ms=(t_disp1 - t_disp0) * 1e3,
+                dispatch_ms=(t_disp1 - t_disp0) * 1e3,
                 observe_ms=(t_obs1 - t_disp1) * 1e3,
                 mesh=self.label)
-            if self.cost:
-                _trace.note_device_cost(self.cost, t_disp1 - t_disp0,
-                                        self.n_steps, mesh=self.label)
-        if self.program._params_grads is not None:
-            _watchdog.observe_value(
-                "executor.step_time_s",
-                (t_obs1 - t_host0) / max(1, self.n_steps),
-                step=window_start + self.n_steps - 1, mesh=self.label)
-            from ..observe import goodput as _goodput
+            if self.program._params_grads is not None:
+                _watchdog.observe_value(
+                    "executor.step_time_s",
+                    (t_obs1 - t_host0) / max(1, self.n_steps),
+                    step=window_start + self.n_steps - 1, mesh=self.label)
+                from ..observe import goodput as _goodput
 
-            # goodput ledger: the one-off AOT lower+compile is compile
-            # state; the rest of the window is device compute
-            cdur = t_disp0 - t if fresh_compile else 0.0
-            if cdur > 0.0:
-                _goodput.note("compile", cdur, mesh=self.label)
-            _goodput.note("device",
-                          max(0.0, (t_obs1 - t_host0) - cdur),
-                          mesh=self.label)
-        if return_numpy:
-            return [np.asarray(self.step.fetch_to_host(v)) for v in fetches]
-        return list(fetches)
+                # goodput ledger: the one-off AOT lower+compile is compile
+                # state; the rest of the window is device compute
+                cdur = t_disp0 - t if fresh_compile else 0.0
+                if cdur > 0.0:
+                    _goodput.note("compile", cdur, mesh=self.label)
+                _goodput.note("device",
+                              max(0.0, (t_obs1 - t_host0) - cdur),
+                              mesh=self.label)
+            if return_numpy:
+                return [np.asarray(self.step.fetch_to_host(v))
+                        for v in fetches]
+            return list(fetches)

@@ -238,23 +238,36 @@ def test_sharded_window_memory_gauges_and_events(tmp_path, monkeypatch):
     assert any(n.startswith("memory.live_bytes") for n in tracks), tracks
 
 
-def test_traced_single_device_window_memory(tmp_path, monkeypatch):
-    """The PR 9 traced lowering point also yields memory truth: a traced
-    run_steps window publishes memory.peak_bytes with no mesh label."""
+def test_single_device_window_memory_is_the_callers_compile(tmp_path,
+                                                            monkeypatch):
+    """A span never lowers: a run_steps window under an observe sink is
+    lowered ONCE, by its own first dispatch (it used to be lowered and
+    compiled a second time to read its memory).  The compiled truth of a
+    single-device program is what the caller asks for, through
+    ``Executor.compiled_memory_stats``."""
     monkeypatch.setenv("PADDLE_OBSERVE_DIR", str(tmp_path))
     fluid.default_main_program().random_seed = 5
     fluid.default_startup_program().random_seed = 5
     loss = _build_mlp()
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(fluid.default_startup_program())
+    before = observe.registry().flat().get("compile.lowerings", 0)
     exe.run_steps(fluid.default_main_program(), _mlp_feed(), [loss],
                   n_steps=4)
+    flat = observe.registry().flat()
+    assert flat["compile.lowerings"] - before == 1, flat
+    assert "memory.peak_bytes" not in flat
+    stats = exe.compiled_memory_stats(fluid.default_main_program(),
+                                      _mlp_feed(), [loss])
+    from paddle_tpu.observe import memory as obsmem
+
+    obsmem.note_compiled_memory(stats, kind="run")
     gauges = observe.registry().snapshot()["gauges"]
     assert gauges.get("memory.peak_bytes", 0) > 0, sorted(gauges)
     recs = [json.loads(line)
             for line in open(observe.get_sink().events.path)]
     prof = [r for r in recs if r["event"] == "memory.profile"]
-    assert prof and prof[0]["kind"] == "run_steps"
+    assert prof and prof[0]["kind"] == "run"
 
 
 def test_warm_start_reports_memory_without_relowering(tmp_path,

@@ -1,19 +1,22 @@
-"""Tracing + attribution smoke (CPU, < 5 s).
+"""Span tracing smoke (CPU, < 5 s).
 
-The CI oracle for the ISSUE 9 span tracer: with an observe dir
-configured,
+The CI oracle for the span primitive (``observe.trace``): with an observe
+dir configured,
 
- - a traced 16-step training window produces an ``executor.window`` span
-   with ``executor.stage`` / ``executor.dispatch`` / ``executor.observe``
-   children sharing one trace id, the ``window.*_ms`` breakdown gauges,
-   and a NONZERO ``device.mfu`` gauge (XLA-cost-backed);
+ - a 16-step training window produces an ``executor.window`` span with
+   ``executor.stage`` / ``executor.dispatch`` / ``executor.observe``
+   children sharing one trace id, the ``window.*_ms`` host breakdown
+   gauges, and the same spans in the in-memory ring, where the first
+   window's dispatch holds one ``fluid.compile.lower`` and whatever the
+   later ones hold is what ``executor.relowerings`` counted;
  - 8 served requests produce per-request ``serving.request`` spans that
    decompose into queue / batch / dispatch / resolve children;
  - the merged stream round-trips through the chrome-trace exporter as
    ``"ph": "X"`` complete events carrying span ids;
- - ``PADDLE_TRACE=0`` runs the SAME paths and emits ZERO spans (the
-   disabled hot path — no device syncs, no extra lowering), with both
-   per-window timings reported so overhead is visible in the log.
+ - ``PADDLE_TRACE=0`` runs the SAME paths and writes ZERO spans to the
+   event log while the ring still fills: the two differ by the log
+   writes alone (a span never syncs or lowers), and both per-window
+   timings are reported so that is visible.
 
 Run directly (``python tools/trace_smoke.py``) or from tier-1 via
 ``tests/test_trace.py::test_trace_smoke_tool``.
@@ -72,6 +75,7 @@ def main() -> dict:
 
     import paddle_tpu.fluid as fluid
     from paddle_tpu import observe
+    from paddle_tpu.observe import trace
     from paddle_tpu.observe.export import chrome_trace
     from paddle_tpu.observe.fleet import fleet_events
 
@@ -86,11 +90,17 @@ def main() -> dict:
         traced_ms = _run_window(fluid, np, prog, startup, loss,
                                 n_windows=2)
         flat = observe.registry().flat()
-        report["mfu"] = flat.get("device.mfu")
-        report["mfu_nonzero"] = bool(flat.get("device.mfu"))
         report["breakdown_gauges"] = all(
             f"window.{k}_ms" in flat
-            for k in ("host", "stage", "device", "observe"))
+            for k in ("host", "stage", "dispatch", "observe"))
+        ring = trace.recorded()
+        dispatches = [r for r in ring if r.name == "executor.dispatch"]
+        lowered = [sum(1 for r in ring if r.name == "fluid.compile.lower"
+                       and r.parent_id == d.span_id) for d in dispatches]
+        report["ring_lowerings_per_window"] = lowered
+        report["relowerings"] = int(flat.get("executor.relowerings", 0))
+        report["relowerings_agree"] = (
+            lowered[0] == 1 and sum(lowered[1:]) == report["relowerings"])
 
         # -- 2. traced serving requests --------------------------------
         from paddle_tpu.inference import (AnalysisConfig, PaddleTensor)
@@ -158,24 +168,30 @@ def main() -> dict:
             len(xs) >= len(dur_spans)
             and any(e["args"].get("span_id") for e in xs))
 
-        # -- 4. disabled mode: zero spans, no syncs --------------------
+        # -- 4. PADDLE_TRACE=0: nothing in the log, the ring still fills
         os.environ["PADDLE_TRACE"] = "0"
         n_spans_before = len(spans)
+        n_ring_before = sum(1 for r in trace.recorded()
+                            if r.name == "executor.window")
         prog2, startup2, loss2 = _build_train(fluid)
         untraced_ms = _run_window(fluid, np, prog2, startup2, loss2,
                                   n_windows=2)
         observe.get_sink().flush()
         spans_after = [r for r in fleet_events(root) if r.get("span_id")]
         report["disabled_no_spans"] = len(spans_after) == n_spans_before
+        report["disabled_ring_fills"] = sum(
+            1 for r in trace.recorded()
+            if r.name == "executor.window") == n_ring_before + 2
         report["window_ms_traced"] = round(traced_ms[-1], 2)
         report["window_ms_untraced"] = round(untraced_ms[-1], 2)
 
         report["elapsed_s"] = round(time.perf_counter() - t0, 2)
         report["ok"] = all(report[k] for k in (
-            "mfu_nonzero", "breakdown_gauges", "window_spans",
+            "relowerings_agree", "breakdown_gauges", "window_spans",
             "window_children", "request_spans", "request_children",
             "request_decomposes", "one_trace_per_run",
-            "chrome_round_trip", "disabled_no_spans"))
+            "chrome_round_trip", "disabled_no_spans",
+            "disabled_ring_fills"))
     except Exception as exc:  # a broken smoke must still print its JSON
         import traceback
 
