@@ -44,15 +44,22 @@ def sgd(ctx):
     return {"ParamOut": p - _lr(ctx) * g}
 
 
-def _fused_opt_ok(ctx, p, g, out_slots):
+def _fused_opt_ok(ctx, kind, p, g, out_slots):
     """Route this update through the single-sweep Pallas kernel?  Gate +
-    static suitability + (under a mesh) spec alignment of param and
-    accumulators — ZeRO-1-diverged updates keep the unfused lowering."""
+    static suitability (an open gate that the tensor does not suit is
+    counted as ``ops.fused.declined{kind,why}``; ``why=layout``: no 2-D
+    view of it is free, ``pallas_fused._sweep_view``) + (under a mesh)
+    spec alignment of param and accumulators — ZeRO-1-diverged updates
+    keep the unfused lowering."""
     from . import pallas_fused
 
-    if not (pallas_fused.fused_decision() and pallas_fused.opt_fusable(p, g)):
+    if not pallas_fused.fused_decision():
         return False
     names = [(ctx.outputs_spec.get(s) or [None])[0] for s in out_slots]
+    why = pallas_fused.opt_declined(p, g, names[0])
+    if why is not None:
+        pallas_fused._note("declined", kind=kind, why=why)
+        return False
     return pallas_fused.opt_specs_aligned(names)
 
 
@@ -62,7 +69,7 @@ def momentum(ctx):
     g = _grad(ctx, p)
     mu = ctx.attr("mu")
     lr = _lr(ctx)
-    if _fused_opt_ok(ctx, p, g, ("ParamOut", "VelocityOut")):
+    if _fused_opt_ok(ctx, "momentum", p, g, ("ParamOut", "VelocityOut")):
         from . import pallas_fused
 
         p_out, v_out = pallas_fused.fused_momentum(
@@ -88,7 +95,8 @@ def adam(ctx):
     b2 = ctx.attr("beta2", 0.999)
     eps = ctx.attr("epsilon", 1e-8)
     lr = _lr(ctx) * jnp.sqrt(1.0 - b2p) / (1.0 - b1p)
-    if _fused_opt_ok(ctx, p, g, ("ParamOut", "Moment1Out", "Moment2Out")):
+    if _fused_opt_ok(ctx, "adam", p, g,
+                     ("ParamOut", "Moment1Out", "Moment2Out")):
         from . import pallas_fused
 
         # the bias-corrected lr and beta-pow counters are [1]-shaped

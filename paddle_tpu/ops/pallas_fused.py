@@ -43,7 +43,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from jax import shard_map as _shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .pallas_flash import block_index
 
@@ -94,17 +94,20 @@ def _active_mesh():
     return spmd.active_mesh()
 
 
-def _note(kind: str) -> None:
-    """One ``ops.fused.<kind>`` dispatch-decision counter per trace
+def _note(family: str, /, **labels) -> None:
+    """One ``ops.fused.<family>`` dispatch-decision counter per trace
     (mesh-labeled under an active mesh) — the observe-side evidence that
-    a program actually lowered through the fused kernel."""
+    a program actually lowered through the fused kernel, or, as
+    ``ops.fused.declined{kind=...,why=...}``, that the gate was open and
+    the update kept XLA's lowering."""
     try:
         from .. import observe
         from ..parallel.mesh import mesh_label
 
         mesh = _active_mesh()
-        labels = {"mesh": mesh_label(mesh)} if mesh is not None else None
-        observe.registry().inc(f"ops.fused.{kind}", labels=labels)
+        if mesh is not None:
+            labels["mesh"] = mesh_label(mesh)
+        observe.registry().inc(f"ops.fused.{family}", labels=labels or None)
     except Exception:
         pass  # accounting must never fail the trace it measures
 
@@ -530,17 +533,21 @@ def _adam_kernel(p_ref, g_ref, m1_ref, m2_ref, lr_ref, po_ref, m1o_ref,
 
 
 def _sweep_view(shape):
-    """2-D view ``(rows, cols)`` of one parameter for the sweep.  A
-    lane-aligned last dim is kept and the leading dims collapse onto it,
-    which leaves the tiled HBM layout alone; other lane-aligned element
-    counts (1-D biases) become rows of 128; anything else is one ragged
-    row, which the sweep walks in column blocks."""
+    """2-D view ``(rows, cols)`` of one parameter for the sweep, or None
+    where there is none.  The rule: the kernel never asks XLA for a
+    relayout.  A ``pallas_call`` takes its operands row-major, so the
+    view has to be one the tiled device layout already is: a lane-aligned
+    last dim is kept and the leading dims collapse onto it; a 1-D tensor
+    becomes rows of 128, or one ragged row that the sweep walks in column
+    blocks.  A tensor of two or more dims with a ragged last dim has no
+    such view: an OIHW convolution filter (last dim 1, 3 or 7) lives with
+    its channel dims minor, and flattening it is a copy through a layout
+    that pads every 3x3 patch to a 4x128 tile, for each operand and
+    result.  It keeps the op's own XLA lowering, in the layout it has."""
     n = int(np.prod(shape, dtype=np.int64))
-    if len(shape) >= 2 and shape[-1] % LANE == 0:
-        return (n // shape[-1], shape[-1])
-    if n % LANE == 0:
-        return (n // LANE, LANE)
-    return (1, n)
+    if len(shape) >= 2:
+        return (n // shape[-1], shape[-1]) if shape[-1] % LANE == 0 else None
+    return (n // LANE, LANE) if n % LANE == 0 else (1, n)
 
 
 def _sweep_blocks(rows, cols):
@@ -586,18 +593,29 @@ def _opt_sweep(kernel, arrays, lr, n_out, interpret):
     return [o.reshape(shape) for o in outs]
 
 
-def opt_fusable(p, g) -> bool:
-    """Static suitability of one optimizer update for the fused sweep."""
+def opt_declined(p, g, var_name: Optional[str] = None) -> Optional[str]:
+    """Why one optimizer update keeps the op's own XLA lowering although
+    the gate is open (the ``why`` label of ``ops.fused.declined``), or
+    None where it takes the fused sweep.  Under an active mesh the sweep
+    runs on the local shard of ``var_name``'s spec, so the layout rule
+    reads that shard's shape."""
     if str(p.dtype) not in _FUSABLE_DTYPES:
-        return False
+        return "dtype"
     n = int(np.prod(p.shape, dtype=np.int64))
-    if n == 0:
-        return False
+    if n == 0 or g is None or g.shape != p.shape:
+        return "shape"
+    if _sweep_view(_local_shape(p.shape, var_name)) is None:
+        return "layout"
     # a non-lane-aligned tensor runs as one [1, n] row that fills one
     # sublane in eight; past this size the unfused lowering is the better
     if n % LANE and n > (1 << 17):
-        return False
-    return g is not None and g.shape == p.shape
+        return "ragged"
+    return None
+
+
+def opt_fusable(p, g, var_name: Optional[str] = None) -> bool:
+    """Static suitability of one optimizer update for the fused sweep."""
+    return opt_declined(p, g, var_name) is None
 
 
 def _param_spec(mesh, var_name: Optional[str], shape):
@@ -616,6 +634,16 @@ def _param_spec(mesh, var_name: Optional[str], shape):
                    and shape[d] % mesh.shape[ax] == 0) else None
             for d, ax in enumerate(tuple(spec))]
     return P(*dims)
+
+
+def _local_shape(shape, var_name: Optional[str]):
+    """The shape ``_opt_sweep`` is handed: the array's own, or under an
+    active mesh its local shard per :func:`_param_spec`."""
+    mesh = _active_mesh()
+    if mesh is None:
+        return tuple(shape)
+    spec = _param_spec(mesh, var_name, shape)
+    return NamedSharding(mesh, spec).shard_shape(tuple(shape))
 
 
 def opt_specs_aligned(out_names) -> bool:

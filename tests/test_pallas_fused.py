@@ -200,8 +200,27 @@ def test_xent_softmax_output_path():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("shape", [(33, 7), (256, 128), (10,),
-                                   (1500, 256), (3, 40000)])
+@pytest.mark.parametrize("shape,fusable", [
+    ((512, 512, 3, 3), False), ((256, 64, 1, 1), False),
+    ((64, 3, 7, 7), False), ((2048, 1000), False), ((512, 30000), False),
+    ((33, 7), False),
+    ((512, 2048), True), ((30000, 512), True), ((512,), True),
+    ((30000,), True), ((64,), True)])
+def test_sweep_taken_only_where_its_view_is_no_copy(shape, fusable):
+    """The rule of the fused optimizer sweep: a tensor of two or more dims
+    with a ragged last dim (every OIHW convolution filter, ResNet's
+    ``[2048, 1000]`` fc, the Transformer's ``[512, 30000]`` projection)
+    has no 2-D view the chip's tiled layout already is, so the update
+    keeps XLA's lowering; lane-aligned matrices and every 1-D tensor keep
+    the sweep."""
+    x = jax.ShapeDtypeStruct(shape, jnp.float32)
+    assert pf.opt_fusable(x, x) is fusable
+    assert (pf._sweep_view(shape) is not None) is fusable
+    assert pf.opt_declined(x, x) == (None if fusable else "layout")
+
+
+@pytest.mark.parametrize("shape", [(231,), (256, 128), (10,),
+                                   (1500, 256), (120000,)])
 def test_fused_adam_matches_formula(shape):
     """Lane-aligned AND ragged shapes (the [1, n] single-row path), and
     two whose last row block / column block hangs over the edge."""
@@ -222,7 +241,7 @@ def test_fused_adam_matches_formula(shape):
 @pytest.mark.parametrize("nesterov", [False, True])
 def test_fused_momentum_matches_formula(nesterov):
     rng = np.random.RandomState(7)
-    p, g, v = (jnp.asarray(rng.normal(size=(64, 32)).astype(np.float32))
+    p, g, v = (jnp.asarray(rng.normal(size=(16, 128)).astype(np.float32))
                for _ in range(3))
     po, vo = pf.fused_momentum(p, g, v, jnp.float32(0.05), 0.9, nesterov)
     vr = 0.9 * v + g
@@ -285,6 +304,48 @@ def test_fused_training_matches_unfused(monkeypatch):
     assert c.get("ops.fused.adam", 0) > 0
 
 
+def test_conv_filter_declines_the_sweep_and_equals_unfused(monkeypatch):
+    """A momentum program over a conv filter and a batch-norm scale under
+    PADDLE_TPU_FUSED=1: the filter's update is declined for its layout
+    (counted) and is the unfused run's bit for bit; the 1-D scale takes
+    the sweep."""
+    x = fluid.layers.data(name="x", shape=[3, 8, 8], dtype="float32")
+    h = fluid.layers.conv2d(x, num_filters=4, filter_size=3, padding=1,
+                            bias_attr=False)
+    h = fluid.layers.batch_norm(
+        h, bias_attr=fluid.ParamAttr(trainable=False))
+    loss = fluid.layers.mean(h * h)
+    fluid.optimizer.Momentum(learning_rate=0.1, momentum=0.9).minimize(loss)
+    prog = fluid.default_main_program()
+    (filt,) = [p.name for p in prog.global_block().all_parameters()
+               if len(p.shape) == 4]
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    scope = _executor._global_scope
+    init = _snapshot(scope)
+    feed = {"x": np.random.RandomState(3).normal(
+        size=(2, 3, 8, 8)).astype(np.float32)}
+
+    declined = 'ops.fused.declined{kind="momentum",why="layout"}'
+    got = {}
+    for fused in ("0", "1"):
+        monkeypatch.setenv("PADDLE_TPU_FUSED", fused)
+        _restore(scope, init)
+        before = fluid.profiler.counters()
+        exe.lower_step(prog, feed, [loss])     # one trace of the step
+        after = fluid.profiler.counters()
+        for _ in range(3):
+            exe.run(prog, feed=feed, fetch_list=[loss])
+        got[fused] = np.asarray(scope.get(filt))
+        delta = {k: after.get(k, 0) - before.get(k, 0)
+                 for k in ("ops.fused.momentum", declined)}
+        assert delta == ({"ops.fused.momentum": 1, declined: 1}
+                         if fused == "1" else
+                         {"ops.fused.momentum": 0, declined: 0})
+    assert not np.array_equal(got["1"], init[filt])
+    np.testing.assert_array_equal(got["1"], got["0"])
+
+
 def test_guarded_fp16_scaled_window_fused_matches_unfused(monkeypatch):
     """The ISSUE 6 window-equivalence oracle with the fused kernels on the
     path: a guardian-gated + dynamically-fp16-loss-scaled 8-step run_steps
@@ -333,6 +394,27 @@ def test_guarded_fp16_scaled_window_fused_matches_unfused(monkeypatch):
 # ---------------------------------------------------------------------------
 # tp-sharded lowerings (dp2×tp2 on the 8 forced CPU devices)
 # ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("shape,spec,why", [
+    ((512, 2048), (None, "tp"), None),        # local [512, 1024]
+    ((512, 30000), (None, "tp"), "layout"),   # local [512, 15000]
+    ((64, 128), (None, "tp"), "layout"),      # local [64, 64]
+    ((64, 128), ("tp", None), None),          # local [32, 128]
+    ((30000,), ("tp",), None)])               # local [15000]: 1-D
+def test_sweep_rule_reads_the_local_shard(shape, spec, why):
+    """Under a mesh the sweep runs inside ``shard_map`` on the local
+    shard of the param's spec, so that shard's shape decides."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from paddle_tpu.parallel import spmd
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
+    x = jax.ShapeDtypeStruct(shape, jnp.float32)
+    with spmd.mesh_scope(mesh), spmd.param_spec_scope({"w": P(*spec)}):
+        assert pf.opt_declined(x, x, "w") == why
+        assert pf.opt_declined(x, x) == pf.opt_declined(x, x, "other")
+    assert pf.opt_declined(x, x, "w") == pf.opt_declined(x, x)
 
 
 def test_xent_sharded_matches_single_device():
