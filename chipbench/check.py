@@ -94,13 +94,15 @@ def _call(reference, sizes, matmul_dtype, *args):
 
 
 def program(reference, sizes, weights, feed, loss, grads, params_after):
-    """The program's step against the reference."""
+    """The program's step against the reference.  ``loss``, ``grads`` and
+    ``params_after`` are taken as they come: device arrays stay on the
+    device, so the comparison holds no second copy of them (how many copies
+    of the parameters it does hold: README, "Sizing the comparison")."""
     import numpy as np
 
     return _call(reference, sizes, None, list(weights),
                  {k: np.asarray(v) for k, v in feed.items()},
-                 np.asarray(loss), [np.asarray(g) for g in grads],
-                 list(params_after))
+                 loss, list(grads), list(params_after))
 
 
 def control(reference, sizes, weights, feed, matmul_dtype):

@@ -12,11 +12,41 @@ score and value contractions and is counted as half.
 
 The count is per SAMPLE (one sequence pair, one image): shapes whose batch
 dim is dynamic (-1) are read with batch 1.
+
+The walk knows four contractions and nothing else, so it may only be trusted
+on a program whose every op type it has been told about: ``COUNTED`` (the
+four) or ``NO_CONTRACTION`` (the other op types of the two programs the
+benchmark was accepted with, read from their built ``Program``s; none holds
+a contraction), each with its ``_grad``.  ``uncounted_op_types`` names the
+rest, and where there is any, ``mfu_pct`` is withheld: a program whose
+contractions sit in an op the walk does not know (routed experts, a scan)
+would otherwise report an undercount as its MFU.  A configuration with such
+ops states its FLOPs itself, in ``configs/<name>/flops.py``
+(``train_flops_per_sample(sizes)``), which then replaces the walk.
 """
 
 from __future__ import annotations
 
 import math
+
+
+COUNTED = frozenset({"mul", "matmul", "conv2d", "ring_attention"})
+NO_CONTRACTION = frozenset({
+    "accuracy", "adam", "assign_value", "batch_norm", "cast",
+    "cross_entropy", "dropout", "elementwise_add", "elementwise_div",
+    "elementwise_mul", "equal", "fill_any_like", "fill_constant",
+    "fill_constant_batch_size_like", "layer_norm", "logical_not",
+    "lookup_table", "mean", "momentum", "one_hot", "pool2d", "reduce_sum",
+    "relu", "reshape", "scale", "softmax", "softmax_with_cross_entropy",
+    "sum", "top_k", "transpose"})
+
+
+def uncounted_op_types(program) -> list:
+    """The op types of ``program`` (block 0) that the walk neither counts
+    nor knows to hold no contraction."""
+    known = COUNTED | NO_CONTRACTION
+    return sorted({op.type for op in program.global_block().ops}
+                  - known - {t + "_grad" for t in known})
 
 
 def _shape(block, name, batch=1):

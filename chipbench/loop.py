@@ -97,6 +97,15 @@ def make_step(fluid, traffic, program, loss, feed, chips):
     return dispatch, finish, lower
 
 
+def device_arrays(handles):
+    """What ``dispatch`` returned, as the arrays behind it, still on the
+    device.  ``Executor.run(return_numpy=False)`` wraps each fetch in a
+    ``LoDTensor`` that offers only a host copy (``__array__``, which also
+    lets go of the device array); the wrapper has no accessor for the
+    array itself yet (PERF.md, Open questions)."""
+    return [getattr(h, "_data", h) for h in handles]
+
+
 def run_window(dispatch, finish, seconds=None, steps=None, lookahead=1,
                clock=time.perf_counter):
     """Run until ``seconds`` have passed since the window opened, or for

@@ -53,7 +53,16 @@ def metrics_for(entries, cell_name):
 
 
 def cell_sizes(config_entry, rehearse):
+    """The configuration's sizes as this run uses them.  A file that breaks
+    the rules of a cut configuration (``chipbench/cuts.py``) is refused
+    here, before anything is built; the cut is the record's first line."""
+    from chipbench import cuts
+
     sizes = read_json(ROOT, config_entry["file"])
+    wrong = cuts.problems(sizes, config_entry)
+    if wrong:
+        raise SystemExit(f"{config_entry['file']}: " + "; ".join(wrong))
+    print(cuts.line(sizes), flush=True)
     if rehearse:
         sizes = {**sizes, **sizes["tiny"]}
     return sizes
@@ -123,6 +132,7 @@ class Cell:
         self.chips = cell["chips"]
         cfg_dir = os.path.dirname(config_entry["file"])
         rel = os.path.relpath(os.path.join(ROOT, cfg_dir), HERE)
+        self.config_dir = rel
         self.builder = plugins.load(rel, "build")
         self.reference = plugins.load(rel, "reference")
         self.times = {}
@@ -198,7 +208,7 @@ class Cell:
         against the float32 reference (or, with ``matmul_dtype``, the
         CONTROL in the program's place).  Must run right after
         ``seed_state``: the optimizer comparison is of the first step."""
-        from chipbench import check
+        from chipbench import check, loop
 
         fluid = self.fluid
         feed = self.feed(seed, self.sizes["check_batch"] * self.chips
@@ -208,12 +218,31 @@ class Cell:
             return check.control(self.reference, self.sizes, self.weights,
                                  feed, matmul_dtype)
         grads = [n + "@GRAD" for n in self.names]
-        outs = dispatch(program=self.check_main, feed=feed,
-                        fetch=[self.check_loss] + grads)
+        outs = loop.device_arrays(dispatch(
+            program=self.check_main, feed=feed,
+            fetch=[self.check_loss] + grads))
         scope = fluid.global_scope()
         after = [scope.get(n) for n in self.names]
+        # loss, gradients and parameters go in as the device arrays they
+        # are: nothing is copied to the host and sent back
         return check.program(self.reference, self.sizes, self.weights, feed,
                              outs[0], outs[1:], after)
+
+
+def print_arrays_peak(devices, when):
+    """``memory after <when>: arrays <peak_bytes_in_use> ...`` of the
+    fullest chip, so that a run's record says in which phase the arrays'
+    high-water mark (half of ``peak_hbm_gib``) was reached: the value only
+    ever rises, so the first line that shows the final value names it."""
+    stats = [d.memory_stats() or {} for d in devices]
+    if not any(stats):
+        print(f"memory after {when}: this backend reports none", flush=True)
+        return
+    full = max(stats, key=lambda s: int(s.get("peak_bytes_in_use", 0)))
+    print(f"memory after {when}: arrays {full.get('peak_bytes_in_use')} at "
+          f"the peak, {full.get('bytes_in_use')} now, program temporaries "
+          f"{full.get('peak_bytes_reserved')} of {full.get('bytes_limit')} "
+          "bytes", flush=True)
 
 
 def device_record(jax, devices):
@@ -287,16 +316,21 @@ def main(argv=None) -> int:
     fluid = built.fluid
     built.times["import_and_device_s"] = t_import
     built.seed_state(args.seed)
+    print_arrays_peak(used, "weights")
     feed = built.feed(args.seed, built.batch)
     dispatch, finish, lower = built.make_step(feed)
 
     t0 = time.perf_counter()
     numbers = built.check(args.seed, dispatch)
+    # the reference's weights have done their work: the window runs
+    # beside the program's own state and nothing of the comparison's
+    built.weights = None
     limits = sizes.get("limits", {})
     verdict = check.decide(numbers, limits)
     built.times["reference_check_s"] = time.perf_counter() - t0
-    for line in check.report(numbers, limits):
-        print(line, flush=True)
+    compared = list(check.report(numbers, limits))
+    print("\n".join(compared), flush=True)
+    print_arrays_peak(used, "comparison")
 
     t0 = time.perf_counter()
     first_loss = finish(dispatch())
@@ -305,6 +339,7 @@ def main(argv=None) -> int:
     warm = loop.run_window(dispatch, finish, steps=traffic["warmup_steps"],
                            lookahead=traffic["lookahead"])
     built.times["warmup_s"] = time.perf_counter() - t0
+    print_arrays_peak(used, "warm-up")
 
     # a mix the standard loop cannot express brings its own, as a file
     own = plugins.load("traffic", cell["traffic"])
@@ -392,6 +427,9 @@ def main(argv=None) -> int:
         print("rehearsal counts: " + json.dumps(
             {"steps": run["steps"], "dispatches": run["dispatches"],
              "trace": run.get("trace_counts")}), flush=True)
+    # the numbers compared, beside their limits, as the last lines of the
+    # errors too: of a run that is not correct the driver keeps their end
+    print("\n".join(compared), file=sys.stderr, flush=True)
     print(json.dumps(out), flush=True)
     return 0
 

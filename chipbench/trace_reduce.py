@@ -214,6 +214,14 @@ def kernel_roofline(events: Sequence[Event], calls: Sequence[Call],
             "least_s": least, "took_s": took, "families": families}
 
 
+def family_pct(roofline: Optional[dict], family: str) -> Optional[float]:
+    """One family's share of its roofline (the HBM-bytes reading) out of
+    ``kernel_roofline``'s result; None when the run was not traced, the
+    step calls no kernel of the family, or its events were not all found."""
+    fam = ((roofline or {}).get("families") or {}).get(family) or {}
+    return fam.get("pct")
+
+
 def exposed_collective_s(events: Sequence[Event]) -> Tuple[float, float]:
     """(exposed, total) collective seconds of one device: total is the
     union of the collective events' intervals, exposed the part of it that

@@ -51,12 +51,36 @@ def event_label(scopes, calls):
     return label
 
 
+def needed_flops(built):
+    """FLOPs that one sample's forward and backward need: what the
+    configuration states in a ``flops.py`` of its own, else the walk of the
+    program (``chipbench/flops.py``), else None: the walk met an op type it
+    cannot vouch for, and no reading is better than an undercount."""
+    from chipbench import flops, plugins
+
+    own = plugins.load(built.config_dir, "flops")
+    if own is not None:
+        n = int(own.train_flops_per_sample(built.sizes))
+        print(f"flops per sample: {n}, stated by "
+              f"chipbench/{built.config_dir}/flops.py", flush=True)
+        return n
+    unknown = flops.uncounted_op_types(built.main)
+    if unknown:
+        print("flops per sample: withheld, and mfu_pct with it: the walk "
+              f"of chipbench/flops.py cannot count the op types {unknown}; "
+              f"chipbench/{built.config_dir}/flops.py may state them",
+              flush=True)
+        return None
+    n = flops.train_flops_per_sample(built.main)
+    print(f"flops per sample: {n}, walked from the program", flush=True)
+    return n
+
+
 def traced_stretch(built, dispatch, finish, lower, traffic, trace_dir,
                    keep_dir=None):
     import jax
 
     from chipbench import loop, trace_reduce
-    from chipbench.flops import train_flops_per_sample
 
     shutil.rmtree(trace_dir, ignore_errors=True)
     os.makedirs(trace_dir, exist_ok=True)
@@ -90,12 +114,14 @@ def traced_stretch(built, dispatch, finish, lower, traffic, trace_dir,
 
     out = {"traced_losses": traced["losses"],
            "steps_traced": steps_traced,
-           "flops_per_sample": train_flops_per_sample(built.main),
            "optimized_hlo": optimized,
            "step_memory": step_memory,
            "trace_counts": {"device_planes": len(trace.devices),
                             "host_spans": len(trace.host_spans),
                             "steps_traced": steps_traced}}
+    flops = needed_flops(built)
+    if flops is not None:
+        out["flops_per_sample"] = flops
     if built.rehearse:
         # a CPU trace has no device plane: the code path ran, the counts
         # are printed, and no share is made of it
@@ -142,6 +168,10 @@ def reduce_trace(trace, stablehlo, optimized, steps_traced, peaks):
     return {
         "trace": summary,
         "pallas_time_pct": 100.0 * pallas_s / busy0,
+        # every label of the device that was labelled, for the readers
+        # that take one op's share (``chipbench/op_time.py``)
+        "time_by_label": by_label,
+        "labelled_busy_s": busy0,
         "roofline": roof,
         "collective_exposed_pct": 100.0 * max(
             e / trace_reduce.busy_and_window(trace.devices[n])[1]

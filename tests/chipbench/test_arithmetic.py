@@ -89,6 +89,39 @@ def test_resnet50_program_needs_twice_3_86_gmacs():
         == 3 * fwd
 
 
+@pytest.mark.parametrize("config", ["transformer_base_wmt",
+                                    "resnet50_imagenet"])
+def test_the_walk_knows_every_op_type_of_an_accepted_program(config):
+    import json
+
+    import paddle_tpu.fluid as fluid
+
+    sizes = json.load(open(os.path.join(BENCH, "configs", config,
+                                        "config.json")))
+    build = load(os.path.join("configs", config), "build")
+    for deterministic in (False, True):
+        main = fluid.Program()
+        with fluid.program_guard(main, fluid.Program()), \
+                fluid.unique_name.guard():
+            build.build(fluid, sizes, deterministic=deterministic)
+        assert flops.uncounted_op_types(main) == []
+        assert flops.train_flops_per_sample(main) > 0
+
+
+def test_the_walk_names_the_op_types_it_cannot_count():
+    import paddle_tpu.fluid as fluid
+
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()), \
+            fluid.unique_name.guard():
+        x = fluid.layers.data(name="x", shape=[8], dtype="float32")
+        h = fluid.layers.sigmoid(fluid.layers.fc(input=x, size=8))
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(
+            fluid.layers.mean(h))
+    assert flops.uncounted_op_types(main) == ["sgd", "sigmoid",
+                                              "sigmoid_grad"]
+
+
 # -- kernel families and the lowered calls -----------------------------
 
 QKV = ((32, 256, 64), "bf16")
@@ -303,6 +336,49 @@ def test_time_by_label_uses_self_time():
                      ).devices["/device:TPU:0"]
     by = tr.time_by_label(evs, lambda e: e.name.split(".")[0])
     assert by == {"while": pytest.approx(8e-9), "fusion": pytest.approx(2e-9)}
+
+
+def test_op_time_share_sums_the_labels_that_start_so():
+    from chipbench import op_time
+
+    run = {"time_by_label": {"op:mul": 2.0, "op:mul_grad": 4.0,
+                             "kernel:adam": 1.0, "op:scale": 1.0},
+           "labelled_busy_s": 10.0}
+    assert op_time.share(run, ("op:mul",)) == pytest.approx(0.6)
+    assert op_time.share(run, ("kernel:", "op:scale")) == pytest.approx(0.2)
+    assert op_time.share(run, ("op:moe",)) is None
+    assert op_time.share({"steps": 3}, ("op:mul",)) is None
+
+
+FAMILIES = ["flash_fwd", "flash_dq", "flash_dkv", "xent_fwd", "xent_bwd",
+            "adam"]
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_family_roofline_metric_reads_its_own_family(family):
+    metric = load("layer_metrics", family + "_roofline")
+    roof = tr.kernel_roofline(
+        [tr.Event("call." + f, 10.0 * i, 4.0 + i)
+         for i, f in enumerate(FAMILIES)],
+        [tr.Call(f, "sig." + f, 0.0, 819) for f in FAMILIES], 1,
+        lambda e: ("sig." + e.name.split(".")[1], 819),
+        {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
+    i = FAMILIES.index(family)
+    # 819 bytes at 819 GB/s is 1 ns, of an event that took 4 + i ns
+    assert metric.value({"roofline": roof}) == pytest.approx(100 / (4.0 + i))
+    assert roof["families"][family]["counted"]
+    # two steps traced, one event found: withheld, and so left out
+    short = tr.kernel_roofline(
+        [tr.Event("call." + family, 0.0, 4.0)],
+        [tr.Call(family, "sig." + family, 0.0, 819)], 2,
+        lambda e: ("sig." + family, 819),
+        {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
+    assert metric.value({"roofline": short}) is None
+    assert metric.value({"roofline": None}) is None
+    assert metric.value({"steps": 3}) is None
+    # a step that calls no kernel of the family (the ResNet cell)
+    other = {"families": {"momentum": {"pct": 2.6, "counted": True}}}
+    assert metric.value({"roofline": other}) is None
 
 
 # -- a recording from the v5e ------------------------------------------

@@ -14,6 +14,10 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
+sys.path.insert(0, ROOT)
+
+from chipbench import cuts  # noqa: E402
+
 BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -117,7 +121,7 @@ def test_at_most_one_cell_asks_for_four_chips():
 def test_configs(c):
     assert set(c) == {"name", "source", "file", "reduced", "why"}
     sizes = json.load(open(os.path.join(ROOT, c["file"])))
-    assert sizes["reduced"] == c["reduced"] == []
+    assert cuts.problems(sizes, c) == []
     assert sizes["source"] == c["source"]
     assert any(w["config"] == c["name"] for w in BENCH["workloads"])
     d = os.path.dirname(os.path.join(ROOT, c["file"]))
@@ -234,7 +238,8 @@ def test_dp4_traffic_rehearses_through_parallel_executor(tmp_path):
 
 TOY_CONFIG = {
     "name": "toy_mlp", "source": "a test", "task": "train", "unit": "rows",
-    "precision": "bfloat16", "reduced": [], "width": 32, "classes": 4,
+    "precision": "bfloat16", "reduced": [], "width": 32,
+    "num_hidden_layers": 1, "vocab_size": 4,
     "batch_per_chip": 8, "check_batch": 8,
     "optimizer": {"type": "sgd", "lr": 0.1},
     "limits": {"grad_rel": 0.2, "update_rel": 0.01},
@@ -244,19 +249,25 @@ TOY_BUILD = '''
 import numpy as np
 
 
+def LR(sizes):
+    return sizes["optimizer"]["lr"]
+
+
 def build(fluid, sizes, deterministic=False):
     x = fluid.layers.data(name="x", shape=[sizes["width"]], dtype="float32")
     y = fluid.layers.data(name="y", shape=[1], dtype="int64")
-    h = fluid.layers.fc(input=x, size=sizes["width"], act="relu")
-    p = fluid.layers.fc(input=h, size=sizes["classes"], act="softmax")
+    h = x
+    for _ in range(sizes["num_hidden_layers"]):
+        h = fluid.layers.fc(input=h, size=sizes["width"], act="relu")
+    p = fluid.layers.fc(input=h, size=sizes["vocab_size"], act="softmax")
     loss = fluid.layers.mean(fluid.layers.cross_entropy(input=p, label=y))
-    fluid.optimizer.SGD(learning_rate=sizes["optimizer"]["lr"]).minimize(loss)
+    fluid.optimizer.SGD(learning_rate=LR(sizes)).minimize(loss)
     return {"loss": loss, "units_per_sample": 1}
 
 
 def make_feed(sizes, batch, rng):
     return {"x": rng.normal(size=(batch, sizes["width"])).astype(np.float32),
-            "y": rng.randint(0, sizes["classes"],
+            "y": rng.randint(0, sizes["vocab_size"],
                              size=(batch, 1)).astype(np.int64)}
 
 
@@ -270,9 +281,10 @@ import numpy as np
 
 
 def param_spec(s):
-    w, c = s["width"], s["classes"]
-    return [("w1", (w, w), None), ("b1", (w,), None), ("w2", (w, c), None),
-            ("b2", (c,), None)]
+    w, c = s["width"], s["vocab_size"]
+    hidden = [(n + str(i), shape, None) for i in range(s["num_hidden_layers"])
+              for n, shape in (("w", (w, w)), ("b", (w,)))]
+    return hidden + [("w_out", (w, c), None), ("b_out", (c,), None)]
 
 
 def init_params(seed, s):
@@ -286,9 +298,11 @@ def loss_fn(params, feed, s, matmul_dtype=None):
     def q(a):
         return a if matmul_dtype is None else \\
             a.astype(matmul_dtype).astype(jnp.float32)
-    w1, b1, w2, b2 = params
-    h = jax.nn.relu(q(feed["x"]) @ q(w1) + b1)
-    logp = jax.nn.log_softmax(q(h) @ q(w2) + b2)
+    *hidden, w_out, b_out = params
+    h = feed["x"]
+    for w, b in zip(hidden[::2], hidden[1::2]):
+        h = jax.nn.relu(q(h) @ q(w) + b)
+    logp = jax.nn.log_softmax(q(h) @ q(w_out) + b_out)
     return -jnp.take_along_axis(logp, feed["y"], axis=-1).mean()
 
 
@@ -314,19 +328,42 @@ def value(run):
 '''
 
 
-def test_config_traffic_metric_and_cell_added_as_new_files_only(tmp_path):
-    root = temporary_checkout(tmp_path)
-    before = {}
+def files_of(root):
+    out = {}
     for d, _, files in os.walk(os.path.join(root, "chipbench")):
         for f in files:
             path = os.path.join(d, f)
-            before[path] = open(path, "rb").read()
+            out[path] = open(path, "rb").read()
+    return out
 
-    cfg = os.path.join(root, "chipbench", "configs", "toy_mlp")
+
+def add_toy(root, config, build=TOY_BUILD, flops=None):
+    """The toy configuration ``config`` as files of its own, and a bench
+    (not yet written) with its entry and one cell ``<name>.resident``."""
+    name = config["name"]
+    cfg = os.path.join(root, "chipbench", "configs", name)
     os.makedirs(cfg)
-    json.dump(TOY_CONFIG, open(os.path.join(cfg, "config.json"), "w"))
-    open(os.path.join(cfg, "build.py"), "w").write(TOY_BUILD)
+    json.dump(config, open(os.path.join(cfg, "config.json"), "w"))
+    open(os.path.join(cfg, "build.py"), "w").write(build)
     open(os.path.join(cfg, "reference.py"), "w").write(TOY_REFERENCE)
+    if flops:
+        open(os.path.join(cfg, "flops.py"), "w").write(flops)
+    bench = json.loads(json.dumps(BENCH))
+    bench["configs"].append({
+        "name": name, "source": config["source"], "why": "test",
+        "reduced": config["reduced"],
+        "file": f"chipbench/configs/{name}/config.json"})
+    bench["workloads"].append({
+        "name": name + ".resident", "config": name, "traffic": "resident",
+        "chips": 1, "why": "test"})
+    return bench
+
+
+def test_config_traffic_metric_and_cell_added_as_new_files_only(tmp_path):
+    root = temporary_checkout(tmp_path)
+    before = files_of(root)
+
+    bench = add_toy(root, TOY_CONFIG)
     traffic = json.load(open(os.path.join(
         root, "chipbench", "traffic", "resident.json")))
     traffic.update(name="resident_x2", batch_per_chip_scale=2)
@@ -334,14 +371,8 @@ def test_config_traffic_metric_and_cell_added_as_new_files_only(tmp_path):
         root, "chipbench", "traffic", "resident_x2.json"), "w"))
     open(os.path.join(root, "chipbench", "layer_metrics",
                       "steps_in_window.py"), "w").write(TOY_METRIC)
-
-    bench = json.loads(json.dumps(BENCH))
-    bench["configs"].append({
-        "name": "toy_mlp", "source": "a test", "reduced": [], "why": "test",
-        "file": "chipbench/configs/toy_mlp/config.json"})
-    bench["workloads"].append({
-        "name": "toy_mlp.resident_x2", "config": "toy_mlp",
-        "traffic": "resident_x2", "chips": 1, "why": "test"})
+    bench["workloads"][-1].update(name="toy_mlp.resident_x2",
+                                  traffic="resident_x2")
     bench["per_layer"].append({
         "name": "steps_in_window", "unit": "count", "better": "higher",
         "source": "program_counter", "layer": "executor dispatch",
@@ -353,5 +384,198 @@ def test_config_traffic_metric_and_cell_added_as_new_files_only(tmp_path):
     assert last["correct"] is True, lines
     assert last["metrics"]["steps_in_window"]["value"] >= 1
     assert last["metrics"]["compiles_in_window"]["value"] == 0
+    assert lines[0] == "cut: none"
+    # its optimizer op is none the walk of chipbench/flops.py knows, and it
+    # states no FLOPs of its own: no reading, and the record says why
+    assert [l for l in lines if l.startswith("flops per sample: withheld")
+            and "['sgd']" in l], lines
     for path, content in before.items():
         assert open(path, "rb").read() == content, path + " was edited"
+
+
+# -- a configuration that is one chip's share of a deployment -----------
+
+TOY_CUT = {
+    **TOY_CONFIG, "name": "toy_cut", "num_hidden_layers": 4,
+    "vocab_size": 16, "num_experts": 8,
+    "reduced": ["num_hidden_layers", "vocab_size", "num_experts"],
+    "published": {"num_hidden_layers": 12, "vocab_size": 128,
+                  "num_experts": 64},
+    "deployment": {
+        "chips_sharing_a_layer": 8,
+        "how": "experts and vocabulary rows of a layer lie on 8 chips",
+        "cuts": {
+            "num_hidden_layers": {"kind": "depth", "why": "the layers left "
+                                  "out lie on further pipeline stages"},
+            "vocab_size": {"kind": "vocabulary", "why": "an eighth of the "
+                           "rows; ids and loss are over the slice"},
+            "num_experts": {"kind": "experts_held", "why": "an eighth of a "
+                            "layer's routed experts (the toy routes none)"}}},
+}
+TOY_FLOPS = '''
+"""Two operations per multiply-accumulate of every fc, forward and twice
+that backward, from the sizes as run."""
+
+
+def train_flops_per_sample(sizes):
+    w = sizes["width"]
+    return 3 * 2 * (sizes["num_hidden_layers"] * w * w
+                    + w * sizes["vocab_size"])
+'''
+
+
+def test_cut_configuration_with_its_own_flops_added_as_new_files_only(
+        tmp_path):
+    root = temporary_checkout(tmp_path)
+    before = files_of(root)
+    bench = add_toy(root, TOY_CUT, flops=TOY_FLOPS)
+    json.dump(bench, open(os.path.join(root, "BENCHMARK.json"), "w"))
+    assert cuts.problems(TOY_CUT, bench["configs"][-1]) == []
+
+    lines, last = rehearse(root, "toy_cut.resident", trace=1, seed=11)
+    assert last["correct"] is True, lines
+    assert lines[0] == (
+        "cut: num_hidden_layers 4 of 12 (depth), vocab_size 16 of 128 "
+        "(vocabulary), num_experts 8 of 64 (experts_held); one of 8 chips "
+        "that share a layer: experts and vocabulary rows of a layer lie on "
+        "8 chips")
+    want = 3 * 2 * (4 * 32 * 32 + 32 * 16)
+    assert (f"flops per sample: {want}, stated by "
+            "chipbench/configs/toy_cut/flops.py") in lines
+    for path, content in before.items():
+        assert open(path, "rb").read() == content, path + " was edited"
+
+
+def changed(config, **top):
+    """A deep copy of ``config`` with keys replaced; ``a__b`` reaches into
+    ``config["a"]["b"]``, and None deletes the key."""
+    out = json.loads(json.dumps(config))
+    for key, v in top.items():
+        where = out
+        *path, last = key.split("__")
+        for part in path:
+            where = where[part]
+        if v is None:
+            del where[last]
+        else:
+            where[last] = v
+    return out
+
+
+@pytest.mark.parametrize("config, entry_reduced, reason", [
+    (TOY_CUT, ["num_hidden_layers", "vocab_size"], "reduced differs"),
+    (changed(TOY_CUT, published__vocab_size=None),
+     None, "vocab_size: missing from `published`"),
+    (changed(TOY_CUT, num_experts=7),
+     None, "num_experts: 7 as run is under the floor of 8"),
+    (changed(TOY_CUT, num_hidden_layers=3),
+     None, "num_hidden_layers: 3 as run is under the floor of 4"),
+    (changed(TOY_CUT, vocab_size=15),
+     None, "vocab_size: 15 as run is under the floor of 16"),
+    (changed(TOY_CUT, published__num_experts=8),
+     None, "num_experts: published 8 is not larger"),
+    (changed(TOY_CUT, deployment=None), None, "states `deployment`"),
+    (changed(TOY_CUT, deployment__chips_sharing_a_layer=0),
+     None, "chips_sharing_a_layer is a whole number"),
+    (changed(TOY_CUT, reduced=TOY_CUT["reduced"] + ["width"],
+             published__width=64,
+             deployment__cuts__width={"kind": "depth", "why": "narrower"}),
+     TOY_CUT["reduced"] + ["width"], "width: a cut of a width"),
+    (changed(TOY_CUT, reduced=["num_hidden_layers: 4 of 12"]),
+     ["num_hidden_layers: 4 of 12"], "each a name"),
+], ids=["differs_between_the_files", "missing_from_published",
+        "experts_under_8", "depth_under_4", "vocabulary_under_an_eighth",
+        "published_not_larger", "no_deployment", "no_chips", "a_width",
+        "not_a_name"])
+def test_cut_configuration_is_refused(config, entry_reduced, reason):
+    entry = {"name": config["name"], "reduced":
+             config["reduced"] if entry_reduced is None else entry_reduced}
+    wrong = cuts.problems(config, entry)
+    assert any(reason in w for w in wrong), wrong
+    assert len(wrong) == 1, wrong
+
+
+def test_run_refuses_a_cut_that_breaks_the_rules(tmp_path):
+    root = temporary_checkout(tmp_path)
+    bench = add_toy(root, changed(TOY_CUT, num_experts=7))
+    json.dump(bench, open(os.path.join(root, "BENCHMARK.json"), "w"))
+    p = subprocess.run(
+        [sys.executable, os.path.join(root, "chipbench", "run.py"),
+         "--workload", "toy_cut.resident", "--seed", "1", "--seconds", "1",
+         "--trace", "0", "--rehearse"],
+        cwd=root, capture_output=True, text=True, timeout=300)
+    assert p.returncode != 0 and not p.stdout.strip()
+    assert "under the floor of 8" in p.stderr
+
+
+# -- the timed path broken underneath ------------------------------------
+
+def test_a_step_that_leaves_its_state_unchanged_is_not_correct(tmp_path):
+    """The harness's whole run but for its look for a chip, over a program
+    whose optimizer moves nothing (the configuration states lr 0.1)."""
+    root = temporary_checkout(tmp_path)
+    broken = TOY_BUILD.replace('return sizes["optimizer"]["lr"]',
+                               "return 0.0")
+    assert broken != TOY_BUILD
+    bench = add_toy(root, TOY_CONFIG, build=broken)
+    json.dump(bench, open(os.path.join(root, "BENCHMARK.json"), "w"))
+    lines, last = rehearse(root, "toy_mlp.resident", trace=0, seed=13)
+    assert last["correct"] is False and last["failed"] == 0
+    assert [l for l in lines if l.startswith("compare update_rel:")
+            and l.endswith("NOT WITHIN")], lines
+    assert [l for l in lines if l.startswith("compare grad_rel:")
+            and l.endswith(" ok")], lines
+
+
+# -- the comparison holds no copy it does not need ------------------------
+
+def test_comparison_takes_device_arrays_as_they_are(monkeypatch):
+    """Live array bytes while the comparison runs: the reference's weights,
+    the fetched gradients and the parameters after the step, 3 copies of
+    the parameters, with a quarter of one for the feed and the loss.  With a
+    round trip through the host there is a fourth: the gradients again."""
+    import types
+
+    import jax
+    import numpy as np
+
+    from chipbench import check
+
+    ref = types.ModuleType("toy_reference")
+    exec(TOY_REFERENCE, ref.__dict__)
+    sizes = {**TOY_CONFIG, "width": 256, "num_hidden_layers": 4}
+    rng = np.random.RandomState(5)
+    feed = {"x": rng.normal(size=(8, 256)).astype(np.float32),
+            "y": rng.randint(0, 4, size=(8, 1)).astype(np.int64)}
+
+    def live_bytes():
+        return sum(a.nbytes for a in jax.live_arrays())
+
+    start = live_bytes()
+    weights = ref.init_params(3, sizes)
+    one_copy = sum(w.nbytes for w in weights)
+    loss, grads = ref.loss_and_grads(weights, feed, sizes)
+    after = [ref.optimizer_step(w, g, sizes) for w, g in zip(weights, grads)]
+    seen = {}
+    jitted = check._jitted
+
+    def watched(*key):
+        fn = jitted(*key)
+
+        def call(*args):
+            host = [a for a in jax.tree_util.tree_leaves(args)
+                    if not isinstance(a, jax.Array)]
+            seen["bytes"] = live_bytes() - start \
+                + sum(np.asarray(a).nbytes for a in host)
+            seen["args"] = args
+            return fn(*args)
+        return call
+
+    monkeypatch.setattr(check, "_jitted", watched)
+    numbers = check.program(ref, sizes, weights, feed, loss, grads, after)
+    assert numbers["grad_rel"] == 0.0 and numbers["update_rel"] < 1e-3
+    assert all(a is b for a, b in zip(seen["args"][3], grads))
+    assert all(a is b for a, b in zip(seen["args"][4], after))
+    assert seen["args"][2] is loss
+    assert 3 * one_copy <= seen["bytes"] <= 3.25 * one_copy, \
+        (seen["bytes"], one_copy)
