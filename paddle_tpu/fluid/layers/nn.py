@@ -33,6 +33,8 @@ __all__ = [
     "image_resize", "resize_bilinear", "autoincreased_step_counter",
     "lod_reset", "prelu", "dice_loss", "log_loss", "huber_loss",
     "ring_attention", "moe_ffn", "gpipe_mlp_stack",
+    "rms_norm", "rotary_embedding", "sparse_indexer", "sparse_attention",
+    "moe_experts",
     "kv_cache_update", "kv_cache_scatter", "token_select",
     "paged_attention", "spec_accept",
     "transformer_encoder_stack", "transformer_decoder_stack", "cos_sim",
@@ -1298,6 +1300,157 @@ def ring_attention(q, k, v, causal=False, scale=None, sp_axis="sp",
                "sp_axis": sp_axis,
                "flash": -1 if flash is None else int(bool(flash))})
     return out
+
+def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
+    """x * rsqrt(mean(x^2) + epsilon) * scale over the LAST axis, with one
+    scale of that width (init 1): the per-row norm of a [B, T, D] stream and
+    the per-head norm of [B, T, H, Dh] alike.  Statistics in float32."""
+    helper = LayerHelper("rms_norm", **locals())
+    dtype = helper.input_dtype()
+    scale = helper.create_parameter(
+        attr=helper.param_attr, shape=[int(input.shape[-1])], dtype=dtype,
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(dtype)
+    out.shape = tuple(input.shape)
+    helper.append_op(type="rms_norm", inputs={"X": [input], "Scale": [scale]},
+                     outputs={"Y": [out]}, attrs={"epsilon": float(epsilon)})
+    return out
+
+
+def rotary_embedding(input, theta=10000.0, name=None):
+    """Rotary positions (rotate-half form) on [B, T, H, D]: position t, the
+    index along axis 1, rotates the pair (i, i + D/2) by t * theta^(-2i/D)."""
+    helper = LayerHelper("rotary_embedding", **locals())
+    out = helper.create_variable_for_type_inference(helper.input_dtype())
+    out.shape = tuple(input.shape)
+    helper.append_op(type="rotary_embedding", inputs={"X": [input]},
+                     outputs={"Out": [out]}, attrs={"theta": float(theta)})
+    return out
+
+
+def _named_attrs(param_attr, name, suffixes):
+    """One ParamAttr per parameter of a layer that makes several: copies of
+    ``param_attr``, named ``<name>_<suffix>`` where the layer has a name."""
+    from ..param_attr import ParamAttr
+
+    out = []
+    for suffix in suffixes:
+        attr = copy.deepcopy(ParamAttr._to_attr(param_attr))
+        if name is not None:
+            attr.name = f"{name}_{suffix}"
+        out.append(attr)
+    return out
+
+
+def sparse_indexer(input, num_heads, head_dim, topk, theta=10000.0,
+                   param_attr=None, name=None):
+    """The indexer of a learned sparse attention and its top-k (ops/
+    decoder_ops.py).  input: [B, T, D].  ``num_heads`` index query heads of
+    ``head_dim`` and ONE index key head, both rotated, and a weight per
+    head: ``I[t,s] = sum_j w[t,j] relu(qI[t,j] . kI[s]) / sqrt(head_dim)``.
+    Returns the selection [B, T, T] int8 (1 where query t attends key s):
+    the ``topk`` keys ``s <= t`` of largest ``I[t,s]``, every such key while
+    ``t < topk``; the form ``sparse_attention`` takes.  The selection is
+    piecewise constant, so the three weights (``<name>_q_w``, ``_k_w``,
+    ``_w_w``) get a gradient of exactly zero through it."""
+    helper = LayerHelper("sparse_indexer", **locals())
+    dtype = helper.input_dtype()
+    d = int(input.shape[-1])
+    aq, ak, aw = _named_attrs(param_attr, name, ("q_w", "k_w", "w_w"))
+    wq = helper.create_parameter(attr=aq, shape=[d, num_heads * head_dim],
+                                 dtype=dtype)
+    wk = helper.create_parameter(attr=ak, shape=[d, head_dim], dtype=dtype)
+    ww = helper.create_parameter(attr=aw, shape=[d, num_heads], dtype=dtype)
+    sel = helper.create_variable_for_type_inference("int8")
+    sel.shape = (input.shape[0], input.shape[1], input.shape[1])
+    helper.append_op(
+        type="sparse_indexer",
+        inputs={"X": [input], "WQ": [wq], "WK": [wk], "WW": [ww]},
+        outputs={"Sel": [sel]},
+        attrs={"num_heads": int(num_heads), "topk": int(topk),
+               "theta": float(theta)})
+    return sel
+
+
+def sparse_attention(q, k, v, selection=None, scale=None, flash=None,
+                     name=None):
+    """Causal grouped-query attention, optionally over a per-query
+    selection of keys (a sibling of ``ring_attention``; ops/decoder_ops.py
+    + ops/pallas_sparse_flash.py).  q: [B, Hq, T, D]; k, v: [B, Hkv, T, D]
+    with Hq a multiple of Hkv (query head h reads head h // (Hq / Hkv));
+    ``selection``: [B, T, T] int8 from ``sparse_indexer`` or None (every
+    key s <= t).  ``flash`` as in ``ring_attention``: the Pallas kernels (the selection as a mask inside
+    them) or the blocked XLA path; nothing [Hq, T, T] reaches HBM either
+    way."""
+    helper = LayerHelper("sparse_attention", **locals())
+    out = helper.create_variable_for_type_inference(helper.input_dtype("q"))
+    out.shape = tuple(q.shape)
+    # the kernels' log-sum-exp, kept for their backward
+    lse = helper.create_variable_for_type_inference("float32",
+                                                    stop_gradient=True)
+    lse.shape = tuple(q.shape[:3]) + (1,)
+    inputs = {"Q": [q], "K": [k], "V": [v]}
+    topk = 0
+    if selection is not None:
+        inputs["Sel"] = [selection]
+        # the label of the call's counter: the attr of the op that made it
+        topk = next((op.attr("topk", 0) for op in selection.block.ops
+                     if selection.name in op.output_arg_names), 0)
+    helper.append_op(
+        type="sparse_attention", inputs=inputs,
+        outputs={"Out": [out], "Lse": [lse]},
+        attrs={"scale": float(scale or 0.0), "topk": int(topk),
+               "flash": -1 if flash is None else int(bool(flash))})
+    return out
+
+
+def moe_experts(input, num_routed, experts_held, hidden_size, top_k,
+                expert_offset=0, norm_topk=True, param_attr=None, name=None):
+    """The share of a routed expert layer that ``experts_held`` of its
+    ``num_routed`` experts give (parallel/moe.py ``routed_experts``): the
+    router is ``num_routed`` wide and every token picks its ``top_k`` over
+    all of them; the experts ``[expert_offset, expert_offset + held)`` live
+    here, SiLU-gated with no bias (``<name>_w1`` gate and ``_w3`` up
+    [held, D, hidden], ``_w2`` down [held, hidden, D], ``_router_w``
+    [D, num_routed]).  No capacity and no dropped assignment; what absent
+    experts would add is left out.  ``held = num_routed`` is the whole
+    layer.  Unlike ``moe_ffn`` (dense [N, E, C] dispatch with a capacity
+    that drops, ReLU experts with biases, every expert held) this sorts the
+    assignments by expert and multiplies them as grouped products."""
+    from ..initializer import XavierInitializer
+
+    helper = LayerHelper("moe_experts", **locals())
+    dtype = helper.input_dtype()
+    d = int(input.shape[-1])
+    ar, a1, a3, a2 = _named_attrs(param_attr, name,
+                                  ("router_w", "w1", "w3", "w2"))
+    router = helper.create_parameter(attr=ar, shape=[d, num_routed],
+                                     dtype=dtype)
+    up = XavierInitializer(fan_in=d, fan_out=hidden_size)
+    w1 = helper.create_parameter(attr=a1, shape=[experts_held, d,
+                                                 hidden_size],
+                                 dtype=dtype, default_initializer=up)
+    w3 = helper.create_parameter(attr=a3, shape=[experts_held, d,
+                                                 hidden_size],
+                                 dtype=dtype, default_initializer=up)
+    w2 = helper.create_parameter(
+        attr=a2, shape=[experts_held, hidden_size, d], dtype=dtype,
+        default_initializer=XavierInitializer(fan_in=hidden_size, fan_out=d))
+    for p in (w1, w3, w2):
+        p.dist_hint = "ep"
+    out = helper.create_variable_for_type_inference(dtype)
+    out.shape = tuple(input.shape)
+    helper.append_op(
+        type="moe_experts",
+        inputs={"X": [input], "RouterW": [router], "W1": [w1], "W3": [w3],
+                "W2": [w2]},
+        outputs={"Out": [out]},
+        attrs={"num_routed": int(num_routed),
+               "experts_held": int(experts_held),
+               "expert_offset": int(expert_offset), "top_k": int(top_k),
+               "norm_topk": bool(norm_topk)})
+    return out
+
 
 def paged_attention(q, cache_k, cache_v, page_table, bias, scale=1.0,
                     fused=None, name=None):

@@ -26,5 +26,6 @@ from . import (  # noqa: F401
     pipeline_ops,
     transformer_ops,
     decode_ops,
+    decoder_ops,
 )
 from . import infer_rules  # noqa: F401,E402  (static infer rules, after impls)

@@ -1,0 +1,268 @@
+"""Ops of a decoder-only language model with routed experts and a learned
+sparse attention: RMS norm, rotary positions, the indexer that selects each
+query's keys, grouped-query attention over that selection, and the share
+of a routed expert layer that the experts held here give.
+
+Each is a pure JAX function; gradients go through the generic vjp path
+(``ops/registry.py``) except where noted.  ``sparse_attention`` and
+``moe_experts`` count which path each call took at lowering
+(``ops.sparse_attention.calls{topk,seq,path}``, ``ops.moe.calls{held,
+routed,path}``, and ``...declined{why}`` for every fallback).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from .registry import register_grad, register_op
+
+INDEX_Q_BLOCK = 512
+
+
+def _count(name, **labels):
+    try:
+        from .. import observe
+
+        observe.registry().inc(name, labels={k: str(v)
+                                             for k, v in labels.items()})
+    except Exception:
+        pass  # accounting must never fail the trace it measures
+
+
+@register_op("rms_norm")
+def rms_norm_op(ctx):
+    """x * rsqrt(mean(x^2, last axis) + eps) * scale; statistics in float32
+    whatever the input's type.  Scale: [x.shape[-1]], so the same op is the
+    per-row norm ([B, T, D]) and the per-head one ([B, T, H, Dh])."""
+    x, scale = ctx.input("X"), ctx.input("Scale")
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True)
+                           + jnp.float32(ctx.attr("epsilon", 1e-6)))
+    return {"Y": (y * scale.astype(jnp.float32)).astype(x.dtype)}
+
+
+def rotary(x, theta):
+    """x: [B, T, H, D]; position t (the index along axis 1) rotates the
+    pair (i, i + D/2) by t * theta^(-2i/D): the rotate-half form."""
+    t, d = x.shape[1], x.shape[-1]
+    inv = jnp.float32(theta) ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[None, :, None]
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[None, :, None]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :d // 2], xf[..., d // 2:]
+    return (xf * cos + jnp.concatenate([-x2, x1], -1) * sin).astype(x.dtype)
+
+
+@register_op("rotary_embedding")
+def rotary_embedding_op(ctx):
+    return {"Out": rotary(ctx.input("X"), ctx.attr("theta", 10000.0))}
+
+
+def _order_key(x):
+    """float32 -> int32 whose order is the floats' (-0.0 below +0.0)."""
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(b < 0, b ^ jnp.int32(0x7FFFFFFF), b)
+
+
+def select_top_k(score, q0, topk):
+    """[bq, tk] int8: for the query at row r (position q0 + r) the ``topk``
+    keys ``s <= q0 + r`` of largest score, every such key while there are
+    no more than ``topk``; the lowest index wins a tie, as ``lax.top_k``
+    has it.  An exact selection without a sort: the k-th largest score of
+    each row by bisection on its bit pattern (32 counts), then, among the
+    keys that tie with it, the lowest indices by bisection on the index."""
+    bq, tk = score.shape
+    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, tk), 0)
+    kpos = jax.lax.broadcasted_iota(jnp.int32, (bq, tk), 1)
+    causal = qpos >= kpos
+    if topk >= tk:
+        return causal.astype(jnp.int8)
+    lowest = jnp.int32(-2 ** 31)
+    key = jnp.where(causal, _order_key(score.astype(jnp.float32)), lowest)
+    want = jnp.minimum(jnp.int32(topk), qpos[:, :1] + 1)         # [bq, 1]
+
+    def count(mask):
+        return jnp.sum(mask.astype(jnp.int32), axis=1, keepdims=True)
+
+    # largest thr with count(key >= thr) >= want, from the sign bit down
+    def value_bit(i, thr):
+        bit = jnp.left_shift(jnp.int32(1), jnp.int32(30) - i)
+        cand = thr + bit
+        return jnp.where(count(key >= cand) >= want, cand, thr)
+
+    thr = jnp.where(count(key >= 0) >= want, jnp.int32(0), lowest)
+    thr = jax.lax.fori_loop(0, 31, value_bit,
+                            jnp.broadcast_to(thr, (bq, 1)))
+    above = key > thr
+    ties = key == thr
+    need = want - count(above)                                   # >= 1
+    # smallest index bound with count(ties & kpos <= bound) >= need
+    bits = max(1, (tk - 1).bit_length())
+
+    def index_bit(i, bound):
+        bit = jnp.left_shift(jnp.int32(1), jnp.int32(bits - 1) - i)
+        cand = bound - bit
+        return jnp.where(count(ties & (kpos <= cand)) >= need, cand, bound)
+
+    bound = jax.lax.fori_loop(
+        0, bits, index_bit,
+        jnp.full((bq, 1), (1 << bits) - 1, jnp.int32))
+    return ((above | (ties & (kpos <= bound))) & causal).astype(jnp.int8)
+
+
+def index_select(x, wq, wk, ww, heads, topk, theta):
+    """The indexer of one layer and its top-k: [B, T, T] int8, 1 where
+    query t attends key s.  Scores are made in query tiles against the keys
+    up to the tile's end, contraction inputs in the AMP type with float32
+    accumulation: ``I[t,s] = sum_j w[t,j] relu(qI[t,j].kI[s]) / sqrt(dI)``."""
+    from ..fluid import amp
+
+    b, t, _ = x.shape
+    di = wk.shape[-1]
+    qi = rotary(amp.matmul(x, wq).reshape(b, t, heads, di), theta)
+    ki = rotary(amp.matmul(x, wk).reshape(b, t, 1, di), theta)[:, :, 0]
+    w = amp.matmul(x, ww).astype(jnp.float32) * jnp.float32(di ** -0.5)
+    bq = min(INDEX_Q_BLOCK, t)
+    rows = []
+    for q0 in range(0, t, bq):
+        q1 = min(q0 + bq, t)
+        dots = amp.einsum("bqjd,bsd->bqjs", qi[:, q0:q1], ki[:, :q1])
+        score = jnp.einsum("bqj,bqjs->bqs", w[:, q0:q1],
+                           jax.nn.relu(dots.astype(jnp.float32)))
+        sel = jax.vmap(lambda s: select_top_k(s, q0, topk))(score)
+        rows.append(jnp.pad(sel, ((0, 0), (0, 0), (0, t - q1))))
+    return jnp.concatenate(rows, axis=1)
+
+
+@register_op("sparse_indexer")
+def sparse_indexer_op(ctx):
+    sel = index_select(ctx.input("X"), ctx.input("WQ"), ctx.input("WK"),
+                       ctx.input("WW"), int(ctx.attr("num_heads")),
+                       int(ctx.attr("topk")), ctx.attr("theta", 10000.0))
+    return {"Sel": sel}
+
+
+@register_grad("sparse_indexer")
+def sparse_indexer_grad(ctx):
+    """The selection is piecewise constant in the indexer's inputs and
+    weights: under a loss that reads it only through the attention, their
+    gradients are exactly zero (the indexer's own alignment loss is not
+    built)."""
+    return {slot: jnp.zeros_like(ctx.input(slot[:-5]))
+            for slot in ctx.outputs_spec}
+
+
+def blocked_attention(q, k, v, sel, scale, block=512):
+    """The XLA path of ``sparse_attention``: query tiles against the keys
+    up to the tile's end, the selection as a mask, each tile a checkpoint so
+    that the backward holds one tile's [Hq, bq, T] scores at a time."""
+    from ..fluid import amp
+
+    b, hq, t, d = q.shape
+    hkv = k.shape[1]
+    qg = q.reshape(b, hkv, hq // hkv, t, d)
+
+    @jax.checkpoint
+    def tile(qt, kt, vt, keep):
+        s = amp.einsum("bgrqd,bgsd->bgrqs", qt, kt).astype(jnp.float32) \
+            * jnp.float32(scale)
+        s = jnp.where(keep[:, None, None], s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1)
+        return amp.einsum("bgrqs,bgsd->bgrqd", p.astype(vt.dtype), vt)
+
+    bq = min(block, t)
+    outs = []
+    for q0 in range(0, t, bq):
+        q1 = min(q0 + bq, t)
+        keep = (q0 + jnp.arange(q1 - q0))[:, None] >= jnp.arange(q1)[None]
+        keep = jnp.broadcast_to(keep[None], (b,) + keep.shape)
+        if sel is not None:
+            keep = keep & (sel[:, q0:q1, :q1] > 0)
+        outs.append(tile(qg[:, :, :, q0:q1], k[:, :, :q1], v[:, :, :q1],
+                         keep))
+    return jnp.concatenate(outs, axis=3).reshape(b, hq, t, d).astype(q.dtype)
+
+
+def _attention_path(ctx, q, k, sel, count):
+    """'pallas' where the flash gate is open and the kernels take the
+    operands, else 'xla'; counted where ``count``."""
+    from .attention_ops import _flash_decision
+    from . import pallas_sparse_flash as psf
+
+    path = "xla"
+    if _flash_decision(int(ctx.attr("flash", -1))):
+        why = psf.supported(q, k, sel)
+        if not why:
+            path = "pallas"
+        elif count:
+            _count("ops.sparse_attention.declined", why=why)
+    if count:
+        _count("ops.sparse_attention.calls", path=path,
+               topk=ctx.attr("topk", 0), seq=q.shape[2])
+    return path
+
+
+def _attention_operands(ctx):
+    q, k, v = ctx.input("Q"), ctx.input("K"), ctx.input("V")
+    sel = ctx.input("Sel") if ctx.has_input("Sel") else None
+    return q, k, v, sel, ctx.attr("scale", 0.0) or q.shape[-1] ** -0.5
+
+
+@register_op("sparse_attention", no_grad_inputs=("Sel",))
+def sparse_attention_op(ctx):
+    """Causal grouped-query attention, optionally over a per-query
+    selection.  Q: [B, Hq, T, D]; K, V: [B, Hkv, T, D]; Sel: [B, T, T] int8
+    or absent.  The Pallas kernels where the flash gate is open and they
+    take the operands, else the blocked XLA path.  Lse ([B, Hq, T, 1]
+    float32) is the kernels' log-sum-exp, kept for their backward; zeros on
+    the XLA path, whose backward is the generic vjp."""
+    from . import pallas_sparse_flash as psf
+
+    q, k, v, sel, scale = _attention_operands(ctx)
+    if _attention_path(ctx, q, k, sel, count=True) == "pallas":
+        out, lse = psf.forward(q, k, v, sel, scale)
+        return {"Out": out, "Lse": lse}
+    return {"Out": blocked_attention(q, k, v, sel, scale),
+            "Lse": jnp.zeros(q.shape[:3] + (1,), jnp.float32)}
+
+
+@register_grad("sparse_attention")
+def sparse_attention_grad(ctx):
+    """The dQ and dK/dV kernels from the forward's own Out and Lse: the
+    generic vjp would trace, and the chip would run, the forward kernel a
+    second time.  The XLA path keeps the generic vjp."""
+    from . import pallas_sparse_flash as psf
+    from . import registry
+
+    q, k, v, sel, scale = _attention_operands(ctx)
+    if _attention_path(ctx, q, k, sel, count=False) != "pallas":
+        return registry.run_grad_generic(
+            registry.get_op_def("sparse_attention"), ctx)
+    dq, dk, dv = psf.backward(q, k, v, sel, ctx.input("Out"),
+                              ctx.input("Lse"),
+                              ctx.input("Out@GRAD").astype(q.dtype), scale)
+    grads = {"Q@GRAD": dq, "K@GRAD": dk, "V@GRAD": dv}
+    return {s: g for s, g in grads.items() if s in ctx.outputs_spec}
+
+
+@register_op("moe_experts")
+def moe_experts_op(ctx):
+    from ..parallel import moe
+
+    w1 = ctx.input("W1")
+    routed = int(ctx.attr("num_routed"))
+    held, offset = int(ctx.attr("experts_held")), int(ctx.attr(
+        "expert_offset", 0))
+    if w1.shape[0] != held or ctx.input("RouterW").shape[-1] != routed \
+            or offset < 0 or offset + held > routed:
+        raise ValueError(
+            f"moe_experts: {w1.shape[0]} expert weights and a router "
+            f"{ctx.input('RouterW').shape[-1]} wide for experts_held="
+            f"{held}, expert_offset={offset}, num_routed={routed}")
+    _count("ops.moe.calls", held=held, routed=routed, path="ragged_dot")
+    return {"Out": moe.routed_experts(
+        ctx.input("X"), ctx.input("RouterW"), w1, ctx.input("W3"),
+        ctx.input("W2"), top_k=int(ctx.attr("top_k")),
+        expert_offset=offset, norm_topk=bool(ctx.attr("norm_topk", True)))}

@@ -472,3 +472,99 @@ def _infer_param_update(op, ins):
 for _t in ("sgd", "momentum", "adam", "adamax", "adagrad", "rmsprop",
            "decayed_adagrad", "ftrl", "lars_momentum"):
     register_infer(_t)(_infer_param_update)
+
+
+# -- the decoder path (ops/decoder_ops.py) ---------------------------------
+
+@register_infer("rms_norm")
+def infer_rms_norm(op, ins):
+    x, scale = _in(ins, "X"), _in(ins, "Scale")
+    if x is not None and scale is not None \
+            and tuple(scale[0]) != (x[0][-1],):
+        raise InferMismatch(
+            f"rms_norm: scale {_names(op, 'Scale')} {list(scale[0])} must "
+            f"be [{x[0][-1]}], the last dim of {_names(op, 'X')} "
+            f"{list(x[0])}")
+    return {"Y": [x]}
+
+
+@register_infer("rotary_embedding")
+def infer_rotary_embedding(op, ins):
+    x = _in(ins, "X")
+    if x is not None and (len(x[0]) != 4 or x[0][-1] % 2):
+        raise InferMismatch(
+            f"rotary_embedding: {_names(op, 'X')} {list(x[0])} must be "
+            f"[batch, positions, heads, an even head width]")
+    return {"Out": [x]}
+
+
+@register_infer("sparse_indexer")
+def infer_sparse_indexer(op, ins):
+    """Sel is [B, T, T] int8: an explicit rule so the verifier never
+    abstractly evaluates T/512 tiles of bisection."""
+    x = _in(ins, "X")
+    wq, wk, ww = _in(ins, "WQ"), _in(ins, "WK"), _in(ins, "WW")
+    heads = int(op.attr("num_heads", 0))
+    if x is None:
+        return None
+    if len(x[0]) != 3:
+        raise InferMismatch(
+            f"sparse_indexer: {_names(op, 'X')} {list(x[0])} must be "
+            f"[batch, positions, hidden]")
+    if wq is not None and wk is not None and ww is not None and (
+            wq[0][1] != heads * wk[0][1] or ww[0][1] != heads
+            or {wq[0][0], wk[0][0], ww[0][0]} != {x[0][-1]}):
+        raise InferMismatch(
+            f"sparse_indexer: weights {list(wq[0])}, {list(wk[0])}, "
+            f"{list(ww[0])} do not make {heads} query heads, one key head "
+            f"of the same width and {heads} head weights over hidden "
+            f"{x[0][-1]}")
+    return {"Sel": [((x[0][0], x[0][1], x[0][1]), "int8")]}
+
+
+@register_infer("sparse_attention")
+def infer_sparse_attention(op, ins):
+    """Out mirrors Q (never evaluated abstractly, as ring_attention's);
+    the grouped heads and the selection's shape are checked here, where
+    the vars have names."""
+    q, k, v, sel = (_in(ins, s) for s in ("Q", "K", "V", "Sel"))
+    lse = None if q is None else (tuple(q[0][:3]) + (1,), "float32")
+    if q is None or k is None:
+        return {"Out": [q], "Lse": [lse]}
+    if len(q[0]) != 4 or len(k[0]) != 4 or (v is not None
+                                            and tuple(v[0]) != tuple(k[0])):
+        raise InferMismatch(
+            f"sparse_attention: {_names(op, 'Q')} {list(q[0])}, "
+            f"{_names(op, 'K')} {list(k[0])} and V must be [B, H, T, D] "
+            f"with K and V alike")
+    if q[0][1] % k[0][1] or q[0][2:] != k[0][2:]:
+        raise InferMismatch(
+            f"sparse_attention: {q[0][1]} query heads of {_names(op, 'Q')} "
+            f"{list(q[0])} do not group over the {k[0][1]} key-value heads "
+            f"of {_names(op, 'K')} {list(k[0])} at equal length and width")
+    if sel is not None and (tuple(sel[0][1:]) != (q[0][2], q[0][2])
+                            or sel[1] not in _INT_DTYPES):
+        raise InferMismatch(
+            f"sparse_attention: selection {_names(op, 'Sel')} "
+            f"{list(sel[0])} {sel[1]} must be an integer "
+            f"[B, {q[0][2]}, {q[0][2]}] mask")
+    return {"Out": [q], "Lse": [lse]}
+
+
+@register_infer("moe_experts")
+def infer_moe_experts(op, ins):
+    x, r, w1 = _in(ins, "X"), _in(ins, "RouterW"), _in(ins, "W1")
+    held, routed = int(op.attr("experts_held", 0)), int(
+        op.attr("num_routed", 0))
+    offset, top_k = int(op.attr("expert_offset", 0)), int(op.attr("top_k", 0))
+    if not 0 < top_k <= routed or offset < 0 or offset + held > routed:
+        raise InferMismatch(
+            f"moe_experts: experts [{offset}, {offset + held}) and top_k "
+            f"{top_k} do not fit a router over {routed} experts")
+    if r is not None and w1 is not None and (r[0][-1] != routed
+                                             or w1[0][0] != held):
+        raise InferMismatch(
+            f"moe_experts: router {_names(op, 'RouterW')} {list(r[0])} and "
+            f"expert weights {_names(op, 'W1')} {list(w1[0])} must be "
+            f"{routed} wide and {held} experts")
+    return {"Out": [x]}

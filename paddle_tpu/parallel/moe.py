@@ -97,3 +97,103 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, top_k: int = 2,
     expert_out = jnp.einsum("ech,ehd->ecd", h, w2) + b2[:, None, :]
     y = jnp.einsum("nec,ecd->nd", combine.astype(dtype), expert_out)
     return y.reshape(orig_shape), aux.astype(dtype)
+
+
+def route_top_k(x, router_w, top_k: int, norm_topk: bool = True):
+    """(weights [N, k] float32, experts [N, k] int32) of the published
+    router: softmax over ALL ``router_w.shape[-1]`` routed experts in
+    float32 (at the highest matmul precision: a logit's last bits decide
+    the choice), the top k, renormalized over the chosen when
+    ``norm_topk``."""
+    logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    vals, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if norm_topk:
+        vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+    return vals, idx.astype(jnp.int32)
+
+
+def routed_experts(x, router_w, w1, w3, w2, top_k: int,
+                   expert_offset: int = 0, norm_topk: bool = True):
+    """The share of a routed expert layer that the experts held here give.
+
+    x: [..., D]; router_w: [D, R] over all R routed experts; w1, w3:
+    [E, D, F] and w2: [E, F, D], the E experts ``[expert_offset,
+    expert_offset + E)`` with SiLU-gated feed-forwards and no bias.  Every
+    token routes over all R; an assignment to an expert held here is
+    computed, one to an absent expert is left out (its chip adds it in a
+    deployment; nothing stands in for it here).  There is no capacity and
+    nothing is dropped: the ``N * top_k`` assignments are sorted by expert
+    (absent ones last, as zero rows) and multiplied as grouped products
+    (``lax.ragged_dot``), so a step in which every token chose held experts
+    only is as right as any other.
+    """
+    from ..fluid import amp
+
+    shape = x.shape
+    e = w1.shape[0]
+
+    # a checkpoint: the backward makes the sorted rows and the experts'
+    # hidden activations again instead of keeping N * top_k rows of them
+    # per layer (1 GB a layer at 8,192 tokens x 8 choices)
+    @jax.checkpoint
+    def share(xt, router_w, w1, w3, w2):
+        n = xt.shape[0]
+        vals, idx = route_top_k(xt, router_w, top_k, norm_topk)
+        local = idx - jnp.int32(expert_offset)
+        held = (local >= 0) & (local < e)
+        group = jnp.where(held, local, e).reshape(-1)      # absent: last
+        # where each assignment lands once sorted by expert: its group's
+        # first row plus how many earlier assignments chose the same group
+        # (a cumulative count; no scatter).  int32 throughout: the package
+        # runs jax in x64 mode, where a sum of int32 is int64, which the
+        # TPU's grouped product refuses
+        i32 = jnp.int32
+        chose = (group[:, None] == jnp.arange(e + 1, dtype=i32)
+                 ).astype(i32)                             # [N*k, E+1]
+        counts = jnp.sum(chose, axis=0, dtype=i32)
+        first = jnp.cumsum(counts, dtype=i32) - counts
+        back = jnp.sum(chose * (first[None, :] - 1
+                                + jnp.cumsum(chose, axis=0, dtype=i32)),
+                       axis=1, dtype=i32)
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        live = (jnp.arange(order.shape[0], dtype=i32) < first[e])[:, None]
+
+        # XLA's grouped product on the TPU leaves the rows outside every
+        # group UNWRITTEN, in its results and in the cotangents it hands
+        # back (NaN gradients on the chip; the CPU zero-fills them).  So
+        # what a product returns for a row that holds no held assignment
+        # is SELECTED away before anything reads it, never multiplied by
+        # zero, and ``where``'s own vjp does the same to the cotangent on
+        # its way back: xs and the two hidden products by ``live``, the
+        # last product where its rows are gathered, by ``held``.  Each
+        # select sits in a fusion that reads the rows anyway.  Right
+        # whatever ``sizes`` covers.
+        def live_rows(rows):
+            return jnp.where(live, rows, 0)
+
+        # DEBT (ROADMAP S11, PERF.md section 7): the absent experts'
+        # assignments ride along as zero rows at the end of the LAST held
+        # group, so all N * top_k rows are gathered, multiplied and
+        # scattered back: 8 of every 9 where an eighth of the experts is
+        # held, about 200 ms of a 414 ms step at 8,192 tokens.  Without
+        # this line (sizes = counts[:e]) the products skip the row tiles
+        # past the last group and a step's time follows the router, which
+        # drifts toward the experts held as it trains without the absent
+        # ones; nothing else depends on it.
+        sizes = counts[:e].at[e - 1].add(counts[e])
+        xs, a1, a3, a2, _ = amp.cast_operands(
+            jnp.take(xt, order // top_k, axis=0), w1, w3, w2)
+        xs = live_rows(xs)
+        h = jax.nn.silu(live_rows(lax.ragged_dot(xs, a1, sizes))
+                        .astype(jnp.float32)) \
+            * live_rows(lax.ragged_dot(xs, a3, sizes)).astype(jnp.float32)
+        ys = lax.ragged_dot(h.astype(xs.dtype), a2, sizes)  # [N*k, D]
+        # back to assignment order, weighted, summed over a token's choices
+        ys = jnp.where(held[..., None],
+                       jnp.take(ys, back, axis=0).reshape(n, top_k, -1), 0)
+        gate = jnp.where(held, vals, 0.0)
+        return jnp.einsum("nk,nkd->nd", gate, ys.astype(jnp.float32))
+
+    y = share(x.reshape((-1, shape[-1])), router_w, w1, w3, w2)
+    return y.astype(x.dtype).reshape(shape)

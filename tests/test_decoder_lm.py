@@ -1,0 +1,423 @@
+"""The decoder path (ops/decoder_ops.py, ops/pallas_sparse_flash.py,
+parallel/moe.routed_experts, models/decoder_lm.py) against the plain
+float32 reference of ``chipbench/configs/keye_vl_2_0_30b_a3b`` at a tiny
+size, on the CPU, with seeded random weights."""
+
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu.fluid as fluid
+from paddle_tpu.fluid import layers
+from paddle_tpu.ops import decoder_ops, registry
+from paddle_tpu.ops import pallas_sparse_flash as psf
+from paddle_tpu.parallel import moe
+from paddle_tpu.parallel.ring_attention import full_attention
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from chipbench import plugins  # noqa: E402
+
+CONFIG = "configs/keye_vl_2_0_30b_a3b"
+BUILD = plugins.load(CONFIG, "build")
+REF = plugins.load(CONFIG, "reference")
+
+
+def tiny_sizes(**over):
+    sizes = json.load(open(os.path.join(ROOT, "chipbench", CONFIG,
+                                        "config.json")))
+    return {**sizes, **sizes["tiny"], **over}
+
+
+def counters(prefix):
+    return {k: v for k, v in fluid.profiler.counters().items()
+            if k.startswith(prefix)}
+
+
+# -- (a) the program against the reference ------------------------------
+
+@pytest.mark.parametrize("flash", ["xla", "pallas"])
+def test_program_equals_the_reference_where_the_selection_cuts(
+        monkeypatch, flash):
+    """Loss and every gradient through ``fluid.Executor`` with
+    ``optimizer.minimize``, ``seq_len`` four times ``topk``; the indexer's
+    three weights get a gradient of exactly zero on both sides."""
+    monkeypatch.setenv("PADDLE_TPU_FLASH", "1" if flash == "pallas" else "0")
+    sizes = tiny_sizes()
+    assert sizes["seq_len"] >= 4 * sizes["sa_config"]["topk"]
+    assert sizes["num_experts"] < sizes["published"]["num_experts"]
+    built = BUILD.build(fluid, sizes)
+    main = fluid.default_main_program()
+    names = BUILD.trainable_names(main)
+    spec = REF.param_spec(sizes)
+    assert [n for n, _, _ in spec] == names
+    exe = fluid.Executor(fluid.TPUPlace())
+    exe.run(fluid.default_startup_program())
+    scope = fluid.global_scope()
+    weights = REF.init_params(5, sizes)
+    for (_, shape, _), name, w in zip(spec, names, weights):
+        assert tuple(np.shape(scope.get(name))) == tuple(shape), name
+        scope.set(name, jnp.array(w))
+    feed = BUILD.make_feed(sizes, 2, np.random.RandomState(3))
+    outs = exe.run(main, feed=feed, fetch_list=[built["loss"]]
+                   + [n + "@GRAD" for n in names])
+    ref_loss, ref_grads = REF.loss_and_grads(weights, feed, sizes)
+    assert float(outs[0].reshape(-1)[0]) == pytest.approx(float(ref_loss),
+                                                          rel=1e-5)
+    for name, g, r in zip(names, outs[1:], ref_grads):
+        g = np.asarray(g).reshape(r.shape)
+        if "_idx_" in name:
+            assert not np.any(g) and not np.any(np.asarray(r)), name
+        else:
+            assert np.abs(g - r).max() <= 2e-4 * np.abs(r).max() + 1e-7, name
+    # which path each layer took: once for its forward; the generic vjp
+    # (the XLA path's, and the expert layer's) traces the forward again
+    layers_ = sizes["num_hidden_layers"]
+    (key, n), = counters("ops.sparse_attention.calls").items()
+    assert f'path="{flash}"' in key and 'topk="16"' in key \
+        and 'seq="64"' in key
+    assert n == (1 if flash == "pallas" else 2) * layers_
+    (key, n), = counters("ops.moe.calls").items()
+    assert 'held="4"' in key and 'routed="8"' in key \
+        and 'path="ragged_dot"' in key and n == 2 * layers_
+    assert not counters("ops.sparse_attention.declined")
+
+
+# -- (b) topk >= seq_len is dense causal attention ------------------------
+
+@pytest.mark.parametrize("flash", [False, True])
+def test_selecting_every_key_equals_causal_attention_through_ring_attention(
+        flash):
+    b, hq, hkv, t, d, hid = 2, 4, 2, 32, 16, 24
+    rng = np.random.RandomState(0)
+    x = layers.data(name="x", shape=[t, hid], dtype="float32")
+    q = layers.data(name="q", shape=[hq, t, d], dtype="float32")
+    k = layers.data(name="k", shape=[hkv, t, d], dtype="float32")
+    v = layers.data(name="v", shape=[hkv, t, d], dtype="float32")
+    kr = layers.data(name="kr", shape=[hq, t, d], dtype="float32")
+    vr = layers.data(name="vr", shape=[hq, t, d], dtype="float32")
+    sel = layers.sparse_indexer(x, num_heads=2, head_dim=8, topk=t,
+                                name="idx")
+    new = layers.sparse_attention(q, k, v, selection=sel, flash=flash)
+    plain = layers.sparse_attention(q, k, v, flash=flash)
+    old = layers.ring_attention(q, kr, vr, causal=True, flash=False)
+    exe = fluid.Executor(fluid.TPUPlace())
+    exe.run(fluid.default_startup_program())
+    feed = {"x": rng.randn(b, t, hid).astype("float32"),
+            "q": rng.randn(b, hq, t, d).astype("float32"),
+            "k": rng.randn(b, hkv, t, d).astype("float32"),
+            "v": rng.randn(b, hkv, t, d).astype("float32")}
+    feed["kr"] = np.repeat(feed["k"], hq // hkv, axis=1)
+    feed["vr"] = np.repeat(feed["v"], hq // hkv, axis=1)
+    s, a, p, o = exe.run(feed=feed, fetch_list=[sel, new, plain, old])
+    assert np.array_equal(np.asarray(s)[0], np.tril(np.ones((t, t))))
+    np.testing.assert_allclose(a, o, atol=2e-6)
+    np.testing.assert_allclose(p, o, atol=2e-6)
+
+
+# -- (c) the share test ---------------------------------------------------
+
+def moe_weights(rng, n, d, f, routed):
+    x = jnp.asarray(rng.randn(n, d), jnp.float32)
+    wr = jnp.asarray(rng.randn(d, routed), jnp.float32)
+    w1, w3 = (jnp.asarray(0.3 * rng.randn(routed, d, f), jnp.float32)
+              for _ in range(2))
+    w2 = jnp.asarray(0.3 * rng.randn(routed, f, d), jnp.float32)
+    return x, wr, w1, w3, w2
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer():
+    """128 routed experts, 8 per token, 16 held by each of 8 chips
+    (``expert_offset`` 0, 16, ..., 112): the parts add up to what the
+    reference gives for the whole layer with all 128 experts."""
+    routed, held, k = 128, 16, 8
+    x, wr, w1, w3, w2 = moe_weights(np.random.RandomState(1), 48, 16, 8,
+                                    routed)
+    whole = REF.moe_layer(x, wr, w1, w3, w2, k, 0)
+    total = 0.0
+    for off in range(0, routed, held):
+        part = moe.routed_experts(
+            x, wr, w1[off:off + held], w3[off:off + held],
+            w2[off:off + held], top_k=k, expert_offset=off)
+        mine = REF.moe_layer(x, wr, w1[off:off + held], w3[off:off + held],
+                             w2[off:off + held], k, off)
+        np.testing.assert_allclose(part, mine, atol=1e-5)
+        total = total + part
+    np.testing.assert_allclose(total, whole, atol=2e-5)
+    assert float(jnp.abs(whole).max()) > 0.1
+
+
+# -- (d) no assignment is lost when every token picks held experts --------
+
+def test_every_token_choosing_held_experts_only_loses_no_assignment():
+    routed, held, k, off, n = 16, 4, 4, 8, 40
+    rng = np.random.RandomState(2)
+    x, wr, w1, w3, w2 = moe_weights(rng, n, 12, 8, routed)
+    # a constant feature whose router row lifts the held experts' logits
+    # far above the others: every token's 4 choices are the 4 held
+    x = x.at[:, 0].set(1.0)
+    wr = wr.at[0].set(jnp.where((jnp.arange(routed) >= off)
+                                & (jnp.arange(routed) < off + held),
+                                60.0, 0.0))
+    _, idx = moe.route_top_k(x, wr, k)
+    assert bool(jnp.all((idx >= off) & (idx < off + held)))   # n*k rows
+    w1, w3, w2 = (w[off:off + held] for w in (w1, w3, w2))
+
+    def program(x, w1, w2):
+        return jnp.sum(moe.routed_experts(x, wr, w1, w3, w2, top_k=k,
+                                          expert_offset=off) ** 2)
+
+    def reference(x, w1, w2):
+        return jnp.sum(REF.moe_layer(x, wr, w1, w3, w2, k, off) ** 2)
+
+    np.testing.assert_allclose(program(x, w1, w2), reference(x, w1, w2),
+                               rtol=1e-5)
+    for g, r in zip(jax.grad(program, (0, 1, 2))(x, w1, w2),
+                    jax.grad(reference, (0, 1, 2))(x, w1, w2)):
+        np.testing.assert_allclose(g, r, atol=1e-4 * float(jnp.abs(r).max()))
+
+
+def test_every_row_of_a_grouped_product_lies_in_a_group(monkeypatch):
+    """On the TPU XLA's grouped product leaves rows outside every group
+    unwritten, results and cotangents alike (the chip's first run of PR 30
+    read NaN gradients from them), and skips their tiles.  Today the
+    group sizes cover all ``N * top_k`` rows (absent experts' assignments
+    ride as zero rows in the last group: debt, ROADMAP S11); here every
+    product poisons what lies outside, and the sizes are int32."""
+    routed, held, k, n = 8, 2, 2, 24
+    x, wr, w1, w3, w2 = moe_weights(np.random.RandomState(3), n, 8, 4,
+                                    routed)
+    w1, w3, w2 = (w[:held] for w in (w1, w3, w2))
+    plain = jax.lax.ragged_dot
+    seen = []
+
+    def poisoned(lhs, rhs, sizes, **kw):
+        rows = jnp.arange(lhs.shape[0])[:, None] < jnp.sum(sizes)
+        seen.append((lhs.shape[0], sizes.dtype))
+        return jnp.where(rows, plain(lhs, rhs, sizes, **kw), jnp.nan)
+
+    monkeypatch.setattr(moe.lax, "ragged_dot", poisoned)
+    y = moe.routed_experts(x, wr, w1, w3, w2, top_k=k)
+    np.testing.assert_allclose(y, REF.moe_layer(x, wr, w1, w3, w2, k, 0),
+                               atol=1e-5)
+    # a row outside the groups would have made the result NaN
+    assert seen == [(n * k, jnp.int32)] * 3      # the TPU refuses int64
+    _, idx = moe.route_top_k(x, wr, k)
+    assert int(jnp.sum(idx < held)) < n * k      # some rows ARE absent
+
+
+def test_rows_without_a_held_assignment_poison_nothing(monkeypatch):
+    """The cure of the unwritten rows does not rest on the group sizes
+    covering them: here every product returns NaN in the rows that hold no
+    held assignment, and hands NaN back as their cotangent, as the chip
+    would were the sizes to stop at the last held assignment.  Result and
+    every gradient still equal the reference's."""
+    routed, held, k, n = 8, 2, 2, 24
+    x, wr, w1, w3, w2 = moe_weights(np.random.RandomState(4), n, 8, 4,
+                                    routed)
+    w1, w3, w2 = (w[:held] for w in (w1, w3, w2))
+    _, idx = moe.route_top_k(x, wr, k)
+    live = int(jnp.sum(idx < held))
+    assert 0 < live < n * k
+    plain = jax.lax.ragged_dot
+
+    def poisoned(lhs, rhs, sizes, **kw):
+        dead = np.arange(lhs.shape[0])[:, None] >= live     # a constant
+
+        @jax.custom_vjp
+        def product(lhs, rhs, sizes):
+            return jnp.where(dead, jnp.nan, plain(lhs, rhs, sizes, **kw))
+
+        def fwd(lhs, rhs, sizes):
+            return product(lhs, rhs, sizes), (lhs, rhs, sizes)
+
+        def bwd(kept, g):
+            lhs, rhs, sizes = kept
+            dl, dr = jax.vjp(lambda a, b: plain(a, b, sizes, **kw),
+                             lhs, rhs)[1](g)
+            return jnp.where(dead, jnp.nan, dl), dr, None
+
+        product.defvjp(fwd, bwd)
+        return product(lhs, rhs, sizes)
+
+    def reference(x, w1, w3, w2):
+        return jnp.sum(REF.moe_layer(x, wr, w1, w3, w2, k, 0) ** 2)
+
+    want = jax.value_and_grad(reference, (0, 1, 2, 3))(x, w1, w3, w2)
+    monkeypatch.setattr(moe.lax, "ragged_dot", poisoned)
+
+    def program(x, w1, w3, w2):
+        return jnp.sum(moe.routed_experts(x, wr, w1, w3, w2, top_k=k) ** 2)
+
+    got = jax.value_and_grad(program, (0, 1, 2, 3))(x, w1, w3, w2)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for g, r in zip(got[1], want[1]):
+        np.testing.assert_allclose(g, r, atol=1e-4 * float(jnp.abs(r).max()))
+
+
+def test_moe_experts_op_refuses_a_share_outside_the_router():
+    ctx = registry.ExecContext(
+        "moe_experts",
+        {"X": [jnp.zeros((4, 8))], "RouterW": [jnp.zeros((8, 16))],
+         "W1": [jnp.zeros((4, 8, 2))], "W3": [jnp.zeros((4, 8, 2))],
+         "W2": [jnp.zeros((4, 2, 8))]}, {"Out": ["y"]},
+        {"num_routed": 16, "experts_held": 4, "expert_offset": 14,
+         "top_k": 2})
+    with pytest.raises(ValueError, match="expert_offset=14"):
+        registry.get_op_def("moe_experts").fn(ctx)
+
+
+# -- (e) grouped-query flash against full_attention -----------------------
+
+def dense(q, k, v, sel):
+    g = q.shape[1] // k.shape[1]
+    t = q.shape[2]
+    bias = None
+    if sel is not None:
+        bias = jnp.where(sel > 0, 0.0, -jnp.inf)[:, None]
+    kr, vr = jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
+    if bias is None:
+        return full_attention(q, kr, vr, causal=True)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, kr) * q.shape[-1] ** -0.5 + bias
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), vr)
+
+
+@pytest.mark.parametrize("selected", [False, True])
+@pytest.mark.parametrize("block", [64, 16])
+def test_grouped_query_flash_forward_and_backward_at_head_width_128(
+        monkeypatch, selected, block):
+    """Interpreted; 8 query heads over 2 key-value heads of width 128.
+    With a selection, some rows have no selected key in a whole tile."""
+    monkeypatch.setattr(psf, "BLOCK", block)
+    b, hq, hkv, t, d = 1, 8, 2, 64, 128
+    rng = np.random.RandomState(4)
+    q, k, v = (jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
+               for h in (hq, hkv, hkv))
+    sel = None
+    if selected:
+        keep = (rng.rand(b, t, t) < 0.3) | np.eye(t, dtype=bool)
+        keep[:, 40:, :16] = False            # an empty first tile
+        sel = jnp.asarray(np.tril(keep).astype(np.int8))
+    w = jnp.cos(jnp.arange(d, dtype=jnp.float32))
+
+    def kernel(q, k, v):
+        return jnp.sum(psf.sparse_flash_attention(q, k, v, sel, None, True)
+                       * w)
+
+    def plain(q, k, v):
+        return jnp.sum(dense(q, k, v, sel) * w)
+
+    np.testing.assert_allclose(
+        psf.sparse_flash_attention(q, k, v, sel, None, True),
+        dense(q, k, v, sel), atol=2e-5)
+    for g, r in zip(jax.grad(kernel, (0, 1, 2))(q, k, v),
+                    jax.grad(plain, (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(g, r, atol=2e-4)
+    # the blocked XLA path is the same function
+    np.testing.assert_allclose(
+        decoder_ops.blocked_attention(q, k, v, sel, d ** -0.5, block=block),
+        dense(q, k, v, sel), atol=2e-5)
+
+
+def test_operands_the_kernels_do_not_take_are_declined_with_a_reason(
+        monkeypatch):
+    monkeypatch.setenv("PADDLE_TPU_FLASH", "1")
+    q = jnp.ones((1, 4, 12, 8))          # 12 positions: no tile of 8 rows
+    k = jnp.ones((1, 2, 12, 8))
+    ctx = registry.ExecContext("sparse_attention",
+                               {"Q": [q], "K": [k], "V": [k]},
+                               {"Out": ["o"]}, {"topk": 0})
+    got = registry.get_op_def("sparse_attention").fn(ctx)
+    out = got["Out"]
+    assert got["Lse"].shape == (1, 4, 12, 1)
+    np.testing.assert_allclose(out, dense(q, k, k, None), atol=1e-6)
+    assert counters("ops.sparse_attention.declined") == {
+        'ops.sparse_attention.declined{why="ragged"}': 1}
+    (key, _), = counters("ops.sparse_attention.calls").items()
+    assert 'path="xla"' in key
+
+
+# -- the selection --------------------------------------------------------
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_select_top_k_is_lax_top_k_with_its_tie_break(seed):
+    """Scores with a heavy atom at zero (the indexer's relu makes one): the
+    bisection picks exactly what ``lax.top_k`` picks, lowest index first."""
+    rng = np.random.RandomState(seed)
+    bq, tk, topk, q0 = 16, 64, 8, 48
+    score = rng.randn(bq, tk).astype(np.float32)
+    score[rng.rand(bq, tk) < 0.5] = 0.0
+    got = np.asarray(decoder_ops.select_top_k(jnp.asarray(score), q0, topk))
+    causal = (q0 + np.arange(bq))[:, None] >= np.arange(tk)[None]
+    vals, idx = jax.lax.top_k(jnp.where(causal, score, -jnp.inf), topk)
+    want = np.zeros((bq, tk), np.int8)
+    for r in range(bq):
+        want[r, np.asarray(idx[r])[np.asarray(vals[r]) > -np.inf]] = 1
+    assert np.array_equal(got, want)
+    assert got.sum(1).tolist() == [topk] * bq
+
+
+def test_selection_of_the_program_is_the_references():
+    sizes = tiny_sizes()
+    c = REF._dims(sizes)
+    rng = np.random.RandomState(7)
+    t, d = sizes["seq_len"], sizes["hidden_size"]
+    x = jnp.asarray(rng.randn(1, t, d), jnp.float32)
+    wq, wk, ww = (jnp.asarray(0.1 * rng.randn(d, n), jnp.float32)
+                  for n in (c["hi"] * c["di"], c["di"], c["hi"]))
+    got = decoder_ops.index_select(x, wq, wk, ww, c["hi"], c["topk"],
+                                   c["theta"])
+    qi = REF.rope((x[0] @ wq).reshape(t, c["hi"], c["di"]), c["theta"])
+    ki = REF.rope((x[0] @ wk).reshape(t, 1, c["di"]), c["theta"])[:, 0]
+    want = REF.selection(qi, ki, x[0] @ ww, c["topk"], 0)
+    assert np.array_equal(np.asarray(got[0]) > 0, np.asarray(want))
+    assert np.asarray(got[0]).sum(1).tolist() == \
+        [min(i + 1, c["topk"]) for i in range(t)]
+
+
+# -- infer rules ------------------------------------------------------------
+
+def test_infer_rules_name_what_is_wrong():
+    from paddle_tpu import analysis
+
+    q = layers.data(name="q", shape=[4, 16, 8], dtype="float32")
+    k = layers.data(name="k", shape=[3, 16, 8], dtype="float32")
+    out = layers.sparse_attention(q, k, k)
+    report = analysis.verify_program(fluid.default_main_program(),
+                                     fetch_list=[out])
+    assert any("do not group over the 3 key-value heads" in d.message
+               for d in report.errors), report.format()
+
+
+def test_infer_rules_give_the_shapes_of_the_new_ops():
+    from paddle_tpu.ops.registry import get_infer_rule
+
+    class Op:
+        def __init__(self, **attrs):
+            self.attrs, self.inputs, self.type = attrs, {}, "t"
+
+        def attr(self, name, default=None):
+            return self.attrs.get(name, default)
+
+    x = ((2, 16, 32), "float32")
+    out = get_infer_rule("sparse_indexer")(
+        Op(num_heads=4, topk=8), {"X": [x], "WQ": [((32, 64), "float32")],
+                                  "WK": [((32, 16), "float32")],
+                                  "WW": [((32, 4), "float32")]})
+    assert out == {"Sel": [((2, 16, 16), "int8")]}
+    assert get_infer_rule("rms_norm")(
+        Op(), {"X": [x], "Scale": [((32,), "float32")]}) == {"Y": [x]}
+    assert get_infer_rule("moe_experts")(
+        Op(num_routed=8, experts_held=4, expert_offset=4, top_k=2),
+        {"X": [x], "RouterW": [((32, 8), "float32")],
+         "W1": [((4, 32, 8), "float32")]}) == {"Out": [x]}
+    with pytest.raises(registry.InferMismatch, match="do not fit a router"):
+        get_infer_rule("moe_experts")(
+            Op(num_routed=8, experts_held=4, expert_offset=6, top_k=2), {})

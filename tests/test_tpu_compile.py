@@ -32,7 +32,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 import paddle_tpu  # noqa: F401  (x64 mode on, as every user has it)
-from paddle_tpu.ops import pallas_flash, pallas_fused, pallas_paged, registry
+from paddle_tpu.ops import (pallas_flash, pallas_fused, pallas_paged,
+                            pallas_sparse_flash, registry)
 
 B, H, T, D = 64, 8, 256, 64          # attention: [batch, heads, len, d_head]
 R, V = B * T, 30000                  # loss head: [batch*len, vocab]
@@ -126,8 +127,28 @@ def _paged():
                 ((s, 1, n * ps), F32)]
 
 
+def _sparse_flash(selected):
+    """The decoder cell's attention (``keye_vl_2_0_30b_a3b``: one sequence
+    of 8,192 tokens, 32 query heads over 4 key-value heads of width 128,
+    bf16, an int8 selection), forward and the two backward kernels."""
+    t = 8192
+
+    def fn(q, k, v, sel):
+        def loss(q, k, v):
+            return pallas_sparse_flash.sparse_flash_attention(
+                q, k, v, sel if selected else None, None,
+                False).astype(F32).sum()
+
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    kv = ((1, 4, t, 128), BF16)
+    return fn, [((1, 32, t, 128), BF16), kv, kv, ((1, t, t), jnp.int8)]
+
+
 #: name -> (builder, number of ``tpu_custom_call`` the compiled text holds)
 CASES = {
+    "sparse_flash_selected": (lambda: _sparse_flash(True), 3),
+    "sparse_flash_causal": (lambda: _sparse_flash(False), 3),
     "flash_fwd_causal": (lambda: _flash(True, False), 1),     # decoder self
     "flash_fwd_key_bias": (lambda: _flash(False, True), 1),   # encoder/cross
     "flash_bwd_causal": (lambda: _flash_bwd(True, False), 3),
@@ -189,6 +210,49 @@ def test_momentum_op_asks_for_no_relayout(topo, monkeypatch, shape, sweeps):
         assert "T(4,128)" not in text
         five = 5 * 4 * math.prod(shape)
         assert compiled.cost_analysis()["bytes accessed"] < 10 * five
+
+
+def _adam_op(p, g, m1, m2, lr, b1p, b2p):
+    """The ``adam`` OP as the executor calls it, not the bare kernel."""
+    ctx = registry.ExecContext(
+        "adam",
+        {"Param": [p], "Grad": [g], "Moment1": [m1], "Moment2": [m2],
+         "LearningRate": [lr], "Beta1Pow": [b1p], "Beta2Pow": [b2p]},
+        {"ParamOut": ["w"], "Moment1Out": ["w_m1"], "Moment2Out": ["w_m2"],
+         "Beta1PowOut": ["b1p"], "Beta2PowOut": ["b2p"]},
+        {"beta1": 0.9, "beta2": 0.95, "epsilon": 1e-8})
+    out = registry.get_op_def("adam").fn(ctx)
+    return out["ParamOut"], out["Moment1Out"], out["Moment2Out"]
+
+
+@pytest.mark.parametrize("shape,sweeps", [((16, 2048, 768), 1),
+                                          ((2048, 18992), 0)])
+def test_adam_op_asks_for_no_relayout(topo, monkeypatch, shape, sweeps):
+    """The optimizer tail of the decoder cell, without a chip.  The stacked
+    expert weight ``[16, 2048, 768]`` collapses to ``[32768, 768]`` as a
+    view (768 lanes, 2048 sublanes a slab) and keeps its Pallas sweep; the
+    untied head ``[2048, 18992]`` has a ragged last dim (18992 = 148 * 128
+    + 48), so its update is XLA's, in place.  Neither asks for a copy into
+    another layout: about the seven tensors' bytes are accessed (param,
+    grad and two moments read; param and two moments written)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    chip = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct(shape, F32, sharding=chip)
+    one = jax.ShapeDtypeStruct((1,), F32, sharding=chip)
+    compiled = jax.jit(_adam_op, donate_argnums=(0, 2, 3)).lower(
+        x, x, x, x, one, one, one).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == sweeps
+    assert "T(4,128)" not in text
+    # scalars (the beta powers) may be copied into on-chip memory; nothing
+    # of the tensor's size is copied or transposed
+    import re
+
+    for dims in re.findall(r"= f32\[([0-9,]+)\][^=\n]* (?:copy|transpose)\(",
+                           text):
+        assert math.prod(int(d) for d in dims.split(",")) <= 128, dims
+    seven = 7 * 4 * math.prod(shape)
+    assert compiled.cost_analysis()["bytes accessed"] < 1.5 * seven
 
 
 def test_sharded_xent_compiles_under_2x2_mesh(topo):
