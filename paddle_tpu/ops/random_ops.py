@@ -6,12 +6,27 @@ key threaded through the traced program as hidden state (@RNG_STATE@), so a
 Program with random_seed set replays identically — the determinism contract
 the reference's OpTest relies on (SURVEY.md hard part #6).  An op with an
 explicit nonzero ``seed`` attr uses its own fixed key instead.
+
+Dropout's keep mask is ``keep_mask``: 32 threefry bits per element against an
+integer threshold made on the host, with the counters made here in uint32, so
+no float64 and no uint64 reaches the device.  (The package runs jax in x64
+mode, where ``jax.random.bernoulli`` with a Python float draws 64-bit words
+and compares float64 uniforms, and jax's own partitionable ``random_bits``
+counts in uint64 and spends a whole threefry block per 32-bit word; the v5e
+emulates all of that.)  Replay on one tree is unchanged, on one chip or
+sharded; the mask VALUES for a given seed differ from trees before PR 31,
+which drew other words of the same key's stream.
 """
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.extend.random import threefry2x32_p
 
 from .registry import register_op, register_grad
 
@@ -29,6 +44,37 @@ def _key(ctx):
     return ctx.rng()
 
 
+def keep_mask(key, keep_prob, shape):
+    """Boolean mask, True with probability ``round(keep_prob * 2**32) / 2**32``
+    (within 1.2e-10 of ``keep_prob``, a Python float): 32 threefry bits per
+    element below a uint32 threshold.  The ends are decided here without a
+    draw, since a threshold of 2**32 does not fit a uint32."""
+    threshold = round(float(keep_prob) * 2**32)
+    if threshold <= 0:
+        return jnp.zeros(shape, jnp.bool_)
+    if threshold >= 2**32:
+        return jnp.ones(shape, jnp.bool_)
+    # One threefry2x32 block per PAIR of elements: the block counted by
+    # (index along axis 0, flat index over the other axes) of the first half
+    # of the last axis gives that element its first word and the element half
+    # an axis further its second.  The counters are sums of uint32 iotas, so
+    # they depend on the position alone (a sharded run draws what one chip
+    # draws) and partition along every axis but the last without traffic.
+    last = shape[-1] if shape else 1
+    half = tuple(shape[:-1]) + (-(-last // 2),)
+    if math.prod(half[1:]) >= 2**32:
+        raise NotImplementedError(
+            f"keep mask of shape {shape}: the counters past axis 0 overflow 32 bits")
+    flat, stride = jnp.zeros(half, jnp.uint32), 1
+    for axis in range(len(half) - 1, 0, -1):
+        flat = flat + lax.broadcasted_iota(jnp.uint32, half, axis) * np.uint32(stride)
+        stride *= half[axis]
+    words = threefry2x32_p.bind(key[0], key[1],
+                                lax.broadcasted_iota(jnp.uint32, half, 0), flat)
+    keep = jnp.concatenate([w < np.uint32(threshold) for w in words], axis=-1)
+    return keep[..., :last].reshape(shape)
+
+
 @register_op("fill_constant")
 def fill_constant(ctx):
     dt = _np_dtype(ctx)
@@ -38,8 +84,6 @@ def fill_constant(ctx):
     # and host-ness keeps loop counters / conditions concrete under jit so
     # while sub-blocks can unroll (the role force_cpu plays in the
     # reference; here it is the default).  jnp consumers auto-promote.
-    import numpy as np
-
     return {"Out": np.full(shape, value, dt)}
 
 
@@ -70,8 +114,6 @@ def assign(ctx):
 
 @register_op("assign_value")
 def assign_value(ctx):
-    import numpy as np
-
     dt = _np_dtype(ctx)
     vals = ctx.attr("fp32_values") or ctx.attr("int32_values") or ctx.attr("values")
     # Host (numpy) value like fill_constant above: a jnp constant would
@@ -142,7 +184,7 @@ def dropout(ctx):
         if impl == "upscale_in_train":
             return {"Out": x, "Mask": jnp.ones_like(x)}
         return {"Out": x * (1.0 - p), "Mask": jnp.ones_like(x)}
-    keep = jax.random.bernoulli(_key(ctx), 1.0 - p, x.shape)
+    keep = keep_mask(_key(ctx), 1.0 - p, x.shape)
     if impl == "upscale_in_train":
         mask = keep.astype(x.dtype) / max(1.0 - p, 1e-12)
     else:

@@ -43,6 +43,7 @@ from jax import lax
 from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..ops.random_ops import keep_mask
 from . import ring_attention as ra
 
 # slot -> (index of the dim sharded over "mp", or None).  Dim 0 is always
@@ -86,8 +87,7 @@ def _dropout(x, key, rate, is_test):
         return x
     if is_test:
         return x * (1.0 - rate)
-    keep = jax.random.bernoulli(key, 1.0 - rate, x.shape)
-    return x * keep.astype(x.dtype)
+    return x * keep_mask(key, 1.0 - rate, x.shape).astype(x.dtype)
 
 
 def _attend(q, k, v, bias, causal, local_heads, sp_axis, flash=False):

@@ -255,6 +255,58 @@ def test_adam_op_asks_for_no_relayout(topo, monkeypatch, shape, sweeps):
     assert compiled.cost_analysis()["bytes accessed"] < 1.5 * seven
 
 
+def _dropout_op(x, key):
+    """The ``dropout`` OP as the executor calls it, the RNG thread's split
+    included."""
+    box = [key]
+    ctx = registry.ExecContext(
+        "dropout", {"X": [x]}, {"Out": ["o"], "Mask": ["m"]},
+        {"dropout_prob": 0.1, "is_test": False}, rng_box=box)
+    out = registry.get_op_def("dropout").fn(ctx)
+    return out["Out"], out["Mask"], box[0]
+
+
+def test_dropout_op_draws_its_mask_in_32_bits(topo):
+    """One of the Transformer-base step's 32 dropout ops, ``[64, 256, 512]``
+    bf16, lowered for the v5e (threefry unrolled, as the chip gets it): no
+    float64 and no 64-bit type of the mask's size in what the compiler is
+    handed.  Until PR 31 the op was ``bernoulli(p: f64)``: 25 ``ui64`` and
+    15 ``f64`` tensor types of that size, all emulated on the chip (23.9 ms
+    of a 155 ms step).  Left: the key split of the RNG thread, which counts
+    its two keys in uint64."""
+    import re
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct((B, T, 512), BF16, sharding=chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=chip)
+    lowered = jax.jit(_dropout_op).lower(x, key)
+    wide = set(re.findall(r"tensor<((?:[0-9?]+x)*)(f64|i64|ui64)>",
+                          lowered.as_text()))
+    assert wide <= {("", "ui64"), ("2x", "ui64")}, wide
+    five = 5 * 2 * B * T * 512      # X, Out, Mask and the two halves' bools
+    assert lowered.compile().cost_analysis()["bytes accessed"] < five
+
+
+def test_keep_mask_partitions_without_traffic(topo):
+    """The mask's counters are sums of iotas, so under a mesh each chip
+    draws its own shard of what one chip would draw: sharded along the
+    batch or the sequence axis, the compiled program has no collective."""
+    import numpy as np
+
+    from paddle_tpu.ops.random_ops import keep_mask
+
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("dp",))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                               sharding=NamedSharding(mesh, P()))
+    for spec in (P("dp"), P(None, "dp")):
+        text = jax.jit(
+            lambda k: keep_mask(k, 0.9, (B, T, 512)),
+            out_shardings=NamedSharding(mesh, spec)).lower(key).compile().as_text()
+        for collective in ("all-gather", "all-reduce", "all-to-all",
+                           "collective-permute"):
+            assert collective not in text, (spec, collective)
+
+
 def test_sharded_xent_compiles_under_2x2_mesh(topo):
     """The shard_map lowering of the fused loss head on the four-chip mesh
     ``chip_smoke.py --chips 4`` builds: rows over dp, the vocabulary over
