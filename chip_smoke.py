@@ -4,8 +4,8 @@
 With no arguments it needs ONE TPU chip and drives the two main paths once,
 through the entry points a user calls, with every kernel gate left in AUTO:
 
- 1. **train** — Transformer-base exactly as ``bench.py`` builds it on an
-    accelerator (``transformer.base_config()``: d_model 512, d_inner 2048,
+ 1. **train** — Transformer-base at the benchmark cell's sizes
+    (``transformer.base_config()``: d_model 512, d_inner 2048,
     8 heads, 6+6 layers, vocabulary 30,000; src_len = tgt_len = 256, batch
     64, Adam, bf16 AMP with keep-low activations): startup program, two
     warm-up steps, eight timed steps through
@@ -156,8 +156,7 @@ class BackendCacheEvents:
 
 
 def build_transformer(fluid, rehearse: bool, seed: int):
-    """Transformer-base as bench.py builds it on an accelerator, and the
-    fixed feed.  Labels repeat the decoder input, so ten steps on the one
+    """Transformer-base at the benchmark cell's sizes, and the fixed feed.  Labels repeat the decoder input, so ten steps on the one
     batch must lower the loss."""
     from paddle_tpu.models import transformer
 

@@ -1,7 +1,8 @@
 """paddle_tpu.compile_cache — persistent, cross-process compilation cache.
 
-Every process today pays full XLA compilation from zero: ``bench.py``'s
-~10x compile overhead before steady state, every elastic-supervisor
+Without it every process pays full XLA compilation from zero: about five
+minutes before a benchmark cell's first step (``first_setup_s``, PERF.md
+section 2), every elastic-supervisor
 generation recompiling the exact program the dead generation ran, every
 serving restart re-AOT-compiling its whole bucket set.  This package makes
 compiled programs a durable artifact:

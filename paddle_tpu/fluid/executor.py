@@ -17,20 +17,19 @@ so XLA can alias their buffers (true in-place update on TPU HBM).
 
 from __future__ import annotations
 
-import os
-import warnings
 from collections import OrderedDict
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from . import core
+from . import step as _step
 from .framework import (OpRole, Program, RNG_STATE_VAR, Variable,
                         default_main_program)
+from .step import BlockPlan  # noqa: F401  (planned there, imported from here)
 from ..ops import registry as _reg
-
 
 # ---------------------------------------------------------------------------
 # Scope (ref: scope.h:41 — hierarchical name->Variable map)
@@ -179,119 +178,6 @@ def scope_guard(scope):
 # ---------------------------------------------------------------------------
 
 
-_SIDE_EFFECT_OPS = frozenset(["print", "save", "save_combine"])
-
-
-class BlockPlan:
-    """Static analysis of a block: which ops are live for the requested
-    fetches (dead ops are pruned — XLA would DCE them anyway, but pruning
-    first avoids demanding un-fed inputs), which names come from scope
-    (state_in), which persistables are (re)written (state_out)."""
-
-    def __init__(self, program: Program, block_idx: int,
-                 feed_names: Sequence[str], fetch_names: Sequence[str]):
-        block = program.block(block_idx)
-        self.block = block
-        self.feed_names = list(feed_names)
-        self.fetch_names = list(fetch_names)
-
-        def _is_persistable(name: str) -> bool:
-            return block._has_var_recursive(name) and \
-                block._var_recursive(name).persistable
-
-        # 1. live-op slice: keep ops needed for fetches or persistable updates
-        needed = set(fetch_names)
-        kept = []
-        for op in reversed(block.ops):
-            if op.type in _SKIP_OPS:
-                continue
-            outs = [n for n in op.output_arg_names if n]
-            live = (op.type in _SIDE_EFFECT_OPS
-                    or any(n in needed for n in outs)
-                    or any(_is_persistable(n) for n in outs))
-            if not live:
-                continue
-            kept.append(op)
-            needed.update(n for n in op.input_arg_names if n)
-        self.ops = list(reversed(kept))
-
-        # 2. dataflow analysis over the kept ops
-        written = set(feed_names)
-        state_in: List[str] = []
-        self.needs_rng = False
-        self.needs_eager = False
-
-        def _scan_rng(op):
-            d = _resolve_opdef(op.type)
-            if d is not None and d.stateful:
-                self.needs_rng = True
-            sub = op.attr("sub_block") if hasattr(op, "attr") else None
-            if isinstance(sub, int):
-                for bop in program.block(sub).ops:
-                    _scan_rng(bop)
-
-        def _op_is_eager(op) -> bool:
-            """Data-dependent op (or control flow containing one) — must run
-            outside jit."""
-            from ..ops.array_ops import EAGER_OPS
-
-            base = op.type[:-5] if op.type.endswith("_grad") else op.type
-            if base in EAGER_OPS:
-                return True
-            sub = op.attr("sub_block") if hasattr(op, "attr") else None
-            if isinstance(sub, int):
-                return any(_op_is_eager(b) for b in program.block(sub).ops)
-            return False
-
-        for op in self.ops:
-            _scan_rng(op)
-
-        # eager-island segmentation (SURVEY.md §7 hard part #1): contiguous
-        # runs of traceable ops become jittable segments; only the
-        # data-dependent islands between them run eagerly.  A beam-search
-        # decode program keeps its whole encoder in one compiled segment.
-        self.segments: List[Tuple[str, list]] = []
-        for op in self.ops:
-            kind = "eager" if _op_is_eager(op) else "jit"
-            if self.segments and self.segments[-1][0] == kind:
-                self.segments[-1][1].append(op)
-            else:
-                self.segments.append((kind, [op]))
-        self.needs_eager = any(k == "eager" for k, _ in self.segments)
-        for op in self.ops:
-            for name in op.input_arg_names:
-                if not name:
-                    continue
-                if name not in written and name not in state_in:
-                    state_in.append(name)
-            for name in op.output_arg_names:
-                if name:
-                    written.add(name)
-        state_out: List[str] = []
-        for op in self.ops:
-            for name in op.output_arg_names:
-                if not name or name in state_out:
-                    continue
-                if name in state_in or _is_persistable(name):
-                    state_out.append(name)
-        # fetches that are never produced in-block must come from state
-        for name in self.fetch_names:
-            if name not in written and name not in state_in:
-                state_in.append(name)
-        self.state_in = state_in
-        self.state_out = state_out
-
-
-def _resolve_opdef(op_type):
-    if _reg.is_registered(op_type):
-        return _reg.get_op_def(op_type)
-    if op_type.endswith("_grad") and _reg.is_registered(op_type[:-5]):
-        return _reg.get_op_def(op_type[:-5])
-    return None
-
-
-_SKIP_OPS = frozenset(["feed", "fetch", "read", "create_py_reader"])
-
 
 def build_window_fn(program: Program, plan: "BlockPlan", guard, n_user: int,
                     n_steps: int, feed_per_step: bool,
@@ -414,6 +300,11 @@ def trace_block(program: Program, block_idx: int, plan: BlockPlan,
         rng_box = [state_vals[RNG_STATE_VAR]]
     for op in plan.ops:
         run_op(op, env, rng_box)
+    return _block_outputs(plan, env, rng_box, lod_box)
+
+
+def _block_outputs(plan, env, rng_box, lod_box):
+    """``(fetches, new_state)`` out of the environment a block ran in."""
     fetches = [env[n] for n in plan.fetch_names]
     new_state = {n: env[n] for n in plan.state_out if n in env}
     if rng_box is not None:
@@ -637,118 +528,55 @@ class Executor:
         Returns the fetches of the LAST step (host numpy).  Programs with
         data-dependent eager islands cannot be scanned and raise.
         """
+        import time as _time
+
+        from . import guardian as _guardian
+        from ..observe import trace as _trace
+
         program = program or default_main_program()
         scope = scope or global_scope()
         n_steps = int(n_steps)
         fetch_names = [f.name if isinstance(f, Variable) else str(f)
                        for f in fetch_list or []]
-        feed_arrays = {}
-        for k, v in dict(feed or {}).items():
-            arr, _lod = self._coerce_feed(program, k, v)
-            if _lod:
-                raise RuntimeError(
-                    "run_steps: LoD feeds are not supported in the "
-                    "scanned loop; use Executor.run per step")
-            feed_arrays[k] = arr
-        from . import amp as _amp
-        from . import guardian as _guardian
-
+        feed_arrays, feed_lods = _step.coerce_feeds(program, feed)
+        if feed_lods:
+            raise RuntimeError(
+                "run_steps: LoD feeds are not supported in the "
+                "scanned loop; use Executor.run per step")
         # guarded window: sentinel + dynamic loss scale fold into the scan
         # body exactly like Executor.run's single guarded step
         guard = _guardian.for_program(program)
-        n_user = len(fetch_names)
-
-        from ..observe import trace as _trace
-
-        key = ("run_steps", program._cache_token, program._version,
-               tuple(fetch_names), n_steps, bool(feed_per_step),
-               tuple(sorted((k, tuple(v.shape), str(v.dtype))
-                            for k, v in feed_arrays.items())),
-               self.place.device_type,
-               # execution-mode toggles invalidate compiled fns (same
-               # contract as Executor.run's cache key)
-               _amp.compute_dtype(),
-               guard.cache_token() if guard is not None else None,
-               os.environ.get("PADDLE_TPU_FLASH", ""),
-               os.environ.get("PADDLE_TPU_FUSED", ""))
+        key, extra = _step.signature(
+            "run_steps", program, fetch_names, feed_arrays, guard,
+            n_steps=n_steps, feed_per_step=bool(feed_per_step),
+            platform=self.place.device_type)
         entry = self._cache.get(key)
         probe = None
         fresh_entry = entry is None
         if entry is None:
-            import time as _t
-
-            from .log import VLOG
-            from .. import analysis as _analysis
-            from .. import compile_cache as _cc
             from ..observe import goodput as _goodput
 
-            t_trace0 = _t.perf_counter()
+            t_trace0 = _time.perf_counter()
             with _trace.span("executor.trace", n_steps=n_steps):
-                # pre-compile verifier (PADDLE_TPU_VERIFY): milliseconds of
-                # static checks before seconds of trace/compile; strict mode
-                # raises VerifyError here, before any backend work.  Stacked
-                # per-step feeds verify as ONE step's slice.
-                _analysis.check_before_compile(
-                    program,
-                    feed=({k: v[0] if getattr(v, "ndim", 0) > 0 else v
-                           for k, v in feed_arrays.items()}
-                          if feed_per_step else feed_arrays),
-                    fetch_list=fetch_names, kind="run_steps")
-                # persistent-cache consult BEFORE tracing: a hit means
-                # another process already compiled this exact (program, jit
-                # config) — the backend executable loads from the shared
-                # disk cache
-                probe = _cc.executor_probe(
-                    program, feed_arrays, fetch_names,
-                    extra={"kind": "run_steps", "n_steps": n_steps,
-                           "feed_per_step": bool(feed_per_step),
-                           "platform": self.place.device_type,
-                           "amp": _amp.compute_dtype(),
-                           "guard": (guard.cache_token()
-                                     if guard is not None else None),
-                           "flash": os.environ.get("PADDLE_TPU_FLASH", ""),
-                           "fused": os.environ.get("PADDLE_TPU_FUSED", "")})
-                VLOG(1, f"Executor.run_steps: compiling {n_steps}-step scan"
-                        f"{' (guarded)' if guard is not None else ''}")
-                plan_fetches = list(fetch_names)
-                if guard is not None:
-                    plan_fetches += guard.extra_fetch_names()
-                plan = BlockPlan(program, 0, list(feed_arrays), plan_fetches)
-                if plan.needs_eager:
-                    if guard is not None and guard.scale_vars is not None:
-                        raise RuntimeError(
-                            "dynamic fp16 loss scaling is not supported for "
-                            "programs with data-dependent eager ops")
-                    raise RuntimeError(
-                        "run_steps: program contains data-dependent eager "
-                        "ops; use Executor.run per step")
-                if guard is not None and guard.scale_vars:
-                    # the scale/good-steps vars are read/written only by the
-                    # guarded wrapper (no IR op touches the counter), so
-                    # liveness never saw them — gather with the rest of
-                    # state
-                    for n in guard.scale_vars:
-                        if n not in plan.state_in:
-                            plan.state_in.append(n)
-
-                kfn = build_window_fn(program, plan, guard, n_user, n_steps,
-                                      feed_per_step)
-                device = core.get_jax_device(self.place)
-                donate = self._donate_argnums(device, program)
-                entry = (plan, jax.jit(kfn, donate_argnums=donate), guard)
+                entry, probe = self._build_entry(
+                    "run_steps", program, feed_arrays, fetch_names, guard,
+                    extra,
+                    lambda plan, guard: jax.jit(
+                        build_window_fn(program, plan, guard,
+                                        len(fetch_names), n_steps,
+                                        feed_per_step),
+                        donate_argnums=_step.donate_argnums(program)),
+                    verify_feed=_step.one_step_feed(feed_arrays,
+                                                    feed_per_step),
+                    eager_error="run_steps: program contains data-dependent "
+                                "eager ops; use Executor.run per step")
                 self._cache[key] = entry
             if program._params_grads is not None:
                 # host tracing/verification is compile-state wall-clock
                 # (the backend compile itself lands in the first dispatch,
                 # booked below)
-                _goodput.note("compile", _t.perf_counter() - t_trace0)
+                _goodput.note("compile", _time.perf_counter() - t_trace0)
         plan, fn, guard = entry
-
-        import time as _time
-
-        from . import fault as _fault
-        from . import profiler as _prof
-        from ..observe import watchdog as _watchdog
 
         # the window span wraps the WHOLE dispatch cycle, so guardian
         # trips / cache probes / slo breaches emitted inside it carry its
@@ -758,136 +586,38 @@ class Executor:
         with _trace.span("executor.window", n_steps=n_steps,
                          fresh=fresh_entry):
             t_host0 = _time.perf_counter()
-            window_start = 0
-            if program._params_grads is not None:
-                window_start = self._step_boundary(_fault, n_steps)
-            g = _guardian.current() if guard is not None else None
-            if g is not None:
-                # one-window-lag sentinel: observe the PREVIOUS dispatch's
-                # aggregated health and apply policy BEFORE this window runs
-                g.on_boundary()
+            d = _step.Dispatch(program, scope, plan, guard, n_steps)
             t_stage0 = _time.perf_counter()
             with _trace.span("executor.stage"):
-                state_vals = self._gather_state(program, plan, scope)
-                mut_names = set(plan.state_out)
-                if plan.needs_rng:
-                    mut_names.add(RNG_STATE_VAR)
-                if guard is not None and guard.scale_vars:
-                    mut_names.update(guard.scale_vars)
-                mut_state = {k: v for k, v in state_vals.items()
-                             if k in mut_names}
-                const_state = {k: v for k, v in state_vals.items()
-                               if k not in mut_names}
+                const_state, mut_state = d.split(
+                    self._gather_state(program, plan, scope))
                 device = core.get_jax_device(self.place)
                 feed_dev = {k: self._put_feed(k, v, device)
                             for k, v in feed_arrays.items()}
-            t_stage1 = _time.perf_counter()
-            sentinel = None
-            dump_state = None
-            if guard is not None:
-                seed_mul, loss_mul = _fault.sentinel_injection_window(
-                    window_start, n_steps)
-                sentinel = {
-                    "loss_cap": np.float32(g.loss_cap() if g is not None
-                                           else float("inf")),
-                    "seed_mul": seed_mul,
-                    "loss_mul": loss_mul,
-                }
-                dump_state = state_vals
-                if g is not None and g.config.policy == "dump_and_halt" \
-                        and self._donate_argnums(device, program):
-                    # donation invalidates mutated input buffers after the
-                    # dispatch; dump mode keeps pre-window device copies
-                    # alive
-                    dump_state = {k: (jnp.array(v, copy=True)
-                                      if k in mut_names else v)
-                                  for k, v in state_vals.items()}
-            agg = None
-            t = _time.perf_counter()
+            t_stage1 = t = _time.perf_counter()
             # `compile`: the goodput ledger books a fresh entry's first
             # dispatch (lazy jit: trace + compile on the host) as compile
             with _trace.span("executor.dispatch", compile=fresh_entry):
-                if guard is not None:
-                    fetches, new_state, agg = fn(feed_dev, const_state,
-                                                 mut_state, sentinel)
-                else:
-                    fetches, new_state = fn(feed_dev, const_state,
-                                            mut_state, None)
-                if _prof.is_profiling() and guard is None:
-                    # fluid.profiler's timeline wants the device time; no
-                    # span, sink or PADDLE_TRACE setting ever waits here
-                    jax.block_until_ready((fetches, new_state))
+                fetches, new_state, agg = d.call(fn, feed_dev, const_state,
+                                                 mut_state)
             t_disp1 = _time.perf_counter()
             with _trace.span("executor.observe"):
-                if _prof.is_profiling():
-                    _prof.record_event(
-                        f"executor_run[{len(plan.ops)}ops x{n_steps}steps]",
-                        t_disp1 - t, start=t)
-                # window visibility in the always-on counters (the smoke
-                # oracle counts dispatches; window_steps tracks
-                # amortization)
-                _prof.record_counter("executor.dispatches")
-                _prof.record_counter("executor.windows")
-                _prof.record_counter("executor.window_steps", inc=n_steps)
-                if probe is not None:
-                    probe.finish(t_disp1 - t, program,
-                                 meta={"kind": "run_steps",
-                                       "n_steps": n_steps})
-                if _fault.active() is not None:
-                    new_state = _fault.corrupt_state(new_state)
-                for name, val in new_state.items():
-                    scope.set(name, val)
-                self._check_nan_inf(list(new_state.items())
-                                    + list(zip(plan.fetch_names, fetches)))
-                if g is not None and agg is not None:
-                    g.defer(guard, window_start, agg, {
-                        "program": program, "feeds": feed_arrays,
-                        "feed_lods": {}, "fetch_names": fetch_names,
-                        "state": dump_state, "sentinel": sentinel,
-                        "duration_s": t_disp1 - t,
-                        "window": {"start": window_start,
-                                   "n_steps": n_steps,
-                                   "feed_per_step": bool(feed_per_step)}})
-                if program._params_grads is not None:
-                    from .. import observe
-                    from ..observe import memory as _obsmem
-
-                    # events emitted after the window (checkpoint commits,
-                    # cache probes) correlate to its LAST executed step,
-                    # not its first
-                    observe.note_step(window_start + n_steps - 1)
-                    # live-buffer ledger: scope residency + watermark at
-                    # the window boundary (gauges, high-water, watchdog
-                    # feed)
-                    _obsmem.note_scope_live(scope, scope_label="train",
-                                            step=window_start + n_steps - 1)
+                new_state = _step.commit(scope, new_state)
+                d.report(fetches, new_state, agg, t_host0, (t, t_disp1 - t),
+                         fresh_entry, compile_s=t_disp1 - t, probe=probe,
+                         meta={"kind": "run_steps", "n_steps": n_steps},
+                         feeds=feed_arrays, feed_lods={},
+                         fetch_names=fetch_names,
+                         feed_per_step=bool(feed_per_step))
             t_obs1 = _time.perf_counter()
             # the host breakdown of this window (host_ms = everything not
             # in the other three); dispatch_ms is the ENQUEUE, plus trace
             # and compile on a fresh entry
             _trace.note_window_breakdown(
-                host_ms=((t_stage0 - t_host0) + (t - t_stage1)) * 1e3,
+                host_ms=(t_stage0 - t_host0) * 1e3,
                 stage_ms=(t_stage1 - t_stage0) * 1e3,
                 dispatch_ms=(t_disp1 - t) * 1e3,
                 observe_ms=(t_obs1 - t_disp1) * 1e3)
-            if program._params_grads is not None:
-                # SLO watchdog: per-step time of this dispatch (no-op
-                # unless PADDLE_SLO is armed)
-                _watchdog.observe_value(
-                    "executor.step_time_s",
-                    (t_obs1 - t_host0) / max(1, n_steps),
-                    step=window_start + n_steps - 1)
-                from ..observe import goodput as _goodput
-
-                # goodput ledger: a fresh entry's first dispatch is
-                # compile cost (lazy jit), everything else device compute
-                disp = t_disp1 - t
-                if fresh_entry:
-                    _goodput.note("compile", disp)
-                    _goodput.note("device",
-                                  max(0.0, (t_obs1 - t_host0) - disp))
-                else:
-                    _goodput.note("device", t_obs1 - t_host0)
             return [np.asarray(v) for v in fetches]
 
     def run(self, program=None, feed=None, fetch_list=None, feed_var_name="feed",
@@ -910,10 +640,7 @@ class Executor:
              use_program_cache):
         import time as _time
 
-        from . import amp as _amp
-        from . import fault as _fault
         from . import guardian as _guardian
-        from . import profiler as _prof
         from ..observe import trace as _trace
 
         with _trace.span("fluid.run.feed"):
@@ -933,12 +660,7 @@ class Executor:
 
             fetch_names = [f.name if isinstance(f, Variable) else str(f)
                            for f in fetch_list]
-            feed_arrays, feed_lods = {}, {}
-            for k, v in feed.items():
-                arr, lod = self._coerce_feed(program, k, v)
-                feed_arrays[k] = arr
-                if lod:
-                    feed_lods[k] = lod
+            feed_arrays, feed_lods = _step.coerce_feeds(program, feed)
             device = core.get_jax_device(self.place)
             feed_dev = {k: self._put_feed(k, v, device)
                         for k, v in feed_arrays.items()}
@@ -958,103 +680,48 @@ class Executor:
             # into the same jitted program (guardian.py module docstring)
             guard = _guardian.for_program(program)
 
-            key = (program._cache_token, program._version,
-                   tuple(fetch_names),
-                   tuple(sorted((k, tuple(v.shape), str(v.dtype))
-                                for k, v in feed_arrays.items())),
-                   tuple(sorted(feed_lods.items())),
-                   tuple(sorted(state_lods.items())),
-                   self.place.device_type,
-                   # execution-mode toggles invalidate compiled fns
-                   _amp.compute_dtype(),
-                   guard.cache_token() if guard is not None else None,
-                   os.environ.get("PADDLE_TPU_FLASH", ""),
-                   os.environ.get("PADDLE_TPU_FUSED", ""))
+            key, extra = _step.signature(
+                "run", program, fetch_names, feed_arrays, guard,
+                feed_lods=tuple(sorted(feed_lods.items())),
+                state_lods=tuple(sorted(state_lods.items())),
+                platform=self.place.device_type)
             entry = self._cache.get(key) if use_program_cache else None
             probe = None
             fresh = entry is None
             root.set(fresh=fresh)
             if fresh:
                 with _trace.span("fluid.run.build"):
+                    lod_box = {}
                     entry, probe = self._build_entry(
-                        program, feed_arrays, feed_lods, state_lods,
-                        fetch_names, guard)
+                        "run", program, feed_arrays, fetch_names, guard,
+                        extra,
+                        lambda plan, guard: self._build(
+                            program, plan, {**state_lods, **feed_lods},
+                            lod_box, guard=guard, n_user=len(fetch_names)))
+                    entry += (lod_box,)
                 if use_program_cache:
                     self._cache[key] = entry
-            plan, fn, lod_box, guard = entry
+            plan, fn, guard, lod_box = entry
 
         with _trace.span("fluid.run.state"):
-            step_idx = 0
-            if program._params_grads is not None:
-                # training-step boundary (programs built via
-                # optimizer.minimize; hook points for fault injection +
-                # elastic liveness)
-                step_idx = self._step_boundary(_fault)
-            root.set(step=step_idx)
-            g = _guardian.current() if guard is not None else None
-            if g is not None:
-                # one-step-lag sentinel: observe the PREVIOUS step's health
-                # (its dispatch has retired, materializing two scalars is
-                # free) and apply policy BEFORE this step runs
-                g.on_boundary()
-            state_vals = self._gather_state(program, plan, scope)
+            d = _step.Dispatch(program, scope, plan, guard)
+            root.set(step=d.start)
+            const_state, mut_state = d.split(
+                self._gather_state(program, plan, scope))
 
-            # only vars that get rewritten are donated; read-only state
-            # (lr, params in eval programs) must keep its buffers alive in
-            # the scope
-            mut_names = set(plan.state_out)
-            if plan.needs_rng:
-                mut_names.add(RNG_STATE_VAR)
-            mut_state = {k: v for k, v in state_vals.items()
-                         if k in mut_names}
-            const_state = {k: v for k, v in state_vals.items()
-                           if k not in mut_names}
-            sentinel = None
-            dump_state = None
-            if guard is not None:
-                seed_mul, loss_mul = _fault.sentinel_injection(step_idx)
-                sentinel = {
-                    "loss_cap": np.float32(g.loss_cap() if g is not None
-                                           else float("inf")),
-                    "seed_mul": np.float32(seed_mul),
-                    "loss_mul": np.float32(loss_mul),
-                }
-                dump_state = state_vals
-                if g is not None and g.config.policy == "dump_and_halt" \
-                        and self._donate_argnums(device, program):
-                    # donation invalidates mutated input buffers after the
-                    # dispatch; dump mode keeps pre-step device copies
-                    # alive
-                    dump_state = {k: (jnp.array(v, copy=True)
-                                      if k in mut_names else v)
-                                  for k, v in state_vals.items()}
-
-        health = None
         t = _time.perf_counter()
         with _trace.span("fluid.run.call"):
-            if guard is not None:
-                fetches, new_state, health = fn(feed_dev, const_state,
-                                                mut_state, sentinel)
-            else:
-                fetches, new_state = fn(feed_dev, const_state, mut_state)
-                if _prof.is_profiling():
-                    # fluid.profiler's timeline wants the device time; no
-                    # span, sink or PADDLE_TRACE setting ever waits here
-                    jax.block_until_ready(fetches)
+            fetches, new_state, health = d.call(fn, feed_dev, const_state,
+                                                mut_state)
         call_s = _time.perf_counter() - t
 
         with _trace.span("fluid.run.commit"):
-            if _fault.active() is not None:
-                new_state = _fault.corrupt_state(new_state)
-            for name, val in new_state.items():
-                scope.set(name, val)
-                if name in lod_box:
-                    scope._lods[name] = lod_box[name]
+            new_state = _step.commit(scope, new_state, lod_box)
             # the arrays the scope just let go of (donated, or replaced)
             # die with their last reference: here, in the span that
             # replaced them, not when this frame ends (0.9 ms a step for
             # the Transformer's 190 on the v5e's host)
-            del state_vals, mut_state, const_state
+            del mut_state, const_state
             out = fetches
             if not return_numpy:
                 from .lod_tensor import LoDTensor
@@ -1066,119 +733,54 @@ class Executor:
                 # var aliases a buffer the next run will donate: copy
                 # those on device so the returned handle survives
                 # (donation would otherwise delete it under the caller).
-                donated = set(plan.state_out) | (
-                    {RNG_STATE_VAR} if plan.needs_rng else set())
                 out = []
                 for n, v in zip(plan.fetch_names, fetches):
-                    if n in donated and isinstance(v, jax.Array):
+                    if n in d.mut_names and isinstance(v, jax.Array):
                         v = jnp.array(v, copy=True)
                     out.append(LoDTensor(v, lod_box.get(n)))
 
         with _trace.span("fluid.run.observe"):
-            if _prof.is_profiling():
-                _prof.record_event(f"executor_run[{len(plan.ops)}ops]",
-                                   call_s, start=t)
-            _prof.record_counter("executor.dispatches")
-            if probe is not None:
-                # first dispatch of a fresh entry = trace + compile; commit
-                # the artifact (miss) / freshen it (hit) now that it exists
-                probe.finish(call_s, program,
-                             meta={"kind": "run",
-                                   "ops": len(plan.ops),
-                                   "fetches": len(plan.fetch_names)})
-            self._check_nan_inf(list(new_state.items())
-                                + list(zip(plan.fetch_names, fetches)))
-            if g is not None and health is not None:
-                g.defer(guard, step_idx, health, {
-                    "program": program, "feeds": feed_arrays,
-                    "feed_lods": feed_lods, "fetch_names": fetch_names,
-                    "state": dump_state, "sentinel": sentinel,
-                    "duration_s": _time.perf_counter() - t})
-            if program._params_grads is not None:
-                from ..observe import goodput as _goodput
-                from ..observe import memory as _obsmem
-                from ..observe import watchdog as _watchdog
-
-                # SLO watchdog on the per-step training path (no-op unless
-                # PADDLE_SLO is armed); async dispatch means this measures
-                # submit-to-submit pacing, which is what regresses under
-                # load
-                _watchdog.observe_value("executor.step_time_s",
-                                        _time.perf_counter() - t,
-                                        step=step_idx)
-                # ledger gauges only (quiet): per-step watermark EVENTS
-                # would flood the stream, windows own the event cadence
-                _obsmem.note_scope_live(scope, scope_label="train",
-                                        step=step_idx, emit_event=False)
-                # per-step training dispatch: a fresh entry's first
-                # dispatch is compile cost (lazy jit), everything after
-                # device compute
-                _goodput.note("compile" if fresh else "device",
-                              _time.perf_counter() - t)
+            d.report(fetches, new_state, health, t, (t, call_s), fresh,
+                     probe=probe,
+                     meta={"kind": "run", "ops": len(plan.ops),
+                           "fetches": len(plan.fetch_names)},
+                     feeds=feed_arrays, feed_lods=feed_lods,
+                     fetch_names=fetch_names)
 
         if return_numpy:
             with _trace.span("fluid.run.fetch"):
                 out = [np.asarray(v) for v in fetches]
         return out
 
-    def _build_entry(self, program, feed_arrays, feed_lods, state_lods,
-                     fetch_names, guard):
+    def _build_entry(self, kind, program, feed_arrays, fetch_names, guard,
+                     extra, build, verify_feed=None, eager_error=None):
         """The executor's cache missed: verify, consult the persistent
-        compile cache, plan the block and build the (lazily compiled) jit.
-        Returns the cache entry and the compile-cache probe."""
-        from . import amp as _amp
+        compile cache, plan the block and ``build(plan, guard)`` the
+        (lazily compiled) jit.  Returns ``(plan, fn, guard)`` and the
+        compile-cache probe."""
         from .log import VLOG
         from .. import analysis as _analysis
         from .. import compile_cache as _cc
 
         # pre-compile verifier (PADDLE_TPU_VERIFY=warn|strict|off): named
         # diagnostics in milliseconds instead of an XLA trace error seconds
-        # into compile
+        # into compile; strict mode raises VerifyError here, before any
+        # backend work
         _analysis.check_before_compile(
-            program, feed=feed_arrays, fetch_list=fetch_names, kind="run")
-        # persistent-cache consult BEFORE tracing (hit/miss counters +
-        # backend warm start through the shared jax disk cache)
-        probe = _cc.executor_probe(
-            program, feed_arrays, fetch_names,
-            extra={"kind": "run",
-                   "feed_lods": tuple(sorted(feed_lods.items())),
-                   "state_lods": tuple(sorted(state_lods.items())),
-                   "platform": self.place.device_type,
-                   "amp": _amp.compute_dtype(),
-                   "guard": (guard.cache_token()
-                             if guard is not None else None),
-                   "flash": os.environ.get("PADDLE_TPU_FLASH", ""),
-                   "fused": os.environ.get("PADDLE_TPU_FUSED", "")})
-        VLOG(1, f"Executor: compiling block "
+            program, feed=feed_arrays if verify_feed is None else verify_feed,
+            fetch_list=fetch_names, kind=kind)
+        # persistent-cache consult BEFORE tracing: a hit means another
+        # process already compiled this exact (program, jit config) and the
+        # backend executable loads from the shared jax disk cache
+        probe = _cc.executor_probe(program, feed_arrays, fetch_names,
+                                   extra=extra)
+        VLOG(1, f"Executor.{kind}: compiling block "
                 f"({len(program.global_block().ops)} ops, "
-                f"fetches={fetch_names})")
-        plan_fetches = list(fetch_names)
-        if guard is not None:
-            plan_fetches += guard.extra_fetch_names()
-        plan = BlockPlan(program, 0, list(feed_arrays), plan_fetches)
-        if guard is not None and plan.needs_eager:
-            if guard.scale_vars is not None:
-                raise RuntimeError(
-                    "dynamic fp16 loss scaling is not supported for "
-                    "programs with data-dependent eager ops")
-            warnings.warn(
-                "guardian: program contains data-dependent eager ops; "
-                "the numerics sentinel is disabled for it")
-            guard = None
-            plan = BlockPlan(program, 0, list(feed_arrays), fetch_names)
-        if guard is not None and guard.scale_vars:
-            # the good-steps counter is read/written only by the guarded
-            # wrapper (no IR op touches it), so liveness never saw it:
-            # gather it with the rest of the state
-            for n in guard.scale_vars:
-                if n not in plan.state_in:
-                    plan.state_in.append(n)
-        lod_box = {}
-        all_lods = dict(state_lods)
-        all_lods.update(feed_lods)
-        fn = self._build(program, plan, all_lods, lod_box,
-                         guard=guard, n_user=len(fetch_names))
-        return (plan, fn, lod_box, guard), probe
+                f"fetches={fetch_names}"
+                f"{', guarded' if guard is not None else ''})")
+        plan, guard = _step.plan_step(program, feed_arrays, fetch_names,
+                                      guard, eager_error)
+        return (plan, build(plan, guard), guard), probe
 
     def lower_step(self, program, feed, fetch_list, scope=None):
         """AOT-lower the SAME traced step ``Executor.run`` would jit for
@@ -1190,12 +792,9 @@ class Executor:
         scope = scope or global_scope()
         fetch_names = [f.name if isinstance(f, Variable) else str(f)
                        for f in fetch_list or []]
-        feed_arrays = {}
-        for k, v in dict(feed or {}).items():
-            arr, lod = self._coerce_feed(program, k, v)
-            if lod:
-                return None
-            feed_arrays[k] = arr
+        feed_arrays, feed_lods = _step.coerce_feeds(program, feed)
+        if feed_lods:
+            return None
         program = self._prune_for_unfed(program, feed_arrays, fetch_names,
                                         scope)
         plan = BlockPlan(program, 0, list(feed_arrays), fetch_names)
@@ -1213,12 +812,8 @@ class Executor:
 
         state_vals = {k: norm(v) for k, v in
                       self._gather_state(program, plan, scope).items()}
-        mut_names = set(plan.state_out)
-        if plan.needs_rng:
-            mut_names.add(RNG_STATE_VAR)
-        mut_state = {k: v for k, v in state_vals.items() if k in mut_names}
-        const_state = {k: v for k, v in state_vals.items()
-                       if k not in mut_names}
+        const_state, mut_state = _step.split_state(
+            state_vals, _step.mutable_names(plan))
         feed_dev = {k: jax.device_put(jnp.asarray(v), device)
                     for k, v in feed_arrays.items()}
         return fn.lower(feed_dev, const_state, mut_state)
@@ -1243,84 +838,6 @@ class Executor:
             return None
 
     # -- helpers --
-    @staticmethod
-    def _donate_argnums(device, program):
-        """Donation argnums for the jitted step: the mutable-state arg
-        (index 2) is donated so XLA aliases its buffers into the updated
-        state — a true in-place parameter update.  Modern jax implements
-        donation on every backend (cpu/gpu/tpu), and the executor already
-        protects the one read-after-donate hazard (fetches aliasing
-        mutated state are copied on return, executor.run's donated-fetch
-        path), so it is on for every TRAINING program (built via
-        optimizer.minimize, whose step loop is single-threaded by
-        contract).  Inference/eval programs never donate: predictor
-        clones run concurrently against one shared scope, and a donated
-        buffer deleted under a sibling thread's in-flight dispatch is the
-        one hazard copy-on-return cannot fix.  ``PADDLE_TPU_DONATE=0``
-        opts out entirely (debugging buffer lifetimes).
-
-        Exception to the inference rule: a program that sets
-        ``_donate_state = True`` (the serving DecodeEngine's decode-step
-        / prefill programs, whose persistable KV cache is rewritten by
-        exactly one engine worker thread per the single-dispatcher
-        contract) opts back in, so the [max_slots, max_len, ...] cache
-        buffers alias window-over-window instead of copying every
-        tick."""
-        if program is not None and program._params_grads is None \
-                and not getattr(program, "_donate_state", False):
-            return ()
-        from . import envcontract
-
-        if not envcontract.get("PADDLE_TPU_DONATE"):
-            return ()
-        return (2,)
-
-    @staticmethod
-    def _step_boundary(_fault, n_steps=1):
-        """Training-step boundary: fires armed step faults (kill-at-step-N)
-        and emits an elastic-supervisor heartbeat when a heartbeat dir is
-        configured.  A fused run_steps dispatch advances the whole window at
-        once — a kill armed inside it fires before the dispatch.  Returns
-        the step index this dispatch executes (window start for fused)."""
-        fired = _fault.current_step()
-        if _fault.active() is not None:
-            if n_steps == 1:
-                fired = _fault.on_step()
-            else:
-                _fault.advance(n_steps)
-            # straggler oracle: the armed rank's sleep lands here, INSIDE
-            # the window span, so its per-step time inflates like a real
-            # slow chip's and the skew detector must flag it
-            _fault.straggler_delay(n_steps)
-        else:
-            _fault._step += n_steps  # keep the index flowing for the guardian
-        from .. import observe
-
-        # every subsystem's events from here to the next boundary correlate
-        # to this step (guardian trips, cache hits, checkpoint commits)
-        observe.note_step(fired)
-        hb_dir = os.environ.get("PADDLE_ELASTIC_HB_DIR")
-        if hb_dir:
-            from ..parallel.elastic import write_heartbeat
-
-            write_heartbeat(hb_dir, step=_fault.current_step())
-        return fired
-
-    @staticmethod
-    def _check_nan_inf(named_vals):
-        """Debug mode (ref FLAGS_check_nan_inf, operator.cc:643): fault
-        with the variable NAME on the first non-finite value.  Host-side
-        materialization forces a sync per step — debug only."""
-        if not core.GLOBAL_FLAGS.get("check_nan_inf"):
-            return
-        for name, val in named_vals:
-            arr = np.asarray(val)
-            if np.issubdtype(arr.dtype, np.floating) \
-                    and not np.isfinite(arr).all():
-                raise FloatingPointError(
-                    f"check_nan_inf: variable '{name}' contains "
-                    f"NaN/Inf after op block execution")
-
     def _put_feed(self, name, arr, device):
         """H2D-transfer a feed value, skipping the copy when the bytes are
         identical to what this feed name already holds on device.
@@ -1368,8 +885,7 @@ class Executor:
 
     def _build(self, program, plan, feed_lods=None, lod_box=None,
                guard=None, n_user=None):
-        device = core.get_jax_device(self.place)
-        donate = self._donate_argnums(device, program)
+        donate = _step.donate_argnums(program)
         static_env = {k + LOD_SUFFIX: lod
                       for k, lod in (feed_lods or {}).items()}
 
@@ -1421,42 +937,26 @@ class Executor:
             env.update(mut_state)
             env.update(feed_vals)
             rng_box = [env[RNG_STATE_VAR]] if plan.needs_rng else None
+            import time as _time
+
             from . import profiler as _prof
+
+            def timed(label, fn, *args):
+                if not _prof.is_profiling():
+                    return fn(*args)
+                t = _time.perf_counter()
+                fn(*args)
+                _prof.record_event(label, _time.perf_counter() - t, start=t)
 
             for si, (kind, ops) in enumerate(plan.segments):
                 if kind == "eager":
                     for op in ops:
-                        if _prof.is_profiling():
-                            import time as _time
-
-                            t = _time.perf_counter()
-                            run_op(op, env, rng_box)
-                            _prof.record_event(
-                                f"eager:{op.type}",
-                                _time.perf_counter() - t, start=t)
-                        else:
-                            run_op(op, env, rng_box)
-                    continue
-                if _prof.is_profiling():
-                    import time as _time
-
-                    t = _time.perf_counter()
-                    self._run_jit_segment(si, ops, env, rng_box, seg_cache)
-                    _prof.record_event(
-                        f"jit_segment[{si}:{len(ops)}ops]",
-                        _time.perf_counter() - t, start=t)
+                        timed(f"eager:{op.type}", run_op, op, env, rng_box)
                 else:
-                    self._run_jit_segment(si, ops, env, rng_box, seg_cache)
-            fetches = [env[n] for n in plan.fetch_names]
-            new_state = {n: env[n] for n in plan.state_out if n in env}
-            if rng_box is not None:
-                new_state[RNG_STATE_VAR] = rng_box[0]
-            if lod_box is not None:
-                for n in list(plan.fetch_names) + list(plan.state_out):
-                    lod = env.get(n + LOD_SUFFIX)
-                    if lod is not None:
-                        lod_box[n] = lod
-            return fetches, new_state
+                    timed(f"jit_segment[{si}:{len(ops)}ops]",
+                          self._run_jit_segment, si, ops, env, rng_box,
+                          seg_cache)
+            return _block_outputs(plan, env, rng_box, lod_box)
 
         return run_segments
 
@@ -1665,60 +1165,6 @@ class Executor:
         return program  # pruning cannot help; keep the error
 
     def _gather_state(self, program, plan, scope):
-        state = {}
-        for name in plan.state_in:
-            val = scope.get(name, _MISSING)
-            if val is _MISSING:
-                gb = program.global_block()
-                if gb._has_var_recursive(name) and \
-                        gb._var_recursive(name).is_data:
-                    raise RuntimeError(
-                        f"Data variable '{name}' was not fed. Pass it in the "
-                        f"feed dict (feed keys were misspelled or missing).")
-                raise RuntimeError(
-                    f"Variable '{name}' is not initialized in the scope. "
-                    f"Did you run the startup program?")
-            state[name] = val if isinstance(val, jax.Array) else jnp.asarray(val)
-        if plan.needs_rng:
-            rk = scope.get(RNG_STATE_VAR, _MISSING)
-            if rk is _MISSING:
-                rk = jax.random.PRNGKey(program.random_seed or 0)
-                scope.set(RNG_STATE_VAR, rk)
-            state[RNG_STATE_VAR] = rk
-        return state
-
-    def _coerce_feed(self, program, name, value):
-        lod = None
-        from .lod_tensor import LoDTensor
-
-        if isinstance(value, LoDTensor):
-            lod = value.lod() or None
-            # unwrap WITHOUT np.asarray: a device-resident LoDTensor (what
-            # run(return_numpy=False) returns) must stay on device — the
-            # jax.Array branch below passes it through, avoiding a blocking
-            # D2H + re-upload round trip on the decode hot path
-            value = value._data
-        elif isinstance(value, tuple) and len(value) == 2 \
-                and isinstance(value[1], (list, tuple)):
-            # (array, recursive_sequence_lengths) convenience form
-            from .lod_tensor import _lengths_to_offsets
-
-            value, lengths = value
-            lod = tuple(tuple(_lengths_to_offsets(l)) for l in lengths) or None
-        if isinstance(value, jax.Array):
-            # pre-placed device array: keep it on device (astype stays lazy)
-            gb = program.global_block()
-            if gb._has_var_recursive(name):
-                want = core.np_dtype(gb._var_recursive(name).dtype)
-                if value.dtype != want:
-                    value = value.astype(want)
-            return value, lod
-        arr = np.asarray(value)
-        gb = program.global_block()
-        if gb._has_var_recursive(name):
-            want = core.np_dtype(gb._var_recursive(name).dtype)
-            if arr.dtype != want:
-                arr = arr.astype(want)
-        if lod is not None:
-            lod = tuple(tuple(int(x) for x in level) for level in lod)
-        return arr, lod
+        return {k: v if isinstance(v, jax.Array) else jnp.asarray(v)
+                for k, v in _step.gather_state(program, plan,
+                                               scope).items()}

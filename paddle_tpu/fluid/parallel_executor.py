@@ -18,15 +18,14 @@ Loss scaling: the reference writes a 1/N constant per device
 
 from __future__ import annotations
 
-import os
-from typing import Dict, List, Optional
+from typing import Dict
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import core
-from .executor import _MISSING, global_scope
+from . import step as _step
+from .executor import global_scope
 from .framework import Variable, default_main_program
 from ..parallel.mesh import env_mesh_spec, mesh_from_spec, mesh_label
 from ..parallel.spmd import ShardedTrainStep, ShardedWindowRunner
@@ -142,7 +141,6 @@ class ParallelExecutor:
                              return_numpy)
 
     def _run(self, root, fetch_list, feed, return_numpy):
-        from . import amp as _amp
         from ..observe import trace as _trace
 
         fetch_names = [f.name if isinstance(f, Variable) else str(f)
@@ -155,29 +153,14 @@ class ParallelExecutor:
                     for k, v in d.items():
                         merged.setdefault(k, []).append(np.asarray(v))
                 feed = {k: np.concatenate(v, 0) for k, v in merged.items()}
-            feed = feed or {}
-            # normalize dtypes BEFORE the cache key so float64-from-list
-            # feeds don't compile a duplicate executable
-            gb_ = self._program.global_block()
-            feed_arrays = {}
-            for k, v in feed.items():
-                arr = np.asarray(v)
-                if gb_._has_var_recursive(k):
-                    want = core.np_dtype(gb_._var_recursive(k).dtype)
-                    if arr.dtype != want:
-                        arr = arr.astype(want)
-                feed_arrays[k] = arr
+            feed_arrays = {k: _step.feed_dtype(self._program, k, v)
+                           for k, v in (feed or {}).items()}
 
         with _trace.span("fluid.run.lookup"):
-            key = (id(self._program), self._program._version,
-                   tuple(fetch_names),
-                   tuple(sorted((k, tuple(v.shape), str(v.dtype))
-                                for k, v in feed_arrays.items())),
-                   # execution-mode toggles invalidate compiled steps (same
-                   # contract as Executor.run's cache key)
-                   _amp.compute_dtype(),
-                   os.environ.get("PADDLE_TPU_FLASH", ""),
-                   os.environ.get("PADDLE_TPU_FUSED", ""))
+            # the mesh and the reduce strategy are this executor's own and
+            # fixed, so its key needs neither
+            key, _ = _step.signature("sharded_step", self._program,
+                                     fetch_names, feed_arrays)
             step = self._cache.get(key)
             root.set(fresh=step is None)
             if step is None:
@@ -199,8 +182,9 @@ class ParallelExecutor:
             fetches, new_state = step(feed_dev, state_vals)
 
         with _trace.span("fluid.run.commit"):
-            for name, val in new_state.items():
-                self._scope.set(name, val)
+            # this path has no step boundary, hence no fault hooks
+            # (ROADMAP D15)
+            _step.commit(self._scope, new_state, faults=False)
             # the arrays the scope just let go of die with their last
             # reference: here, not when this frame ends
             del state_vals, feed_dev
@@ -255,35 +239,17 @@ class ParallelExecutor:
         (dim 1) shards over the mesh's dp axes and must divide them —
         indivisible batches raise a clear ValueError rather than an
         opaque XLA sharding error."""
-        from . import amp as _amp
         from . import guardian as _guardian
 
         n_steps = int(n_steps)
         fetch_names = [f.name if isinstance(f, Variable) else str(f)
                        for f in fetch_list or []]
-        gb = self._program.global_block()
-        feed_arrays = {}
-        for k, v in dict(feed or {}).items():
-            if isinstance(v, jax.Array):
-                feed_arrays[k] = v
-                continue
-            arr = np.asarray(v)
-            if gb._has_var_recursive(k):
-                want = core.np_dtype(gb._var_recursive(k).dtype)
-                if arr.dtype != want:
-                    arr = arr.astype(want)
-            feed_arrays[k] = arr
-
-        guard = _guardian.for_program(self._program)
-        key = (id(self._program), self._program._version,
-               tuple(fetch_names), n_steps, bool(feed_per_step),
-               tuple(sorted((k, tuple(v.shape), str(v.dtype))
-                            for k, v in feed_arrays.items())),
-               _amp.compute_dtype(),
-               guard.cache_token() if guard is not None else None,
-               os.environ.get("PADDLE_TPU_FLASH", ""),
-               os.environ.get("PADDLE_TPU_FUSED", ""),
-               self.mesh_label)
+        feed_arrays = {k: _step.feed_dtype(self._program, k, v)
+                       for k, v in dict(feed or {}).items()}
+        key, _ = _step.signature(
+            "sharded_window", self._program, fetch_names, feed_arrays,
+            _guardian.for_program(self._program), n_steps=n_steps,
+            feed_per_step=bool(feed_per_step), mesh=self.mesh_label)
         runner = self._window_cache.get(key)
         if runner is None:
             from .. import analysis as _analysis
@@ -291,12 +257,9 @@ class ParallelExecutor:
 
             with _trace.span("executor.trace", n_steps=n_steps,
                              mesh=self.mesh_label):
-                # stacked (n_steps, batch, ...) windows verify as one step
                 _analysis.check_before_compile(
                     self._program,
-                    feed=({k: v[0] if getattr(v, "ndim", 0) > 0 else v
-                           for k, v in feed_arrays.items()}
-                          if feed_per_step else feed_arrays),
+                    feed=_step.one_step_feed(feed_arrays, feed_per_step),
                     fetch_list=fetch_names, mesh=self._mesh,
                     kind="pe_run_steps")
                 zero1 = (self._build_strategy.reduce_strategy ==
@@ -332,14 +295,7 @@ class ParallelExecutor:
         return out
 
     def _check_initialized(self, plan):
-        gb = self._program.global_block()
-        for name in plan.state_in:
-            if self._scope.get(name, _MISSING) is _MISSING:
-                if gb._has_var_recursive(name) and \
-                        gb._var_recursive(name).is_data:
-                    raise RuntimeError(f"Data variable '{name}' was not fed")
-                raise RuntimeError(f"Variable '{name}' is not initialized; "
-                                   f"run the startup program first")
+        _step.gather_state(self._program, plan, self._scope)
 
     def bcast_params(self):
         """ref: parallel_executor.cc:234 BCastParamsToDevices — replication is

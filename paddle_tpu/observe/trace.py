@@ -81,7 +81,7 @@ __all__ = [
     "Span", "Recorded", "RING_SPANS", "span", "start_span", "emit_span",
     "recorded", "current", "enabled", "trace_context", "set_trace_context",
     "new_span_id", "format_traceparent", "parse_traceparent", "thread_tid",
-    "peak_tflops", "note_window_breakdown", "reset",
+    "note_window_breakdown", "reset",
 ]
 
 # one wall/perf anchor pair so perf_counter intervals map onto the event
@@ -467,29 +467,8 @@ jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 # ---------------------------------------------------------------------------
-# the chip's published peak (bench.py), and the per-window host breakdown
+# the per-window host breakdown
 # ---------------------------------------------------------------------------
-
-#: Peak dense bf16 TFLOP/s of one chip, keyed by ``device_kind`` exactly
-#: as jax reports it — the one such table in the repo (bench.py reads it
-#: too).  A kind that is not here is an error, never a default: add it
-#: with its source when such a chip is attached.
-#:  - "TPU v5 lite" (v5e): 197, Google Cloud documentation, "TPU v5e".
-#:  - "cpu": a documented NOMINAL figure, there only so that bench.py's
-#:    ratio stays defined on the test backend; a ratio against it is a
-#:    diagnostic, not a device metric.
-PEAK_BF16_TFLOPS = {"TPU v5 lite": 197.0, "cpu": 0.5}
-
-
-def peak_tflops(device_kind: str) -> float:
-    """Peak bf16 TFLOP/s of one ``device_kind`` chip; unknown kinds raise."""
-    try:
-        return PEAK_BF16_TFLOPS[device_kind]
-    except KeyError:
-        raise KeyError(
-            f"no peak rate recorded for device_kind {device_kind!r}; add "
-            f"it with its source to observe.trace.PEAK_BF16_TFLOPS "
-            f"(known: {sorted(PEAK_BF16_TFLOPS)})") from None
 
 
 def note_window_breakdown(host_ms: float, stage_ms: float,

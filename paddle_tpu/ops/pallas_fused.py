@@ -83,8 +83,8 @@ def fused_decision(req: int = -1) -> bool:
 
 def active_families() -> list:
     """The kernel families that would dispatch fused under the current
-    env/backend — recorded in every BENCH line (bench.py) so rounds are
-    attributable to kernel changes."""
+    env/backend (a diagnostic; a run's own account is the
+    ``ops.fused.*`` counters)."""
     return (["softmax_xent", "momentum", "adam"] if fused_decision() else [])
 
 

@@ -26,7 +26,6 @@ donated state, compile-cache warm starts keyed on mesh + spec table).
 
 from __future__ import annotations
 
-import os
 import re
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
@@ -36,9 +35,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..fluid import core
-from ..fluid.executor import (BlockPlan, _MISSING, build_window_fn,
-                              global_scope, trace_block)
+from ..fluid import step as _step
+from ..fluid.executor import (BlockPlan, build_window_fn, global_scope,
+                              trace_block)
 from ..fluid.framework import Parameter, Program, RNG_STATE_VAR
 from .mesh import mesh_label
 
@@ -420,6 +419,8 @@ class ShardedTrainStep:
             with mesh_scope(mesh), param_spec_scope(specs):
                 return trace_block(program, 0, plan, feed_vals, state_vals)
 
+        self._trace = fn
+
         # input shardings are carried by the placed arrays (place_feed /
         # place_state); pin the output state so updated params keep their
         # layout across steps, and pin fetches replicated so every host can
@@ -467,21 +468,12 @@ class ShardedTrainStep:
 
     def place_state(self, scope=None):
         """Place scope state onto the mesh with the chosen shardings."""
-        scope = scope or global_scope()
-        state = {}
-        for name in self.plan.state_in:
-            val = scope.get(name, _MISSING)
-            if val is _MISSING:
-                raise RuntimeError(f"state var {name} missing from scope")
-            sh = NamedSharding(self.mesh, self.specs.get(name, P()))
-            state[name] = self._place(val, sh, from_full=True)
-        if self.plan.needs_rng:
-            rk = scope.get(RNG_STATE_VAR, _MISSING)
-            if rk is _MISSING:
-                rk = jax.random.PRNGKey(self.program.random_seed or 0)
-            state[RNG_STATE_VAR] = self._place(
-                rk, NamedSharding(self.mesh, P()), from_full=True)
-        return state
+        state = _step.gather_state(self.program, self.plan,
+                                   scope or global_scope())
+        return {name: self._place(
+                    val, NamedSharding(self.mesh, self.specs.get(name, P())),
+                    from_full=True)
+                for name, val in state.items()}
 
     def _batch_divisor(self) -> int:
         """How many equal shards this process's feed must split into: the
@@ -557,12 +549,8 @@ class ShardedTrainStep:
                            self.bspec if divisible else P())
         rep = NamedSharding(self.mesh, P())
         out = {}
-        gb = self.program.global_block()
         for k, arr in arrays.items():
-            if gb._has_var_recursive(k):
-                want = core.np_dtype(gb._var_recursive(k).dtype)
-                if arr.dtype != want:
-                    arr = arr.astype(want)
+            arr = _step.feed_dtype(self.program, k, arr)
             spec = self.feed_specs.get(k)
             if spec is not None and divisible and all(
                     ax is None or (d < arr.ndim
@@ -582,27 +570,20 @@ class ShardedTrainStep:
 
         return mh.fetch_to_host(val)
 
-    def cache_extra(self, **more) -> dict:
+    def cache_extra(self, kind, feed, guard=_step.UNGUARDED, **more) -> dict:
         """The compile-cache fingerprint extra for this sharded program:
         mesh axis names AND extents fold in (dp8 vs dp4,tp2 must be
-        distinct executables), as do the jit-level toggles."""
-        from ..fluid import amp as _amp
-
-        extra = {"platform": "spmd",
-                 "mesh": [[a, int(self.mesh.shape[a])]
-                          for a in self.mesh.axis_names],
-                 "multihost": self.multihost,
-                 "amp": _amp.compute_dtype(),
-                 "flash": os.environ.get("PADDLE_TPU_FLASH", ""),
-                 "fused": os.environ.get("PADDLE_TPU_FUSED", "")}
-        extra.update(self._probe_ctx)
-        extra.update(more)
-        return extra
+        distinct executables), as do the execution-mode toggles."""
+        own = {"platform": "spmd",
+               "mesh": tuple((a, int(self.mesh.shape[a]))
+                             for a in self.mesh.axis_names),
+               "multihost": self.multihost, **self._probe_ctx, **more}
+        return _step.signature(kind, self.program, self.plan.fetch_names,
+                               feed, guard, **own)[1]
 
     def __call__(self, feed, state):
         import time as _time
 
-        from ..fluid import profiler as _prof
         from .. import compile_cache as _cc
         from .. import observe
 
@@ -613,26 +594,22 @@ class ShardedTrainStep:
             # reshaped mesh or relaid spec table misses by construction
             probe = _cc.executor_probe(
                 self.program, feed, self.plan.fetch_names,
-                extra=self.cache_extra(kind="sharded_step"),
+                extra=self.cache_extra("sharded_step", feed),
                 spec_table=table_signature(self.specs))
         observe.note_mesh(self.label)
         fresh = not self._dispatched
         t0 = _time.perf_counter()
         out = self._fn(feed, state)
         self._dispatched = True
-        _prof.record_counter("executor.dispatches")
-        observe.registry().inc("executor.dispatches",
-                               labels={"mesh": self.label})
+        _step.count_dispatch(mesh=self.label)
         if probe is not None:
             jax.block_until_ready(out)
             probe.finish(_time.perf_counter() - t0, self.program,
                          meta={"kind": "sharded_step", "mesh": self.label})
         if self.program._params_grads is not None:
-            from ..observe import goodput as _goodput
-
             # per-step sharded dispatch: first call compiles (lazy jit)
-            _goodput.note("compile" if fresh else "device",
-                          _time.perf_counter() - t0, mesh=self.label)
+            _step.book_time(_time.perf_counter() - t0, fresh,
+                            mesh=self.label)
         return out
 
 
@@ -711,7 +688,6 @@ class ShardedWindowRunner:
                  tp_axis: Optional[str] = None, zero1: bool = False,
                  donate: Optional[bool] = None, multihost: bool = False):
         from ..fluid import guardian as _guardian
-        from ..fluid.executor import Executor
 
         self.program = program
         self.mesh = mesh
@@ -720,38 +696,26 @@ class ShardedWindowRunner:
         self.feed_per_step = bool(feed_per_step)
         self.fetch_names = [str(f) for f in fetch_names]
         self.n_user = len(self.fetch_names)
-        guard = _guardian.for_program(program)
+        plan, guard = _step.plan_step(
+            program, feed_names, self.fetch_names,
+            _guardian.for_program(program),
+            eager_error="sharded window: program contains data-dependent "
+                        "eager ops; use the per-step ParallelExecutor.run "
+                        "path")
         self.guard = guard
-        plan_fetches = list(self.fetch_names)
-        if guard is not None:
-            plan_fetches += guard.extra_fetch_names()
-        # the composed ShardedTrainStep supplies plan, spec table and all
-        # placement machinery; its per-step jit wrapper stays untraced
-        self.step = ShardedTrainStep(program, list(feed_names), plan_fetches,
-                                     mesh, tp_axis=tp_axis, zero1=zero1,
-                                     multihost=multihost)
-        plan = self.step.plan
-        if plan.needs_eager:
-            raise RuntimeError(
-                "sharded window: program contains data-dependent eager "
-                "ops; use the per-step ParallelExecutor.run path")
-        if guard is not None and guard.scale_vars:
-            # the scale/good-steps vars are read/written only by the
-            # guarded wrapper — gather them with the rest of state
-            for n in guard.scale_vars:
-                if n not in plan.state_in:
-                    plan.state_in.append(n)
-        self.plan = plan
+        # the composed ShardedTrainStep supplies spec table and all
+        # placement machinery; its per-step jit wrapper stays untraced.  It
+        # plans the same fetches itself, so its spec table (and the
+        # compile cache's fingerprint of it) has no entry for the scaler's
+        # variables; it then places the state of the plan that gathers them
+        self.step = ShardedTrainStep(program, list(feed_names),
+                                     plan.fetch_names, mesh, tp_axis=tp_axis,
+                                     zero1=zero1, multihost=multihost)
+        self.step.plan = self.plan = plan
         self.specs = self.step.specs
         if donate is None:
-            donate = Executor._donate_argnums(None, program) != ()
+            donate = _step.donate_argnums(program) != ()
         self.donate = bool(donate)
-
-        specs = self.specs
-
-        def trace(feed_vals, state_vals):
-            with mesh_scope(mesh), param_spec_scope(specs):
-                return trace_block(program, 0, plan, feed_vals, state_vals)
 
         rep = NamedSharding(mesh, P())
 
@@ -771,7 +735,7 @@ class ShardedWindowRunner:
 
         kfn = build_window_fn(program, plan, guard, self.n_user,
                               self.n_steps, self.feed_per_step,
-                              trace=trace, finalize=finalize)
+                              trace=self.step._trace, finalize=finalize)
         self._jit = jax.jit(kfn,
                             donate_argnums=(2,) if self.donate else ())
         self._compiled = None
@@ -852,14 +816,9 @@ class ShardedWindowRunner:
         ``Executor.run_steps``)."""
         import time as _time
 
-        from ..fluid import fault as _fault
-        from ..fluid import guardian as _guardian
-        from ..fluid import profiler as _prof
-        from ..fluid.executor import Executor
         from .. import compile_cache as _cc
         from .. import observe
         from ..observe import trace as _trace
-        from ..observe import watchdog as _watchdog
 
         scope = scope or global_scope()
         # the window span and its children (feed/state staging, dispatch,
@@ -870,69 +829,25 @@ class ShardedWindowRunner:
         with _trace.span("executor.window", n_steps=self.n_steps,
                          mesh=self.label):
             t_host0 = _time.perf_counter()
-            gb = self.program.global_block()
-            feed_arrays = {}
-            for k, v in dict(feed or {}).items():
-                if isinstance(v, jax.Array):
-                    feed_arrays[k] = v
-                    continue
-                arr = np.asarray(v)
-                if gb._has_var_recursive(k):
-                    want = core.np_dtype(gb._var_recursive(k).dtype)
-                    if arr.dtype != want:
-                        arr = arr.astype(want)
-                feed_arrays[k] = arr
+            feed_arrays = {k: _step.feed_dtype(self.program, k, v)
+                           for k, v in dict(feed or {}).items()}
             t_feed0 = _time.perf_counter()
             with _trace.span("executor.stage", what="feed"):
                 feed_dev = self.place_feed_window(feed_arrays)
             t_feed1 = _time.perf_counter()
 
-            window_start = 0
-            if self.program._params_grads is not None:
-                window_start = Executor._step_boundary(_fault, self.n_steps)
-            g = _guardian.current() if self.guard is not None else None
-            if g is not None:
-                # one-window-lag sentinel: observe the PREVIOUS dispatch's
-                # aggregated health and apply policy BEFORE this window
-                # runs
-                g.on_boundary()
+            d = _step.Dispatch(self.program, scope, self.plan, self.guard,
+                               self.n_steps, self.label)
             t_state0 = _time.perf_counter()
             with _trace.span("executor.stage", what="state"):
                 state_vals = self.step.place_state(scope)
             t_state1 = _time.perf_counter()
-            mut_names = set(self.plan.state_out)
-            if self.plan.needs_rng:
-                mut_names.add(RNG_STATE_VAR)
-            if self.guard is not None and self.guard.scale_vars:
-                mut_names.update(self.guard.scale_vars)
-            mut_state = {k: v for k, v in state_vals.items()
-                         if k in mut_names}
-            const_state = {k: v for k, v in state_vals.items()
-                           if k not in mut_names}
+            # sentinel inputs placed replicated explicitly: the AOT
+            # executable requires mesh-consistent input shardings
             rep = NamedSharding(self.mesh, P())
-            sentinel = None
-            dump_state = None
-            if self.guard is not None:
-                seed_mul, loss_mul = _fault.sentinel_injection_window(
-                    window_start, self.n_steps)
-                # sentinel inputs placed replicated explicitly: the AOT
-                # executable requires mesh-consistent input shardings
-                sentinel = {
-                    "loss_cap": jax.device_put(
-                        jnp.float32(g.loss_cap() if g is not None
-                                    else float("inf")), rep),
-                    "seed_mul": jax.device_put(jnp.asarray(seed_mul), rep),
-                    "loss_mul": jax.device_put(jnp.asarray(loss_mul), rep),
-                }
-                dump_state = state_vals
-                if g is not None and g.config.policy == "dump_and_halt" \
-                        and self.donate:
-                    # donation invalidates mutated input buffers after the
-                    # dispatch; dump mode keeps pre-window device copies
-                    # alive
-                    dump_state = {k: (jnp.array(v, copy=True)
-                                      if k in mut_names else v)
-                                  for k, v in state_vals.items()}
+            const_state, mut_state = d.split(
+                state_vals, self.donate,
+                place=lambda v: jax.device_put(jnp.asarray(v), rep))
 
             probe = None
             t = _time.perf_counter()
@@ -943,84 +858,40 @@ class ShardedWindowRunner:
                     probe = _cc.executor_probe(
                         self.program, feed_arrays, self.fetch_names,
                         extra=self.step.cache_extra(
-                            kind="sharded_window", n_steps=self.n_steps,
+                            "sharded_window", feed_arrays, self.guard,
+                            n_steps=self.n_steps,
                             feed_per_step=self.feed_per_step,
-                            donate=self.donate,
-                            guard=(self.guard.cache_token()
-                                   if self.guard is not None else None)),
+                            donate=self.donate),
                         spec_table=table_signature(self.specs))
                     # AOT compile once; the same Compiled serves every
                     # window AND yields the optimized HLO for the
                     # collective gauges, with no second trace/compile
                     # through the jit dispatch path
                     self._compiled = self._jit.lower(
-                        feed_dev, const_state, mut_state, sentinel).compile()
+                        feed_dev, const_state, mut_state,
+                        d.sentinel).compile()
                     self._note_collectives()
             observe.note_mesh(self.label)
             t_disp0 = _time.perf_counter()
-            agg = None
             with _trace.span("executor.dispatch", mesh=self.label):
-                if self.guard is not None:
-                    fetches, new_state, agg = self._compiled(
-                        feed_dev, const_state, mut_state, sentinel)
-                else:
-                    fetches, new_state = self._compiled(
-                        feed_dev, const_state, mut_state, sentinel)
-                if _prof.is_profiling() and self.guard is None:
-                    # fluid.profiler's timeline wants the device time; no
-                    # span, sink or PADDLE_TRACE setting ever waits here
-                    jax.block_until_ready((fetches, new_state))
+                fetches, new_state, agg = d.call(
+                    self._compiled, feed_dev, const_state, mut_state)
             t_disp1 = _time.perf_counter()
-            dt = t_disp1 - t
             with _trace.span("executor.observe"):
-                if _prof.is_profiling():
-                    _prof.record_event(
-                        f"executor_run[{len(self.plan.ops)}ops "
-                        f"x{self.n_steps}steps mesh={self.label}]", dt,
-                        start=t)
-                _prof.record_counter("executor.dispatches")
-                _prof.record_counter("executor.windows")
-                _prof.record_counter("executor.window_steps",
-                                     inc=self.n_steps)
-                reg = observe.registry()
-                labels = {"mesh": self.label}
-                reg.inc("executor.dispatches", labels=labels)
-                reg.inc("executor.windows", labels=labels)
-                reg.inc("executor.window_steps", self.n_steps, labels=labels)
-                if probe is not None:
-                    meta = {"kind": "sharded_window",
-                            "n_steps": self.n_steps, "mesh": self.label}
-                    if isinstance(self.memory, dict):
-                        # per-executable memory table in the cache
-                        # manifest, so a warm start re-reports HBM truth
-                        # without re-lowering
-                        meta["memory"] = self.memory
-                    probe.finish(dt, self.program, meta=meta)
-                if _fault.active() is not None:
-                    new_state = _fault.corrupt_state(new_state)
-                for name, val in new_state.items():
-                    scope.set(name, val)
-                Executor._check_nan_inf(
-                    list(new_state.items())
-                    + list(zip(self.plan.fetch_names, fetches)))
-                if g is not None and agg is not None:
-                    g.defer(self.guard, window_start, agg, {
-                        "program": self.program, "feeds": feed_arrays,
-                        "feed_lods": {}, "fetch_names": self.fetch_names,
-                        "state": dump_state, "sentinel": sentinel,
-                        "duration_s": dt,
-                        "window": {"start": window_start,
-                                   "n_steps": self.n_steps,
-                                   "feed_per_step": self.feed_per_step}})
-                if self.program._params_grads is not None:
-                    observe.note_step(window_start + self.n_steps - 1)
-                    from ..observe import memory as _obsmem
-
-                    # live-buffer ledger: mesh-labeled scope residency +
-                    # watermark at the window boundary
-                    _obsmem.note_scope_live(
-                        scope, scope_label="train", mesh=self.label,
-                        step=window_start + self.n_steps - 1)
+                meta = {"kind": "sharded_window", "n_steps": self.n_steps,
+                        "mesh": self.label}
+                if isinstance(self.memory, dict):
+                    # per-executable memory table in the cache manifest, so
+                    # a warm start re-reports HBM truth without re-lowering
+                    meta["memory"] = self.memory
+                new_state = _step.commit(scope, new_state)
+                # the one-off AOT lower+compile is compile state; the rest
+                # of the window is device compute
+                d.report(fetches, new_state, agg, t_host0, (t, t_disp1 - t),
+                         fresh_compile, compile_s=t_disp0 - t, probe=probe,
+                         meta=meta, feeds=feed_arrays, feed_lods={},
+                         fetch_names=self.fetch_names,
+                         feed_per_step=self.feed_per_step)
             t_obs1 = _time.perf_counter()
             stage_ms = ((t_feed1 - t_feed0) + (t_state1 - t_state0)) * 1e3
             _trace.note_window_breakdown(
@@ -1029,21 +900,6 @@ class ShardedWindowRunner:
                 dispatch_ms=(t_disp1 - t_disp0) * 1e3,
                 observe_ms=(t_obs1 - t_disp1) * 1e3,
                 mesh=self.label)
-            if self.program._params_grads is not None:
-                _watchdog.observe_value(
-                    "executor.step_time_s",
-                    (t_obs1 - t_host0) / max(1, self.n_steps),
-                    step=window_start + self.n_steps - 1, mesh=self.label)
-                from ..observe import goodput as _goodput
-
-                # goodput ledger: the one-off AOT lower+compile is compile
-                # state; the rest of the window is device compute
-                cdur = t_disp0 - t if fresh_compile else 0.0
-                if cdur > 0.0:
-                    _goodput.note("compile", cdur, mesh=self.label)
-                _goodput.note("device",
-                              max(0.0, (t_obs1 - t_host0) - cdur),
-                              mesh=self.label)
             if return_numpy:
                 return [np.asarray(self.step.fetch_to_host(v))
                         for v in fetches]

@@ -242,7 +242,7 @@ def device_buffered(reader, size=None, place=None):
     host→device transfer for every array in the sample, so samples arrive
     at the consumer already device-resident — the H2D copy overlaps the
     consumer's compute instead of serializing with it (the Executor passes
-    pre-placed jax arrays straight through, ``Executor._coerce_feed``).
+    pre-placed jax arrays straight through, ``fluid.step.coerce_feed``).
 
     ``size`` bounds the number of in-flight staged samples (default
     ``PADDLE_TPU_PREFETCH_DEPTH``); worker exceptions propagate to the

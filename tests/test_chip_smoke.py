@@ -144,14 +144,6 @@ def test_tpu_place_resolves_when_pinned_to_cpu():
     assert core.get_jax_device(fluid.TPUPlace(3)).id == 3
 
 
-def test_peak_rate_of_unknown_device_is_an_error():
-    from paddle_tpu.observe import trace
-
-    assert trace.peak_tflops("TPU v5 lite") == 197.0
-    with pytest.raises(KeyError, match="unknown"):
-        trace.peak_tflops("unknown")
-
-
 @pytest.mark.parametrize("env_dir", [None, "/some/dir"])
 def test_backend_cache_dir_is_decided_in_one_place(env_dir, monkeypatch,
                                                    tmp_path):

@@ -571,21 +571,3 @@ def test_fused_smoke_tool():
     assert report["ok"] and report["killswitch_bitwise"]
     assert report["ops_fused_softmax_xent"] > 0
     assert report["ops_fused_adam"] > 0
-
-
-def test_bench_kernels_smoke():
-    """tools/bench_kernels.py --smoke: every kernel family benches fused
-    vs unfused with parity asserted, one parseable JSON line each."""
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "bench_kernels.py"),
-         "--smoke"],
-        capture_output=True, text=True, timeout=300,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert r.returncode == 0, r.stdout + r.stderr
-    rows = [json.loads(line) for line in r.stdout.splitlines() if line]
-    kernels = {row["kernel"] for row in rows}
-    assert kernels == {"softmax_xent", "flash_attention", "adam",
-                       "momentum"}
-    for row in rows:
-        assert "error" not in row, row
-        assert row["max_err"] < 1e-3

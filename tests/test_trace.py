@@ -634,34 +634,6 @@ def test_trace_smoke_tool():
     assert report["elapsed_s"] < 5.0, report
 
 
-def test_bench_gate_tool(tmp_path):
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import bench_gate
-    finally:
-        sys.path.pop(0)
-
-    def write_round(n, resnet, trf):
-        tail = "\n".join([
-            json.dumps({"metric": "resnet", "value": resnet,
-                        "unit": "i/s", "vs_baseline": 0.1}),
-            json.dumps({"metric": "trf", "value": trf, "unit": "t/s",
-                        "vs_baseline": 0.1}),
-        ]) + "\n"
-        with open(os.path.join(str(tmp_path), f"BENCH_r{n:02d}.json"),
-                  "w") as f:
-            json.dump({"n": n, "tail": tail, "parsed": {}}, f)
-
-    write_round(1, 100.0, 5000.0)
-    write_round(2, 90.0, 5100.0)  # -10%: inside a 25% threshold
-    assert bench_gate.main(["--dir", str(tmp_path), "--json"]) == 0
-    write_round(3, 40.0, 5100.0)  # -55% vs round 2: regression
-    assert bench_gate.main(["--dir", str(tmp_path), "--json"]) == 1
-    # single round: nothing to compare, never blocks
-    assert bench_gate.main(["--dir", str(tmp_path / "empty"),
-                            "--json"]) == 0
-
-
 def test_span_emission_thread_safe(tmp_path):
     """Many threads opening/closing spans concurrently: every span lands
     exactly once and the context stacks never cross threads."""
