@@ -47,7 +47,7 @@ def ring_attention_op(ctx):
                     pallas_fused.flash_tp_axis(q, mesh))
             else:
                 out = flash_attention(q, k, v, bias, scale, causal)
-                pallas_fused._note("flash_attention")
+                pallas_fused.note_flash(q, k, v)
         else:
             out = ra.full_attention(q, k, v, causal, scale, bias=bias)
     else:

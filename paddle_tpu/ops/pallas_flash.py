@@ -4,9 +4,24 @@ The hot op of the transformer/BERT path gets hand-scheduled kernels
 (SURVEY.md §7.3: "Pallas only where XLA underperforms"): one grid step
 owns a [BLOCK, D] tile resident in VMEM and streams the opposing tiles
 through the MXU with the online-softmax recurrence, so the [T, T] score
-matrix never hits HBM — forward, dQ, and dK/dV alike.  Accumulation is
-fp32 in VMEM scratch regardless of the input dtype (the same
-master-accumulator discipline as fluid.amp).
+matrix never hits HBM — forward, dQ, and dK/dV alike.  Every contraction
+takes q, k, v and dO in the dtype they arrive in (bf16 under AMP) and
+accumulates in fp32; the tiles a kernel makes itself (P, dS) are rounded
+to the other operand's dtype at the contraction and nowhere else.
+Scores, softmax statistics, masks and the VMEM accumulators are fp32
+regardless of the input dtype (the same master-accumulator discipline as
+fluid.amp).  (On the v5e, at d = 64, Mosaic multiplies float32 tiles in
+one bf16 pass too: float32 copies of bf16 operands gave the same bits in
+the same time, PERF.md Findings PR 33.  What a grid step pays for is its
+trips through VMEM scratch and every relayout, hence:)
+
+Where one tile pair covers the sequence (t <= the block size: the
+Transformer-base step's 256) nothing is carried from one grid step to
+the next, and each kernel writes its tile's result straight out instead
+of through the scratch state.  All three kernels hold scores as [bq, bk]
+— queries on sublanes, keys on lanes — so lse and delta, [bq, 1] columns
+as the forward wrote them, broadcast along lanes; dK/dV contract P and dS
+over their first dim rather than asking for those columns as rows.
 
 Backward (Dao FlashAttention-2 formulation): the forward emits the
 per-row logsumexp L, so each backward tile recomputes P = exp(S - L)
@@ -56,25 +71,72 @@ def _causal_mask(logits, q_off, k_off):
     return jnp.where(qpos >= kpos, logits, jnp.float32(NEG_INF))
 
 
+def _dot(a, b, contract):
+    """``a`` contracted with ``b`` over dims ``contract`` = (of a, of b),
+    accumulated in fp32."""
+    return jax.lax.dot_general(
+        a, b, (((contract[0],), (contract[1],)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _logits(q, k, bias_ref, scale, causal, q_off, k_off):
+    """scale · q kᵀ + key bias, causal-masked: fp32 [bq, bk], queries on
+    sublanes and keys on lanes in all three kernels, so the per-query
+    columns (max, sum, lse, delta: [bq, 1]) broadcast along lanes and are
+    never turned into rows."""
+    s = _dot(q, k, (1, 1)) * jnp.float32(scale)
+    if bias_ref is not None:
+        s = s + bias_ref[0].astype(jnp.float32)        # [1, bk]
+    if causal:
+        s = _causal_mask(s, q_off, k_off)
+    return s
+
+
+def _tile_offsets(q_ref, k_ref, qi, ki):
+    # all index math in i32: under the package-wide x64 mode python ints
+    # promote to i64, which Mosaic's index ops reject
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    q_off = qi * jnp.int32(bq)
+    k_off = ki * jnp.int32(bk)
+    return q_off, k_off, k_off <= q_off + jnp.int32(bq - 1)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, n_k,
                   has_bias):
     """Forward grid step (bh, q-block, k-block): one [bq, d] query tile
     against one [bk, d] K/V tile, online-softmax state (m, l, acc) in fp32
     VMEM scratch carried across the (sequential, minormost) k dimension —
-    VMEM holds one K/V TILE at a time, t_kv can be arbitrarily long."""
+    VMEM holds one K/V TILE at a time, t_kv can be arbitrarily long.
+    Where one K/V tile IS the sequence (n_k == 1) there is nothing to
+    carry: the softmax of the tile goes straight to the results."""
     if has_bias:
         bias_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
         bias_ref = None
     ki = pl.program_id(2)
-    qi = pl.program_id(1)
-    bq = q_ref.shape[1]
-    bk = k_ref.shape[1]
-    # all index math in i32: under the package-wide x64 mode python ints
-    # promote to i64, which Mosaic's index ops reject
-    q_off = qi * jnp.int32(bq)
-    k_off = ki * jnp.int32(bk)
+    q_off, k_off, on_or_below_diagonal = _tile_offsets(
+        q_ref, k_ref, pl.program_id(1), ki)
+
+    def tile(m_old):
+        logits = _logits(q_ref[0], k_ref[0], bias_ref, scale, causal,
+                         q_off, k_off)
+        m = jnp.max(logits, axis=1, keepdims=True)
+        if m_old is not None:
+            m = jnp.maximum(m_old, m)
+        p = jnp.exp(logits - m)
+        v = v_ref[0]
+        return (m, jnp.sum(p, axis=1, keepdims=True),
+                _dot(p.astype(v.dtype), v, (1, 0)))
+
+    def flush(m, l, acc):
+        l = jnp.maximum(l, jnp.float32(1e-30))
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
+        lse_ref[0] = m + jnp.log(l)
+
+    if n_k == 1:
+        flush(*tile(None))
+        return
 
     @pl.when(ki == 0)
     def _init():
@@ -84,80 +146,62 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, n_k,
 
     # under causal masking, blocks strictly above the diagonal contribute
     # nothing — skip both MXU contractions for them (~2x FLOPs at long T)
-    live = (k_off <= q_off + jnp.int32(bq - 1)) if causal else True
-
-    @pl.when(live)
+    @pl.when(on_or_below_diagonal if causal else True)
     def _attend():
-        q = q_ref[0].astype(jnp.float32) * jnp.float32(scale)  # [bq, d]
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)   # [bq, bk]
-        if bias_ref is not None:
-            logits = logits + bias_ref[0].astype(jnp.float32)  # [1, bk]
-        if causal:
-            logits = _causal_mask(logits, q_off, k_off)
-        m = m_ref[:]
-        l = l_ref[:]
-        m_new = jnp.maximum(m, jnp.max(logits, axis=1, keepdims=True))
-        p = jnp.exp(logits - m_new)
-        corr = jnp.exp(m - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = l * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        m_old = m_ref[:]
+        m, l, pv = tile(m_old)
+        corr = jnp.exp(m_old - m)
+        m_ref[:] = m
+        l_ref[:] = l_ref[:] * corr + l
+        acc_ref[:] = acc_ref[:] * corr + pv
 
     @pl.when(ki == n_k - 1)
     def _flush():
-        l = jnp.maximum(l_ref[:], jnp.float32(1e-30))
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[:] + jnp.log(l)
+        flush(m_ref[:], l_ref[:], acc_ref[:])
+
+
+def _backward_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   bias_ref, scale, causal, q_off, k_off):
+    """P and dS of one tile pair, fp32 [bq, bk], from the forward's lse
+    and delta = rowsum(dO ∘ O)."""
+    s = _logits(q_ref[0], k_ref[0], bias_ref, scale, causal, q_off, k_off)
+    p = jnp.exp(s - lse_ref[0])
+    dp = _dot(do_ref[0], v_ref[0], (1, 1))
+    return p, p * (dp - delta_ref[0])
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                scale, causal, n_k, has_bias):
     """dQ grid step (bh, q-block, k-block): q/dO/lse/delta tiles resident,
-    K/V tiles stream; dq accumulates in fp32 scratch over ki."""
+    K/V tiles stream; dq accumulates in fp32 scratch over ki (one K/V
+    tile: straight to the result)."""
     if has_bias:
         bias_ref, dq_ref, dq_acc = rest
     else:
         dq_ref, dq_acc = rest
         bias_ref = None
     ki = pl.program_id(2)
-    qi = pl.program_id(1)
-    bq, bk = q_ref.shape[1], k_ref.shape[1]
-    q_off = qi * jnp.int32(bq)
-    k_off = ki * jnp.int32(bk)
+    q_off, k_off, on_or_below_diagonal = _tile_offsets(
+        q_ref, k_ref, pl.program_id(1), ki)
+
+    def tile():
+        _, ds = _backward_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                               delta_ref, bias_ref, scale, causal, q_off,
+                               k_off)
+        k = k_ref[0]
+        return jnp.float32(scale) * _dot(ds.astype(k.dtype), k, (1, 0))
+
+    if n_k == 1:
+        dq_ref[0] = tile().astype(dq_ref.dtype)
+        return
 
     @pl.when(ki == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    live = (k_off <= q_off + jnp.int32(bq - 1)) if causal else True
-
-    @pl.when(live)
+    @pl.when(on_or_below_diagonal if causal else True)
     def _accum():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * jnp.float32(scale)
-        if bias_ref is not None:
-            s = s + bias_ref[0].astype(jnp.float32)
-        if causal:
-            s = _causal_mask(s, q_off, k_off)
-        p = jnp.exp(s - lse_ref[0])                     # [bq, bk]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [bq, bk]
-        ds = p * (dp - delta_ref[0])
-        dq_acc[:] += jnp.float32(scale) * jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dq_acc[:] += tile()
 
     @pl.when(ki == n_k - 1)
     def _flush():
@@ -167,53 +211,44 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                 scale, causal, n_q, has_bias):
     """dK/dV grid step (bh, k-block, q-block): K/V tiles resident, Q/dO/
-    lse/delta tiles stream; dk/dv accumulate in fp32 scratch over qi."""
+    lse/delta tiles stream; dk/dv accumulate in fp32 scratch over qi (one
+    Q tile: straight to the results).  P and dS are [bq, bk] as in dQ and
+    are contracted over their FIRST dim (Pᵀ dO, dSᵀ Q): the MXU takes the
+    transposed operand, where a [bk, bq] tile would want lse and delta as
+    rows, a relayout of two [bq, 1] columns every step."""
     if has_bias:
         bias_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
     else:
         dk_ref, dv_ref, dk_acc, dv_acc = rest
         bias_ref = None
     qi = pl.program_id(2)
-    kjj = pl.program_id(1)
-    bq, bk = q_ref.shape[1], k_ref.shape[1]
-    q_off = qi * jnp.int32(bq)
-    k_off = kjj * jnp.int32(bk)
+    q_off, k_off, on_or_below_diagonal = _tile_offsets(
+        q_ref, k_ref, qi, pl.program_id(1))
+
+    def tile():
+        p, ds = _backward_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                               delta_ref, bias_ref, scale, causal, q_off,
+                               k_off)
+        q, do = q_ref[0], do_ref[0]
+        return (jnp.float32(scale) * _dot(ds.astype(q.dtype), q, (0, 0)),
+                _dot(p.astype(do.dtype), do, (0, 0)))
+
+    if n_q == 1:
+        dk, dv = tile()
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+        return
 
     @pl.when(qi == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    live = (q_off + jnp.int32(bq - 1) >= k_off) if causal else True
-
-    @pl.when(live)
+    @pl.when(on_or_below_diagonal if causal else True)
     def _accum():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        # [bk, bq] orientation: k rows resident
-        st = jax.lax.dot_general(
-            k, q, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * jnp.float32(scale)
-        if bias_ref is not None:
-            # key-bias is constant along q: one column vector [bk, 1]
-            st = st + bias_ref[0].reshape(bk, 1).astype(jnp.float32)
-        if causal:
-            kpos = k_off + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
-            qpos = q_off + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
-            st = jnp.where(qpos >= kpos, st, jnp.float32(NEG_INF))
-        pt = jnp.exp(st - lse_ref[0].reshape(1, bq))    # [bk, bq]
-        dv_acc[:] += jax.lax.dot_general(
-            pt, do, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dpt = jax.lax.dot_general(
-            v, do, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [bk, bq]
-        dst = pt * (dpt - delta_ref[0].reshape(1, bq))
-        dk_acc[:] += jnp.float32(scale) * jax.lax.dot_general(
-            dst, q, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dk, dv = tile()
+        dk_acc[:] += dk
+        dv_acc[:] += dv
 
     @pl.when(qi == n_q - 1)
     def _flush():

@@ -759,5 +759,15 @@ def flash_attention_sharded(q, k, v, bias, scale, causal, mesh,
         in_specs.append(P(ba, *([None] * (bias.ndim - 1))))
     out = _shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                      out_specs=spec, check_vma=False)(*args)
-    _note("flash_attention")
+    note_flash(q, k, v)
     return out
+
+
+def note_flash(q, k, v) -> None:
+    """A dispatch through ``pallas_flash.flash_attention``: the family's
+    counter, and beside it the dtype its contractions take their operands
+    in (the kernels contract q, k, v and dO as they arrive: bf16 under
+    AMP, float32 otherwise)."""
+    _note("flash_attention")
+    _note("flash_contraction",
+          operands="/".join(sorted({str(a.dtype) for a in (q, k, v)})))

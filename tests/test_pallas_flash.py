@@ -162,6 +162,136 @@ def test_flash_bf16_inputs():
     np.testing.assert_allclose(out, ref, rtol=2e-2, atol=2e-2)
 
 
+_CASES = [(False, False), (True, False), (False, True), (True, True)]
+
+
+def _kernel_dots(jaxpr, found=None):
+    """Every ``dot_general`` inside the ``pallas_call`` bodies of a jaxpr:
+    ``{kernel name: [(lhs dtype, rhs dtype, result dtype), ...]}``."""
+    found = {} if found is None else found
+
+    def walk(jp, kernel):
+        for eqn in jp.eqns:
+            inner = kernel
+            if eqn.primitive.name == "pallas_call":
+                inner = eqn.params["jaxpr"].debug_info.func_name
+            elif kernel and eqn.primitive.name == "dot_general":
+                found.setdefault(kernel, []).append(
+                    tuple(str(v.aval.dtype)
+                          for v in (*eqn.invars, eqn.outvars[0])))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, inner)
+
+    walk(jaxpr.jaxpr, None)
+    return found
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("block", [16, 32], ids=["two_tiles", "one_tile"])
+@pytest.mark.parametrize("causal,with_bias", _CASES)
+def test_flash_contracts_in_the_operands_dtype(causal, with_bias, block,
+                                               dtype):
+    """The nine contractions of the three kernels take q, k, v and dO in
+    the dtype they arrive in and accumulate in float32, whether the tile
+    pair's result goes through scratch or straight out; a float32 program
+    keeps float32 products."""
+    rng = np.random.RandomState(8)
+    q, k, v = (x.astype(dtype) for x in _qkv(rng, t=32))
+    bias = _key_bias(rng, 2, 32) if with_bias else None
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, bias, causal=causal, block_q=block,
+                               block_k=block).astype(jnp.float32).sum()
+
+    dots = _kernel_dots(jax.make_jaxpr(loss)(q, k, v))
+    _kernel_dots(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v), dots)
+    assert sorted(dots) == ["_dkv_kernel", "_dq_kernel", "_flash_kernel"]
+    # 2 contractions in the forward, traced for the loss and for its grad
+    assert [len(dots[n]) for n in sorted(dots)] == [4, 3, 4]
+    for name, found in dots.items():
+        assert set(found) == {(dtype, dtype, "float32")}, (name, found)
+
+
+def _rel_l2(got, ref):
+    got, ref = (np.asarray(a, np.float64) for a in (got, ref))
+    return float(np.linalg.norm(got - ref) / np.linalg.norm(ref))
+
+
+@pytest.mark.parametrize("block", [128, 256], ids=["two_tiles", "one_tile"])
+@pytest.mark.parametrize("causal,with_bias", _CASES)
+def test_flash_bf16_gradients_against_float32_reference(causal, with_bias,
+                                                        block):
+    """bf16 q, k, v and dO at the cell's [.,.,256,64] a head, as one tile
+    pair (the cell's own: straight to the results) and as two (the state
+    carried through scratch): dQ, dK, dV against the float32 reference on
+    the float32 casts of the SAME inputs.  What separates them is the
+    bf16 rounding of P, dS and of the results (readings here 2.3e-3 to
+    2.5e-3; 1.7e-3 to 1.8e-3 with float32 products on the CPU, the
+    results' own rounding; on the v5e both read the same bits)."""
+    rng = np.random.RandomState(9)
+    q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(rng, h=4, t=256, d=64))
+    do = jnp.asarray(rng.normal(size=q.shape), jnp.bfloat16)
+    bias = _key_bias(rng, 2, 256) if with_bias else None
+
+    _, vjp = jax.vjp(lambda q, k, v: flash_attention(
+        q, k, v, bias, causal=causal, block_q=block, block_k=block),
+        q, k, v)
+    got = vjp(do)
+    ref = flash_bwd_reference(*(x.astype(jnp.float32)
+                                for x in (q, k, v, do)),
+                              bias=bias, causal=causal)
+    for g, r, n in zip(got, ref, ("dq", "dk", "dv")):
+        assert g.dtype == jnp.bfloat16
+        assert _rel_l2(g.astype(jnp.float32), r) <= 5e-3, n
+
+
+@pytest.mark.parametrize("block", [16, 32], ids=["two_tiles", "one_tile"])
+@pytest.mark.parametrize("causal,with_bias", _CASES)
+def test_flash_float32_is_still_float32(causal, with_bias, block):
+    """float32 inputs (AMP off): output and gradients at the float32
+    tolerances this file already holds, with a scale that is no power of
+    two (d = 24), now applied to the scores and not to q."""
+    rng = np.random.RandomState(10)
+    q, k, v = _qkv(rng, t=32, d=24)
+    do = jnp.asarray(rng.normal(size=q.shape).astype(np.float32))
+    bias = _key_bias(rng, 2, 32) if with_bias else None
+
+    out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+        q, k, v, bias, causal=causal, block_q=block, block_k=block),
+        q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(full_attention(q, k, v, causal,
+                                                   bias=bias)),
+        rtol=2e-5, atol=2e-5)
+    ref = flash_bwd_reference(q, k, v, do, bias=bias, causal=causal)
+    for g, r, n in zip(vjp(do), ref, ("dq", "dk", "dv")):
+        assert g.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=5e-4, atol=5e-4, err_msg=n)
+
+
+@pytest.mark.parametrize("tq,tk", [(64, 32), (32, 64)])
+def test_flash_cross_lengths_mix_one_and_two_tiles(tq, tk):
+    """Cross attention whose one side is a single tile and whose other is
+    two: the kernels that carry state and those that write straight to
+    their results meet in one backward."""
+    rng = np.random.RandomState(12)
+    q, _, _ = _qkv(rng, t=tq)
+    _, k, v = _qkv(rng, t=tk)
+    do = jnp.asarray(rng.normal(size=q.shape).astype(np.float32))
+    bias = _key_bias(rng, 2, tk)
+    out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+        q, k, v, bias, block_q=32, block_k=32), q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(full_attention(q, k, v, False,
+                                                   bias=bias)),
+        rtol=2e-5, atol=2e-5)
+    ref = flash_bwd_reference(q, k, v, do, bias=bias)
+    for g, r, n in zip(vjp(do), ref, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=5e-4, atol=5e-4, err_msg=n)
+
+
 def test_flash_op_hookup_env_gated(monkeypatch):
     import paddle_tpu.fluid as fluid
 
@@ -181,6 +311,52 @@ def test_flash_op_hookup_env_gated(monkeypatch):
     (l2,) = exe2.run(fluid.default_main_program(), feed={"x": xa},
                      fetch_list=[loss])
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l2), rtol=1e-5)
+
+
+def test_flash_contraction_counter_names_the_operands_dtype(monkeypatch):
+    """Lowering the two-layer Transformer under bf16 AMP counts
+    ``ops.fused.flash_contraction{operands="bfloat16"}`` once for each of
+    its 6 attention ops and once for each grad op (which traces the
+    forward again), none under float32, and leaves the unlabelled
+    ``ops.fused.flash_attention`` what it was; the same program without AMP
+    counts them under float32."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.models import transformer
+
+    monkeypatch.setenv("PADDLE_TPU_FLASH", "1")
+    cfg = transformer.tiny_config()
+    batch, seq = 2, 16
+    _, _, _, loss = transformer.build(cfg, src_len=seq, tgt_len=seq, lr=1e-3)
+    prog = fluid.default_main_program()
+    ops = [op.type for op in prog.global_block().ops]
+    assert ops.count("ring_attention") == 6 == ops.count(
+        "ring_attention_grad")
+    rng = np.random.RandomState(11)
+    tgt = rng.randint(1, cfg.tgt_vocab_size, size=(batch, seq))
+    feed = {"src_word": rng.randint(1, cfg.src_vocab_size,
+                                    size=(batch, seq)).astype(np.int64),
+            "tgt_word": tgt.astype(np.int64),
+            "lbl_word": tgt[..., None].astype(np.int64)}
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+
+    def lowered_counts():
+        before = fluid.profiler.counters()
+        exe.lower_step(prog, feed, [loss])
+        after = fluid.profiler.counters()
+        return {k.replace("ops.fused.", ""): after[k] - before.get(k, 0)
+                for k in after if k.startswith("ops.fused.flash")
+                and after[k] != before.get(k, 0)}
+
+    try:
+        fluid.amp.enable("bfloat16", keep_activations=True)
+        assert lowered_counts() == {
+            "flash_attention": 12,
+            'flash_contraction{operands="bfloat16"}': 12}
+    finally:
+        fluid.amp.disable()
+    assert lowered_counts() == {
+        "flash_attention": 12, 'flash_contraction{operands="float32"}': 12}
 
 
 def test_flash_trains_flagship_transformer():

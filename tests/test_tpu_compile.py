@@ -63,17 +63,17 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-def _flash(causal, bias):
+def _flash(causal, bias, t=T):
     def fwd(q, k, v, *b):
         return pallas_flash.flash_attention(
             q, k, v, b[0] if b else None, None, causal, 256, 256, False)
 
-    qkv = [((B, H, T, D), BF16)] * 3
-    return fwd, qkv + ([((B, 1, 1, T), F32)] if bias else [])
+    qkv = [((B, H, t, D), BF16)] * 3
+    return fwd, qkv + ([((B, 1, 1, t), F32)] if bias else [])
 
 
-def _flash_bwd(causal, bias):
-    fwd, shapes = _flash(causal, bias)
+def _flash_bwd(causal, bias, t=T):
+    fwd, shapes = _flash(causal, bias, t)
 
     def bwd(q, k, v, *b):
         return jax.grad(lambda *a: fwd(*a, *b).astype(F32).sum(),
@@ -153,6 +153,11 @@ CASES = {
     "flash_fwd_key_bias": (lambda: _flash(False, True), 1),   # encoder/cross
     "flash_bwd_causal": (lambda: _flash_bwd(True, False), 3),
     "flash_bwd_key_bias": (lambda: _flash_bwd(False, True), 3),
+    # two tiles a sequence: the state carried through VMEM scratch, which
+    # the step's own length (one tile pair) never enters
+    "flash_bwd_causal_two_tiles": (lambda: _flash_bwd(True, False, 512), 3),
+    "flash_bwd_key_bias_two_tiles": (lambda: _flash_bwd(False, True, 512),
+                                     3),
     "xent_fwd_soft": (lambda: _xent(True), 1),    # label smoothing: the step's
     "xent_bwd_soft": (lambda: _xent_bwd(True), 2),
     "xent_fwd_hard": (lambda: _xent(False), 1),
@@ -173,6 +178,50 @@ def test_kernel_compiles_for_v5e(topo, name):
     args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert compiled.as_text().count("tpu_custom_call") >= n_calls
+
+
+#: what ``chipbench/kernels/flash_*.py`` count FLOPs from and what
+#: ``chipbench/trace_reduce.kernel_roofline`` matches trace events by: family
+#: -> (kernel, contractions, plain operands, results).  The benchmark's files
+#: are not a kernel PR's to edit, so a kernel that changes its name, its
+#: operands or their order, or its results silently leaves the benchmark.
+_BH = B * H
+_TILE, _ROW = f"bf16[{_BH},{T},{D}]", f"f32[{_BH},{T},1]"
+FLASH_FAMILIES = {
+    "flash_fwd": ("_flash_kernel", 2, [_TILE] * 3, [_TILE, _ROW]),
+    "flash_dq": ("_dq_kernel", 3, [_TILE] * 4 + [_ROW] * 2, [_TILE]),
+    "flash_dkv": ("_dkv_kernel", 4, [_TILE] * 4 + [_ROW] * 2, [_TILE] * 2),
+}
+
+
+@pytest.mark.parametrize("causal,bias", [(True, False), (False, True)])
+def test_flash_signatures_are_the_benchmarks(topo, causal, bias):
+    """Forward + backward at the cell's shapes, lowered for the described
+    chip: the three kernels' names, operand and result types are what the
+    benchmark's family files expect (3 / 6 / 6 plain operands, ``[b*h, t,
+    d]`` first, the key bias last), and their FLOP counts read them so."""
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from chipbench import hlo
+    from chipbench.plugins import load
+
+    fn, shapes = _flash_bwd(causal, bias)
+    chip = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    calls = {c.kernel: c for c in
+             hlo.custom_calls(jax.jit(fn).lower(*args).as_text())}
+    assert sorted(calls) == sorted(k for k, *_ in FLASH_FAMILIES.values())
+    key_bias = [f"f32[{B},1,{T}]"] if bias else []
+    for family, (kernel, matmuls, operands, results) in \
+            FLASH_FAMILIES.items():
+        module, call = load("kernels", family), calls[kernel]
+        assert module.KERNEL == kernel
+        assert hlo.signature(call) == \
+            ",".join(results) + "<-" + ",".join(operands + key_bias), family
+        assert module.flops(call.operands, call.results) == \
+            2.0 * matmuls * _BH * T * T * D / (2 if causal else 1), family
 
 
 def _momentum_op(p, g, v, lr):
