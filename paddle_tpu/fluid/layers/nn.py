@@ -34,7 +34,7 @@ __all__ = [
     "lod_reset", "prelu", "dice_loss", "log_loss", "huber_loss",
     "ring_attention", "moe_ffn", "gpipe_mlp_stack",
     "rms_norm", "rotary_embedding", "sparse_indexer", "sparse_attention",
-    "moe_experts",
+    "moe_experts", "moe_bias_update",
     "kv_cache_update", "kv_cache_scatter", "token_select",
     "paged_attention", "spec_accept",
     "transformer_encoder_stack", "transformer_decoder_stack", "cos_sim",
@@ -1373,13 +1373,16 @@ def sparse_indexer(input, num_heads, head_dim, topk, theta=10000.0,
 
 
 def sparse_attention(q, k, v, selection=None, scale=None, flash=None,
-                     name=None):
+                     window=0, name=None):
     """Causal grouped-query attention, optionally over a per-query
     selection of keys (a sibling of ``ring_attention``; ops/decoder_ops.py
     + ops/pallas_sparse_flash.py).  q: [B, Hq, T, D]; k, v: [B, Hkv, T, D]
     with Hq a multiple of Hkv (query head h reads head h // (Hq / Hkv));
     ``selection``: [B, T, T] int8 from ``sparse_indexer`` or None (every
-    key s <= t).  ``flash`` as in ``ring_attention``: the Pallas kernels (the selection as a mask inside
+    key s <= t).  ``window``: 0, or a causal window: key s counts for query
+    t iff ``0 <= t - s < window`` (a static band that the kernels' grids
+    are cut to; never a [B, T, T] mask).  ``flash`` as in
+    ``ring_attention``: the Pallas kernels (the selection as a mask inside
     them) or the blocked XLA path; nothing [Hq, T, T] reaches HBM either
     way."""
     helper = LayerHelper("sparse_attention", **locals())
@@ -1400,12 +1403,15 @@ def sparse_attention(q, k, v, selection=None, scale=None, flash=None,
         type="sparse_attention", inputs=inputs,
         outputs={"Out": [out], "Lse": [lse]},
         attrs={"scale": float(scale or 0.0), "topk": int(topk),
-               "flash": -1 if flash is None else int(bool(flash))})
+               "flash": -1 if flash is None else int(bool(flash)),
+               **({"window": int(window)} if window else {})})
     return out
 
 
 def moe_experts(input, num_routed, experts_held, hidden_size, top_k,
-                expert_offset=0, norm_topk=True, param_attr=None, name=None):
+                expert_offset=0, norm_topk=True, param_attr=None, name=None,
+                score="softmax", select_bias=False, norm_eps=0.0,
+                route_scale=1.0):
     """The share of a routed expert layer that ``experts_held`` of its
     ``num_routed`` experts give (parallel/moe.py ``routed_experts``): the
     router is ``num_routed`` wide and every token picks its ``top_k`` over
@@ -1416,7 +1422,18 @@ def moe_experts(input, num_routed, experts_held, hidden_size, top_k,
     experts would add is left out.  ``held = num_routed`` is the whole
     layer.  Unlike ``moe_ffn`` (dense [N, E, C] dispatch with a capacity
     that drops, ReLU experts with biases, every expert held) this sorts the
-    assignments by expert and multiplies them as grouped products."""
+    assignments by expert and multiplies them as grouped products.
+
+    ``score``: ``softmax`` over the router's outputs, or ``sigmoid`` of
+    each; the chosen scores are renormalized where ``norm_topk`` (over
+    their sum + ``norm_eps``) and multiplied by ``route_scale``.
+    ``select_bias``: the router also holds ``<name>_route_bias``
+    ([num_routed] float32, zeros, persistable, NOT trainable), added to the
+    scores for the choice of the ``top_k`` only, never to the weights.  The
+    layer then returns ``(out, bias, counts)``, ``counts`` [num_routed]
+    int32 being the step's assignments to every routed expert:
+    ``moe_bias_update(bias, counts, coeff)``, appended after
+    ``optimizer.minimize``, is the rule that moves the bias."""
     from ..initializer import XavierInitializer
 
     helper = LayerHelper("moe_experts", **locals())
@@ -1440,16 +1457,51 @@ def moe_experts(input, num_routed, experts_held, hidden_size, top_k,
         p.dist_hint = "ep"
     out = helper.create_variable_for_type_inference(dtype)
     out.shape = tuple(input.shape)
-    helper.append_op(
-        type="moe_experts",
-        inputs={"X": [input], "RouterW": [router], "W1": [w1], "W3": [w3],
-                "W2": [w2]},
-        outputs={"Out": [out]},
-        attrs={"num_routed": int(num_routed),
-               "experts_held": int(experts_held),
-               "expert_offset": int(expert_offset), "top_k": int(top_k),
-               "norm_topk": bool(norm_topk)})
-    return out
+    inputs = {"X": [input], "RouterW": [router], "W1": [w1], "W3": [w3],
+              "W2": [w2]}
+    outputs = {"Out": [out]}
+    attrs = {"num_routed": int(num_routed),
+             "experts_held": int(experts_held),
+             "expert_offset": int(expert_offset), "top_k": int(top_k),
+             "norm_topk": bool(norm_topk)}
+    # only what departs from the softmax router is written into the op
+    if score != "softmax":
+        attrs["score"] = str(score)
+    if norm_eps:
+        attrs["norm_eps"] = float(norm_eps)
+    if route_scale != 1.0:
+        attrs["route_scale"] = float(route_scale)
+    bias = counts = None
+    if select_bias:
+        from . import tensor
+
+        bias = tensor.create_global_var(
+            [num_routed], 0.0, "float32", persistable=True,
+            name=None if name is None else f"{name}_route_bias")
+        bias.stop_gradient = True
+        counts = helper.create_variable_for_type_inference(
+            "int32", stop_gradient=True)
+        counts.shape = (num_routed,)
+        inputs["Bias"], outputs["Counts"] = [bias], [counts]
+    helper.append_op(type="moe_experts", inputs=inputs, outputs=outputs,
+                     attrs=attrs)
+    return (out, bias, counts) if select_bias else out
+
+
+def moe_bias_update(bias, counts, coeff, name=None):
+    """The balancing rule of a router's selection bias (ops/decoder_ops.py
+    ``moe_bias_update``), in place: ``bias += coeff * sign(mean(counts) -
+    counts)``: up for an expert that got fewer assignments this step than
+    the mean, down for one that got more.  ``bias`` and ``counts`` are what
+    ``moe_experts(select_bias=True)`` returned.  Append it AFTER
+    ``optimizer.minimize``: it is state moved by a rule and has no
+    gradient."""
+    helper = LayerHelper("moe_bias_update", **locals())
+    helper.append_op(type="moe_bias_update",
+                     inputs={"Bias": [bias], "Counts": [counts]},
+                     outputs={"BiasOut": [bias]},
+                     attrs={"coeff": float(coeff)})
+    return bias
 
 
 def paged_attention(q, cache_k, cache_v, page_table, bias, scale=1.0,

@@ -1,21 +1,50 @@
-"""A decoder-only language model from its sizes: pre-norm residual blocks
-of grouped-query attention (optionally over a learned per-query selection
-of keys) and a routed expert layer of which this program holds a stated
-share, RMS norms, rotary positions, an untied head, next-token loss.
+"""A decoder-only language model from its sizes: residual blocks of
+grouped-query attention (over a learned per-query selection of keys, under
+a causal window, or plain causal; window layers beside global ones) and a
+feed-forward that is dense in the leading layers and elsewhere a routed
+expert layer of which this program holds a stated share, RMS norms, rotary
+positions, an untied head, next-token loss.
 
 Everything is configuration (``Config``); nothing here is specific to one
-model or to the benchmark.  The layer, for x = one sequence [T, hidden]::
+model or to the benchmark.  The layer, for x = one sequence [T, hidden] and
+layer i (PUBLISHED index ``layer_offset + i``; [..] marks what a ``Config``
+field turns on)::
 
-    h1 = h + Attn(RMSNorm(h));  h2 = h1 + MoE(RMSNorm(h1))
-    q = RoPE(RMSNorm_head(x Wq)), k = RoPE(RMSNorm_head(x Wk)), v = x Wv
-    S = sparse_indexer(x)            (index_topk set; else every s <= t)
-    Attn = concat_h softmax_{s in S_t}(q_h k_{h // group} / sqrt(d)) v  Wo
-    MoE = sum over the top_k experts a token chose AND this program holds
-          of weight_e W2_e(silu(W1_e x) * W3_e x)
+    h0 = Emb[tokens] [* embed_scale]
+    a  = RMSNorm(x);  q = a Wq, k = a Wk, v = a Wv  [g = a Wg]
+    q  = RMSNorm_head(q), k = RMSNorm_head(k)        per head
+    window layer: q, k = RoPE(q, k); key s counts for query t iff
+                  0 <= t - s < window
+    global layer ((published index + 1) % global_every == 0, or every layer
+                  where there is no window): q, k = RoPE(q, k) [not where
+                  rope_global is off]; key s counts iff s <= t [and s in
+                  S_t = sparse_indexer(a), where index_topk is set]
+    o  = concat_h softmax_{keys that count}(q_h k_{h // group} / sqrt(d)) v
+    y  = (o [* sigmoid(g)]) Wo
+    x1 = x + y                      [post_norms: x + RMSNorm(y)]
+    m  = RMSNorm(x1)
+    dense layer (published index < dense_layers):
+        f = W2(silu(W1 m) * W3 m)                     width dense_width
+    routed layer:
+        s = softmax(m Wr) [or sigmoid(m Wr)] over all num_routed experts
+        E = top experts_per_token of s [+ b: a bias that chooses only]
+        w_e = s_e [/ (sum_E s + route_norm_eps)] [* route_scale]
+        f = [Shared(m) +] sum over e in E AND held here of
+            w_e W2_e(silu(W1_e m) * W3_e m)
+        Shared: the dense feed-forward at width shared_width
+    x2 = x1 + f                     [post_norms: x1 + RMSNorm(f)]
+    logits = RMSNorm(x_last) Whead; loss = mean next-token cross-entropy
+    after each step [route_bias_coeff], per routed layer:
+        b_e += route_bias_coeff * sign(mean_e'(n_e') - n_e)
+        n_e = the step's assignments to expert e, over ALL num_routed;
+        b starts at 0, is persistable and gets no gradient
 
 Parameters are created in a fixed order and named ``tok_emb``,
-``l<i>_{attn_norm,q_w,k_w,v_w,q_norm,k_norm,idx_q_w,idx_k_w,idx_w_w,o_w,
-moe_norm,router_w,w1,w3,w2}``, ``final_norm``, ``lm_head_w``.
+``l<i>_{attn_norm,q_w,q_norm,k_w,k_norm,v_w,idx_q_w,idx_k_w,idx_w_w,gate_w,
+o_w,post_attn_norm}``, then ``l<i>_{mlp_norm,mlp_w1,mlp_w3,mlp_w2}`` (dense)
+or ``l<i>_{moe_norm,shared_w1,shared_w3,shared_w2,router_w,w1,w3,w2}``
+(routed; ``l<i>_route_bias`` is no parameter), ``l<i>_post_mlp_norm``,
+``final_norm``, ``lm_head_w``; ``i`` counts the layers held, from 0.
 """
 
 from __future__ import annotations
@@ -30,10 +59,20 @@ class Config:
                  num_kv_heads, head_dim, expert_width, num_routed,
                  experts_held, experts_per_token, expert_offset=0,
                  norm_topk=True, rms_eps=1e-6, rope_theta=10000.0,
-                 index_heads=0, index_head_dim=0, index_topk=0):
+                 index_heads=0, index_head_dim=0, index_topk=0,
+                 window=0, global_every=0, rope_global=True, layer_offset=0,
+                 attn_gate=False, post_norms=False, embed_scale=1.0,
+                 dense_layers=0, dense_width=0, shared_width=0,
+                 router_score="softmax", route_norm_eps=0.0,
+                 route_scale=1.0, route_bias_coeff=0.0):
         if num_heads % num_kv_heads:
             raise ValueError(f"{num_heads} query heads do not group over "
                              f"{num_kv_heads} key-value heads")
+        if window and index_topk:
+            raise ValueError("a window and a learned selection in one "
+                             "model: no layer kind is defined for both")
+        if dense_layers > layer_offset and not dense_width:
+            raise ValueError("a leading dense layer needs dense_width")
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.num_layers = num_layers
@@ -52,6 +91,34 @@ class Config:
         self.index_heads = index_heads
         self.index_head_dim = index_head_dim
         self.index_topk = index_topk
+        # window 0: every layer global.  global_every n: of the PUBLISHED
+        # layers every n-th is global (0: none is, where there is a window)
+        self.window = window
+        self.global_every = global_every
+        self.rope_global = rope_global
+        # the published index of the first layer held: dense_layers and
+        # global_every keep their published values under a cut in depth
+        self.layer_offset = layer_offset
+        self.attn_gate = attn_gate
+        self.post_norms = post_norms
+        self.embed_scale = embed_scale
+        self.dense_layers = dense_layers
+        self.dense_width = dense_width
+        self.shared_width = shared_width
+        self.router_score = router_score
+        self.route_norm_eps = route_norm_eps
+        self.route_scale = route_scale
+        self.route_bias_coeff = route_bias_coeff
+
+    def layer_window(self, i):
+        """The window of held layer ``i``: 0 where it is a global one."""
+        published = self.layer_offset + i
+        if self.global_every and (published + 1) % self.global_every == 0:
+            return 0
+        return self.window
+
+    def layer_is_dense(self, i):
+        return self.layer_offset + i < self.dense_layers
 
 
 def tiny_config():
@@ -74,22 +141,30 @@ def _proj(x, width, name):
                      param_attr=_attr(name))
 
 
-def _heads(x, seq_len, n, cfg, norm_name=None):
-    """[B, T, n*Dh] -> [B, n, T, Dh]; normed per head and rotated where
-    ``norm_name`` names the norm's scale (q and k; v is neither)."""
+def _norm(x, cfg, name):
+    return layers.rms_norm(x, epsilon=cfg.rms_eps,
+                           param_attr=ParamAttr(name=name))
+
+
+def _heads(x, seq_len, n, cfg, norm_name=None, rotate=True):
+    """[B, T, n*Dh] -> [B, n, T, Dh]; normed per head where ``norm_name``
+    names the norm's scale (q and k; v is neither), and then rotated where
+    ``rotate``."""
     x = layers.reshape(x, [-1, seq_len, n, cfg.head_dim])
     if norm_name is not None:
-        x = layers.rms_norm(x, epsilon=cfg.rms_eps,
-                            param_attr=ParamAttr(name=norm_name))
-        x = layers.rotary_embedding(x, theta=cfg.rope_theta)
+        x = _norm(x, cfg, norm_name)
+        if rotate:
+            x = layers.rotary_embedding(x, theta=cfg.rope_theta)
     return layers.transpose(x, perm=[0, 2, 1, 3])
 
 
-def _attention(x, cfg, seq_len, p):
-    q = _heads(_proj(x, cfg.num_heads * cfg.head_dim, f"{p}_q_w"),
-               seq_len, cfg.num_heads, cfg, f"{p}_q_norm")
+def _attention(x, cfg, seq_len, p, window):
+    rotate = bool(window) or cfg.rope_global
+    width = cfg.num_heads * cfg.head_dim
+    q = _heads(_proj(x, width, f"{p}_q_w"),
+               seq_len, cfg.num_heads, cfg, f"{p}_q_norm", rotate)
     k = _heads(_proj(x, cfg.num_kv_heads * cfg.head_dim, f"{p}_k_w"),
-               seq_len, cfg.num_kv_heads, cfg, f"{p}_k_norm")
+               seq_len, cfg.num_kv_heads, cfg, f"{p}_k_norm", rotate)
     v = _heads(_proj(x, cfg.num_kv_heads * cfg.head_dim, f"{p}_v_w"),
                seq_len, cfg.num_kv_heads, cfg)
     sel = None
@@ -99,43 +174,85 @@ def _attention(x, cfg, seq_len, p):
             theta=cfg.rope_theta, name=f"{p}_idx",
             param_attr=_attr(None))
     ctx = layers.sparse_attention(q, k, v, selection=sel,
-                                  scale=cfg.head_dim ** -0.5)
+                                  scale=cfg.head_dim ** -0.5, window=window)
     ctx = layers.reshape(layers.transpose(ctx, perm=[0, 2, 1, 3]),
-                         [-1, seq_len, cfg.num_heads * cfg.head_dim])
+                         [-1, seq_len, width])
+    if cfg.attn_gate:
+        ctx = layers.elementwise_mul(
+            ctx, layers.sigmoid(_proj(x, width, f"{p}_gate_w")))
     return _proj(ctx, cfg.hidden_size, f"{p}_o_w")
+
+
+def _feed_forward(x, cfg, width, p):
+    """W2(silu(W1 x) * W3 x), no bias: ``<p>_w1`` gate, ``_w3`` up, ``_w2``
+    down."""
+    h = layers.elementwise_mul(layers.swish(_proj(x, width, f"{p}_w1")),
+                               _proj(x, width, f"{p}_w3"))
+    return _proj(h, cfg.hidden_size, f"{p}_w2")
+
+
+def _experts(x, cfg, p, routers):
+    """The routed layer's share [beside the shared expert]; a router with a
+    selection bias adds its (bias, counts) to ``routers``."""
+    shared = _feed_forward(x, cfg, cfg.shared_width, f"{p}_shared") \
+        if cfg.shared_width else None
+    out = layers.moe_experts(
+        x, cfg.num_routed, cfg.experts_held, cfg.expert_width,
+        cfg.experts_per_token, expert_offset=cfg.expert_offset,
+        norm_topk=cfg.norm_topk, name=p, param_attr=_attr(None),
+        score=cfg.router_score, select_bias=bool(cfg.route_bias_coeff),
+        norm_eps=cfg.route_norm_eps, route_scale=cfg.route_scale)
+    if cfg.route_bias_coeff:
+        out, bias, counts = out
+        routers.append((bias, counts))
+    return out if shared is None else layers.elementwise_add(shared, out)
+
+
+def _forward(cfg, seq_len):
+    tokens = layers.data(name="tokens", shape=[seq_len], dtype="int64")
+    labels = layers.data(name="labels", shape=[seq_len, 1], dtype="int64")
+    h = layers.embedding(tokens, size=[cfg.vocab_size, cfg.hidden_size],
+                         param_attr=_attr("tok_emb"))
+    if cfg.embed_scale != 1.0:
+        h = layers.scale(h, scale=float(cfg.embed_scale))
+    routers = []
+    for i in range(cfg.num_layers):
+        p = f"l{i}"
+        y = _attention(_norm(h, cfg, f"{p}_attn_norm"), cfg, seq_len, p,
+                       cfg.layer_window(i))
+        if cfg.post_norms:
+            y = _norm(y, cfg, f"{p}_post_attn_norm")
+        h = layers.elementwise_add(h, y)
+        if cfg.layer_is_dense(i):
+            f = _feed_forward(_norm(h, cfg, f"{p}_mlp_norm"), cfg,
+                              cfg.dense_width, f"{p}_mlp")
+        else:
+            f = _experts(_norm(h, cfg, f"{p}_moe_norm"), cfg, p, routers)
+        if cfg.post_norms:
+            f = _norm(f, cfg, f"{p}_post_mlp_norm")
+        h = layers.elementwise_add(h, f)
+    h = _norm(h, cfg, "final_norm")
+    logits = _proj(h, cfg.vocab_size, "lm_head_w")
+    loss = layers.mean(layers.softmax_with_cross_entropy(logits, labels))
+    return tokens, labels, loss, logits, routers
 
 
 def forward(cfg, seq_len):
     """Data layers, logits and the mean next-token cross-entropy.  Returns
     (tokens, labels, loss, logits); ``labels[b, t]`` is the token that
     follows ``tokens[b, t]``."""
-    tokens = layers.data(name="tokens", shape=[seq_len], dtype="int64")
-    labels = layers.data(name="labels", shape=[seq_len, 1], dtype="int64")
-    h = layers.embedding(tokens, size=[cfg.vocab_size, cfg.hidden_size],
-                         param_attr=_attr("tok_emb"))
-    for i in range(cfg.num_layers):
-        p = f"l{i}"
-        x = layers.rms_norm(h, epsilon=cfg.rms_eps,
-                            param_attr=ParamAttr(name=f"{p}_attn_norm"))
-        h = layers.elementwise_add(h, _attention(x, cfg, seq_len, p))
-        x = layers.rms_norm(h, epsilon=cfg.rms_eps,
-                            param_attr=ParamAttr(name=f"{p}_moe_norm"))
-        h = layers.elementwise_add(h, layers.moe_experts(
-            x, cfg.num_routed, cfg.experts_held, cfg.expert_width,
-            cfg.experts_per_token, expert_offset=cfg.expert_offset,
-            norm_topk=cfg.norm_topk, name=p, param_attr=_attr(None)))
-    h = layers.rms_norm(h, epsilon=cfg.rms_eps,
-                        param_attr=ParamAttr(name="final_norm"))
-    logits = _proj(h, cfg.vocab_size, "lm_head_w")
-    loss = layers.mean(layers.softmax_with_cross_entropy(logits, labels))
-    return tokens, labels, loss, logits
+    return _forward(cfg, seq_len)[:4]
 
 
 def build(cfg=None, seq_len=64, lr=1e-4, beta1=0.9, beta2=0.95,
           epsilon=1e-8):
-    """The training graph with Adam.  Returns (tokens, labels, loss)."""
+    """The training graph with Adam and, after it, the balancing rule of
+    every router that has a selection bias.  Returns (tokens, labels,
+    loss)."""
     cfg = cfg or tiny_config()
-    tokens, labels, loss, _ = forward(cfg, seq_len)
+    tokens, labels, loss, _, routers = _forward(cfg, seq_len)
     fluid.optimizer.Adam(learning_rate=lr, beta1=beta1, beta2=beta2,
                          epsilon=epsilon).minimize(loss)
+    for bias, counts in routers:
+        layers.moe_bias_update(bias, counts, cfg.route_bias_coeff)
     return tokens, labels, loss

@@ -6,8 +6,10 @@ of a routed expert layer that the experts held here give.
 Each is a pure JAX function; gradients go through the generic vjp path
 (``ops/registry.py``) except where noted.  ``sparse_attention`` and
 ``moe_experts`` count which path each call took at lowering
-(``ops.sparse_attention.calls{topk,seq,path}``, ``ops.moe.calls{held,
-routed,path}``, and ``...declined{why}`` for every fallback).
+(``ops.sparse_attention.calls{topk,seq,path}`` and, under a causal window,
+``window``; ``ops.moe.calls{held,routed,path}`` and, for a router that is
+not the softmax one, ``score``; ``...declined{why}`` for every fallback;
+``ops.moe.bias_updates`` for every ``moe_bias_update`` lowered).
 """
 
 from __future__ import annotations
@@ -154,10 +156,13 @@ def sparse_indexer_grad(ctx):
             for slot in ctx.outputs_spec}
 
 
-def blocked_attention(q, k, v, sel, scale, block=512):
+def blocked_attention(q, k, v, sel, scale, block=512, window=0):
     """The XLA path of ``sparse_attention``: query tiles against the keys
-    up to the tile's end, the selection as a mask, each tile a checkpoint so
-    that the backward holds one tile's [Hq, bq, T] scores at a time."""
+    up to the tile's end (under a ``window``, from the first key that the
+    tile's first query still sees), the selection and the window as masks,
+    the window's made from positions and never a [B, T, T] tensor; each
+    tile a checkpoint so that the backward holds one tile's [Hq, bq, T]
+    scores at a time."""
     from ..fluid import amp
 
     b, hq, t, d = q.shape
@@ -176,16 +181,21 @@ def blocked_attention(q, k, v, sel, scale, block=512):
     outs = []
     for q0 in range(0, t, bq):
         q1 = min(q0 + bq, t)
-        keep = (q0 + jnp.arange(q1 - q0))[:, None] >= jnp.arange(q1)[None]
+        k0 = max(0, q0 - window + 1) if window else 0
+        qpos = (q0 + jnp.arange(q1 - q0))[:, None]
+        kpos = jnp.arange(k0, q1)[None]
+        keep = qpos >= kpos
+        if window:
+            keep = keep & (qpos - kpos < window)
         keep = jnp.broadcast_to(keep[None], (b,) + keep.shape)
         if sel is not None:
-            keep = keep & (sel[:, q0:q1, :q1] > 0)
-        outs.append(tile(qg[:, :, :, q0:q1], k[:, :, :q1], v[:, :, :q1],
+            keep = keep & (sel[:, q0:q1, k0:q1] > 0)
+        outs.append(tile(qg[:, :, :, q0:q1], k[:, :, k0:q1], v[:, :, k0:q1],
                          keep))
     return jnp.concatenate(outs, axis=3).reshape(b, hq, t, d).astype(q.dtype)
 
 
-def _attention_path(ctx, q, k, sel, count):
+def _attention_path(ctx, q, k, sel, window, count):
     """'pallas' where the flash gate is open and the kernels take the
     operands, else 'xla'; counted where ``count``."""
     from .attention_ops import _flash_decision
@@ -193,38 +203,49 @@ def _attention_path(ctx, q, k, sel, count):
 
     path = "xla"
     if _flash_decision(int(ctx.attr("flash", -1))):
-        why = psf.supported(q, k, sel)
+        why = psf.supported(q, k, sel, window)
         if not why:
             path = "pallas"
         elif count:
             _count("ops.sparse_attention.declined", why=why)
     if count:
+        # the label as the op states it, a window that cuts nothing too
+        stated = int(ctx.attr("window", 0))
         _count("ops.sparse_attention.calls", path=path,
-               topk=ctx.attr("topk", 0), seq=q.shape[2])
+               topk=ctx.attr("topk", 0), seq=q.shape[2],
+               **({"window": stated} if stated else {}))
     return path
 
 
 def _attention_operands(ctx):
+    """(q, k, v, sel, scale, window); a window that reaches every key
+    ``s <= t`` is none."""
     q, k, v = ctx.input("Q"), ctx.input("K"), ctx.input("V")
     sel = ctx.input("Sel") if ctx.has_input("Sel") else None
-    return q, k, v, sel, ctx.attr("scale", 0.0) or q.shape[-1] ** -0.5
+    window = int(ctx.attr("window", 0))
+    if window < 0:
+        raise ValueError(f"sparse_attention: window {window} is negative")
+    return (q, k, v, sel, ctx.attr("scale", 0.0) or q.shape[-1] ** -0.5,
+            0 if window >= q.shape[2] else window)
 
 
 @register_op("sparse_attention", no_grad_inputs=("Sel",))
 def sparse_attention_op(ctx):
     """Causal grouped-query attention, optionally over a per-query
-    selection.  Q: [B, Hq, T, D]; K, V: [B, Hkv, T, D]; Sel: [B, T, T] int8
+    selection and, with the attr ``window`` (0: none), over the last
+    ``window`` keys only: key s counts for query t iff ``0 <= t - s <
+    window``.  Q: [B, Hq, T, D]; K, V: [B, Hkv, T, D]; Sel: [B, T, T] int8
     or absent.  The Pallas kernels where the flash gate is open and they
     take the operands, else the blocked XLA path.  Lse ([B, Hq, T, 1]
     float32) is the kernels' log-sum-exp, kept for their backward; zeros on
     the XLA path, whose backward is the generic vjp."""
     from . import pallas_sparse_flash as psf
 
-    q, k, v, sel, scale = _attention_operands(ctx)
-    if _attention_path(ctx, q, k, sel, count=True) == "pallas":
-        out, lse = psf.forward(q, k, v, sel, scale)
+    q, k, v, sel, scale, window = _attention_operands(ctx)
+    if _attention_path(ctx, q, k, sel, window, count=True) == "pallas":
+        out, lse = psf.forward(q, k, v, sel, scale, window=window)
         return {"Out": out, "Lse": lse}
-    return {"Out": blocked_attention(q, k, v, sel, scale),
+    return {"Out": blocked_attention(q, k, v, sel, scale, window=window),
             "Lse": jnp.zeros(q.shape[:3] + (1,), jnp.float32)}
 
 
@@ -236,19 +257,24 @@ def sparse_attention_grad(ctx):
     from . import pallas_sparse_flash as psf
     from . import registry
 
-    q, k, v, sel, scale = _attention_operands(ctx)
-    if _attention_path(ctx, q, k, sel, count=False) != "pallas":
+    q, k, v, sel, scale, window = _attention_operands(ctx)
+    if _attention_path(ctx, q, k, sel, window, count=False) != "pallas":
         return registry.run_grad_generic(
             registry.get_op_def("sparse_attention"), ctx)
     dq, dk, dv = psf.backward(q, k, v, sel, ctx.input("Out"),
                               ctx.input("Lse"),
-                              ctx.input("Out@GRAD").astype(q.dtype), scale)
+                              ctx.input("Out@GRAD").astype(q.dtype), scale,
+                              window=window)
     grads = {"Q@GRAD": dq, "K@GRAD": dk, "V@GRAD": dv}
     return {s: g for s, g in grads.items() if s in ctx.outputs_spec}
 
 
-@register_op("moe_experts")
+@register_op("moe_experts", no_grad_inputs=("Bias",))
 def moe_experts_op(ctx):
+    """``parallel/moe.routed_experts`` as an op.  With the input ``Bias``
+    ([num_routed], a selection bias that chooses and does not weigh) the
+    op also gives ``Counts`` ([num_routed] int32, the step's assignments to
+    every routed expert), which ``moe_bias_update`` reads."""
     from ..parallel import moe
 
     w1 = ctx.input("W1")
@@ -261,8 +287,34 @@ def moe_experts_op(ctx):
             f"moe_experts: {w1.shape[0]} expert weights and a router "
             f"{ctx.input('RouterW').shape[-1]} wide for experts_held="
             f"{held}, expert_offset={offset}, num_routed={routed}")
-    _count("ops.moe.calls", held=held, routed=routed, path="ragged_dot")
-    return {"Out": moe.routed_experts(
+    score = ctx.attr("score", "softmax")
+    bias = ctx.input("Bias") if ctx.has_input("Bias") else None
+    if bias is not None and bias.shape != (routed,):
+        raise ValueError(f"moe_experts: a selection bias {bias.shape} for "
+                         f"num_routed={routed}")
+    _count("ops.moe.calls", held=held, routed=routed, path="ragged_dot",
+           **({} if score == "softmax" else {"score": score}))
+    out = moe.routed_experts(
         ctx.input("X"), ctx.input("RouterW"), w1, ctx.input("W3"),
         ctx.input("W2"), top_k=int(ctx.attr("top_k")),
-        expert_offset=offset, norm_topk=bool(ctx.attr("norm_topk", True)))}
+        expert_offset=offset, norm_topk=bool(ctx.attr("norm_topk", True)),
+        score=score, bias=bias,
+        norm_eps=float(ctx.attr("norm_eps", 0.0)),
+        scale=float(ctx.attr("route_scale", 1.0)),
+        with_counts=bias is not None)
+    if bias is None:
+        return {"Out": out}
+    return {"Out": out[0], "Counts": out[1]}
+
+
+@register_op("moe_bias_update", no_grad_inputs=("Bias", "Counts"))
+def moe_bias_update_op(ctx):
+    """The balancing rule of a router's selection bias, after the step:
+    ``BiasOut = Bias + coeff * sign(mean(Counts) - Counts)``
+    (``parallel/moe.balance_bias``).  State changed by a rule: the op has
+    no gradient and the bias gets none."""
+    from ..parallel import moe
+
+    _count("ops.moe.bias_updates")
+    return {"BiasOut": moe.balance_bias(
+        ctx.input("Bias"), ctx.input("Counts"), float(ctx.attr("coeff")))}

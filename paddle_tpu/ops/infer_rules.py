@@ -548,6 +548,10 @@ def infer_sparse_attention(op, ins):
             f"sparse_attention: selection {_names(op, 'Sel')} "
             f"{list(sel[0])} {sel[1]} must be an integer "
             f"[B, {q[0][2]}, {q[0][2]}] mask")
+    if int(op.attr("window", 0)) < 0:
+        raise InferMismatch(
+            f"sparse_attention: window {op.attr('window')} is negative "
+            f"(0: none; else the last `window` keys s <= t)")
     return {"Out": [q], "Lse": [lse]}
 
 
@@ -567,4 +571,29 @@ def infer_moe_experts(op, ins):
             f"moe_experts: router {_names(op, 'RouterW')} {list(r[0])} and "
             f"expert weights {_names(op, 'W1')} {list(w1[0])} must be "
             f"{routed} wide and {held} experts")
-    return {"Out": [x]}
+    if op.attr("score", "softmax") not in ("softmax", "sigmoid"):
+        raise InferMismatch(
+            f"moe_experts: score {op.attr('score')!r} is neither 'softmax' "
+            f"nor 'sigmoid'")
+    bias = _in(ins, "Bias")
+    if bias is None:
+        return {"Out": [x]}
+    if tuple(bias[0]) != (routed,):
+        raise InferMismatch(
+            f"moe_experts: selection bias {_names(op, 'Bias')} "
+            f"{list(bias[0])} must be [{routed}], one per routed expert")
+    return {"Out": [x], "Counts": [((routed,), "int32")]}
+
+
+@register_infer("moe_bias_update")
+def infer_moe_bias_update(op, ins):
+    bias, counts = _in(ins, "Bias"), _in(ins, "Counts")
+    if bias is not None and counts is not None and (
+            tuple(bias[0]) != tuple(counts[0])
+            or counts[1] not in _INT_DTYPES):
+        raise InferMismatch(
+            f"moe_bias_update: bias {_names(op, 'Bias')} {list(bias[0])} "
+            f"and counts {_names(op, 'Counts')} {list(counts[0])} "
+            f"{counts[1]} must be one float and one integer per routed "
+            f"expert")
+    return {"BiasOut": [bias]}

@@ -99,23 +99,67 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, top_k: int = 2,
     return y.reshape(orig_shape), aux.astype(dtype)
 
 
-def route_top_k(x, router_w, top_k: int, norm_topk: bool = True):
+def route_top_k(x, router_w, top_k: int, norm_topk: bool = True,
+                score: str = "softmax", bias=None, norm_eps: float = 0.0,
+                scale: float = 1.0):
     """(weights [N, k] float32, experts [N, k] int32) of the published
-    router: softmax over ALL ``router_w.shape[-1]`` routed experts in
+    router: scores over ALL ``router_w.shape[-1]`` routed experts in
     float32 (at the highest matmul precision: a logit's last bits decide
     the choice), the top k, renormalized over the chosen when
-    ``norm_topk``."""
+    ``norm_topk`` (``/ (sum + norm_eps)``), times ``scale``.  ``score`` is
+    ``softmax`` or ``sigmoid`` (each expert's score its own).  ``bias``
+    ([routed], no gradient) is added to the scores for the CHOICE only: it
+    says which k experts a token takes, and their weights are made from the
+    scores without it."""
     logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
-    vals, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"route_top_k: score {score!r} is neither "
+                         "'softmax' nor 'sigmoid'")
+    if bias is None:
+        vals, idx = lax.top_k(scores, top_k)
+    else:
+        _, idx = lax.top_k(scores + lax.stop_gradient(
+            bias.astype(jnp.float32)), top_k)
+        vals = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_topk:
-        vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+        total = jnp.sum(vals, axis=-1, keepdims=True)
+        vals = vals / ((total + jnp.float32(norm_eps)) if norm_eps
+                       else total)
+    if scale != 1.0:
+        vals = vals * jnp.float32(scale)
     return vals, idx.astype(jnp.int32)
 
 
+def assignment_counts(idx, routed: int):
+    """[routed] int32: how many of the assignments ``idx`` ([N, k], over all
+    ``routed`` experts, held here or not) each expert got."""
+    i32 = jnp.int32
+    return jnp.sum((idx.reshape(-1)[:, None]
+                    == jnp.arange(routed, dtype=i32)).astype(i32),
+                   axis=0, dtype=i32)
+
+
+def balance_bias(bias, counts, coeff: float):
+    """The selection bias after a step in which the routed experts got
+    ``counts`` assignments: ``b_e += coeff * sign(mean(n) - n_e)``, up for
+    an expert that got fewer than the mean, down for one that got more.  A
+    rule, not a gradient (auxiliary-loss-free balancing)."""
+    n = counts.astype(jnp.float32)
+    return bias + jnp.float32(coeff) * jnp.sign(jnp.mean(n) - n)
+
+
 def routed_experts(x, router_w, w1, w3, w2, top_k: int,
-                   expert_offset: int = 0, norm_topk: bool = True):
-    """The share of a routed expert layer that the experts held here give.
+                   expert_offset: int = 0, norm_topk: bool = True,
+                   score: str = "softmax", bias=None, norm_eps: float = 0.0,
+                   scale: float = 1.0, with_counts: bool = False):
+    """The share of a routed expert layer that the experts held here give;
+    ``with_counts``: a pair of it and ``assignment_counts`` of the step
+    (``score``, ``bias``, ``norm_eps``, ``scale``: ``route_top_k``).
 
     x: [..., D]; router_w: [D, R] over all R routed experts; w1, w3:
     [E, D, F] and w2: [E, F, D], the E experts ``[expert_offset,
@@ -137,9 +181,10 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
     # hidden activations again instead of keeping N * top_k rows of them
     # per layer (1 GB a layer at 8,192 tokens x 8 choices)
     @jax.checkpoint
-    def share(xt, router_w, w1, w3, w2):
+    def share(xt, router_w, w1, w3, w2, bias):
         n = xt.shape[0]
-        vals, idx = route_top_k(xt, router_w, top_k, norm_topk)
+        vals, idx = route_top_k(xt, router_w, top_k, norm_topk, score, bias,
+                                norm_eps, scale)
         local = idx - jnp.int32(expert_offset)
         held = (local >= 0) & (local < e)
         group = jnp.where(held, local, e).reshape(-1)      # absent: last
@@ -193,7 +238,13 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
         ys = jnp.where(held[..., None],
                        jnp.take(ys, back, axis=0).reshape(n, top_k, -1), 0)
         gate = jnp.where(held, vals, 0.0)
-        return jnp.einsum("nk,nkd->nd", gate, ys.astype(jnp.float32))
+        y = jnp.einsum("nk,nkd->nd", gate, ys.astype(jnp.float32))
+        if with_counts:
+            return y, assignment_counts(idx, router_w.shape[-1])
+        return y
 
-    y = share(x.reshape((-1, shape[-1])), router_w, w1, w3, w2)
+    y = share(x.reshape((-1, shape[-1])), router_w, w1, w3, w2, bias)
+    if with_counts:
+        y, counts = y
+        return y.astype(x.dtype).reshape(shape), counts
     return y.astype(x.dtype).reshape(shape)

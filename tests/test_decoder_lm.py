@@ -421,3 +421,300 @@ def test_infer_rules_give_the_shapes_of_the_new_ops():
     with pytest.raises(registry.InferMismatch, match="do not fit a router"):
         get_infer_rule("moe_experts")(
             Op(num_routed=8, experts_held=4, expert_offset=6, top_k=2), {})
+
+
+# == window layers beside global ones, a sigmoid router with a balancing ==
+# == bias beside a shared expert and a leading dense layer: the program   ==
+# == against the reference of ``chipbench/configs/trinity_mini``          ==
+
+TRINITY = "configs/trinity_mini"
+T_BUILD = plugins.load(TRINITY, "build")
+T_REF = plugins.load(TRINITY, "reference")
+
+
+def trinity_sizes(**over):
+    sizes = json.load(open(os.path.join(ROOT, "chipbench", TRINITY,
+                                        "config.json")))
+    return {**sizes, **sizes["tiny"], **over}
+
+
+def windowed(q, k, v, window):
+    """Dense float32: key s counts for query t iff 0 <= t - s < window."""
+    g, t = q.shape[1] // k.shape[1], q.shape[2]
+    back = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, g, axis=1)) \
+        * q.shape[-1] ** -0.5
+    s = jnp.where((back >= 0) & (back < window), s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1),
+                      jnp.repeat(v, g, axis=1))
+
+
+@pytest.mark.parametrize("flash", ["xla", "pallas"])
+def test_window_program_equals_the_reference_and_moves_the_bias(
+        monkeypatch, flash):
+    """Loss, every gradient and every router's bias after the step through
+    ``fluid.Executor`` with ``optimizer.minimize``: published layers 1-5 (a
+    dense window layer, then window, global, window, window routed ones),
+    ``seq_len`` four windows; the bias is state without a gradient."""
+    monkeypatch.setenv("PADDLE_TPU_FLASH", "1" if flash == "pallas" else "0")
+    monkeypatch.setattr(psf, "BLOCK", 16)       # a band of 2 tiles of 4
+    sizes = trinity_sizes()
+    assert sizes["seq_len"] == 4 * sizes["sliding_window"]
+    assert sizes["num_experts"] < sizes["published"]["num_experts"]
+    built = T_BUILD.build(fluid, sizes)
+    main = fluid.default_main_program()
+    names = T_BUILD.trainable_names(main)
+    spec = T_REF.param_spec(sizes)
+    assert [n for n, _, _ in spec] == names
+    exe = fluid.Executor(fluid.TPUPlace())
+    exe.run(fluid.default_startup_program())
+    scope = fluid.global_scope()
+    weights = T_REF.init_params(5, sizes)
+    for (_, shape, _), name, w in zip(spec, names, weights):
+        assert tuple(np.shape(scope.get(name))) == tuple(shape), name
+        scope.set(name, jnp.array(w))
+    routers = [f"l{i}_route_bias" for i in range(1, 5)]
+    block = main.global_block()
+    for name in routers:
+        assert not np.any(np.asarray(scope.get(name)))
+        assert not block.has_var(name + "@GRAD") and name not in names
+    feed = T_BUILD.make_feed(sizes, 2, np.random.RandomState(3))
+    outs = exe.run(main, feed=feed, fetch_list=[built["loss"]]
+                   + [n + "@GRAD" for n in names])
+    ref_loss, ref_grads = T_REF.loss_and_grads(weights, feed, sizes)
+    assert float(outs[0].reshape(-1)[0]) == pytest.approx(float(ref_loss),
+                                                          rel=1e-5)
+    for name, g, r in zip(names, outs[1:], ref_grads):
+        g = np.asarray(g).reshape(r.shape)
+        assert np.abs(g - r).max() <= 2e-4 * np.abs(r).max() + 1e-7, name
+    # the rule moved every bias as the reference's does: by +-coeff, up for
+    # the experts that got fewer assignments than the mean
+    after = T_REF.biases_after_step(weights, feed, sizes)
+    for name, want in zip(routers, after):
+        got = np.asarray(scope.get(name))
+        np.testing.assert_allclose(got, want, atol=1e-9)
+        assert set(np.round(np.abs(got) / sizes["load_balance_coeff"])) \
+            <= {0.0, 1.0} and np.any(got > 0) and np.any(got < 0)
+    # which path each layer took: the window label on 4 layers, none on 1
+    calls = counters("ops.sparse_attention.calls")
+    per = 1 if flash == "pallas" else 2
+    assert calls == {
+        f'ops.sparse_attention.calls{{path="{flash}",seq="64",topk="0",'
+        f'window="16"}}': 4 * per,
+        f'ops.sparse_attention.calls{{path="{flash}",seq="64",'
+        f'topk="0"}}': per}
+    (key, n), = counters("ops.moe.calls").items()
+    assert 'score="sigmoid"' in key and 'routed="8"' in key and n == 2 * 4
+    assert counters("ops.moe.bias_updates") == {"ops.moe.bias_updates": 4}
+    assert not counters("ops.sparse_attention.declined")
+
+
+@pytest.mark.parametrize("window", [16, 24, 40, 64, 100])
+@pytest.mark.parametrize("flash", [False, True])
+def test_window_op_on_both_paths(monkeypatch, flash, window):
+    """window < T on and off the tile (16), and window >= T, which is the
+    global path: the op against dense float32, through the executor."""
+    monkeypatch.setattr(psf, "BLOCK", 16)
+    b, hq, hkv, t, d = 2, 4, 2, 64, 16
+    rng = np.random.RandomState(window)
+    q = layers.data(name="q", shape=[hq, t, d], dtype="float32")
+    k = layers.data(name="k", shape=[hkv, t, d], dtype="float32")
+    v = layers.data(name="v", shape=[hkv, t, d], dtype="float32")
+    q.stop_gradient = k.stop_gradient = v.stop_gradient = False
+    out = layers.sparse_attention(q, k, v, window=window, flash=flash)
+    plain = layers.sparse_attention(q, k, v, flash=flash)
+    w = layers.assign(np.cos(np.arange(d, dtype="float32")))
+    loss = layers.reduce_sum(layers.elementwise_mul(out, w))
+    fluid.backward.append_backward(loss)
+    feed = {n: rng.randn(b, h, t, d).astype("float32")
+            for n, h in (("q", hq), ("k", hkv), ("v", hkv))}
+    got = fluid.Executor(fluid.TPUPlace()).run(
+        feed=feed, fetch_list=[out, plain, "q@GRAD", "k@GRAD", "v@GRAD"])
+    args = [jnp.asarray(feed[n]) for n in "qkv"]
+    np.testing.assert_allclose(got[0], windowed(*args, window), atol=2e-5)
+    if window >= t:
+        np.testing.assert_array_equal(got[0], got[1])
+    want = jax.grad(lambda *a: jnp.sum(windowed(*a, window)
+                                       * jnp.cos(jnp.arange(d))),
+                    (0, 1, 2))(*args)
+    for g, r in zip(got[2:], want):
+        np.testing.assert_allclose(g, r, atol=2e-4)
+    path = "pallas" if flash else "xla"
+    assert any(f'path="{path}"' in key and f'window="{window}"' in key
+               for key in counters("ops.sparse_attention.calls"))
+
+
+@pytest.mark.parametrize("window,block,tiles", [
+    (16, 16, 2), (17, 16, 2), (18, 16, 3), (5, 16, 2), (1, 16, 1),
+    (40, 16, 4), (50, 16, 4), (32, 64, 1)])
+def test_window_kernels_equal_blocked_attention_gradients_too(
+        monkeypatch, window, block, tiles):
+    """The three kernels, interpreted, 8 query heads over 2 key-value heads
+    of width 128, against the XLA path; the band is ``tiles`` wide."""
+    monkeypatch.setattr(psf, "BLOCK", block)
+    b, hq, hkv, t, d = 1, 8, 2, 64, 128
+    assert psf.band_tiles(window, block, t // block) == tiles
+    rng = np.random.RandomState(window)
+    q, k, v = (jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
+               for h in (hq, hkv, hkv))
+    w = jnp.cos(jnp.arange(d, dtype=jnp.float32))
+
+    def kernel(q, k, v):
+        return jnp.sum(psf.sparse_flash_attention(q, k, v, None, None, True,
+                                                  window) * w)
+
+    def blocked(q, k, v):
+        return jnp.sum(decoder_ops.blocked_attention(
+            q, k, v, None, d ** -0.5, block=block, window=window) * w)
+
+    np.testing.assert_allclose(
+        psf.sparse_flash_attention(q, k, v, None, None, True, window),
+        windowed(q, k, v, window), atol=2e-5)
+    for g, r in zip(jax.grad(kernel, (0, 1, 2))(q, k, v),
+                    jax.grad(blocked, (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(g, r, atol=2e-4)
+
+
+def test_a_window_lowers_no_t_by_t_operand(monkeypatch):
+    """The window is a static band: the lowered calls hold its table
+    [tiles, band] and nothing [.., T, T]; a selection beside a window is
+    declined to the XLA path, which masks both."""
+    monkeypatch.setattr(psf, "BLOCK", 64)
+    b, hq, hkv, t, d = 1, 4, 2, 256, 128
+    q = jnp.ones((b, hq, t, d), jnp.bfloat16)
+    k = jnp.ones((b, hkv, t, d), jnp.bfloat16)
+    text = jax.jit(lambda q, k: jax.grad(
+        lambda q: psf.sparse_flash_attention(q, k, k, None, None, True, 32)
+        .astype(jnp.float32).sum())(q)).lower(q, k).as_text()
+    assert f"{t}x{t}x" not in text and "tensor<4x2xi32>" in text
+    assert psf.supported(q, k, jnp.ones((b, t, t), jnp.int8), 32) \
+        == "window_selection"
+    assert psf.supported(q, k, None, 32) == ""
+
+
+def test_the_sixteen_shares_and_one_shared_expert_add_up_to_the_layer():
+    """128 routed experts scored by sigmoids under a selection bias, 8 per
+    token, 8 held by each of 16 chips: the shares, and the shared expert
+    counted ONCE, add up to what the reference gives for the uncut layer;
+    every share reports the same assignments, over all 128."""
+    routed, held, k, scale = 128, 8, 8, 2.826
+    rng = np.random.RandomState(1)
+    x, wr, w1, w3, w2 = moe_weights(rng, 48, 16, 8, routed)
+    bias = jnp.asarray(0.1 * rng.randn(routed), jnp.float32)
+    s1, s3, s2 = (jnp.asarray(0.3 * rng.randn(*s), jnp.float32)
+                  for s in ((16, 8), (16, 8), (8, 16)))
+    whole, n_whole = T_REF.routed(x, wr, bias, w1, w3, w2, k, scale)
+    whole = whole + T_REF.feed_forward(x, s1, s3, s2)
+    assert int(n_whole.sum()) == 48 * k
+    total = T_REF.feed_forward(x, s1, s3, s2)
+    for off in range(0, routed, held):
+        part, n = moe.routed_experts(
+            x, wr, w1[off:off + held], w3[off:off + held],
+            w2[off:off + held], top_k=k, expert_offset=off, score="sigmoid",
+            bias=bias, norm_eps=1e-20, scale=scale, with_counts=True)
+        mine, _ = T_REF.routed(x, wr, bias, w1[off:off + held],
+                               w3[off:off + held], w2[off:off + held], k,
+                               scale, off)
+        np.testing.assert_allclose(part, mine, atol=1e-5)
+        np.testing.assert_array_equal(n, n_whole)
+        total = total + part
+    np.testing.assert_allclose(total, whole, atol=3e-5)
+    assert float(jnp.abs(whole).max()) > 0.1
+
+
+def test_the_bias_chooses_and_does_not_weigh():
+    """A large bias on one expert puts it into every token's choice; the
+    weights stay those of the scores alone, renormalized and scaled, and
+    the bias gets no gradient."""
+    rng = np.random.RandomState(7)
+    x = jnp.asarray(rng.randn(40, 16), jnp.float32)
+    wr = jnp.asarray(rng.randn(16, 12), jnp.float32)
+    none, idx0 = moe.route_top_k(x, wr, 3, True, "sigmoid", jnp.zeros(12),
+                                 1e-20, 2.0)
+    bias = jnp.zeros(12).at[5].set(10.0)
+    vals, idx = moe.route_top_k(x, wr, 3, True, "sigmoid", bias, 1e-20, 2.0)
+    assert bool(jnp.all(jnp.any(idx == 5, -1)))
+    assert not bool(jnp.all(jnp.any(idx0 == 5, -1)))
+    scores = jax.nn.sigmoid(jnp.matmul(x, wr, precision="highest"))
+    chosen = jnp.take_along_axis(scores, idx, -1)
+    np.testing.assert_allclose(
+        vals, 2.0 * chosen / chosen.sum(-1, keepdims=True), rtol=1e-6)
+    np.testing.assert_allclose(vals.sum(-1), 2.0, rtol=1e-6)
+    # the choice without a bias is the top of the scores themselves
+    np.testing.assert_array_equal(idx0, jax.lax.top_k(scores, 3)[1])
+    grad = jax.grad(lambda b: moe.route_top_k(
+        x, wr, 3, True, "sigmoid", b, 1e-20, 2.0)[0][:, 0].sum())(bias)
+    assert not np.any(np.asarray(grad))
+    with pytest.raises(ValueError, match="neither 'softmax' nor 'sigmoid'"):
+        moe.route_top_k(x, wr, 3, score="tanh")
+
+
+def test_the_balancing_rule_moves_toward_the_mean():
+    counts = jnp.asarray([0, 4, 8, 4], jnp.int32)
+    bias = jnp.asarray([0.5, 0.0, 0.0, -0.25], jnp.float32)
+    np.testing.assert_allclose(
+        moe.balance_bias(bias, counts, 0.001),
+        [0.501, 0.0, -0.001, -0.25], atol=1e-9)
+    np.testing.assert_array_equal(
+        moe.assignment_counts(jnp.asarray([[0, 2], [2, 3]]), 5),
+        [1, 0, 2, 1, 0])
+
+
+def test_infer_rules_of_the_window_and_the_bias():
+    from paddle_tpu.ops.registry import get_infer_rule
+
+    class Op:
+        def __init__(self, **attrs):
+            self.attrs, self.inputs, self.type = attrs, {}, "t"
+
+        def attr(self, name, default=None):
+            return self.attrs.get(name, default)
+
+    x = ((2, 16, 32), "float32")
+    ins = {"X": [x], "RouterW": [((32, 8), "float32")],
+           "W1": [((4, 32, 8), "float32")], "Bias": [((8,), "float32")]}
+    op = Op(num_routed=8, experts_held=4, expert_offset=4, top_k=2,
+            score="sigmoid")
+    assert get_infer_rule("moe_experts")(op, ins) == {
+        "Out": [x], "Counts": [((8,), "int32")]}
+    with pytest.raises(registry.InferMismatch, match="one per routed"):
+        get_infer_rule("moe_experts")(op, {**ins,
+                                           "Bias": [((4,), "float32")]})
+    with pytest.raises(registry.InferMismatch, match="neither 'softmax'"):
+        get_infer_rule("moe_experts")(
+            Op(num_routed=8, experts_held=4, top_k=2, score="tanh"), {})
+    assert get_infer_rule("moe_bias_update")(
+        Op(coeff=0.001), {"Bias": [((8,), "float32")],
+                          "Counts": [((8,), "int32")]}) == {
+        "BiasOut": [((8,), "float32")]}
+    with pytest.raises(registry.InferMismatch, match="one float and one"):
+        get_infer_rule("moe_bias_update")(
+            Op(coeff=0.001), {"Bias": [((8,), "float32")],
+                              "Counts": [((8,), "float32")]})
+    q = ((1, 4, 16, 8), "float32")
+    with pytest.raises(registry.InferMismatch, match="is negative"):
+        get_infer_rule("sparse_attention")(
+            Op(window=-1), {"Q": [q], "K": [q], "V": [q]})
+
+
+def test_layer_kinds_follow_the_published_index():
+    from paddle_tpu.models import decoder_lm
+
+    cfg = T_BUILD.config_of(trinity_sizes())
+    assert [bool(cfg.layer_window(i)) for i in range(5)] == [
+        True, True, False, True, True]
+    assert [cfg.layer_is_dense(i) for i in range(5)] == [
+        True, False, False, False, False]
+    whole = decoder_lm.Config(
+        128, 64, 8, 4, 2, 16, 32, 8, 4, 2, window=16, global_every=4,
+        dense_layers=2, dense_width=96)
+    assert [whole.layer_window(i) for i in range(8)] == [
+        16, 16, 16, 0, 16, 16, 16, 0]
+    assert [whole.layer_is_dense(i) for i in range(8)] == [True] * 2 \
+        + [False] * 6
+    with pytest.raises(ValueError, match="no layer kind is defined"):
+        decoder_lm.Config(128, 64, 2, 4, 2, 16, 32, 8, 4, 2, window=16,
+                          index_topk=8)
+    with pytest.raises(ValueError, match="needs dense_width"):
+        decoder_lm.Config(128, 64, 2, 4, 2, 16, 32, 8, 4, 2,
+                          dense_layers=1)

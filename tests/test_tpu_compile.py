@@ -127,17 +127,18 @@ def _paged():
                 ((s, 1, n * ps), F32)]
 
 
-def _sparse_flash(selected):
-    """The decoder cell's attention (``keye_vl_2_0_30b_a3b``: one sequence
+def _sparse_flash(selected, window=0, t=8192):
+    """The decoder cells' attention (``keye_vl_2_0_30b_a3b``: one sequence
     of 8,192 tokens, 32 query heads over 4 key-value heads of width 128,
-    bf16, an int8 selection), forward and the two backward kernels."""
-    t = 8192
+    bf16, an int8 selection; ``trinity_mini``: the same heads under a
+    causal window of 2,048, and with none, at 6,144 tokens), forward and
+    the two backward kernels."""
 
     def fn(q, k, v, sel):
         def loss(q, k, v):
             return pallas_sparse_flash.sparse_flash_attention(
                 q, k, v, sel if selected else None, None,
-                False).astype(F32).sum()
+                False, window).astype(F32).sum()
 
         return jax.grad(loss, (0, 1, 2))(q, k, v)
 
@@ -149,6 +150,11 @@ def _sparse_flash(selected):
 CASES = {
     "sparse_flash_selected": (lambda: _sparse_flash(True), 3),
     "sparse_flash_causal": (lambda: _sparse_flash(False), 3),
+    "window_flash": (lambda: _sparse_flash(False, 2048, 6144), 3),
+    "window_flash_global_layer": (lambda: _sparse_flash(False, 0, 6144), 3),
+    "window_flash_four_windows": (lambda: _sparse_flash(False, 2048), 3),
+    # a window that is no multiple of the tile: one more tile in the band
+    "window_flash_ragged": (lambda: _sparse_flash(False, 1000), 3),
     "flash_fwd_causal": (lambda: _flash(True, False), 1),     # decoder self
     "flash_fwd_key_bias": (lambda: _flash(False, True), 1),   # encoder/cross
     "flash_bwd_causal": (lambda: _flash_bwd(True, False), 3),
@@ -275,13 +281,17 @@ def _adam_op(p, g, m1, m2, lr, b1p, b2p):
 
 
 @pytest.mark.parametrize("shape,sweeps", [((16, 2048, 768), 1),
-                                          ((2048, 18992), 0)])
+                                          ((2048, 18992), 0),
+                                          ((8, 2048, 1024), 1),
+                                          ((2048, 25024), 0)])
 def test_adam_op_asks_for_no_relayout(topo, monkeypatch, shape, sweeps):
-    """The optimizer tail of the decoder cell, without a chip.  The stacked
+    """The optimizer tail of the decoder cells, without a chip.  The stacked
     expert weight ``[16, 2048, 768]`` collapses to ``[32768, 768]`` as a
-    view (768 lanes, 2048 sublanes a slab) and keeps its Pallas sweep; the
-    untied head ``[2048, 18992]`` has a ragged last dim (18992 = 148 * 128
-    + 48), so its update is XLA's, in place.  Neither asks for a copy into
+    view (768 lanes, 2048 sublanes a slab) and keeps its Pallas sweep, as
+    does ``[8, 2048, 1024]``; the untied heads ``[2048, 18992]`` and
+    ``[2048, 25024]`` have a ragged last dim (18992 = 148 * 128 + 48, 25024
+    = 195 * 128 + 64), so their update is XLA's, in place.  None asks for a
+    copy into
     another layout: about the seven tensors' bytes are accessed (param,
     grad and two moments read; param and two moments written)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
