@@ -9,7 +9,10 @@ Each is a pure JAX function; gradients go through the generic vjp path
 (``ops.sparse_attention.calls{topk,seq,path}`` and, under a causal window,
 ``window``; ``ops.moe.calls{held,routed,path}`` and, for a router that is
 not the softmax one, ``score``; ``...declined{why}`` for every fallback;
-``ops.moe.bias_updates`` for every ``moe_bias_update`` lowered).
+``ops.moe.bias_updates`` for every ``moe_bias_update`` lowered;
+``ops.moe.row_moves{pass="backward",how="gather"}``, which
+``parallel/moe.py`` counts through ``_count`` where the backward of a row
+move is traced: two for every ``moe_experts_grad`` lowered).
 """
 
 from __future__ import annotations

@@ -17,6 +17,7 @@ transformer blocks do).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Tuple
 
@@ -120,12 +121,15 @@ def route_top_k(x, router_w, top_k: int, norm_topk: bool = True,
     else:
         raise ValueError(f"route_top_k: score {score!r} is neither "
                          "'softmax' nor 'sigmoid'")
-    if bias is None:
-        vals, idx = lax.top_k(scores, top_k)
-    else:
-        _, idx = lax.top_k(scores + lax.stop_gradient(
-            bias.astype(jnp.float32)), top_k)
-        vals = jnp.take_along_axis(scores, idx, axis=-1)
+    _, idx = lax.top_k(lax.stop_gradient(
+        scores if bias is None else scores + bias.astype(jnp.float32)),
+        top_k)
+    # the chosen scores read by a one-hot select, summed over the columns
+    # (one of them is the score, the others exact zeros): the same values
+    # as ``lax.top_k``'s own, and a transpose that is a select summed over
+    # the k choices where a gather's would be a scatter into [N, R]
+    chosen = idx[..., None] == jnp.arange(scores.shape[-1], dtype=idx.dtype)
+    vals = jnp.sum(jnp.where(chosen, scores[..., None, :], 0), axis=-1)
     if norm_topk:
         total = jnp.sum(vals, axis=-1, keepdims=True)
         vals = vals / ((total + jnp.float32(norm_eps)) if norm_eps
@@ -171,80 +175,151 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
     (absent ones last, as zero rows) and multiplied as grouped products
     (``lax.ragged_dot``), so a step in which every token chose held experts
     only is as right as any other.
+
+    The routing plan (the router's weights and choices, the sort by expert
+    and its inverse, the group sizes) is made once and kept for the
+    backward; the rows themselves (``N * top_k`` sorted rows, the hidden
+    products) are made again there, not kept.  Rows move by gathers in both
+    passes: the backward takes the cotangent rows through the inverse
+    permutation the forward already has (``_take_rows``) and scatters
+    nothing, so what a row costs is the products' and the gathers' time.
     """
     from ..fluid import amp
 
     shape = x.shape
     e = w1.shape[0]
+    xt = x.reshape((-1, shape[-1]))
+    n = xt.shape[0]
+
+    # the routing plan, made ONCE, outside the checkpoint below, which
+    # takes it as arguments: the backward sorts and counts nothing again
+    vals, idx = route_top_k(xt, router_w, top_k, norm_topk, score, bias,
+                            norm_eps, scale)
+    local = idx - jnp.int32(expert_offset)
+    held = (local >= 0) & (local < e)
+    group = jnp.where(held, local, e).reshape(-1)      # absent: last
+    # where each assignment lands once sorted by expert: its group's
+    # first row plus how many earlier assignments chose the same group
+    # (a cumulative count; no scatter).  int32 throughout: the package
+    # runs jax in x64 mode, where a sum of int32 is int64, which the
+    # TPU's grouped product refuses
+    i32 = jnp.int32
+    chose = (group[:, None] == jnp.arange(e + 1, dtype=i32)
+             ).astype(i32)                             # [N*k, E+1]
+    counts = jnp.sum(chose, axis=0, dtype=i32)
+    first = jnp.cumsum(counts, dtype=i32) - counts
+    # ``back`` (assignment -> sorted row) and ``order`` (sorted row ->
+    # assignment) are each other's inverse: either is a gather's index and
+    # the other the index of that gather's transpose (``_take_rows``)
+    back = jnp.sum(chose * (first[None, :] - 1
+                            + jnp.cumsum(chose, axis=0, dtype=i32)),
+                   axis=1, dtype=i32)
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    live = (jnp.arange(order.shape[0], dtype=i32) < first[e])[:, None]
+    # DEBT (ROADMAP S11, PERF.md section 7): the absent experts'
+    # assignments ride along as zero rows at the end of the LAST held
+    # group, so all N * top_k rows are gathered, multiplied and gathered
+    # back, forward and backward: 8 of every 9 where an eighth of the
+    # experts is held.  What that costs is the products' and the gathers'
+    # time over rows (no scatter is left); what dropping it can buy in the
+    # resident cells is what PR 30 read, 414.3 -> 394.6-405.6 ms a step
+    # with the cell's spread lost (a product call took 2.0 ms at 8,192 and
+    # at 65,536 live rows).  Without this line (sizes = counts[:e]) the
+    # products skip the row tiles past the last group and a step's time
+    # follows the router, which drifts toward the experts held as it
+    # trains without the absent ones; nothing else depends on it.
+    sizes = counts[:e].at[e - 1].add(counts[e])
+    gate = jnp.where(held, vals, 0.0)
 
     # a checkpoint: the backward makes the sorted rows and the experts'
     # hidden activations again instead of keeping N * top_k rows of them
-    # per layer (1 GB a layer at 8,192 tokens x 8 choices)
+    # per layer (1 GB a layer at 8,192 tokens x 8 choices).  The plan is
+    # kept, not made again: [N, k] and [N*k] integers and the gate
     @jax.checkpoint
-    def share(xt, router_w, w1, w3, w2, bias):
-        n = xt.shape[0]
-        vals, idx = route_top_k(xt, router_w, top_k, norm_topk, score, bias,
-                                norm_eps, scale)
-        local = idx - jnp.int32(expert_offset)
-        held = (local >= 0) & (local < e)
-        group = jnp.where(held, local, e).reshape(-1)      # absent: last
-        # where each assignment lands once sorted by expert: its group's
-        # first row plus how many earlier assignments chose the same group
-        # (a cumulative count; no scatter).  int32 throughout: the package
-        # runs jax in x64 mode, where a sum of int32 is int64, which the
-        # TPU's grouped product refuses
-        i32 = jnp.int32
-        chose = (group[:, None] == jnp.arange(e + 1, dtype=i32)
-                 ).astype(i32)                             # [N*k, E+1]
-        counts = jnp.sum(chose, axis=0, dtype=i32)
-        first = jnp.cumsum(counts, dtype=i32) - counts
-        back = jnp.sum(chose * (first[None, :] - 1
-                                + jnp.cumsum(chose, axis=0, dtype=i32)),
-                       axis=1, dtype=i32)
-        order = jnp.argsort(group, stable=True).astype(jnp.int32)
-        live = (jnp.arange(order.shape[0], dtype=i32) < first[e])[:, None]
-
+    def share(xt, gate, w1, w3, w2, held, sizes, order, back, live):
         # XLA's grouped product on the TPU leaves the rows outside every
         # group UNWRITTEN, in its results and in the cotangents it hands
         # back (NaN gradients on the chip; the CPU zero-fills them).  So
         # what a product returns for a row that holds no held assignment
         # is SELECTED away before anything reads it, never multiplied by
-        # zero, and ``where``'s own vjp does the same to the cotangent on
-        # its way back: xs and the two hidden products by ``live``, the
-        # last product where its rows are gathered, by ``held``.  Each
-        # select sits in a fusion that reads the rows anyway.  Right
-        # whatever ``sizes`` covers.
+        # zero, and the cotangent the same on its way back: the two hidden
+        # products by ``live`` (``where``'s own vjp selects their
+        # cotangents), the last product where its rows are gathered, by
+        # ``held``, and the cotangent of xs where ITS rows are gathered
+        # back, by ``held`` too (``_take_rows``'s ``keep``).  Each select
+        # sits in a fusion that reads the rows anyway; xs itself needs
+        # none: a row without a held assignment is some token's row, read
+        # by products whose results are selected away and met by exact
+        # zeros in the weights' gradients.  Right whatever ``sizes`` covers.
         def live_rows(rows):
             return jnp.where(live, rows, 0)
 
-        # DEBT (ROADMAP S11, PERF.md section 7): the absent experts'
-        # assignments ride along as zero rows at the end of the LAST held
-        # group, so all N * top_k rows are gathered, multiplied and
-        # scattered back: 8 of every 9 where an eighth of the experts is
-        # held, about 200 ms of a 414 ms step at 8,192 tokens.  Without
-        # this line (sizes = counts[:e]) the products skip the row tiles
-        # past the last group and a step's time follows the router, which
-        # drifts toward the experts held as it trains without the absent
-        # ones; nothing else depends on it.
-        sizes = counts[:e].at[e - 1].add(counts[e])
-        xs, a1, a3, a2, _ = amp.cast_operands(
-            jnp.take(xt, order // top_k, axis=0), w1, w3, w2)
-        xs = live_rows(xs)
+        # the tokens' rows are cast where they are taken, so that their
+        # cotangent comes back through the gather in AMP's type too
+        low, a1, a3, a2, _ = amp.cast_operands(xt, w1, w3, w2)
+        xs = _take_rows(xt, order, back, held.reshape(-1), top_k, low.dtype)
         h = jax.nn.silu(live_rows(lax.ragged_dot(xs, a1, sizes))
                         .astype(jnp.float32)) \
             * live_rows(lax.ragged_dot(xs, a3, sizes)).astype(jnp.float32)
         ys = lax.ragged_dot(h.astype(xs.dtype), a2, sizes)  # [N*k, D]
         # back to assignment order, weighted, summed over a token's choices
         ys = jnp.where(held[..., None],
-                       jnp.take(ys, back, axis=0).reshape(n, top_k, -1), 0)
-        gate = jnp.where(held, vals, 0.0)
-        y = jnp.einsum("nk,nkd->nd", gate, ys.astype(jnp.float32))
-        if with_counts:
-            return y, assignment_counts(idx, router_w.shape[-1])
-        return y
+                       _take_rows(ys, back, order, None, 1, ys.dtype)
+                       .reshape(n, top_k, -1), 0)
+        return jnp.einsum("nk,nkd->nd", gate, ys.astype(jnp.float32))
 
-    y = share(x.reshape((-1, shape[-1])), router_w, w1, w3, w2, bias)
+    y = share(xt, gate, w1, w3, w2, held, sizes, order, back, live)
+    y = y.astype(x.dtype).reshape(shape)
     if with_counts:
-        y, counts = y
-        return y.astype(x.dtype).reshape(shape), counts
-    return y.astype(x.dtype).reshape(shape)
+        return y, assignment_counts(idx, router_w.shape[-1])
+    return y
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _take_rows(rows, index, inverse, keep, repeat: int, dtype):
+    """``rows.astype(dtype)[index // repeat]``, where ``index`` is a
+    permutation of ``repeat * len(rows)`` row numbers and ``inverse`` the
+    permutation that undoes it.  The transpose of that gather, which
+    autodiff would lower as a scatter-add of every row (``unique_indices``
+    false, a row at a time on the TPU), is then a gather too: the cotangent
+    rows, in ``dtype``, taken through ``inverse``, and the ``repeat`` rows
+    that came from one summed in float32; ``keep`` (a mask over
+    ``inverse``, or None) says which of them count: the others, which may
+    never have been written, are selected away inside that sum.
+    ``routed_experts`` moves rows both ways with it: tokens out to their
+    ``top_k`` assignments in expert order (``order``, with ``back`` its
+    inverse, ``repeat = top_k``), and the experts' rows back to assignment
+    order (``back``, ``order``, 1)."""
+    return _permuted(rows.astype(dtype), index // repeat)
+
+
+def _permuted(rows, index):
+    # every index is a row number by construction (a permutation, or one
+    # divided by ``repeat``): said so, the gather needs no bounds check and no
+    # select over the rows it returns (a pass of its own after a TPU gather)
+    return rows.at[index].get(mode="promise_in_bounds")
+
+
+def _take_rows_fwd(rows, index, inverse, keep, repeat, dtype):
+    # the empty array carries the cotangent's type to the backward
+    return (_take_rows(rows, index, inverse, keep, repeat, dtype),
+            (inverse, keep, jnp.zeros((0,), rows.dtype)))
+
+
+def _take_rows_bwd(repeat, dtype, kept, d):
+    from ..ops.decoder_ops import _count
+
+    inverse, keep, like = kept
+    # one for every row move whose backward is traced as a gather: two an
+    # expert layer in each program lowered
+    _count("ops.moe.row_moves", **{"pass": "backward", "how": "gather"})
+    d = _permuted(d, inverse)
+    if keep is not None:
+        d = jnp.where(keep[:, None], d, 0)
+    if repeat > 1:
+        d = jnp.sum(d.reshape((-1, repeat) + d.shape[1:]), axis=1,
+                    dtype=jnp.promote_types(like.dtype, jnp.float32))
+    return d.astype(like.dtype), None, None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)

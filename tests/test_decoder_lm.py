@@ -718,3 +718,231 @@ def test_layer_kinds_follow_the_published_index():
     with pytest.raises(ValueError, match="needs dense_width"):
         decoder_lm.Config(128, 64, 2, 4, 2, 16, 32, 8, 4, 2,
                           dense_layers=1)
+
+
+# -- the expert layer's backward: rows go back through the kept plan ------
+
+def per_expert_loop(x, wr, w1, w3, w2, idx, off, score, norm, norm_eps,
+                    scale):
+    """The share in float64, plain: the router's scores, the weights of
+    the choices ``idx`` (renormalized if ``norm``), then every held expert
+    in turn over ALL tokens, weighted by what each token gave it (zero for
+    most)."""
+    logits = x @ wr
+    scores = jax.nn.softmax(logits, -1) if score == "softmax" \
+        else jax.nn.sigmoid(logits)
+    vals = jnp.take_along_axis(scores, idx, -1)
+    if norm:
+        vals = vals / (vals.sum(-1, keepdims=True) + norm_eps)
+    vals, y = scale * vals, 0.0
+    for j in range(w1.shape[0]):
+        weight = jnp.sum(jnp.where(idx == off + j, vals, 0.0), -1)
+        y = y + weight[:, None] * (
+            (jax.nn.silu(x @ w1[j]) * (x @ w3[j])) @ w2[j])
+    return y
+
+
+@pytest.mark.parametrize("top_k", [1, 8])
+@pytest.mark.parametrize("off,held", [(12, 4), (4, 4), (0, 12)],
+                         ids=["none_held", "some_held", "all_held"])
+@pytest.mark.parametrize("score", ["softmax", "sigmoid_bias"])
+def test_gradients_of_the_share_equal_a_per_expert_loop(score, off, held,
+                                                        top_k):
+    """Gradients w.r.t. x, the router and the three expert weights through
+    the kept plan and the gathers, against a per-expert loop in float64;
+    experts 12-15 are chosen by no token, so a share that holds them holds
+    no assignment, and one that holds 0-11 holds every one.  One choice a
+    token is not renormalized (its weight would be 1 and the router's
+    gradient zero).  The program routes, activates and combines in float32
+    whatever it is handed, hence 1e-5 of each gradient's largest entry and
+    not float64's own 1e-9."""
+    routed, n, d, f = 16, 24, 8, 4
+    rng = np.random.RandomState(11)
+    x, wr, w1, w3, w2 = (jnp.asarray(a, jnp.float64) for a in moe_weights(
+        rng, n, d, f, routed))
+    x = x.at[:, 0].set(1.0)
+    wr = wr.at[0, 12:].set(-60.0)
+    w1, w3, w2 = (w[off:off + held] for w in (w1, w3, w2))
+    sigmoid = score != "softmax"
+    kw = dict(norm_topk=top_k > 1)
+    if sigmoid:
+        kw.update(score="sigmoid", bias=jnp.asarray(
+            0.1 * rng.randn(routed), jnp.float32).at[12:].set(-10.0),
+            norm_eps=1e-20, scale=2.5)
+    _, idx = moe.route_top_k(x, wr, top_k, **kw)
+    lands = int(jnp.sum((idx >= off) & (idx < off + held)))
+    assert lands == {12: 0, 4: lands, 0: n * top_k}[off]
+    assert off != 4 or 0 < lands < n * top_k
+    mix = jnp.asarray(rng.randn(n, d))
+
+    def program(*args):
+        return jnp.sum(mix * moe.routed_experts(
+            *args, top_k=top_k, expert_offset=off, **kw))
+
+    def plain(*args):
+        return jnp.sum(mix * per_expert_loop(
+            *args, idx, off, kw.get("score", "softmax"), top_k > 1,
+            kw.get("norm_eps", 0.0), kw.get("scale", 1.0)))
+
+    args = (x, wr, w1, w3, w2)
+    got = jax.value_and_grad(program, range(5))(*args)
+    want = jax.value_and_grad(plain, range(5))(*args)
+    assert got[1][0].dtype == jnp.float64
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-12)
+    for name, g, r in zip("x router w1 w3 w2".split(), got[1], want[1]):
+        assert np.abs(g - r).max() <= 1e-5 * np.abs(r).max(), name
+        assert bool(np.any(r)) == (lands > 0), name
+
+
+@pytest.mark.parametrize("repeat", [1, 8])
+def test_taking_rows_transposes_as_a_gather_would(repeat):
+    """``moe._take_rows``'s backward, a gather through the inverse
+    permutation, against the scatter-add that is ``jnp.take``'s own
+    transpose: float64, to the last bits (the sum over the ``repeat`` rows
+    of one source in another order)."""
+    rng = np.random.RandomState(repeat)
+    n, d = 40, 6
+    rows = jnp.asarray(rng.randn(n, d))
+    order = jnp.asarray(rng.permutation(n * repeat), jnp.int32)
+    back = jnp.argsort(order).astype(jnp.int32)
+    mix = jnp.asarray(rng.randn(n * repeat, d))
+    np.testing.assert_array_equal(order[back], np.arange(n * repeat))
+    got = jax.value_and_grad(lambda r: jnp.sum(mix * moe._take_rows(
+        r, order, back, None, repeat, r.dtype)))(rows)
+    want = jax.value_and_grad(lambda r: jnp.sum(mix * jnp.take(
+        r, order // repeat, axis=0)))(rows)
+    assert got[0] == want[0] and got[1].dtype == jnp.float64
+    np.testing.assert_allclose(got[1], want[1], rtol=1e-13, atol=1e-15)
+    # in bfloat16 the rows of one source are summed in float32
+    # in bfloat16 the rows of one source are summed in float32, and the
+    # cotangent rows that ``keep`` leaves out (poisoned here) count for 0
+    keep = jnp.asarray(rng.rand(n * repeat) < 0.7)
+    cot = jnp.where(keep[order][:, None], mix, jnp.nan).astype(jnp.bfloat16)
+    low = jax.grad(lambda r: jnp.sum(moe._take_rows(
+        r, order, back, keep, repeat, jnp.bfloat16).astype(jnp.float32)
+        * cot))(rows.astype(jnp.float32))
+    assert low.dtype == jnp.float32 and bool(jnp.all(jnp.isfinite(low)))
+    np.testing.assert_allclose(
+        low, jnp.sum(jnp.where(
+            keep[:, None], jnp.take(cot, back, axis=0), 0)
+            .astype(jnp.float32).reshape(n, repeat, d), 1), rtol=1e-6)
+
+
+def test_the_chosen_scores_cotangent_lands_where_lax_top_k_puts_it():
+    """Repeated scores: router columns 1, 2 and 5 are copies of column 0,
+    so every token's scores tie.  The one-hot read puts the cotangent on
+    the columns ``lax.top_k`` chose (the lower index first) and on no
+    other, as ``lax.top_k``'s own vjp does."""
+    rng = np.random.RandomState(5)
+    x = jnp.asarray(rng.randn(32, 8), jnp.float32)
+    wr = jnp.asarray(rng.randn(8, 6), jnp.float32)
+    wr = wr.at[:, 1].set(wr[:, 0]).at[:, 2].set(wr[:, 0]) \
+        .at[:, 5].set(wr[:, 0])
+    mix = jnp.asarray(rng.randn(32, 2), jnp.float32)
+
+    def scores_of(w):
+        return jax.nn.sigmoid(jnp.matmul(x, w, precision="highest"))
+
+    vals, idx = moe.route_top_k(x, wr, 2, False, "sigmoid")
+    own_vals, own_idx = jax.lax.top_k(scores_of(wr), 2)
+    np.testing.assert_array_equal(idx, own_idx)
+    np.testing.assert_array_equal(vals, own_vals)
+    tied = np.asarray(idx[:, 0] == 0)
+    assert tied.any() and np.all(np.asarray(idx[:, 1])[tied] == 1)
+    got = jax.grad(lambda w: jnp.sum(mix * moe.route_top_k(
+        x, w, 2, False, "sigmoid")[0]))(wr)
+    want = jax.grad(lambda w: jnp.sum(mix * jax.lax.top_k(
+        scores_of(w), 2)[0]))(wr)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
+    # a sigmoid's score is its own column's: columns 2 and 5 tie with 0
+    # and 1 in every row and are never the first two
+    assert np.any(np.asarray(got[:, 0])) and np.any(np.asarray(got[:, 1]))
+    assert not np.any(np.asarray(got[:, [2, 5]]))
+
+
+def lowered_ops(lowered):
+    """(name, the locations it lies under, elements a scatter updates) for
+    every operation of a lowered module as it runs: jax lowers a jitted
+    helper (``argsort``) once, as a private function, however often it is
+    called, so a private function's operations count once for every call
+    of it, under the call's location too."""
+    from jax._src.lib.mlir import ir
+
+    bodies, out = {}, []
+    module = lowered.compiler_ir("stablehlo")
+    for func in module.body.operations:
+        ops = bodies[ir.StringAttr(func.attributes["sym_name"]).value] = []
+
+        def visit(op, ops=ops):
+            callee = updates = None
+            if op.name == "func.call":
+                callee = ir.FlatSymbolRefAttr(op.attributes["callee"]).value
+            elif op.name == "stablehlo.scatter":
+                updates = int(np.prod(op.operands[2].type.shape))
+            ops.append((op.name, str(op.location), callee, updates))
+            return ir.WalkResult.ADVANCE
+
+        func.operation.walk(visit)
+
+    def run(name, under):
+        for op, location, callee, updates in bodies[name]:
+            if callee is None:
+                out.append((op, under + (location,), updates))
+            else:
+                run(callee, under + (location,))
+
+    run("main", ())
+    return out
+
+
+@pytest.mark.parametrize("score", ["softmax", "sigmoid_bias"])
+def test_the_share_backward_lowers_no_row_scatter_and_one_sort(score):
+    """StableHLO of ``jax.grad`` of the share: the one scatter left adds
+    one count to one group size (``sizes``); the assignments are sorted
+    once, not once more behind the checkpoint; both row moves counted."""
+    x, wr, w1, w3, w2 = moe_weights(np.random.RandomState(6), 32, 8, 4, 16)
+    kw = {} if score == "softmax" else dict(
+        score="sigmoid", bias=jnp.zeros(16), norm_eps=1e-20, scale=2.0)
+    ops = lowered_ops(jax.jit(jax.grad(
+        lambda *a: jnp.sum(moe.routed_experts(
+            *a, top_k=4, expert_offset=4, **kw) ** 2), range(5))).lower(
+        x, wr, w1[:4], w3[:4], w2[:4]))
+    names = [op for op, _, _ in ops]
+    assert [n for op, _, n in ops if op == "stablehlo.scatter"] == [1]
+    assert names.count("stablehlo.sort") == 1
+    assert names.count("stablehlo.gather") >= 4
+    assert counters("ops.moe.row_moves") == {
+        'ops.moe.row_moves{how="gather",pass="backward"}': 2}
+
+
+def test_the_training_step_scatters_no_row_under_the_expert_layer():
+    """The tiny decoder's whole training step, lowered: under the expert
+    layer's two ops (``moe_experts`` and ``moe_experts_grad`` in the
+    locations) no scatter moves more than one element, each routed layer
+    sorts once in the forward op and once in the grad op's own trace of
+    the forward, and its backward counts two row moves."""
+    from paddle_tpu.models import decoder_lm
+
+    cfg = decoder_lm.tiny_config()
+    tokens, labels, loss = decoder_lm.build(cfg, seq_len=32)
+    exe = fluid.Executor(fluid.TPUPlace())
+    exe.run(fluid.default_startup_program())
+    rng = np.random.RandomState(0)
+    ids = rng.randint(0, cfg.vocab_size, size=(1, 33)).astype(np.int64)
+    ops = lowered_ops(exe.lower_step(
+        fluid.default_main_program(),
+        {"tokens": ids[:, :-1], "labels": ids[:, 1:, None]}, [loss]))
+    layer = [(op, where, n) for op, where, n in ops
+             if any("moe_experts" in w for w in where)]
+    grad = [op for op, where, _ in layer
+            if any("moe_experts_grad" in w for w in where)]
+    assert grad.count("stablehlo.gather") >= 4 * cfg.num_layers
+    assert [n for op, _, n in layer if op == "stablehlo.scatter"] \
+        == [1] * 2 * cfg.num_layers
+    # the step does scatter rows elsewhere (the embedding's gradient)
+    assert any(op == "stablehlo.scatter" and n > 1 for op, _, n in ops)
+    assert [op for op, _, _ in layer].count("stablehlo.sort") \
+        == 2 * cfg.num_layers
+    assert grad.count("stablehlo.sort") == cfg.num_layers
+    assert counters("ops.moe.row_moves") == {
+        'ops.moe.row_moves{how="gather",pass="backward"}': 2 * cfg.num_layers}
