@@ -34,7 +34,7 @@ __all__ = [
     "lod_reset", "prelu", "dice_loss", "log_loss", "huber_loss",
     "ring_attention", "moe_ffn", "gpipe_mlp_stack",
     "rms_norm", "rotary_embedding", "sparse_indexer", "sparse_attention",
-    "moe_experts", "moe_bias_update",
+    "moe_experts", "moe_bias_update", "short_conv",
     "kv_cache_update", "kv_cache_scatter", "token_select",
     "paged_attention", "spec_accept",
     "transformer_encoder_stack", "transformer_decoder_stack", "cos_sim",
@@ -1502,6 +1502,34 @@ def moe_bias_update(bias, counts, coeff, name=None):
                      outputs={"BiasOut": [bias]},
                      attrs={"coeff": float(coeff)})
     return bias
+
+
+def short_conv(input, taps, param_attr=None, name=None):
+    """A gated short convolution over the sequence (ops/decoder_ops.py
+    ``short_conv``), the token mixer of a layer without attention: for
+    ``input`` = [B | C | u] ([batch, T, 3 * channels], three chunks in this
+    order, as one projection makes them)
+    ``out[t] = C[t] * sum_j w[:, j] * (B * u)[t - (taps - 1) + j]`` with one
+    causal filter of ``taps`` weights a channel (``w`` [channels, taps], no
+    bias) and ``(B * u)[s] = 0`` for ``s < 0``.  Unlike ``row_conv`` it
+    looks back and never ahead, carries both gates, and takes a dense
+    [batch, T, ...] tensor: every row of the batch is a sequence of its
+    own and nothing crosses from one to the next."""
+    helper = LayerHelper("short_conv", **locals())
+    dtype = helper.input_dtype()
+    channels = int(input.shape[-1]) // 3
+    if int(taps) < 1 or 3 * channels != int(input.shape[-1]):
+        raise ValueError(f"short_conv: {taps} taps over an input "
+                         f"{tuple(input.shape)} that is not 3 * channels "
+                         f"wide")
+    w = helper.create_parameter(attr=helper.param_attr,
+                                shape=[channels, int(taps)], dtype=dtype)
+    out = helper.create_variable_for_type_inference(dtype)
+    out.shape = tuple(input.shape[:-1]) + (channels,)
+    helper.append_op(type="short_conv",
+                     inputs={"X": [input], "Filter": [w]},
+                     outputs={"Out": [out]})
+    return out
 
 
 def paged_attention(q, cache_k, cache_v, page_table, bias, scale=1.0,

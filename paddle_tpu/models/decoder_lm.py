@@ -1,9 +1,10 @@
-"""A decoder-only language model from its sizes: residual blocks of
-grouped-query attention (over a learned per-query selection of keys, under
-a causal window, or plain causal; window layers beside global ones) and a
+"""A decoder-only language model from its sizes: residual blocks whose
+token mixer is grouped-query attention (over a learned per-query selection
+of keys, under a causal window, or plain causal; window layers beside
+global ones) or, layer by layer, a gated short convolution, and a
 feed-forward that is dense in the leading layers and elsewhere a routed
 expert layer of which this program holds a stated share, RMS norms, rotary
-positions, an untied head, next-token loss.
+positions, a head of its own or the embedding's transpose, next-token loss.
 
 Everything is configuration (``Config``); nothing here is specific to one
 model or to the benchmark.  The layer, for x = one sequence [T, hidden] and
@@ -11,7 +12,13 @@ layer i (PUBLISHED index ``layer_offset + i``; [..] marks what a ``Config``
 field turns on)::
 
     h0 = Emb[tokens] [* embed_scale]
-    a  = RMSNorm(x);  q = a Wq, k = a Wk, v = a Wv  [g = a Wg]
+    a  = RMSNorm(x)
+    conv layer (mixers[published index] == "conv"):
+        [B | C | u] = a Win                          three chunks of hidden
+        c[t] = sum_j w[:, j] (B * u)[t - (conv_taps - 1) + j], zero before 0
+        y  = (C * c) Wout
+    attention layer (every layer where mixers is None), down to ``y``:
+    q  = a Wq, k = a Wk, v = a Wv  [g = a Wg]
     q  = RMSNorm_head(q), k = RMSNorm_head(k)        per head
     window layer: q, k = RoPE(q, k); key s counts for query t iff
                   0 <= t - s < window
@@ -33,7 +40,9 @@ field turns on)::
             w_e W2_e(silu(W1_e m) * W3_e m)
         Shared: the dense feed-forward at width shared_width
     x2 = x1 + f                     [post_norms: x1 + RMSNorm(f)]
-    logits = RMSNorm(x_last) Whead; loss = mean next-token cross-entropy
+    logits = RMSNorm(x_last) Whead [tie_head: Emb^T, one parameter whose
+        gradient is the lookup's rows plus the head product's]
+    loss = mean next-token cross-entropy
     after each step [route_bias_coeff], per routed layer:
         b_e += route_bias_coeff * sign(mean_e'(n_e') - n_e)
         n_e = the step's assignments to expert e, over ALL num_routed;
@@ -41,10 +50,13 @@ field turns on)::
 
 Parameters are created in a fixed order and named ``tok_emb``,
 ``l<i>_{attn_norm,q_w,q_norm,k_w,k_norm,v_w,idx_q_w,idx_k_w,idx_w_w,gate_w,
-o_w,post_attn_norm}``, then ``l<i>_{mlp_norm,mlp_w1,mlp_w3,mlp_w2}`` (dense)
+o_w,post_attn_norm}`` (a conv layer: ``l<i>_{conv_norm,conv_in_w,conv_w,
+conv_out_w,post_attn_norm}`` and none of the others), then
+``l<i>_{mlp_norm,mlp_w1,mlp_w3,mlp_w2}`` (dense)
 or ``l<i>_{moe_norm,shared_w1,shared_w3,shared_w2,router_w,w1,w3,w2}``
 (routed; ``l<i>_route_bias`` is no parameter), ``l<i>_post_mlp_norm``,
-``final_norm``, ``lm_head_w``; ``i`` counts the layers held, from 0.
+``final_norm``, ``lm_head_w`` (not with ``tie_head``); ``i`` counts the
+layers held, from 0.
 """
 
 from __future__ import annotations
@@ -52,6 +64,9 @@ from __future__ import annotations
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
 from paddle_tpu.fluid.param_attr import ParamAttr
+
+
+MIXERS = ("attention", "conv")
 
 
 class Config:
@@ -64,7 +79,8 @@ class Config:
                  attn_gate=False, post_norms=False, embed_scale=1.0,
                  dense_layers=0, dense_width=0, shared_width=0,
                  router_score="softmax", route_norm_eps=0.0,
-                 route_scale=1.0, route_bias_coeff=0.0):
+                 route_scale=1.0, route_bias_coeff=0.0, mixers=None,
+                 conv_taps=0, tie_head=False):
         if num_heads % num_kv_heads:
             raise ValueError(f"{num_heads} query heads do not group over "
                              f"{num_kv_heads} key-value heads")
@@ -73,6 +89,14 @@ class Config:
                              "model: no layer kind is defined for both")
         if dense_layers > layer_offset and not dense_width:
             raise ValueError("a leading dense layer needs dense_width")
+        held = (mixers or ())[layer_offset:layer_offset + num_layers]
+        if mixers is not None and (
+                len(held) != num_layers or set(held) - set(MIXERS)):
+            raise ValueError(
+                f"mixers names {list(held)} for the {num_layers} layers "
+                f"from {layer_offset} on: one of {MIXERS} each")
+        if "conv" in held and conv_taps < 1:
+            raise ValueError("a conv layer needs conv_taps")
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.num_layers = num_layers
@@ -109,6 +133,17 @@ class Config:
         self.route_norm_eps = route_norm_eps
         self.route_scale = route_scale
         self.route_bias_coeff = route_bias_coeff
+        # the token mixer of every PUBLISHED layer, "attention" or "conv"
+        # (None: attention everywhere); read at layer_offset + i
+        self.mixers = None if mixers is None else tuple(mixers)
+        self.conv_taps = conv_taps
+        self.tie_head = tie_head
+
+    def layer_mixer(self, i):
+        """The kind of held layer ``i``'s token mixer."""
+        if self.mixers is None:
+            return "attention"
+        return self.mixers[self.layer_offset + i]
 
     def layer_window(self, i):
         """The window of held layer ``i``: 0 where it is a global one."""
@@ -183,6 +218,14 @@ def _attention(x, cfg, seq_len, p, window):
     return _proj(ctx, cfg.hidden_size, f"{p}_o_w")
 
 
+def _short_conv(x, cfg, p):
+    """The mixer of a conv layer: one projection to both gates and the
+    filter's input, the gated causal filter, one projection back."""
+    y = layers.short_conv(_proj(x, 3 * cfg.hidden_size, f"{p}_conv_in_w"),
+                          cfg.conv_taps, param_attr=_attr(f"{p}_conv_w"))
+    return _proj(y, cfg.hidden_size, f"{p}_conv_out_w")
+
+
 def _feed_forward(x, cfg, width, p):
     """W2(silu(W1 x) * W3 x), no bias: ``<p>_w1`` gate, ``_w3`` up, ``_w2``
     down."""
@@ -218,8 +261,11 @@ def _forward(cfg, seq_len):
     routers = []
     for i in range(cfg.num_layers):
         p = f"l{i}"
-        y = _attention(_norm(h, cfg, f"{p}_attn_norm"), cfg, seq_len, p,
-                       cfg.layer_window(i))
+        if cfg.layer_mixer(i) == "conv":
+            y = _short_conv(_norm(h, cfg, f"{p}_conv_norm"), cfg, p)
+        else:
+            y = _attention(_norm(h, cfg, f"{p}_attn_norm"), cfg, seq_len, p,
+                           cfg.layer_window(i))
         if cfg.post_norms:
             y = _norm(y, cfg, f"{p}_post_attn_norm")
         h = layers.elementwise_add(h, y)
@@ -232,7 +278,12 @@ def _forward(cfg, seq_len):
             f = _norm(f, cfg, f"{p}_post_mlp_norm")
         h = layers.elementwise_add(h, f)
     h = _norm(h, cfg, "final_norm")
-    logits = _proj(h, cfg.vocab_size, "lm_head_w")
+    if cfg.tie_head:
+        logits = layers.matmul(
+            h, fluid.default_main_program().global_block().var("tok_emb"),
+            transpose_y=True)
+    else:
+        logits = _proj(h, cfg.vocab_size, "lm_head_w")
     loss = layers.mean(layers.softmax_with_cross_entropy(logits, labels))
     return tokens, labels, loss, logits, routers
 

@@ -1,6 +1,7 @@
 """Ops of a decoder-only language model with routed experts and a learned
 sparse attention: RMS norm, rotary positions, the indexer that selects each
-query's keys, grouped-query attention over that selection, and the share
+query's keys, grouped-query attention over that selection, the gated short
+convolution that mixes tokens where a layer has no attention, and the share
 of a routed expert layer that the experts held here give.
 
 Each is a pure JAX function; gradients go through the generic vjp path
@@ -10,6 +11,8 @@ Each is a pure JAX function; gradients go through the generic vjp path
 ``window``; ``ops.moe.calls{held,routed,path}`` and, for a router that is
 not the softmax one, ``score``; ``...declined{why}`` for every fallback;
 ``ops.moe.bias_updates`` for every ``moe_bias_update`` lowered;
+``ops.short_conv.calls{channels,taps,path}`` for every ``short_conv``
+lowered, its backward not counted;
 ``ops.moe.row_moves{pass="backward",how="gather"}``, which
 ``parallel/moe.py`` counts through ``_count`` where the backward of a row
 move is traced: two for every ``moe_experts_grad`` lowered).
@@ -269,6 +272,45 @@ def sparse_attention_grad(ctx):
                               ctx.input("Out@GRAD").astype(q.dtype), scale,
                               window=window)
     grads = {"Q@GRAD": dq, "K@GRAD": dk, "V@GRAD": dv}
+    return {s: g for s, g in grads.items() if s in ctx.outputs_spec}
+
+
+def gated_short_conv(x, w):
+    """``y[t] = C[t] * sum_j w[:, j] * (B * u)[t - (L - 1) + j]`` for
+    x = [B | C | u] ([batch, T, 3 * channels], three chunks in this order)
+    and w [channels, L]: one causal L-tap filter a channel, ``(B * u)[s]``
+    zero for s < 0.  The gates multiply in x's type (bf16 under AMP); the
+    taps are summed in float32.  L shifted multiply-adds over the padded
+    ``B * u``, which XLA fuses into one loop."""
+    c, taps = w.shape
+    t = x.shape[1]
+    z = jnp.pad(x[..., :c] * x[..., 2 * c:], ((0, 0), (taps - 1, 0), (0, 0)))
+    wf = w.astype(jnp.float32)
+    acc = sum(wf[:, j] * z[:, j:j + t].astype(jnp.float32)
+              for j in range(taps))
+    return (x[..., c:2 * c].astype(jnp.float32) * acc).astype(x.dtype)
+
+
+@register_op("short_conv")
+def short_conv_op(ctx):
+    """The token mixer of a layer without attention: both gates and the
+    causal per-channel filter (``gated_short_conv``).  X: [B, T, 3C];
+    Filter: [C, L]; Out: [B, T, C].  No state crosses sequences: each row
+    of the batch is padded on its own."""
+    x, w = ctx.input("X"), ctx.input("Filter")
+    _count("ops.short_conv.calls", channels=w.shape[0], taps=w.shape[1],
+           path="xla")
+    return {"Out": gated_short_conv(x, w)}
+
+
+@register_grad("short_conv")
+def short_conv_grad(ctx):
+    """From X and Filter alone: ``B * u`` and the filter's sum are made
+    again, nothing but the op's inputs is kept from the forward."""
+    x = ctx.input("X")
+    _, vjp = jax.vjp(gated_short_conv, x, ctx.input("Filter"))
+    dx, dw = vjp(ctx.input("Out@GRAD").astype(x.dtype))
+    grads = {"X@GRAD": dx, "Filter@GRAD": dw}
     return {s: g for s, g in grads.items() if s in ctx.outputs_spec}
 
 

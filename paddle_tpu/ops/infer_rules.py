@@ -597,3 +597,19 @@ def infer_moe_bias_update(op, ins):
             f"{counts[1]} must be one float and one integer per routed "
             f"expert")
     return {"BiasOut": [bias]}
+
+
+@register_infer("short_conv")
+def infer_short_conv(op, ins):
+    x, w = _in(ins, "X"), _in(ins, "Filter")
+    if x is None:
+        return None
+    if len(x[0]) != 3 or x[0][-1] % 3 or (
+            w is not None and (len(w[0]) != 2
+                               or 3 * w[0][0] != x[0][-1])):
+        raise InferMismatch(
+            f"short_conv: {_names(op, 'X')} {list(x[0])} must be [batch, "
+            f"positions, 3 * channels] (both gates and the filter's input "
+            f"side by side) and filter {_names(op, 'Filter')} "
+            f"{list(w[0]) if w is not None else '?'} [channels, taps]")
+    return {"Out": [(tuple(x[0][:2]) + (x[0][-1] // 3,), x[1])]}

@@ -946,3 +946,347 @@ def test_the_training_step_scatters_no_row_under_the_expert_layer():
     assert grad.count("stablehlo.sort") == cfg.num_layers
     assert counters("ops.moe.row_moves") == {
         'ops.moe.row_moves{how="gather",pass="backward"}': 2 * cfg.num_layers}
+
+
+# == gated short-convolution layers beside an attention one of head width ==
+# == 64, a tied head, a 32-way sigmoid router with top-4 and a bias: the  ==
+# == program against the reference of ``chipbench/configs/lfm2_8b_a1b``   ==
+
+LFM2 = "configs/lfm2_8b_a1b"
+L_BUILD = plugins.load(LFM2, "build")
+L_REF = plugins.load(LFM2, "reference")
+
+
+def lfm2_sizes(**over):
+    sizes = json.load(open(os.path.join(ROOT, "chipbench", LFM2,
+                                        "config.json")))
+    return {**sizes, **sizes["tiny"], **over}
+
+
+def three_shift_sum(x, w):
+    """The reference's own words for the op, a batch at a time: [B | C | u]
+    chunks, ``C * sum over three shifted copies of B * u``."""
+    c = w.shape[0]
+    return jnp.stack([
+        xb[:, c:2 * c] * L_REF.causal_filter(xb[:, :c] * xb[:, 2 * c:], w)
+        for xb in x])
+
+
+@pytest.mark.parametrize("flash", ["xla", "pallas"])
+def test_conv_program_equals_the_reference_tied_head_and_bias(
+        monkeypatch, flash):
+    """Loss, every gradient and every router's bias after the step through
+    ``fluid.Executor`` with ``optimizer.minimize``: published layers 1-5 (a
+    conv layer with the dense feed-forward, then attention, conv, conv,
+    conv with routed experts).  The embedding is the head: ONE parameter,
+    its gradient the lookup's rows plus the head product's, one Adam
+    update."""
+    monkeypatch.setenv("PADDLE_TPU_FLASH", "1" if flash == "pallas" else "0")
+    monkeypatch.setattr(psf, "BLOCK", 16)
+    sizes = lfm2_sizes()
+    assert sizes["num_experts"] < sizes["published"]["num_experts"]
+    built = L_BUILD.build(fluid, sizes)
+    main = fluid.default_main_program()
+    names = L_BUILD.trainable_names(main)
+    spec = L_REF.param_spec(sizes)
+    assert [n for n, _, _ in spec] == names
+    # a conv layer has no attention parameter, the attention layer no
+    # filter; the head is no parameter of its own
+    assert names.count("tok_emb") == 1 and "lm_head_w" not in names
+    for i, kind in enumerate(sizes["layer_types"][1:6]):
+        mine = {n[len(f"l{i}_"):] for n in names if n.startswith(f"l{i}_")}
+        attn = {"attn_norm", "q_w", "q_norm", "k_w", "k_norm", "v_w", "o_w"}
+        conv = {"conv_norm", "conv_in_w", "conv_w", "conv_out_w"}
+        assert (mine & (attn | conv)) == (conv if kind == "conv" else attn)
+    exe = fluid.Executor(fluid.TPUPlace())
+    exe.run(fluid.default_startup_program())
+    scope = fluid.global_scope()
+    weights = L_REF.init_params(5, sizes)
+    for (_, shape, _), name, w in zip(spec, names, weights):
+        assert tuple(np.shape(scope.get(name))) == tuple(shape), name
+        scope.set(name, jnp.array(w))
+    routers = [f"l{i}_route_bias" for i in range(1, 5)]
+    block = main.global_block()
+    for name in routers:
+        assert not np.any(np.asarray(scope.get(name)))
+        assert not block.has_var(name + "@GRAD") and name not in names
+    assert sum(1 for op in block.ops if op.type == "adam"
+               and "tok_emb" in op.inputs["Param"]) == 1
+    feed = L_BUILD.make_feed(sizes, 2, np.random.RandomState(3))
+    outs = exe.run(main, feed=feed, fetch_list=[built["loss"]]
+                   + [n + "@GRAD" for n in names])
+    ref_loss, ref_grads = L_REF.loss_and_grads(weights, feed, sizes)
+    assert float(outs[0].reshape(-1)[0]) == pytest.approx(float(ref_loss),
+                                                          rel=1e-5)
+    for name, g, r in zip(names, outs[1:], ref_grads):
+        g = np.asarray(g).reshape(r.shape)
+        assert np.abs(g - r).max() <= 2e-4 * np.abs(r).max() + 1e-7, name
+
+    # the tied gradient's two parts, from the reference with the two uses
+    # of the embedding told apart: the rows the lookup touched + the head
+    # product's [vocab, hidden], which is dense
+    def told_apart(lookup, head):
+        total = 0.0
+        for b in range(2):
+            logits, _ = L_REF.forward_one(
+                [lookup] + list(weights[1:]), feed["tokens"][b], sizes,
+                head=head)
+            total -= jnp.mean(jnp.take_along_axis(
+                jax.nn.log_softmax(logits, -1), feed["labels"][b], -1))
+        return total / 2
+
+    with jax.default_matmul_precision("highest"):
+        rows, dense_part = jax.grad(told_apart, (0, 1))(weights[0],
+                                                         weights[0])
+    untouched = np.setdiff1d(np.arange(sizes["vocab_size"]),
+                             np.unique(feed["tokens"]))
+    assert untouched.size and not np.any(np.asarray(rows)[untouched])
+    assert np.all(np.abs(np.asarray(dense_part)).sum(-1) > 0)
+    emb = np.asarray(outs[1 + names.index("tok_emb")])
+    both = np.asarray(rows + dense_part)
+    assert np.abs(emb - both).max() <= 2e-4 * np.abs(both).max()
+    assert np.abs(emb - np.asarray(dense_part)).max() \
+        > 0.1 * np.abs(both).max()
+
+    # one Adam update of the embedding, from the summed gradient
+    want = L_REF.optimizer_step(weights[0], ref_grads[0], sizes)
+    np.testing.assert_allclose(np.asarray(scope.get("tok_emb")), want,
+                               atol=2e-6)
+    after = L_REF.biases_after_step(weights, feed, sizes)
+    rate = sizes["assumed"]["bias_update_rate"]
+    for name, want in zip(routers, after):
+        got = np.asarray(scope.get(name))
+        np.testing.assert_allclose(got, want, atol=1e-9)
+        assert set(np.round(np.abs(got) / rate)) <= {0.0, 1.0} \
+            and np.any(got > 0) and np.any(got < 0)
+    per = 1 if flash == "pallas" else 2
+    assert counters("ops.sparse_attention.calls") == {
+        f'ops.sparse_attention.calls{{path="{flash}",seq="64",'
+        f'topk="0"}}': per}
+    assert counters("ops.short_conv.calls") == {
+        'ops.short_conv.calls{channels="64",path="xla",taps="3"}': 4}
+    (key, n), = counters("ops.moe.calls").items()
+    assert 'score="sigmoid"' in key and 'routed="8"' in key \
+        and 'held="4"' in key and n == 2 * 4
+    assert counters("ops.moe.bias_updates") == {"ops.moe.bias_updates": 4}
+    assert not counters("ops.sparse_attention.declined")
+
+
+@pytest.mark.parametrize("t", [1, 2, 13, 64])
+def test_short_conv_op_equals_the_three_shift_sum_gradients_too(t):
+    """The op through the executor, a batch of 2, at T under the filter's
+    three taps, at T no multiple of 8 and at the tiny cell's: output and
+    both gradients against the reference's sum over three shifted copies."""
+    b, c, taps = 2, 8, 3
+    rng = np.random.RandomState(t)
+    x = layers.data(name="x", shape=[t, 3 * c], dtype="float32")
+    x.stop_gradient = False
+    out = layers.short_conv(
+        x, taps, param_attr=fluid.ParamAttr(
+            name="filter", initializer=fluid.initializer.
+            NormalInitializer(0.0, 0.5)))
+    assert tuple(out.shape[1:]) == (t, c)
+    w = layers.assign(np.cos(np.arange(c, dtype="float32")))
+    loss = layers.reduce_sum(layers.elementwise_mul(out, w))
+    fluid.backward.append_backward(loss)
+    exe = fluid.Executor(fluid.TPUPlace())
+    exe.run(fluid.default_startup_program())
+    filt = jnp.asarray(np.asarray(fluid.global_scope().get("filter")))
+    assert filt.shape == (c, taps)
+    feed = {"x": rng.randn(b, t, 3 * c).astype("float32")}
+    got = exe.run(feed=feed, fetch_list=[out, "x@GRAD", "filter@GRAD"])
+    xs = jnp.asarray(feed["x"])
+    np.testing.assert_allclose(got[0], three_shift_sum(xs, filt), atol=1e-6)
+    want = jax.grad(lambda x, f: jnp.sum(
+        three_shift_sum(x, f) * jnp.cos(jnp.arange(c))), (0, 1))(xs, filt)
+    np.testing.assert_allclose(got[1], want[0], atol=1e-5)
+    np.testing.assert_allclose(got[2], want[1], atol=1e-5)
+    # the backward is the op's own and is not counted as a call
+    assert counters("ops.short_conv.calls") == {
+        f'ops.short_conv.calls{{channels="{c}",path="xla",taps="3"}}': 1}
+
+
+def test_short_conv_leaks_nothing_from_the_future_or_across_sequences():
+    """Perturbing token t of sequence 0 leaves its outputs before t and
+    every output of sequence 1 unchanged, and moves outputs t .. t + 2 and
+    no later one: the filter looks two tokens back and never ahead."""
+    c, t, at = 4, 12, 5
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(2, t, 3 * c), jnp.float32)
+    w = jnp.asarray(rng.randn(c, 3), jnp.float32)
+    base = decoder_ops.gated_short_conv(x, w)
+    moved = decoder_ops.gated_short_conv(x.at[0, at].add(1.0), w)
+    np.testing.assert_array_equal(moved[1], base[1])
+    np.testing.assert_array_equal(moved[0, :at], base[0, :at])
+    assert np.all(np.any(np.asarray(moved[0, at:at + 3] != base[0, at:at + 3]),
+                         -1))
+    np.testing.assert_array_equal(moved[0, at + 3:], base[0, at + 3:])
+    # the first token sees itself alone: the last tap
+    np.testing.assert_allclose(
+        base[:, 0], x[:, 0, c:2 * c] * w[:, 2] * x[:, 0, :c] * x[:, 0, 2 * c:],
+        rtol=1e-6)
+    # bf16 activations: the gates in bf16, the taps summed in float32
+    low = decoder_ops.gated_short_conv(x.astype(jnp.bfloat16), w)
+    assert low.dtype == jnp.bfloat16
+    np.testing.assert_allclose(low.astype(jnp.float32), base, atol=0.15,
+                               rtol=0.05)
+    dx, dw = jax.vjp(decoder_ops.gated_short_conv, x.astype(jnp.bfloat16),
+                     w)[1](jnp.ones_like(low))
+    assert dx.dtype == jnp.bfloat16 and dw.dtype == jnp.float32
+
+
+def test_mixers_are_read_at_the_published_index():
+    """``layer_offset`` 1 over the source's own list: conv, attention,
+    conv, conv, conv; ``None`` is attention everywhere, as every
+    configuration before had it."""
+    from paddle_tpu.models import decoder_lm
+
+    sizes = lfm2_sizes()
+    cfg = L_BUILD.config_of(sizes)
+    assert cfg.layer_offset == 1 and cfg.num_layers == 5
+    assert [cfg.layer_mixer(i) for i in range(5)] == [
+        "conv", "attention", "conv", "conv", "conv"]
+    assert [cfg.layer_is_dense(i) for i in range(5)] == [True] + [False] * 4
+    assert cfg.tie_head and cfg.conv_taps == 3 and cfg.head_dim == 16
+    assert cfg.mixers[:3] == ("conv", "conv", "attention") \
+        and len(cfg.mixers) == 24
+    for build in (BUILD.config_of(tiny_sizes()),
+                  T_BUILD.config_of(trinity_sizes()),
+                  decoder_lm.tiny_config()):
+        assert build.mixers is None and not build.tie_head \
+            and build.layer_mixer(0) == "attention"
+    with pytest.raises(ValueError, match="one of"):
+        decoder_lm.Config(128, 64, 2, 4, 2, 16, 32, 8, 4, 2,
+                          mixers=["conv", "scan"], conv_taps=3)
+    with pytest.raises(ValueError, match="one of"):     # a list too short
+        decoder_lm.Config(128, 64, 3, 4, 2, 16, 32, 8, 4, 2, layer_offset=1,
+                          mixers=["conv"] * 3, conv_taps=3)
+    with pytest.raises(ValueError, match="needs conv_taps"):
+        decoder_lm.Config(128, 64, 2, 4, 2, 16, 32, 8, 4, 2,
+                          mixers=["conv", "attention"])
+    # the cell's build reads the kinds back off the parameters it made
+    L_BUILD.build(fluid, sizes)
+    main = fluid.default_main_program()
+    assert L_BUILD.mixers_built(main, sizes) == sizes["layer_types"][1:6]
+    with pytest.raises(ValueError, match="layer 5 has no mixer"):
+        L_BUILD.mixers_built(main, {**sizes, "num_hidden_layers": 6})
+    with pytest.raises(ValueError, match="no such mixer"):
+        L_BUILD.config_of({**sizes, "layer_types": ["conv", "mamba"] * 12})
+
+
+@pytest.mark.parametrize("block", [64, 16])
+def test_grouped_query_flash_at_head_width_64_group_of_four(monkeypatch,
+                                                            block):
+    """Interpreted; 8 query heads over 2 key-value heads of width 64 (half
+    a lane row; the cell's 32 over 8), no selection: the three kernels
+    against ``blocked_attention`` and dense float32, gradients too, and
+    ``supported`` takes the operands."""
+    monkeypatch.setattr(psf, "BLOCK", block)
+    b, hq, hkv, t, d = 1, 8, 2, 64, 64
+    rng = np.random.RandomState(6)
+    q, k, v = (jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
+               for h in (hq, hkv, hkv))
+    assert psf.supported(q, k, None) == ""
+    w = jnp.cos(jnp.arange(d, dtype=jnp.float32))
+
+    def kernel(q, k, v):
+        return jnp.sum(psf.sparse_flash_attention(q, k, v, None, None, True)
+                       * w)
+
+    def blocked(q, k, v):
+        return jnp.sum(decoder_ops.blocked_attention(
+            q, k, v, None, d ** -0.5, block=block) * w)
+
+    out = psf.sparse_flash_attention(q, k, v, None, None, True)
+    np.testing.assert_allclose(out, dense(q, k, v, None), atol=2e-5)
+    np.testing.assert_allclose(
+        out, decoder_ops.blocked_attention(q, k, v, None, d ** -0.5,
+                                           block=block), atol=2e-5)
+    for g, r in zip(jax.grad(kernel, (0, 1, 2))(q, k, v),
+                    jax.grad(blocked, (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(g, r, atol=2e-4)
+
+
+def test_the_four_shares_of_eight_add_up_to_the_uncut_layer():
+    """32 routed experts scored by sigmoids under a selection bias, 4 per
+    token, 8 held by each of 4 chips, no shared expert: the shares add up
+    to what the reference gives for the uncut layer; every share reports
+    the same assignments, over all 32."""
+    routed, held, k = 32, 8, 4
+    rng = np.random.RandomState(1)
+    x, wr, w1, w3, w2 = moe_weights(rng, 48, 16, 8, routed)
+    bias = jnp.asarray(0.1 * rng.randn(routed), jnp.float32)
+    whole, n_whole = L_REF.routed(x, wr, bias, w1, w3, w2, k, 1.0, 1e-6)
+    assert int(n_whole.sum()) == 48 * k
+    total = 0.0
+    for off in range(0, routed, held):
+        part, n = moe.routed_experts(
+            x, wr, w1[off:off + held], w3[off:off + held],
+            w2[off:off + held], top_k=k, expert_offset=off, score="sigmoid",
+            bias=bias, norm_eps=1e-6, scale=1.0, with_counts=True)
+        mine, _ = L_REF.routed(x, wr, bias, w1[off:off + held],
+                               w3[off:off + held], w2[off:off + held], k,
+                               1.0, 1e-6, off)
+        np.testing.assert_allclose(part, mine, atol=1e-5)
+        np.testing.assert_array_equal(n, n_whole)
+        total = total + part
+    np.testing.assert_allclose(total, whole, atol=3e-5)
+    assert float(jnp.abs(whole).max()) > 0.1
+
+
+def test_the_references_bias_chooses_and_does_not_weigh():
+    """The reference's router with a large bias on one expert: the expert
+    enters every token's choice, the weights are the scores' alone over
+    their sum + 1e-6, and are the program's router's."""
+    rng = np.random.RandomState(7)
+    x = jnp.asarray(rng.randn(40, 16), jnp.float32)
+    wr = jnp.asarray(rng.randn(16, 32), jnp.float32)
+    bias = jnp.zeros(32).at[5].set(10.0)
+    with jax.default_matmul_precision("highest"):
+        vals, idx = L_REF.route(x, wr, bias, 4, 1.0, 1e-6)
+        _, idx0 = L_REF.route(x, wr, jnp.zeros(32), 4, 1.0, 1e-6)
+        scores = jax.nn.sigmoid(jnp.matmul(x, wr))
+    assert bool(jnp.all(jnp.any(idx == 5, -1)))
+    assert not bool(jnp.all(jnp.any(idx0 == 5, -1)))
+    chosen = jnp.take_along_axis(scores, idx, -1)
+    np.testing.assert_allclose(
+        vals, chosen / (chosen.sum(-1, keepdims=True) + 1e-6), rtol=1e-6)
+    mine, mine_idx = moe.route_top_k(x, wr, 4, True, "sigmoid", bias, 1e-6,
+                                     1.0)
+    np.testing.assert_array_equal(mine_idx, idx)
+    np.testing.assert_allclose(mine, vals, rtol=1e-5)
+    # one step of the rule from the counts, as the program's op has it
+    counts = moe.assignment_counts(idx, 32)
+    np.testing.assert_allclose(
+        L_REF.bias_step(bias, counts, lfm2_sizes()),
+        moe.balance_bias(bias, counts, 0.001), atol=1e-9)
+
+
+def test_infer_rule_of_the_short_convolution():
+    from paddle_tpu import analysis
+    from paddle_tpu.ops.registry import get_infer_rule
+
+    class Op:
+        def __init__(self, **attrs):
+            self.attrs, self.inputs, self.type = attrs, {}, "t"
+
+        def attr(self, name, default=None):
+            return self.attrs.get(name, default)
+
+    x = ((2, 16, 96), "bfloat16")
+    assert get_infer_rule("short_conv")(
+        Op(), {"X": [x], "Filter": [((32, 3), "float32")]}) == {
+            "Out": [((2, 16, 32), "bfloat16")]}
+    with pytest.raises(registry.InferMismatch, match="3 \\* channels"):
+        get_infer_rule("short_conv")(
+            Op(), {"X": [x], "Filter": [((48, 3), "float32")]})
+    with pytest.raises(registry.InferMismatch, match="3 \\* channels"):
+        get_infer_rule("short_conv")(Op(), {"X": [((2, 16, 97), "float32")]})
+    with pytest.raises(ValueError, match="not 3 \\* channels wide"):
+        layers.short_conv(layers.data(name="odd", shape=[16, 97],
+                                      dtype="float32"), 3)
+    x = layers.data(name="x", shape=[16, 96], dtype="float32")
+    out = layers.short_conv(x, 3)
+    report = analysis.verify_program(fluid.default_main_program(),
+                                     fetch_list=[out])
+    assert not report.errors, report.format()

@@ -127,12 +127,14 @@ def _paged():
                 ((s, 1, n * ps), F32)]
 
 
-def _sparse_flash(selected, window=0, t=8192):
+def _sparse_flash(selected, window=0, t=8192, hkv=4, d=128):
     """The decoder cells' attention (``keye_vl_2_0_30b_a3b``: one sequence
     of 8,192 tokens, 32 query heads over 4 key-value heads of width 128,
     bf16, an int8 selection; ``trinity_mini``: the same heads under a
-    causal window of 2,048, and with none, at 6,144 tokens), forward and
-    the two backward kernels."""
+    causal window of 2,048, and with none, at 6,144 tokens;
+    ``lfm2_8b_a1b``: 32 query heads over 8 key-value heads of width 64,
+    half a lane row, plain causal at 8,192), forward and the two backward
+    kernels."""
 
     def fn(q, k, v, sel):
         def loss(q, k, v):
@@ -142,14 +144,16 @@ def _sparse_flash(selected, window=0, t=8192):
 
         return jax.grad(loss, (0, 1, 2))(q, k, v)
 
-    kv = ((1, 4, t, 128), BF16)
-    return fn, [((1, 32, t, 128), BF16), kv, kv, ((1, t, t), jnp.int8)]
+    kv = ((1, hkv, t, d), BF16)
+    return fn, [((1, 32, t, d), BF16), kv, kv, ((1, t, t), jnp.int8)]
 
 
 #: name -> (builder, number of ``tpu_custom_call`` the compiled text holds)
 CASES = {
     "sparse_flash_selected": (lambda: _sparse_flash(True), 3),
     "sparse_flash_causal": (lambda: _sparse_flash(False), 3),
+    "sparse_flash_causal_heads_of_64": (
+        lambda: _sparse_flash(False, 0, 8192, 8, 64), 3),
     "window_flash": (lambda: _sparse_flash(False, 2048, 6144), 3),
     "window_flash_global_layer": (lambda: _sparse_flash(False, 0, 6144), 3),
     "window_flash_four_windows": (lambda: _sparse_flash(False, 2048), 3),
@@ -283,12 +287,17 @@ def _adam_op(p, g, m1, m2, lr, b1p, b2p):
 @pytest.mark.parametrize("shape,sweeps", [((16, 2048, 768), 1),
                                           ((2048, 18992), 0),
                                           ((8, 2048, 1024), 1),
-                                          ((2048, 25024), 0)])
+                                          ((2048, 25024), 0),
+                                          ((8, 2048, 1792), 1),
+                                          ((8, 1792, 2048), 1),
+                                          ((16384, 2048), 1)])
 def test_adam_op_asks_for_no_relayout(topo, monkeypatch, shape, sweeps):
     """The optimizer tail of the decoder cells, without a chip.  The stacked
     expert weight ``[16, 2048, 768]`` collapses to ``[32768, 768]`` as a
     view (768 lanes, 2048 sublanes a slab) and keeps its Pallas sweep, as
-    does ``[8, 2048, 1024]``; the untied heads ``[2048, 18992]`` and
+    do ``[8, 2048, 1024]``, ``[8, 2048, 1792]`` and ``[8, 1792, 2048]``
+    (1792 = 14 * 128) and the tied embedding ``[16384, 2048]``, which is
+    swept once though the step uses it twice; the untied heads ``[2048, 18992]`` and
     ``[2048, 25024]`` have a ragged last dim (18992 = 148 * 128 + 48, 25024
     = 195 * 128 + 64), so their update is XLA's, in place.  None asks for a
     copy into
