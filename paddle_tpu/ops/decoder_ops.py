@@ -337,11 +337,13 @@ def moe_experts_op(ctx):
     if bias is not None and bias.shape != (routed,):
         raise ValueError(f"moe_experts: a selection bias {bias.shape} for "
                          f"num_routed={routed}")
-    _count("ops.moe.calls", held=held, routed=routed, path="ragged_dot",
+    top_k = int(ctx.attr("top_k"))
+    _count("ops.moe.calls", held=held, routed=routed,
+           path=moe.product_path(ctx.input("X"), w1, ctx.input("W2"), top_k),
            **({} if score == "softmax" else {"score": score}))
     out = moe.routed_experts(
         ctx.input("X"), ctx.input("RouterW"), w1, ctx.input("W3"),
-        ctx.input("W2"), top_k=int(ctx.attr("top_k")),
+        ctx.input("W2"), top_k=top_k,
         expert_offset=offset, norm_topk=bool(ctx.attr("norm_topk", True)),
         score=score, bias=bias,
         norm_eps=float(ctx.attr("norm_eps", 0.0)),

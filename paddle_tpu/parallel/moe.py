@@ -173,7 +173,7 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
     deployment; nothing stands in for it here).  There is no capacity and
     nothing is dropped: the ``N * top_k`` assignments are sorted by expert
     (absent ones last, as zero rows) and multiplied as grouped products
-    (``lax.ragged_dot``), so a step in which every token chose held experts
+    (``grouped_product``), so a step in which every token chose held experts
     only is as right as any other.
 
     The routing plan (the router's weights and choices, the sort by expert
@@ -230,13 +230,22 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
     # trains without the absent ones; nothing else depends on it.
     sizes = counts[:e].at[e - 1].add(counts[e])
     gate = jnp.where(held, vals, 0.0)
+    # part of the plan where the products are the Pallas kernels': the
+    # tables that tell their grid steps row tiles and groups, for all
+    # twelve calls of the layer
+    tables = None
+    if product_path(x, w1, w2, top_k) == "pallas":
+        from ..ops import pallas_grouped
+
+        tables = pallas_grouped.plan(sizes, n * top_k)
 
     # a checkpoint: the backward makes the sorted rows and the experts'
     # hidden activations again instead of keeping N * top_k rows of them
     # per layer (1 GB a layer at 8,192 tokens x 8 choices).  The plan is
     # kept, not made again: [N, k] and [N*k] integers and the gate
     @jax.checkpoint
-    def share(xt, gate, w1, w3, w2, held, sizes, order, back, live):
+    def share(xt, gate, w1, w3, w2, held, sizes, order, back, live,
+              tables):
         # XLA's grouped product on the TPU leaves the rows outside every
         # group UNWRITTEN, in its results and in the cotangents it hands
         # back (NaN gradients on the chip; the CPU zero-fills them).  So
@@ -258,21 +267,85 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
         # cotangent comes back through the gather in AMP's type too
         low, a1, a3, a2, _ = amp.cast_operands(xt, w1, w3, w2)
         xs = _take_rows(xt, order, back, held.reshape(-1), top_k, low.dtype)
-        h = jax.nn.silu(live_rows(lax.ragged_dot(xs, a1, sizes))
+        h = jax.nn.silu(live_rows(grouped_product(xs, a1, sizes, tables))
                         .astype(jnp.float32)) \
-            * live_rows(lax.ragged_dot(xs, a3, sizes)).astype(jnp.float32)
-        ys = lax.ragged_dot(h.astype(xs.dtype), a2, sizes)  # [N*k, D]
+            * live_rows(grouped_product(xs, a3, sizes, tables)
+                        ).astype(jnp.float32)
+        ys = grouped_product(h.astype(xs.dtype), a2, sizes,
+                             tables)  # [N*k, D]
         # back to assignment order, weighted, summed over a token's choices
         ys = jnp.where(held[..., None],
                        _take_rows(ys, back, order, None, 1, ys.dtype)
                        .reshape(n, top_k, -1), 0)
         return jnp.einsum("nk,nkd->nd", gate, ys.astype(jnp.float32))
 
-    y = share(xt, gate, w1, w3, w2, held, sizes, order, back, live)
+    y = share(xt, gate, w1, w3, w2, held, sizes, order, back, live, tables)
     y = y.astype(x.dtype).reshape(shape)
     if with_counts:
         return y, assignment_counts(idx, router_w.shape[-1])
     return y
+
+
+def grouped_product(rows, weights, sizes, tables=None):
+    """[M, N]: the rows of group g (``sizes[g]`` of them, groups in order)
+    times ``weights[g]``; rows [M, K], weights [G, K, N].  The Pallas
+    kernels of ``ops/pallas_grouped`` where they take the shapes (lane-
+    aligned widths, whole row tiles, bf16 or float32), forward and
+    backward, else ``lax.ragged_dot`` and its own transposes: a choice by
+    what the operands are, which ``product_path`` states for a layer.
+    ``tables``: ``pallas_grouped.plan(sizes, M)`` where the caller made it
+    already for several products over the same groups."""
+    from ..ops import pallas_grouped
+
+    if pallas_grouped.supported(rows, weights):
+        return lax.ragged_dot(rows, weights, sizes)
+    if tables is None:
+        tables = pallas_grouped.plan(sizes, rows.shape[0])
+    return _kernel_product(rows, weights, tables)
+
+
+def product_path(x, w1, w2, top_k: int) -> str:
+    """'pallas' where ``routed_experts`` on these operands multiplies by
+    the Pallas kernels, 'ragged_dot' where by XLA's grouped product."""
+    from ..fluid import amp
+    from ..ops import pallas_grouped
+
+    low, a1, a2 = jax.eval_shape(lambda *a: amp.cast_operands(*a)[:-1],
+                                 x, w1, w2)
+    m = x.size // x.shape[-1] * top_k
+
+    def declined(w):
+        return pallas_grouped.supported(
+            jax.ShapeDtypeStruct((m, w.shape[1]), low.dtype), w)
+
+    return "ragged_dot" if declined(a1) or declined(a2) else "pallas"
+
+
+@jax.custom_vjp
+def _kernel_product(rows, weights, tables):
+    from ..ops import pallas_grouped
+
+    return pallas_grouped.grouped_matmul(rows, weights, None, plan=tables)
+
+
+def _kernel_product_fwd(rows, weights, tables):
+    return _kernel_product(rows, weights, tables), (rows, weights, tables)
+
+
+def _kernel_product_bwd(kept, d):
+    from ..ops import pallas_grouped
+
+    rows, weights, tables = kept
+    d = d.astype(rows.dtype)
+    # the rows' cotangent reads the weights as they lie (no transposed
+    # copy); the weights' gradient is summed in float32 inside the kernel
+    return (pallas_grouped.grouped_matmul(d, weights, None, transpose=True,
+                                          plan=tables),
+            pallas_grouped.grouped_matmul_t(rows, d, None, plan=tables),
+            None)
+
+
+_kernel_product.defvjp(_kernel_product_fwd, _kernel_product_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))

@@ -32,8 +32,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 import paddle_tpu  # noqa: F401  (x64 mode on, as every user has it)
-from paddle_tpu.ops import (pallas_flash, pallas_fused, pallas_paged,
-                            pallas_sparse_flash, registry)
+from paddle_tpu.ops import (pallas_flash, pallas_fused, pallas_grouped,
+                            pallas_paged, pallas_sparse_flash, registry)
 
 B, H, T, D = 64, 8, 256, 64          # attention: [batch, heads, len, d_head]
 R, V = B * T, 30000                  # loss head: [batch*len, vocab]
@@ -148,6 +148,37 @@ def _sparse_flash(selected, window=0, t=8192, hkv=4, d=128):
     return fn, [((1, 32, t, d), BF16), kv, kv, ((1, t, t), jnp.int8)]
 
 
+#: the decoder cells' grouped products: rows (tokens x top_k), hidden width,
+#: expert width, experts held (``chipbench/configs/<cell>/config.json``)
+GROUPED_CELLS = {"keye": (65536, 2048, 768, 16),
+                 "trinity": (49152, 2048, 1024, 8),
+                 "lfm2": (32768, 2048, 1792, 8)}
+
+
+def _grouped(cell, form, which):
+    """One of an expert layer's six products at a cell's shapes (bf16, as
+    AMP hands them over): ``which`` the up (``[G, hidden, width]``) or the
+    down (``[G, width, hidden]``) weights; ``form`` the product itself
+    (``plain``), the rows' cotangent (``transposed``: the weights as they
+    lie, contracted over their last axis) or the weights' gradient.  The
+    tiles have to fit the VMEM a kernel gets by DEFAULT (the module states
+    no ``vmem_limit_bytes``: a step with a larger one hung on the chip), the
+    column chunks are dynamic slices of the lane dimension, and Mosaic
+    wants the scalar-prefetch tables in 32 bits."""
+    m, d, f, g = GROUPED_CELLS[cell]
+    k, n = (d, f) if which == "up" else (f, d)
+    rows, out, w = ((m, k), BF16), ((m, n), BF16), ((g, k, n), BF16)
+    sizes = ((g,), I32)
+    if form == "plain":
+        return (lambda a, b, s: pallas_grouped.grouped_matmul(
+            a, b, s, interpret=False)), [rows, w, sizes]
+    if form == "transposed":
+        return (lambda a, b, s: pallas_grouped.grouped_matmul(
+            a, b, s, transpose=True, interpret=False)), [out, w, sizes]
+    return (lambda a, b, s: pallas_grouped.grouped_matmul_t(
+        a, b, s, interpret=False)), [rows, out, sizes]
+
+
 #: name -> (builder, number of ``tpu_custom_call`` the compiled text holds)
 CASES = {
     "sparse_flash_selected": (lambda: _sparse_flash(True), 3),
@@ -177,6 +208,10 @@ CASES = {
     "momentum_largest_param": (lambda: _sweep(_MOMENTUM, 3, (V, 512)), 1),
     "momentum_ragged_param": (lambda: _sweep(_MOMENTUM, 3, (V,)), 1),
     "paged_step": (_paged, 1),
+    **{f"grouped_{form}_{cell}_{which}": (
+        functools.partial(_grouped, cell, form, which), 1)
+       for cell in GROUPED_CELLS for which in ("up", "down")
+       for form in ("plain", "transposed", "weights_gradient")},
 }
 
 
@@ -232,6 +267,51 @@ def test_flash_signatures_are_the_benchmarks(topo, causal, bias):
             ",".join(results) + "<-" + ",".join(operands + key_bias), family
         assert module.flops(call.operands, call.results) == \
             2.0 * matmuls * _BH * T * T * D / (2 if causal else 1), family
+
+
+@pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))
+def test_grouped_signatures_are_the_benchmarks(topo, monkeypatch, cell):
+    """An expert layer's product with its backward at a cell's shapes,
+    lowered for the described chip: the two kernels' names, three scalar-
+    prefetch tables first (int32: the groups' first rows, then a row tile
+    and a group for each of ``tiles + G - 1`` steps), the rows and the
+    weights (or the cotangent) after them and one result are what
+    ``chipbench/kernels/grouped_matmul*.py`` count FLOPs from and what the
+    trace's events are matched by; three signatures, none shared."""
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from chipbench import hlo
+    from chipbench.plugins import load
+    from paddle_tpu.parallel import moe
+
+    m, d, f, g = GROUPED_CELLS[cell]
+    # the backend here is the CPU, which the kernels would answer with
+    # interpret mode: steered in the test, as the chip would have it
+    monkeypatch.setattr(pallas_grouped, "_resolve", lambda interpret: False)
+
+    def fn(rows, w, sizes):
+        return jax.value_and_grad(
+            lambda a, b: moe.grouped_product(a, b, sizes).astype(F32).sum(),
+            (0, 1))(rows, w)
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, t, sharding=chip) for s, t in
+            (((m, d), BF16), ((g, d, f), BF16), ((g,), I32))]
+    calls = hlo.custom_calls(jax.jit(fn).lower(*args).as_text())
+    steps = m // pallas_grouped.ROW_TILE + g - 1
+    tables = f"s32[{g + 1}],s32[{steps}],s32[{steps}]"
+    rows, hidden = f"bf16[{m},{d}]", f"bf16[{m},{f}]"
+    weights = f"bf16[{g},{d},{f}]"
+    assert [(c.kernel, hlo.signature(c)) for c in calls] == [
+        ("grouped_matmul", f"{hidden}<-{tables},{rows},{weights}"),
+        ("grouped_matmul", f"{rows}<-{tables},{hidden},{weights}"),
+        ("grouped_matmul_t", f"{weights}<-{tables},{rows},{hidden}")]
+    for call in calls:
+        module = load("kernels", call.kernel)
+        assert module.KERNEL == call.kernel
+        assert module.flops(call.operands, call.results) == 2.0 * m * d * f
 
 
 def _momentum_op(p, g, v, lr):
