@@ -13,7 +13,8 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence, Set
 
-from .framework import GRAD_VAR_SUFFIX, OpRole, Program, Variable, grad_var_name
+from .framework import GRAD_VAR_SUFFIX, NAME_SCOPE_ATTR, OpRole, Program, \
+    Variable, grad_var_name, name_scope_at
 from ..ops import registry as _reg
 
 
@@ -62,16 +63,24 @@ def append_backward(loss: Variable, parameter_list=None, no_grad_set=None,
     # fold a dynamic loss scale (and the guardian's grad-Inf fault
     # injection) into the seed at trace time via the @LOSS_SEED_MUL@ env
     # entry — see executor.run_op and guardian.seed_multiplier.
+    # the ops made here and not from a forward op's attrs go under the name
+    # scope of what they belong to: the seed where the loss was made, a sum
+    # of partial gradients where its variable was (a parameter's: where the
+    # parameter was created)
     loss_grad = grad_var_name(loss.name)
     _ensure_grad_var(block, loss.name, loss_grad)
-    block.append_op(
-        type="fill_any_like", inputs={"X": [loss.name]},
-        outputs={"Out": [loss_grad]},
-        attrs={"value": 1.0, "__loss_seed__": True,
-               OpRole.KEY: OpRole.Backward | OpRole.Loss})
+    loss_scope = next((block.ops[i].attr(NAME_SCOPE_ATTR, "")
+                       for i in reversed(relevant)
+                       if loss.name in block.ops[i].output_arg_names), "")
+    with name_scope_at(loss_scope):
+        block.append_op(
+            type="fill_any_like", inputs={"X": [loss.name]},
+            outputs={"Out": [loss_grad]},
+            attrs={"value": 1.0, "__loss_seed__": True,
+                   OpRole.KEY: OpRole.Backward | OpRole.Loss})
     produced[loss.name] = [loss_grad]
 
-    def finalize_grad(name: str) -> Optional[str]:
+    def finalize_grad(name: str, scope: str) -> Optional[str]:
         """Collapse accumulated partial grads for `name` into one var."""
         glist = produced.get(name)
         if not glist:
@@ -80,9 +89,10 @@ def append_backward(loss: Variable, parameter_list=None, no_grad_set=None,
             return glist[0]
         out = grad_var_name(name)
         _ensure_grad_var(block, name, out)
-        block.append_op(type="sum", inputs={"X": list(glist)},
-                        outputs={"Out": [out]},
-                        attrs={OpRole.KEY: OpRole.Backward})
+        with name_scope_at(scope):
+            block.append_op(type="sum", inputs={"X": list(glist)},
+                            outputs={"Out": [out]},
+                            attrs={OpRole.KEY: OpRole.Backward})
         produced[name] = [out]
         return out
 
@@ -94,7 +104,8 @@ def append_backward(loss: Variable, parameter_list=None, no_grad_set=None,
         for slot, names in fop.outputs.items():
             gnames = []
             for n in names:
-                g = finalize_grad(n) if n else None
+                g = finalize_grad(n, fop.attr(NAME_SCOPE_ATTR, "")) \
+                    if n else None
                 gnames.append(g if g is not None else "")
                 if g is not None:
                     has_any = True
@@ -151,7 +162,7 @@ def append_backward(loss: Variable, parameter_list=None, no_grad_set=None,
     for p in params:
         if not getattr(p, "trainable", True):
             continue
-        g = finalize_grad(p.name)
+        g = finalize_grad(p.name, getattr(p, "name_scope", ""))
         if g is None:
             continue
         gvar = block.var(g)

@@ -5,7 +5,8 @@ from __future__ import annotations
 
 import functools
 
-from .framework import OpRole, default_main_program
+from .framework import NAME_SCOPE_ATTR, OPTIMIZER_SCOPE, OpRole, \
+    default_main_program, name_scope_at, param_name_scope
 
 __all__ = ["ErrorClipByValue", "GradientClipByValue", "GradientClipByNorm",
            "GradientClipByGlobalNorm", "append_gradient_clip_ops",
@@ -41,7 +42,8 @@ def error_clip_callback(block, context):
         fwd_var = block._var_recursive(fwd_var_name)
         error_clip = getattr(fwd_var, "error_clip", None)
         if error_clip is not None:
-            error_clip._append_clip_op(block, grad_n)
+            with name_scope_at(op.attr(NAME_SCOPE_ATTR, "")):
+                error_clip._append_clip_op(block, grad_n)
 
 
 class BaseGradientClipAttr:
@@ -103,7 +105,8 @@ class GradientClipByGlobalNorm(BaseGradientClipAttr):
             raise ValueError("all parameters in a group should share clip_norm")
         from .layers import nn as _nn
 
-        local_norm = _nn.reduce_sum(_nn.elementwise_mul(grad, grad))
+        with param_name_scope(param):
+            local_norm = _nn.reduce_sum(_nn.elementwise_mul(grad, grad))
         context[self.group_name].append(local_norm)
         self.context = context
 
@@ -112,12 +115,15 @@ class GradientClipByGlobalNorm(BaseGradientClipAttr):
 
         group_scale_name = self.group_name + "_scale"
         if group_scale_name not in self.context:
-            group_norm = _tensor.sums(input=self.context[self.group_name])
-            group_norm = _ops.sqrt(group_norm)
-            clip_var = _tensor.fill_constant(shape=[1], dtype="float32",
-                                             value=self.clip_norm)
-            group_scale = _nn.elementwise_div(
-                clip_var, _nn.elementwise_max(clip_var, group_norm))
+            # the group's own ops are no parameter's
+            with name_scope_at(OPTIMIZER_SCOPE):
+                group_norm = _tensor.sums(
+                    input=self.context[self.group_name])
+                group_norm = _ops.sqrt(group_norm)
+                clip_var = _tensor.fill_constant(shape=[1], dtype="float32",
+                                                 value=self.clip_norm)
+                group_scale = _nn.elementwise_div(
+                    clip_var, _nn.elementwise_max(clip_var, group_norm))
             self.context[group_scale_name] = group_scale
         new_grad = _nn.elementwise_mul(grad, self.context[group_scale_name])
         return param, new_grad
@@ -149,7 +155,7 @@ def append_unscale_ops(params_grads, loss_scale_var):
             res.append((p, g))
             continue
         block = p.block
-        with program_guard(block.program):
+        with program_guard(block.program), param_name_scope(p):
             new_grad = _nn.elementwise_div(g, loss_scale_var)
         # backward role: for_test clones and inference pruning must drop
         # the unscale ops together with the rest of the backward graph
@@ -168,5 +174,6 @@ def append_gradient_clip_ops(param_grad):
     for p, g in param_grad:
         clip_attr = getattr(p, "gradient_clip_attr", None) or \
             NullGradientClipAttr()
-        res.append(clip_attr._create_operators(param=p, grad=g))
+        with param_name_scope(p):
+            res.append(clip_attr._create_operators(param=p, grad=g))
     return res

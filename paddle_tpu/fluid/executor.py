@@ -17,6 +17,7 @@ so XLA can alias their buffers (true in-place update on TPU HBM).
 
 from __future__ import annotations
 
+import contextlib
 from collections import OrderedDict
 from typing import Dict, List, Optional
 
@@ -26,8 +27,8 @@ import numpy as np
 
 from . import core
 from . import step as _step
-from .framework import (OpRole, Program, RNG_STATE_VAR, Variable,
-                        default_main_program)
+from .framework import (NAME_SCOPE_ATTR, NAME_SCOPE_MARK, OpRole, Program,
+                        RNG_STATE_VAR, Variable, default_main_program)
 from .step import BlockPlan  # noqa: F401  (planned there, imported from here)
 from ..ops import registry as _reg
 
@@ -364,11 +365,18 @@ def run_op(op, env: Dict[str, object], rng_box=None):
     outputs_spec = {slot: list(names) for slot, names in op.outputs.items() if names}
     ctx = _reg.ExecContext(op.type, inputs, outputs_spec, op.attrs, rng_box)
 
-    # the scope name lands in XLA HLO metadata (op_name="jit(..)/<type>/..")
-    # so device profiles attribute per-HLO-op time back to framework ops
-    # (ref: platform/device_tracer.h:49 correlation_id -> op role; here the
-    # correlation is carried by the compiler instead of CUPTI ids)
-    with jax.named_scope(op.type):
+    # the scope names land in XLA HLO metadata
+    # (op_name="jit(..)/<type>/~<name scope>/..") so device profiles
+    # attribute per-HLO-op time back to framework ops and, beneath them,
+    # to the model's blocks (ref: platform/device_tracer.h:49
+    # correlation_id -> op role; here the correlation is carried by the
+    # compiler instead of CUPTI ids).  The op type stays FIRST; the
+    # fluid.name_scope path is ONE segment behind NAME_SCOPE_MARK, which
+    # no segment jax makes starts with.  Metadata only, made at trace time.
+    path = op.attrs.get(NAME_SCOPE_ATTR)
+    with jax.named_scope(op.type), \
+            jax.named_scope(NAME_SCOPE_MARK + path) if path \
+            else contextlib.nullcontext():
         if is_grad:
             if opdef.grad_fn is not None:
                 raw = opdef.grad_fn(ctx)

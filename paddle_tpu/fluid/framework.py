@@ -109,6 +109,8 @@ class Parameter(Variable):
         self.regularizer = kwargs.get("regularizer", None)
         self.gradient_clip_attr = kwargs.get("gradient_clip_attr", None)
         self.do_model_average = kwargs.get("do_model_average", None)
+        # where its update ops go (optimizer.minimize)
+        self.name_scope = current_name_scope()
 
 
 class Operator:
@@ -259,22 +261,30 @@ class Block:
         return [v for v in self.vars.values() if isinstance(v, Parameter)]
 
     # ---- ops ----
-    def append_op(self, type=None, inputs=None, outputs=None, attrs=None) -> Operator:
+    def _new_op(self, type, inputs, outputs, attrs) -> Operator:
+        """An op of this block, under the name scope that is open (unless
+        ``attrs`` already say under which: a grad op's are its forward
+        op's)."""
         op = Operator(self, type=type, inputs=inputs, outputs=outputs, attrs=attrs)
-        self.ops.append(op)
+        path = current_name_scope()
+        if path:
+            op.attrs.setdefault(NAME_SCOPE_ATTR, path)
         self.program._bump_version()
+        return op
+
+    def append_op(self, type=None, inputs=None, outputs=None, attrs=None) -> Operator:
+        op = self._new_op(type, inputs, outputs, attrs)
+        self.ops.append(op)
         return op
 
     def _prepend_op(self, type=None, inputs=None, outputs=None, attrs=None) -> Operator:
-        op = Operator(self, type=type, inputs=inputs, outputs=outputs, attrs=attrs)
+        op = self._new_op(type, inputs, outputs, attrs)
         self.ops.insert(0, op)
-        self.program._bump_version()
         return op
 
     def _insert_op(self, index, type=None, inputs=None, outputs=None, attrs=None) -> Operator:
-        op = Operator(self, type=type, inputs=inputs, outputs=outputs, attrs=attrs)
+        op = self._new_op(type, inputs, outputs, attrs)
         self.ops.insert(index, op)
-        self.program._bump_version()
         return op
 
     def _remove_op(self, index):
@@ -489,10 +499,62 @@ def program_guard(main_program, startup_program=None):
             switch_startup_program(old_startup)
 
 
+# ---------------------------------------------------------------------------
+# name scopes (ref: the later framework.py's name_scope / op_namescope attr)
+# ---------------------------------------------------------------------------
+
+#: the ONE string attr that carries an op's name-scope path (``stage2.block1``)
+NAME_SCOPE_ATTR = "op_namescope"
+#: what the executor puts before the path in the compiled program's metadata
+#: (``jit(fn)/conv2d_grad/~stage2.block1/..``): no segment jax makes starts
+#: so.  Not ``@``: XLA takes a segment that starts with it out of ``op_name``
+#: (it reads there as a call site), on the CPU and on the TPU
+NAME_SCOPE_MARK = "~"
+#: where ``Optimizer.minimize`` puts the ops that belong to no parameter
+OPTIMIZER_SCOPE = "optimizer"
+
+_name_scope_stack: List[str] = []
+
+
+def current_name_scope() -> str:
+    """The open name scopes joined with ``.``; ``""`` where none is open."""
+    return ".".join(_name_scope_stack)
+
+
 @contextlib.contextmanager
 def name_scope(prefix=None):
-    # cosmetic in the reference; kept for parity
-    yield
+    """Every op appended inside carries the path of the open scopes as its
+    ``op_namescope`` attr; the executor pushes it into the compiled
+    program's metadata beneath the op type (``run_op``), so a device trace
+    reads by model block.  ``prefix`` None or empty opens nothing."""
+    if not prefix:
+        yield
+        return
+    depth = len(_name_scope_stack)
+    _name_scope_stack.append(str(prefix))
+    try:
+        yield
+    finally:
+        del _name_scope_stack[depth:]
+
+
+@contextlib.contextmanager
+def name_scope_at(path):
+    """The scope ``path`` whatever is open now: ops made later FOR something
+    named earlier (a parameter's update, a pass's replacement) go where it
+    is.  An empty path is no scope."""
+    saved = _name_scope_stack[:]
+    _name_scope_stack[:] = [path] if path else []
+    try:
+        yield
+    finally:
+        _name_scope_stack[:] = saved
+
+
+def param_name_scope(param):
+    """The scope a parameter's own update, clip and decay ops go under: the
+    one it was created in, else the optimizer's."""
+    return name_scope_at(getattr(param, "name_scope", "") or OPTIMIZER_SCOPE)
 
 
 def fresh_session():
@@ -505,5 +567,6 @@ def fresh_session():
 
     switch_main_program(Program())
     switch_startup_program(Program())
+    del _name_scope_stack[:]
     _unique_name.switch()
     _executor._global_scope = _executor.Scope()

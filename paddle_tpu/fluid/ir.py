@@ -284,12 +284,16 @@ class ConvBNFuse(Pass):
                              dtype="float32", persistable=True)
             # rewrite: conv_out -> add(conv_out, bias) replaces the BN
             bn_out = bn.op.outputs["Y"][0]
-            from .framework import Operator
+            from .framework import NAME_SCOPE_ATTR, Operator
 
+            # it stands for the batch_norm: under that op's name scope
+            attrs = {"axis": 1}
+            if bn.op.attr(NAME_SCOPE_ATTR):
+                attrs[NAME_SCOPE_ATTR] = bn.op.attr(NAME_SCOPE_ATTR)
             add_op = Operator(
                 block, "elementwise_add",
                 inputs={"X": [out_vn.name], "Y": [bias_name]},
-                outputs={"Out": [bn_out]}, attrs={"axis": 1})
+                outputs={"Out": [bn_out]}, attrs=attrs)
             idx = graph.op_nodes.index(bn)
             graph.remove_op(bn)
             new_node = Node("op", "elementwise_add", op=add_op)

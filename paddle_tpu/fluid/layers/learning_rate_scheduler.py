@@ -115,6 +115,7 @@ def append_LARS(params_grads, learning_rate, weight_decay):
     stored back on param.optimize_attr for _create_param_lr to pick up."""
     from . import nn as _nn
     from . import ops as _ops
+    from ..framework import param_name_scope
 
     def _balanced_weight(param_norm, grad_norm):
         if weight_decay == 1.0:
@@ -125,17 +126,18 @@ def append_LARS(params_grads, learning_rate, weight_decay):
     for param, grad in params_grads:
         if grad is None:
             continue
-        attr = param.optimize_attr or {}
-        param_lr = attr.get("learning_rate", 1.0)
-        param_norm = _ops.sqrt(_nn.reduce_sum(_ops.square(param)))
-        grad_norm = _ops.sqrt(_nn.reduce_sum(_ops.square(grad)))
-        if isinstance(param_lr, (int, float)):
-            scaled = learning_rate if param_lr == 1.0 else \
-                _nn.scale(learning_rate, scale=float(param_lr))
-        else:  # a Variable (e.g. a prior LARS pass): compose, like the ref
-            scaled = _nn.elementwise_mul(learning_rate, param_lr)
-        decayed = _nn.elementwise_div(
-            _nn.elementwise_mul(scaled, param_norm),
-            _balanced_weight(param_norm, grad_norm))
-        attr["learning_rate"] = decayed
-        param.optimize_attr = attr
+        with param_name_scope(param):
+            attr = param.optimize_attr or {}
+            param_lr = attr.get("learning_rate", 1.0)
+            param_norm = _ops.sqrt(_nn.reduce_sum(_ops.square(param)))
+            grad_norm = _ops.sqrt(_nn.reduce_sum(_ops.square(grad)))
+            if isinstance(param_lr, (int, float)):
+                scaled = learning_rate if param_lr == 1.0 else \
+                    _nn.scale(learning_rate, scale=float(param_lr))
+            else:  # a Variable (e.g. a prior LARS pass): compose, like the ref
+                scaled = _nn.elementwise_mul(learning_rate, param_lr)
+            decayed = _nn.elementwise_div(
+                _nn.elementwise_mul(scaled, param_norm),
+                _balanced_weight(param_norm, grad_norm))
+            attr["learning_rate"] = decayed
+            param.optimize_attr = attr

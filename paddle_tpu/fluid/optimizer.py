@@ -13,8 +13,9 @@ from collections import defaultdict
 from . import unique_name
 from .backward import append_backward
 from .clip import append_gradient_clip_ops, error_clip_callback
-from .framework import OpRole, Program, Variable, default_main_program, \
-    default_startup_program, program_guard
+from .framework import OPTIMIZER_SCOPE, OpRole, Program, Variable, \
+    default_main_program, default_startup_program, name_scope_at, \
+    param_name_scope, program_guard
 from .initializer import ConstantInitializer
 from .layer_helper import LayerHelper
 from .regularizer import append_regularization_ops
@@ -83,8 +84,9 @@ class Optimizer:
         var = self.helper.create_global_variable(
             name=unique_name.generate(name + "_" + param.name),
             persistable=True, dtype=dtype or param.dtype, shape=shape)
-        self.helper.set_variable_initializer(
-            var, ConstantInitializer(float(fill_value)))
+        with param_name_scope(param):
+            self.helper.set_variable_initializer(
+                var, ConstantInitializer(float(fill_value)))
         self._accumulators[name][param.name] = var
         # explicit accumulator->param registry on the Program, consumed by
         # parallel.spmd.infer_param_specs so sharding specs follow ownership
@@ -130,8 +132,9 @@ class Optimizer:
                 if param_and_grad[1] is None:
                     continue
                 if getattr(param_and_grad[0], "trainable", True):
-                    op = self._append_optimize_op(program.global_block(),
-                                                  param_and_grad)
+                    with param_name_scope(param_and_grad[0]):
+                        op = self._append_optimize_op(
+                            program.global_block(), param_and_grad)
                     op.attrs[OpRole.KEY] = OpRole.Optimize
                     op.attrs[OpRole.VAR_KEY] = [param_and_grad[0].name,
                                                 param_and_grad[1].name]
@@ -147,23 +150,29 @@ class Optimizer:
         # persistable scale var seeds the backward (run_op folds it into
         # the __loss_seed__ op) and the raw grads are unscaled here,
         # BEFORE clip/regularization/update ever see them
+        # name scopes: a grad op keeps its forward op's; what is made here
+        # for ONE parameter (unscale, clip, decay, update) goes where the
+        # parameter was created (``param_name_scope``), the rest (the loss
+        # scale, a group's norm, the learning rate) under OPTIMIZER_SCOPE
         scale_var = None
         if _amp.dynamic_scaling_active():
-            scale_var = _amp.create_loss_scaling_vars(
-                loss.block.program,
-                startup_program or default_startup_program())
+            with name_scope_at(OPTIMIZER_SCOPE):
+                scale_var = _amp.create_loss_scaling_vars(
+                    loss.block.program,
+                    startup_program or default_startup_program())
         params_grads = append_backward(loss, parameter_list, no_grad_set,
                                        [error_clip_callback])
         params_grads = sorted(params_grads, key=lambda x: x[0].name)
-        if scale_var is not None:
-            from .clip import append_unscale_ops
+        with name_scope_at(OPTIMIZER_SCOPE):
+            if scale_var is not None:
+                from .clip import append_unscale_ops
 
-            params_grads = append_unscale_ops(params_grads, scale_var)
-        params_grads = append_gradient_clip_ops(params_grads)
-        params_grads = append_regularization_ops(params_grads,
-                                                 self.regularization)
-        optimize_ops = self._create_optimization_pass(params_grads, loss,
-                                                      startup_program)
+                params_grads = append_unscale_ops(params_grads, scale_var)
+            params_grads = append_gradient_clip_ops(params_grads)
+            params_grads = append_regularization_ops(params_grads,
+                                                     self.regularization)
+            optimize_ops = self._create_optimization_pass(
+                params_grads, loss, startup_program)
         return optimize_ops, params_grads
 
 
@@ -507,7 +516,8 @@ class ModelAverage(Optimizer):
             self._add_accumulator("old_num_accumulates", p, dtype="int64",
                                   shape=[1])
             self._add_accumulator("num_updates", p, dtype="int64", shape=[1])
-            self._append_average_accumulate_op(block, p)
+            with param_name_scope(p):
+                self._append_average_accumulate_op(block, p)
 
     def _append_average_accumulate_op(self, block, param):
         accs = {n: self._get_accumulator(n, param)

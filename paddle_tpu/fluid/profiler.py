@@ -9,14 +9,16 @@ traces open in TensorBoard/perfetto/XProf).
 min, max, ave) and writes a JSON event log that ``tools/timeline.py``
 converts to a chrome://tracing file (ref: tools/timeline.py:36,115).
 
-Storage note (ISSUE 5): the counters and the [calls,total,min,max] event
-aggregates used to live in module-level plain dicts — an unlocked
-read-modify-write per update that DROPPED increments whenever serving
-workers, the guardian observer and the training loop emitted concurrently.
-Both now route through ``paddle_tpu.observe``'s process registry: counters
-via ``registry.inc``/``set_gauge``, event aggregates via
-``registry.record_timing``, and this module's timeline list is mutated
-under the registry's own lock, so one lock covers all profiler state.
+Storage note: this module keeps no store of its own.  Counters and the
+[calls,total,min,max] event aggregates live in ``paddle_tpu.observe``'s
+process registry (``registry.inc``/``set_gauge``/``record_timing``, one lock,
+no dropped increments under concurrent emitters), and a timed event is a
+span like any other: ``record_event`` hands it to
+``observe.trace.emit_span`` (the ring and, where a sink is set, the event
+log), and ``stop_profiler`` writes its JSON from the ring, so the timeline
+holds the executor's own ``fluid.run.*`` spans beside the events recorded
+here.  Nothing here waits on the device: device time is the device
+trace's (``device_op_table``).
 """
 
 from __future__ import annotations
@@ -34,8 +36,7 @@ __all__ = ["cuda_profiler", "reset_profiler", "profiler", "start_profiler",
 
 _trace_dir = None
 _on = False
-_timeline = []   # {"name", "ts", "dur"} microseconds since start
-_t0 = 0.0
+_t0 = 0.0        # perf_counter at start_profiler: the timeline's zero
 
 
 def _registry():
@@ -49,21 +50,17 @@ def is_profiling() -> bool:
 
 
 def record_event(name: str, seconds: float, start: float = None) -> None:
-    """Aggregate one timed host event (executor hooks call this)."""
+    """Aggregate one timed host event (executor hooks call this) and keep
+    it as a span in the one ring (``observe.trace``), stamped with the
+    emitting thread so tools/timeline.py renders concurrent events
+    (prefetch staging vs executor dispatch) on separate rows."""
     if not _on:
         return
     from ..observe import trace as _trace
 
-    reg = _registry()
-    reg.record_timing(name, seconds)
-    ts = ((start if start is not None else time.perf_counter() - seconds)
-          - _t0) * 1e6
-    # stamp the emitting thread so tools/timeline.py renders concurrent
-    # events (prefetch staging vs executor dispatch) on separate rows
-    tid = _trace.thread_tid()
-    with reg.lock:
-        _timeline.append({"name": name, "ts": ts, "dur": seconds * 1e6,
-                          "tid": tid})
+    _registry().record_timing(name, seconds)
+    t0 = start if start is not None else time.perf_counter() - seconds
+    _trace.emit_span(name, t0, t0 + seconds)
 
 
 def record_counter(name: str, inc: int = 1, value=None) -> None:
@@ -105,7 +102,6 @@ def reset_profiler():
     reg = _registry()
     with reg.lock:
         reg.clear(timings_only=True)
-        _timeline.clear()
 
 
 def start_profiler(state="All", trace_dir=None):
@@ -153,10 +149,13 @@ def stop_profiler(sorted_key=None, profile_path="/tmp/profile"):
             print(f"{n[:40]:<40} {c:>8} {tot * 1e3:>12.3f} "
                   f"{mn * 1e3:>10.3f} {mx * 1e3:>10.3f} {ave * 1e3:>10.3f}")
     if profile_path:
+        from ..observe import trace as _trace
         from ..observe.events import host_name
 
-        with reg.lock:
-            events = list(_timeline)
+        # the session's spans out of the ring, microseconds since its start
+        events = [{"name": r.name, "ts": (r.t0 - _t0) * 1e6,
+                   "dur": (r.t1 - r.t0) * 1e6, "tid": r.tid}
+                  for r in _trace.recorded() if r.t0 >= _t0]
         with open(profile_path, "w") as f:
             # "host" + "counters" feed tools/timeline.py's multi-host merge
             # (distinct pids) and its "ph":"C" counter tracks
@@ -180,8 +179,9 @@ def profiler(state="All", sorted_key=None, profile_path="/tmp/profile"):
 # ref: platform/device_tracer.h:49 — the reference correlates CUPTI device
 # records back to framework ops via correlation ids.  The XLA-native
 # equivalent: Executor.run_op wraps every op's trace in
-# jax.named_scope(op.type), so the compiler stamps each HLO instruction's
-# metadata op_name with "jit(..)/<op_type>/<primitive>"; the profiler's
+# jax.named_scope(op.type) and, beneath it, the op's fluid.name_scope path,
+# so the compiler stamps each HLO instruction's metadata op_name with
+# "jit(..)/<op_type>/~<path>/<primitive>"; the profiler's
 # xplane capture then carries per-HLO-instruction device durations, and
 # joining the two attributes measured device time to framework op types —
 # with the honest caveat that XLA FUSES across ops, so a fusion's time is
@@ -190,12 +190,17 @@ def profiler(state="All", sorted_key=None, profile_path="/tmp/profile"):
 
 
 def _parse_hlo_op_names(hlo_text: str):
-    """instruction name -> framework op type, from metadata op_name scopes.
+    """instruction name -> (framework op type, name-scope path), from the
+    metadata op_name scopes.
 
-    HLO: `%fusion.3 = ... metadata={op_name="jit(fn)/conv2d/conv_general..`
-    The first scope segment after the jit(...) prefix is the named_scope
-    the executor pushed, i.e. the fluid op type."""
+    HLO: `%fusion.3 = ... metadata={op_name="jit(fn)/conv2d/~stage1.block2/
+    conv_general..`.  The first scope segment after the jit(...) prefix is
+    the named_scope the executor pushed, i.e. the fluid op type; the
+    segment behind ``NAME_SCOPE_MARK`` is the op's ``fluid.name_scope``
+    path, ``""`` where it was built under none."""
     import re
+
+    from .framework import NAME_SCOPE_MARK
 
     mapping = {}
     for m in re.finditer(
@@ -206,19 +211,26 @@ def _parse_hlo_op_names(hlo_text: str):
         if parts and parts[0].startswith("jit("):
             parts = parts[1:]
         if parts:
-            mapping[inst] = parts[0]
+            path = next((p[len(NAME_SCOPE_MARK):] for p in parts
+                         if p.startswith(NAME_SCOPE_MARK)), "")
+            mapping[inst] = (parts[0], path)
     return mapping
 
 
 def device_op_table(trace_dir=None, hlo_text=None, print_table=True):
-    """Aggregate per-HLO-op DEVICE time from the newest xplane capture.
+    """Aggregate per-HLO-op DEVICE time from the newest xplane capture,
+    read with ``jax.profiler.ProfileData`` (jax alone).
 
     Returns rows sorted by total time:
-      {"hlo_op", "calls", "total_us", "avg_us"[, "fluid_op"]}
+      {"hlo_op", "calls", "total_us", "avg_us"[, "fluid_op", "scope"]}
     ``trace_dir`` defaults to the last start_profiler/stop_profiler dir.
-    ``hlo_text`` (from ``lower_program_hlo``) adds the fluid_op column by
-    joining instruction names against HLO metadata op_name scopes."""
+    ``hlo_text`` (from ``lower_program_hlo``) adds the fluid_op and scope
+    columns by joining instruction names against HLO metadata op_name
+    scopes: the op type, and the ``fluid.name_scope`` path the op was
+    built under (the model's block)."""
     import glob
+
+    from jax.profiler import ProfileData
 
     d = trace_dir or _trace_dir
     if not d:
@@ -228,32 +240,24 @@ def device_op_table(trace_dir=None, hlo_text=None, print_table=True):
                            recursive=True), key=os.path.getmtime)
     if not pbs:
         raise IOError(f"no .xplane.pb under {d}")
-    try:
-        from tensorflow.tsl.profiler.protobuf import xplane_pb2
-    except ImportError as exc:  # pragma: no cover - env without tensorflow
-        raise ImportError(
-            "device_op_table needs the xplane proto (tensorflow.tsl); "
-            "open the trace in TensorBoard/XProf instead") from exc
 
-    xs = xplane_pb2.XSpace()
-    with open(pbs[-1], "rb") as f:
-        xs.ParseFromString(f.read())
     agg = {}
-    for plane in xs.planes:
-        smeta = {k: v.name for k, v in plane.stat_metadata.items()}
-        emeta = {k: v.name for k, v in plane.event_metadata.items()}
+    for plane in ProfileData.from_file(pbs[-1]).planes:
         for line in plane.lines:
             for ev in line.events:
-                stat_names = {smeta.get(s.metadata_id, "") for s in ev.stats}
+                stats = dict(ev.stats)
                 # device-executed HLO instructions carry an hlo_op stat;
                 # whole-module events (the "XLA Modules" line) carry only
                 # hlo_module and would double-count every op under them
-                if "hlo_op" not in stat_names:
+                if "hlo_op" not in stats:
                     continue
-                name = emeta.get(ev.metadata_id, "?")
+                # a TPU names the event by the instruction's whole text
+                name = ev.name.partition(" = ")[0].strip().lstrip("%")
+                ps = stats.get("device_duration_ps")
                 e = agg.setdefault(name, [0, 0.0])
                 e[0] += 1
-                e[1] += ev.duration_ps / 1e6  # ps -> us
+                e[1] += float(ps) / 1e6 if ps is not None \
+                    else ev.duration_ns / 1e3
     name_map = _parse_hlo_op_names(hlo_text) if hlo_text else {}
     rows = []
     for name, (calls, total) in sorted(agg.items(), key=lambda kv: -kv[1][1]):
@@ -261,18 +265,18 @@ def device_op_table(trace_dir=None, hlo_text=None, print_table=True):
                "total_us": round(total, 1),
                "avg_us": round(total / calls, 2)}
         if name_map:
-            row["fluid_op"] = name_map.get(name, "")
+            row["fluid_op"], row["scope"] = name_map.get(name, ("", ""))
         rows.append(row)
     if print_table and rows:
         cols = f"{'HLO op':<44} {'Calls':>6} {'Total(us)':>12} {'Avg(us)':>10}"
         if name_map:
-            cols += f" {'Fluid op':<18}"
+            cols += f" {'Fluid op':<18} {'Scope':<24}"
         print(cols)
         for r in rows:
             line_ = (f"{r['hlo_op'][:44]:<44} {r['calls']:>6} "
                      f"{r['total_us']:>12.1f} {r['avg_us']:>10.2f}")
             if name_map:
-                line_ += f" {r.get('fluid_op', ''):<18}"
+                line_ += f" {r['fluid_op']:<18} {r['scope']:<24}"
             print(line_)
     return rows
 

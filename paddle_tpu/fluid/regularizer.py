@@ -2,7 +2,7 @@
 
 from __future__ import annotations
 
-from .framework import OpRole
+from .framework import OpRole, param_name_scope
 
 __all__ = ["append_regularization_ops", "L1Decay", "L2Decay",
            "L1DecayRegularizer", "L2DecayRegularizer"]
@@ -63,7 +63,9 @@ def append_regularization_ops(parameters_and_grads, regularization=None):
         if grad is None:
             params_and_grads.append((param, grad))
             continue
-        new_grad = _create_regularization_of_grad(param, grad, regularization)
+        with param_name_scope(param):
+            new_grad = _create_regularization_of_grad(param, grad,
+                                                      regularization)
         params_and_grads.append((param, new_grad))
     return params_and_grads
 

@@ -459,17 +459,12 @@ class Dispatch:
 
     def call(self, fn, feed_dev, const_state, mut_state):
         """``(fetches, new_state, health)`` of the built step.  A per-step
-        unguarded step takes no sentinel; every other one does."""
-        from . import profiler as _prof
-
+        unguarded step takes no sentinel; every other one does.  Nothing
+        here waits on the device, profiling or not."""
         args = (feed_dev, const_state, mut_state)
         if self.guard is not None or self.n_steps is not None:
             args += (self.sentinel,)
         fetches, new_state, *health = fn(*args)
-        if _prof.is_profiling() and self.guard is None:
-            # fluid.profiler's timeline wants the device time; no span,
-            # sink or PADDLE_TRACE setting ever waits here
-            jax.block_until_ready((fetches, new_state))
         return fetches, new_state, (health[0] if health else None)
 
     def report(self, fetches, new_state, health, t0, call, fresh=False,

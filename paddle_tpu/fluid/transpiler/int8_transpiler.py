@@ -24,6 +24,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..framework import NAME_SCOPE_ATTR, name_scope_at
+
 # op type -> (weight input slot, per-output-channel axis of the weight)
 _QUANT_TARGETS = {
     "mul": ("Y", 1),        # [in, out]
@@ -98,12 +100,14 @@ class Int8WeightTranspiler:
                 if b is block:
                     firsts[wname] = min(firsts.get(wname, i), i)
             for wname, i in sorted(firsts.items(), key=lambda t: -t[1]):
-                block._insert_op(
-                    i, type="dequantize_weight",
-                    inputs={"X": [wname + "@INT8"],
-                            "Scale": [wname + "@SCALE"]},
-                    outputs={"Out": [wname + "@DEQ"]},
-                    attrs={"quant_axis": axes[wname]})
+                # under the name scope of the consumer it is made for
+                with name_scope_at(block.ops[i].attr(NAME_SCOPE_ATTR, "")):
+                    block._insert_op(
+                        i, type="dequantize_weight",
+                        inputs={"X": [wname + "@INT8"],
+                                "Scale": [wname + "@SCALE"]},
+                        outputs={"Out": [wname + "@DEQ"]},
+                        attrs={"quant_axis": axes[wname]})
             if firsts:
                 self._patch_owner_ops(program, block, list(firsts))
         return list(weights)

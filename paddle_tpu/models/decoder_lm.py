@@ -234,9 +234,10 @@ def _feed_forward(x, cfg, width, p):
     return _proj(h, cfg.hidden_size, f"{p}_w2")
 
 
-def _experts(x, cfg, p, routers):
-    """The routed layer's share [beside the shared expert]; a router with a
-    selection bias adds its (bias, counts) to ``routers``."""
+def _experts(x, cfg, i, routers):
+    """Layer ``i``'s routed share [beside the shared expert]; a router with
+    a selection bias adds its (layer, bias, counts) to ``routers``."""
+    p = f"l{i}"
     shared = _feed_forward(x, cfg, cfg.shared_width, f"{p}_shared") \
         if cfg.shared_width else None
     out = layers.moe_experts(
@@ -247,44 +248,56 @@ def _experts(x, cfg, p, routers):
         norm_eps=cfg.route_norm_eps, route_scale=cfg.route_scale)
     if cfg.route_bias_coeff:
         out, bias, counts = out
-        routers.append((bias, counts))
+        routers.append((i, bias, counts))
     return out if shared is None else layers.elementwise_add(shared, out)
 
 
 def _forward(cfg, seq_len):
+    """Named for the device trace (``fluid.name_scope``): ``embed``,
+    ``layer<i>.mixer`` (the attention of either kind with its indexer, or
+    the short convolution, with projections, norms, gate and the residual
+    add), ``layer<i>.ffn`` (dense or shared feed-forward, router and
+    routed experts, likewise) and ``head`` (final norm, product, loss)."""
     tokens = layers.data(name="tokens", shape=[seq_len], dtype="int64")
     labels = layers.data(name="labels", shape=[seq_len, 1], dtype="int64")
-    h = layers.embedding(tokens, size=[cfg.vocab_size, cfg.hidden_size],
-                         param_attr=_attr("tok_emb"))
-    if cfg.embed_scale != 1.0:
-        h = layers.scale(h, scale=float(cfg.embed_scale))
+    with fluid.name_scope("embed"):
+        h = layers.embedding(tokens, size=[cfg.vocab_size, cfg.hidden_size],
+                             param_attr=_attr("tok_emb"))
+        if cfg.embed_scale != 1.0:
+            h = layers.scale(h, scale=float(cfg.embed_scale))
     routers = []
     for i in range(cfg.num_layers):
         p = f"l{i}"
-        if cfg.layer_mixer(i) == "conv":
-            y = _short_conv(_norm(h, cfg, f"{p}_conv_norm"), cfg, p)
+        with fluid.name_scope(f"layer{i}.mixer"):
+            if cfg.layer_mixer(i) == "conv":
+                y = _short_conv(_norm(h, cfg, f"{p}_conv_norm"), cfg, p)
+            else:
+                y = _attention(_norm(h, cfg, f"{p}_attn_norm"), cfg,
+                               seq_len, p, cfg.layer_window(i))
+            if cfg.post_norms:
+                y = _norm(y, cfg, f"{p}_post_attn_norm")
+            h = layers.elementwise_add(h, y)
+        with fluid.name_scope(f"layer{i}.ffn"):
+            if cfg.layer_is_dense(i):
+                f = _feed_forward(_norm(h, cfg, f"{p}_mlp_norm"), cfg,
+                                  cfg.dense_width, f"{p}_mlp")
+            else:
+                f = _experts(_norm(h, cfg, f"{p}_moe_norm"), cfg, i,
+                             routers)
+            if cfg.post_norms:
+                f = _norm(f, cfg, f"{p}_post_mlp_norm")
+            h = layers.elementwise_add(h, f)
+    with fluid.name_scope("head"):
+        h = _norm(h, cfg, "final_norm")
+        if cfg.tie_head:
+            logits = layers.matmul(
+                h,
+                fluid.default_main_program().global_block().var("tok_emb"),
+                transpose_y=True)
         else:
-            y = _attention(_norm(h, cfg, f"{p}_attn_norm"), cfg, seq_len, p,
-                           cfg.layer_window(i))
-        if cfg.post_norms:
-            y = _norm(y, cfg, f"{p}_post_attn_norm")
-        h = layers.elementwise_add(h, y)
-        if cfg.layer_is_dense(i):
-            f = _feed_forward(_norm(h, cfg, f"{p}_mlp_norm"), cfg,
-                              cfg.dense_width, f"{p}_mlp")
-        else:
-            f = _experts(_norm(h, cfg, f"{p}_moe_norm"), cfg, p, routers)
-        if cfg.post_norms:
-            f = _norm(f, cfg, f"{p}_post_mlp_norm")
-        h = layers.elementwise_add(h, f)
-    h = _norm(h, cfg, "final_norm")
-    if cfg.tie_head:
-        logits = layers.matmul(
-            h, fluid.default_main_program().global_block().var("tok_emb"),
-            transpose_y=True)
-    else:
-        logits = _proj(h, cfg.vocab_size, "lm_head_w")
-    loss = layers.mean(layers.softmax_with_cross_entropy(logits, labels))
+            logits = _proj(h, cfg.vocab_size, "lm_head_w")
+        loss = layers.mean(
+            layers.softmax_with_cross_entropy(logits, labels))
     return tokens, labels, loss, logits, routers
 
 
@@ -304,6 +317,7 @@ def build(cfg=None, seq_len=64, lr=1e-4, beta1=0.9, beta2=0.95,
     tokens, labels, loss, _, routers = _forward(cfg, seq_len)
     fluid.optimizer.Adam(learning_rate=lr, beta1=beta1, beta2=beta2,
                          epsilon=epsilon).minimize(loss)
-    for bias, counts in routers:
-        layers.moe_bias_update(bias, counts, cfg.route_bias_coeff)
+    for i, bias, counts in routers:
+        with fluid.name_scope(f"layer{i}.ffn"):
+            layers.moe_bias_update(bias, counts, cfg.route_bias_coeff)
     return tokens, labels, loss

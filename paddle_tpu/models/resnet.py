@@ -38,10 +38,13 @@ def bottleneck(input, ch_out, stride):
     return fluid.layers.elementwise_add(x=short, y=conv3, act="relu")
 
 
-def _layer_warp(block_func, input, ch_out, count, stride):
-    res_out = block_func(input, ch_out, stride)
-    for _ in range(1, count):
-        res_out = block_func(res_out, ch_out, 1)
+def _layer_warp(block_func, input, ch_out, count, stride, stage):
+    """One stage: ``count`` blocks, named ``stage<k>.block<j>`` (both from
+    1) for the device trace (``fluid.name_scope``)."""
+    res_out = input
+    for j in range(count):
+        with fluid.name_scope(f"stage{stage}.block{j + 1}"):
+            res_out = block_func(res_out, ch_out, stride if j == 0 else 1)
     return res_out
 
 
@@ -56,30 +59,37 @@ _DEPTH_CFG = {
 
 def resnet_imagenet(input, class_dim=1000, depth=50):
     block_func, layers_cfg = _DEPTH_CFG[depth]
-    conv1 = conv_bn_layer(input, ch_out=64, filter_size=7, stride=2, padding=3)
-    pool1 = fluid.layers.pool2d(input=conv1, pool_type="max", pool_size=3,
-                                pool_stride=2, pool_padding=1)
-    res1 = _layer_warp(block_func, pool1, 64, layers_cfg[0], 1)
-    res2 = _layer_warp(block_func, res1, 128, layers_cfg[1], 2)
-    res3 = _layer_warp(block_func, res2, 256, layers_cfg[2], 2)
-    res4 = _layer_warp(block_func, res3, 512, layers_cfg[3], 2)
-    pool2 = fluid.layers.pool2d(input=res4, pool_size=7, pool_type="avg",
-                                global_pooling=True)
-    out = fluid.layers.fc(input=pool2, size=class_dim, act="softmax")
+    with fluid.name_scope("stem"):
+        conv1 = conv_bn_layer(input, ch_out=64, filter_size=7, stride=2,
+                              padding=3)
+        pool1 = fluid.layers.pool2d(input=conv1, pool_type="max",
+                                    pool_size=3, pool_stride=2,
+                                    pool_padding=1)
+    res = pool1
+    for k, (ch_out, stride) in enumerate(
+            [(64, 1), (128, 2), (256, 2), (512, 2)]):
+        res = _layer_warp(block_func, res, ch_out, layers_cfg[k], stride,
+                          stage=k + 1)
+    with fluid.name_scope("head"):
+        pool2 = fluid.layers.pool2d(input=res, pool_size=7, pool_type="avg",
+                                    global_pooling=True)
+        out = fluid.layers.fc(input=pool2, size=class_dim, act="softmax")
     return out
 
 
 def resnet_cifar10(input, class_dim=10, depth=32):
     assert (depth - 2) % 6 == 0
     n = (depth - 2) // 6
-    conv1 = conv_bn_layer(input=input, ch_out=16, filter_size=3, stride=1,
-                          padding=1)
-    res1 = _layer_warp(basicblock, conv1, 16, n, 1)
-    res2 = _layer_warp(basicblock, res1, 32, n, 2)
-    res3 = _layer_warp(basicblock, res2, 64, n, 2)
-    pool = fluid.layers.pool2d(input=res3, pool_size=8, pool_type="avg",
-                               global_pooling=True)
-    out = fluid.layers.fc(input=pool, size=class_dim, act="softmax")
+    with fluid.name_scope("stem"):
+        conv1 = conv_bn_layer(input=input, ch_out=16, filter_size=3,
+                              stride=1, padding=1)
+    res1 = _layer_warp(basicblock, conv1, 16, n, 1, stage=1)
+    res2 = _layer_warp(basicblock, res1, 32, n, 2, stage=2)
+    res3 = _layer_warp(basicblock, res2, 64, n, 2, stage=3)
+    with fluid.name_scope("head"):
+        pool = fluid.layers.pool2d(input=res3, pool_size=8, pool_type="avg",
+                                   global_pooling=True)
+        out = fluid.layers.fc(input=pool, size=class_dim, act="softmax")
     return out
 
 
@@ -94,9 +104,10 @@ def build(batch_size=None, class_dim=1000, depth=50, image_shape=(3, 224, 224),
         prediction = resnet_cifar10(img, class_dim, depth=32)
     else:
         prediction = resnet_imagenet(img, class_dim, depth=depth)
-    loss = fluid.layers.mean(
-        fluid.layers.cross_entropy(input=prediction, label=label))
-    acc = fluid.layers.accuracy(input=prediction, label=label)
+    with fluid.name_scope("head"):
+        loss = fluid.layers.mean(
+            fluid.layers.cross_entropy(input=prediction, label=label))
+        acc = fluid.layers.accuracy(input=prediction, label=label)
     if with_momentum:
         opt = fluid.optimizer.Momentum(learning_rate=lr, momentum=0.9)
     else:

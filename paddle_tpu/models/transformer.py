@@ -226,55 +226,76 @@ def moe_config():
 
 
 def encoder(src_word, cfg, src_len, aux_losses=None):
-    enc = _embed(src_word, cfg.src_vocab_size, src_len, cfg, "src")
-    src_bias = _padding_bias(src_word, src_len)
+    """Named for the device trace (``fluid.name_scope``): ``embed``, then
+    ``encoder.layer<i>.attention`` / ``.ffn``, each with its residual add
+    and norm."""
+    with fluid.name_scope("embed"):
+        enc = _embed(src_word, cfg.src_vocab_size, src_len, cfg, "src")
+        src_bias = _padding_bias(src_word, src_len)
     if cfg.stacked:
-        enc = layers.transformer_encoder_stack(
-            enc, bias=src_bias, n_layer=cfg.n_layer, n_head=cfg.n_head,
-            d_inner=cfg.d_inner, dropout=cfg.dropout,
-            n_microbatches=cfg.n_microbatches,
-            recompute=getattr(cfg, "recompute", False),
-            flash=getattr(cfg, "flash_attention", None))
+        with fluid.name_scope("encoder"):
+            enc = layers.transformer_encoder_stack(
+                enc, bias=src_bias, n_layer=cfg.n_layer, n_head=cfg.n_head,
+                d_inner=cfg.d_inner, dropout=cfg.dropout,
+                n_microbatches=cfg.n_microbatches,
+                recompute=getattr(cfg, "recompute", False),
+                flash=getattr(cfg, "flash_attention", None))
         return enc, src_bias
     for i in range(cfg.n_layer):
-        attn = _multi_head_attention(
-            enc, enc, enc, src_bias, cfg.d_model, cfg.n_head, cfg.dropout,
-            prefix=f"enc{i}_self", use_ring=cfg.ring_attention,
-            flash=getattr(cfg, "flash_attention", None))
-        enc = _postprocess(enc, attn, cfg.dropout)
-        ff = _ffn(enc, cfg.d_inner, cfg.d_model, prefix=f"enc{i}",
-                  cfg=cfg, aux_losses=aux_losses)
-        enc = _postprocess(enc, ff, cfg.dropout)
+        with fluid.name_scope(f"encoder.layer{i}.attention"):
+            attn = _multi_head_attention(
+                enc, enc, enc, src_bias, cfg.d_model, cfg.n_head,
+                cfg.dropout, prefix=f"enc{i}_self",
+                use_ring=cfg.ring_attention,
+                flash=getattr(cfg, "flash_attention", None))
+            enc = _postprocess(enc, attn, cfg.dropout)
+        with fluid.name_scope(f"encoder.layer{i}.ffn"):
+            ff = _ffn(enc, cfg.d_inner, cfg.d_model, prefix=f"enc{i}",
+                      cfg=cfg, aux_losses=aux_losses)
+            enc = _postprocess(enc, ff, cfg.dropout)
     return enc, src_bias
 
 
-def decoder(tgt_word, enc_out, src_bias, cfg, tgt_len, aux_losses=None):
-    dec = _embed(tgt_word, cfg.tgt_vocab_size, tgt_len, cfg, "tgt")
-    if cfg.stacked:
-        dec = layers.transformer_decoder_stack(
-            dec, enc_out, src_bias=src_bias, n_layer=cfg.n_layer,
-            n_head=cfg.n_head, d_inner=cfg.d_inner, dropout=cfg.dropout,
-            n_microbatches=cfg.n_microbatches,
-            recompute=getattr(cfg, "recompute", False),
-            flash=getattr(cfg, "flash_attention", None))
+def _head(dec, cfg):
+    with fluid.name_scope("head"):
         return layers.fc(dec, cfg.tgt_vocab_size, num_flatten_dims=2,
                          param_attr=ParamAttr(name="out_proj_w"))
+
+
+def decoder(tgt_word, enc_out, src_bias, cfg, tgt_len, aux_losses=None):
+    """``embed``, ``decoder.layer<i>.self_attention`` / ``.cross_attention``
+    / ``.ffn``, and the projection to the vocabulary under ``head``."""
+    with fluid.name_scope("embed"):
+        dec = _embed(tgt_word, cfg.tgt_vocab_size, tgt_len, cfg, "tgt")
+    if cfg.stacked:
+        with fluid.name_scope("decoder"):
+            dec = layers.transformer_decoder_stack(
+                dec, enc_out, src_bias=src_bias, n_layer=cfg.n_layer,
+                n_head=cfg.n_head, d_inner=cfg.d_inner, dropout=cfg.dropout,
+                n_microbatches=cfg.n_microbatches,
+                recompute=getattr(cfg, "recompute", False),
+                flash=getattr(cfg, "flash_attention", None))
+        return _head(dec, cfg)
     for i in range(cfg.n_layer):
-        self_attn = _multi_head_attention(
-            dec, dec, dec, None, cfg.d_model, cfg.n_head, cfg.dropout,
-            prefix=f"dec{i}_self", causal=True, use_ring=cfg.ring_attention,
-            flash=getattr(cfg, "flash_attention", None))
-        dec = _postprocess(dec, self_attn, cfg.dropout)
-        cross = _multi_head_attention(
-            dec, enc_out, enc_out, src_bias, cfg.d_model, cfg.n_head,
-            cfg.dropout, prefix=f"dec{i}_cross", use_ring=cfg.ring_attention,
-            flash=getattr(cfg, "flash_attention", None))
-        dec = _postprocess(dec, cross, cfg.dropout)
-        ff = _ffn(dec, cfg.d_inner, cfg.d_model, prefix=f"dec{i}",
-                  cfg=cfg, aux_losses=aux_losses)
-        dec = _postprocess(dec, ff, cfg.dropout)
-    return layers.fc(dec, cfg.tgt_vocab_size, num_flatten_dims=2,
-                     param_attr=ParamAttr(name="out_proj_w"))
+        with fluid.name_scope(f"decoder.layer{i}.self_attention"):
+            self_attn = _multi_head_attention(
+                dec, dec, dec, None, cfg.d_model, cfg.n_head, cfg.dropout,
+                prefix=f"dec{i}_self", causal=True,
+                use_ring=cfg.ring_attention,
+                flash=getattr(cfg, "flash_attention", None))
+            dec = _postprocess(dec, self_attn, cfg.dropout)
+        with fluid.name_scope(f"decoder.layer{i}.cross_attention"):
+            cross = _multi_head_attention(
+                dec, enc_out, enc_out, src_bias, cfg.d_model, cfg.n_head,
+                cfg.dropout, prefix=f"dec{i}_cross",
+                use_ring=cfg.ring_attention,
+                flash=getattr(cfg, "flash_attention", None))
+            dec = _postprocess(dec, cross, cfg.dropout)
+        with fluid.name_scope(f"decoder.layer{i}.ffn"):
+            ff = _ffn(dec, cfg.d_inner, cfg.d_model, prefix=f"dec{i}",
+                      cfg=cfg, aux_losses=aux_losses)
+            dec = _postprocess(dec, ff, cfg.dropout)
+    return _head(dec, cfg)
 
 
 def forward(cfg, src_len, tgt_len):
@@ -288,26 +309,27 @@ def forward(cfg, src_len, tgt_len):
     enc_out, src_bias = encoder(src_word, cfg, src_len, aux_losses)
     logits = decoder(tgt_word, enc_out, src_bias, cfg, tgt_len, aux_losses)
 
-    if cfg.label_smooth:
-        hot = layers.one_hot(lbl_word, cfg.tgt_vocab_size)
-        smooth = layers.label_smooth(hot, epsilon=cfg.label_smooth)
-        cost = layers.softmax_with_cross_entropy(logits, smooth,
-                                                 soft_label=True)
-    else:
-        cost = layers.softmax_with_cross_entropy(logits, lbl_word)
-    # mask loss at pad targets so padding doesn't dilute the objective
-    zeros = layers.fill_constant_batch_size_like(
-        lbl_word, shape=[-1, tgt_len, 1], dtype="int64", value=0)
-    non_pad = layers.cast(
-        layers.logical_not(layers.equal(lbl_word, zeros)), "float32")
-    cost = layers.elementwise_mul(cost, non_pad)
-    avg_cost = layers.elementwise_div(
-        layers.reduce_sum(cost),
-        layers.elementwise_add(layers.reduce_sum(non_pad),
-                               layers.fill_constant([1], "float32", 1e-8)))
-    for aux in aux_losses:  # Switch load-balancing losses (MoE configs)
-        avg_cost = layers.elementwise_add(
-            avg_cost, layers.scale(aux, scale=cfg.moe_aux_weight))
+    with fluid.name_scope("head"):
+        if cfg.label_smooth:
+            hot = layers.one_hot(lbl_word, cfg.tgt_vocab_size)
+            smooth = layers.label_smooth(hot, epsilon=cfg.label_smooth)
+            cost = layers.softmax_with_cross_entropy(logits, smooth,
+                                                     soft_label=True)
+        else:
+            cost = layers.softmax_with_cross_entropy(logits, lbl_word)
+        # mask loss at pad targets so padding doesn't dilute the objective
+        zeros = layers.fill_constant_batch_size_like(
+            lbl_word, shape=[-1, tgt_len, 1], dtype="int64", value=0)
+        non_pad = layers.cast(
+            layers.logical_not(layers.equal(lbl_word, zeros)), "float32")
+        cost = layers.elementwise_mul(cost, non_pad)
+        avg_cost = layers.elementwise_div(
+            layers.reduce_sum(cost),
+            layers.elementwise_add(layers.reduce_sum(non_pad),
+                                   layers.fill_constant([1], "float32", 1e-8)))
+        for aux in aux_losses:  # Switch load-balancing losses (MoE configs)
+            avg_cost = layers.elementwise_add(
+                avg_cost, layers.scale(aux, scale=cfg.moe_aux_weight))
     return src_word, tgt_word, lbl_word, avg_cost, logits
 
 
@@ -785,8 +807,9 @@ def build(cfg=None, src_len=64, tgt_len=64, lr=1e-3, warmup_steps=None):
     cfg = cfg or tiny_config()
     src_word, tgt_word, lbl_word, avg_cost, _ = forward(cfg, src_len, tgt_len)
     if warmup_steps:
-        lr_sched = layers.learning_rate_scheduler.noam_decay(
-            cfg.d_model, warmup_steps)
+        with fluid.name_scope("optimizer"):
+            lr_sched = layers.learning_rate_scheduler.noam_decay(
+                cfg.d_model, warmup_steps)
         opt = fluid.optimizer.Adam(learning_rate=lr_sched,
                                    beta1=0.9, beta2=0.98, epsilon=1e-9)
     else:

@@ -16,15 +16,17 @@ from paddle_tpu.fluid import profiler
 def _build_mlp():
     img = fluid.layers.data(name="img", shape=[32], dtype="float32")
     label = fluid.layers.data(name="label", shape=[1], dtype="int64")
-    h = fluid.layers.fc(input=img, size=64, act="relu")
-    pred = fluid.layers.fc(input=h, size=10, act="softmax")
-    loss = fluid.layers.mean(
-        fluid.layers.cross_entropy(input=pred, label=label))
+    with fluid.name_scope("body"):
+        h = fluid.layers.fc(input=img, size=64, act="relu")
+    with fluid.name_scope("head"):
+        pred = fluid.layers.fc(input=h, size=10, act="softmax")
+        loss = fluid.layers.mean(
+            fluid.layers.cross_entropy(input=pred, label=label))
     fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
     return loss
 
 
-def test_hlo_carries_op_scopes_and_device_table(tmp_path):
+def test_hlo_carries_op_scopes_and_device_table(tmp_path, capsys):
     loss = _build_mlp()
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(fluid.default_startup_program())
@@ -38,7 +40,8 @@ def test_hlo_carries_op_scopes_and_device_table(tmp_path):
     assert 'op_name="' in hlo
     scope_map = profiler._parse_hlo_op_names(hlo)
     assert scope_map, "no op_name metadata parsed from compiled HLO"
-    labeled = set(scope_map.values())
+    labeled = {op_type for op_type, _ in scope_map.values()}
+    assert {path for _, path in scope_map.values()} >= {"body", "head"}
     if not any(t in labeled for t in ("mul", "softmax", "cross_entropy",
                                       "relu", "elementwise_add", "sgd",
                                       "mean", "reduce_mean")):
@@ -55,13 +58,16 @@ def test_hlo_carries_op_scopes_and_device_table(tmp_path):
         exe.run(fluid.default_main_program(), feed=feed, fetch_list=[loss])
     profiler.stop_profiler(profile_path=str(tmp_path / "events.json"))
 
-    try:
-        rows = profiler.device_op_table(trace_dir, hlo_text=hlo,
-                                        print_table=False)
-    except ImportError:
-        pytest.skip("xplane proto unavailable")
+    # read with jax.profiler.ProfileData: no tensorflow.tsl, no skip
+    rows = profiler.device_op_table(trace_dir, hlo_text=hlo,
+                                    print_table=True)
     assert rows, "no device HLO events captured"
     assert sum(r["total_us"] for r in rows) > 0
-    # at least part of the measured device time attributes to fluid ops
+    # at least part of the measured device time attributes to fluid ops,
+    # and beneath them to the blocks the model named
     attributed = [r for r in rows if r.get("fluid_op")]
     assert attributed, rows[:5]
+    assert {r["scope"] for r in attributed} >= {"body", "head"}, rows[:5]
+    said = capsys.readouterr().out.splitlines()
+    assert any(l.split()[-2:] == ["op", "Scope"] for l in said)
+    assert any(l.split()[-2:] == ["mul", "body"] for l in said)
