@@ -13,9 +13,12 @@ not the softmax one, ``score``; ``...declined{why}`` for every fallback;
 ``ops.moe.bias_updates`` for every ``moe_bias_update`` lowered;
 ``ops.short_conv.calls{channels,taps,path}`` for every ``short_conv``
 lowered, its backward not counted;
-``ops.moe.row_moves{pass="backward",how="gather"}``, which
-``parallel/moe.py`` counts through ``_count`` where the backward of a row
-move is traced: two for every ``moe_experts_grad`` lowered).
+``ops.moe.row_moves{pass,how="gather"}``, which ``parallel/moe.py`` counts
+through ``_count`` for every ``[N * top_k, D]`` row gather it traces: two
+``pass="forward"`` for every trace of the layer's forward, of which
+``moe_experts`` makes one and ``moe_experts_grad`` another that only its
+routing plan outlives, and three ``pass="backward"`` for every
+``moe_experts_grad`` lowered).
 """
 
 from __future__ import annotations

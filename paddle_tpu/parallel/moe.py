@@ -178,21 +178,24 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
 
     The routing plan (the router's weights and choices, the sort by expert
     and its inverse, the group sizes) is made once and kept for the
-    backward; the rows themselves (``N * top_k`` sorted rows, the hidden
-    products) are made again there, not kept.  Rows move by gathers in both
-    passes: the backward takes the cotangent rows through the inverse
-    permutation the forward already has (``_take_rows``) and scatters
-    nothing, so what a row costs is the products' and the gathers' time.
+    backward, with the layer's inputs and nothing else.  The backward is
+    written by hand (``_share``): it makes the sorted rows and the two
+    hidden products again (a gather, two products) and NOT the last
+    product, whose one reader there was the gate's cotangent (that is
+    ``<dy @ w2^T, h>`` over a row, and the backward has both).  Rows move
+    by gathers in both passes and nothing is scattered: two ``[N * top_k,
+    D]`` gathers forward, three backward; the combine's cotangent goes out
+    to the sorted rows from ``[N, D]`` and meets the gate on the hidden
+    side, never as ``[N, top_k, D]``.  A layer and step: 8 products and 3
+    weights' gradients.
     """
-    from ..fluid import amp
-
     shape = x.shape
     e = w1.shape[0]
     xt = x.reshape((-1, shape[-1]))
     n = xt.shape[0]
 
-    # the routing plan, made ONCE, outside the checkpoint below, which
-    # takes it as arguments: the backward sorts and counts nothing again
+    # the routing plan, made ONCE, outside ``_share``, which takes it as
+    # arguments and keeps it: the backward sorts and counts nothing again
     vals, idx = route_top_k(xt, router_w, top_k, norm_topk, score, bias,
                             norm_eps, scale)
     local = idx - jnp.int32(expert_offset)
@@ -210,7 +213,7 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
     first = jnp.cumsum(counts, dtype=i32) - counts
     # ``back`` (assignment -> sorted row) and ``order`` (sorted row ->
     # assignment) are each other's inverse: either is a gather's index and
-    # the other the index of that gather's transpose (``_take_rows``)
+    # the other the index of that gather's transpose
     back = jnp.sum(chose * (first[None, :] - 1
                             + jnp.cumsum(chose, axis=0, dtype=i32)),
                    axis=1, dtype=i32)
@@ -219,71 +222,161 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
     # DEBT (ROADMAP S11, PERF.md section 7): the absent experts'
     # assignments ride along as zero rows at the end of the LAST held
     # group, so all N * top_k rows are gathered, multiplied and gathered
-    # back, forward and backward: 8 of every 9 where an eighth of the
-    # experts is held.  What that costs is the products' and the gathers'
-    # time over rows (no scatter is left); what dropping it can buy in the
-    # resident cells is what PR 30 read, 414.3 -> 394.6-405.6 ms a step
-    # with the cell's spread lost (a product call took 2.0 ms at 8,192 and
-    # at 65,536 live rows).  Without this line (sizes = counts[:e]) the
-    # products skip the row tiles past the last group and a step's time
-    # follows the router, which drifts toward the experts held as it
-    # trains without the absent ones; nothing else depends on it.
+    # back, forward and backward: 7 of every 8 where an eighth of the
+    # experts is held and the router is even (Keye's 16 of 128).  What
+    # that costs is the products' and the gathers' time over rows (no
+    # scatter is left): 8 products, 3 weights' gradients and 5 row gathers
+    # a layer and step.  What dropping it can buy in the resident cells is
+    # what PR 30 read with XLA's ``ragged_dot`` (a call of which took 2.0
+    # ms at 8,192 and at 65,536 live rows; the Pallas kernels that stand
+    # since PR 37 take 1.2-1.5 ms over all rows and have not been read
+    # with fewer): 414.3 -> 394.6-405.6 ms a step with the cell's spread
+    # lost.  Without this line (sizes = counts[:e]) the products skip the
+    # row tiles past the last group and a step's time follows the router,
+    # which drifts toward the experts held as it trains without the absent
+    # ones; nothing else depends on it.
     sizes = counts[:e].at[e - 1].add(counts[e])
     gate = jnp.where(held, vals, 0.0)
     # part of the plan where the products are the Pallas kernels': the
     # tables that tell their grid steps row tiles and groups, for all
-    # twelve calls of the layer
+    # eleven calls of the layer
     tables = None
     if product_path(x, w1, w2, top_k) == "pallas":
         from ..ops import pallas_grouped
 
         tables = pallas_grouped.plan(sizes, n * top_k)
 
-    # a checkpoint: the backward makes the sorted rows and the experts'
-    # hidden activations again instead of keeping N * top_k rows of them
-    # per layer (1 GB a layer at 8,192 tokens x 8 choices).  The plan is
-    # kept, not made again: [N, k] and [N*k] integers and the gate
-    @jax.checkpoint
-    def share(xt, gate, w1, w3, w2, held, sizes, order, back, live,
-              tables):
-        # XLA's grouped product on the TPU leaves the rows outside every
-        # group UNWRITTEN, in its results and in the cotangents it hands
-        # back (NaN gradients on the chip; the CPU zero-fills them).  So
-        # what a product returns for a row that holds no held assignment
-        # is SELECTED away before anything reads it, never multiplied by
-        # zero, and the cotangent the same on its way back: the two hidden
-        # products by ``live`` (``where``'s own vjp selects their
-        # cotangents), the last product where its rows are gathered, by
-        # ``held``, and the cotangent of xs where ITS rows are gathered
-        # back, by ``held`` too (``_take_rows``'s ``keep``).  Each select
-        # sits in a fusion that reads the rows anyway; xs itself needs
-        # none: a row without a held assignment is some token's row, read
-        # by products whose results are selected away and met by exact
-        # zeros in the weights' gradients.  Right whatever ``sizes`` covers.
-        def live_rows(rows):
-            return jnp.where(live, rows, 0)
-
-        # the tokens' rows are cast where they are taken, so that their
-        # cotangent comes back through the gather in AMP's type too
-        low, a1, a3, a2, _ = amp.cast_operands(xt, w1, w3, w2)
-        xs = _take_rows(xt, order, back, held.reshape(-1), top_k, low.dtype)
-        h = jax.nn.silu(live_rows(grouped_product(xs, a1, sizes, tables))
-                        .astype(jnp.float32)) \
-            * live_rows(grouped_product(xs, a3, sizes, tables)
-                        ).astype(jnp.float32)
-        ys = grouped_product(h.astype(xs.dtype), a2, sizes,
-                             tables)  # [N*k, D]
-        # back to assignment order, weighted, summed over a token's choices
-        ys = jnp.where(held[..., None],
-                       _take_rows(ys, back, order, None, 1, ys.dtype)
-                       .reshape(n, top_k, -1), 0)
-        return jnp.einsum("nk,nkd->nd", gate, ys.astype(jnp.float32))
-
-    y = share(xt, gate, w1, w3, w2, held, sizes, order, back, live, tables)
+    y = _share(top_k, xt, gate, w1, w3, w2,
+               (held, sizes, order, back, live, tables))
     y = y.astype(x.dtype).reshape(shape)
     if with_counts:
         return y, assignment_counts(idx, router_w.shape[-1])
     return y
+
+
+# XLA's grouped product on the TPU leaves the rows outside every group
+# UNWRITTEN, in its results and in the cotangents it hands back (NaN
+# gradients on the chip; the CPU zero-fills them).  So what a product
+# returns for a row that holds no held assignment is SELECTED away before
+# anything reads it, never multiplied by zero, in both passes of ``_share``:
+# the two hidden products and the cotangent of the hidden rows by ``live``,
+# the last product and the cotangent of the sorted rows where their rows are
+# gathered back, by ``held``.  Each select sits in a fusion that reads the
+# rows anyway.  The sorted rows themselves need none: a row without a held
+# assignment is some token's row, read by products whose results are
+# selected away and met by exact zeros in the weights' gradients.  Right
+# whatever ``sizes`` covers.
+def _sorted_and_hidden(top_k, xt, w1, w3, w2, plan, which):
+    """What both passes of ``_share`` make first: the tokens' rows sorted by
+    expert, ``xs`` [rows, D] in AMP's type; the two hidden products of
+    them, float32 [rows, F], selected by ``live`` (``_gated`` of the two is
+    the experts' hidden rows); and the three weights in AMP's type."""
+    from ..fluid import amp
+
+    _, sizes, order, _, live, tables = plan
+    low, *weights, _ = amp.cast_operands(xt, w1, w3, w2)
+    xs = _rows_out(low, order, top_k, which)
+    a, b = (jnp.where(live, grouped_product(xs, w, sizes, tables), 0)
+            .astype(jnp.float32) for w in weights[:2])
+    return xs, a, b, weights
+
+
+def _gated(a, b):
+    return jax.nn.silu(a) * b
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _share(top_k, xt, gate, w1, w3, w2, plan):
+    """[N, D] float32: the held experts' rows of ``xt`` [N, D], weighted by
+    ``gate`` [N, top_k] (0 where an assignment is absent) and summed over a
+    token's choices; ``plan``: what ``routed_experts`` made of the router's
+    choices.  Its backward is its own (``_share_bwd``)."""
+    held, sizes, _, back, _, tables = plan
+    xs, a, b, (_, _, a2) = _sorted_and_hidden(top_k, xt, w1, w3, w2, plan,
+                                              "forward")
+    ys = grouped_product(_gated(a, b).astype(xs.dtype), a2, sizes,
+                         tables)  # [N*k, D]
+    # back to assignment order, weighted, summed over a token's choices
+    return jnp.einsum("nk,nkd->nd", gate, _rows_home(
+        ys, back, held, "forward").astype(jnp.float32))
+
+
+def _share_fwd(top_k, xt, gate, w1, w3, w2, plan):
+    # kept: the plan and the layer's inputs, no [N * top_k, .] array (1 GB
+    # a layer at 8,192 tokens x 8 choices)
+    return (_share(top_k, xt, gate, w1, w3, w2, plan),
+            (xt, gate, w1, w3, w2, plan))
+
+
+def _share_bwd(top_k, kept, dy):
+    # behind a barrier, as ``jax.checkpoint`` puts one: without it XLA
+    # finds the sorted rows and the hidden products below to be the
+    # forward's own and keeps those from the forward instead.  With the
+    # cotangent in it, or XLA makes them as soon as the layer's inputs are
+    # there, before the loss, and they lie across the step's fullest point
+    (xt, gate, w1, w3, w2, plan), dy = lax.optimization_barrier((kept, dy))
+    held, sizes, order, back, live, tables = plan
+    xs, a, b, (a1, a3, a2) = _sorted_and_hidden(top_k, xt, w1, w3, w2, plan,
+                                                "backward")
+    low = xs.dtype
+    h, silu_bwd = jax.vjp(_gated, a, b)
+    # the combine's cotangent goes out to the sorted rows from [N, D], as
+    # the tokens' rows do, and meets the gate on the hidden side
+    dy_s = _rows_out(dy.astype(low), order, top_k, "backward")
+    gate_s = _permuted(gate.reshape(-1), order)[:, None]
+    hg = (gate_s * h).astype(low)
+    to_h, to_w2 = product_transposes(hg, a2, sizes, tables)
+    u = jnp.where(live, to_h(dy_s), 0).astype(jnp.float32)
+    # <dy, ys> over a row is <dy @ w2^T, h> over the same row
+    dgate_s = jnp.sum(u * h, axis=1)
+    da, db = (d.astype(low) for d in silu_bwd(gate_s * u))
+    to_xs1, to_w1 = product_transposes(xs, a1, sizes, tables)
+    to_xs3, to_w3 = product_transposes(xs, a3, sizes, tables)
+    dxt = jnp.sum(_rows_home(to_xs1(da) + to_xs3(db), back, held,
+                             "backward"),
+                  axis=1, dtype=jnp.promote_types(xt.dtype, jnp.float32))
+    dgate = jnp.where(held, _permuted(dgate_s, back).reshape(held.shape), 0)
+    return (dxt.astype(xt.dtype), dgate.astype(gate.dtype),
+            to_w1(xs, da).astype(w1.dtype), to_w3(xs, db).astype(w3.dtype),
+            to_w2(hg, dy_s).astype(w2.dtype), None)
+
+
+_share.defvjp(_share_fwd, _share_bwd)
+
+
+def _rows_out(rows, order, top_k: int, which: str):
+    """[N * top_k, D]: the tokens' ``rows`` [N, D] out to their ``top_k``
+    assignments in expert order (``order``: sorted row -> assignment).  Its
+    transpose is ``_rows_home`` through ``order``'s inverse, summed over a
+    token's choices, which autodiff would lower as a scatter-add of every
+    row (``unique_indices`` false, a row at a time on the TPU)."""
+    _count_row_move(which)
+    return _permuted(rows, order // top_k)
+
+
+def _rows_home(rows, back, held, which: str):
+    """[N, top_k, D]: the sorted ``rows`` [N * top_k, D] back in assignment
+    order (``back``: assignment -> sorted row), those of an absent
+    assignment, which may never have been written, selected away; a view of
+    the gathered rows for the sum over a token's choices that reads it."""
+    _count_row_move(which)
+    return jnp.where(held[..., None],
+                     _permuted(rows, back).reshape(held.shape + (-1,)), 0)
+
+
+def _count_row_move(which: str):
+    from ..ops.decoder_ops import _count
+
+    # one for every [N * top_k, D] gather traced: two in a forward, three
+    # in a backward
+    _count("ops.moe.row_moves", **{"pass": which, "how": "gather"})
+
+
+def _permuted(rows, index):
+    # every index is a row number by construction (a permutation, or one
+    # divided by ``top_k``): said so, the gather needs no bounds check and no
+    # select over the rows it returns (a pass of its own after a TPU gather)
+    return rows.at[index].get(mode="promise_in_bounds")
 
 
 def grouped_product(rows, weights, sizes, tables=None):
@@ -321,6 +414,51 @@ def product_path(x, w1, w2, top_k: int) -> str:
     return "ragged_dot" if declined(a1) or declined(a2) else "pallas"
 
 
+def product_transposes(rows, weights, sizes, tables=None):
+    """``(to_rows, to_weights)``, the two transposes of ``grouped_product(
+    rows, weights, sizes, tables)`` on the path that product takes:
+    ``to_rows(d)`` [M, K] is the cotangent ``d`` [M, N] of group g's rows
+    times ``weights[g].T``, and ``to_weights(rows, d)`` [G, K, N] the
+    weights' gradient from ANY rows [M, K] in those groups.  For a backward
+    written by hand, which has no product to differentiate: of ``rows`` only
+    the shape and type are read."""
+    from ..ops import pallas_grouped
+
+    if not pallas_grouped.supported(rows, weights):
+        if tables is None:
+            tables = pallas_grouped.plan(sizes, rows.shape[0])
+        return _kernel_transposes(weights, tables)
+
+    # XLA's own transposes of its product, by ``jax.vjp`` (bilinear: where
+    # the vjp is taken does not matter, and the product it traces has no
+    # reader); ``jax.linear_transpose`` would refuse a product that brings
+    # a vjp of its own, as the tests' poisoned one does
+    def to_rows(d):
+        return jax.vjp(lambda r: lax.ragged_dot(r, weights, sizes),
+                       rows)[1](d)[0]
+
+    def to_weights(rows, d):
+        return jax.vjp(lambda w: lax.ragged_dot(rows, w, sizes),
+                       weights)[1](d)[0]
+
+    return to_rows, to_weights
+
+
+def _kernel_transposes(weights, tables):
+    from ..ops import pallas_grouped
+
+    # the rows' cotangent reads the weights as they lie (no transposed
+    # copy); the weights' gradient is summed in float32 inside the kernel
+    def to_rows(d):
+        return pallas_grouped.grouped_matmul(d, weights, None,
+                                             transpose=True, plan=tables)
+
+    def to_weights(rows, d):
+        return pallas_grouped.grouped_matmul_t(rows, d, None, plan=tables)
+
+    return to_rows, to_weights
+
+
 @jax.custom_vjp
 def _kernel_product(rows, weights, tables):
     from ..ops import pallas_grouped
@@ -333,66 +471,10 @@ def _kernel_product_fwd(rows, weights, tables):
 
 
 def _kernel_product_bwd(kept, d):
-    from ..ops import pallas_grouped
-
     rows, weights, tables = kept
+    to_rows, to_weights = _kernel_transposes(weights, tables)
     d = d.astype(rows.dtype)
-    # the rows' cotangent reads the weights as they lie (no transposed
-    # copy); the weights' gradient is summed in float32 inside the kernel
-    return (pallas_grouped.grouped_matmul(d, weights, None, transpose=True,
-                                          plan=tables),
-            pallas_grouped.grouped_matmul_t(rows, d, None, plan=tables),
-            None)
+    return to_rows(d), to_weights(rows, d), None
 
 
 _kernel_product.defvjp(_kernel_product_fwd, _kernel_product_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _take_rows(rows, index, inverse, keep, repeat: int, dtype):
-    """``rows.astype(dtype)[index // repeat]``, where ``index`` is a
-    permutation of ``repeat * len(rows)`` row numbers and ``inverse`` the
-    permutation that undoes it.  The transpose of that gather, which
-    autodiff would lower as a scatter-add of every row (``unique_indices``
-    false, a row at a time on the TPU), is then a gather too: the cotangent
-    rows, in ``dtype``, taken through ``inverse``, and the ``repeat`` rows
-    that came from one summed in float32; ``keep`` (a mask over
-    ``inverse``, or None) says which of them count: the others, which may
-    never have been written, are selected away inside that sum.
-    ``routed_experts`` moves rows both ways with it: tokens out to their
-    ``top_k`` assignments in expert order (``order``, with ``back`` its
-    inverse, ``repeat = top_k``), and the experts' rows back to assignment
-    order (``back``, ``order``, 1)."""
-    return _permuted(rows.astype(dtype), index // repeat)
-
-
-def _permuted(rows, index):
-    # every index is a row number by construction (a permutation, or one
-    # divided by ``repeat``): said so, the gather needs no bounds check and no
-    # select over the rows it returns (a pass of its own after a TPU gather)
-    return rows.at[index].get(mode="promise_in_bounds")
-
-
-def _take_rows_fwd(rows, index, inverse, keep, repeat, dtype):
-    # the empty array carries the cotangent's type to the backward
-    return (_take_rows(rows, index, inverse, keep, repeat, dtype),
-            (inverse, keep, jnp.zeros((0,), rows.dtype)))
-
-
-def _take_rows_bwd(repeat, dtype, kept, d):
-    from ..ops.decoder_ops import _count
-
-    inverse, keep, like = kept
-    # one for every row move whose backward is traced as a gather: two an
-    # expert layer in each program lowered
-    _count("ops.moe.row_moves", **{"pass": "backward", "how": "gather"})
-    d = _permuted(d, inverse)
-    if keep is not None:
-        d = jnp.where(keep[:, None], d, 0)
-    if repeat > 1:
-        d = jnp.sum(d.reshape((-1, repeat) + d.shape[1:]), axis=1,
-                    dtype=jnp.promote_types(like.dtype, jnp.float32))
-    return d.astype(like.dtype), None, None, None
-
-
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)

@@ -794,38 +794,171 @@ def test_gradients_of_the_share_equal_a_per_expert_loop(score, off, held,
         assert bool(np.any(r)) == (lands > 0), name
 
 
-@pytest.mark.parametrize("repeat", [1, 8])
-def test_taking_rows_transposes_as_a_gather_would(repeat):
-    """``moe._take_rows``'s backward, a gather through the inverse
-    permutation, against the scatter-add that is ``jnp.take``'s own
-    transpose: float64, to the last bits (the sum over the ``repeat`` rows
-    of one source in another order)."""
-    rng = np.random.RandomState(repeat)
+def plain_share(x, wr, w1, w3, w2, top_k, expert_offset=0, **router):
+    """THE REFERENCE of ``moe.routed_experts``'s hand-written backward: the
+    share in its plain formulation, differentiated by jax.  The same
+    router (``moe.route_top_k``), the assignments sorted by expert with the
+    absent ones last, rows moved by ``jnp.take`` (whose transpose is a
+    scatter-add), XLA's own grouped product, the last product MADE and
+    read for the gate's cotangent, the combine an einsum over ``[N, k,
+    D]``: what the program did before its backward was written by hand,
+    less the checkpoint.  Nothing of ``moe._share`` is used."""
+    e, n = w1.shape[0], x.shape[0]
+    vals, idx = moe.route_top_k(x, wr, top_k, **router)
+    local = idx - expert_offset
+    held = (local >= 0) & (local < e)
+    group = jnp.where(held, local, e).reshape(-1)
+    order = jnp.argsort(group, stable=True)
+    counts = jnp.sum(group[:, None] == jnp.arange(e + 1), axis=0,
+                     dtype=jnp.int32)
+    sizes = counts[:e].at[e - 1].add(counts[e])
+    live = (jnp.arange(n * top_k) < jnp.sum(counts[:e]))[:, None]
+    xs = jnp.take(x, order // top_k, axis=0)
+    h = jax.nn.silu(jnp.where(live, jax.lax.ragged_dot(xs, w1, sizes), 0)) \
+        * jnp.where(live, jax.lax.ragged_dot(xs, w3, sizes), 0)
+    ys = jnp.take(jax.lax.ragged_dot(h, w2, sizes), jnp.argsort(order),
+                  axis=0).reshape(n, top_k, -1)
+    return jnp.einsum("nk,nkd->nd", jnp.where(held, vals, 0.0),
+                      jnp.where(held[..., None], ys, 0))
+
+
+def share_case(case, score, seed):
+    """Operands of a share at a lane-aligned size (512 rows of width 128:
+    what the Pallas kernels take), 3 of 8 experts held from expert 2 on, so
+    that most assignments are absent; ``an_empty_expert``: the held expert
+    3 is chosen by no token."""
+    n, d, f, routed, held, k, off = 256, 128, 128, 8, 3, 2, 2
+    rng = np.random.RandomState(seed)
+    x = jnp.asarray(rng.randn(n, d), jnp.float32).at[:, 0].set(1.0)
+    wr = jnp.asarray(rng.randn(d, routed) / np.sqrt(d), jnp.float32)
+    if case == "an_empty_expert":
+        wr = wr.at[0, 3].set(-60.0)
+    w1, w3 = (jnp.asarray(rng.randn(held, d, f) / np.sqrt(d), jnp.float32)
+              for _ in range(2))
+    w2 = jnp.asarray(rng.randn(held, f, d) / np.sqrt(f), jnp.float32)
+    kw = dict(top_k=k, expert_offset=off)
+    if score == "sigmoid_bias":
+        bias = jnp.asarray(0.1 * rng.randn(routed), jnp.float32)
+        kw.update(score="sigmoid", norm_eps=1e-20, scale=2.5, bias=bias.at[
+            3].set(-10.0) if case == "an_empty_expert" else bias)
+    _, idx = moe.route_top_k(x, wr, k, **{
+        a: b for a, b in kw.items() if a not in ("top_k", "expert_offset")})
+    lands = np.bincount(np.asarray(idx).reshape(-1), minlength=routed)
+    assert 0 < lands[off:off + held].sum() < n * k       # some are absent
+    assert (lands[3] == 0) == (case == "an_empty_expert")
+    mix = jnp.asarray(rng.randn(n, d), jnp.float32)
+    return (x, wr, w1, w3, w2), kw, mix
+
+
+@pytest.mark.parametrize("case", ["absent_assignments", "an_empty_expert"])
+@pytest.mark.parametrize("score", ["softmax", "sigmoid_bias"])
+@pytest.mark.parametrize("path", ["ragged_dot", "pallas"])
+def test_the_handwritten_backward_equals_autodiff_of_the_plain_share(
+        monkeypatch, path, score, case):
+    """All five gradients (x, router, w1, w3, w2) of ``routed_experts``,
+    whose backward is written by hand, against jax's own differentiation of
+    ``plain_share``, in float32, on both product paths (the Pallas kernels
+    interpreted).  The two differ in what they sum and in which order (the
+    gate's cotangent over the hidden width, not over D; the rows' sums over
+    a token's choices by gather, not by scatter-add), hence the per-expert
+    loop's tolerance: 1e-5 of each gradient's largest entry."""
+    from paddle_tpu.ops import pallas_grouped
+
+    args, kw, mix = share_case(case, score, seed=21)
+    if path == "ragged_dot":
+        monkeypatch.setattr(pallas_grouped, "supported", lambda *a: "off")
+    assert moe.product_path(args[0], args[2], args[4], kw["top_k"]) == path
+    got = jax.value_and_grad(lambda *a: jnp.sum(
+        mix * moe.routed_experts(*a, **kw)), range(5))(*args)
+    want = jax.value_and_grad(lambda *a: jnp.sum(
+        mix * plain_share(*a, **kw)), range(5))(*args)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for name, g, r in zip("x router w1 w3 w2".split(), got[1], want[1]):
+        assert g.dtype == r.dtype == jnp.float32
+        assert np.abs(g - r).max() <= 1e-5 * np.abs(r).max(), name
+        assert np.any(np.asarray(r)), name
+    if case == "an_empty_expert":
+        for g in got[1][2:]:
+            assert not np.any(np.asarray(g[1]))
+
+
+@pytest.mark.parametrize("path", ["ragged_dot", "pallas"])
+def test_the_gates_cotangent_is_dy_against_the_last_product(monkeypatch,
+                                                            path):
+    """The backward makes no last product: what it gives the gate is
+    ``<dy @ w2^T, h>`` over a sorted row.  Against ``<dy, ys>`` computed
+    outright, every token through every held expert it chose, and exactly
+    zero where the choice is absent."""
+    from paddle_tpu.ops import pallas_grouped
+
+    (x, wr, w1, w3, w2), kw, dy = share_case("absent_assignments",
+                                             "softmax", seed=22)
+    if path == "ragged_dot":
+        monkeypatch.setattr(pallas_grouped, "supported", lambda *a: "off")
+    vals, idx = moe.route_top_k(x, wr, kw["top_k"])
+
+    def weighted(vals):
+        # the router's weights handed in as they are: their cotangent is
+        # the gate's
+        monkeypatch.setattr(moe, "route_top_k", lambda *a, **k: (vals, idx))
+        return jnp.sum(dy * moe.routed_experts(x, wr, w1, w3, w2, **kw))
+
+    got = jax.grad(weighted)(vals)
+    off = kw["expert_offset"]
+    ys = jnp.stack([(jax.nn.silu(x @ w1[j]) * (x @ w3[j])) @ w2[j]
+                    for j in range(w1.shape[0])], 1)          # [N, held, D]
+    dots = jnp.einsum("nd,njd->nj", dy, ys,
+                      precision=jax.lax.Precision.HIGHEST)
+    held = (idx >= off) & (idx < off + w1.shape[0])
+    want = jnp.where(held, jnp.take_along_axis(
+        dots, jnp.clip(idx - off, 0, w1.shape[0] - 1), 1), 0.0)
+    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+    assert not np.any(np.asarray(got)[~np.asarray(held)])
+    assert np.any(np.asarray(held)) and not np.all(np.asarray(held))
+
+
+@pytest.mark.parametrize("top_k", [1, 8])
+def test_rows_come_home_as_the_transpose_of_going_out(top_k):
+    """The backward's third gather: ``moe._rows_home`` through the inverse
+    permutation, summed over a token's ``top_k`` choices, is the transpose
+    of ``moe._rows_out`` (the gather both passes take the tokens' rows out
+    to the sorted assignments with, and the backward the combine's
+    cotangent), against the scatter-add that is ``jnp.take``'s own
+    transpose: float64, to the last bits (the sum over the rows of one
+    token in another order).  These two stand where ``_take_rows``'s
+    custom vjp stood until PR 39."""
+    rng = np.random.RandomState(top_k)
     n, d = 40, 6
     rows = jnp.asarray(rng.randn(n, d))
-    order = jnp.asarray(rng.permutation(n * repeat), jnp.int32)
+    order = jnp.asarray(rng.permutation(n * top_k), jnp.int32)
     back = jnp.argsort(order).astype(jnp.int32)
-    mix = jnp.asarray(rng.randn(n * repeat, d))
-    np.testing.assert_array_equal(order[back], np.arange(n * repeat))
-    got = jax.value_and_grad(lambda r: jnp.sum(mix * moe._take_rows(
-        r, order, back, None, repeat, r.dtype)))(rows)
-    want = jax.value_and_grad(lambda r: jnp.sum(mix * jnp.take(
-        r, order // repeat, axis=0)))(rows)
-    assert got[0] == want[0] and got[1].dtype == jnp.float64
-    np.testing.assert_allclose(got[1], want[1], rtol=1e-13, atol=1e-15)
-    # in bfloat16 the rows of one source are summed in float32
-    # in bfloat16 the rows of one source are summed in float32, and the
-    # cotangent rows that ``keep`` leaves out (poisoned here) count for 0
-    keep = jnp.asarray(rng.rand(n * repeat) < 0.7)
-    cot = jnp.where(keep[order][:, None], mix, jnp.nan).astype(jnp.bfloat16)
-    low = jax.grad(lambda r: jnp.sum(moe._take_rows(
-        r, order, back, keep, repeat, jnp.bfloat16).astype(jnp.float32)
-        * cot))(rows.astype(jnp.float32))
+    mix = jnp.asarray(rng.randn(n * top_k, d))
+    held = jnp.ones((n, top_k), bool)
+    np.testing.assert_array_equal(order[back], np.arange(n * top_k))
+    np.testing.assert_array_equal(
+        moe._rows_out(rows, order, top_k, "forward"),
+        jnp.take(rows, order // top_k, axis=0))
+    got = jnp.sum(moe._rows_home(mix, back, held, "backward"), axis=1)
+    want = jax.grad(lambda r: jnp.sum(mix * jnp.take(
+        r, order // top_k, axis=0)))(rows)
+    assert got.dtype == jnp.float64
+    np.testing.assert_allclose(got, want, rtol=1e-13, atol=1e-15)
+    # in bfloat16 the share's backward sums the rows of one token in
+    # float32, and the cotangent rows of an absent assignment (poisoned
+    # here, as a product may leave them) count for 0
+    held = jnp.asarray(rng.rand(n, top_k) < 0.7)
+    cot = jnp.where(held.reshape(-1)[order][:, None], mix,
+                    jnp.nan).astype(jnp.bfloat16)
+    low = jnp.sum(moe._rows_home(cot, back, held, "backward"), axis=1,
+                  dtype=jnp.float32)
     assert low.dtype == jnp.float32 and bool(jnp.all(jnp.isfinite(low)))
     np.testing.assert_allclose(
         low, jnp.sum(jnp.where(
-            keep[:, None], jnp.take(cot, back, axis=0), 0)
-            .astype(jnp.float32).reshape(n, repeat, d), 1), rtol=1e-6)
+            held[..., None], jnp.take(cot, back, axis=0)
+            .astype(jnp.float32).reshape(n, top_k, d), 0), 1), rtol=1e-6)
+    assert counters("ops.moe.row_moves") == {
+        'ops.moe.row_moves{how="gather",pass="backward"}': 2,
+        'ops.moe.row_moves{how="gather",pass="forward"}': 1}
 
 
 def test_the_chosen_scores_cotangent_lands_where_lax_top_k_puts_it():
@@ -861,11 +994,11 @@ def test_the_chosen_scores_cotangent_lands_where_lax_top_k_puts_it():
 
 
 def lowered_ops(lowered):
-    """(name, the locations it lies under, elements a scatter updates) for
-    every operation of a lowered module as it runs: jax lowers a jitted
-    helper (``argsort``) once, as a private function, however often it is
-    called, so a private function's operations count once for every call
-    of it, under the call's location too."""
+    """(name, the locations it lies under, elements a scatter updates, its
+    results' shapes) for every operation of a lowered module as it runs:
+    jax lowers a jitted helper (``argsort``) once, as a private function,
+    however often it is called, so a private function's operations count
+    once for every call of it, under the call's location too."""
     from jax._src.lib.mlir import ir
 
     bodies, out = {}, []
@@ -879,15 +1012,17 @@ def lowered_ops(lowered):
                 callee = ir.FlatSymbolRefAttr(op.attributes["callee"]).value
             elif op.name == "stablehlo.scatter":
                 updates = int(np.prod(op.operands[2].type.shape))
-            ops.append((op.name, str(op.location), callee, updates))
+            shapes = [tuple(r.type.shape) for r in op.results
+                      if isinstance(r.type, ir.RankedTensorType)]
+            ops.append((op.name, str(op.location), callee, updates, shapes))
             return ir.WalkResult.ADVANCE
 
         func.operation.walk(visit)
 
     def run(name, under):
-        for op, location, callee, updates in bodies[name]:
+        for op, location, callee, updates, shapes in bodies[name]:
             if callee is None:
-                out.append((op, under + (location,), updates))
+                out.append((op, under + (location,), updates, shapes))
             else:
                 run(callee, under + (location,))
 
@@ -895,24 +1030,81 @@ def lowered_ops(lowered):
     return out
 
 
+#: what may give a value of a token's ``top_k`` rows side by side, ``[N,
+#: top_k, D]``: views of gathered rows on their way into a sum over the
+#: choices (and the zeros a select puts in).  No arithmetic: until PR 39
+#: the combine's transpose made ``gate * dy`` at that size and gathered it
+VIEWS = {"stablehlo.reshape", "stablehlo.select", "stablehlo.convert",
+         "stablehlo.broadcast_in_dim", "stablehlo.constant"}
+
+
+def makers_of(ops, shape):
+    return {op for op, _, _, shapes in ops if shape in shapes}
+
+
 @pytest.mark.parametrize("score", ["softmax", "sigmoid_bias"])
 def test_the_share_backward_lowers_no_row_scatter_and_one_sort(score):
     """StableHLO of ``jax.grad`` of the share: the one scatter left adds
     one count to one group size (``sizes``); the assignments are sorted
-    once, not once more behind the checkpoint; both row moves counted."""
-    x, wr, w1, w3, w2 = moe_weights(np.random.RandomState(6), 32, 8, 4, 16)
+    once, by the forward, and the backward takes the plan as it is; every
+    row move is a gather and counted, two forward and three backward; and
+    nothing is computed at ``[N, top_k, D]``."""
+    n, k, d = 32, 4, 8
+    x, wr, w1, w3, w2 = moe_weights(np.random.RandomState(6), n, d, 4, 16)
     kw = {} if score == "softmax" else dict(
         score="sigmoid", bias=jnp.zeros(16), norm_eps=1e-20, scale=2.0)
     ops = lowered_ops(jax.jit(jax.grad(
         lambda *a: jnp.sum(moe.routed_experts(
-            *a, top_k=4, expert_offset=4, **kw) ** 2), range(5))).lower(
+            *a, top_k=k, expert_offset=4, **kw) ** 2), range(5))).lower(
         x, wr, w1[:4], w3[:4], w2[:4]))
-    names = [op for op, _, _ in ops]
-    assert [n for op, _, n in ops if op == "stablehlo.scatter"] == [1]
+    names = [op for op, *_ in ops]
+    assert [u for op, _, u, _ in ops if op == "stablehlo.scatter"] == [1]
     assert names.count("stablehlo.sort") == 1
-    assert names.count("stablehlo.gather") >= 4
+    row_gathers = [op for op, _, _, shapes in ops
+                   if op == "stablehlo.gather" and shapes == [(n * k, d)]]
+    assert len(row_gathers) == 5
+    assert makers_of(ops, (n, k, d)) <= VIEWS
+    assert "stablehlo.reshape" in makers_of(ops, (n, k, d))
     assert counters("ops.moe.row_moves") == {
-        'ops.moe.row_moves{how="gather",pass="backward"}': 2}
+        'ops.moe.row_moves{how="gather",pass="forward"}': 2,
+        'ops.moe.row_moves{how="gather",pass="backward"}': 3}
+
+
+def kernel_calls(fn, *args):
+    """How often each Pallas kernel is called in ``fn``'s jaxpr, by name."""
+    calls = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                name = eqn.params["name"]
+                calls[name] = calls.get(name, 0) + 1
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (list, tuple)) \
+                        else [value]:
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return calls
+
+
+def test_a_layer_and_its_backward_call_eight_products_and_three_gradients():
+    """On the Pallas path, as traced: the forward calls ``grouped_matmul``
+    three times, the backward five times more (the two hidden products
+    again, the three rows' cotangents; NOT the last product again) and
+    ``grouped_matmul_t`` three times."""
+    (x, wr, w1, w3, w2), kw, mix = share_case("absent_assignments",
+                                              "softmax", seed=23)
+
+    def layer(*a):
+        return jnp.sum(mix * moe.routed_experts(*a, **kw))
+
+    assert moe.product_path(x, w1, w2, kw["top_k"]) == "pallas"
+    assert kernel_calls(layer, x, wr, w1, w3, w2) == {"grouped_matmul": 3}
+    assert kernel_calls(jax.grad(layer, range(5)), x, wr, w1, w3, w2) == {
+        "grouped_matmul": 8, "grouped_matmul_t": 3}
 
 
 def test_the_training_step_scatters_no_row_under_the_expert_layer():
@@ -920,32 +1112,43 @@ def test_the_training_step_scatters_no_row_under_the_expert_layer():
     layer's two ops (``moe_experts`` and ``moe_experts_grad`` in the
     locations) no scatter moves more than one element, each routed layer
     sorts once in the forward op and once in the grad op's own trace of
-    the forward, and its backward counts two row moves."""
+    the forward, of which the plan alone has a reader (jax drops the rest
+    before it lowers), so the grad op holds the backward's three row
+    gathers and nothing computed at ``[N, top_k, D]``.  Row moves as
+    TRACED: two for each of those two traces of the forward, three for the
+    backward."""
     from paddle_tpu.models import decoder_lm
 
     cfg = decoder_lm.tiny_config()
-    tokens, labels, loss = decoder_lm.build(cfg, seq_len=32)
+    seq = 32
+    tokens, labels, loss = decoder_lm.build(cfg, seq_len=seq)
     exe = fluid.Executor(fluid.TPUPlace())
     exe.run(fluid.default_startup_program())
     rng = np.random.RandomState(0)
-    ids = rng.randint(0, cfg.vocab_size, size=(1, 33)).astype(np.int64)
+    ids = rng.randint(0, cfg.vocab_size, size=(1, seq + 1)).astype(np.int64)
     ops = lowered_ops(exe.lower_step(
         fluid.default_main_program(),
         {"tokens": ids[:, :-1], "labels": ids[:, 1:, None]}, [loss]))
-    layer = [(op, where, n) for op, where, n in ops
+    layer = [(op, where, u, shapes) for op, where, u, shapes in ops
              if any("moe_experts" in w for w in where)]
-    grad = [op for op, where, _ in layer
+    grad = [(op, where, u, shapes) for op, where, u, shapes in layer
             if any("moe_experts_grad" in w for w in where)]
-    assert grad.count("stablehlo.gather") >= 4 * cfg.num_layers
-    assert [n for op, _, n in layer if op == "stablehlo.scatter"] \
+    rows = (seq * cfg.experts_per_token, cfg.hidden_size)
+    assert sum(op == "stablehlo.gather" and shapes == [rows]
+               for op, _, _, shapes in grad) == 3 * cfg.num_layers
+    assert makers_of(grad, (seq, cfg.experts_per_token,
+                            cfg.hidden_size)) <= VIEWS
+    assert [u for op, _, u, _ in layer if op == "stablehlo.scatter"] \
         == [1] * 2 * cfg.num_layers
     # the step does scatter rows elsewhere (the embedding's gradient)
-    assert any(op == "stablehlo.scatter" and n > 1 for op, _, n in ops)
-    assert [op for op, _, _ in layer].count("stablehlo.sort") \
+    assert any(op == "stablehlo.scatter" and u > 1 for op, _, u, _ in ops)
+    assert [op for op, *_ in layer].count("stablehlo.sort") \
         == 2 * cfg.num_layers
-    assert grad.count("stablehlo.sort") == cfg.num_layers
+    assert [op for op, *_ in grad].count("stablehlo.sort") == cfg.num_layers
     assert counters("ops.moe.row_moves") == {
-        'ops.moe.row_moves{how="gather",pass="backward"}': 2 * cfg.num_layers}
+        'ops.moe.row_moves{how="gather",pass="forward"}': 4 * cfg.num_layers,
+        'ops.moe.row_moves{how="gather",pass="backward"}':
+            3 * cfg.num_layers}
 
 
 # == gated short-convolution layers beside an attention one of head width ==

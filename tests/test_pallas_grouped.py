@@ -165,7 +165,7 @@ def test_routed_experts_take_the_kernels_at_lane_aligned_sizes(monkeypatch):
     """256 tokens x top-2 = 512 rows, hidden and expert width 128: all
     three products, their rows' cotangents and their weights' gradients go
     through the kernels, none through ``lax.ragged_dot``; value and every
-    gradient equal the ``ragged_dot`` path's."""
+    gradient, the router's among them, equal the ``ragged_dot`` path's."""
     n, d, f, routed, held, k = 256, 128, 128, 8, 3, 2
     x, wr, w1, w3, w2 = layer_weights(np.random.RandomState(0), n, d, f,
                                       routed, held)
@@ -182,19 +182,20 @@ def test_routed_experts_take_the_kernels_at_lane_aligned_sizes(monkeypatch):
     monkeypatch.setattr(moe.lax, "ragged_dot",
                         counted("ragged_dot", lax.ragged_dot))
 
-    def program(x, w1, w3, w2):
+    def program(x, wr, w1, w3, w2):
         return jnp.sum(moe.routed_experts(x, wr, w1, w3, w2, top_k=k,
                                           expert_offset=2) ** 2)
 
     assert moe.product_path(x, w1, w2, k) == "pallas"
-    got = jax.value_and_grad(program, (0, 1, 2, 3))(x, w1, w3, w2)
-    # forward 3, the checkpoint's again 3, rows' cotangents 3; gradients 3
-    assert calls == {"grouped_matmul": 9, "grouped_matmul_t": 3,
+    got = jax.value_and_grad(program, range(5))(x, wr, w1, w3, w2)
+    # forward 3; the hand-written backward: the two hidden products again
+    # (not the last one) and three rows' cotangents; gradients 3
+    assert calls == {"grouped_matmul": 8, "grouped_matmul_t": 3,
                      "ragged_dot": 0}
     monkeypatch.setattr(pg, "supported", lambda *a: "off")
     assert moe.product_path(x, w1, w2, k) == "ragged_dot"
-    want = jax.value_and_grad(program, (0, 1, 2, 3))(x, w1, w3, w2)
-    assert calls["grouped_matmul"] == 9 and calls["ragged_dot"] >= 3
+    want = jax.value_and_grad(program, range(5))(x, wr, w1, w3, w2)
+    assert calls["grouped_matmul"] == 8 and calls["ragged_dot"] >= 3
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
     for g, r in zip(got[1], want[1]):
         np.testing.assert_allclose(g, r, atol=1e-4 * float(jnp.abs(r).max()))

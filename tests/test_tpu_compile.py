@@ -16,6 +16,7 @@ described.  jax's persistent compilation cache is off around them: an
 executable compiled for a described chip cannot be read back without one.
 """
 
+import collections
 import functools
 import math
 import os
@@ -312,6 +313,70 @@ def test_grouped_signatures_are_the_benchmarks(topo, monkeypatch, cell):
         module = load("kernels", call.kernel)
         assert module.KERNEL == call.kernel
         assert module.flops(call.operands, call.results) == 2.0 * m * d * f
+
+
+#: a cell's routed layer: tokens a step, choices a token, routed experts
+#: (``GROUPED_CELLS`` has the rows, the widths and the experts held)
+GROUPED_LAYERS = {"keye": (8192, 8, 128), "trinity": (6144, 8, 128),
+                  "lfm2": (8192, 4, 32)}
+
+
+@pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))
+def test_a_routed_layer_and_its_backward_lower_eleven_calls(
+        topo, monkeypatch, cell):
+    """``routed_experts`` with its hand-written backward at a cell's
+    sizes, under the cells' AMP, lowered and compiled for the described
+    chip: 8 ``grouped_matmul`` (3 forward; the two hidden products again
+    and three rows' cotangents backward, NOT the last product again) and 3
+    ``grouped_matmul_t``, in the six signatures that
+    ``test_grouped_signatures_are_the_benchmarks`` pins one product at a
+    time, each counted as ``2 * M * D * F`` FLOPs by the benchmark's files;
+    XLA drops none and adds none."""
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from chipbench import hlo
+    from chipbench.plugins import load
+    from paddle_tpu.fluid import amp
+    from paddle_tpu.parallel import moe
+
+    m, d, f, g = GROUPED_CELLS[cell]
+    tokens, top_k, routed = GROUPED_LAYERS[cell]
+    assert tokens * top_k == m
+    monkeypatch.setattr(pallas_grouped, "_resolve", lambda interpret: False)
+
+    def layer(x, wr, w1, w3, w2):
+        return moe.routed_experts(x, wr, w1, w3, w2, top_k=top_k).astype(
+            F32).sum()
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, t, sharding=chip) for s, t in
+            (((1, tokens, d), BF16), ((d, routed), F32), ((g, d, f), F32),
+             ((g, d, f), F32), ((g, f, d), F32))]
+    amp.enable("bfloat16", keep_activations=True)
+    try:
+        lowered = jax.jit(jax.value_and_grad(layer, range(5))).lower(*args)
+    finally:
+        amp.disable()
+    calls = hlo.custom_calls(lowered.as_text())
+    steps = m // pallas_grouped.ROW_TILE + g - 1
+    tables = f"s32[{g + 1}],s32[{steps}],s32[{steps}]"
+    rows, hidden = f"bf16[{m},{d}]", f"bf16[{m},{f}]"
+    up, down = f"bf16[{g},{d},{f}]", f"bf16[{g},{f},{d}]"
+    for call in calls:
+        assert load("kernels", call.kernel).flops(
+            call.operands, call.results) == 2.0 * m * d * f
+    assert collections.Counter(
+            (call.kernel, hlo.signature(call)) for call in calls) == {
+        ("grouped_matmul", f"{hidden}<-{tables},{rows},{up}"): 4,
+        ("grouped_matmul", f"{rows}<-{tables},{hidden},{down}"): 1,
+        ("grouped_matmul", f"{hidden}<-{tables},{rows},{down}"): 1,
+        ("grouped_matmul", f"{rows}<-{tables},{hidden},{up}"): 2,
+        ("grouped_matmul_t", f"{up}<-{tables},{rows},{hidden}"): 2,
+        ("grouped_matmul_t", f"{down}<-{tables},{hidden},{rows}"): 1}
+    assert lowered.compile().as_text().count(
+        'custom_call_target="tpu_custom_call"') == 11
 
 
 def _momentum_op(p, g, v, lr):
