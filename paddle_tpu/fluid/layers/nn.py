@@ -1317,14 +1317,32 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
     return out
 
 
-def rotary_embedding(input, theta=10000.0, name=None):
-    """Rotary positions (rotate-half form) on [B, T, H, D]: position t, the
-    index along axis 1, rotates the pair (i, i + D/2) by t * theta^(-2i/D)."""
+def rotary_embedding(input, theta=10000.0, start=0, dims=0, interleaved=False,
+                     inv_freq=None, name=None):
+    """Rotary positions on [B, T, H, D]: position t, the index along axis
+    1, rotates the pair (i, i + n/2) of the head's ``n = dims`` columns
+    from ``start`` on (``dims`` 0: to the head's end; the defaults are the
+    whole head) by t * theta^(-2i/n), the rotate-half form.
+    ``interleaved``: the pair is (2i, 2i + 1).  ``inv_freq``: n/2
+    frequencies in ``theta``'s place, for a table that a scaling rule made
+    (``models.decoder_lm.yarn_inv_freq``).  Columns outside the part pass
+    unchanged."""
     helper = LayerHelper("rotary_embedding", **locals())
     out = helper.create_variable_for_type_inference(helper.input_dtype())
     out.shape = tuple(input.shape)
+    # only what departs from the whole head, rotate-half, theta's table is
+    # written into the op
+    attrs = {"theta": float(theta)}
+    if start:
+        attrs["start"] = int(start)
+    if dims:
+        attrs["dims"] = int(dims)
+    if interleaved:
+        attrs["interleaved"] = True
+    if inv_freq is not None:
+        attrs["inv_freq"] = [float(f) for f in inv_freq]
     helper.append_op(type="rotary_embedding", inputs={"X": [input]},
-                     outputs={"Out": [out]}, attrs={"theta": float(theta)})
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 

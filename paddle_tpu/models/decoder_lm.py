@@ -1,10 +1,12 @@
 """A decoder-only language model from its sizes: residual blocks whose
 token mixer is grouped-query attention (over a learned per-query selection
 of keys, under a causal window, or plain causal; window layers beside
-global ones) or, layer by layer, a gated short convolution, and a
-feed-forward that is dense in the leading layers and elsewhere a routed
-expert layer of which this program holds a stated share, RMS norms, rotary
-positions, a head of its own or the embedding's transpose, next-token loss.
+global ones) or, layer by layer, a gated short convolution or attention
+whose keys and values come from a low-rank latent, and a feed-forward that
+is dense in the leading layers and elsewhere a routed expert layer of which
+this program holds a stated share, RMS norms, rotary positions, a head of
+its own or the embedding's transpose, next-token loss and, where the model
+has one, a multi-token module's loss beside it.
 
 Everything is configuration (``Config``); nothing here is specific to one
 model or to the benchmark.  The layer, for x = one sequence [T, hidden] and
@@ -28,6 +30,16 @@ field turns on)::
                   S_t = sparse_indexer(a), where index_topk is set]
     o  = concat_h softmax_{keys that count}(q_h k_{h // group} / sqrt(d)) v
     y  = (o [* sigmoid(g)]) Wo
+    latent layer (mixers[published index] == "latent"; L = cfg.latent):
+        q = a Wq                      [T, H, L.nope + L.rope]
+        [c | kr] = a Wkva             L.rank + L.rope
+        [k_nope | v] = RMSNorm(c) Wkvb      [T, H, L.nope + L.value]
+        k = [k_nope | kr, the same for every head]
+        q = RMSNorm_head(q), k = RMSNorm_head(k)
+        q, k = RoPE on their last L.rope columns only, pairs (2i, 2i + 1)
+               where L.interleaved, by the frequencies L.inv_freq
+        o = concat_h softmax_{s <= t}(q_h k_h * L.scale) v_h
+        y = (o [* sigmoid(g)]) Wo
     x1 = x + y                      [post_norms: x + RMSNorm(y)]
     m  = RMSNorm(x1)
     dense layer (published index < dense_layers):
@@ -40,9 +52,20 @@ field turns on)::
             w_e W2_e(silu(W1_e m) * W3_e m)
         Shared: the dense feed-forward at width shared_width
     x2 = x1 + f                     [post_norms: x1 + RMSNorm(f)]
+    [residual "farskip": a sub-block reads the stream WITHOUT the sub-block
+        just before it.  With s_0 = h0 = s_{-1} and the sub-blocks F_j
+        numbered mixer, feed-forward, mixer, ...:
+        s_j = s_{j-1} + F_j(RMSNorm_j(s_{j-2})); "sequential", the default
+        and the lines above: s_j = s_{j-1} + F_j(RMSNorm_j(s_{j-1}))]
     logits = RMSNorm(x_last) Whead [tie_head: Emb^T, one parameter whose
         gradient is the lookup's rows plus the head product's]
     loss = mean next-token cross-entropy
+    [mtp_depth 1, the multi-token module after the trunk:
+        h' = [RMSNorm(x_last) | RMSNorm(Emb[labels])] Wmerge   the SAME Emb
+        u  = one more routed block on h' (the mixer of the last published
+             layer, a global one; the residual rule, from s_0 = h')
+        logits' = RMSNorm(u) Whead                             the SAME head
+        loss += mtp_weight * mean cross-entropy(logits', labels2)]
     after each step [route_bias_coeff], per routed layer:
         b_e += route_bias_coeff * sign(mean_e'(n_e') - n_e)
         n_e = the step's assignments to expert e, over ALL num_routed;
@@ -51,22 +74,75 @@ field turns on)::
 Parameters are created in a fixed order and named ``tok_emb``,
 ``l<i>_{attn_norm,q_w,q_norm,k_w,k_norm,v_w,idx_q_w,idx_k_w,idx_w_w,gate_w,
 o_w,post_attn_norm}`` (a conv layer: ``l<i>_{conv_norm,conv_in_w,conv_w,
-conv_out_w,post_attn_norm}`` and none of the others), then
+conv_out_w,post_attn_norm}`` and none of the others; a latent layer:
+``l<i>_{attn_norm,q_w,q_norm,kva_w,kv_norm,kvb_w,k_norm,gate_w,o_w,
+post_attn_norm}``), then
 ``l<i>_{mlp_norm,mlp_w1,mlp_w3,mlp_w2}`` (dense)
 or ``l<i>_{moe_norm,shared_w1,shared_w3,shared_w2,router_w,w1,w3,w2}``
 (routed; ``l<i>_route_bias`` is no parameter), ``l<i>_post_mlp_norm``,
-``final_norm``, ``lm_head_w`` (not with ``tie_head``); ``i`` counts the
-layers held, from 0.
+``final_norm``, ``lm_head_w`` (not with ``tie_head``), then the module's
+``mtp_{h_norm,e_norm,merge_w}``, its block's as a routed layer's under
+``mtp_`` for ``l<i>_``, and ``mtp_norm``; ``i`` counts the layers held,
+from 0.
 """
 
 from __future__ import annotations
 
+import math
+from typing import NamedTuple, Optional, Tuple
+
 import paddle_tpu.fluid as fluid
+from paddle_tpu import observe
 from paddle_tpu.fluid import layers
 from paddle_tpu.fluid.param_attr import ParamAttr
 
 
-MIXERS = ("attention", "conv")
+MIXERS = ("attention", "conv", "latent")
+RESIDUALS = ("sequential", "farskip")
+
+
+class Latent(NamedTuple):
+    """What a ``latent`` mixer needs beyond the model's heads: the rank of
+    the latent that keys and values are made from, the unrotated and the
+    rotated width of a query's and a key's head (``Config.head_dim`` is
+    their sum), the value's width, and the rotary of the rotated part: its
+    ``rope // 2`` frequencies (None: ``rope_theta``'s own), its pairing,
+    and the softmax scale where it is not ``head_dim ** -0.5``."""
+    rank: int
+    nope: int
+    rope: int
+    value: int
+    inv_freq: Optional[Tuple[float, ...]] = None
+    interleaved: bool = False
+    scale: float = 0.0
+
+
+def yarn_inv_freq(dims, base, factor, original_positions, beta_fast=32,
+                  beta_slow=1):
+    """The ``dims // 2`` rotary frequencies under YaRN: ``base ** (-2i /
+    dims)`` for the pairs that turn more than ``beta_fast`` times over the
+    ``original_positions``, that over ``factor`` for those that turn fewer
+    than ``beta_slow`` times, and a linear blend between."""
+    def pair_that_turns(r):
+        return dims * math.log(original_positions / (2 * math.pi * r)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(pair_that_turns(beta_fast)), 0)
+    high = min(math.ceil(pair_that_turns(beta_slow)), dims - 1)
+    out = []
+    for i in range(dims // 2):
+        f = base ** (-2.0 * i / dims)
+        ramp = min(max((i - low) / max(high - low, 0.001), 0.0), 1.0)
+        out.append(f / factor * ramp + f * (1.0 - ramp))
+    return tuple(out)
+
+
+def yarn_softmax_scale(head_dim, factor, mscale_all_dim=1.0):
+    """``head_dim ** -0.5 * m ** 2`` with ``m = 0.1 * mscale_all_dim *
+    ln(factor) + 1``: the temperature YaRN puts on attention beside its
+    blended frequencies."""
+    m = 0.1 * mscale_all_dim * math.log(factor) + 1.0 if factor > 1 else 1.0
+    return head_dim ** -0.5 * m * m
 
 
 class Config:
@@ -80,7 +156,8 @@ class Config:
                  dense_layers=0, dense_width=0, shared_width=0,
                  router_score="softmax", route_norm_eps=0.0,
                  route_scale=1.0, route_bias_coeff=0.0, mixers=None,
-                 conv_taps=0, tie_head=False):
+                 conv_taps=0, tie_head=False, latent=None,
+                 residual="sequential", mtp_depth=0, mtp_weight=0.0):
         if num_heads % num_kv_heads:
             raise ValueError(f"{num_heads} query heads do not group over "
                              f"{num_kv_heads} key-value heads")
@@ -97,6 +174,26 @@ class Config:
                 f"from {layer_offset} on: one of {MIXERS} each")
         if "conv" in held and conv_taps < 1:
             raise ValueError("a conv layer needs conv_taps")
+        if mtp_depth not in (0, 1):
+            raise ValueError(f"mtp_depth {mtp_depth}: one module after the "
+                             "trunk is what is built, or none")
+        if latent is not None:
+            latent = Latent(*latent)
+            if latent.nope + latent.rope != head_dim or latent.rope % 2 \
+                    or latent.value != head_dim \
+                    or num_kv_heads != num_heads:
+                raise ValueError(
+                    f"latent heads of {latent.nope} + {latent.rope} and "
+                    f"values of {latent.value} beside head_dim {head_dim} "
+                    f"and {num_heads}/{num_kv_heads} heads: a query's and "
+                    "a key's two parts add up to head_dim, the rotated one "
+                    "even, every query head has its own key and value, and "
+                    "no attention path takes a value of another width")
+        elif "latent" in held or (mtp_depth and mixers
+                                  and mixers[-1] == "latent"):
+            raise ValueError("a latent layer needs the record `latent`")
+        if residual not in RESIDUALS:
+            raise ValueError(f"residual {residual!r}: one of {RESIDUALS}")
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.num_layers = num_layers
@@ -138,12 +235,24 @@ class Config:
         self.mixers = None if mixers is None else tuple(mixers)
         self.conv_taps = conv_taps
         self.tie_head = tie_head
+        # what a "latent" mixer needs: a Latent (or its fields in order)
+        self.latent = latent
+        # which stream a sub-block reads: "farskip" the one without the
+        # sub-block just before it
+        self.residual = residual
+        # the multi-token module: how many (0 or 1) and its loss's weight
+        self.mtp_depth = mtp_depth
+        self.mtp_weight = mtp_weight
 
     def layer_mixer(self, i):
         """The kind of held layer ``i``'s token mixer."""
         if self.mixers is None:
             return "attention"
         return self.mixers[self.layer_offset + i]
+
+    def mtp_mixer(self):
+        """The kind of the module's block: the last published layer's."""
+        return "attention" if self.mixers is None else self.mixers[-1]
 
     def layer_window(self, i):
         """The window of held layer ``i``: 0 where it is a global one."""
@@ -193,6 +302,18 @@ def _heads(x, seq_len, n, cfg, norm_name=None, rotate=True):
     return layers.transpose(x, perm=[0, 2, 1, 3])
 
 
+def _gated_out(ctx, x, cfg, seq_len, p):
+    """[B, H, T, Dh] -> the mixer's output: heads side by side, the gate
+    where the model has one, the output projection."""
+    width = cfg.num_heads * cfg.head_dim
+    ctx = layers.reshape(layers.transpose(ctx, perm=[0, 2, 1, 3]),
+                         [-1, seq_len, width])
+    if cfg.attn_gate:
+        ctx = layers.elementwise_mul(
+            ctx, layers.sigmoid(_proj(x, width, f"{p}_gate_w")))
+    return _proj(ctx, cfg.hidden_size, f"{p}_o_w")
+
+
 def _attention(x, cfg, seq_len, p, window):
     rotate = bool(window) or cfg.rope_global
     width = cfg.num_heads * cfg.head_dim
@@ -210,12 +331,44 @@ def _attention(x, cfg, seq_len, p, window):
             param_attr=_attr(None))
     ctx = layers.sparse_attention(q, k, v, selection=sel,
                                   scale=cfg.head_dim ** -0.5, window=window)
-    ctx = layers.reshape(layers.transpose(ctx, perm=[0, 2, 1, 3]),
-                         [-1, seq_len, width])
-    if cfg.attn_gate:
-        ctx = layers.elementwise_mul(
-            ctx, layers.sigmoid(_proj(x, width, f"{p}_gate_w")))
-    return _proj(ctx, cfg.hidden_size, f"{p}_o_w")
+    return _gated_out(ctx, x, cfg, seq_len, p)
+
+
+def _latent_attention(x, cfg, seq_len, p):
+    """Attention whose keys and values come from a normed low-rank latent:
+    every head's key is an unrotated part of its own beside ONE rotated
+    part that all heads share; the query has the same two parts.  What is
+    the latent's own (its two products, its norm, the shared key spread
+    over the heads, the key's head norm and both partial rotaries) runs
+    under the name scope ``latent``."""
+    lat, heads = cfg.latent, cfg.num_heads
+
+    def rotated(t):
+        return layers.rotary_embedding(
+            t, theta=cfg.rope_theta, start=lat.nope, dims=lat.rope,
+            interleaved=lat.interleaved, inv_freq=lat.inv_freq)
+
+    q = _norm(layers.reshape(_proj(x, heads * cfg.head_dim, f"{p}_q_w"),
+                             [-1, seq_len, heads, cfg.head_dim]),
+              cfg, f"{p}_q_norm")
+    with fluid.name_scope("latent"):
+        q = layers.transpose(rotated(q), perm=[0, 2, 1, 3])
+        c, kr = layers.split(_proj(x, lat.rank + lat.rope, f"{p}_kva_w"),
+                             [lat.rank, lat.rope], dim=-1)
+        kv = layers.reshape(
+            _proj(_norm(c, cfg, f"{p}_kv_norm"),
+                  heads * (lat.nope + lat.value), f"{p}_kvb_w"),
+            [-1, seq_len, heads, lat.nope + lat.value])
+        k_nope, v = layers.split(kv, [lat.nope, lat.value], dim=-1)
+        kr = layers.expand(layers.reshape(kr, [-1, seq_len, 1, lat.rope]),
+                           [1, 1, heads, 1])
+        k = rotated(_norm(layers.concat([k_nope, kr], axis=-1), cfg,
+                          f"{p}_k_norm"))
+        k = layers.transpose(k, perm=[0, 2, 1, 3])
+        v = layers.transpose(v, perm=[0, 2, 1, 3])
+    ctx = layers.sparse_attention(
+        q, k, v, scale=lat.scale or cfg.head_dim ** -0.5)
+    return _gated_out(ctx, x, cfg, seq_len, p)
 
 
 def _short_conv(x, cfg, p):
@@ -234,10 +387,10 @@ def _feed_forward(x, cfg, width, p):
     return _proj(h, cfg.hidden_size, f"{p}_w2")
 
 
-def _experts(x, cfg, i, routers):
-    """Layer ``i``'s routed share [beside the shared expert]; a router with
-    a selection bias adds its (layer, bias, counts) to ``routers``."""
-    p = f"l{i}"
+def _experts(x, cfg, p, routers):
+    """The routed share of the layer whose parameters start with ``p``
+    [beside the shared expert]; a router with a selection bias adds its
+    (name scope, bias, counts) to ``routers``."""
     shared = _feed_forward(x, cfg, cfg.shared_width, f"{p}_shared") \
         if cfg.shared_width else None
     out = layers.moe_experts(
@@ -248,63 +401,123 @@ def _experts(x, cfg, i, routers):
         norm_eps=cfg.route_norm_eps, route_scale=cfg.route_scale)
     if cfg.route_bias_coeff:
         out, bias, counts = out
-        routers.append((i, bias, counts))
+        routers.append((fluid.framework.current_name_scope(), bias, counts))
     return out if shared is None else layers.elementwise_add(shared, out)
+
+
+def _sub_block(stream, cfg, make):
+    """``(s_{j-2}, s_{j-1}) -> (s_{j-1}, s_j)`` with ``s_j = s_{j-1} +
+    make(the stream the residual rule reads)``."""
+    before, h = stream
+    y = make(before if cfg.residual == "farskip" else h)
+    return h, layers.elementwise_add(h, y)
+
+
+def _block(stream, cfg, seq_len, p, scope, mixer, window, dense, routers):
+    """One layer's two sub-blocks, under ``<scope>.mixer`` and
+    ``<scope>.ffn``; its parameters start with ``p``."""
+    def mix(x):
+        if mixer == "conv":
+            y = _short_conv(_norm(x, cfg, f"{p}_conv_norm"), cfg, p)
+        elif mixer == "latent":
+            y = _latent_attention(_norm(x, cfg, f"{p}_attn_norm"), cfg,
+                                  seq_len, p)
+        else:
+            y = _attention(_norm(x, cfg, f"{p}_attn_norm"), cfg, seq_len,
+                           p, window)
+        return _norm(y, cfg, f"{p}_post_attn_norm") if cfg.post_norms else y
+
+    def feed(x):
+        if dense:
+            f = _feed_forward(_norm(x, cfg, f"{p}_mlp_norm"), cfg,
+                              cfg.dense_width, f"{p}_mlp")
+        else:
+            f = _experts(_norm(x, cfg, f"{p}_moe_norm"), cfg, p, routers)
+        return _norm(f, cfg, f"{p}_post_mlp_norm") if cfg.post_norms else f
+
+    observe.registry().inc("models.decoder.blocks", labels={
+        "mixer": mixer, "residual": cfg.residual,
+        "where": "mtp" if scope == "mtp" else "trunk"})
+    with fluid.name_scope(f"{scope}.mixer"):
+        stream = _sub_block(stream, cfg, mix)
+    with fluid.name_scope(f"{scope}.ffn"):
+        return _sub_block(stream, cfg, feed)
+
+
+def _head_loss(h, cfg, norm_name, labels):
+    """(logits, mean cross-entropy) of the one head on ``h``."""
+    h = _norm(h, cfg, norm_name)
+    if cfg.tie_head:
+        logits = layers.matmul(
+            h, fluid.default_main_program().global_block().var("tok_emb"),
+            transpose_y=True)
+    else:
+        logits = _proj(h, cfg.vocab_size, "lm_head_w")
+    return logits, layers.mean(
+        layers.softmax_with_cross_entropy(logits, labels))
+
+
+def _embed(ids, cfg):
+    h = layers.embedding(ids, size=[cfg.vocab_size, cfg.hidden_size],
+                         param_attr=_attr("tok_emb"))
+    if cfg.embed_scale != 1.0:
+        h = layers.scale(h, scale=float(cfg.embed_scale))
+    return h
+
+
+def _multi_token(h, loss, cfg, seq_len, labels, routers):
+    """``loss`` with the module's share added: the trunk's last stream
+    merged with the NEXT token's embedding (``labels``, through the same
+    table), one more routed block, the same head, against ``labels2``: the
+    token after the next."""
+    labels2 = layers.data(name="labels2", shape=[seq_len, 1], dtype="int64")
+    with fluid.name_scope("mtp.merge"):
+        merged = layers.concat([_norm(h, cfg, "mtp_h_norm"),
+                                _norm(_embed(labels, cfg), cfg,
+                                      "mtp_e_norm")], axis=-1)
+        # the stream stays float32 under keep-low AMP, as the trunk's does
+        u = layers.cast(_proj(merged, cfg.hidden_size, "mtp_merge_w"),
+                        "float32")
+    _, u = _block((u, u), cfg, seq_len, "mtp", "mtp", cfg.mtp_mixer(), 0,
+                  False, routers)
+    with fluid.name_scope("mtp.head"):
+        more = _head_loss(u, cfg, "mtp_norm", labels2)[1]
+        return layers.elementwise_add(
+            loss, layers.scale(more, scale=float(cfg.mtp_weight)))
 
 
 def _forward(cfg, seq_len):
     """Named for the device trace (``fluid.name_scope``): ``embed``,
-    ``layer<i>.mixer`` (the attention of either kind with its indexer, or
+    ``layer<i>.mixer`` (the attention of any kind with its indexer, or
     the short convolution, with projections, norms, gate and the residual
-    add), ``layer<i>.ffn`` (dense or shared feed-forward, router and
-    routed experts, likewise) and ``head`` (final norm, product, loss)."""
+    add; what only a latent mixer has beneath it as ``.latent``),
+    ``layer<i>.ffn`` (dense or shared feed-forward, router and routed
+    experts, likewise), ``head`` (final norm, product, loss) and, for the
+    multi-token module, ``mtp.merge``, ``mtp.mixer``, ``mtp.ffn``,
+    ``mtp.head``."""
     tokens = layers.data(name="tokens", shape=[seq_len], dtype="int64")
     labels = layers.data(name="labels", shape=[seq_len, 1], dtype="int64")
     with fluid.name_scope("embed"):
-        h = layers.embedding(tokens, size=[cfg.vocab_size, cfg.hidden_size],
-                             param_attr=_attr("tok_emb"))
-        if cfg.embed_scale != 1.0:
-            h = layers.scale(h, scale=float(cfg.embed_scale))
+        h = _embed(tokens, cfg)
     routers = []
+    stream = (h, h)
     for i in range(cfg.num_layers):
-        p = f"l{i}"
-        with fluid.name_scope(f"layer{i}.mixer"):
-            if cfg.layer_mixer(i) == "conv":
-                y = _short_conv(_norm(h, cfg, f"{p}_conv_norm"), cfg, p)
-            else:
-                y = _attention(_norm(h, cfg, f"{p}_attn_norm"), cfg,
-                               seq_len, p, cfg.layer_window(i))
-            if cfg.post_norms:
-                y = _norm(y, cfg, f"{p}_post_attn_norm")
-            h = layers.elementwise_add(h, y)
-        with fluid.name_scope(f"layer{i}.ffn"):
-            if cfg.layer_is_dense(i):
-                f = _feed_forward(_norm(h, cfg, f"{p}_mlp_norm"), cfg,
-                                  cfg.dense_width, f"{p}_mlp")
-            else:
-                f = _experts(_norm(h, cfg, f"{p}_moe_norm"), cfg, i,
-                             routers)
-            if cfg.post_norms:
-                f = _norm(f, cfg, f"{p}_post_mlp_norm")
-            h = layers.elementwise_add(h, f)
+        stream = _block(stream, cfg, seq_len, f"l{i}", f"layer{i}",
+                        cfg.layer_mixer(i), cfg.layer_window(i),
+                        cfg.layer_is_dense(i), routers)
     with fluid.name_scope("head"):
-        h = _norm(h, cfg, "final_norm")
-        if cfg.tie_head:
-            logits = layers.matmul(
-                h,
-                fluid.default_main_program().global_block().var("tok_emb"),
-                transpose_y=True)
-        else:
-            logits = _proj(h, cfg.vocab_size, "lm_head_w")
-        loss = layers.mean(
-            layers.softmax_with_cross_entropy(logits, labels))
+        logits, loss = _head_loss(stream[1], cfg, "final_norm", labels)
+    if cfg.mtp_depth:
+        loss = _multi_token(stream[1], loss, cfg, seq_len, labels, routers)
     return tokens, labels, loss, logits, routers
 
 
 def forward(cfg, seq_len):
     """Data layers, logits and the mean next-token cross-entropy.  Returns
     (tokens, labels, loss, logits); ``labels[b, t]`` is the token that
-    follows ``tokens[b, t]``."""
+    follows ``tokens[b, t]``.  With the multi-token module the program
+    also reads ``labels2`` (the token after that) and ``loss`` holds the
+    module's share."""
     return _forward(cfg, seq_len)[:4]
 
 
@@ -317,7 +530,7 @@ def build(cfg=None, seq_len=64, lr=1e-4, beta1=0.9, beta2=0.95,
     tokens, labels, loss, _, routers = _forward(cfg, seq_len)
     fluid.optimizer.Adam(learning_rate=lr, beta1=beta1, beta2=beta2,
                          epsilon=epsilon).minimize(loss)
-    for i, bias, counts in routers:
-        with fluid.name_scope(f"layer{i}.ffn"):
+    for scope, bias, counts in routers:
+        with fluid.name_scope(scope):
             layers.moe_bias_update(bias, counts, cfg.route_bias_coeff)
     return tokens, labels, loss

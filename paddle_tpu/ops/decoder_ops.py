@@ -53,12 +53,30 @@ def rms_norm_op(ctx):
     return {"Y": (y * scale.astype(jnp.float32)).astype(x.dtype)}
 
 
-def rotary(x, theta):
+def rotary(x, theta, start=0, dims=0, interleaved=False, inv_freq=None):
     """x: [B, T, H, D]; position t (the index along axis 1) rotates the
-    pair (i, i + D/2) by t * theta^(-2i/D): the rotate-half form."""
-    t, d = x.shape[1], x.shape[-1]
-    inv = jnp.float32(theta) ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    pair (i, i + n/2) of the ``n = dims`` columns from ``start`` on
+    (``dims`` 0: to the head's end) by ``t * theta^(-2i/n)``: the
+    rotate-half form.  ``interleaved``: the pair is (2i, 2i + 1) instead.
+    ``inv_freq``: the n/2 frequencies themselves, in ``theta``'s place (a
+    table that a scaling rule blended).  Columns outside the part pass."""
+    d = x.shape[-1]
+    n = dims or d - start
+    if (start, n) != (0, d):
+        part = rotary(x[..., start:start + n], theta, 0, 0, interleaved,
+                      inv_freq)
+        return jnp.concatenate(
+            [x[..., :start], part, x[..., start + n:]], -1)
+    t = x.shape[1]
+    inv = jnp.float32(theta) ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    if interleaved:
+        cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+        pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                         -1).reshape(x.shape).astype(x.dtype)
     cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[None, :, None]
     sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[None, :, None]
     xf = x.astype(jnp.float32)
@@ -66,9 +84,35 @@ def rotary(x, theta):
     return (xf * cos + jnp.concatenate([-x2, x1], -1) * sin).astype(x.dtype)
 
 
+def _rotary_attrs(ctx):
+    """``rotary``'s keyword arguments as the op states them; every default
+    is the whole head, rotate-half, theta's table."""
+    table = ctx.attr("inv_freq", None)
+    return dict(theta=ctx.attr("theta", 10000.0),
+                start=int(ctx.attr("start", 0)),
+                dims=int(ctx.attr("dims", 0)),
+                interleaved=bool(ctx.attr("interleaved", False)),
+                inv_freq=tuple(table) if table else None)
+
+
 @register_op("rotary_embedding")
 def rotary_embedding_op(ctx):
-    return {"Out": rotary(ctx.input("X"), ctx.attr("theta", 10000.0))}
+    x, how = ctx.input("X"), _rotary_attrs(ctx)
+    _count("ops.rotary.calls",
+           dims=how["dims"] or x.shape[-1] - how["start"],
+           pairing="interleaved" if how["interleaved"] else "half",
+           scaled=int(how["inv_freq"] is not None))
+    return {"Out": rotary(x, **how)}
+
+
+@register_grad("rotary_embedding")
+def rotary_embedding_grad(ctx):
+    """The rotation is orthogonal and has no parameter: the cotangent is
+    turned back by the same angles (the transpose, through ``jax.vjp``),
+    and the forward's lowering is not counted a second time."""
+    x, how = ctx.input("X"), _rotary_attrs(ctx)
+    _, vjp = jax.vjp(lambda a: rotary(a, **how), x)
+    return {"X@GRAD": vjp(ctx.input("Out@GRAD").astype(x.dtype))[0]}
 
 
 def _order_key(x):

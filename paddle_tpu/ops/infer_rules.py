@@ -491,10 +491,23 @@ def infer_rms_norm(op, ins):
 @register_infer("rotary_embedding")
 def infer_rotary_embedding(op, ins):
     x = _in(ins, "X")
-    if x is not None and (len(x[0]) != 4 or x[0][-1] % 2):
+    if x is None:
+        return {"Out": [x]}
+    start, dims = int(op.attr("start", 0)), int(op.attr("dims", 0))
+    head = x[0][-1] if len(x[0]) == 4 else 0
+    part = dims or head - start
+    if len(x[0]) != 4 or start < 0 or part < 2 or part % 2 \
+            or start + part > head:
         raise InferMismatch(
             f"rotary_embedding: {_names(op, 'X')} {list(x[0])} must be "
-            f"[batch, positions, heads, an even head width]")
+            f"[batch, positions, heads, an even head width]"
+            + (f", of which it turns the {part} columns from {start} on"
+               if start or dims else ""))
+    table = op.attr("inv_freq", None)
+    if table and len(table) != part // 2:
+        raise InferMismatch(
+            f"rotary_embedding: inv_freq has {len(table)} frequencies for "
+            f"the {part // 2} pairs of the {part} columns it turns")
     return {"Out": [x]}
 
 

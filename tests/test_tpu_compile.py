@@ -128,14 +128,15 @@ def _paged():
                 ((s, 1, n * ps), F32)]
 
 
-def _sparse_flash(selected, window=0, t=8192, hkv=4, d=128):
+def _sparse_flash(selected, window=0, t=8192, hkv=4, d=128, hq=32):
     """The decoder cells' attention (``keye_vl_2_0_30b_a3b``: one sequence
     of 8,192 tokens, 32 query heads over 4 key-value heads of width 128,
     bf16, an int8 selection; ``trinity_mini``: the same heads under a
     causal window of 2,048, and with none, at 6,144 tokens;
     ``lfm2_8b_a1b``: 32 query heads over 8 key-value heads of width 64,
-    half a lane row, plain causal at 8,192), forward and the two backward
-    kernels."""
+    half a lane row, plain causal at 8,192; ``instella_moe_16b_a3b``: 16
+    query heads each over its own key-value head of width 128, a group of
+    one, plain causal at 8,192), forward and the two backward kernels."""
 
     def fn(q, k, v, sel):
         def loss(q, k, v):
@@ -146,14 +147,15 @@ def _sparse_flash(selected, window=0, t=8192, hkv=4, d=128):
         return jax.grad(loss, (0, 1, 2))(q, k, v)
 
     kv = ((1, hkv, t, d), BF16)
-    return fn, [((1, 32, t, d), BF16), kv, kv, ((1, t, t), jnp.int8)]
+    return fn, [((1, hq, t, d), BF16), kv, kv, ((1, t, t), jnp.int8)]
 
 
 #: the decoder cells' grouped products: rows (tokens x top_k), hidden width,
 #: expert width, experts held (``chipbench/configs/<cell>/config.json``)
 GROUPED_CELLS = {"keye": (65536, 2048, 768, 16),
                  "trinity": (49152, 2048, 1024, 8),
-                 "lfm2": (32768, 2048, 1792, 8)}
+                 "lfm2": (32768, 2048, 1792, 8),
+                 "instella": (49152, 2048, 1408, 8)}
 
 
 def _grouped(cell, form, which):
@@ -186,6 +188,8 @@ CASES = {
     "sparse_flash_causal": (lambda: _sparse_flash(False), 3),
     "sparse_flash_causal_heads_of_64": (
         lambda: _sparse_flash(False, 0, 8192, 8, 64), 3),
+    "sparse_flash_causal_group_of_one": (
+        lambda: _sparse_flash(False, 0, 8192, 16, 128, 16), 3),
     "window_flash": (lambda: _sparse_flash(False, 2048, 6144), 3),
     "window_flash_global_layer": (lambda: _sparse_flash(False, 0, 6144), 3),
     "window_flash_four_windows": (lambda: _sparse_flash(False, 2048), 3),
@@ -318,7 +322,7 @@ def test_grouped_signatures_are_the_benchmarks(topo, monkeypatch, cell):
 #: a cell's routed layer: tokens a step, choices a token, routed experts
 #: (``GROUPED_CELLS`` has the rows, the widths and the experts held)
 GROUPED_LAYERS = {"keye": (8192, 8, 128), "trinity": (6144, 8, 128),
-                  "lfm2": (8192, 4, 32)}
+                  "lfm2": (8192, 4, 32), "instella": (8192, 6, 64)}
 
 
 @pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))
