@@ -18,7 +18,10 @@ through ``_count`` for every ``[N * top_k, D]`` row gather it traces: two
 ``pass="forward"`` for every trace of the layer's forward, of which
 ``moe_experts`` makes one and ``moe_experts_grad`` another that only its
 routing plan outlives, and three ``pass="backward"`` for every
-``moe_experts_grad`` lowered).
+``moe_experts_grad`` lowered;
+``ops.moe.column_tiles{kernel,width,tile,tiles,ragged}``, which
+``ops/pallas_grouped.py`` counts the same way for every grouped-product
+kernel call it traces: the column tile that product took).
 """
 
 from __future__ import annotations

@@ -27,6 +27,20 @@ is made ``COLUMN_CHUNK`` result columns at a time in a loop: Mosaic unrolls
 a product, and a body that held the whole tile's (three times over, once
 for each way to store it) added 92 MB of code to a step of 48 calls.
 
+The column tile covers the width in the FEWEST tiles that fit and need not
+divide it: at 1,408 = 11 x 128 the only divisor that fits is one lane row,
+and every product read its rows eleven times (PERF.md, Findings PR 42).
+The grid has ``cdiv(n, tn)`` column tiles and the last is ragged.  Pallas
+reads a block that runs past its array with unspecified values there and
+drops what is written there.  That is safe here ONLY because no value
+crosses columns: a result column is a function of its own weight (or
+cotangent) column, the selects and the float32 accumulator are column-wise,
+and nothing is reduced over a tile's width; a change that sums over a
+tile's columns has to mask them.  The last tile's chunk loop stops at the
+result's edge (``_chunks``), so nothing past it is multiplied either.
+``ops.moe.column_tiles{kernel,width,tile,tiles,ragged}`` counts the tile of
+every kernel call traced.
+
 Group boundaries come from a table made in ``jnp`` (``visits``; ``plan``
 makes it once for the calls that share their groups), the calls'
 scalar-prefetch operands: for each step of the grid's last dimension its
@@ -82,33 +96,47 @@ def supported(rows, weights) -> str:
     return ""
 
 
-def _chunk(tn):
-    """Columns of one product in a kernel's body: a divisor of the tile's."""
-    return math.gcd(tn, COLUMN_CHUNK)
+def _chunk(tn, n):
+    """Columns of one product in a kernel's body: a divisor of the tile's
+    and of what the LAST tile holds of a width ``n`` (all of it where the
+    tile divides ``n``), so that no chunk lies across the result's edge and
+    nothing past it is multiplied."""
+    return math.gcd(math.gcd(tn, n % tn), COLUMN_CHUNK)
 
 
 def tile(m, k, n, itemsize, transposed_result=False):
     """(row tile, column tile) of a product that contracts ``k`` whole and
     gives a result ``n`` wide (``transposed_result``: ``[k, n]`` a group
-    from ``[m, k]`` and ``[m, n]`` rows).  The widest column tile, a
-    multiple of the lane width that divides ``n``, that fits
-    ``VMEM_BUDGET``: two buffers of every block that moves, the float32
-    product of one column chunk, and for the transposed result its float32
-    accumulator and the masked copy of the rows."""
+    from ``[m, k]`` and ``[m, n]`` rows).  The column tile, a multiple of
+    the lane width, that covers ``n`` in the FEWEST tiles that fit
+    ``VMEM_BUDGET``, whether or not it divides ``n`` (the last tile is then
+    ragged): two buffers of every block that moves, the float32 product of
+    one column chunk, and for the transposed result its float32 accumulator
+    and the masked copy of the rows.  The transposed result keeps the
+    widest tile that DIVIDES ``n`` wherever one of two lane rows or more
+    fits, and takes the ragged one only where the divisors leave a single
+    lane row: its accumulator grows with the tile, and at 1,024 to 2,048
+    columns three to six ragged tiles of 384 or 768 ran 8 to 10% slower on
+    the chip than four to eight that divide (PERF.md, Findings PR 42)."""
     tm = min(ROW_TILE, m)
-    for parts in range(1, n // LANE + 1):
-        tn = n // parts
-        if n % parts or tn % LANE:
-            continue
-        width = _chunk(tn)
+    lanes = n // LANE
+
+    def fits(tn):
+        width = _chunk(tn, n)
         blocks = 2 * itemsize * (tm * k + k * tn + tm * tn)
         if transposed_result:
             work = 4 * k * tn + itemsize * tm * k + 4 * k * width
         else:
             work = (4 + itemsize) * tm * width
-        if blocks + work <= VMEM_BUDGET:
-            return tm, tn
-    return tm, LANE
+        return blocks + work <= VMEM_BUDGET
+
+    fitting = [tn for tn in (LANE * pl.cdiv(lanes, parts)
+                             for parts in range(1, lanes + 1)) if fits(tn)]
+    if transposed_result:
+        dividing = next((tn for tn in fitting if n % tn == 0), LANE)
+        if dividing > LANE:
+            return tm, dividing
+    return tm, fitting[0] if fitting else LANE
 
 
 def visits(sizes, m, tm, empty_groups=False):
@@ -168,16 +196,27 @@ def _columns(j, width):
     return pl.ds(pl.multiple_of(j * width, width), width)
 
 
+def _chunks(tn, n):
+    """(columns of one product, products) of this grid step's column tile:
+    in the last tile of a width ``n`` that the tile does not divide, only
+    the chunks that hold a column of the result."""
+    width = _chunk(tn, n)
+    if n % tn == 0:
+        return width, tn // width
+    return width, jnp.where(pl.program_id(0) == n // tn,
+                            jnp.int32(n % tn // width),
+                            jnp.int32(tn // width))
+
+
 def _matmul_kernel(offsets, tiles, groups, rows_ref, w_ref, out_ref, *,
-                   transpose):
+                   transpose, n):
     # ONE product of COLUMN_CHUNK result columns in the body, looped over
     # the tile's columns (Mosaic unrolls a product: its code is what a call
     # adds to the program in HBM), selected into place whatever the step:
     # the group's rows from the product, the others from what an earlier
     # group of the same tile left, or zeros where this is the tile's first
     mine, live, same_tile = _step(offsets, tiles, groups, rows_ref.shape[0])
-    tn = out_ref.shape[1]
-    width = _chunk(tn)
+    width, n_chunks = _chunks(out_ref.shape[1], n)
 
     @pl.when(live)
     def _groups_rows():
@@ -192,7 +231,7 @@ def _matmul_kernel(offsets, tiles, groups, rows_ref, w_ref, out_ref, *,
             out_ref[:, cols] = jnp.where(mine, product, others)
             return carry
 
-        jax.lax.fori_loop(jnp.int32(0), jnp.int32(tn // width), chunk, 0)
+        jax.lax.fori_loop(jnp.int32(0), jnp.int32(n_chunks), chunk, 0)
 
     @pl.when(jnp.logical_and(jnp.logical_not(live),
                              jnp.logical_not(same_tile)))
@@ -201,13 +240,12 @@ def _matmul_kernel(offsets, tiles, groups, rows_ref, w_ref, out_ref, *,
 
 
 def _matmul_t_kernel(offsets, tiles, groups, rows_ref, cot_ref, out_ref,
-                     acc_ref, *, n_visits):
+                     acc_ref, *, n_visits, n):
     i32 = jnp.int32
     v = pl.program_id(1)
     g = groups[v]
     mine, live, _ = _step(offsets, tiles, groups, rows_ref.shape[0])
-    tn = out_ref.shape[2]
-    width = _chunk(tn)
+    width, n_chunks = _chunks(out_ref.shape[2], n)
 
     @pl.when(jnp.logical_or(v == 0, groups[jnp.maximum(v - 1, 0)] != g))
     def _init():
@@ -227,13 +265,21 @@ def _matmul_t_kernel(offsets, tiles, groups, rows_ref, cot_ref, out_ref,
                 preferred_element_type=jnp.float32)
             return carry
 
-        jax.lax.fori_loop(jnp.int32(0), jnp.int32(tn // width), chunk, 0)
+        jax.lax.fori_loop(jnp.int32(0), jnp.int32(n_chunks), chunk, 0)
 
     @pl.when(jnp.logical_or(
         v == n_visits - 1,
         groups[jnp.minimum(v + 1, i32(n_visits - 1))] != g))
     def _flush():
         out_ref[0] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _count_tiles(kernel, m, k, n, itemsize):
+    from .decoder_ops import _count
+
+    tn = tile(m, k, n, itemsize, kernel == "grouped_matmul_t")[1]
+    _count("ops.moe.column_tiles", kernel=kernel, width=n, tile=tn,
+           tiles=pl.cdiv(n, tn), ragged=int(n % tn > 0))
 
 
 def _resolve(interpret):
@@ -266,6 +312,8 @@ def grouped_matmul(rows, weights, sizes, transpose=False, interpret=None,
     """``rows[group g's rows] @ weights[g]`` (``transpose``: ``@
     weights[g].T``) for every group, rows past the last group zeros."""
     m = rows.shape[0]
+    _count_tiles("grouped_matmul", *rows.shape,
+                 weights.shape[1 if transpose else 2], rows.dtype.itemsize)
     table = visits(sizes, m, min(ROW_TILE, m)) if plan is None else plan[0]
     return _matmul(rows, weights, table, transpose, _resolve(interpret))
 
@@ -274,6 +322,8 @@ def grouped_matmul_t(rows, cot, sizes, interpret=None, plan=None):
     """[G, K, N]: for every group ``rows[its rows].T @ cot[its rows]``,
     zeros for a group of no rows."""
     m = rows.shape[0]
+    _count_tiles("grouped_matmul_t", *rows.shape, cot.shape[1],
+                 rows.dtype.itemsize)
     table = visits(sizes, m, min(ROW_TILE, m), empty_groups=True) \
         if plan is None else plan[1]
     return _matmul_t(rows, cot, table, _resolve(interpret))
@@ -298,10 +348,11 @@ def _matmul(rows, weights, table, transpose, interpret):
         return block_index(tiles[v], j)
 
     return pl.pallas_call(
-        functools.partial(_matmul_kernel, transpose=transpose),
+        functools.partial(_matmul_kernel, transpose=transpose, n=n),
         out_shape=jax.ShapeDtypeStruct((m, n), rows.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(n // tn, table[1].shape[0]),
+            num_scalar_prefetch=3,
+            grid=(pl.cdiv(n, tn), table[1].shape[0]),
             in_specs=[pl.BlockSpec((tm, k), _row_block),
                       pl.BlockSpec((1, tn, k) if transpose else (1, k, tn),
                                    weight_block)],
@@ -327,11 +378,11 @@ def _matmul_t(rows, cot, table, interpret):
         return block_index(groups[v], 0, j)
 
     return pl.pallas_call(
-        functools.partial(_matmul_t_kernel, n_visits=n_visits),
+        functools.partial(_matmul_t_kernel, n_visits=n_visits, n=n),
         out_shape=jax.ShapeDtypeStruct((table[0].shape[0] - 1, k, n),
                                        rows.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(n // tn, n_visits),
+            num_scalar_prefetch=3, grid=(pl.cdiv(n, tn), n_visits),
             in_specs=[pl.BlockSpec((tm, k), _row_block),
                       pl.BlockSpec((tm, tn), cot_block)],
             out_specs=pl.BlockSpec((1, k, tn), out_block),

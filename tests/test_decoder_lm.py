@@ -1128,6 +1128,55 @@ def test_a_layer_and_its_backward_call_eight_products_and_three_gradients():
         "grouped_matmul": 8, "grouped_matmul_t": 3}
 
 
+@pytest.mark.parametrize("path", ["pallas", "ragged_dot"])
+def test_every_kernel_call_traced_counts_its_column_tile(monkeypatch, path):
+    """``ops.moe.column_tiles{kernel,width,tile,tiles,ragged}``: one for
+    each kernel call traced (eight products and three weights' gradients in
+    a layer's backward), with the tile ``pallas_grouped.tile`` gives; at a
+    budget that holds 384 of 640 = 5 x 128 columns the tile is ragged (384
+    + 256) for the products and the weights' gradients alike, since no
+    divisor but one lane row fits; nothing on the ``ragged_dot`` path."""
+    from paddle_tpu.ops import pallas_grouped
+
+    n, d, f, routed, held, k = 256, 128, 640, 8, 3, 2
+    rng = np.random.RandomState(24)
+    x = jnp.asarray(rng.randn(n, d), jnp.float32)
+    wr = jnp.asarray(rng.randn(d, routed), jnp.float32)
+    w1, w3 = (jnp.asarray(0.1 * rng.randn(held, d, f), jnp.float32)
+              for _ in range(2))
+    w2 = jnp.asarray(0.1 * rng.randn(held, f, d), jnp.float32)
+    monkeypatch.setattr(pallas_grouped, "VMEM_BUDGET", 3 << 20)
+    if path == "ragged_dot":
+        monkeypatch.setattr(pallas_grouped, "supported", lambda *a: "off")
+    assert moe.product_path(x, w1, w2, k) == path
+
+    def layer(*a):
+        return jnp.sum(moe.routed_experts(*a, top_k=k, expert_offset=2))
+
+    jax.make_jaxpr(jax.grad(layer, range(5)))(x, wr, w1, w3, w2)
+    if path == "ragged_dot":
+        assert counters("ops.moe.column_tiles") == {}
+        return
+    m = n * k
+    tiles = {(kernel, width): pallas_grouped.tile(
+                 m, other, width, 4, kernel == "grouped_matmul_t")[1]
+             for kernel in ("grouped_matmul", "grouped_matmul_t")
+             for width, other in ((f, d), (d, f))}
+    assert tiles == {("grouped_matmul", f): 384, ("grouped_matmul", d): 128,
+                     ("grouped_matmul_t", f): 384,
+                     ("grouped_matmul_t", d): 128}
+    # 640 wide: xs @ w1, xs @ w3 forward and again backward, dy_s @ w2^T;
+    # 128 wide: h @ w2, da @ w1^T, db @ w3^T
+    calls = {("grouped_matmul", f): 5, ("grouped_matmul", d): 3,
+             ("grouped_matmul_t", f): 2, ("grouped_matmul_t", d): 1}
+    assert counters("ops.moe.column_tiles") == {
+        f'ops.moe.column_tiles{{kernel="{kernel}",'
+        f'ragged="{int(width % tn > 0)}",tile="{tn}",'
+        f'tiles="{-(-width // tn)}",width="{width}"}}':
+        calls[kernel, width]
+        for (kernel, width), tn in tiles.items()}
+
+
 def test_the_training_step_scatters_no_row_under_the_expert_layer():
     """The tiny decoder's whole training step, lowered: under the expert
     layer's two ops (``moe_experts`` and ``moe_experts_grad`` in the

@@ -48,29 +48,26 @@ def weights_gradient(rows, cot, sizes):
     return lax.ragged_dot_general(rows, cot, sizes, dims)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("case", sorted(SIZES))
-@pytest.mark.parametrize("form", FORMS)
-def test_kernel_equals_xlas_grouped_product(form, case, dtype):
-    """Every form at every arrangement of groups; the rows past the last
-    group hold NaN in the operands and must come back as zeros (products)
-    or add nothing (the weights' gradient)."""
-    rows, cot, w = operands(dtype)
+def assert_equals_xlas_product(form, rows, other, case, dtype):
+    """One form over ``rows`` and its ``other`` operand (the weights, or
+    for the weights' gradient the cotangent) in the groups of ``case``; the
+    rows past the last group hold NaN in the operands and must come back
+    as zeros (products) or add nothing (the weights' gradient)."""
     sizes = jnp.asarray(SIZES[case], jnp.int32)
     total = sum(SIZES[case])
     zeroed = lambda a: a.at[total:].set(0)      # noqa: E731
     if form == "plain":
-        got = pg.grouped_matmul(poisoned(rows, total), w, sizes)
-        want = zeroed(lax.ragged_dot(rows, w, sizes))
+        got = pg.grouped_matmul(poisoned(rows, total), other, sizes)
+        want = zeroed(lax.ragged_dot(rows, other, sizes))
     elif form == "transposed":
-        got = pg.grouped_matmul(poisoned(cot, total), w, sizes,
+        got = pg.grouped_matmul(poisoned(rows, total), other, sizes,
                                 transpose=True)
-        want = zeroed(lax.ragged_dot(cot, jnp.swapaxes(w, 1, 2), sizes))
+        want = zeroed(lax.ragged_dot(rows, jnp.swapaxes(other, 1, 2),
+                                     sizes))
     else:
         got = pg.grouped_matmul_t(poisoned(rows, total),
-                                  poisoned(cot, total), sizes)
-        want = weights_gradient(zeroed(rows), zeroed(cot), sizes)
+                                  poisoned(other, total), sizes)
+        want = weights_gradient(zeroed(rows), zeroed(other), sizes)
     assert got.dtype == dtype and got.shape == want.shape
     got, want = (np.asarray(a, np.float32) for a in (got, want))
     assert np.isfinite(got).all()
@@ -79,6 +76,18 @@ def test_kernel_equals_xlas_grouped_product(form, case, dtype):
                                atol=tol * max(np.abs(want).max(), 1.0))
     if form != "weights_gradient":
         assert not got[total:].any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(SIZES))
+@pytest.mark.parametrize("form", FORMS)
+def test_kernel_equals_xlas_grouped_product(form, case, dtype):
+    """Every form at every arrangement of groups."""
+    rows, cot, w = operands(dtype)
+    assert_equals_xlas_product(
+        form, *{"plain": (rows, w), "transposed": (cot, w),
+                "weights_gradient": (rows, cot)}[form], case, dtype)
 
 
 @pytest.mark.parametrize("empty_groups", [False, True])
@@ -112,31 +121,107 @@ def test_visits_name_every_tile_of_every_group_once(case, empty_groups):
         assert {t for t, _ in seen} == set(range(M // tm))
 
 
-#: the cells' products (BENCHMARK.json's three decoder cells: rows x
+#: the cells' products (BENCHMARK.json's four decoder cells: rows x
 #: [experts held, hidden, expert width]) and the column tile each form
-#: takes: the whole contraction, and of the result's width what fits the
-#: budget (Mosaic's default VMEM, no limit stated)
+#: takes: the whole contraction, and of the result's width the fewest tiles
+#: that fit the budget (Mosaic's default VMEM, no limit stated), ragged at
+#: Instella's 1,408 = 11 x 128 (768 + 640; 384 x 3 + 256)
 CELLS = {"keye": (65536, 2048, 768), "trinity": (49152, 2048, 1024),
-         "lfm2": (32768, 2048, 1792)}
+         "lfm2": (32768, 2048, 1792), "instella": (49152, 2048, 1408)}
 TILES = {("keye", "up"): 768, ("keye", "down"): 2048,
          ("keye", "up_t"): 384, ("keye", "down_t"): 1024,
          ("trinity", "up"): 512, ("trinity", "down"): 1024,
          ("trinity", "up_t"): 256, ("trinity", "down_t"): 512,
          ("lfm2", "up"): 896, ("lfm2", "down"): 1024,
-         ("lfm2", "up_t"): 256, ("lfm2", "down_t"): 256}
+         ("lfm2", "up_t"): 256, ("lfm2", "down_t"): 256,
+         ("instella", "up"): 768, ("instella", "down"): 1024,
+         ("instella", "up_t"): 384, ("instella", "down_t"): 512}
+
+
+def vmem_bytes(tm, k, tn, n, itemsize, transposed_result):
+    """What a grid step keeps in VMEM, as ``pg.tile``'s docstring counts
+    it: every block that moves twice, one chunk's float32 product (and its
+    cast), and for the weights' gradient the accumulator, the masked rows
+    and one chunk's product."""
+    width = pg._chunk(tn, n)
+    blocks = 2 * itemsize * (tm * k + k * tn + tm * tn)
+    if transposed_result:
+        return blocks + 4 * k * tn + itemsize * tm * k + 4 * k * width
+    return blocks + (4 + itemsize) * tm * width
 
 
 @pytest.mark.parametrize("cell,form", sorted(TILES))
 def test_tile_is_derived_from_the_shapes_and_the_budget(cell, form):
     m, d, f = CELLS[cell]
     k, n = (d, f) if form.startswith("up") else (f, d)
-    tm, tn = pg.tile(m, k, n, 2, transposed_result=form.endswith("_t"))
+    transposed = form.endswith("_t")
+    tm, tn = pg.tile(m, k, n, 2, transposed_result=transposed)
     assert (tm, tn) == (512, TILES[cell, form])
-    assert n % tn == 0 and tn % pg.LANE == 0
-    # the blocks that move, twice over, fit under Mosaic's default 16 MiB
-    assert 2 * 2 * (tm * k + k * tn + tm * tn) <= pg.VMEM_BUDGET < 16 << 20
+    assert tn % pg.LANE == 0
+    assert vmem_bytes(tm, k, tn, n, 2, transposed) <= pg.VMEM_BUDGET \
+        < 16 << 20
+    # one column tile fewer does not fit the budget; the weights' gradient
+    # may stay at a tile that divides the width instead, of two lane rows
+    # or more
+    tiles = -(-n // tn)
+    if tiles > 1 and not (transposed and n % tn == 0 and tn > pg.LANE):
+        wider = pg.LANE * -(-(n // pg.LANE) // (tiles - 1))
+        assert vmem_bytes(tm, k, wider, n, 2, transposed) > pg.VMEM_BUDGET
+    # the chunk of one product divides the tile and what the last tile
+    # holds of the width: nothing past the result's edge is multiplied
+    assert tn % pg._chunk(tn, n) == 0 and (n % tn) % pg._chunk(tn, n) == 0
     # float32 operands take twice the room: never a wider tile
-    assert pg.tile(m, k, n, 4, form.endswith("_t"))[1] <= tn
+    assert pg.tile(m, k, n, 4, transposed)[1] <= tn
+
+
+def test_the_weights_gradient_is_ragged_only_where_no_divisor_fits():
+    """Trinity's and LFM2's weights' gradients would fit 384 x 3 and 384 x
+    5 ragged; they keep the dividing 256 (faster on the chip, PERF.md
+    Findings PR 42).  At 11, 13, 17, 19 and 23 lane rows every divisor but
+    one lane row is over the budget, and the ragged tile is taken."""
+    for n, want in ((1024, 256), (1792, 256), (1408, 384), (1664, 384),
+                    (2176, 384), (2432, 384), (2944, 384)):
+        assert pg.tile(49152, 2048, n, 2, transposed_result=True)[1] == want
+    # the products take the fewest tiles whatever divides
+    for n, want in ((1408, 768), (1664, 896), (2816, 768)):
+        assert pg.tile(49152, 2048, n, 2)[1] == want
+
+
+#: a result's width that no fitting tile divides, at shapes small enough to
+#: interpret: (contraction, width, column tile).  The budget is lowered to
+#: exactly what that tile takes
+RAGGED = {"640_as_384_and_256": (256, 640, 384),
+          # a tile of 512 alone is one chunk: the last tile holds less
+          "896_as_512_and_less_than_a_chunk": (128, 896, 512),
+          "1408_as_three_of_384_and_256": (128, 1408, 384)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["short", "uneven"])
+@pytest.mark.parametrize("shape", sorted(RAGGED))
+@pytest.mark.parametrize("form", FORMS)
+def test_a_ragged_last_column_tile_equals_xlas_grouped_product(
+        monkeypatch, form, shape, case, dtype):
+    """The column tile does not divide the width: the last tile runs past
+    the result (and past the weights or the cotangent), whatever is read
+    there is dropped, and the chunk loop stops at the edge.  ``short``: an
+    empty group, a boundary inside a row tile and a row tile past the last
+    group (zeros), its operands NaN there."""
+    c, n, tn = RAGGED[shape]
+    itemsize = jnp.dtype(dtype).itemsize
+    transposed = form == "weights_gradient"
+    monkeypatch.setattr(pg, "VMEM_BUDGET",
+                        vmem_bytes(512, c, tn, n, itemsize, transposed))
+    assert pg.tile(M, c, n, itemsize, transposed) == (512, tn)
+    assert n % tn and pg._chunk(tn, n) <= n % tn
+    rng = np.random.RandomState(1)
+    rows = jnp.asarray(rng.randn(M, c), dtype)
+    other = {"plain": (len(SIZES[case]), c, n),
+             "transposed": (len(SIZES[case]), n, c),
+             "weights_gradient": (M, n)}[form]
+    assert_equals_xlas_product(form, rows, jnp.asarray(rng.randn(*other),
+                                                       dtype), case, dtype)
 
 
 @pytest.mark.parametrize("rows,weights,dtype,why", [

@@ -156,6 +156,11 @@ GROUPED_CELLS = {"keye": (65536, 2048, 768, 16),
                  "trinity": (49152, 2048, 1024, 8),
                  "lfm2": (32768, 2048, 1792, 8),
                  "instella": (49152, 2048, 1408, 8)}
+#: no cell's: an expert width of 13 lane rows, whose only dividing tile is
+#: one lane row as at Instella's 11 (ragged tiles of 896 + 768 and 384 x 4 +
+#: 128 where the result is that wide: ``plain`` and ``weights_gradient`` of
+#: ``up``, ``transposed`` of ``down``); compiled alone, not as a layer
+GROUPED_WIDTHS = {**GROUPED_CELLS, "width_1664": (49152, 2048, 1664, 8)}
 
 
 def _grouped(cell, form, which):
@@ -168,7 +173,7 @@ def _grouped(cell, form, which):
     no ``vmem_limit_bytes``: a step with a larger one hung on the chip), the
     column chunks are dynamic slices of the lane dimension, and Mosaic
     wants the scalar-prefetch tables in 32 bits."""
-    m, d, f, g = GROUPED_CELLS[cell]
+    m, d, f, g = GROUPED_WIDTHS[cell]
     k, n = (d, f) if which == "up" else (f, d)
     rows, out, w = ((m, k), BF16), ((m, n), BF16), ((g, k, n), BF16)
     sizes = ((g,), I32)
@@ -215,7 +220,7 @@ CASES = {
     "paged_step": (_paged, 1),
     **{f"grouped_{form}_{cell}_{which}": (
         functools.partial(_grouped, cell, form, which), 1)
-       for cell in GROUPED_CELLS for which in ("up", "down")
+       for cell in GROUPED_WIDTHS for which in ("up", "down")
        for form in ("plain", "transposed", "weights_gradient")},
 }
 
@@ -317,6 +322,68 @@ def test_grouped_signatures_are_the_benchmarks(topo, monkeypatch, cell):
         module = load("kernels", call.kernel)
         assert module.KERNEL == call.kernel
         assert module.flops(call.operands, call.results) == 2.0 * m * d * f
+
+
+#: the column tile of each product, as ``tests/test_pallas_grouped.py``'s
+#: ``TILES`` has them (``up``: result as wide as the experts, ``_t``: the
+#: weights' gradient).  Keye's all divide their widths and are the parent's:
+#: its step must lower byte-equal across a change of the tile rule
+GROUPED_TILES = {
+    "keye": {"up": 768, "down": 2048, "up_t": 384, "down_t": 1024},
+    "trinity": {"up": 512, "down": 1024, "up_t": 256, "down_t": 512},
+    "lfm2": {"up": 896, "down": 1024, "up_t": 256, "down_t": 256},
+    "instella": {"up": 768, "down": 1024, "up_t": 384, "down_t": 512}}
+
+
+def _mosaic_bodies(stablehlo_text):
+    """Every ``tpu_custom_call``'s kernel as MLIR text without source
+    locations (the ``body`` of its ``backend_config`` is bytecode that
+    carries them)."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    ctx = mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        return [ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+                    enable_debug_info=False)
+                for body in re.findall(r'body\\22: \\22([^\\]*)\\22',
+                                       stablehlo_text)]
+
+
+@pytest.mark.parametrize("form,which,key", [
+    ("plain", "up", "up"), ("plain", "down", "down"),
+    ("transposed", "up", "down"), ("transposed", "down", "up"),
+    ("weights_gradient", "up", "up_t"), ("weights_gradient", "down",
+                                         "down_t")])
+@pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))
+def test_grouped_grid_is_the_tiles(topo, cell, form, which, key):
+    """The kernel each of a cell's six forms lowers to: ``cdiv(width,
+    tile)`` column tiles by ``tiles + G - 1`` steps, the result's block one
+    row tile by one column tile, ragged or not."""
+    import re
+
+    m, d, f, g = GROUPED_CELLS[cell]
+    tn = GROUPED_TILES[cell][key]
+    width = f if key.startswith("up") else d
+    fn, shapes = _grouped(cell, form, which)
+    chip = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, t, sharding=chip) for s, t in shapes]
+    body, = _mosaic_bodies(jax.jit(fn).lower(*args).as_text())
+    ints = lambda found: [int(i) for i in found.split(",")]  # noqa: E731
+    assert ints(re.search(r"iteration_bounds = array<i64: ([\d, ]+)>",
+                          body).group(1)) == \
+        [-(-width // tn), m // pallas_grouped.ROW_TILE + g - 1]
+    blocks = [ints(b) for b in
+              re.findall(r"window_bounds = array<i64: ([\d, ]+)>", body)]
+    contraction = d if key.startswith("up") else f
+    assert blocks[-1] == ([1, contraction, tn] if form == "weights_gradient"
+                          else [pallas_grouped.ROW_TILE, tn])
 
 
 #: a cell's routed layer: tokens a step, choices a token, routed experts
