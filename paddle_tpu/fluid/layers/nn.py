@@ -34,7 +34,7 @@ __all__ = [
     "lod_reset", "prelu", "dice_loss", "log_loss", "huber_loss",
     "ring_attention", "moe_ffn", "gpipe_mlp_stack",
     "rms_norm", "rotary_embedding", "sparse_indexer", "sparse_attention",
-    "moe_experts", "moe_bias_update", "short_conv",
+    "moe_experts", "moe_bias_update", "short_conv", "gated_delta_rule",
     "kv_cache_update", "kv_cache_scatter", "token_select",
     "paged_attention", "spec_accept",
     "transformer_encoder_stack", "transformer_decoder_stack", "cos_sim",
@@ -1522,21 +1522,24 @@ def moe_bias_update(bias, counts, coeff, name=None):
     return bias
 
 
-def short_conv(input, taps, param_attr=None, name=None):
-    """A gated short convolution over the sequence (ops/decoder_ops.py
-    ``short_conv``), the token mixer of a layer without attention: for
+def short_conv(input, taps, param_attr=None, name=None, gated=True):
+    """A short convolution over the sequence (ops/decoder_ops.py
+    ``short_conv``): one causal filter of ``taps`` weights a channel
+    (``w`` [channels, taps], no bias), zero before position 0.  ``gated``
+    (the default), the token mixer of a layer without attention: for
     ``input`` = [B | C | u] ([batch, T, 3 * channels], three chunks in this
     order, as one projection makes them)
-    ``out[t] = C[t] * sum_j w[:, j] * (B * u)[t - (taps - 1) + j]`` with one
-    causal filter of ``taps`` weights a channel (``w`` [channels, taps], no
-    bias) and ``(B * u)[s] = 0`` for ``s < 0``.  Unlike ``row_conv`` it
-    looks back and never ahead, carries both gates, and takes a dense
+    ``out[t] = C[t] * sum_j w[:, j] * (B * u)[t - (taps - 1) + j]``.  Not
+    ``gated``: ``out = SiLU(filter(input))`` over ``input``'s own channels,
+    the form in front of a linear attention (``gated_delta_rule``).  Unlike
+    ``row_conv`` it looks back and never ahead and takes a dense
     [batch, T, ...] tensor: every row of the batch is a sequence of its
     own and nothing crosses from one to the next."""
     helper = LayerHelper("short_conv", **locals())
     dtype = helper.input_dtype()
-    channels = int(input.shape[-1]) // 3
-    if int(taps) < 1 or 3 * channels != int(input.shape[-1]):
+    width = int(input.shape[-1])
+    channels = width // 3 if gated else width
+    if int(taps) < 1 or (gated and 3 * channels != width):
         raise ValueError(f"short_conv: {taps} taps over an input "
                          f"{tuple(input.shape)} that is not 3 * channels "
                          f"wide")
@@ -1544,9 +1547,36 @@ def short_conv(input, taps, param_attr=None, name=None):
                                 shape=[channels, int(taps)], dtype=dtype)
     out = helper.create_variable_for_type_inference(dtype)
     out.shape = tuple(input.shape[:-1]) + (channels,)
+    # only what departs from the gated form is written into the op
     helper.append_op(type="short_conv",
                      inputs={"X": [input], "Filter": [w]},
-                     outputs={"Out": [out]})
+                     outputs={"Out": [out]},
+                     attrs=None if gated else {"gated": False})
+    return out
+
+
+def gated_delta_rule(q, k, v, g, beta, chunk=64, scale=None, norm_eps=0.0,
+                     name=None):
+    """The gated delta rule (ops/decoder_ops.py ``gated_delta_rule``,
+    ops/delta_rule.py), a linear attention: every value head keeps a
+    [dk, dv] state that each token decays by ``exp(g_t)``, corrects towards
+    its value along its key with the step ``beta_t`` and reads with its
+    query.  q, k: [B, T, Hk, dk]; v: [B, T, Hv, dv], Hv a multiple of Hk
+    (key head j serves the value heads ``j * Hv / Hk`` on); g (<= 0) and
+    beta: [B, T, Hv].  Returns [B, T, Hv, dv].  ``chunk``: the tokens worked
+    at once between two steps of the state; ``scale`` multiplies q (None:
+    ``dk ** -0.5``); ``norm_eps`` > 0 l2-norms q and k per head first.
+    Every row of the batch starts from a zero state."""
+    helper = LayerHelper("gated_delta_rule", **locals())
+    out = helper.create_variable_for_type_inference(helper.input_dtype("v"))
+    out.shape = tuple(v.shape)
+    attrs = {"chunk": int(chunk), "scale": float(scale or 0.0)}
+    if norm_eps:
+        attrs["norm_eps"] = float(norm_eps)
+    helper.append_op(
+        type="gated_delta_rule",
+        inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]},
+        outputs={"Out": [out]}, attrs=attrs)
     return out
 
 

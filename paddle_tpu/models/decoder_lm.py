@@ -1,9 +1,10 @@
 """A decoder-only language model from its sizes: residual blocks whose
 token mixer is grouped-query attention (over a learned per-query selection
 of keys, under a causal window, or plain causal; window layers beside
-global ones) or, layer by layer, a gated short convolution or attention
-whose keys and values come from a low-rank latent, and a feed-forward that
-is dense in the leading layers and elsewhere a routed expert layer of which
+global ones) or, layer by layer, a gated short convolution, attention
+whose keys and values come from a low-rank latent, or a gated delta rule
+(linear attention with a matrix state a head), and a feed-forward that is
+dense in the leading layers and elsewhere a routed expert layer of which
 this program holds a stated share, RMS norms, rotary positions, a head of
 its own or the embedding's transpose, next-token loss and, where the model
 has one, a multi-token module's loss beside it.
@@ -22,7 +23,8 @@ field turns on)::
     attention layer (every layer where mixers is None), down to ``y``:
     q  = a Wq, k = a Wk, v = a Wv  [g = a Wg]
     q  = RMSNorm_head(q), k = RMSNorm_head(k)        per head
-    window layer: q, k = RoPE(q, k); key s counts for query t iff
+    window layer: q, k = RoPE(q, k) [on the head's first rotary_dims
+                  columns only]; key s counts for query t iff
                   0 <= t - s < window
     global layer ((published index + 1) % global_every == 0, or every layer
                   where there is no window): q, k = RoPE(q, k) [not where
@@ -40,6 +42,19 @@ field turns on)::
                where L.interleaved, by the frequencies L.inv_freq
         o = concat_h softmax_{s <= t}(q_h k_h * L.scale) v_h
         y = (o [* sigmoid(g)]) Wo
+    delta layer (mixers[published index] == "delta"; R = cfg.delta, Hk key
+                 heads of dk, Hv value heads of dv):
+        [q | k | v | z] = a Wqkvz         Hk dk, Hk dk, Hv dv, Hv dv wide
+        [b | al] = a Wba                  Hv each, float32 from here on
+        [q | k | v] = SiLU(filter([q | k | v]))   one causal R.taps-tap
+                                          filter a channel, zero before 0
+        beta = sigmoid(b); g = -exp(A_log) * softplus(al + dt_bias)
+        q = l2norm(q) * dk ** -0.5, k = l2norm(k)      per head, eps 1e-6
+        per value head h (key head h // (Hv / Hk)), S_0 = 0 [dk, dv]:
+            S' = exp(g_t) S_{t-1};  u_t = beta_t (v_t - S'^T k_t)
+            S_t = S' + k_t u_t^T;   o_t = S_t^T q_t
+            (computed R.chunk tokens at a time: ops/delta_rule.py)
+        y = (RMSNorm_head(o) * SiLU(z)) Wout      one scale of dv
     x1 = x + y                      [post_norms: x + RMSNorm(y)]
     m  = RMSNorm(x1)
     dense layer (published index < dense_layers):
@@ -50,7 +65,8 @@ field turns on)::
         w_e = s_e [/ (sum_E s + route_norm_eps)] [* route_scale]
         f = [Shared(m) +] sum over e in E AND held here of
             w_e W2_e(silu(W1_e m) * W3_e m)
-        Shared: the dense feed-forward at width shared_width
+        Shared: the dense feed-forward at width shared_width [shared_gate:
+            times sigmoid(m w_sg), one number a token]
     x2 = x1 + f                     [post_norms: x1 + RMSNorm(f)]
     [residual "farskip": a sub-block reads the stream WITHOUT the sub-block
         just before it.  With s_0 = h0 = s_{-1} and the sub-blocks F_j
@@ -76,10 +92,11 @@ Parameters are created in a fixed order and named ``tok_emb``,
 o_w,post_attn_norm}`` (a conv layer: ``l<i>_{conv_norm,conv_in_w,conv_w,
 conv_out_w,post_attn_norm}`` and none of the others; a latent layer:
 ``l<i>_{attn_norm,q_w,q_norm,kva_w,kv_norm,kvb_w,k_norm,gate_w,o_w,
-post_attn_norm}``), then
+post_attn_norm}``; a delta layer: ``l<i>_{attn_norm,qkvz_w,ba_w,conv_w,
+dt_bias,a_log,delta_norm,o_w,post_attn_norm}``), then
 ``l<i>_{mlp_norm,mlp_w1,mlp_w3,mlp_w2}`` (dense)
-or ``l<i>_{moe_norm,shared_w1,shared_w3,shared_w2,router_w,w1,w3,w2}``
-(routed; ``l<i>_route_bias`` is no parameter), ``l<i>_post_mlp_norm``,
+or ``l<i>_{moe_norm,shared_w1,shared_w3,shared_w2,shared_gate_w,router_w,w1,
+w3,w2}`` (routed; ``l<i>_route_bias`` is no parameter), ``l<i>_post_mlp_norm``,
 ``final_norm``, ``lm_head_w`` (not with ``tie_head``), then the module's
 ``mtp_{h_norm,e_norm,merge_w}``, its block's as a routed layer's under
 ``mtp_`` for ``l<i>_``, and ``mtp_norm``; ``i`` counts the layers held,
@@ -97,7 +114,7 @@ from paddle_tpu.fluid import layers
 from paddle_tpu.fluid.param_attr import ParamAttr
 
 
-MIXERS = ("attention", "conv", "latent")
+MIXERS = ("attention", "conv", "latent", "delta")
 RESIDUALS = ("sequential", "farskip")
 
 
@@ -115,6 +132,23 @@ class Latent(NamedTuple):
     inv_freq: Optional[Tuple[float, ...]] = None
     interleaved: bool = False
     scale: float = 0.0
+
+
+class Delta(NamedTuple):
+    """What a ``delta`` mixer needs, none of it the model's attention
+    heads: its key heads (queries have as many) and value heads (a multiple
+    of them), the width of each, the taps of the causal filter in front of
+    the rule, and the tokens the rule works at once."""
+    key_heads: int
+    value_heads: int
+    key_dim: int
+    value_dim: int
+    taps: int = 4
+    chunk: int = 64
+
+
+DELTA_NORM_EPS = 1e-6   # the l2 norm of a delta mixer's queries and keys
+DT_BIAS_INIT = -3.0     # softplus(-3) = 0.049: a token forgets a twentieth
 
 
 def yarn_inv_freq(dims, base, factor, original_positions, beta_fast=32,
@@ -157,7 +191,8 @@ class Config:
                  router_score="softmax", route_norm_eps=0.0,
                  route_scale=1.0, route_bias_coeff=0.0, mixers=None,
                  conv_taps=0, tie_head=False, latent=None,
-                 residual="sequential", mtp_depth=0, mtp_weight=0.0):
+                 residual="sequential", mtp_depth=0, mtp_weight=0.0,
+                 delta=None, rotary_dims=0, shared_gate=False):
         if num_heads % num_kv_heads:
             raise ValueError(f"{num_heads} query heads do not group over "
                              f"{num_kv_heads} key-value heads")
@@ -192,6 +227,22 @@ class Config:
         elif "latent" in held or (mtp_depth and mixers
                                   and mixers[-1] == "latent"):
             raise ValueError("a latent layer needs the record `latent`")
+        if delta is not None:
+            delta = Delta(*delta)
+            if delta.value_heads % delta.key_heads or delta.taps < 1 \
+                    or delta.chunk < 1:
+                raise ValueError(
+                    f"{delta}: the value heads are a multiple of the key "
+                    "heads, and a filter and a chunk hold a token at least")
+        elif "delta" in held or (mtp_depth and mixers
+                                 and mixers[-1] == "delta"):
+            raise ValueError("a delta layer needs the record `delta`")
+        if rotary_dims % 2 or not 0 <= rotary_dims <= head_dim:
+            raise ValueError(f"rotary_dims {rotary_dims}: an even part of "
+                             f"the head's {head_dim} columns, or 0 for all")
+        if shared_gate and not shared_width:
+            raise ValueError("a gate on the shared expert needs "
+                             "shared_width")
         if residual not in RESIDUALS:
             raise ValueError(f"residual {residual!r}: one of {RESIDUALS}")
         self.vocab_size = vocab_size
@@ -230,8 +281,8 @@ class Config:
         self.route_norm_eps = route_norm_eps
         self.route_scale = route_scale
         self.route_bias_coeff = route_bias_coeff
-        # the token mixer of every PUBLISHED layer, "attention" or "conv"
-        # (None: attention everywhere); read at layer_offset + i
+        # the token mixer of every PUBLISHED layer, one of MIXERS (None:
+        # attention everywhere); read at layer_offset + i
         self.mixers = None if mixers is None else tuple(mixers)
         self.conv_taps = conv_taps
         self.tie_head = tie_head
@@ -243,6 +294,13 @@ class Config:
         # the multi-token module: how many (0 or 1) and its loss's weight
         self.mtp_depth = mtp_depth
         self.mtp_weight = mtp_weight
+        # what a "delta" mixer needs: a Delta (or its fields in order)
+        self.delta = delta
+        # the leading columns of a plain attention head that the rotary
+        # turns (0: the whole head)
+        self.rotary_dims = rotary_dims
+        # the shared expert's output times sigmoid(m w_sg)
+        self.shared_gate = shared_gate
 
     def layer_mixer(self, i):
         """The kind of held layer ``i``'s token mixer."""
@@ -298,7 +356,8 @@ def _heads(x, seq_len, n, cfg, norm_name=None, rotate=True):
     if norm_name is not None:
         x = _norm(x, cfg, norm_name)
         if rotate:
-            x = layers.rotary_embedding(x, theta=cfg.rope_theta)
+            x = layers.rotary_embedding(x, theta=cfg.rope_theta,
+                                        dims=cfg.rotary_dims)
     return layers.transpose(x, perm=[0, 2, 1, 3])
 
 
@@ -379,6 +438,47 @@ def _short_conv(x, cfg, p):
     return _proj(y, cfg.hidden_size, f"{p}_conv_out_w")
 
 
+def _delta_mixer(x, cfg, seq_len, p):
+    """The gated delta rule between one projection in (queries, keys,
+    values and the output's gate side by side; the rule's two gates beside
+    them) and one out.  What is not one of those plain products (the
+    filter, the gates, the rule, the gated head norm) runs under the name
+    scope ``delta``."""
+    dl = cfg.delta
+    keys, values = dl.key_heads * dl.key_dim, dl.value_heads * dl.value_dim
+    qkvz = _proj(x, 2 * keys + 2 * values, f"{p}_qkvz_w")
+    ba = _proj(x, 2 * dl.value_heads, f"{p}_ba_w")
+
+    def gate_param(name, value):
+        return layers.create_parameter(
+            [dl.value_heads], "float32", attr=ParamAttr(name=f"{p}_{name}"),
+            default_initializer=fluid.initializer.ConstantInitializer(value))
+
+    with fluid.name_scope("delta"):
+        qkv, z = layers.split(qkvz, [2 * keys + values, values], dim=-1)
+        qkv = layers.short_conv(qkv, dl.taps, gated=False,
+                                param_attr=_attr(f"{p}_conv_w"))
+        q, k, v = layers.split(qkv, [keys, keys, values], dim=-1)
+        # the gates in float32: their sums along a chunk are exponents
+        b, al = layers.split(layers.cast(ba, "float32"),
+                             [dl.value_heads, dl.value_heads], dim=-1)
+        decay = layers.elementwise_mul(
+            layers.softplus(layers.elementwise_add(
+                al, gate_param("dt_bias", DT_BIAS_INIT))),
+            layers.exp(gate_param("a_log", 0.0)))
+        o = layers.gated_delta_rule(
+            layers.reshape(q, [-1, seq_len, dl.key_heads, dl.key_dim]),
+            layers.reshape(k, [-1, seq_len, dl.key_heads, dl.key_dim]),
+            layers.reshape(v, [-1, seq_len, dl.value_heads, dl.value_dim]),
+            layers.scale(decay, scale=-1.0), layers.sigmoid(b),
+            chunk=dl.chunk, scale=dl.key_dim ** -0.5,
+            norm_eps=DELTA_NORM_EPS)
+        o = layers.elementwise_mul(
+            layers.reshape(_norm(o, cfg, f"{p}_delta_norm"),
+                           [-1, seq_len, values]), layers.swish(z))
+    return _proj(o, cfg.hidden_size, f"{p}_o_w")
+
+
 def _feed_forward(x, cfg, width, p):
     """W2(silu(W1 x) * W3 x), no bias: ``<p>_w1`` gate, ``_w3`` up, ``_w2``
     down."""
@@ -393,6 +493,9 @@ def _experts(x, cfg, p, routers):
     (name scope, bias, counts) to ``routers``."""
     shared = _feed_forward(x, cfg, cfg.shared_width, f"{p}_shared") \
         if cfg.shared_width else None
+    if cfg.shared_gate:
+        shared = layers.elementwise_mul(
+            shared, layers.sigmoid(_proj(x, 1, f"{p}_shared_gate_w")))
     out = layers.moe_experts(
         x, cfg.num_routed, cfg.experts_held, cfg.expert_width,
         cfg.experts_per_token, expert_offset=cfg.expert_offset,
@@ -422,6 +525,9 @@ def _block(stream, cfg, seq_len, p, scope, mixer, window, dense, routers):
         elif mixer == "latent":
             y = _latent_attention(_norm(x, cfg, f"{p}_attn_norm"), cfg,
                                   seq_len, p)
+        elif mixer == "delta":
+            y = _delta_mixer(_norm(x, cfg, f"{p}_attn_norm"), cfg, seq_len,
+                             p)
         else:
             y = _attention(_norm(x, cfg, f"{p}_attn_norm"), cfg, seq_len,
                            p, window)
@@ -488,9 +594,10 @@ def _multi_token(h, loss, cfg, seq_len, labels, routers):
 
 def _forward(cfg, seq_len):
     """Named for the device trace (``fluid.name_scope``): ``embed``,
-    ``layer<i>.mixer`` (the attention of any kind with its indexer, or
-    the short convolution, with projections, norms, gate and the residual
-    add; what only a latent mixer has beneath it as ``.latent``),
+    ``layer<i>.mixer`` (the attention of any kind with its indexer, the
+    short convolution or the delta rule, with projections, norms, gate and
+    the residual add; what only a latent mixer has beneath it as
+    ``.latent``, what only a delta mixer has as ``.delta``),
     ``layer<i>.ffn`` (dense or shared feed-forward, router and routed
     experts, likewise), ``head`` (final norm, product, loss) and, for the
     multi-token module, ``mtp.merge``, ``mtp.mixer``, ``mtp.ffn``,

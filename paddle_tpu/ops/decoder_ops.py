@@ -1,8 +1,9 @@
 """Ops of a decoder-only language model with routed experts and a learned
 sparse attention: RMS norm, rotary positions, the indexer that selects each
-query's keys, grouped-query attention over that selection, the gated short
-convolution that mixes tokens where a layer has no attention, and the share
-of a routed expert layer that the experts held here give.
+query's keys, grouped-query attention over that selection, the short
+convolution (between two gates where it is a layer's whole mixer, or
+followed by SiLU in front of a linear attention), the gated delta rule, and
+the share of a routed expert layer that the experts held here give.
 
 Each is a pure JAX function; gradients go through the generic vjp path
 (``ops/registry.py``) except where noted.  ``sparse_attention`` and
@@ -12,7 +13,10 @@ Each is a pure JAX function; gradients go through the generic vjp path
 not the softmax one, ``score``; ``...declined{why}`` for every fallback;
 ``ops.moe.bias_updates`` for every ``moe_bias_update`` lowered;
 ``ops.short_conv.calls{channels,taps,path}`` for every ``short_conv``
-lowered, its backward not counted;
+lowered, its backward not counted, with ``gated="0"`` where the op is the
+filter and SiLU alone;
+``ops.delta_rule.calls{key_heads,value_heads,dim,chunk,path}`` for every
+``gated_delta_rule`` lowered, its backward not counted;
 ``ops.moe.row_moves{pass,how="gather"}``, which ``parallel/moe.py`` counts
 through ``_count`` for every ``[N * top_k, D]`` row gather it traces: two
 ``pass="forward"`` for every trace of the layer's forward, of which
@@ -325,42 +329,115 @@ def sparse_attention_grad(ctx):
     return {s: g for s, g in grads.items() if s in ctx.outputs_spec}
 
 
+def causal_filter(z, w):
+    """``sum_j w[:, j] * z[t - (L - 1) + j]`` in float32 for z [batch, T,
+    channels] and w [channels, L]: one causal L-tap filter a channel,
+    ``z[s]`` zero for s < 0.  L shifted multiply-adds over the padded
+    input, which XLA fuses into one loop."""
+    taps = w.shape[1]
+    t = z.shape[1]
+    z = jnp.pad(z, ((0, 0), (taps - 1, 0), (0, 0)))
+    wf = w.astype(jnp.float32)
+    return sum(wf[:, j] * z[:, j:j + t].astype(jnp.float32)
+               for j in range(taps))
+
+
 def gated_short_conv(x, w):
     """``y[t] = C[t] * sum_j w[:, j] * (B * u)[t - (L - 1) + j]`` for
     x = [B | C | u] ([batch, T, 3 * channels], three chunks in this order)
-    and w [channels, L]: one causal L-tap filter a channel, ``(B * u)[s]``
-    zero for s < 0.  The gates multiply in x's type (bf16 under AMP); the
-    taps are summed in float32.  L shifted multiply-adds over the padded
-    ``B * u``, which XLA fuses into one loop."""
-    c, taps = w.shape
-    t = x.shape[1]
-    z = jnp.pad(x[..., :c] * x[..., 2 * c:], ((0, 0), (taps - 1, 0), (0, 0)))
-    wf = w.astype(jnp.float32)
-    acc = sum(wf[:, j] * z[:, j:j + t].astype(jnp.float32)
-              for j in range(taps))
+    and w [channels, L] (``causal_filter`` of ``B * u``).  The gates
+    multiply in x's type (bf16 under AMP); the taps are summed in
+    float32."""
+    c = w.shape[0]
+    acc = causal_filter(x[..., :c] * x[..., 2 * c:], w)
     return (x[..., c:2 * c].astype(jnp.float32) * acc).astype(x.dtype)
+
+
+def silu_short_conv(x, w):
+    """``y = SiLU(causal_filter(x))`` for x [batch, T, channels]: the filter
+    alone, as it stands in front of a linear attention; filter and SiLU in
+    float32."""
+    return jax.nn.silu(causal_filter(x, w)).astype(x.dtype)
+
+
+def _short_conv_form(ctx):
+    """(the op's form as a function of X and Filter, is it the gated one)."""
+    gated = bool(ctx.attr("gated", True))
+    return (gated_short_conv if gated else silu_short_conv), gated
 
 
 @register_op("short_conv")
 def short_conv_op(ctx):
-    """The token mixer of a layer without attention: both gates and the
-    causal per-channel filter (``gated_short_conv``).  X: [B, T, 3C];
-    Filter: [C, L]; Out: [B, T, C].  No state crosses sequences: each row
-    of the batch is padded on its own."""
+    """The causal per-channel filter over the sequence, in one of two
+    forms.  ``gated`` (the default), the token mixer of a layer without
+    attention: both gates around the filter (``gated_short_conv``), X:
+    [B, T, 3C]; Filter: [C, L]; Out: [B, T, C].  Not ``gated``: the filter
+    and SiLU (``silu_short_conv``), X and Out [B, T, C].  No state crosses
+    sequences: each row of the batch is padded on its own."""
     x, w = ctx.input("X"), ctx.input("Filter")
+    form, gated = _short_conv_form(ctx)
     _count("ops.short_conv.calls", channels=w.shape[0], taps=w.shape[1],
-           path="xla")
-    return {"Out": gated_short_conv(x, w)}
+           path="xla", **({} if gated else {"gated": 0}))
+    return {"Out": form(x, w)}
 
 
 @register_grad("short_conv")
 def short_conv_grad(ctx):
-    """From X and Filter alone: ``B * u`` and the filter's sum are made
+    """From X and Filter alone: the filter's input and its sum are made
     again, nothing but the op's inputs is kept from the forward."""
     x = ctx.input("X")
-    _, vjp = jax.vjp(gated_short_conv, x, ctx.input("Filter"))
+    _, vjp = jax.vjp(_short_conv_form(ctx)[0], x, ctx.input("Filter"))
     dx, dw = vjp(ctx.input("Out@GRAD").astype(x.dtype))
     grads = {"X@GRAD": dx, "Filter@GRAD": dw}
+    return {s: g for s, g in grads.items() if s in ctx.outputs_spec}
+
+
+def _delta_rule(ctx):
+    """(the rule as a function of the op's five inputs, the inputs)."""
+    from . import delta_rule
+
+    def rule(q, k, v, g, beta):
+        return delta_rule.chunked(
+            q, k, v, g, beta, chunk=int(ctx.attr("chunk", 64)),
+            scale=float(ctx.attr("scale", 0.0)),
+            norm_eps=float(ctx.attr("norm_eps", 0.0)))
+
+    return rule, [ctx.input(s) for s in ("Q", "K", "V", "G", "Beta")]
+
+
+@register_op("gated_delta_rule")
+def gated_delta_rule_op(ctx):
+    """The gated delta rule over the sequence (``ops/delta_rule.py``), in
+    chunks of ``chunk`` tokens.  Q, K: [B, T, Hk, dk]; V: [B, T, Hv, dv]
+    with Hv a multiple of Hk; G (the log of each token's decay, <= 0) and
+    Beta (its step): [B, T, Hv]; Out: [B, T, Hv, dv].  ``scale`` multiplies
+    Q (0: ``dk ** -0.5``); ``norm_eps`` > 0: Q and K are l2-normed per head
+    first.  The state starts at zero in every row of the batch and nothing
+    crosses from one row to the next."""
+    rule, operands = _delta_rule(ctx)
+    q, v = operands[0], operands[2]
+    _count("ops.delta_rule.calls", key_heads=q.shape[2],
+           value_heads=v.shape[2], dim=v.shape[3],
+           chunk=int(ctx.attr("chunk", 64)), path="xla")
+    return {"Out": rule(*operands)}
+
+
+@register_grad("gated_delta_rule")
+def gated_delta_rule_grad(ctx):
+    """From the op's five inputs alone: everything a chunk needs (decays,
+    the inverse, what each token writes) and the state at every chunk's
+    start are made again, then walked backwards; nothing but the inputs is
+    kept from the forward."""
+    rule, operands = _delta_rule(ctx)
+    # behind a barrier with the cotangent in it, as ``jax.checkpoint`` puts
+    # one: without it XLA finds the second forward to be the first and
+    # keeps a gigabyte a layer (every chunk's state, inverse and writes)
+    # from the forward pass to here
+    operands, dout = jax.lax.optimization_barrier(
+        (operands, ctx.input("Out@GRAD")))
+    out, vjp = jax.vjp(rule, *operands)
+    grads = dict(zip(("Q@GRAD", "K@GRAD", "V@GRAD", "G@GRAD", "Beta@GRAD"),
+                     vjp(dout.astype(out.dtype))))
     return {s: g for s, g in grads.items() if s in ctx.outputs_spec}
 
 

@@ -617,6 +617,15 @@ def infer_short_conv(op, ins):
     x, w = _in(ins, "X"), _in(ins, "Filter")
     if x is None:
         return None
+    if not bool(op.attr("gated", True)):
+        if len(x[0]) != 3 or (w is not None and (
+                len(w[0]) != 2 or w[0][0] != x[0][-1])):
+            raise InferMismatch(
+                f"short_conv: {_names(op, 'X')} {list(x[0])} must be "
+                f"[batch, positions, channels] (the filter's input alone: "
+                f"gated is off) and filter {_names(op, 'Filter')} "
+                f"{list(w[0]) if w is not None else '?'} [channels, taps]")
+        return {"Out": [x]}
     if len(x[0]) != 3 or x[0][-1] % 3 or (
             w is not None and (len(w[0]) != 2
                                or 3 * w[0][0] != x[0][-1])):
@@ -626,3 +635,29 @@ def infer_short_conv(op, ins):
             f"side by side) and filter {_names(op, 'Filter')} "
             f"{list(w[0]) if w is not None else '?'} [channels, taps]")
     return {"Out": [(tuple(x[0][:2]) + (x[0][-1] // 3,), x[1])]}
+
+
+@register_infer("gated_delta_rule")
+def infer_gated_delta_rule(op, ins):
+    q, k, v = _in(ins, "Q"), _in(ins, "K"), _in(ins, "V")
+    g, beta = _in(ins, "G"), _in(ins, "Beta")
+    if q is None or v is None:
+        return {"Out": [v]}
+    if len(q[0]) != 4 or len(v[0]) != 4 or (
+            k is not None and tuple(k[0]) != tuple(q[0])) \
+            or tuple(q[0][:2]) != tuple(v[0][:2]) or v[0][2] % q[0][2]:
+        raise InferMismatch(
+            f"gated_delta_rule: {_names(op, 'Q')} {list(q[0])} and K must "
+            f"be alike, [B, T, key heads, dk], and {_names(op, 'V')} "
+            f"{list(v[0])} [B, T, value heads, dv] over the same tokens "
+            f"with the value heads a multiple of the key heads")
+    for name, gate in (("G", g), ("Beta", beta)):
+        if gate is not None and tuple(gate[0]) != tuple(v[0][:3]):
+            raise InferMismatch(
+                f"gated_delta_rule: {_names(op, name)} {list(gate[0])} "
+                f"must be {list(v[0][:3])}, one number a token and value "
+                f"head")
+    if int(op.attr("chunk", 64)) < 1:
+        raise InferMismatch(
+            f"gated_delta_rule: chunk {op.attr('chunk')} is not positive")
+    return {"Out": [v]}

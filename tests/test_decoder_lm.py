@@ -1881,6 +1881,19 @@ def test_infer_rule_of_a_partial_rotary_and_its_table():
         rule(Op(), {"X": [((2, 16, 4, 127), "float32")]})
 
 
+def program_digest():
+    """(ops, (how many, sha256 of every op's type, inputs, outputs and
+    attrs in order)) of the default main program's block 0."""
+    import hashlib
+
+    ops = fluid.default_main_program().global_block().ops
+    text = "\n".join(repr((
+        op.type, sorted((k, list(v)) for k, v in op.inputs.items()),
+        sorted((k, list(v)) for k, v in op.outputs.items()),
+        sorted((k, repr(v)) for k, v in op.attrs.items()))) for op in ops)
+    return ops, (len(ops), hashlib.sha256(text.encode()).hexdigest())
+
+
 #: the three decoder programs at their tiny sizes, as they stood before the
 #: latent mixer, the residual rule and the multi-token module: (ops,
 #: sha256 of every op's type, inputs, outputs and attrs in order)
@@ -1900,19 +1913,12 @@ def test_programs_without_the_new_fields_are_op_for_op_what_they_were(
     """``latent``, ``residual``, ``mtp_depth`` unset: the builder appends
     the ops it appended before this kind existed, names, attrs and name
     scopes included (a digest taken on the commit before)."""
-    import hashlib
-
     sizes = json.load(open(os.path.join(ROOT, "chipbench", "configs", config,
                                         "config.json")))
     plugins.load(f"configs/{config}", "build").build(
         fluid, {**sizes, **sizes["tiny"]})
-    ops = fluid.default_main_program().global_block().ops
-    text = "\n".join(repr((
-        op.type, sorted((k, list(v)) for k, v in op.inputs.items()),
-        sorted((k, list(v)) for k, v in op.outputs.items()),
-        sorted((k, repr(v)) for k, v in op.attrs.items()))) for op in ops)
-    assert (len(ops), hashlib.sha256(text.encode()).hexdigest()) \
-        == PROGRAMS_BEFORE[config]
+    ops, digest = program_digest()
+    assert digest == PROGRAMS_BEFORE[config]
     assert not {"split", "concat", "expand", "cast"} & {o.type for o in ops}
     assert not any(set(op.attrs) & {"start", "dims", "interleaved",
                                     "inv_freq"} for op in ops)
@@ -1947,3 +1953,211 @@ def test_config_refuses_a_latent_layer_it_cannot_build():
     with pytest.raises(ValueError, match="one module after the trunk"):
         decoder_lm.Config(**base, mtp_depth=2)
     assert "latent" in decoder_lm.MIXERS
+
+
+# == three gated-delta-rule mixers to one gated attention layer with a    ==
+# == rotary on a quarter of the head, a softmax router and a gated shared ==
+# == expert: the program against the reference of                         ==
+# == ``chipbench/configs/qwen3_next_80b_a3b``                             ==
+
+QWEN = "configs/qwen3_next_80b_a3b"
+Q_BUILD = plugins.load(QWEN, "build")
+Q_REF = plugins.load(QWEN, "reference")
+
+
+def qwen_sizes(**over):
+    sizes = json.load(open(os.path.join(ROOT, "chipbench", QWEN,
+                                        "config.json")))
+    return {**sizes, **sizes["tiny"], **over}
+
+
+@pytest.mark.parametrize("flash", ["xla", "pallas"])
+def test_delta_program_equals_the_reference_and_its_adam_step(
+        monkeypatch, flash):
+    """Loss, every gradient and every parameter after one Adam step through
+    ``fluid.Executor`` with ``optimizer.minimize``: the CHUNKED rule (four
+    chunks of 16) in three layers against the reference's token-by-token
+    recurrence, then the attention layer with its partial rotary, and in
+    every layer the gated shared expert beside the routed share."""
+    monkeypatch.setenv("PADDLE_TPU_FLASH", "1" if flash == "pallas" else "0")
+    monkeypatch.setattr(psf, "BLOCK", 16)
+    sizes = qwen_sizes()
+    assert sizes["seq_len"] == 4 * sizes["delta_chunk"]
+    assert sizes["num_experts"] < sizes["published"]["num_experts"]
+    built, names, weights = seeded_program(Q_BUILD, Q_REF, sizes)
+    main, scope = fluid.default_main_program(), fluid.global_scope()
+    for i in range(4):
+        mine = {n[3:] for n in names if n.startswith(f"l{i}_")}
+        delta = {"qkvz_w", "ba_w", "conv_w", "dt_bias", "a_log",
+                 "delta_norm"}
+        plain = {"q_w", "q_norm", "k_w", "k_norm", "v_w", "gate_w"}
+        assert (delta <= mine, bool(plain & mine)) == (i < 3, i == 3), i
+        assert {"attn_norm", "o_w", "moe_norm", "shared_w1", "shared_w3",
+                "shared_w2", "shared_gate_w", "router_w", "w1", "w3",
+                "w2"} <= mine
+    feed = Q_BUILD.make_feed(sizes, 2, np.random.RandomState(3))
+    exe = fluid.Executor(fluid.TPUPlace())
+    outs = exe.run(main, feed=feed, fetch_list=[built["loss"]]
+                   + [n + "@GRAD" for n in names])
+    ref_loss, ref_grads = Q_REF.loss_and_grads(weights, feed, sizes)
+    assert float(outs[0].reshape(-1)[0]) == pytest.approx(float(ref_loss),
+                                                          rel=1e-5)
+    for name, g, r in zip(names, outs[1:], ref_grads):
+        g = np.asarray(g).reshape(r.shape)
+        assert np.abs(g - r).max() <= 3e-4 * np.abs(r).max() + 1e-7, name
+        assert np.abs(r).max() > 0, name
+    # one Adam step of every parameter, from the program's own gradient
+    # (where a gradient is near Adam's epsilon the step follows its last
+    # digits, which the two algorithms do not share)
+    for name, w, g in zip(names, weights, outs[1:]):
+        np.testing.assert_allclose(
+            np.asarray(scope.get(name)).reshape(w.shape),
+            Q_REF.optimizer_step(w, jnp.asarray(g).reshape(w.shape), sizes),
+            atol=2e-6, err_msg=name)
+    # what ran, as the counters say it
+    per = 1 if flash == "pallas" else 2
+    assert counters("ops.sparse_attention.calls") == {
+        f'ops.sparse_attention.calls{{path="{flash}",seq="64",'
+        f'topk="0"}}': per}
+    assert not counters("ops.sparse_attention.declined")
+    assert counters("ops.rotary.calls") == {
+        'ops.rotary.calls{dims="4",pairing="half",scaled="0"}': 2}
+    assert counters("ops.delta_rule.calls") == {
+        'ops.delta_rule.calls{chunk="16",dim="8",key_heads="2",path="xla",'
+        'value_heads="4"}': 3}
+    assert counters("ops.short_conv.calls") == {
+        'ops.short_conv.calls{channels="64",gated="0",path="xla",'
+        'taps="4"}': 3}
+    assert counters("models.decoder.blocks") == {
+        'models.decoder.blocks{mixer="delta",residual="sequential",'
+        'where="trunk"}': 3,
+        'models.decoder.blocks{mixer="attention",residual="sequential",'
+        'where="trunk"}': 1}
+    (key, n), = counters("ops.moe.calls").items()
+    assert "score" not in key and 'routed="8"' in key and 'held="4"' in key \
+        and n == 2 * 4
+    # every op under a name; a delta mixer's own under ``.delta``, its two
+    # plain products not
+    scopes = {op.attrs.get("op_namescope", "") for op in main.all_ops()}
+    assert {"embed", "head"} | {f"layer{i}.{part}" for i in range(4)
+                                for part in ("mixer", "ffn")} | {
+        f"layer{i}.mixer.delta" for i in range(3)} == scopes
+    block = main.global_block()
+    for op in block.ops:
+        if op.type in ("short_conv", "gated_delta_rule"):
+            assert op.attrs["op_namescope"].endswith(".mixer.delta")
+        if op.type == "mul" and op.inputs["Y"][0].endswith(
+                ("_qkvz_w", "_ba_w", "_o_w")):
+            assert op.attrs["op_namescope"].endswith(".mixer")
+
+
+def test_the_fp8_control_of_the_delta_cell_misses_what_float32_meets():
+    """The reference with float8 contraction inputs (the state's two reads
+    among them) in the program's place: far outside the tiny limits; the
+    same comparison of the reference with itself reads zero."""
+    from chipbench import check
+
+    sizes = qwen_sizes()
+    weights = Q_REF.init_params(7, sizes)
+    feed = Q_BUILD.make_feed(sizes, 2, np.random.RandomState(3))
+    numbers = check.control(Q_REF, sizes, weights, feed, jnp.float8_e4m3fn)
+    assert check.decide(numbers, sizes["limits"]) is False
+    assert numbers["grad_rel"] > 3 * sizes["limits"]["grad_rel"]
+
+
+@pytest.mark.parametrize("routed,held,k", [(32, 4, 5), (16, 8, 3)])
+def test_the_shares_and_the_gated_shared_expert_once_add_up_to_the_layer(
+        routed, held, k):
+    """A softmax router ``routed`` wide with ``k`` a token, ``held`` by
+    each of ``routed / held`` chips: the program's shares, and the shared
+    expert behind its sigmoid gate counted ONCE, add up to what the cell's
+    reference gives for the UNCUT layer (``experts`` with every expert
+    held)."""
+    rng = np.random.RandomState(2)
+    x, wr, w1, w3, w2 = moe_weights(rng, 48, 16, 8, routed)
+    s1, s3, s2 = (jnp.asarray(0.3 * rng.randn(*s), jnp.float32)
+                  for s in ((16, 8), (16, 8), (8, 16)))
+    wsg = jnp.asarray(rng.randn(16, 1), jnp.float32)
+    c = {"k": k, "offset": 0}
+    with jax.default_matmul_precision("highest"):
+        whole = Q_REF.experts(x, (s1, s3, s2, wsg, wr, w1, w3, w2), c,
+                              lambda a: a)
+        gated = jax.nn.sigmoid(x @ wsg) * Q_REF.feed_forward(x, s1, s3, s2)
+        total = gated
+        for off in range(0, routed, held):
+            part = moe.routed_experts(
+                x, wr, w1[off:off + held], w3[off:off + held],
+                w2[off:off + held], top_k=k, expert_offset=off)
+            mine = Q_REF.routed(x, wr, w1[off:off + held],
+                                w3[off:off + held], w2[off:off + held], k,
+                                off)
+            np.testing.assert_allclose(part, mine, atol=1e-5)
+            total = total + part
+    np.testing.assert_allclose(total, whole, atol=3e-5)
+    assert float(jnp.abs(whole - gated).max()) > 0.1 \
+        and float(jnp.abs(gated).max()) > 0.1
+
+
+#: the two other decoder programs at their tiny sizes as they stood before
+#: the delta mixer: Instella's, and ``decoder_lm.build()``'s own default
+MORE_PROGRAMS_BEFORE = {
+    "instella_moe_16b_a3b": (504, "7a603a8af2c505857474e1823bd3d412"
+                                  "9de0ef9bfad2e68a66506c8b9c8ae455"),
+    "tiny_config": (142, "ea22fd7171f49fe2ec0ac217b545cb95"
+                         "affd0975690a1a2a9e51a1e8662ed90f"),
+}
+
+
+@pytest.mark.parametrize("config", sorted(MORE_PROGRAMS_BEFORE))
+def test_programs_without_delta_rotary_dims_and_shared_gate_are_unchanged(
+        config):
+    """``delta``, ``rotary_dims``, ``shared_gate`` unset: with the three
+    programs of ``PROGRAMS_BEFORE`` (whose digests hold too), the five
+    older programs are op for op what they were on the commit before."""
+    from paddle_tpu.models import decoder_lm
+
+    if config == "tiny_config":
+        decoder_lm.build()
+    else:
+        sizes = json.load(open(os.path.join(ROOT, "chipbench", "configs",
+                                            config, "config.json")))
+        plugins.load(f"configs/{config}", "build").build(
+            fluid, {**sizes, **sizes["tiny"]})
+    ops, digest = program_digest()
+    assert digest == MORE_PROGRAMS_BEFORE[config]
+    assert not {"gated_delta_rule"} & {o.type for o in ops}
+    assert not any("gated" in op.attrs for op in ops)
+    assert not counters("models.decoder.blocks{mixer=\"delta\"")
+
+
+def test_config_refuses_a_delta_layer_it_cannot_build():
+    from paddle_tpu.models import decoder_lm
+
+    base = dict(vocab_size=128, hidden_size=64, num_layers=2, num_heads=4,
+                num_kv_heads=2, head_dim=16, expert_width=32, num_routed=8,
+                experts_held=4, experts_per_token=2)
+    delta = decoder_lm.Delta(key_heads=2, value_heads=4, key_dim=8,
+                             value_dim=8)
+    cfg = decoder_lm.Config(**base, mixers=["delta", "attention"],
+                            delta=delta, rotary_dims=4, shared_width=32,
+                            shared_gate=True)
+    assert cfg.delta == delta and (delta.taps, delta.chunk) == (4, 64)
+    assert [cfg.layer_mixer(i) for i in range(2)] == ["delta", "attention"]
+    assert decoder_lm.Config(**base, delta=tuple(delta)).delta == delta
+    plain = decoder_lm.Config(**base)
+    assert (plain.delta, plain.rotary_dims, plain.shared_gate) == (
+        None, 0, False)
+    with pytest.raises(ValueError, match="needs the record `delta`"):
+        decoder_lm.Config(**base, mixers=["delta"] * 2)
+    with pytest.raises(ValueError, match="a multiple of the key"):
+        decoder_lm.Config(**base, mixers=["delta"] * 2,
+                          delta=delta._replace(value_heads=3))
+    with pytest.raises(ValueError, match="hold a token at least"):
+        decoder_lm.Config(**base, mixers=["delta"] * 2,
+                          delta=delta._replace(chunk=0))
+    for wrong in (3, 18, -2):
+        with pytest.raises(ValueError, match="an even part of the head"):
+            decoder_lm.Config(**base, rotary_dims=wrong)
+    with pytest.raises(ValueError, match="needs shared_width"):
+        decoder_lm.Config(**base, shared_gate=True)
+    assert "delta" in decoder_lm.MIXERS

@@ -1,0 +1,318 @@
+"""The gated delta rule (ops/delta_rule.py, the op ``gated_delta_rule`` and
+its grad op) and the ungated form of ``short_conv``: the chunked
+computation against the token-by-token recurrence, value and every
+cotangent, in float32 on the CPU."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu.fluid as fluid
+from paddle_tpu.fluid import layers
+from paddle_tpu.ops import decoder_ops, delta_rule, registry
+
+
+def recurrence(q, k, v, g, beta, scale, norm_eps=0.0):
+    """The rule as it is stated: one token after another, one [dk, dv]
+    state a value head.  q, k: [B, T, Hk, dk]; v: [B, T, Hv, dv]; g, beta:
+    [B, T, Hv]."""
+    b, t, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    if norm_eps:
+        q = q / jnp.sqrt(jnp.sum(q * q, -1, keepdims=True) + norm_eps)
+        k = k / jnp.sqrt(jnp.sum(k * k, -1, keepdims=True) + norm_eps)
+    q = jnp.repeat(q, hv // hk, 2) * scale
+    k = jnp.repeat(k, hv // hk, 2)
+
+    def token(state, x):
+        q_t, k_t, v_t, g_t, b_t = x
+        state = jnp.exp(g_t)[..., None, None] * state
+        u = b_t[..., None] * (v_t - jnp.einsum("bhkv,bhk->bhv", state, k_t))
+        state = state + jnp.einsum("bhk,bhv->bhkv", k_t, u)
+        return state, jnp.einsum("bhkv,bhk->bhv", state, q_t)
+
+    xs = tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta))
+    _, out = jax.lax.scan(token, jnp.zeros((b, hv, dk, dv), jnp.float32), xs)
+    return jnp.moveaxis(out, 0, 1)
+
+
+def operands(t, seed=0, b=2, hk=2, hv=4, dk=8, dv=12, decay=0.5):
+    rng = np.random.RandomState(seed)
+    k = rng.randn(b, t, hk, dk)
+    return tuple(jnp.asarray(a, jnp.float32) for a in (
+        rng.randn(b, t, hk, dk), k / np.linalg.norm(k, axis=-1,
+                                                    keepdims=True),
+        rng.randn(b, t, hv, dv), -decay * rng.rand(b, t, hv),
+        rng.rand(b, t, hv)))
+
+
+def weighted_sum(fn):
+    """A scalar of ``fn``'s output that weighs every element differently."""
+    def loss(*xs):
+        out = fn(*xs)
+        return jnp.sum(out * jnp.cos(jnp.arange(out.size, dtype=jnp.float32)
+                                     ).reshape(out.shape))
+    return loss
+
+
+@pytest.fixture(autouse=True)
+def exact_products():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.mark.parametrize("t,chunk", [(53, 16), (48, 16), (70, 32), (7, 16),
+                                     (96, 64)])
+def test_chunked_equals_the_recurrence_value_and_all_five_cotangents(
+        t, chunk):
+    """Several chunks with a ragged last one (53 = 3 x 16 + 5, 70 = 2 x 32
+    + 6), whole chunks, a sequence under one chunk, and the published chunk
+    of 64; two value heads a key head."""
+    xs = operands(t, seed=t)
+    scale = xs[0].shape[-1] ** -0.5
+    want = recurrence(*xs, scale)
+    got = delta_rule.chunked(*xs, chunk=chunk)
+    assert got.shape == want.shape == xs[2].shape
+    np.testing.assert_allclose(got, want, atol=5e-6)
+    grads = jax.grad(weighted_sum(
+        lambda *a: delta_rule.chunked(*a, chunk=chunk)), range(5))(*xs)
+    wants = jax.grad(weighted_sum(lambda *a: recurrence(*a, scale)),
+                     range(5))(*xs)
+    for name, g, w in zip("q k v g beta".split(), grads, wants):
+        np.testing.assert_allclose(g, w, atol=2e-5 * float(
+            jnp.abs(w).max()) + 1e-6, err_msg=name)
+
+
+def test_a_decay_that_underflows_inside_a_chunk_gives_zeros_not_nans():
+    """g down to -200 a token: ``exp(G_i - G_j)`` underflows within a few
+    tokens and its mirror above the diagonal would overflow; value and
+    every cotangent stay finite and equal the recurrence's."""
+    q, k, v, g, beta = operands(53, seed=1)
+    g = g * 400.0
+    assert float(jnp.cumsum(g, 1).min()) < -1000
+    scale = q.shape[-1] ** -0.5
+    want = recurrence(q, k, v, g, beta, scale)
+    got = delta_rule.chunked(q, k, v, g, beta, chunk=16)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    grads = jax.grad(weighted_sum(
+        lambda *a: delta_rule.chunked(*a, chunk=16)), range(5))(
+            q, k, v, g, beta)
+    wants = jax.grad(weighted_sum(lambda *a: recurrence(*a, scale)),
+                     range(5))(q, k, v, g, beta)
+    for got_g, want_g in zip(grads, wants):
+        assert bool(jnp.isfinite(got_g).all())
+        np.testing.assert_allclose(got_g, want_g, atol=2e-5 * float(
+            jnp.abs(want_g).max()) + 1e-6)
+
+
+def test_the_l2_norm_and_a_stated_scale_are_the_recurrences():
+    q, k, v, g, beta = operands(40, seed=2)
+    k = k * 3.0
+    want = recurrence(q, k, v, g, beta, 0.25, norm_eps=1e-6)
+    got = delta_rule.chunked(q, k, v, g, beta, chunk=16, scale=0.25,
+                             norm_eps=1e-6)
+    np.testing.assert_allclose(got, want, atol=5e-6)
+
+
+@pytest.mark.parametrize("c", [1, 2, 5, 16, 48, 64])
+def test_unit_lower_inverse_is_the_inverse(c):
+    rng = np.random.RandomState(c)
+    a = jnp.asarray(np.tril(rng.randn(3, c, c), -1), jnp.float32)
+    inv = delta_rule.unit_lower_inverse(a)
+    want = np.linalg.inv(np.eye(c) + np.asarray(a, np.float64))
+    np.testing.assert_allclose(inv, want, atol=1e-4 * np.abs(want).max())
+    assert not np.any(np.triu(np.asarray(inv), 1))
+
+
+def test_nothing_leaks_from_the_future_or_across_sequences():
+    """Perturbing token 21 of sequence 0 (inside the second chunk) leaves
+    its outputs before 21 and every output of sequence 1 unchanged, and
+    moves outputs in its own chunk and in the chunks after it: the state
+    carries it on."""
+    q, k, v, g, beta = operands(53, seed=3)
+    base = delta_rule.chunked(q, k, v, g, beta, chunk=16)
+    moved = delta_rule.chunked(q, k, v.at[0, 21].add(1.0), g, beta, chunk=16)
+    np.testing.assert_array_equal(moved[1], base[1])
+    np.testing.assert_array_equal(moved[0, :21], base[0, :21])
+    changed = np.any(np.asarray(moved[0] != base[0]), (1, 2))
+    assert changed[21] and changed[31] and changed[32] and changed[52]
+
+
+def test_low_precision_inputs_give_their_own_type_and_stay_close():
+    """bf16 q, k, v under AMP: the output is bf16, gates' cotangents stay
+    float32, and the values are the float32 ones to bf16's rounding."""
+    q, k, v, g, beta = operands(48, seed=4)
+    want = delta_rule.chunked(q, k, v, g, beta, chunk=16, norm_eps=1e-6)
+    low = [a.astype(jnp.bfloat16) for a in (q, k, v)]
+    with fluid.amp.amp_guard("bfloat16", keep_activations=True):
+        got, vjp = jax.vjp(lambda *a: delta_rule.chunked(
+            *a, chunk=16, norm_eps=1e-6), *low, g, beta)
+        grads = vjp(jnp.ones_like(got))
+    assert got.dtype == jnp.bfloat16
+    assert [x.dtype for x in grads] == [jnp.bfloat16] * 3 + [jnp.float32] * 2
+    np.testing.assert_allclose(got.astype(jnp.float32), want, atol=0.06,
+                               rtol=0.05)
+
+
+def build_rule(t, hk=2, hv=4, dk=8, dv=12, chunk=16, **attrs):
+    names = ("q", "k", "v", "g", "beta")
+    shapes = ([t, hk, dk], [t, hk, dk], [t, hv, dv], [t, hv], [t, hv])
+    data = [layers.data(name=n, shape=s, dtype="float32")
+            for n, s in zip(names, shapes)]
+    for d in data:
+        d.stop_gradient = False
+    out = layers.gated_delta_rule(*data, chunk=chunk, **attrs)
+    return names, data, out
+
+
+@pytest.mark.parametrize("t", [5, 53])
+def test_the_grad_op_equals_jax_grad_of_the_forward(t):
+    """The op and its grad op through the executor against ``jax.grad`` of
+    ``delta_rule.chunked``: all five inputs' gradients; the backward is
+    the op's own and is not counted as a call."""
+    names, _, out = build_rule(t, norm_eps=1e-6)
+    assert tuple(out.shape[1:]) == (t, 4, 12)
+    weights = np.cos(np.arange(12, dtype="float32"))
+    loss = layers.reduce_sum(layers.elementwise_mul(
+        out, layers.assign(weights)))
+    fluid.backward.append_backward(loss)
+    exe = fluid.Executor(fluid.TPUPlace())
+    xs = operands(t, seed=t)
+    got = exe.run(feed={n: np.asarray(x) for n, x in zip(names, xs)},
+                  fetch_list=[out] + [n + "@GRAD" for n in names])
+
+    def forward(*a):
+        return delta_rule.chunked(*a, chunk=16, norm_eps=1e-6)
+
+    np.testing.assert_allclose(got[0], forward(*xs), atol=1e-6)
+    want = jax.grad(lambda *a: jnp.sum(forward(*a) * weights), range(5))(*xs)
+    for name, g, w in zip(names, got[1:], want):
+        np.testing.assert_allclose(g, w, atol=1e-5, err_msg=name)
+    assert {k: v for k, v in fluid.profiler.counters().items()
+            if k.startswith("ops.delta_rule")} == {
+        'ops.delta_rule.calls{chunk="16",dim="12",key_heads="2",path="xla",'
+        'value_heads="4"}': 1}
+
+
+def test_infer_rule_and_layer_of_the_delta_rule():
+    from paddle_tpu import analysis
+    from paddle_tpu.ops.registry import get_infer_rule
+
+    class Op:
+        def __init__(self, **attrs):
+            self.attrs, self.type = attrs, "gated_delta_rule"
+            self.inputs = {s: [s.lower()] for s in ("Q", "K", "V", "G",
+                                                    "Beta")}
+
+        def attr(self, name, default=None):
+            return self.attrs.get(name, default)
+
+    rule = get_infer_rule("gated_delta_rule")
+    q, v = ((2, 64, 16, 128), "bfloat16"), ((2, 64, 32, 128), "bfloat16")
+    gate = ((2, 64, 32), "float32")
+    ins = {"Q": [q], "K": [q], "V": [v], "G": [gate], "Beta": [gate]}
+    assert rule(Op(chunk=64), ins) == {"Out": [v]}
+    for wrong, said in (
+            ({"K": [((2, 64, 8, 128), "bfloat16")]}, "must be alike"),
+            ({"V": [((2, 64, 24, 128), "bfloat16")]}, "a multiple of"),
+            ({"V": [((2, 32, 32, 128), "bfloat16")]}, "the same tokens"),
+            ({"G": [((2, 64, 16), "float32")]}, "one number a token"),
+            ({"Beta": [((2, 64), "float32")]}, "one number a token")):
+        with pytest.raises(registry.InferMismatch, match=said):
+            rule(Op(), {**ins, **wrong})
+    with pytest.raises(registry.InferMismatch, match="not positive"):
+        rule(Op(chunk=0), ins)
+    _, _, out = build_rule(16)
+    report = analysis.verify_program(fluid.default_main_program(),
+                                     fetch_list=[out])
+    assert not report.errors, report.format()
+    op = fluid.default_main_program().global_block().ops[-1]
+    assert op.type == "gated_delta_rule" and "norm_eps" not in op.attrs \
+        and op.attrs["chunk"] == 16 and op.attrs["scale"] == 0.0
+
+
+# -- the filter alone, followed by SiLU -------------------------------------
+
+def filter_then_silu(x, w):
+    taps, t = w.shape[1], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    return jax.nn.silu(sum(w[:, j] * padded[:, j:j + t]
+                           for j in range(taps)))
+
+
+@pytest.mark.parametrize("t", [1, 3, 13, 64])
+def test_ungated_short_conv_is_the_filter_and_silu_gradients_too(t):
+    """``gated=False`` through the executor at T under the four taps, at T
+    no multiple of 8 and at the tiny cell's: output and both gradients;
+    the call is counted with ``gated="0"``, its backward not at all."""
+    b, c, taps = 2, 8, 4
+    rng = np.random.RandomState(t)
+    x = layers.data(name="x", shape=[t, c], dtype="float32")
+    x.stop_gradient = False
+    out = layers.short_conv(
+        x, taps, gated=False, param_attr=fluid.ParamAttr(
+            name="filter", initializer=fluid.initializer.
+            NormalInitializer(0.0, 0.5)))
+    assert tuple(out.shape[1:]) == (t, c)
+    op = fluid.default_main_program().global_block().ops[-1]
+    assert op.type == "short_conv" and op.attrs["gated"] is False
+    weights = np.cos(np.arange(c, dtype="float32"))
+    loss = layers.reduce_sum(layers.elementwise_mul(
+        out, layers.assign(weights)))
+    fluid.backward.append_backward(loss)
+    exe = fluid.Executor(fluid.TPUPlace())
+    exe.run(fluid.default_startup_program())
+    filt = jnp.asarray(np.asarray(fluid.global_scope().get("filter")))
+    assert filt.shape == (c, taps)
+    feed = {"x": rng.randn(b, t, c).astype("float32")}
+    got = exe.run(feed=feed, fetch_list=[out, "x@GRAD", "filter@GRAD"])
+    xs = jnp.asarray(feed["x"])
+    np.testing.assert_allclose(got[0], filter_then_silu(xs, filt), atol=1e-6)
+    want = jax.grad(lambda x, f: jnp.sum(filter_then_silu(x, f) * weights),
+                    (0, 1))(xs, filt)
+    np.testing.assert_allclose(got[1], want[0], atol=1e-5)
+    np.testing.assert_allclose(got[2], want[1], atol=1e-5)
+    assert {k: v for k, v in fluid.profiler.counters().items()
+            if k.startswith("ops.short_conv")} == {
+        f'ops.short_conv.calls{{channels="{c}",gated="0",path="xla",'
+        f'taps="4"}}': 1}
+
+
+def test_the_two_forms_share_one_filter_and_the_gated_one_is_unchanged():
+    """``causal_filter`` is what both forms sum; the gated form is the
+    gates around it, bit for bit what the op computed before it had a
+    second form."""
+    c, t = 4, 12
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(2, t, 3 * c), jnp.float32)
+    w = jnp.asarray(rng.randn(c, 3), jnp.float32)
+    acc = decoder_ops.causal_filter(x[..., :c] * x[..., 2 * c:], w)
+    np.testing.assert_array_equal(decoder_ops.gated_short_conv(x, w),
+                                  x[..., c:2 * c] * acc)
+    np.testing.assert_array_equal(
+        decoder_ops.silu_short_conv(x[..., :c], w),
+        jax.nn.silu(decoder_ops.causal_filter(x[..., :c], w)))
+    low = decoder_ops.silu_short_conv(x[..., :c].astype(jnp.bfloat16), w)
+    assert low.dtype == jnp.bfloat16
+
+
+def test_infer_rule_of_the_ungated_short_convolution():
+    from paddle_tpu.ops.registry import get_infer_rule
+
+    class Op:
+        def __init__(self, **attrs):
+            self.attrs, self.inputs, self.type = attrs, {}, "short_conv"
+
+        def attr(self, name, default=None):
+            return self.attrs.get(name, default)
+
+    rule = get_infer_rule("short_conv")
+    x = ((2, 16, 96), "bfloat16")
+    assert rule(Op(gated=False), {"X": [x], "Filter": [((96, 4), "float32")]}
+                ) == {"Out": [x]}
+    with pytest.raises(registry.InferMismatch, match="gated is off"):
+        rule(Op(gated=False), {"X": [x], "Filter": [((32, 4), "float32")]})
+    # the gated form's rule is what it was
+    assert rule(Op(), {"X": [x], "Filter": [((32, 3), "float32")]}) == {
+        "Out": [((2, 16, 32), "bfloat16")]}

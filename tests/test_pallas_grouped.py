@@ -127,7 +127,8 @@ def test_visits_name_every_tile_of_every_group_once(case, empty_groups):
 #: that fit the budget (Mosaic's default VMEM, no limit stated), ragged at
 #: Instella's 1,408 = 11 x 128 (768 + 640; 384 x 3 + 256)
 CELLS = {"keye": (65536, 2048, 768), "trinity": (49152, 2048, 1024),
-         "lfm2": (32768, 2048, 1792), "instella": (49152, 2048, 1408)}
+         "lfm2": (32768, 2048, 1792), "instella": (49152, 2048, 1408),
+         "qwen3_next": (81920, 2048, 512)}
 TILES = {("keye", "up"): 768, ("keye", "down"): 2048,
          ("keye", "up_t"): 384, ("keye", "down_t"): 1024,
          ("trinity", "up"): 512, ("trinity", "down"): 1024,
@@ -135,7 +136,9 @@ TILES = {("keye", "up"): 768, ("keye", "down"): 2048,
          ("lfm2", "up"): 896, ("lfm2", "down"): 1024,
          ("lfm2", "up_t"): 256, ("lfm2", "down_t"): 256,
          ("instella", "up"): 768, ("instella", "down"): 1024,
-         ("instella", "up_t"): 384, ("instella", "down_t"): 512}
+         ("instella", "up_t"): 384, ("instella", "down_t"): 512,
+         ("qwen3_next", "up"): 512, ("qwen3_next", "down"): 2048,
+         ("qwen3_next", "up_t"): 256, ("qwen3_next", "down_t"): 1024}
 
 
 def vmem_bytes(tm, k, tn, n, itemsize, transposed_result):

@@ -136,7 +136,9 @@ def _sparse_flash(selected, window=0, t=8192, hkv=4, d=128, hq=32):
     ``lfm2_8b_a1b``: 32 query heads over 8 key-value heads of width 64,
     half a lane row, plain causal at 8,192; ``instella_moe_16b_a3b``: 16
     query heads each over its own key-value head of width 128, a group of
-    one, plain causal at 8,192), forward and the two backward kernels."""
+    one, plain causal at 8,192; ``qwen3_next_80b_a3b``: 16 query heads over
+    2 key-value heads of width 256, two lane rows and a group of 8, plain
+    causal at 8,192), forward and the two backward kernels."""
 
     def fn(q, k, v, sel):
         def loss(q, k, v):
@@ -150,12 +152,44 @@ def _sparse_flash(selected, window=0, t=8192, hkv=4, d=128, hq=32):
     return fn, [((1, hq, t, d), BF16), kv, kv, ((1, t, t), jnp.int8)]
 
 
+def _delta_layer(t=8192, hk=16, hv=32, d=128, taps=4, chunk=64):
+    """``qwen3_next_80b_a3b``'s delta mixer between its two plain products,
+    at the cell's shapes under the cells' AMP: the four-tap filter and SiLU
+    over 8,192 channels, the gated delta rule in 128 chunks of 64 (16 key
+    heads, 32 value heads of 128, the [128, 128] float32 state a head) and
+    the backward of both from their inputs alone.  No Pallas kernel: what
+    is compiled is the XLA lowering the chip runs."""
+    from paddle_tpu.fluid import amp
+    from paddle_tpu.ops import decoder_ops, delta_rule
+
+    keys, values = hk * d, hv * d
+
+    def fn(x, w, g, beta):
+        def loss(x, w, g, beta):
+            qkv = decoder_ops.silu_short_conv(x, w)
+            q, k, v = (qkv[..., :keys], qkv[..., keys:2 * keys],
+                       qkv[..., 2 * keys:])
+            with amp.amp_guard("bfloat16", keep_activations=True):
+                out = delta_rule.chunked(
+                    q.reshape(1, t, hk, d), k.reshape(1, t, hk, d),
+                    v.reshape(1, t, hv, d), g, beta, chunk=chunk,
+                    norm_eps=1e-6)
+            return out.astype(F32).sum()
+
+        return jax.grad(loss, (0, 1, 2, 3))(x, w, g, beta)
+
+    gate = ((1, t, hv), F32)
+    return fn, [((1, t, 2 * keys + values), BF16),
+                ((2 * keys + values, taps), F32), gate, gate]
+
+
 #: the decoder cells' grouped products: rows (tokens x top_k), hidden width,
 #: expert width, experts held (``chipbench/configs/<cell>/config.json``)
 GROUPED_CELLS = {"keye": (65536, 2048, 768, 16),
                  "trinity": (49152, 2048, 1024, 8),
                  "lfm2": (32768, 2048, 1792, 8),
-                 "instella": (49152, 2048, 1408, 8)}
+                 "instella": (49152, 2048, 1408, 8),
+                 "qwen3_next": (81920, 2048, 512, 16)}
 #: no cell's: an expert width of 13 lane rows, whose only dividing tile is
 #: one lane row as at Instella's 11 (ragged tiles of 896 + 768 and 384 x 4 +
 #: 128 where the result is that wide: ``plain`` and ``weights_gradient`` of
@@ -195,6 +229,9 @@ CASES = {
         lambda: _sparse_flash(False, 0, 8192, 8, 64), 3),
     "sparse_flash_causal_group_of_one": (
         lambda: _sparse_flash(False, 0, 8192, 16, 128, 16), 3),
+    "sparse_flash_causal_heads_of_256": (
+        lambda: _sparse_flash(False, 0, 8192, 2, 256, 16), 3),
+    "delta_layer": (lambda: _delta_layer(), 0),
     "window_flash": (lambda: _sparse_flash(False, 2048, 6144), 3),
     "window_flash_global_layer": (lambda: _sparse_flash(False, 0, 6144), 3),
     "window_flash_four_windows": (lambda: _sparse_flash(False, 2048), 3),
@@ -332,7 +369,8 @@ GROUPED_TILES = {
     "keye": {"up": 768, "down": 2048, "up_t": 384, "down_t": 1024},
     "trinity": {"up": 512, "down": 1024, "up_t": 256, "down_t": 512},
     "lfm2": {"up": 896, "down": 1024, "up_t": 256, "down_t": 256},
-    "instella": {"up": 768, "down": 1024, "up_t": 384, "down_t": 512}}
+    "instella": {"up": 768, "down": 1024, "up_t": 384, "down_t": 512},
+    "qwen3_next": {"up": 512, "down": 2048, "up_t": 256, "down_t": 1024}}
 
 
 def _mosaic_bodies(stablehlo_text):
@@ -389,7 +427,8 @@ def test_grouped_grid_is_the_tiles(topo, cell, form, which, key):
 #: a cell's routed layer: tokens a step, choices a token, routed experts
 #: (``GROUPED_CELLS`` has the rows, the widths and the experts held)
 GROUPED_LAYERS = {"keye": (8192, 8, 128), "trinity": (6144, 8, 128),
-                  "lfm2": (8192, 4, 32), "instella": (8192, 6, 64)}
+                  "lfm2": (8192, 4, 32), "instella": (8192, 6, 64),
+                  "qwen3_next": (8192, 10, 512)}
 
 
 @pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))
