@@ -16,7 +16,10 @@ not the softmax one, ``score``; ``...declined{why}`` for every fallback;
 lowered, its backward not counted, with ``gated="0"`` where the op is the
 filter and SiLU alone;
 ``ops.delta_rule.calls{key_heads,value_heads,dim,chunk,path}`` for every
-``gated_delta_rule`` lowered, its backward not counted;
+``gated_delta_rule`` lowered, its backward not counted there but as
+``ops.delta_rule.grad_calls{chunk,path="by_hand"}`` for every
+``gated_delta_rule_grad`` lowered (``by_hand``: the backward written out in
+``ops/delta_rule.py``, no autodiff through the walk over the chunks);
 ``ops.moe.row_moves{pass,how="gather"}``, which ``parallel/moe.py`` counts
 through ``_count`` for every ``[N * top_k, D]`` row gather it traces: two
 ``pass="forward"`` for every trace of the layer's forward, of which
@@ -424,20 +427,24 @@ def gated_delta_rule_op(ctx):
 
 @register_grad("gated_delta_rule")
 def gated_delta_rule_grad(ctx):
-    """From the op's five inputs alone: everything a chunk needs (decays,
-    the inverse, what each token writes) and the state at every chunk's
-    start are made again, then walked backwards; nothing but the inputs is
+    """From the op's five inputs alone, by the backward ``delta_rule.chunked``
+    carries (written by hand, nothing differentiates through its walk):
+    everything a chunk needs (decays, the inverse, what each token writes)
+    and the state at every chunk's start are made again, the outputs are
+    not, then the chunks are walked backwards; nothing but the inputs is
     kept from the forward."""
     rule, operands = _delta_rule(ctx)
+    _count("ops.delta_rule.grad_calls", chunk=int(ctx.attr("chunk", 64)),
+           path="by_hand")
     # behind a barrier with the cotangent in it, as ``jax.checkpoint`` puts
     # one: without it XLA finds the second forward to be the first and
     # keeps a gigabyte a layer (every chunk's state, inverse and writes)
     # from the forward pass to here
     operands, dout = jax.lax.optimization_barrier(
         (operands, ctx.input("Out@GRAD")))
-    out, vjp = jax.vjp(rule, *operands)
+    _, vjp = jax.vjp(rule, *operands)
     grads = dict(zip(("Q@GRAD", "K@GRAD", "V@GRAD", "G@GRAD", "Beta@GRAD"),
-                     vjp(dout.astype(out.dtype))))
+                     vjp(dout.astype(operands[2].dtype))))
     return {s: g for s, g in grads.items() if s in ctx.outputs_spec}
 
 

@@ -1,8 +1,9 @@
 """The gated delta rule, a linear attention whose state is a matrix a head
 that every token decays, corrects and reads, in the chunked form a training
-step needs.  The mathematics of the op ``gated_delta_rule``
-(``ops/decoder_ops.py``), plain ``jax.numpy`` with one ``lax.scan`` over the
-chunks: the XLA lowering, and what the CPU runs.
+step needs, with its backward written by hand.  The mathematics of the op
+``gated_delta_rule`` and of its grad op (``ops/decoder_ops.py``), plain
+``jax.numpy`` with one ``lax.scan`` over the chunks forward and two
+backward: the XLA lowering, and what the CPU runs.
 
 For one value head (its key head is ``h // (Hv // Hk)``: key head j serves
 the value heads ``j * Hv / Hk`` and the ``Hv / Hk - 1`` after it), with
@@ -14,23 +15,68 @@ the value heads ``j * Hv / Hk`` and the ``Hv / Hk - 1`` after it), with
     o_t = S_t^T q_t
 
 Chunked (``C`` tokens a chunk, ``G_i`` the running sum of g inside the
-chunk, ``S`` the state the chunk starts from)::
+chunk, ``gamma = exp(G)``, ``e = exp(G_C - G)``, ``D_ij = exp(G_i - G_j)``
+for ``i >= j`` else 0, ``S`` the state the chunk starts from)::
 
-    A_ij = beta_i (k_i . k_j) exp(G_i - G_j)             for i > j, else 0
+    A    = beta_i (k_i . k_j) D_ij                       for i > j, else 0
     T    = (I + A)^{-1}                                  unit lower triangular
-    U    = T (beta * v),   W = T (beta * exp(G) * k)
+    U    = T (beta * v),   W = T (beta * gamma * k)
+    P    = (Q K^T) * D
     V'   = U - W S                                       what each token writes
-    o_i  = exp(G_i) (q_i S) + sum_{j <= i} (q_i . k_j) exp(G_i - G_j) V'_j
-    S   <- exp(G_C) S + sum_j exp(G_C - G_j) k_j V'_j^T
+    S   <- exp(G_C) S + K^T (e * V')                     (the walk: these two)
+    O    = gamma * (Q S) + P V'
 
-Everything up to ``W`` is made for all chunks at once; only the last three
-lines walk the chunks one after another.  Decays, their sums, the inverse,
-its two products and the state are float32 (the inverse and its products at
-the highest matmul precision); the other contractions take their inputs in
-the AMP type where ``fluid.amp`` is on and accumulate in float32.  No
+``_system`` makes everything down to ``P`` for all chunks at once.  ``_walk``
+goes through the chunks one after another and carries the state and nothing
+else: two products a step, which emits ``V'`` and the state the chunk
+STARTED from.  ``O`` reads those for all chunks at once, outside the walk.
+
+The backward (``_rule``'s ``jax.custom_vjp``; nothing differentiates through
+a scan or through the inverse) keeps the five operands alone.  It makes the
+system again and walks again for ``V'`` and every chunk's ``S`` (no ``O``),
+makes ``P^T dO`` and ``Q^T (gamma * dO)`` for all chunks at once, then walks
+from the last chunk to the first with ``dS`` (zero behind the last), again
+two products a step, emitting ``dV'`` and the ``dS`` it came with::
+
+    dV'  = P^T dO + e * (K dS)
+    dS  <- Q^T (gamma * dO) + exp(G_C) dS - W^T dV'
+
+and everything else is made for all chunks at once from ``dV'``, ``dS`` (of
+the state the chunk hands ON), ``S``, ``V'`` and ``dO``::
+
+    dU   = dV',   dW = -dV' S^T
+    d(beta * v) = T^T dU,   d(beta * gamma * k) = T^T dW
+    dT   = dU (beta * v)^T + dW (beta * gamma * k)^T     never made:
+    dA   = -T^T dT T^T                                   kept where i > j
+         = -d(beta * v) U^T - d(beta * gamma * k) W^T
+    dP   = dO V'^T
+    dQ   = (gamma * dO) S^T + (dP * D) K
+    dK   = (dP * D)^T Q + (e * V') dS^T + beta * gamma * d(beta gamma k)
+           + (X + X^T) K              with X = beta_i dA_ij D_ij, summed
+                                      over the key head's value heads
+    dv   = beta * d(beta * v)
+    dbeta_i = d(beta v)_i . v_i + gamma_i d(beta gamma k)_i . k_i
+              + sum_j dA_ij (k_i . k_j) D_ij
+    dG_i = (gamma_i dO_i) . (Q S)_i + beta_i gamma_i d(beta gamma k)_i . k_i
+           + sum_j Y_ij - sum_j Y_ji - e_i de_i,   Y = dP * P + dA * A,
+           e * de = sum_v (dV' - P^T dO) * V'
+    dG_C += sum_i e_i de_i + exp(G_C) <dS, S>
+    dg   = the running sum of dG from the chunk's end
+
+so every term that holds an ``exp`` of ``G`` comes back multiplied by that
+same ``exp`` and nothing is ever divided by one.
+
+Precision, forward and its mirror image backward.  Decays, their sums, the
+inverse and its two products are float32, the products at the highest
+matmul precision; so are ``dA`` and the cotangents through ``U`` and ``W``
+(``T^T dU``, ``T^T dW``); the carried ``S`` and ``dS`` are float32.  Every
+other contraction takes its inputs in the AMP type where ``fluid.amp`` is on
+and accumulates in float32; the walks read ``W`` and ``K`` and emit the
+states in that type, which is the one their products cast them to.  No
 exponent is ever positive: ``exp`` sees ``G_i - G_j`` for ``i >= j`` only,
 the rest is masked to ``-inf`` BEFORE the exponential, so a decay that
-underflows inside a chunk gives zeros and never ``inf * 0``.
+underflows inside a chunk gives zeros and never ``inf * 0``, in the
+cotangents too.
 """
 
 from __future__ import annotations
@@ -44,15 +90,11 @@ from jax import lax
 _exact = functools.partial(jnp.matmul, precision=lax.Precision.HIGHEST)
 
 
-def _dot(spec, a, b):
-    """A contraction with float32 accumulation, its inputs in the AMP
-    compute type where AMP is on."""
-    from ..fluid import amp
-
-    low = amp.compute_dtype()
-    if low is not None:
-        a, b = a.astype(low), b.astype(low)
-    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
+def _dot(low, spec, a, b):
+    """A contraction with float32 accumulation, its inputs in ``low`` (the
+    AMP compute type where AMP is on, else None: as they are)."""
+    return jnp.einsum(spec, _cast(low, a), _cast(low, b),
+                      preferred_element_type=jnp.float32)
 
 
 def l2norm(x, eps):
@@ -83,10 +125,147 @@ def unit_lower_inverse(a):
     return x
 
 
+def solve_cotangents(inv, z, dz):
+    """For ``z = inv x`` with ``inv = (I + a)^{-1}``: ``(dx, da)`` from
+    ``dz``, ``da`` before the mask that keeps it below the diagonal.
+    ``dx = inv^T dz``, and the inverse's closed form ``da = -inv^T dinv
+    inv^T`` with ``dinv = dz x^T`` is ``-dx z^T``: no [C, C] x [C, C]
+    product is left of it."""
+    dx = _exact(_t(inv), dz)
+    return dx, -_exact(dx, _t(z))
+
+
 def _chunks(x, n, c):
     """[B, n * c, H, ...] -> [n, B, H, c, ...]."""
     x = x.reshape((x.shape[0], n, c) + x.shape[2:])
     return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 2, 3)
+
+
+def _t(x):
+    return jnp.swapaxes(x, -1, -2)
+
+
+def _cast(low, x):
+    return x if low is None else x.astype(low)
+
+
+def _system(low, qc, kc, vc, gc, bc):
+    """Everything of every chunk that no state enters.  qc, kc:
+    [n,B,Hk,C,dk]; vc: [n,B,Hk,R,C,dv]; gc, bc: [n,B,Hk,R,C]; all float32."""
+    c = qc.shape[-2]
+    at = jnp.arange(c)
+    gsum = jnp.cumsum(gc, -1)
+    decay = jnp.exp(jnp.where(at[:, None] >= at[None, :],
+                              gsum[..., :, None] - gsum[..., None, :],
+                              -jnp.inf))                    # [n,B,Hk,R,C,C]
+    gamma = jnp.exp(gsum)
+    kk = _dot(low, "nbhid,nbhjd->nbhij", kc, kc)[:, :, :, None]
+    below = at[:, None] > at[None, :]
+    inv = unit_lower_inverse(
+        jnp.where(below, bc[..., :, None] * kk * decay, 0.0))
+    xu = bc[..., None] * vc
+    xw = (bc * gamma)[..., None] * kc[:, :, :, None]
+    qk = _dot(low, "nbhid,nbhjd->nbhij", qc, kc)[:, :, :, None]
+    return dict(
+        decay=decay, gamma=gamma, kk=kk, below=below, inv=inv,
+        u=_exact(inv, xu), w=_exact(inv, xw), scores=qk * decay,
+        # what is left of each token's write, and of the state, at the
+        # chunk's end
+        to_end=jnp.exp(gsum[..., -1:] - gsum), kept=gamma[..., -1])
+
+
+def _walk(low, kc, m):
+    """The forward walk: (what each token writes, the state each chunk
+    starts from), the states in the type the products against them take."""
+    def step(state, xs):
+        k_i, u_i, w_i, e_i, a_i = xs
+        start = _cast(low, state)
+        wrote = u_i - _dot(low, "bhrck,bhrkv->bhrcv", w_i, start)
+        state = a_i[..., None, None] * state + _dot(
+            low, "bhck,bhrcv->bhrkv", k_i, e_i[..., None] * wrote)
+        return state, (wrote, start)
+
+    u = m["u"]                                          # [n,B,Hk,R,C,dv]
+    _, (wrote, starts) = lax.scan(
+        step, jnp.zeros(u.shape[1:4] + (kc.shape[-1], u.shape[-1]),
+                        jnp.float32),
+        (_cast(low, kc), u, _cast(low, m["w"]), m["to_end"], m["kept"]))
+    return wrote, starts
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _rule(low, qc, kc, vc, gc, bc):
+    """The rule over chunked float32 operands (``_system``'s) ->
+    [n,B,Hk,R,C,dv] float32; ``low``: the AMP type's name or None."""
+    m = _system(low, qc, kc, vc, gc, bc)
+    wrote, starts = _walk(low, kc, m)
+    return m["gamma"][..., None] * _dot(
+        low, "nbhck,nbhrkv->nbhrcv", qc, starts) \
+        + _dot(low, "nbhrcj,nbhrjv->nbhrcv", m["scores"], wrote)
+
+
+def _rule_fwd(low, *operands):
+    return _rule(low, *operands), operands
+
+
+def _rule_bwd(low, operands, dout):
+    """The five cotangents from the five operands and ``dout`` alone (the
+    module's docstring has the equations)."""
+    qc, kc, vc, gc, bc = operands
+    m = _system(low, *operands)
+    wrote, starts = _walk(low, kc, m)
+    gamma, decay, inv, to_end = m["gamma"], m["decay"], m["inv"], m["to_end"]
+    from_out = _dot(low, "nbhrij,nbhriv->nbhrjv", m["scores"], dout)
+    read = gamma[..., None] * dout
+
+    def step(dstate, xs):
+        k_i, w_i, e_i, a_i, out_i, read_i = xs
+        dwrote = out_i + e_i[..., None] * _dot(
+            low, "bhck,bhrkv->bhrcv", k_i, dstate)
+        before = read_i + a_i[..., None, None] * dstate - _dot(
+            low, "bhrck,bhrcv->bhrkv", w_i, dwrote)
+        return before, (dwrote, dstate)
+
+    _, (dwrote, dnext) = lax.scan(
+        step, jnp.zeros(starts.shape[1:], jnp.float32),
+        (_cast(low, kc), _cast(low, m["w"]), to_end, m["kept"], from_out,
+         _dot(low, "nbhck,nbhrcv->nbhrkv", qc, read)), reverse=True)
+
+    # the inverse and its two products, float32 at the highest precision
+    dw = -_dot(low, "nbhrcv,nbhrkv->nbhrck", dwrote, starts)
+    dxu, da_u = solve_cotangents(inv, m["u"], dwrote)
+    dxw, da_w = solve_cotangents(inv, m["w"], dw)
+    da = jnp.where(m["below"], da_u + da_w, 0.0)
+    # dA * A without its beta_i: beta's cotangent by row, and (times beta)
+    # that of G_i - G_j
+    through = da * m["kk"] * decay
+    dkk = jnp.sum(bc[..., :, None] * da * decay, 3)
+    dstep = jnp.sum(dxw * kc[:, :, :, None], -1)       # of beta * gamma
+    # the scores and the read of the state
+    dscores = _dot(low, "nbhriv,nbhrjv->nbhrij", dout, wrote)
+    dqk = jnp.sum(dscores * decay, 3)
+    dq_read = _dot(low, "nbhrcv,nbhrkv->nbhrck", read, starts)
+    written = to_end[..., None] * wrote
+    dq = jnp.sum(dq_read, 3) + _dot(low, "nbhij,nbhjd->nbhid", dqk, kc)
+    dk = _dot(low, "nbhij,nbhid->nbhjd", dqk, qc) \
+        + _dot(low, "nbhrcv,nbhrkv->nbhck", written, dnext) \
+        + jnp.sum((bc * gamma)[..., None] * dxw, 3) \
+        + _dot(low, "nbhij,nbhjd->nbhid", dkk + _t(dkk), kc)
+    dbeta = jnp.sum(dxu * vc, -1) + gamma * dstep + jnp.sum(through, -1)
+    # every exponential of G: gamma (read and W), G_i - G_j (scores and A),
+    # G_C - G (the write's decay to the chunk's end), G_C (the state's)
+    pair = dscores * m["scores"] + bc[..., :, None] * through
+    dto_end = jnp.sum((dwrote - from_out) * wrote, -1)
+    dgsum = jnp.sum(dq_read * qc[:, :, :, None], -1) \
+        + bc * gamma * dstep + jnp.sum(pair, -1) - jnp.sum(pair, -2) - dto_end
+    dgsum = dgsum.at[..., -1].add(
+        jnp.sum(dto_end, -1) + m["kept"] * jnp.sum(
+            dnext * starts.astype(jnp.float32), (-1, -2)))
+    dg = lax.cumsum(dgsum, dgsum.ndim - 1, reverse=True)
+    return dq, dk, bc[..., None] * dxu, dg, dbeta
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
 
 
 def chunked(q, k, v, g, beta, chunk=64, scale=0.0, norm_eps=0.0):
@@ -95,6 +274,8 @@ def chunked(q, k, v, g, beta, chunk=64, scale=0.0, norm_eps=0.0):
     ``norm_eps`` > 0: q and k are l2-normed per head first, with that
     epsilon.  ``T`` need not be a multiple of ``chunk``: the tail is padded
     with tokens that write nothing (beta 0) and decay nothing (g 0)."""
+    from ..fluid import amp
+
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2:]
     rep = hv // hk
@@ -113,36 +294,9 @@ def chunked(q, k, v, g, beta, chunk=64, scale=0.0, norm_eps=0.0):
         a = a.astype(f32).reshape((b, t + pad, hk, rep) + a.shape[3:])
         return jnp.moveaxis(_chunks(a, n, chunk), 4, 3)
 
-    qc, kc = _chunks(q, n, chunk), _chunks(k, n, chunk)     # [n,B,Hk,C,dk]
-    vc, gc, bc = per_value(v), per_value(g), per_value(beta)
-    gsum = jnp.cumsum(gc, -1)
-    at = jnp.arange(chunk)
-    seen = at[:, None] >= at[None, :]
-    decay = jnp.exp(jnp.where(seen, gsum[..., :, None] - gsum[..., None, :],
-                              -jnp.inf))                    # [n,B,Hk,R,C,C]
-    kk = _dot("nbhid,nbhjd->nbhij", kc, kc)[:, :, :, None]
-    a = jnp.where(at[:, None] > at[None, :],
-                  bc[..., :, None] * kk * decay, 0.0)
-    inv = unit_lower_inverse(a)
-    u = _exact(inv, bc[..., None] * vc)                     # [n,B,Hk,R,C,dv]
-    w = _exact(inv, (bc * jnp.exp(gsum))[..., None] * kc[:, :, :, None])
-    scores = _dot("nbhid,nbhjd->nbhij", qc, kc)[:, :, :, None] * decay
-    # what is left of each token's write at the chunk's end
-    to_end = jnp.exp(gsum[..., -1:] - gsum)
-
-    @jax.checkpoint
-    def step(state, xs):
-        q_i, k_i, u_i, w_i, s_i, g_i, e_i = xs
-        wrote = u_i - _dot("bhrck,bhrkv->bhrcv", w_i, state)
-        out = jnp.exp(g_i)[..., None] \
-            * _dot("bhck,bhrkv->bhrcv", q_i, state) \
-            + _dot("bhrcj,bhrjv->bhrcv", s_i, wrote)
-        state = jnp.exp(g_i[..., -1])[..., None, None] * state \
-            + _dot("bhck,bhrcv->bhrkv", k_i, e_i[..., None] * wrote)
-        return state, out
-
-    _, out = lax.scan(step, jnp.zeros((b, hk, rep, dk, dv), f32),
-                      (qc, kc, u, w, scores, gsum, to_end))
+    out = _rule(amp.compute_dtype(), _chunks(q, n, chunk),
+                _chunks(k, n, chunk), per_value(v), per_value(g),
+                per_value(beta))
     # [n,B,Hk,R,C,dv] -> [B, n*C, Hv, dv]
     out = jnp.transpose(out, (1, 0, 4, 2, 3, 5)).reshape(b, t + pad, hv, dv)
     return out[:, :t].astype(v.dtype)

@@ -3,6 +3,8 @@ its grad op) and the ungated form of ``short_conv``: the chunked
 computation against the token-by-token recurrence, value and every
 cotangent, in float32 on the CPU."""
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -62,26 +64,163 @@ def exact_products():
         yield
 
 
-@pytest.mark.parametrize("t,chunk", [(53, 16), (48, 16), (70, 32), (7, 16),
-                                     (96, 64)])
-def test_chunked_equals_the_recurrence_value_and_all_five_cotangents(
-        t, chunk):
-    """Several chunks with a ragged last one (53 = 3 x 16 + 5, 70 = 2 x 32
-    + 6), whole chunks, a sequence under one chunk, and the published chunk
-    of 64; two value heads a key head."""
-    xs = operands(t, seed=t)
+def rel(got, want):
+    """The distance of two arrays as a share of the second's norm."""
+    got, want = (np.asarray(a, np.float64) for a in (got, want))
+    return float(np.linalg.norm(got - want) / (np.linalg.norm(want) + 1e-30))
+
+
+#: (tokens, chunk, batch, key heads, value heads, AMP type or None, decay)
+CASES = {
+    # several chunks with a ragged last one (53 = 3 x 16 + 5, 70 = 2 x 32
+    # + 6), whole chunks, a sequence under one chunk, the published chunk
+    "ragged_53_by_16": (53, 16, 2, 2, 4, None, 0.5),
+    "whole_48_by_16": (48, 16, 2, 2, 4, None, 0.5),
+    "ragged_70_by_32": (70, 32, 2, 2, 4, None, 0.5),
+    "under_a_chunk": (7, 16, 2, 2, 4, None, 0.5),
+    "whole_96_by_64": (96, 64, 2, 2, 4, None, 0.5),
+    # one value head a key head; one row; whole and ragged chunks of 64
+    "one_value_head_a_key_head": (53, 16, 2, 2, 2, None, 0.5),
+    "one_value_head_whole_chunks": (64, 16, 2, 3, 3, None, 0.5),
+    "one_row": (48, 16, 1, 2, 4, None, 0.5),
+    "whole_128_by_64": (128, 64, 2, 1, 2, None, 0.5),
+    "ragged_150_by_64": (150, 64, 1, 2, 2, None, 0.5),
+    # g down to -200 a token: exp(G_i - G_j) underflows within a few tokens
+    "underflow": (53, 16, 2, 2, 4, None, 200.0),
+    "underflow_one_value_head_by_64": (150, 64, 1, 2, 2, None, 200.0),
+    # bf16 q, k, v under AMP: every contraction but the inverse's in bf16
+    "bf16_ragged_53_by_16": (53, 16, 2, 2, 4, "bfloat16", 0.5),
+    "bf16_one_value_head_by_64": (128, 64, 2, 2, 2, "bfloat16", 0.5),
+    "bf16_underflow": (70, 32, 2, 2, 4, "bfloat16", 200.0),
+}
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_chunked_equals_the_recurrence_value_and_all_five_cotangents(case):
+    """The chunked form and the backward it carries (written by hand)
+    against the recurrence and ``jax.grad`` of it.  The recurrence has no
+    padded tail, so equal cotangents at a ragged length say that the tail
+    writes nothing, decays nothing and hands nothing back; under AMP the
+    inputs are the same bf16 numbers on both sides and the distance is
+    bf16's rounding of the contractions."""
+    t, chunk, b, hk, hv, low, decay = CASES[case]
+    xs = operands(t, seed=t, b=b, hk=hk, hv=hv, decay=decay)
+    if low:
+        xs = tuple(a.astype(low) for a in xs[:3]) + xs[3:]
+    exact = tuple(a.astype(jnp.float32) for a in xs)
     scale = xs[0].shape[-1] ** -0.5
-    want = recurrence(*xs, scale)
-    got = delta_rule.chunked(*xs, chunk=chunk)
-    assert got.shape == want.shape == xs[2].shape
-    np.testing.assert_allclose(got, want, atol=5e-6)
-    grads = jax.grad(weighted_sum(
-        lambda *a: delta_rule.chunked(*a, chunk=chunk)), range(5))(*xs)
+
+    def rule(*a):
+        return delta_rule.chunked(*a, chunk=chunk).astype(jnp.float32)
+
+    want = recurrence(*exact, scale)
     wants = jax.grad(weighted_sum(lambda *a: recurrence(*a, scale)),
-                     range(5))(*xs)
+                     range(5))(*exact)
+    with fluid.amp.amp_guard(low, keep_activations=True) if low \
+            else contextlib.nullcontext():
+        got = delta_rule.chunked(*xs, chunk=chunk)
+        grads = jax.grad(weighted_sum(rule), range(5))(*xs)
+    assert got.shape == want.shape == xs[2].shape
+    assert got.dtype == xs[2].dtype
+    assert [g.dtype for g in grads] == [a.dtype for a in xs]
+    for g in (got,) + grads:
+        assert bool(jnp.isfinite(g).all())
+    if low:
+        assert rel(got, want) < 0.02
+        for name, g, w in zip("q k v g beta".split(), grads, wants):
+            assert rel(g, w) < 0.03, name
+        return
+    np.testing.assert_allclose(got, want, atol=2e-5 if decay > 1 else 5e-6)
+    # a float32 running sum near -6,000 (64 tokens of g near -100) is exact
+    # to 5e-4, and so is every exp(G_i - G_j) made from it
+    near = 1e-3 if chunk * decay > 4000 else 2e-5
     for name, g, w in zip("q k v g beta".split(), grads, wants):
-        np.testing.assert_allclose(g, w, atol=2e-5 * float(
+        np.testing.assert_allclose(g, w, atol=near * float(
             jnp.abs(w).max()) + 1e-6, err_msg=name)
+
+
+def eqns_of(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                if hasattr(getattr(sub, "jaxpr", sub), "eqns"):
+                    yield from eqns_of(sub)
+
+
+def products(jaxpr):
+    return [e for e in eqns_of(jaxpr) if e.primitive.name == "dot_general"]
+
+
+def test_each_walk_holds_two_products_and_the_inverse_goes_back_in_none():
+    """The mechanism, read off the jaxprs at a small size: the forward is
+    one ``scan`` whose body holds exactly two ``dot_general``; the backward
+    is two (the walk again, and the walk from the last chunk to the
+    first), two products each; and outside the ladder that makes the
+    inverse again (two [C, C] x [C, C] products a level, log2(C) - 1
+    levels) the backward has NO such product: the closed form
+    ``-T^T dT T^T`` is taken as ``-(T^T dZ) Z^T``.  Nothing differentiates
+    through a scan or the ladder (autodiff's backward of it alone is four
+    such products a level), and a product that slips back into a walk
+    fails here."""
+    chunk = 16          # no other width of the operands is 16
+    xs = operands(48, seed=0, b=1, hk=2, hv=4, dk=8, dv=12)
+
+    def rule(*a):
+        return delta_rule.chunked(*a, chunk=chunk)
+
+    forward = jax.make_jaxpr(rule)(*xs)
+    out, vjp = jax.vjp(rule, *xs)
+    backward = jax.make_jaxpr(vjp)(out)
+    for jaxpr, walks in ((forward, 1), (backward, 2)):
+        scans = [e for e in eqns_of(jaxpr) if e.primitive.name == "scan"]
+        assert len(scans) == walks
+        for scan in scans:
+            assert len(products(scan.params["jaxpr"])) == 2
+            assert scan.params["length"] == 3
+        assert [scan.params["reverse"] for scan in scans] == \
+            [False, True][:walks]
+
+    def square(eqn):
+        return all(v.aval.shape[-2:] == (chunk, chunk) for v in eqn.invars)
+
+    ladder = 2 * (int(np.log2(chunk)) - 1)
+    assert len([e for e in products(forward) if square(e)]) == ladder
+    assert len([e for e in products(backward) if square(e)]) == ladder
+    through_the_ladder = jax.make_jaxpr(
+        jax.vjp(delta_rule.unit_lower_inverse,
+                jnp.zeros((chunk, chunk)))[1])(jnp.zeros((chunk, chunk)))
+    assert len([e for e in products(through_the_ladder) if square(e)]) \
+        == 2 * ladder
+
+
+def test_the_inverses_cotangent_in_closed_form_is_autodiffs():
+    """``z = (I + a)^{-1} x``: ``solve_cotangents`` against ``jax.vjp``
+    through the five levels of ``unit_lower_inverse`` and the product, and
+    against the closed form as it is usually written, ``dA = -T^T dT T^T``
+    with ``dT = dz x^T``, below the diagonal."""
+    rng = np.random.RandomState(5)
+    a = jnp.asarray(np.tril(rng.randn(3, 64, 64) * 0.2, -1), jnp.float32)
+    x = jnp.asarray(rng.randn(3, 64, 24), jnp.float32)
+    dz = jnp.asarray(rng.randn(3, 64, 24), jnp.float32)
+    z, vjp = jax.vjp(lambda a, x: delta_rule.unit_lower_inverse(a) @ x, a, x)
+    want_a, want_x = vjp(dz)
+    inv = delta_rule.unit_lower_inverse(a)
+    got_x, got_a = delta_rule.solve_cotangents(inv, z, dz)
+    np.testing.assert_allclose(got_x, want_x,
+                               atol=2e-5 * float(jnp.abs(want_x).max()))
+    want_a = np.tril(np.asarray(want_a), -1)
+    np.testing.assert_allclose(np.tril(np.asarray(got_a), -1), want_a,
+                               atol=2e-5 * np.abs(want_a).max())
+    t64 = np.asarray(inv, np.float64)
+    written = -np.swapaxes(t64, -1, -2) @ (
+        np.asarray(dz, np.float64) @ np.swapaxes(np.asarray(x, np.float64),
+                                                 -1, -2)
+    ) @ np.swapaxes(t64, -1, -2)
+    np.testing.assert_allclose(np.tril(np.asarray(got_a), -1),
+                               np.tril(written, -1),
+                               atol=2e-5 * np.abs(want_a).max())
 
 
 def test_a_decay_that_underflows_inside_a_chunk_gives_zeros_not_nans():
@@ -170,7 +309,8 @@ def build_rule(t, hk=2, hv=4, dk=8, dv=12, chunk=16, **attrs):
 def test_the_grad_op_equals_jax_grad_of_the_forward(t):
     """The op and its grad op through the executor against ``jax.grad`` of
     ``delta_rule.chunked``: all five inputs' gradients; the backward is
-    the op's own and is not counted as a call."""
+    the op's own and is counted as the one written by hand, not as a
+    call."""
     names, _, out = build_rule(t, norm_eps=1e-6)
     assert tuple(out.shape[1:]) == (t, 4, 12)
     weights = np.cos(np.arange(12, dtype="float32"))
@@ -192,7 +332,8 @@ def test_the_grad_op_equals_jax_grad_of_the_forward(t):
     assert {k: v for k, v in fluid.profiler.counters().items()
             if k.startswith("ops.delta_rule")} == {
         'ops.delta_rule.calls{chunk="16",dim="12",key_heads="2",path="xla",'
-        'value_heads="4"}': 1}
+        'value_heads="4"}': 1,
+        'ops.delta_rule.grad_calls{chunk="16",path="by_hand"}': 1}
 
 
 def test_infer_rule_and_layer_of_the_delta_rule():
