@@ -28,7 +28,11 @@ routing plan outlives, and three ``pass="backward"`` for every
 ``moe_experts_grad`` lowered;
 ``ops.moe.column_tiles{kernel,width,tile,tiles,ragged}``, which
 ``ops/pallas_grouped.py`` counts the same way for every grouped-product
-kernel call it traces: the column tile that product took).
+kernel call it traces: the column tile that product took;
+``ops.sparse_attention.tiles{kernel,kind}``, which
+``ops/pallas_sparse_flash.py`` counts the same way for every attention
+kernel call it traces: the live tiles one head walks there,
+``kind="interior"`` where no positional mask is made and ``"edge"``).
 """
 
 from __future__ import annotations
@@ -41,12 +45,12 @@ from .registry import register_grad, register_op
 INDEX_Q_BLOCK = 512
 
 
-def _count(name, **labels):
+def _count(name, value=1, **labels):
     try:
         from .. import observe
 
-        observe.registry().inc(name, labels={k: str(v)
-                                             for k, v in labels.items()})
+        observe.registry().inc(name, value,
+                               labels={k: str(v) for k, v in labels.items()})
     except Exception:
         pass  # accounting must never fail the trace it measures
 

@@ -30,6 +30,39 @@ scalar-prefetch table ``[tiles, band]`` int32: the call's first operand,
 whose shape states the band to whoever counts the kernel's work from its
 declared shapes.  With a window there is no selection.
 
+Where a selection or a window makes the mask more than one compare, a live
+tile is INTERIOR or EDGE, told apart from the grid position and the static
+shapes (``interior_reach``, ``_by_kind``), and the kernel holds one body
+for each under ``pl.when``.  Interior: every (query, key) pair of the tile
+satisfies the causal rule and the band's: the key tile lies wholly before
+the query tile and, under a window, its farthest pair is still inside it
+(at window 2,048 in tiles of 512 the three tiles before the diagonal).  The
+body there makes no positional mask: no iota, no compare; under a window no
+select either, with a selection the mask is the selection alone.  Edge: the
+diagonal tile and the band's far tile(s), which mask by position as well.
+The diagonal tile keeps the causal compare under a selection too: ``Sel``
+is an input of the op, nothing makes it causal, and ``blocked_attention``
+ANDs it with the causal rule, so the kernels give causal-AND-selection for
+any ``Sel``.  120 of the 136 live tiles are interior at 8,192 tokens under a
+selection, 30 of 50 in a band of 2,048 over 6,144 (``tile_counts``; counted
+for every call traced as ``ops.sparse_attention.tiles{kernel,kind}``).
+With neither a selection nor a window a kernel keeps ONE body that masks
+every live tile: on the chip (v5e, the kernels alone at the decoder cells'
+shapes, one body -> two) the second body cost the forward 1.3% at head
+width 128, moved dK/dV by +0.1 to +0.3% and dQ by -0.9 to +0.1%, where under
+a selection it saves 1.4 / 4.7 / 1.9% of forward / dQ / dK/dV and in a band
+0.6 / 4.6 / 0.4%: the lone causal compare rides in issue slots that are
+free, and a second body's code is not (PERF.md, Findings PR 46).
+
+The forward keeps a row's running maximum and sum in EVERY lane of a
+``[rows, 128]`` scratch (the lane reductions hand them back so): a
+``[rows, 1]`` column would cost a lane broadcast through the permute unit
+for each row group and tile, and that round trip, not the mask, was what
+bound the forward's schedule.  A cut score reads ``MASKED``, which is under
+the running maximum's first value, so its exponential is an exact 0 and the
+forward selects once.  The row statistics leave the kernel as the
+``[.., T, 1]`` log-sum-exp the backward kernels read.
+
 The kernels carry names of their own (``sparse_flash_fwd``,
 ``sparse_flash_dq``, ``sparse_flash_dkv``; with a window
 ``window_flash_fwd``, ``window_flash_dq``, ``window_flash_dkv``).
@@ -46,6 +79,13 @@ from jax.experimental import pallas as pl
 from .pallas_flash import NEG_INF, block_index
 
 BLOCK = 512
+#: lanes of a vector register: the forward keeps a row's running maximum
+#: and sum in every lane of a ``[rows, LANE]`` scratch
+LANE = 128
+#: what a cut score reads: under the running maximum's first value
+#: (``NEG_INF``), so ``exp(MASKED - m)`` is an exact 0 for every m a row
+#: can hold and the forward needs no second select
+MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 def _block(t):
@@ -84,32 +124,95 @@ def _scores(q, k, scale):
         preferred_element_type=jnp.float32) * jnp.float32(scale)
 
 
-def _keep(sel_ref, shape, q_off, k_off, window=0):
-    """[bq, bk] bool: the pairs of this tile that count."""
-    qpos = q_off + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    kpos = k_off + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    keep = qpos >= kpos
-    if window:
-        keep = jnp.logical_and(keep, qpos - kpos < jnp.int32(window))
+def _keep(sel_ref, shape, offsets, window=0):
+    """[bq, bk] bool: the pairs of this tile that count; None where all do.
+    ``offsets``: (first query, first key) of an edge tile, whose positions
+    the causal rule and the band are asked about; None in an interior
+    tile, where both hold for every pair and only the selection cuts."""
+    keep = None
+    if offsets is not None:
+        qpos = offsets[0] + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        kpos = offsets[1] + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        keep = qpos >= kpos
+        if window:
+            keep = jnp.logical_and(keep, qpos - kpos < jnp.int32(window))
     if sel_ref is not None:
-        keep = jnp.logical_and(keep, sel_ref[0].astype(jnp.float32) > 0.5)
+        chosen = sel_ref[0].astype(jnp.float32) > 0.5
+        keep = chosen if keep is None else jnp.logical_and(keep, chosen)
     return keep
 
 
-def _q_side_step(q_ref, k_ref, n_k, window):
-    """(k step, q offset, k offset, live) of a grid step of forward and dQ;
-    ``live()`` says whether the step's tile holds a pair that counts.
-    Without a window step s is key tile s, live up to the diagonal; with
-    one it is key tile ``qi - (n_k - 1) + s`` of the band's ``n_k``, live
-    from tile 0 on.  ``live`` is a thunk so that its compare is traced where
-    the caller's ``pl.when`` stands, as it was before there was a window."""
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    bq, bk = q_ref.shape[1], k_ref.shape[1]
+def interior_reach(window, blk, band, selected=False):
+    """How many of the ``band - 1`` tiles before the diagonal one are
+    INTERIOR: run by a body of their own that makes no positional mask.
+    The pairs of a tile ``dist`` tiles back lie ``dist * blk - (blk - 1)``
+    to ``dist * blk + blk - 1`` keys back: all before the query, and all
+    inside a window iff the farthest is.  0 without a selection or a
+    window: the mask is then one compare, a second body buys nothing (the
+    module's docstring has the readings) and the kernel keeps one."""
     if window:
-        kt = qi - jnp.int32(n_k - 1) + ki
-        return ki, qi * jnp.int32(bq), kt * jnp.int32(bk), lambda: kt >= 0
-    q_off, k_off = qi * jnp.int32(bq), ki * jnp.int32(bk)
-    return ki, q_off, k_off, lambda: k_off <= q_off + jnp.int32(bq - 1)
+        return max(min((window - blk) // blk, band - 1), 0)
+    return band - 1 if selected else 0
+
+
+def tile_counts(t, window=0, selected=False):
+    """(interior, edge): the live tiles of each kind that one query head
+    walks in a kernel call over ``t`` positions."""
+    blk = _block(t)
+    n = t // blk
+    band = band_tiles(window, blk, n) if window else n
+    reach = interior_reach(window, blk, band, selected)
+    live = sum(min(j + 1, band) for j in range(n))
+    interior = sum(min(j, reach) for j in range(n))
+    return interior, live - interior
+
+
+def _by_kind(live, dist, reach, offsets, body):
+    """Runs ``body`` in a live tile whose query tile lies ``dist`` tiles
+    after its key tile: ``body(None)`` in an interior one (one of the
+    ``reach`` before the diagonal), ``body(offsets)`` in an edge one (the
+    diagonal tile, the band's far tiles), which masks by position.  One
+    kernel, two bodies; one where nothing is interior."""
+    if not reach:
+        pl.when(live)(lambda: body(offsets))
+        return
+    interior = jnp.logical_and(dist >= 1, dist <= jnp.int32(reach))
+    pl.when(jnp.logical_and(live, interior))(lambda: body(None))
+    pl.when(jnp.logical_and(live, jnp.logical_not(interior)))(
+        lambda: body(offsets))
+
+
+def _q_side_step(q_ref, n_k, window):
+    """(k step, (first query, first key), live, dist) of a grid step of
+    forward and dQ; ``dist``: how many tiles the key tile lies before the
+    query tile.  Without a window step s is key tile s, live up to the
+    diagonal; with one it is key tile ``qi - (n_k - 1) + s`` of the band's
+    ``n_k``, live from tile 0 on."""
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    blk = jnp.int32(q_ref.shape[1])
+    if window:
+        dist = jnp.int32(n_k - 1) - ki
+        kt = qi - dist
+        return ki, (qi * blk, kt * blk), kt >= 0, dist
+    return ki, (qi * blk, ki * blk), ki <= qi, qi - ki
+
+
+def _lanes(x, n):
+    """``x`` [rows, w], every lane of a row the same value, as [rows, n]."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    w = x.shape[1]
+    if n <= w:
+        return x[:, :n]
+    if n % w:               # a head width such as 192: one lane, broadcast
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return pltpu.repeat(x, n // w, axis=1)
+
+
+def _probabilities(s, lse, keep):
+    """exp(s - lse) of the backward kernels, 0 where ``keep`` cuts."""
+    p = jnp.exp(s - lse)
+    return p if keep is None else jnp.where(keep, p, jnp.float32(0.0))
 
 
 def _fwd_kernel(*refs, scale, n_k, has_sel, window=0):
@@ -121,7 +224,8 @@ def _fwd_kernel(*refs, scale, n_k, has_sel, window=0):
     else:
         o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
         sel_ref = None
-    ki, q_off, k_off, live = _q_side_step(q_ref, k_ref, n_k, window)
+    ki, offsets, live, dist = _q_side_step(q_ref, n_k, window)
+    reach = interior_reach(window, q_ref.shape[1], n_k, has_sel)
 
     @pl.when(ki == 0)
     def _init():
@@ -129,27 +233,31 @@ def _fwd_kernel(*refs, scale, n_k, has_sel, window=0):
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(live())
-    def _attend():
+    def attend(offsets):
         s = _scores(q_ref[0], k_ref[0], scale)
-        keep = _keep(sel_ref, s.shape, q_off, k_off, window)
-        s = jnp.where(keep, s, jnp.float32(NEG_INF))
+        keep = _keep(sel_ref, s.shape, offsets, window)
+        if keep is not None:
+            s = jnp.where(keep, s, jnp.float32(MASKED))
         m = m_ref[:]
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        # a row may have no selected key in this tile: exp(-inf - -inf)
-        p = jnp.where(keep, jnp.exp(s - m_new), jnp.float32(0.0))
+        # a cut score is under every m_new: its exp is an exact 0
+        p = jnp.exp(s - _lanes(m_new, s.shape[1]))
         corr = jnp.exp(m - m_new)
         m_ref[:] = m_new
         l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[:] = acc_ref[:] * _lanes(corr, acc_ref.shape[1]) \
+            + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    _by_kind(live, dist, reach, offsets, attend)
 
     @pl.when(ki == n_k - 1)
     def _flush():
         l = jnp.maximum(l_ref[:], jnp.float32(1e-30))
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[:] + jnp.log(l)
+        o_ref[0] = (acc_ref[:] / _lanes(l, acc_ref.shape[1])).astype(
+            o_ref.dtype)
+        lse_ref[0] = (m_ref[:] + jnp.log(l))[:, :1]
 
 
 def _dq_kernel(*refs, scale, n_k, has_sel, window=0):
@@ -161,18 +269,18 @@ def _dq_kernel(*refs, scale, n_k, has_sel, window=0):
     else:
         dq_ref, dq_acc = rest
         sel_ref = None
-    ki, q_off, k_off, live = _q_side_step(q_ref, k_ref, n_k, window)
+    ki, offsets, live, dist = _q_side_step(q_ref, n_k, window)
+    reach = interior_reach(window, q_ref.shape[1], n_k, has_sel)
 
     @pl.when(ki == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(live())
-    def _accum():
+    def accum(offsets):
         k = k_ref[0]
         s = _scores(q_ref[0], k, scale)
-        keep = _keep(sel_ref, s.shape, q_off, k_off, window)
-        p = jnp.where(keep, jnp.exp(s - lse_ref[0]), jnp.float32(0.0))
+        p = _probabilities(s, lse_ref[0],
+                           _keep(sel_ref, s.shape, offsets, window))
         dp = jax.lax.dot_general(
             do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -180,6 +288,8 @@ def _dq_kernel(*refs, scale, n_k, has_sel, window=0):
         dq_acc[:] += jnp.float32(scale) * jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _by_kind(live, dist, reach, offsets, accum)
 
     @pl.when(ki == n_k - 1)
     def _flush():
@@ -200,28 +310,25 @@ def _dkv_kernel(*refs, scale, n_q, n_inner, has_sel, window=0, n_tiles=0):
         dk_ref, dv_ref, dk_acc, dv_acc = rest
         sel_ref = None
     kj, inner = pl.program_id(1), pl.program_id(2)
+    blk = q_ref.shape[1]
     qi = jax.lax.rem(inner, jnp.int32(n_q))
-    bq, bk = q_ref.shape[1], k_ref.shape[1]
     if window:
         qi = kj + qi
-    q_off, k_off = qi * jnp.int32(bq), kj * jnp.int32(bk)
-
-    def live():
-        if window:
-            return qi <= jnp.int32(n_tiles - 1)
-        return q_off + jnp.int32(bq - 1) >= k_off
+        live = qi <= jnp.int32(n_tiles - 1)
+    else:
+        live = qi >= kj
+    reach = interior_reach(window, blk, n_q, has_sel)
 
     @pl.when(inner == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(live())
-    def _accum():
+    def accum(offsets):
         q, do = q_ref[0], do_ref[0]
         s = _scores(q, k_ref[0], scale)                      # [bq, bk]
-        keep = _keep(sel_ref, s.shape, q_off, k_off, window)
-        p = jnp.where(keep, jnp.exp(s - lse_ref[0]), jnp.float32(0.0))
+        p = _probabilities(s, lse_ref[0],
+                           _keep(sel_ref, s.shape, offsets, window))
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)              # [bk, d]
@@ -232,6 +339,9 @@ def _dkv_kernel(*refs, scale, n_q, n_inner, has_sel, window=0, n_tiles=0):
         dk_acc[:] += jnp.float32(scale) * jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _by_kind(live, qi - kj, reach,
+             (qi * jnp.int32(blk), kj * jnp.int32(blk)), accum)
 
     @pl.when(inner == n_inner - 1)
     def _flush():
@@ -288,10 +398,14 @@ def _band_tables(n, band):
 
 
 def _call(kernel, name, table, args, *, grid, in_specs, out_specs,
-          out_shape, scratch_shapes, interpret):
+          out_shape, scratch_shapes, interpret, tiles):
     """``pallas_call``; with a band's table, as its scalar-prefetch
-    operand."""
+    operand.  Counts the call's ``tiles`` (interior, edge) a head."""
     from jax.experimental.pallas import tpu as pltpu
+    from .decoder_ops import _count
+
+    for kind, count in zip(("interior", "edge"), tiles):
+        _count("ops.sparse_attention.tiles", count, kernel=name, kind=kind)
 
     if table is None:
         return pl.pallas_call(
@@ -338,10 +452,10 @@ def _forward(q, k, v, sel, scale, interpret, window=0):
                    pl.BlockSpec((1, blk, 1), resident)],
         out_shape=[jax.ShapeDtypeStruct((b * hq, t, d), q.dtype),
                    jax.ShapeDtypeStruct((b * hq, t, 1), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((blk, 1), jnp.float32),
-                        pltpu.VMEM((blk, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((blk, LANE), jnp.float32),
+                        pltpu.VMEM((blk, LANE), jnp.float32),
                         pltpu.VMEM((blk, d), jnp.float32)],
-        interpret=interpret)
+        interpret=interpret, tiles=tile_counts(t, window, sel is not None))
     return out.reshape(b, hq, t, d), lse.reshape(b, hq, t, 1)
 
 
@@ -360,6 +474,7 @@ def _backward(q, k, v, sel, out, lse, do, scale, interpret, window=0):
     lser, dr = lse.reshape(b * hq, t, 1), delta.reshape(b * hq, t, 1)
     has_sel = sel is not None
     sel_args = [sel] if has_sel else []
+    tiles = tile_counts(t, window, has_sel)
     band, k_table, q_table = n, None, None
     if window:
         band = band_tiles(window, blk, n)
@@ -385,7 +500,7 @@ def _backward(q, k, v, sel, out, lse, do, scale, interpret, window=0):
         out_specs=pl.BlockSpec((1, blk, d), resident),
         out_shape=jax.ShapeDtypeStruct((b * hq, t, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
-        interpret=interpret)
+        interpret=interpret, tiles=tiles)
 
     # dK/dV: grid (b*hkv, k tile, group member x q tile)
     def k_side(i, j, s, *table):
@@ -425,7 +540,7 @@ def _backward(q, k, v, sel, out, lse, do, scale, interpret, window=0):
                    jax.ShapeDtypeStruct((b * hkv, t, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32),
                         pltpu.VMEM((blk, d), jnp.float32)],
-        interpret=interpret)
+        interpret=interpret, tiles=tiles)
     return (dq.reshape(b, hq, t, d), dk.reshape(b, hkv, t, d),
             dv.reshape(b, hkv, t, d))
 

@@ -575,6 +575,113 @@ def test_window_kernels_equal_blocked_attention_gradients_too(
         np.testing.assert_allclose(g, r, atol=2e-4)
 
 
+def _against_blocked(q, k, v, sel, window, block):
+    """The three kernels, interpreted, against ``blocked_attention`` at the
+    flash tests' tolerances: output and dQ / dK / dV."""
+    d = q.shape[-1]
+    w = jnp.cos(jnp.arange(d, dtype=jnp.float32))
+
+    def kernel(q, k, v):
+        return jnp.sum(psf.sparse_flash_attention(q, k, v, sel, None, True,
+                                                  window) * w)
+
+    def blocked(q, k, v):
+        return jnp.sum(decoder_ops.blocked_attention(
+            q, k, v, sel, d ** -0.5, block=block, window=window) * w)
+
+    np.testing.assert_allclose(
+        psf.sparse_flash_attention(q, k, v, sel, None, True, window),
+        decoder_ops.blocked_attention(q, k, v, sel, d ** -0.5, block=block,
+                                      window=window), atol=2e-5)
+    for g, r in zip(jax.grad(kernel, (0, 1, 2))(q, k, v),
+                    jax.grad(blocked, (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(g, r, atol=2e-4)
+
+
+@pytest.mark.parametrize("d,group,window,tiles", [
+    (64, 1, 0, (0, 10)), (64, 4, 32, (3, 6)), (128, 8, 40, (3, 7)),
+    (128, 4, 33, (3, 6)), (256, 8, 0, (0, 10)), (256, 1, 48, (5, 5)),
+    (192, 4, 0, (0, 10)), (192, 1, 32, (3, 6))])
+def test_interior_and_edge_tiles_equal_blocked_attention(
+        monkeypatch, d, group, window, tiles):
+    """Four tiles of 16 a row, so a row's tiles are interior (no positional
+    mask made), diagonal and, under a window, the band's far edge: a window
+    that is a multiple of the tile (32, 48) and one that is not (33, 40),
+    head widths 64 / 128 / 256 and one that is no multiple of a register's
+    lanes (192), groups of 1 / 4 / 8.  Without a window (and without a
+    selection) one body masks every live tile: none is interior."""
+    monkeypatch.setattr(psf, "BLOCK", 16)
+    t, hq = 64, 8
+    assert psf.tile_counts(t, window) == tiles
+    rng = np.random.RandomState(d + group + window)
+    q, k, v = (jnp.asarray(rng.randn(1, h, t, d), jnp.float32)
+               for h in (hq, hq // group, hq // group))
+    _against_blocked(q, k, v, None, window, 16)
+
+
+@pytest.mark.parametrize("case", ["no_key_of_an_interior_tile",
+                                  "no_key_of_the_first_tile", "not_causal"])
+def test_a_selection_cuts_interior_tiles_and_the_diagonal_stays_causal(
+        monkeypatch, case):
+    """An interior tile reads its mask from the selection alone; the
+    diagonal tile still ANDs the causal rule, whatever ``Sel`` holds: a
+    selection that is not causal gives causal-AND-selection, as
+    ``blocked_attention`` does."""
+    monkeypatch.setattr(psf, "BLOCK", 16)
+    b, hq, hkv, t, d = 1, 8, 2, 64, 128
+    rng = np.random.RandomState(11)
+    q, k, v = (jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
+               for h in (hq, hkv, hkv))
+    keep = (rng.rand(b, t, t) < 0.3) | np.eye(t, dtype=bool)
+    if case == "no_key_of_an_interior_tile":
+        keep[:, 40:, 16:32] = False
+    elif case == "no_key_of_the_first_tile":
+        keep[:, 40:, :16] = False
+    if case != "not_causal":
+        keep = np.tril(keep)
+    else:
+        assert np.triu(keep[0], 1).any()
+    sel = jnp.asarray(keep.astype(np.int8))
+    _against_blocked(q, k, v, sel, 0, 16)
+    np.testing.assert_allclose(
+        psf.sparse_flash_attention(q, k, v, sel, None, True),
+        dense(q, k, v, jnp.asarray(np.tril(keep).astype(np.int8))),
+        atol=2e-5)
+
+
+@pytest.mark.parametrize("t,window,selected,interior,edge", [
+    (64, 0, True, 6, 4),        # n (n - 1) / 2 and n at n = 4
+    (64, 0, False, 0, 10),      # one compare to drop: one body, all edge
+    (192, 64, False, 30, 20),   # trinity_mini's band in small: 12 tiles, 5 wide
+    (192, 0, True, 66, 12),
+    (64, 40, False, 3, 7),      # a ragged window: two far tiles a row are edge
+    (16, 0, True, 0, 1)])       # a single tile: the diagonal one
+def test_every_kernel_call_lowered_counts_its_tiles_by_kind(
+        monkeypatch, t, window, selected, interior, edge):
+    """``ops.sparse_attention.tiles{kernel,kind}``: the live tiles one head
+    walks in each of the three calls lowered, interior (a body of their own
+    with no positional mask: only under a selection or a window) and edge."""
+    monkeypatch.setattr(psf, "BLOCK", 16)
+    q = jnp.ones((1, 4, t, 128), jnp.bfloat16)
+    k = jnp.ones((1, 2, t, 128), jnp.bfloat16)
+    sel = jnp.ones((1, t, t), jnp.int8) if selected else None
+    jax.jit(jax.grad(lambda q: psf.sparse_flash_attention(
+        q, k, k, sel, None, True, window).astype(jnp.float32).sum())
+        ).lower(q)
+    family = "window_flash" if window else "sparse_flash"
+    assert counters("ops.sparse_attention.tiles") == {
+        f'ops.sparse_attention.tiles{{kernel="{family}_{kernel}",'
+        f'kind="{kind}"}}': count
+        for kernel in ("fwd", "dq", "dkv")
+        for kind, count in (("interior", interior), ("edge", edge))}
+    n = t // 16
+    if selected:
+        assert (interior, edge) == (n * (n - 1) // 2, n)
+    assert interior + edge == sum(
+        min(j + 1, psf.band_tiles(window, 16, n) if window else n)
+        for j in range(n))
+
+
 def test_a_window_lowers_no_t_by_t_operand(monkeypatch):
     """The window is a static band: the lowered calls hold its table
     [tiles, band] and nothing [.., T, T]; a selection beside a window is
