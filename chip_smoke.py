@@ -191,8 +191,7 @@ def check_losses(losses) -> None:
 
 def train_phase(args) -> None:
     import paddle_tpu.fluid as fluid
-    from paddle_tpu.ops import pallas_fused
-    from paddle_tpu.ops.attention_ops import _flash_decision
+    from paddle_tpu.ops import kernel_choice
 
     loss, feed, shape = build_transformer(fluid, args.rehearse, args.seed)
     before = counters("ops.fused.")
@@ -226,8 +225,8 @@ def train_phase(args) -> None:
          note="host-clock smoke reading, not a benchmark number", ok=True)
 
     text = exe.lower_step(prog, feed, [loss]).as_text()
-    fused = pallas_fused.fused_decision()
-    gates = {"ops.fused.flash_attention": _flash_decision(),
+    fused = kernel_choice.gate("fused")
+    gates = {"ops.fused.flash_attention": kernel_choice.gate("flash"),
              "ops.fused.softmax_xent": fused, "ops.fused.adam": fused}
     kernels_ok = kernel_lines("train", TRAIN_FAMILIES, text, before, gates,
                               args.rehearse)
@@ -245,7 +244,7 @@ def train_phase(args) -> None:
 def serve_phase(args) -> None:
     import paddle_tpu.fluid as fluid
     from paddle_tpu.models import transformer
-    from paddle_tpu.ops import pallas_fused
+    from paddle_tpu.ops import kernel_choice
     from paddle_tpu.serving import DecodeEngine
 
     fluid.amp.disable()  # the engine's model and its oracle are float32
@@ -322,7 +321,7 @@ def serve_phase(args) -> None:
     text = exe.lower_step(model.step_program, zeros,
                           [model.step_fetch, model.logits_fetch],
                           scope=scope).as_text()
-    gates = {"ops.fused.paged_attention": pallas_fused.fused_decision()}
+    gates = {"ops.fused.paged_attention": kernel_choice.gate("fused")}
     kernels_ok = kernel_lines("serve", PAGED_FAMILY, text, before, gates,
                               args.rehearse)
     if not (good and kernels_ok):

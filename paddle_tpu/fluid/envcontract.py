@@ -128,13 +128,16 @@ declare("PADDLE_TPU_VERIFY", "enum", "warn", "analysis",
         "Pre-compile program verifier mode", choices=("warn", "strict",
                                                       "off"))
 declare("PADDLE_TPU_FLASH", "enum", "auto", "ops",
-        "Pallas flash-attention kernel gate: 0 kill-switch wins over "
-        "everything, 1 forces on, AUTO = per-op attr then TPU-backend-only",
+        "Pallas attention-kernel gate (flash, sparse, windowed): 0 forces "
+        "the XLA twin, 1 forces the kernel (interpret mode off-TPU), AUTO = "
+        "TPU-backend-only; the only way to overrule the platform (tests, "
+        "the benchmark's rehearsal)",
         choices=("0", "1", "true", "false", "auto"))
 declare("PADDLE_TPU_FUSED", "enum", "auto", "ops",
-        "Pallas fused-kernel gate (softmax-xent + optimizer sweeps): 0 "
-        "restores the unfused XLA lowering, 1 forces on (interpret mode "
-        "off-TPU), AUTO = TPU-backend-only",
+        "Pallas fused-kernel gate (softmax-xent, optimizer sweeps, paged "
+        "attention): 0 restores the unfused XLA lowering, 1 forces on "
+        "(interpret mode off-TPU), AUTO = TPU-backend-only; the only way to "
+        "overrule the platform (tests, the benchmark's rehearsal)",
         choices=("0", "1", "true", "false", "auto"))
 declare("PADDLE_TPU_SPD", "int", 0, "trainer",
         "Steps per dispatch: K>1 runs the trainer loop as K-step fused "

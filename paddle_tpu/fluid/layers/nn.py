@@ -1277,16 +1277,15 @@ def gpipe_mlp_stack(input, n_layers, act="relu", n_microbatches=4,
 
 
 def ring_attention(q, k, v, causal=False, scale=None, sp_axis="sp",
-                   bias=None, flash=None, name=None):
+                   bias=None, name=None):
     """Fused attention (TPU-native capability beyond the reference — see
     parallel/ring_attention.py + ops/pallas_flash.py).  q, k, v:
     [B, H, T, D].  Under a mesh with an `sp` axis the sequence dim shards
-    across devices and K/V rotate the ICI ring; single-device the executor
-    picks the Pallas flash kernel (fwd + bwd VMEM streaming) or XLA full
-    softmax.  ``bias``, if given, is an additive [B, 1, 1, T] key bias
-    (padding mask).  ``flash``: True forces the Pallas kernel, False
-    forbids it, None (default) = auto (TPU backend, PADDLE_TPU_FLASH
-    honored — ops/attention_ops._use_flash)."""
+    across devices and K/V rotate the ICI ring; single-device the op runs
+    the Pallas flash kernel (fwd + bwd VMEM streaming) or XLA full softmax:
+    the kernel on a TPU backend, XLA elsewhere (ops/kernel_choice.py; no
+    argument of a layer chooses).  ``bias``, if given, is an additive
+    [B, 1, 1, T] key bias (padding mask)."""
     helper = LayerHelper("ring_attention", **locals())
     out = helper.create_variable_for_type_inference(helper.input_dtype("q"))
     out.shape = tuple(q.shape)
@@ -1297,8 +1296,7 @@ def ring_attention(q, k, v, causal=False, scale=None, sp_axis="sp",
         type="ring_attention", inputs=inputs,
         outputs={"Out": [out]},
         attrs={"causal": causal, "scale": float(scale or 0.0),
-               "sp_axis": sp_axis,
-               "flash": -1 if flash is None else int(bool(flash))})
+               "sp_axis": sp_axis})
     return out
 
 def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
@@ -1390,8 +1388,8 @@ def sparse_indexer(input, num_heads, head_dim, topk, theta=10000.0,
     return sel
 
 
-def sparse_attention(q, k, v, selection=None, scale=None, flash=None,
-                     window=0, name=None):
+def sparse_attention(q, k, v, selection=None, scale=None, window=0,
+                     name=None):
     """Causal grouped-query attention, optionally over a per-query
     selection of keys (a sibling of ``ring_attention``; ops/decoder_ops.py
     + ops/pallas_sparse_flash.py).  q: [B, Hq, T, D]; k, v: [B, Hkv, T, D]
@@ -1399,7 +1397,7 @@ def sparse_attention(q, k, v, selection=None, scale=None, flash=None,
     ``selection``: [B, T, T] int8 from ``sparse_indexer`` or None (every
     key s <= t).  ``window``: 0, or a causal window: key s counts for query
     t iff ``0 <= t - s < window`` (a static band that the kernels' grids
-    are cut to; never a [B, T, T] mask).  ``flash`` as in
+    are cut to; never a [B, T, T] mask).  Kernel or twin as for
     ``ring_attention``: the Pallas kernels (the selection as a mask inside
     them) or the blocked XLA path; nothing [Hq, T, T] reaches HBM either
     way."""
@@ -1421,7 +1419,6 @@ def sparse_attention(q, k, v, selection=None, scale=None, flash=None,
         type="sparse_attention", inputs=inputs,
         outputs={"Out": [out], "Lse": [lse]},
         attrs={"scale": float(scale or 0.0), "topk": int(topk),
-               "flash": -1 if flash is None else int(bool(flash)),
                **({"window": int(window)} if window else {})})
     return out
 
@@ -1581,7 +1578,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64, scale=None, norm_eps=0.0,
 
 
 def paged_attention(q, cache_k, cache_v, page_table, bias, scale=1.0,
-                    fused=None, name=None):
+                    name=None):
     """One decode step of attention over a PAGED K/V cache
     (serving/kvpool, ops/decode_ops.py + ops/pallas_paged.py).  q:
     [slots, 1, d_model]; cache_k/cache_v: [num_pages + 1, page_size,
@@ -1589,9 +1586,9 @@ def paged_attention(q, cache_k, cache_v, page_table, bias, scale=1.0,
     [slots, pages_per_slot] int (unmapped entries point at the trash
     page); bias: [slots, 1, pages_per_slot * page_size] additive
     validity bias with exact ``-inf`` past each slot's live length.
-    ``fused``: True forces the Pallas scalar-prefetch gather kernel,
-    False the XLA ``take`` fallback, None (default) = PADDLE_TPU_FUSED
-    auto.  Returns [slots, 1, d_model]."""
+    The Pallas scalar-prefetch gather kernel on a TPU backend, the XLA
+    ``take`` twin elsewhere (ops/kernel_choice.py).  Returns
+    [slots, 1, d_model]."""
     helper = LayerHelper("paged_attention", **locals())
     out = helper.create_variable_for_type_inference(helper.input_dtype("q"))
     out.shape = tuple(q.shape)
@@ -1600,8 +1597,7 @@ def paged_attention(q, cache_k, cache_v, page_table, bias, scale=1.0,
         inputs={"Q": [q], "CacheK": [cache_k], "CacheV": [cache_v],
                 "PageTable": [page_table], "Bias": [bias]},
         outputs={"Out": [out]},
-        attrs={"scale": float(scale),
-               "fused": -1 if fused is None else int(bool(fused))})
+        attrs={"scale": float(scale)})
     return out
 
 
@@ -1727,7 +1723,7 @@ def _stack_params(helper, dtype, n_layer, d_model, d_inner, decoder,
 def transformer_encoder_stack(input, bias=None, n_layer=2, n_head=4,
                               d_inner=None, dropout=0.0, is_test=False,
                               n_microbatches=4, recompute=False,
-                              flash=None, param_attr=None, name=None):
+                              param_attr=None, name=None):
     """A full transformer ENCODER stack as one mesh-aware op (TPU-native
     capability — see parallel/transformer_stack.py).  input: [N, T, D];
     bias: optional [N, 1, 1, T] additive key bias (padding mask).
@@ -1757,16 +1753,14 @@ def transformer_encoder_stack(input, bias=None, n_layer=2, n_head=4,
         attrs={"n_head": int(n_head), "dropout": float(dropout),
                "is_test": bool(is_test),
                "n_microbatches": int(n_microbatches),
-               "recompute": bool(recompute),
-               "flash": -1 if flash is None else int(bool(flash))})
+               "recompute": bool(recompute)})
     return out
 
 
 def transformer_decoder_stack(input, enc_out, src_bias=None, n_layer=2,
                               n_head=4, d_inner=None, dropout=0.0,
                               is_test=False, n_microbatches=4,
-                              recompute=False, flash=None,
-                              param_attr=None, name=None):
+                              recompute=False, param_attr=None, name=None):
     """A full transformer DECODER stack (causal self-attn + cross-attn +
     FFN per layer) as one mesh-aware op; see transformer_encoder_stack.
     input: [N, Tt, D]; enc_out: [N, Ts, D]; src_bias: [N, 1, 1, Ts]."""
@@ -1790,8 +1784,7 @@ def transformer_decoder_stack(input, enc_out, src_bias=None, n_layer=2,
         attrs={"n_head": int(n_head), "dropout": float(dropout),
                "is_test": bool(is_test),
                "n_microbatches": int(n_microbatches),
-               "recompute": bool(recompute),
-               "flash": -1 if flash is None else int(bool(flash))})
+               "recompute": bool(recompute)})
     return out
 
 

@@ -95,10 +95,9 @@ def signature(kind, program, fetch_names, feed_arrays, guard=UNGUARDED,
     that changes what a block traces to and is missing from this list
     serves a stale executable."""
     from . import amp
+    from ..ops import kernel_choice
 
-    mode = {"amp": amp.compute_dtype(),
-            "flash": os.environ.get("PADDLE_TPU_FLASH", ""),
-            "fused": os.environ.get("PADDLE_TPU_FUSED", "")}
+    mode = {"amp": amp.compute_dtype(), **kernel_choice.switches()}
     if guard is not UNGUARDED:
         mode["guard"] = guard.cache_token() if guard is not None else None
     # the program's serial, never id(): a dropped program's id is recycled

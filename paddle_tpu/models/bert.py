@@ -27,7 +27,7 @@ class BertConfig:
     def __init__(self, name, vocab_size=30522, d_model=768, d_inner=3072,
                  n_head=12, n_layer=12, type_vocab_size=2, max_len=512,
                  dropout=0.1, ring_attention=False, stacked=False,
-                 n_microbatches=4, recompute=False, flash_attention=None):
+                 n_microbatches=4, recompute=False):
         self.name = name
         self.vocab_size = vocab_size
         self.d_model = d_model
@@ -49,9 +49,6 @@ class BertConfig:
         self.stacked = stacked
         self.n_microbatches = n_microbatches
         self.recompute = recompute
-        # flash_attention: models/transformer.Config.flash_attention
-        # semantics (True/False/None-auto Pallas streamed attention)
-        self.flash_attention = flash_attention
 
 
 def base_config():
@@ -87,15 +84,13 @@ def encoder_stack(emb, pad_bias, cfg):
             emb, bias=pad_bias, n_layer=cfg.n_layer, n_head=cfg.n_head,
             d_inner=cfg.d_inner, dropout=cfg.dropout,
             n_microbatches=getattr(cfg, "n_microbatches", 4),
-            recompute=getattr(cfg, "recompute", False),
-            flash=getattr(cfg, "flash_attention", None))
+            recompute=getattr(cfg, "recompute", False))
     enc = emb
     for i in range(cfg.n_layer):
         attn = _multi_head_attention(
             enc, enc, enc, pad_bias, cfg.d_model, cfg.n_head, cfg.dropout,
             prefix=f"bert{i}_self",
-            use_ring=getattr(cfg, "ring_attention", False),
-            flash=getattr(cfg, "flash_attention", None))
+            use_ring=getattr(cfg, "ring_attention", False))
         enc = _postprocess(enc, attn, cfg.dropout)
         ff = _ffn(enc, cfg.d_inner, cfg.d_model, prefix=f"bert{i}")
         enc = _postprocess(enc, ff, cfg.dropout)

@@ -28,7 +28,7 @@ class Config:
                  d_inner, n_head, n_layer, dropout=0.1, label_smooth=0.1,
                  moe_experts=0, moe_top_k=2, moe_aux_weight=1e-2,
                  stacked=False, ring_attention=False, n_microbatches=4,
-                 recompute=False, flash_attention=None):
+                 recompute=False):
         self.name = name
         self.src_vocab_size = src_vocab_size
         self.tgt_vocab_size = tgt_vocab_size
@@ -55,12 +55,6 @@ class Config:
         # probability dropout is skipped in this mode (the [T, T] matrix
         # never materializes under the ring).
         self.ring_attention = ring_attention
-        # flash_attention: True routes every attention through the Pallas
-        # streamed kernel (fwd + bwd, ops/pallas_flash.py), False forbids
-        # it, None = auto (on for TPU backends; PADDLE_TPU_FLASH
-        # overrides).  Attention-probability dropout is skipped on the
-        # flash path (the [T, T] matrix never materializes), like ring.
-        self.flash_attention = flash_attention
         self.n_microbatches = n_microbatches
         # recompute=True (stacked mode) wraps each layer in
         # jax.checkpoint: backward rematerializes activations layer by
@@ -117,8 +111,7 @@ def _postprocess(prev, out, dropout):
 
 
 def _multi_head_attention(q_in, k_in, v_in, bias, d_model, n_head,
-                          dropout, prefix, causal=False, use_ring=False,
-                          flash=None):
+                          dropout, prefix, causal=False, use_ring=False):
     """[b, lq, d] x [b, lk, d] -> [b, lq, d]; bias broadcasts into the
     [b, h, lq, lk] logits (None, [lq, lk] causal, or [b, 1, 1, lk] padding).
 
@@ -127,7 +120,16 @@ def _multi_head_attention(q_in, k_in, v_in, bias, d_model, n_head,
     single-device); the causal mask is then expressed via the op's
     ``causal`` flag and ``bias`` must be a key-position padding bias
     ([b, 1, 1, lk]) or None — and attention-probability dropout is skipped
-    (the ring never materializes the probability matrix)."""
+    (the ring never materializes the probability matrix).
+
+    The PROGRAM depends on the flash gate at build time (ROADMAP D20):
+    where ``kernel_choice.gate("flash")`` is open when this is called (a
+    TPU backend, or ``PADDLE_TPU_FLASH=1``) the same fused op is emitted as
+    for ``use_ring``, and it runs the Pallas kernel or, the gate closed
+    again at run time, its XLA twin; where it is closed (a build on the
+    CPU) the composition ``matmul``, ``softmax``, ``dropout``, ``matmul``
+    is.  Only the composition applies attention-probability dropout, so a
+    build on the chip trains without it at any ``dropout``."""
     lq, lk = q_in.shape[1], k_in.shape[1]
     d_k = d_model // n_head
     q = layers.fc(q_in, d_model, num_flatten_dims=2, bias_attr=False,
@@ -143,16 +145,12 @@ def _multi_head_attention(q_in, k_in, v_in, bias, d_model, n_head,
                          perm=[0, 2, 1, 3])
     v = layers.transpose(layers.reshape(v, [-1, lk, n_head, d_k]),
                          perm=[0, 2, 1, 3])
-    from ..ops.attention_ops import _flash_decision
-    if use_ring or flash or (flash is None and _flash_decision()):
-        # the fused attention op: executor picks ring (sp mesh axis) /
-        # Pallas flash / XLA full softmax; prob-dropout is skipped.
-        # flash=None auto-routes here when the backend would take the
-        # Pallas path (TPU, PADDLE_TPU_FLASH honored) so the Config
-        # docstring's "None = auto" holds for dense builds too
+    from ..ops import kernel_choice
+    if use_ring or kernel_choice.gate("flash"):
+        # the fused attention op: ring (sp mesh axis) / Pallas flash / XLA
+        # full softmax, chosen where it is lowered; prob-dropout is skipped
         ctx = layers.ring_attention(q, k, v, causal=causal,
-                                    scale=d_k ** -0.5, bias=bias,
-                                    flash=flash)
+                                    scale=d_k ** -0.5, bias=bias)
     else:
         logits = layers.matmul(layers.scale(q, scale=d_k ** -0.5), k,
                                transpose_y=True)
@@ -238,16 +236,14 @@ def encoder(src_word, cfg, src_len, aux_losses=None):
                 enc, bias=src_bias, n_layer=cfg.n_layer, n_head=cfg.n_head,
                 d_inner=cfg.d_inner, dropout=cfg.dropout,
                 n_microbatches=cfg.n_microbatches,
-                recompute=getattr(cfg, "recompute", False),
-                flash=getattr(cfg, "flash_attention", None))
+                recompute=getattr(cfg, "recompute", False))
         return enc, src_bias
     for i in range(cfg.n_layer):
         with fluid.name_scope(f"encoder.layer{i}.attention"):
             attn = _multi_head_attention(
                 enc, enc, enc, src_bias, cfg.d_model, cfg.n_head,
                 cfg.dropout, prefix=f"enc{i}_self",
-                use_ring=cfg.ring_attention,
-                flash=getattr(cfg, "flash_attention", None))
+                use_ring=cfg.ring_attention)
             enc = _postprocess(enc, attn, cfg.dropout)
         with fluid.name_scope(f"encoder.layer{i}.ffn"):
             ff = _ffn(enc, cfg.d_inner, cfg.d_model, prefix=f"enc{i}",
@@ -273,23 +269,20 @@ def decoder(tgt_word, enc_out, src_bias, cfg, tgt_len, aux_losses=None):
                 dec, enc_out, src_bias=src_bias, n_layer=cfg.n_layer,
                 n_head=cfg.n_head, d_inner=cfg.d_inner, dropout=cfg.dropout,
                 n_microbatches=cfg.n_microbatches,
-                recompute=getattr(cfg, "recompute", False),
-                flash=getattr(cfg, "flash_attention", None))
+                recompute=getattr(cfg, "recompute", False))
         return _head(dec, cfg)
     for i in range(cfg.n_layer):
         with fluid.name_scope(f"decoder.layer{i}.self_attention"):
             self_attn = _multi_head_attention(
                 dec, dec, dec, None, cfg.d_model, cfg.n_head, cfg.dropout,
                 prefix=f"dec{i}_self", causal=True,
-                use_ring=cfg.ring_attention,
-                flash=getattr(cfg, "flash_attention", None))
+                use_ring=cfg.ring_attention)
             dec = _postprocess(dec, self_attn, cfg.dropout)
         with fluid.name_scope(f"decoder.layer{i}.cross_attention"):
             cross = _multi_head_attention(
                 dec, enc_out, enc_out, src_bias, cfg.d_model, cfg.n_head,
                 cfg.dropout, prefix=f"dec{i}_cross",
-                use_ring=cfg.ring_attention,
-                flash=getattr(cfg, "flash_attention", None))
+                use_ring=cfg.ring_attention)
             dec = _postprocess(dec, cross, cfg.dropout)
         with fluid.name_scope(f"decoder.layer{i}.ffn"):
             ff = _ffn(dec, cfg.d_inner, cfg.d_model, prefix=f"dec{i}",

@@ -12,8 +12,7 @@ per-place kernels (op_registry.h OpKernelType).
 
 from __future__ import annotations
 
-import jax.numpy as jnp
-
+from . import kernel_choice
 from .registry import register_op
 
 
@@ -27,13 +26,12 @@ def ring_attention_op(ctx):
     from ..parallel import ring_attention as ra
     from ..parallel import spmd
 
-    flash_req = int(ctx.attr("flash", -1))
     mesh = spmd.active_mesh()
     if mesh is not None and sp_axis in mesh.axis_names \
             and mesh.shape[sp_axis] > 1:
         out = ra.ring_attention(q, k, v, mesh, sp_axis, causal, scale,
                                 bias=bias)
-    elif _flash_decision(flash_req):
+    elif kernel_choice.gate("flash"):
         from . import pallas_fused
         from .pallas_flash import bias_supported, flash_attention
 
@@ -53,32 +51,3 @@ def ring_attention_op(ctx):
     else:
         out = ra.full_attention(q, k, v, causal, scale, bias=bias)
     return {"Out": out}
-
-
-def _flash_decision(flash_req: int = -1) -> bool:
-    """Pallas flash-attention kernel gate.
-
-    Precedence: the PADDLE_TPU_FLASH env kill-switch wins over everything
-    (=0 forces OFF even for models built with flash=True; =1 forces ON),
-    then the per-op attr (1 on / 0 off), then AUTO: on when the backend
-    is a TPU (the kernels compile for the chip and stream K/V through
-    VMEM — ops/pallas_flash.py), off on CPU/GPU (interpret mode is a
-    correctness tool, not a fast path).  Read through the declared env
-    contract (fluid.envcontract) like every other knob."""
-    import jax
-
-    from ..fluid import envcontract
-
-    v = envcontract.get("PADDLE_TPU_FLASH")
-    if v in ("0", "false"):
-        return False
-    if v in ("1", "true"):
-        return True
-    if flash_req != -1:
-        return bool(flash_req)
-    return jax.default_backend() == "tpu"
-
-
-def _use_flash() -> bool:
-    """AUTO-mode gate (no per-op request) — see _flash_decision."""
-    return _flash_decision(-1)

@@ -144,13 +144,12 @@ def paged_attention_op(ctx):
     pt = ctx.input("PageTable")
     bias = ctx.input("Bias")
     scale = float(ctx.attr("scale", 1.0))
-    fused_req = int(ctx.attr("fused", -1))
-    from . import pallas_fused
+    from . import kernel_choice, pallas_fused
 
     # The Pallas kernel is specialized to one query row per slot; the
     # speculative verify step passes k + 1 rows and always takes the
     # generic unfused lowering (bitwise-identical math either way).
-    if q.shape[1] == 1 and pallas_fused.fused_decision(fused_req):
+    if q.shape[1] == 1 and kernel_choice.gate("fused"):
         from .pallas_paged import paged_attention
 
         out = paged_attention(q, ck, cv, pt, bias, scale)

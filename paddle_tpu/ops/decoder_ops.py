@@ -21,7 +21,7 @@ filter and SiLU alone;
 ``gated_delta_rule_grad`` lowered (``by_hand``: the backward written out in
 ``ops/delta_rule.py``, no autodiff through the walk over the chunks);
 ``ops.moe.row_moves{pass,how="gather"}``, which ``parallel/moe.py`` counts
-through ``_count`` for every ``[N * top_k, D]`` row gather it traces: two
+for every ``[N * top_k, D]`` row gather it traces: two
 ``pass="forward"`` for every trace of the layer's forward, of which
 ``moe_experts`` makes one and ``moe_experts_grad`` another that only its
 routing plan outlives, and three ``pass="backward"`` for every
@@ -263,13 +263,15 @@ def blocked_attention(q, k, v, sel, scale, block=512, window=0):
 
 
 def _attention_path(ctx, q, k, sel, window, count):
-    """'pallas' where the flash gate is open and the kernels take the
-    operands, else 'xla'; counted where ``count``."""
-    from .attention_ops import _flash_decision
+    """'pallas' where the flash gate is open (``kernel_choice.gate``: the
+    environment switch where set, else the platform; the op states no
+    wish) and the kernels take the operands, else 'xla'; counted where
+    ``count``."""
+    from . import kernel_choice
     from . import pallas_sparse_flash as psf
 
     path = "xla"
-    if _flash_decision(int(ctx.attr("flash", -1))):
+    if kernel_choice.gate("flash"):
         why = psf.supported(q, k, sel, window)
         if not why:
             path = "pallas"

@@ -41,10 +41,10 @@ def softmax_with_cross_entropy(ctx):
     logits = ctx.input("Logits")
     label = ctx.input("Label")
     from ..fluid import amp
-    from . import pallas_fused
+    from . import kernel_choice, pallas_fused
 
     soft = ctx.attr("soft_label", False)
-    if pallas_fused.fused_decision() \
+    if kernel_choice.gate("fused") \
             and pallas_fused.xent_fusable(logits, label, soft):
         # streaming Pallas lowering: the [batch, vocab] probability matrix
         # never materializes in HBM; backward recomputes P per tile from

@@ -51,9 +51,9 @@ def _fused_opt_ok(ctx, kind, p, g, out_slots):
     view of it is free, ``pallas_fused._sweep_view``) + (under a mesh)
     spec alignment of param and accumulators — ZeRO-1-diverged updates
     keep the unfused lowering."""
-    from . import pallas_fused
+    from . import kernel_choice, pallas_fused
 
-    if not pallas_fused.fused_decision():
+    if not kernel_choice.gate("fused"):
         return False
     names = [(ctx.outputs_spec.get(s) or [None])[0] for s in out_slots]
     why = pallas_fused.opt_declined(p, g, names[0])

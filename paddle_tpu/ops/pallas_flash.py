@@ -53,6 +53,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import kernel_choice
+
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
 NEG_INF = -1e30
@@ -437,17 +439,17 @@ def flash_attention(q, k, v, bias=None, scale=None, causal=False,
     return out
 
 
-def _resolve(q, scale, interpret):
+def resolve(q, scale, interpret):
+    """(scale, interpret) with what a caller left open filled in: the
+    head's width to the -1/2, and ``kernel_choice.interpret``."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return scale, interpret
+    return scale, kernel_choice.interpret(interpret)
 
 
 def _flash_fwd_impl(q, k, v, bias, scale, causal, block_q, block_k,
                     interpret):
-    scale, interpret = _resolve(q, scale, interpret)
+    scale, interpret = resolve(q, scale, interpret)
     bias = _bias_2d(bias, q.shape[0], q.shape[1], k.shape[2])
     return _flash_forward(q, k, v, bias, scale, causal, block_q, block_k,
                           interpret)
@@ -461,7 +463,7 @@ def _flash_fwd(q, k, v, bias, scale, causal, block_q, block_k, interpret):
 
 def _flash_bwd(scale, causal, block_q, block_k, interpret, res, do):
     q, k, v, bias, out, lse = res
-    scale, interpret = _resolve(q, scale, interpret)
+    scale, interpret = resolve(q, scale, interpret)
     bias2 = _bias_2d(bias, q.shape[0], q.shape[1], k.shape[2])
     dq, dk, dv = _flash_backward(q, k, v, bias2, out, lse, do, scale,
                                  causal, block_q, block_k, interpret)

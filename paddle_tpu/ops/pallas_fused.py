@@ -24,11 +24,12 @@ This completes the fused-kernel layer ROADMAP item 2 reserves for Pallas
    updates run on the local shard of param/moment buffers per the PR 7
    spec table; flash attention shards its head dim.
 
-Dispatch is env-gated by ``PADDLE_TPU_FUSED`` with the same 0/1/AUTO
-precedence as ``PADDLE_TPU_FLASH`` (AUTO: on for TPU backends, off on
-CPU/GPU; interpret mode keeps the kernels testable on the CPU mesh), and
-every fused dispatch decision increments an ``ops.fused.<kind>`` counter
-(mesh-labeled under a mesh) so BENCH rounds are attributable to kernels.
+Whether a kernel here or its XLA twin runs is ``kernel_choice.gate("fused")``
+(``PADDLE_TPU_FUSED`` where set, else the platform; ``ops/kernel_choice.py``)
+and this module's own checks of the operands (``xent_fusable``,
+``opt_declined``); every dispatch that takes a kernel increments an
+``ops.fused.<kind>`` counter (mesh-labeled under a mesh), so a run's numbers
+are attributable to kernels.
 """
 
 from __future__ import annotations
@@ -45,6 +46,7 @@ from jax.experimental import pallas as pl
 from jax import shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from . import kernel_choice
 from .pallas_flash import block_index
 
 DEFAULT_BLOCK_R = 256    # rows (flattened batch) per grid step
@@ -59,33 +61,8 @@ _FUSABLE_DTYPES = ("float32", "bfloat16", "float16")
 
 
 # ---------------------------------------------------------------------------
-# dispatch decision + counters
+# counters
 # ---------------------------------------------------------------------------
-
-
-def fused_decision(req: int = -1) -> bool:
-    """PADDLE_TPU_FUSED gate, same precedence contract as
-    ``attention_ops._flash_decision``: the env kill-switch wins over
-    everything (=0 forces OFF, =1 forces ON — interpret mode off-TPU),
-    then the per-call request, then AUTO (on iff the backend is a TPU;
-    interpret mode is a correctness tool, not a CPU fast path)."""
-    from ..fluid import envcontract
-
-    v = envcontract.get("PADDLE_TPU_FUSED")
-    if v in ("0", "false"):
-        return False
-    if v in ("1", "true"):
-        return True
-    if req != -1:
-        return bool(req)
-    return jax.default_backend() == "tpu"
-
-
-def active_families() -> list:
-    """The kernel families that would dispatch fused under the current
-    env/backend (a diagnostic; a run's own account is the
-    ``ops.fused.*`` counters)."""
-    return (["softmax_xent", "momentum", "adam"] if fused_decision() else [])
 
 
 def _active_mesh():
@@ -110,10 +87,6 @@ def _note(family: str, /, **labels) -> None:
         observe.registry().inc(f"ops.fused.{family}", labels=labels or None)
     except Exception:
         pass  # accounting must never fail the trace it measures
-
-
-def _interp(interpret):
-    return jax.default_backend() != "tpu" if interpret is None else interpret
 
 
 def _fit_block(size, block):
@@ -250,7 +223,7 @@ def _xent_partial(x2, lab2, soft, block_r, block_v, interpret):
         in_specs=[pl.BlockSpec((br, bv), _tile), lab_spec],
         out_specs=[out_spec] * n_out,
         scratch_shapes=[pltpu.VMEM((br, 1), jnp.float32)] * n_out,
-        interpret=_interp(interpret),
+        interpret=kernel_choice.interpret(interpret),
     )(x2, lab2)
     if soft:
         m, l, a, b = outs
@@ -273,7 +246,7 @@ def _xent_bwd_call(x2, lab2, lse, g1, g2, soft, block_r, block_v,
         grid=(pl.cdiv(r, br), pl.cdiv(v, bv)),
         in_specs=[pl.BlockSpec((br, bv), _tile), lab_spec, col, col, col],
         out_specs=pl.BlockSpec((br, bv), _tile),
-        interpret=_interp(interpret),
+        interpret=kernel_choice.interpret(interpret),
     )(x2, lab2, lse, g1, g2)
 
 
@@ -458,7 +431,7 @@ softmax_xent_sharded.defvjp(_xent_sharded_fwd_vjp, _xent_sharded_bwd_vjp)
 
 def xent_fusable(logits, label, soft) -> bool:
     """Static suitability of this softmax_with_cross_entropy instance for
-    the streaming kernel (the decision itself is :func:`fused_decision`)."""
+    the streaming kernel (the gate itself is ``kernel_choice.gate``)."""
     if str(logits.dtype) not in _FUSABLE_DTYPES:
         return False
     if logits.ndim < 2 or logits.shape[-1] < 2:
@@ -588,7 +561,7 @@ def _opt_sweep(kernel, arrays, lr, n_out, interpret):
         in_specs=[blk] * len(flat) + [scal],
         out_specs=[blk] * n_out,
         input_output_aliases=aliases,
-        interpret=_interp(interpret),
+        interpret=kernel_choice.interpret(interpret),
     )(*flat, lr2)
     return [o.reshape(shape) for o in outs]
 
@@ -611,11 +584,6 @@ def opt_declined(p, g, var_name: Optional[str] = None) -> Optional[str]:
     if n % LANE and n > (1 << 17):
         return "ragged"
     return None
-
-
-def opt_fusable(p, g, var_name: Optional[str] = None) -> bool:
-    """Static suitability of one optimizer update for the fused sweep."""
-    return opt_declined(p, g, var_name) is None
 
 
 def _param_spec(mesh, var_name: Optional[str], shape):

@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import kernel_choice
 from .pallas_flash import block_index
 
 #: what a kernel's blocks, double-buffered, and its float32 working set may
@@ -282,10 +283,6 @@ def _count_tiles(kernel, m, k, n, itemsize):
            tiles=pl.cdiv(n, tn), ragged=int(n % tn > 0))
 
 
-def _resolve(interpret):
-    return jax.default_backend() != "tpu" if interpret is None else interpret
-
-
 def _row_block(j, v, offsets, tiles, groups):
     return block_index(tiles[v], 0)
 
@@ -315,7 +312,8 @@ def grouped_matmul(rows, weights, sizes, transpose=False, interpret=None,
     _count_tiles("grouped_matmul", *rows.shape,
                  weights.shape[1 if transpose else 2], rows.dtype.itemsize)
     table = visits(sizes, m, min(ROW_TILE, m)) if plan is None else plan[0]
-    return _matmul(rows, weights, table, transpose, _resolve(interpret))
+    return _matmul(rows, weights, table, transpose,
+                   kernel_choice.interpret(interpret))
 
 
 def grouped_matmul_t(rows, cot, sizes, interpret=None, plan=None):
@@ -326,7 +324,7 @@ def grouped_matmul_t(rows, cot, sizes, interpret=None, plan=None):
                  rows.dtype.itemsize)
     table = visits(sizes, m, min(ROW_TILE, m), empty_groups=True) \
         if plan is None else plan[1]
-    return _matmul_t(rows, cot, table, _resolve(interpret))
+    return _matmul_t(rows, cot, table, kernel_choice.interpret(interpret))
 
 
 # jitted: a step calls each form a dozen times a layer with the same

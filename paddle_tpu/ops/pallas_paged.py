@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import kernel_choice
 from .pallas_flash import block_index
 
 
@@ -98,8 +99,7 @@ def paged_attention(q, cache_k, cache_v, page_table, bias, scale=1.0,
         raise ValueError(
             f"paged_attention bias must be [S, 1, n_pages * page_size] = "
             f"[{s_n}, 1, {ell}]; got {bias.shape}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = kernel_choice.interpret(interpret)
 
     def slot(s, j, pt):
         return block_index(s, 0, 0)

@@ -76,7 +76,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .pallas_flash import NEG_INF, block_index
+from .pallas_flash import NEG_INF, block_index, resolve
 
 BLOCK = 512
 #: lanes of a vector register: the forward keeps a row's running maximum
@@ -545,24 +545,16 @@ def _backward(q, k, v, sel, out, lse, do, scale, interpret, window=0):
             dv.reshape(b, hkv, t, d))
 
 
-def _resolve(q, scale, interpret):
-    if scale is None:
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return scale, interpret
-
-
 def forward(q, k, v, sel=None, scale=None, interpret=None, window=0):
     """(out, lse [B, Hq, T, 1] float32): what ``backward`` needs kept."""
-    scale, interpret = _resolve(q, scale, interpret)
+    scale, interpret = resolve(q, scale, interpret)
     return _forward(q, k, v, sel, scale, interpret, window)
 
 
 def backward(q, k, v, sel, out, lse, do, scale=None, interpret=None,
              window=0):
     """(dq, dk, dv) from the forward's own ``out`` and ``lse``."""
-    scale, interpret = _resolve(q, scale, interpret)
+    scale, interpret = resolve(q, scale, interpret)
     return _backward(q, k, v, sel, out, lse, do, scale, interpret, window)
 
 

@@ -30,11 +30,10 @@ def _stack_args(ctx, decoder):
     from ..parallel import spmd
     from ..parallel import transformer_stack as ts
 
-    from . import attention_ops
+    from . import kernel_choice
 
     slots = ts.DECODER_SLOTS if decoder else ts.ENCODER_SLOTS
     params = _collect(ctx, slots)
-    flash_req = int(ctx.attr("flash", -1))
     return dict(
         kind="dec" if decoder else "enc",
         enc=ctx.input("EncOut") if decoder else None,
@@ -45,7 +44,7 @@ def _stack_args(ctx, decoder):
         is_test=bool(ctx.attr("is_test", False)),
         n_micro=int(ctx.attr("n_microbatches", 4)),
         recompute=bool(ctx.attr("recompute", False)),
-        flash=attention_ops._flash_decision(flash_req),
+        flash=kernel_choice.gate("flash"),
         mesh=spmd.active_mesh(),
     )
 

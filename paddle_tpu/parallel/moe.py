@@ -237,9 +237,9 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
     # ones; nothing else depends on it.
     sizes = counts[:e].at[e - 1].add(counts[e])
     gate = jnp.where(held, vals, 0.0)
-    # part of the plan where the products are the Pallas kernels': the
-    # tables that tell their grid steps row tiles and groups, for all
-    # eleven calls of the layer
+    # the layer's ONE choice of kernels or XLA's grouped product, part of
+    # the plan: the tables that tell the kernels' grid steps row tiles and
+    # groups, for all eleven calls of the layer, or None
     tables = None
     if product_path(x, w1, w2, top_k) == "pallas":
         from ..ops import pallas_grouped
@@ -365,11 +365,15 @@ def _rows_home(rows, back, held, which: str):
 
 
 def _count_row_move(which: str):
-    from ..ops.decoder_ops import _count
-
     # one for every [N * top_k, D] gather traced: two in a forward, three
     # in a backward
-    _count("ops.moe.row_moves", **{"pass": which, "how": "gather"})
+    try:
+        from .. import observe
+
+        observe.registry().inc("ops.moe.row_moves",
+                               labels={"pass": which, "how": "gather"})
+    except Exception:
+        pass  # accounting must never fail the trace it measures
 
 
 def _permuted(rows, index):
@@ -381,52 +385,61 @@ def _permuted(rows, index):
 
 def grouped_product(rows, weights, sizes, tables=None):
     """[M, N]: the rows of group g (``sizes[g]`` of them, groups in order)
-    times ``weights[g]``; rows [M, K], weights [G, K, N].  The Pallas
-    kernels of ``ops/pallas_grouped`` where they take the shapes (lane-
-    aligned widths, whole row tiles, bf16 or float32), forward and
-    backward, else ``lax.ragged_dot`` and its own transposes: a choice by
-    what the operands are, which ``product_path`` states for a layer.
-    ``tables``: ``pallas_grouped.plan(sizes, M)`` where the caller made it
-    already for several products over the same groups."""
+    times ``weights[g]``; rows [M, K], weights [G, K, N].  By the Pallas
+    kernels of ``ops/pallas_grouped``, forward and backward, where
+    ``tables`` is their plan for these groups; by ``lax.ragged_dot`` and its
+    own transposes where it is None.  ``routed_experts`` decides that once
+    a layer; a caller with no plan asks ``product_tables``."""
+    if tables is None:
+        return lax.ragged_dot(rows, weights, sizes)
+    return _kernel_product(rows, weights, tables)
+
+
+def _declined(m, dtype, *weights) -> str:
+    """'' where the Pallas kernels take the products of ``m`` rows of
+    ``dtype`` with every one of ``weights`` [G, K, .] (lane-aligned widths,
+    whole row tiles, bf16 or float32), else the first reason against.  By
+    the operands alone, and the one place that asks."""
     from ..ops import pallas_grouped
 
-    if pallas_grouped.supported(rows, weights):
-        return lax.ragged_dot(rows, weights, sizes)
-    if tables is None:
-        tables = pallas_grouped.plan(sizes, rows.shape[0])
-    return _kernel_product(rows, weights, tables)
+    for w in weights:
+        why = pallas_grouped.supported(
+            jax.ShapeDtypeStruct((m, w.shape[1]), dtype), w)
+        if why:
+            return why
+    return ""
+
+
+def product_tables(rows, weights, sizes):
+    """``tables`` for a caller with no routing plan: the kernels' plan
+    where they take ``rows`` [M, K] times ``weights`` [G, K, N], else None."""
+    from ..ops import pallas_grouped
+
+    if _declined(rows.shape[0], rows.dtype, weights):
+        return None
+    return pallas_grouped.plan(sizes, rows.shape[0])
 
 
 def product_path(x, w1, w2, top_k: int) -> str:
     """'pallas' where ``routed_experts`` on these operands multiplies by
     the Pallas kernels, 'ragged_dot' where by XLA's grouped product."""
     from ..fluid import amp
-    from ..ops import pallas_grouped
 
     low, a1, a2 = jax.eval_shape(lambda *a: amp.cast_operands(*a)[:-1],
                                  x, w1, w2)
     m = x.size // x.shape[-1] * top_k
-
-    def declined(w):
-        return pallas_grouped.supported(
-            jax.ShapeDtypeStruct((m, w.shape[1]), low.dtype), w)
-
-    return "ragged_dot" if declined(a1) or declined(a2) else "pallas"
+    return "ragged_dot" if _declined(m, low.dtype, a1, a2) else "pallas"
 
 
 def product_transposes(rows, weights, sizes, tables=None):
     """``(to_rows, to_weights)``, the two transposes of ``grouped_product(
-    rows, weights, sizes, tables)`` on the path that product takes:
+    rows, weights, sizes, tables)`` on the path that ``tables`` says:
     ``to_rows(d)`` [M, K] is the cotangent ``d`` [M, N] of group g's rows
     times ``weights[g].T``, and ``to_weights(rows, d)`` [G, K, N] the
     weights' gradient from ANY rows [M, K] in those groups.  For a backward
     written by hand, which has no product to differentiate: of ``rows`` only
     the shape and type are read."""
-    from ..ops import pallas_grouped
-
-    if not pallas_grouped.supported(rows, weights):
-        if tables is None:
-            tables = pallas_grouped.plan(sizes, rows.shape[0])
+    if tables is not None:
         return _kernel_transposes(weights, tables)
 
     # XLA's own transposes of its product, by ``jax.vjp`` (bilinear: where

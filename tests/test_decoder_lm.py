@@ -93,7 +93,12 @@ def test_program_equals_the_reference_where_the_selection_cuts(
 
 @pytest.mark.parametrize("flash", [False, True])
 def test_selecting_every_key_equals_causal_attention_through_ring_attention(
-        flash):
+        monkeypatch, flash):
+    """One switch for the whole program: ``ring_attention`` takes the same
+    side as ``sparse_attention``, so the reference is the plain softmax
+    (``parallel/ring_attention.full_attention``) called directly, and the
+    fused op is held to it too."""
+    monkeypatch.setenv("PADDLE_TPU_FLASH", "1" if flash else "0")
     b, hq, hkv, t, d, hid = 2, 4, 2, 32, 16, 24
     rng = np.random.RandomState(0)
     x = layers.data(name="x", shape=[t, hid], dtype="float32")
@@ -104,9 +109,9 @@ def test_selecting_every_key_equals_causal_attention_through_ring_attention(
     vr = layers.data(name="vr", shape=[hq, t, d], dtype="float32")
     sel = layers.sparse_indexer(x, num_heads=2, head_dim=8, topk=t,
                                 name="idx")
-    new = layers.sparse_attention(q, k, v, selection=sel, flash=flash)
-    plain = layers.sparse_attention(q, k, v, flash=flash)
-    old = layers.ring_attention(q, kr, vr, causal=True, flash=False)
+    new = layers.sparse_attention(q, k, v, selection=sel)
+    plain = layers.sparse_attention(q, k, v)
+    old = layers.ring_attention(q, kr, vr, causal=True)
     exe = fluid.Executor(fluid.TPUPlace())
     exe.run(fluid.default_startup_program())
     feed = {"x": rng.randn(b, t, hid).astype("float32"),
@@ -117,8 +122,13 @@ def test_selecting_every_key_equals_causal_attention_through_ring_attention(
     feed["vr"] = np.repeat(feed["v"], hq // hkv, axis=1)
     s, a, p, o = exe.run(feed=feed, fetch_list=[sel, new, plain, old])
     assert np.array_equal(np.asarray(s)[0], np.tril(np.ones((t, t))))
-    np.testing.assert_allclose(a, o, atol=2e-6)
-    np.testing.assert_allclose(p, o, atol=2e-6)
+    want = full_attention(*(jnp.asarray(feed[n]) for n in ("q", "kr", "vr")),
+                          True, None)
+    for got in (a, p, o):
+        np.testing.assert_allclose(got, want, atol=2e-6)
+    path = "pallas" if flash else "xla"
+    assert all(f'path="{path}"' in key
+               for key in counters("ops.sparse_attention.calls"))
 
 
 # -- (c) the share test ---------------------------------------------------
@@ -509,20 +519,66 @@ def test_window_program_equals_the_reference_and_moves_the_bias(
     assert not counters("ops.sparse_attention.declined")
 
 
+@pytest.mark.parametrize("stated", [-1, 1])
+def test_a_saved_program_that_states_a_wish_runs_as_one_that_does_not(
+        monkeypatch, stated):
+    """Programs saved before PR 47 carry ``"flash"`` (-1 from every model,
+    0 / 1 from a layer's argument) in the descs of their attention ops and
+    of the grad ops made from them.  The attribute loads and is not read:
+    same loss, same gradients, same path as the program without it."""
+    monkeypatch.delenv("PADDLE_TPU_FLASH", raising=False)
+    b, hq, hkv, t, d = 2, 4, 2, 32, 16
+    q = layers.data(name="q", shape=[hq, t, d], dtype="float32")
+    k = layers.data(name="k", shape=[hkv, t, d], dtype="float32")
+    q.stop_gradient = k.stop_gradient = False
+    mixed = layers.elementwise_add(
+        layers.sparse_attention(q, k, k, window=8),
+        layers.ring_attention(q, q, q, causal=True))
+    loss = layers.reduce_sum(layers.elementwise_mul(mixed, mixed))
+    fluid.backward.append_backward(loss)
+    main = fluid.default_main_program()
+    rng = np.random.RandomState(1)
+    feed = {"q": rng.randn(b, hq, t, d).astype("float32"),
+            "k": rng.randn(b, hkv, t, d).astype("float32")}
+    fetch = [loss.name, "q@GRAD", "k@GRAD"]
+    exe = fluid.Executor(fluid.TPUPlace())
+    want = exe.run(main, feed=feed, fetch_list=fetch)
+    first = counters("ops.sparse_attention.calls")
+
+    old = fluid.Program.parse_from_string(main.serialize_to_string())
+    touched = [op for op in old.global_block().ops
+               if op.type.split("_grad")[0] in ("sparse_attention",
+                                                "ring_attention")]
+    assert len(touched) == 4
+    for op in touched:
+        assert not op.has_attr("flash")
+        op._set_attr("flash", stated)
+    old = fluid.Program.parse_from_string(old.serialize_to_string())
+    assert all(op.attr("flash") == stated for op in old.global_block().ops
+               if op.type in ("sparse_attention", "ring_attention"))
+    got = exe.run(old, feed=feed, fetch_list=fetch)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    assert first and all('path="xla"' in key for key in first)
+    assert counters("ops.sparse_attention.calls") == {
+        key: 2 * n for key, n in first.items()}
+
+
 @pytest.mark.parametrize("window", [16, 24, 40, 64, 100])
 @pytest.mark.parametrize("flash", [False, True])
 def test_window_op_on_both_paths(monkeypatch, flash, window):
     """window < T on and off the tile (16), and window >= T, which is the
     global path: the op against dense float32, through the executor."""
     monkeypatch.setattr(psf, "BLOCK", 16)
+    monkeypatch.setenv("PADDLE_TPU_FLASH", "1" if flash else "0")
     b, hq, hkv, t, d = 2, 4, 2, 64, 16
     rng = np.random.RandomState(window)
     q = layers.data(name="q", shape=[hq, t, d], dtype="float32")
     k = layers.data(name="k", shape=[hkv, t, d], dtype="float32")
     v = layers.data(name="v", shape=[hkv, t, d], dtype="float32")
     q.stop_gradient = k.stop_gradient = v.stop_gradient = False
-    out = layers.sparse_attention(q, k, v, window=window, flash=flash)
-    plain = layers.sparse_attention(q, k, v, flash=flash)
+    out = layers.sparse_attention(q, k, v, window=window)
+    plain = layers.sparse_attention(q, k, v)
     w = layers.assign(np.cos(np.arange(d, dtype="float32")))
     loss = layers.reduce_sum(layers.elementwise_mul(out, w))
     fluid.backward.append_backward(loss)
@@ -1990,14 +2046,23 @@ def test_infer_rule_of_a_partial_rotary_and_its_table():
 
 def program_digest():
     """(ops, (how many, sha256 of every op's type, inputs, outputs and
-    attrs in order)) of the default main program's block 0."""
+    attrs in order)) of the default main program's block 0.  The digests
+    below were taken while ``sparse_attention`` and its grad op still
+    carried the per-op request ``flash: -1`` (gone in PR 47; nothing read
+    any other value): it is put back for the hash, so that they stay the
+    digests of the commits they were taken on."""
     import hashlib
 
+    def attrs(op):
+        gone = {"flash": -1} if op.type.startswith("sparse_attention") else {}
+        return sorted((k, repr(v)) for k, v in {**op.attrs, **gone}.items())
+
     ops = fluid.default_main_program().global_block().ops
+    assert not any("flash" in op.attrs or "fused" in op.attrs for op in ops)
     text = "\n".join(repr((
         op.type, sorted((k, list(v)) for k, v in op.inputs.items()),
-        sorted((k, list(v)) for k, v in op.outputs.items()),
-        sorted((k, repr(v)) for k, v in op.attrs.items()))) for op in ops)
+        sorted((k, list(v)) for k, v in op.outputs.items()), attrs(op)))
+        for op in ops)
     return ops, (len(ops), hashlib.sha256(text.encode()).hexdigest())
 
 

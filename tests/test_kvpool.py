@@ -311,7 +311,8 @@ def test_paged_kill_switch_restores_dense_bitwise(engines, monkeypatch):
 # op level: kernel vs fallback, infer rule
 # ---------------------------------------------------------------------------
 
-def _paged_attention_run(fused):
+def _paged_attention_run(monkeypatch, fused):
+    monkeypatch.setenv("PADDLE_TPU_FUSED", str(fused))
     rng = np.random.RandomState(7)
     s_n, n_pages, ps, d = 2, 2, 4, 8
     q = rng.randn(s_n, 1, d).astype(np.float32)
@@ -333,8 +334,7 @@ def _paged_attention_run(fused):
                           append_batch_size=False)
         bv = layers.data("bias", shape=[s_n, 1, n_pages * ps],
                          dtype="float32", append_batch_size=False)
-        out = layers.paged_attention(qv, ckv, cvv, ptv, bv, scale=0.25,
-                                     fused=fused)
+        out = layers.paged_attention(qv, ckv, cvv, ptv, bv, scale=0.25)
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(startup)
     (res,) = exe.run(prog, feed={"q": q, "ck": ck, "cv": cv, "pt": pt,
@@ -342,14 +342,14 @@ def _paged_attention_run(fused):
     return np.asarray(res)
 
 
-def test_paged_attention_kernel_matches_fallback():
+def test_paged_attention_kernel_matches_fallback(monkeypatch):
     """Kernel vs XLA-take fallback: same exact-softmax algorithm, so
     they agree to fp32 ULP (jit reduction-order only; the BITWISE
     sequential-equivalence contract lives on the engine path, where one
     lowering is used consistently — the engine tests above prove it)."""
     c0 = fluid.profiler.counters().get("ops.fused.paged_attention", 0)
-    unfused = _paged_attention_run(fused=0)
-    fused = _paged_attention_run(fused=1)     # Pallas (interpret on CPU)
+    unfused = _paged_attention_run(monkeypatch, fused=0)
+    fused = _paged_attention_run(monkeypatch, fused=1)  # Pallas, interpreted
     assert fused.shape == (2, 1, 8)
     np.testing.assert_allclose(fused, unfused, rtol=1e-6, atol=1e-6)
     assert np.isfinite(unfused).all()         # trash garbage fully masked

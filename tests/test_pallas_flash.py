@@ -359,8 +359,8 @@ def test_flash_contraction_counter_names_the_operands_dtype(monkeypatch):
         "flash_attention": 12, 'flash_contraction{operands="float32"}': 12}
 
 
-def test_flash_trains_flagship_transformer():
-    """cfg.flash_attention=True: the STACKED flagship transformer trains
+def test_flash_trains_flagship_transformer(monkeypatch):
+    """The flash gate open: the STACKED flagship transformer trains
     through the Pallas fwd+bwd kernels (interpret mode here) with losses
     matching the XLA-softmax build — flash is a training path, not a demo.
     Padding bias included, so the kernels' bias handling is on the path."""
@@ -376,13 +376,13 @@ def test_flash_trains_flagship_transformer():
         framework.switch_startup_program(framework.Program())
         unique_name.switch()
         _executor._global_scope = _executor.Scope()
+        monkeypatch.setenv("PADDLE_TPU_FLASH", "1" if flash else "0")
         fluid.default_main_program().random_seed = 21
         fluid.default_startup_program().random_seed = 21
         cfg = transformer.Config(
             "t", src_vocab_size=50, tgt_vocab_size=47, d_model=16,
             d_inner=32, n_head=2, n_layer=2, dropout=0.0,
-            label_smooth=0.0, stacked=True, n_microbatches=2,
-            flash_attention=flash)
+            label_smooth=0.0, stacked=True, n_microbatches=2)
         src, tgt, lbl, loss = transformer.build(cfg, src_len=8, tgt_len=8,
                                                 lr=5e-3)
         exe = fluid.Executor(fluid.CPUPlace())
@@ -407,16 +407,25 @@ def test_flash_trains_flagship_transformer():
 
 
 def test_flash_gate_precedence(monkeypatch):
-    """PADDLE_TPU_FLASH=0 is the kill-switch: it must win over a
-    model built with flash=True; =1 wins over flash=0; unset defers to
-    the per-op attr, then to backend auto."""
-    from paddle_tpu.ops.attention_ops import _flash_decision
+    """The rule has two levels: PADDLE_TPU_FLASH where it is set (0 closes
+    the gate on any backend, 1 opens it on any), else the backend.  The two
+    groups read their own switch; off the TPU a kernel is interpreted."""
+    from paddle_tpu.ops import kernel_choice
 
-    monkeypatch.setenv("PADDLE_TPU_FLASH", "0")
-    assert _flash_decision(1) is False          # kill-switch wins
-    monkeypatch.setenv("PADDLE_TPU_FLASH", "1")
-    assert _flash_decision(0) is True           # force-on wins
-    monkeypatch.delenv("PADDLE_TPU_FLASH")
-    assert _flash_decision(1) is True           # attr on
-    assert _flash_decision(0) is False          # attr off
-    assert _flash_decision(-1) is (jax.default_backend() == "tpu")
+    monkeypatch.delenv("PADDLE_TPU_FUSED", raising=False)
+    for backend in ("cpu", "tpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        monkeypatch.setenv("PADDLE_TPU_FLASH", "0")
+        assert kernel_choice.gate("flash") is False     # closed anywhere
+        monkeypatch.setenv("PADDLE_TPU_FLASH", "1")
+        assert kernel_choice.gate("flash") is True      # open anywhere
+        assert kernel_choice.gate("fused") is (backend == "tpu")
+        assert kernel_choice.switches() == {"flash": "1", "fused": ""}
+        for unset in ("auto", ""):
+            monkeypatch.setenv("PADDLE_TPU_FLASH", unset)
+            assert kernel_choice.gate("flash") is (backend == "tpu")
+        monkeypatch.delenv("PADDLE_TPU_FLASH")
+        assert kernel_choice.gate("flash") is (backend == "tpu")
+        assert kernel_choice.interpret() is (backend != "tpu")
+        assert kernel_choice.interpret(True) is True
+        assert kernel_choice.interpret(False) is False

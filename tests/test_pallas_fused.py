@@ -214,7 +214,6 @@ def test_sweep_taken_only_where_its_view_is_no_copy(shape, fusable):
     keeps XLA's lowering; lane-aligned matrices and every 1-D tensor keep
     the sweep."""
     x = jax.ShapeDtypeStruct(shape, jnp.float32)
-    assert pf.opt_fusable(x, x) is fusable
     assert (pf._sweep_view(shape) is not None) is fusable
     assert pf.opt_declined(x, x) == (None if fusable else "layout")
 
@@ -543,20 +542,21 @@ def test_sharded_window_transformer_fused_acceptance(monkeypatch):
 
 
 def test_fused_gate_precedence(monkeypatch):
-    """PADDLE_TPU_FUSED: 0 kill-switch wins, 1 forces on, unset AUTO
-    defers to the per-call request then the backend."""
+    """PADDLE_TPU_FUSED: 0 closes the gate, 1 opens it, unset (or auto)
+    it is the backend's; nothing else has a say (ops/kernel_choice.py)."""
+    from paddle_tpu.ops import kernel_choice
+
     monkeypatch.setenv("PADDLE_TPU_FUSED", "0")
-    assert pf.fused_decision(1) is False
+    assert kernel_choice.gate("fused") is False
     monkeypatch.setenv("PADDLE_TPU_FUSED", "1")
-    assert pf.fused_decision(0) is True
+    assert kernel_choice.gate("fused") is True
     monkeypatch.delenv("PADDLE_TPU_FUSED")
-    assert pf.fused_decision(1) is True
-    assert pf.fused_decision(0) is False
-    assert pf.fused_decision(-1) is (jax.default_backend() == "tpu")
-    monkeypatch.setenv("PADDLE_TPU_FUSED", "1")
-    assert pf.active_families() == ["softmax_xent", "momentum", "adam"]
+    assert kernel_choice.gate("fused") is (jax.default_backend() == "tpu")
     monkeypatch.setenv("PADDLE_TPU_FUSED", "0")
-    assert pf.active_families() == []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kernel_choice.gate("fused") is False     # closed on a TPU too
+    monkeypatch.setenv("PADDLE_TPU_FUSED", "auto")
+    assert kernel_choice.gate("fused") is True
 
 
 def test_fused_smoke_tool():

@@ -33,8 +33,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 import paddle_tpu  # noqa: F401  (x64 mode on, as every user has it)
-from paddle_tpu.ops import (pallas_flash, pallas_fused, pallas_grouped,
-                            pallas_paged, pallas_sparse_flash, registry)
+from paddle_tpu.ops import (kernel_choice, pallas_flash, pallas_fused,
+                            pallas_grouped, pallas_paged, pallas_sparse_flash,
+                            registry)
 
 B, H, T, D = 64, 8, 256, 64          # attention: [batch, heads, len, d_head]
 R, V = B * T, 30000                  # loss head: [batch*len, vocab]
@@ -336,11 +337,13 @@ def test_grouped_signatures_are_the_benchmarks(topo, monkeypatch, cell):
     m, d, f, g = GROUPED_CELLS[cell]
     # the backend here is the CPU, which the kernels would answer with
     # interpret mode: steered in the test, as the chip would have it
-    monkeypatch.setattr(pallas_grouped, "_resolve", lambda interpret: False)
+    monkeypatch.setattr(kernel_choice, "interpret", lambda stated=None: False)
 
     def fn(rows, w, sizes):
         return jax.value_and_grad(
-            lambda a, b: moe.grouped_product(a, b, sizes).astype(F32).sum(),
+            lambda a, b: moe.grouped_product(
+                a, b, sizes, moe.product_tables(a, b, sizes)).astype(
+                    F32).sum(),
             (0, 1))(rows, w)
 
     chip = SingleDeviceSharding(topo.devices[0])
@@ -454,7 +457,7 @@ def test_a_routed_layer_and_its_backward_lower_eleven_calls(
     m, d, f, g = GROUPED_CELLS[cell]
     tokens, top_k, routed = GROUPED_LAYERS[cell]
     assert tokens * top_k == m
-    monkeypatch.setattr(pallas_grouped, "_resolve", lambda interpret: False)
+    monkeypatch.setattr(kernel_choice, "interpret", lambda stated=None: False)
 
     def layer(x, wr, w1, w3, w2):
         return moe.routed_experts(x, wr, w1, w3, w2, top_k=top_k).astype(
