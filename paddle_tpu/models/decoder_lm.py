@@ -28,9 +28,13 @@ field turns on)::
                   0 <= t - s < window
     global layer ((published index + 1) % global_every == 0, or every layer
                   where there is no window): q, k = RoPE(q, k) [not where
-                  rope_global is off]; key s counts iff s <= t [and s in
-                  S_t = sparse_indexer(a), where index_topk is set]
+                  rope_global is off; global_rotary: by the frequencies
+                  G.inv_freq in rope_theta's place, G = cfg.global_rotary];
+                  key s counts iff s <= t [and s in S_t =
+                  sparse_indexer(a), where index_topk is set]
     o  = concat_h softmax_{keys that count}(q_h k_{h // group} / sqrt(d)) v
+         [global_rotary, in a global layer: * G.scale in 1 / sqrt(d)'s
+         place; a window layer keeps rope_theta's table and 1 / sqrt(d)]
     y  = (o [* sigmoid(g)]) Wo
     latent layer (mixers[published index] == "latent"; L = cfg.latent):
         q = a Wq                      [T, H, L.nope + L.rope]
@@ -105,6 +109,7 @@ from 0.
 
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import NamedTuple, Optional, Tuple
 
@@ -145,6 +150,17 @@ class Delta(NamedTuple):
     value_dim: int
     taps: int = 4
     chunk: int = 64
+
+
+class Rotary(NamedTuple):
+    """The rotary of the GLOBAL plain-attention layers where it is not the
+    window layers': the frequencies of the rotated columns' pairs
+    (``rotary_dims // 2`` of them, ``head_dim // 2`` where the whole head
+    turns) in ``rope_theta``'s place, and the softmax scale where it is not
+    ``head_dim ** -0.5`` (a table that a scaling rule made brings a
+    temperature: ``yarn_inv_freq``, ``yarn_softmax_scale``)."""
+    inv_freq: Tuple[float, ...]
+    scale: float = 0.0
 
 
 DELTA_NORM_EPS = 1e-6   # the l2 norm of a delta mixer's queries and keys
@@ -192,7 +208,8 @@ class Config:
                  route_scale=1.0, route_bias_coeff=0.0, mixers=None,
                  conv_taps=0, tie_head=False, latent=None,
                  residual="sequential", mtp_depth=0, mtp_weight=0.0,
-                 delta=None, rotary_dims=0, shared_gate=False):
+                 delta=None, rotary_dims=0, shared_gate=False,
+                 global_rotary=None):
         if num_heads % num_kv_heads:
             raise ValueError(f"{num_heads} query heads do not group over "
                              f"{num_kv_heads} key-value heads")
@@ -240,6 +257,17 @@ class Config:
         if rotary_dims % 2 or not 0 <= rotary_dims <= head_dim:
             raise ValueError(f"rotary_dims {rotary_dims}: an even part of "
                              f"the head's {head_dim} columns, or 0 for all")
+        if global_rotary is not None:
+            inv_freq, *rest = global_rotary
+            global_rotary = Rotary(tuple(inv_freq), *rest)
+            pairs = (rotary_dims or head_dim) // 2
+            if not rope_global or len(global_rotary.inv_freq) != pairs:
+                raise ValueError(
+                    f"global_rotary with {len(global_rotary.inv_freq)} "
+                    f"frequencies beside rope_global {rope_global}: the "
+                    f"global layers' own table, one frequency for each of "
+                    f"the {pairs} pairs they rotate, and no such layer goes "
+                    "without positions")
         if shared_gate and not shared_width:
             raise ValueError("a gate on the shared expert needs "
                              "shared_width")
@@ -301,6 +329,10 @@ class Config:
         self.rotary_dims = rotary_dims
         # the shared expert's output times sigmoid(m w_sg)
         self.shared_gate = shared_gate
+        # the global plain-attention layers' own rotary table and softmax
+        # scale: a Rotary (or its fields in order); None: the window
+        # layers' rope_theta and head_dim ** -0.5
+        self.global_rotary = global_rotary
 
     def layer_mixer(self, i):
         """The kind of held layer ``i``'s token mixer."""
@@ -348,16 +380,17 @@ def _norm(x, cfg, name):
                            param_attr=ParamAttr(name=name))
 
 
-def _heads(x, seq_len, n, cfg, norm_name=None, rotate=True):
+def _heads(x, seq_len, n, cfg, norm_name=None, rotate=True, inv_freq=None):
     """[B, T, n*Dh] -> [B, n, T, Dh]; normed per head where ``norm_name``
     names the norm's scale (q and k; v is neither), and then rotated where
-    ``rotate``."""
+    ``rotate``: by ``rope_theta``'s table, or by ``inv_freq``."""
     x = layers.reshape(x, [-1, seq_len, n, cfg.head_dim])
     if norm_name is not None:
         x = _norm(x, cfg, norm_name)
         if rotate:
             x = layers.rotary_embedding(x, theta=cfg.rope_theta,
-                                        dims=cfg.rotary_dims)
+                                        dims=cfg.rotary_dims,
+                                        inv_freq=inv_freq)
     return layers.transpose(x, perm=[0, 2, 1, 3])
 
 
@@ -373,13 +406,16 @@ def _gated_out(ctx, x, cfg, seq_len, p):
     return _proj(ctx, cfg.hidden_size, f"{p}_o_w")
 
 
-def _attention(x, cfg, seq_len, p, window):
+def _attention(x, cfg, seq_len, p, window, own=None):
+    """``own``: the ``Rotary`` of a global layer that has one; its table
+    and scale stand in ``rope_theta``'s and ``head_dim ** -0.5``'s place."""
     rotate = bool(window) or cfg.rope_global
+    table = own.inv_freq if own else None
     width = cfg.num_heads * cfg.head_dim
     q = _heads(_proj(x, width, f"{p}_q_w"),
-               seq_len, cfg.num_heads, cfg, f"{p}_q_norm", rotate)
+               seq_len, cfg.num_heads, cfg, f"{p}_q_norm", rotate, table)
     k = _heads(_proj(x, cfg.num_kv_heads * cfg.head_dim, f"{p}_k_w"),
-               seq_len, cfg.num_kv_heads, cfg, f"{p}_k_norm", rotate)
+               seq_len, cfg.num_kv_heads, cfg, f"{p}_k_norm", rotate, table)
     v = _heads(_proj(x, cfg.num_kv_heads * cfg.head_dim, f"{p}_v_w"),
                seq_len, cfg.num_kv_heads, cfg)
     sel = None
@@ -388,8 +424,9 @@ def _attention(x, cfg, seq_len, p, window):
             x, cfg.index_heads, cfg.index_head_dim, cfg.index_topk,
             theta=cfg.rope_theta, name=f"{p}_idx",
             param_attr=_attr(None))
-    ctx = layers.sparse_attention(q, k, v, selection=sel,
-                                  scale=cfg.head_dim ** -0.5, window=window)
+    ctx = layers.sparse_attention(
+        q, k, v, selection=sel, window=window,
+        scale=own.scale if own and own.scale else cfg.head_dim ** -0.5)
     return _gated_out(ctx, x, cfg, seq_len, p)
 
 
@@ -529,8 +566,10 @@ def _block(stream, cfg, seq_len, p, scope, mixer, window, dense, routers):
             y = _delta_mixer(_norm(x, cfg, f"{p}_attn_norm"), cfg, seq_len,
                              p)
         else:
-            y = _attention(_norm(x, cfg, f"{p}_attn_norm"), cfg, seq_len,
-                           p, window)
+            with fluid.name_scope("global") if own \
+                    else contextlib.nullcontext():
+                y = _attention(_norm(x, cfg, f"{p}_attn_norm"), cfg,
+                               seq_len, p, window, own)
         return _norm(y, cfg, f"{p}_post_attn_norm") if cfg.post_norms else y
 
     def feed(x):
@@ -541,9 +580,15 @@ def _block(stream, cfg, seq_len, p, scope, mixer, window, dense, routers):
             f = _experts(_norm(x, cfg, f"{p}_moe_norm"), cfg, p, routers)
         return _norm(f, cfg, f"{p}_post_mlp_norm") if cfg.post_norms else f
 
+    # a global plain-attention layer's own rotary, where the model states
+    # one: told from the window layers in the device trace by ``global``
+    own = cfg.global_rotary if mixer == "attention" and not window else None
     observe.registry().inc("models.decoder.blocks", labels={
         "mixer": mixer, "residual": cfg.residual,
         "where": "mtp" if scope == "mtp" else "trunk"})
+    if own:
+        observe.registry().inc("models.decoder.rotary", labels={
+            "kind": "global", "table": "given", "scope": scope})
     with fluid.name_scope(f"{scope}.mixer"):
         stream = _sub_block(stream, cfg, mix)
     with fluid.name_scope(f"{scope}.ffn"):
@@ -597,7 +642,9 @@ def _forward(cfg, seq_len):
     ``layer<i>.mixer`` (the attention of any kind with its indexer, the
     short convolution or the delta rule, with projections, norms, gate and
     the residual add; what only a latent mixer has beneath it as
-    ``.latent``, what only a delta mixer has as ``.delta``),
+    ``.latent``, what only a delta mixer has as ``.delta``, and all of a
+    global attention layer that rotates by ``global_rotary`` but the
+    residual add as ``.global``),
     ``layer<i>.ffn`` (dense or shared feed-forward, router and routed
     experts, likewise), ``head`` (final norm, product, loss) and, for the
     multi-token module, ``mtp.merge``, ``mtp.mixer``, ``mtp.ffn``,

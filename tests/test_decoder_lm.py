@@ -4,6 +4,7 @@ float32 reference of ``chipbench/configs/keye_vl_2_0_30b_a3b`` at a tiny
 size, on the CPU, with seeded random weights."""
 
 import json
+import math
 import os
 import sys
 
@@ -759,9 +760,12 @@ def reference_router(case):
     """A cell's reference's router as ``routed(x, wr, bias, w1, w3, w2,
     top_k, scale, offset)``, whatever its own argument list."""
     ref, eps = {"trinity": (T_REF, None), "lfm2": (L_REF, 1e-6),
-                "instella": (I_REF, 1e-20)}[case]
+                "instella": (I_REF, 1e-20), "mellum2": (M_REF, 0.0)}[case]
     if eps is None:         # the norm's epsilon is a constant of the file
         return ref, ref.routed
+    if case == "mellum2":   # a softmax router: no bias, scale or counts
+        return ref, lambda x, wr, bias, w1, w3, w2, k, scale, off=0: (
+            ref.routed(x, wr, w1, w3, w2, k, off), None)
     return ref, lambda x, wr, bias, w1, w3, w2, k, scale, off=0: ref.routed(
         x, wr, bias, w1, w3, w2, k, scale, eps, off)
 
@@ -769,21 +773,29 @@ def reference_router(case):
 @pytest.mark.parametrize("case,routed,held,k,scale,eps,shared", [
     ("trinity", 128, 8, 8, 2.826, 1e-20, 8),
     ("lfm2", 32, 8, 4, 1.0, 1e-6, 0),
-    ("instella", 64, 8, 6, 2.5, 1e-20, 16)])
+    ("instella", 64, 8, 6, 2.5, 1e-20, 16),
+    ("mellum2", 64, 8, 8, 1.0, 0.0, 0)])
 def test_the_shares_and_the_shared_expert_once_add_up_to_the_layer(
         case, routed, held, k, scale, eps, shared):
     """A sigmoid router under a selection bias, ``k`` per token over all
     ``routed``, ``held`` by each of ``routed / held`` chips (Trinity: 16
     shares of 128 with top-8; LFM2: 4 shares of 32 with top-4 and no shared
     expert; Instella: 8 shares of 64 with top-6, scale 2.5 and two shared
-    experts as one of twice the width): the shares, and the shared expert
-    counted ONCE, add up to what the cell's reference gives for the uncut
-    layer; every share reports the same assignments, over all ``routed``."""
+    experts as one of twice the width; Mellum2: 8 shares of 64 with top-8,
+    experts 8c..8c+7 on chip c, under a SOFTMAX router without a bias and
+    no shared expert): the shares, and the shared expert counted ONCE, add
+    up to what the cell's reference gives for the uncut layer; every share
+    reports the same assignments, over all ``routed``."""
     rng = np.random.RandomState(1)
     x, wr, w1, w3, w2 = moe_weights(rng, 48, 16, 8, routed)
-    bias = jnp.asarray(0.1 * rng.randn(routed), jnp.float32)
+    softmax = case == "mellum2"
+    bias = None if softmax \
+        else jnp.asarray(0.1 * rng.randn(routed), jnp.float32)
     ref, ref_routed = reference_router(case)
     whole, n_whole = ref_routed(x, wr, bias, w1, w3, w2, k, scale)
+    if softmax:             # the reference counts nothing: the op's own
+        n_whole = moe.routed_experts(x, wr, w1, w3, w2, top_k=k,
+                                     with_counts=True)[1]
     assert int(n_whole.sum()) == 48 * k
     total = 0.0
     if shared:
@@ -794,8 +806,9 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_layer(
     for off in range(0, routed, held):
         part, n = moe.routed_experts(
             x, wr, w1[off:off + held], w3[off:off + held],
-            w2[off:off + held], top_k=k, expert_offset=off, score="sigmoid",
-            bias=bias, norm_eps=eps, scale=scale, with_counts=True)
+            w2[off:off + held], top_k=k, expert_offset=off,
+            score="softmax" if softmax else "sigmoid", bias=bias,
+            norm_eps=eps, scale=scale, with_counts=True)
         mine, _ = ref_routed(x, wr, bias, w1[off:off + held],
                              w3[off:off + held], w2[off:off + held], k,
                              scale, off)
@@ -2333,3 +2346,220 @@ def test_config_refuses_a_delta_layer_it_cannot_build():
     with pytest.raises(ValueError, match="needs shared_width"):
         decoder_lm.Config(**base, shared_gate=True)
     assert "delta" in decoder_lm.MIXERS
+
+
+# == three window layers to one global layer whose rotary is a YaRN table ==
+# == of its own at a softmax scale of its own, a softmax router and no    ==
+# == other feed-forward: the program against the reference of             ==
+# == ``chipbench/configs/mellum2_12b_a2_5b``                              ==
+
+MELLUM = "configs/mellum2_12b_a2_5b"
+M_BUILD = plugins.load(MELLUM, "build")
+M_REF = plugins.load(MELLUM, "reference")
+
+
+def mellum_sizes(**over):
+    sizes = json.load(open(os.path.join(ROOT, "chipbench", MELLUM,
+                                        "config.json")))
+    return {**sizes, **sizes["tiny"], **over}
+
+
+@pytest.mark.parametrize("flash", ["xla", "pallas"])
+def test_rotary_by_layer_kind_program_equals_the_reference_and_its_adam_step(
+        monkeypatch, flash):
+    """Loss, every gradient and every parameter after one Adam step through
+    ``fluid.Executor`` with ``optimizer.minimize``: three window layers of
+    16 keys over 64 tokens (a band of 3 tiles of 8, one interior, as 1,024
+    over tiles of 512) that rotate by ``rope_theta``, then the global layer
+    that rotates by the YaRN table at the YaRN scale; the reference makes
+    both tables itself.  With the two tables SWAPPED in the reference the
+    same gradients are far off."""
+    monkeypatch.setenv("PADDLE_TPU_FLASH", "1" if flash == "pallas" else "0")
+    monkeypatch.setattr(psf, "BLOCK", 8)
+    sizes = mellum_sizes()
+    assert sizes["seq_len"] == 4 * sizes["sliding_window"]
+    assert sizes["num_experts"] < sizes["published"]["num_experts"]
+    assert sizes["layer_types"][:4] == ["sliding_attention"] * 3 \
+        + ["full_attention"]
+    built, names, weights = seeded_program(M_BUILD, M_REF, sizes)
+    main, scope = fluid.default_main_program(), fluid.global_scope()
+    assert {n[3:] for n in names if n.startswith("l3_")} == {
+        "attn_norm", "q_w", "q_norm", "k_w", "k_norm", "v_w", "o_w",
+        "moe_norm", "router_w", "w1", "w3", "w2"}
+    feed = M_BUILD.make_feed(sizes, 2, np.random.RandomState(3))
+    outs = fluid.Executor(fluid.TPUPlace()).run(
+        main, feed=feed, fetch_list=[built["loss"]]
+        + [n + "@GRAD" for n in names])
+    ref_loss, ref_grads = M_REF.loss_and_grads(weights, feed, sizes)
+    assert float(outs[0].reshape(-1)[0]) == pytest.approx(float(ref_loss),
+                                                          rel=1e-5)
+    for name, g, r in zip(names, outs[1:], ref_grads):
+        g = np.asarray(g).reshape(r.shape)
+        assert np.abs(g - r).max() <= 3e-4 * np.abs(r).max() + 1e-7, name
+        assert np.abs(r).max() > 0, name
+    for name, w, g in zip(names, weights, outs[1:]):
+        np.testing.assert_allclose(
+            np.asarray(scope.get(name)).reshape(w.shape),
+            M_REF.optimizer_step(w, jnp.asarray(g).reshape(w.shape), sizes),
+            atol=2e-6, err_msg=name)
+    # the tables the other way round: no such model
+    rope = sizes["rope_parameters"]
+    swapped = {**sizes, "rope_parameters": {
+        "full_attention": rope["sliding_attention"],
+        "sliding_attention": rope["full_attention"]}}
+    from chipbench import check
+
+    def grad_rel(loss, grads):      # the number that decides ``correct``
+        return float(check._errors(outs[0], outs[1:], loss,
+                                   grads)["grad_rel"])
+
+    assert grad_rel(ref_loss, ref_grads) < 1e-4
+    assert grad_rel(*M_REF.loss_and_grads(weights, feed, swapped)) > 0.1
+    # what ran, as the counters say it
+    per = 1 if flash == "pallas" else 2
+    assert counters("ops.sparse_attention.calls") == {
+        f'ops.sparse_attention.calls{{path="{flash}",seq="64",topk="0",'
+        f'window="16"}}': 3 * per,
+        f'ops.sparse_attention.calls{{path="{flash}",seq="64",'
+        f'topk="0"}}': per}
+    assert not counters("ops.sparse_attention.declined")
+    assert counters("ops.rotary.calls") == {
+        'ops.rotary.calls{dims="16",pairing="half",scaled="0"}': 6,
+        'ops.rotary.calls{dims="16",pairing="half",scaled="1"}': 2}
+    assert counters("models.decoder.rotary") == {
+        'models.decoder.rotary{kind="global",scope="layer3",'
+        'table="given"}': 1}
+    assert counters("models.decoder.blocks") == {
+        'models.decoder.blocks{mixer="attention",residual="sequential",'
+        'where="trunk"}': 4}
+    (key, n), = counters("ops.moe.calls").items()
+    assert "score" not in key and 'routed="8"' in key and 'held="4"' in key \
+        and n == 2 * 4
+    if flash == "pallas":       # 8 rows of tiles: 1 + 2 + 6 x 3, 7 interior
+        tiles = counters("ops.sparse_attention.tiles")
+        for kernel in ("fwd", "dq", "dkv"):
+            assert [tiles[f'ops.sparse_attention.tiles{{kernel="window_flash_'
+                          f'{kernel}",kind="{kind}"}}']
+                    for kind in ("interior", "edge")] == [3 * 7, 3 * 14]
+    # every op under a name; all of the global layer's mixer but the
+    # residual add under ``.global``, no window layer's
+    scopes = {op.attrs.get("op_namescope", "") for op in main.all_ops()}
+    assert {"embed", "head", "layer3.mixer.global"} | {
+        f"layer{i}.{part}" for i in range(4)
+        for part in ("mixer", "ffn")} == scopes
+
+
+def test_window_layers_lower_theta_and_the_global_layer_the_record():
+    """A window layer's two rotaries carry ``theta`` and no table, its
+    attention ``head_dim ** -0.5``; the global layer's carry the record's
+    frequencies, its attention the record's scale."""
+    sizes = mellum_sizes()
+    M_BUILD.build(fluid, sizes)
+    record = M_BUILD.config_of(sizes).global_rotary
+    assert record == M_BUILD.global_rotary(sizes)
+    block = fluid.default_main_program().global_block()
+    seen = {"window": 0, "global": 0}
+    for op in block.ops:
+        if op.type not in ("rotary_embedding", "sparse_attention"):
+            continue
+        own = op.attrs["op_namescope"] == "layer3.mixer.global"
+        seen["global" if own else "window"] += 1
+        if op.type == "rotary_embedding":
+            assert op.attrs["theta"] == 100.0
+            assert op.attrs.get("inv_freq") == (
+                list(record.inv_freq) if own else None)
+        else:
+            assert op.attrs.get("window", 0) == (0 if own else 16)
+            assert op.attrs["scale"] == pytest.approx(
+                record.scale if own else 16 ** -0.5, rel=1e-12)
+    assert seen == {"window": 9, "global": 3}
+    assert record.scale == pytest.approx(0.25 * 1.63139, rel=1e-5)
+
+
+def test_the_yarn_table_by_hand():
+    """Mellum2's ``full_attention`` table at the published sizes: pairs
+    0-18 turn more than 32 times over 8,192 positions and stay as they are,
+    pairs 35-63 turn less than once and are divided by 16, a linear blend
+    between; the temperature is ``(0.1 ln 16 + 1) ** 2`` on ``128 **
+    -0.5``.  The builder's record and the reference's own table agree."""
+    sizes = json.load(open(os.path.join(ROOT, "chipbench", MELLUM,
+                                        "config.json")))
+    record = M_BUILD.config_of(sizes).global_rotary
+    plain = [500000.0 ** (-2.0 * j / 128) for j in range(64)]
+    assert len(record.inv_freq) == 64
+    np.testing.assert_allclose(record.inv_freq[:19], plain[:19], rtol=1e-12)
+    np.testing.assert_allclose(record.inv_freq[35:],
+                               [f / 16 for f in plain[35:]], rtol=1e-12)
+    for j in range(19, 35):
+        r = (j - 18) / 17
+        assert record.inv_freq[j] == pytest.approx(
+            plain[j] / 16 * r + plain[j] * (1 - r), rel=1e-12)
+        assert plain[j] / 16 < record.inv_freq[j] < plain[j]
+    m = 1.2772588722239782
+    assert m == pytest.approx(0.1 * math.log(16) + 1, rel=1e-15)
+    assert m * m == pytest.approx(1.63139, rel=1e-5)
+    assert record.scale == pytest.approx(128 ** -0.5 * m * m, rel=1e-12)
+    table, factor = M_REF.rotary_table(
+        sizes["rope_parameters"]["full_attention"], 128)
+    np.testing.assert_allclose(table, record.inv_freq, rtol=1e-12)
+    assert factor == m
+    assert M_REF.rotary_table(
+        sizes["rope_parameters"]["sliding_attention"], 128) == (
+            tuple(plain), 1.0)
+    rope = sizes["rope_parameters"]
+    with pytest.raises(ValueError, match="is not the 0.1 ln"):
+        M_BUILD.config_of({**sizes, "rope_parameters": {
+            **rope, "full_attention": {**rope["full_attention"],
+                                       "attention_factor": 1.2}}})
+    with pytest.raises(ValueError, match="a plain table for the window"):
+        M_BUILD.config_of({**sizes, "rope_parameters": {
+            **rope, "sliding_attention": rope["full_attention"]}})
+
+
+def test_config_refuses_a_global_rotary_it_cannot_build():
+    from paddle_tpu.models import decoder_lm
+
+    base = dict(vocab_size=128, hidden_size=64, num_layers=4, num_heads=4,
+                num_kv_heads=2, head_dim=16, expert_width=32, num_routed=8,
+                experts_held=4, experts_per_token=2, window=16,
+                global_every=4)
+    record = decoder_lm.Rotary(inv_freq=tuple(0.5 ** j for j in range(8)),
+                               scale=0.3)
+    cfg = decoder_lm.Config(**base, global_rotary=record)
+    assert cfg.global_rotary == record
+    assert decoder_lm.Config(
+        **base, global_rotary=(list(record.inv_freq), 0.3)
+    ).global_rotary == record
+    assert decoder_lm.Rotary(record.inv_freq).scale == 0.0
+    assert decoder_lm.Config(**base).global_rotary is None
+    with pytest.raises(ValueError, match="goes without positions"):
+        decoder_lm.Config(**base, rope_global=False, global_rotary=record)
+    with pytest.raises(ValueError, match="each of the 8 pairs"):
+        decoder_lm.Config(**base, global_rotary=record._replace(
+            inv_freq=record.inv_freq[:4]))
+    assert decoder_lm.Config(
+        **base, rotary_dims=8, global_rotary=record._replace(
+            inv_freq=record.inv_freq[:4])).global_rotary.scale == 0.3
+
+
+@pytest.mark.parametrize("config", sorted(PROGRAMS_BEFORE)
+                         + sorted(MORE_PROGRAMS_BEFORE)
+                         + ["qwen3_next_80b_a3b"])
+def test_programs_without_a_global_rotary_have_no_scope_or_counter_of_it(
+        config):
+    """``global_rotary`` unset: no op under ``.global``, no rotary from a
+    table in a plain attention layer, no ``models.decoder.rotary`` (the six
+    older programs' digests, above, hold too)."""
+    from paddle_tpu.models import decoder_lm
+
+    if config == "tiny_config":
+        decoder_lm.build()
+    else:
+        sizes = json.load(open(os.path.join(ROOT, "chipbench", "configs",
+                                            config, "config.json")))
+        plugins.load(f"configs/{config}", "build").build(
+            fluid, {**sizes, **sizes["tiny"]})
+    ops = list(fluid.default_main_program().all_ops())
+    assert ops and not any(
+        op.attrs.get("op_namescope", "").endswith(".global") for op in ops)
+    assert not counters("models.decoder.rotary")

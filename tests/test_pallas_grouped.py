@@ -128,7 +128,7 @@ def test_visits_name_every_tile_of_every_group_once(case, empty_groups):
 #: Instella's 1,408 = 11 x 128 (768 + 640; 384 x 3 + 256)
 CELLS = {"keye": (65536, 2048, 768), "trinity": (49152, 2048, 1024),
          "lfm2": (32768, 2048, 1792), "instella": (49152, 2048, 1408),
-         "qwen3_next": (81920, 2048, 512)}
+         "qwen3_next": (81920, 2048, 512), "mellum2": (65536, 2304, 896)}
 TILES = {("keye", "up"): 768, ("keye", "down"): 2048,
          ("keye", "up_t"): 384, ("keye", "down_t"): 1024,
          ("trinity", "up"): 512, ("trinity", "down"): 1024,
@@ -138,7 +138,10 @@ TILES = {("keye", "up"): 768, ("keye", "down"): 2048,
          ("instella", "up"): 768, ("instella", "down"): 1024,
          ("instella", "up_t"): 384, ("instella", "down_t"): 512,
          ("qwen3_next", "up"): 512, ("qwen3_next", "down"): 2048,
-         ("qwen3_next", "up_t"): 256, ("qwen3_next", "down_t"): 1024}
+         ("qwen3_next", "up_t"): 256, ("qwen3_next", "down_t"): 1024,
+         # the first contraction that is not 2,048 wide
+         ("mellum2", "up"): 512, ("mellum2", "down"): 1152,
+         ("mellum2", "up_t"): 256, ("mellum2", "down_t"): 1152}
 
 
 def vmem_bytes(tm, k, tn, n, itemsize, transposed_result):

@@ -190,7 +190,8 @@ GROUPED_CELLS = {"keye": (65536, 2048, 768, 16),
                  "trinity": (49152, 2048, 1024, 8),
                  "lfm2": (32768, 2048, 1792, 8),
                  "instella": (49152, 2048, 1408, 8),
-                 "qwen3_next": (81920, 2048, 512, 16)}
+                 "qwen3_next": (81920, 2048, 512, 16),
+                 "mellum2": (65536, 2304, 896, 8)}
 #: no cell's: an expert width of 13 lane rows, whose only dividing tile is
 #: one lane row as at Instella's 11 (ragged tiles of 896 + 768 and 384 x 4 +
 #: 128 where the result is that wide: ``plain`` and ``weights_gradient`` of
@@ -373,7 +374,10 @@ GROUPED_TILES = {
     "trinity": {"up": 512, "down": 1024, "up_t": 256, "down_t": 512},
     "lfm2": {"up": 896, "down": 1024, "up_t": 256, "down_t": 256},
     "instella": {"up": 768, "down": 1024, "up_t": 384, "down_t": 512},
-    "qwen3_next": {"up": 512, "down": 2048, "up_t": 256, "down_t": 1024}}
+    "qwen3_next": {"up": 512, "down": 2048, "up_t": 256, "down_t": 1024},
+    # the first hidden width that is not 2,048: 18 lane rows in two tiles;
+    # 896 is 7 lane rows, ragged as 512 + 384 and as 256 x 3 + 128
+    "mellum2": {"up": 512, "down": 1152, "up_t": 256, "down_t": 1152}}
 
 
 def _mosaic_bodies(stablehlo_text):
@@ -431,7 +435,7 @@ def test_grouped_grid_is_the_tiles(topo, cell, form, which, key):
 #: (``GROUPED_CELLS`` has the rows, the widths and the experts held)
 GROUPED_LAYERS = {"keye": (8192, 8, 128), "trinity": (6144, 8, 128),
                   "lfm2": (8192, 4, 32), "instella": (8192, 6, 64),
-                  "qwen3_next": (8192, 10, 512)}
+                  "qwen3_next": (8192, 10, 512), "mellum2": (8192, 8, 64)}
 
 
 @pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))
