@@ -8,6 +8,7 @@ reference's compile-time InferShape.
 from __future__ import annotations
 
 import copy
+import math
 
 import numpy as np
 
@@ -388,6 +389,47 @@ def cross_entropy(input, label, soft_label=False, ignore_index=-100):
     return out
 
 
+def _uniform_smoothing(block, logits, label):
+    """``(ids, eps)`` where the soft ``label`` is, in ``block``,
+    ``scale(one_hot(ids, V), 1 - eps, eps / V)`` over the ``V`` classes of
+    ``logits`` (what ``label_smooth`` with no ``prior_dist`` appends), else
+    None.  The loss then needs ``ids`` and ``eps``, not the distribution.
+    (The two forms part only on an id outside ``[0, V)``, which the
+    reference's ``one_hot`` refuses: here it made a row of zeros.)"""
+    if not logits.shape:
+        return None
+    made = {}                      # var name -> indices of the ops writing it
+    for i, op in enumerate(block.ops):
+        for n in op.output_arg_names:
+            made.setdefault(n, []).append(i)
+
+    def only_maker(name, op_type):
+        at = made.get(name, ())
+        if len(at) == 1 and block.ops[at[0]].type == op_type:
+            return at[0]
+        return None
+
+    at = only_maker(label.name, "scale")
+    if at is None:
+        return None
+    smooth = block.ops[at]
+    at = only_maker(smooth.input("X")[0], "one_hot")
+    if at is None:
+        return None
+    hot = block.ops[at]
+    width = logits.shape[-1]
+    k, bias = smooth.attrs["scale"], smooth.attrs["bias"]
+    ids = hot.input("X")[0]
+    if hot.attrs["depth"] != width or not 0.0 <= k < 1.0 \
+            or not smooth.attrs.get("bias_after_scale", True) \
+            or not math.isclose(bias * width, 1.0 - k, rel_tol=1e-9) \
+            or not block.has_var(ids) \
+            or not str(block.var(ids).dtype).startswith(("int", "uint")) \
+            or any(i > at for i in made.get(ids, ())):
+        return None
+    return block.var(ids), 1.0 - k
+
+
 def softmax_with_cross_entropy(logits, label, soft_label=False,
                                ignore_index=-100, numeric_stable_mode=True,
                                return_softmax=False):
@@ -397,11 +439,20 @@ def softmax_with_cross_entropy(logits, label, soft_label=False,
     loss = helper.create_variable_for_type_inference(logits.dtype)
     if logits.shape:
         loss.shape = tuple(logits.shape[:-1]) + (1,)
+    attrs = {"soft_label": soft_label, "ignore_index": ignore_index}
+    smoothing = soft_label and _uniform_smoothing(
+        helper.main_program.current_block(), logits, label)
+    if smoothing:
+        # hard labels again, smoothed inside the loss: the one_hot and the
+        # scale stay in the block with no reader (a soft label knows no
+        # ignore_index, so the hard form is given none)
+        label, eps = smoothing
+        attrs = {"soft_label": False, "ignore_index": -100,
+                 "smooth_epsilon": eps}
     helper.append_op(type="softmax_with_cross_entropy",
                      inputs={"Logits": [logits], "Label": [label]},
                      outputs={"Softmax": [softmax_out], "Loss": [loss]},
-                     attrs={"soft_label": soft_label,
-                            "ignore_index": ignore_index})
+                     attrs=attrs)
     if return_softmax:
         return loss, softmax_out
     return loss

@@ -44,20 +44,23 @@ def softmax_with_cross_entropy(ctx):
     from . import kernel_choice, pallas_fused
 
     soft = ctx.attr("soft_label", False)
+    # hard labels smoothed uniformly: (1 - eps) * onehot + eps / V, from
+    # the label column alone (set by layers.softmax_with_cross_entropy)
+    eps = 0.0 if soft else float(ctx.attr("smooth_epsilon", 0.0))
     if kernel_choice.gate("fused") \
             and pallas_fused.xent_fusable(logits, label, soft):
         # streaming Pallas lowering: the [batch, vocab] probability matrix
         # never materializes in HBM; backward recomputes P per tile from
         # the saved logsumexp (ops/pallas_fused.py)
         return pallas_fused.softmax_xent_op(
-            logits, label, soft, ctx.attr("ignore_index", -100))
+            logits, label, soft, ctx.attr("ignore_index", -100), eps)
 
     in_dtype = logits.dtype
     if amp.is_low_float(in_dtype):
         logits = logits.astype(jnp.float32)  # fp32 at the loss boundary
     sm = jax.nn.softmax(logits, axis=-1).astype(in_dtype)
     logp = jax.nn.log_softmax(logits, axis=-1)
-    if ctx.attr("soft_label", False):
+    if soft:
         loss = -jnp.sum(label * logp, -1, keepdims=True)
     else:
         li = label
@@ -65,6 +68,9 @@ def softmax_with_cross_entropy(ctx):
             li = li.reshape(li.shape[:-1])
         li = li.astype(jnp.int32)
         loss = -jnp.take_along_axis(logp, li[..., None], axis=-1)
+        if eps:
+            loss = (1.0 - eps) * loss - (eps / logp.shape[-1]) * jnp.sum(
+                logp, -1, keepdims=True)
         ignore = ctx.attr("ignore_index", -100)
         if ignore >= 0:
             loss = jnp.where((li == ignore)[..., None], 0.0, loss)

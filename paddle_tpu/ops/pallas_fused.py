@@ -10,7 +10,17 @@ This completes the fused-kernel layer ROADMAP item 2 reserves for Pallas
    ``[batch, vocab]`` probability matrix never materializes in HBM; the
    backward recomputes ``P = exp(logits - lse)`` per tile from the saved
    logsumexp (the FlashAttention discipline applied to the loss boundary).
-   Hard labels (with ``ignore_index``) and soft labels both stream.
+   The target takes three forms and all stream: hard labels (an int32
+   column, with ``ignore_index``), soft labels (a ``[batch, vocab]``
+   distribution read tile by tile), and hard labels SMOOTHED uniformly,
+   ``(1 - eps) * onehot + eps / V``, which is still the int32 column:
+   the loss is ``lse - (1 - eps) * x[label] - (eps / V) * sum_j x_j``
+   (one more row accumulator) and the gradient's target a select between
+   two constants.  ``eps`` is the op's ``smooth_epsilon`` attribute,
+   which ``layers.softmax_with_cross_entropy`` sets where the soft label
+   it is handed is ``label_smooth(one_hot(ids))`` with no prior
+   (``fluid/layers/nn.py`` ``_uniform_smoothing``), so a program written
+   the reference's way never makes the dense distribution.
  - **Fused optimizer updates**: momentum and adam as single multi-tensor
    kernels — one grid sweep reads param + grad + moments and writes the
    updated buffers back through ``input_output_aliases``, instead of the
@@ -103,7 +113,7 @@ def _fit_block(size, block):
 # ---------------------------------------------------------------------------
 
 
-def _xent_partial_kernel(x_ref, lab_ref, *out_refs, bv, n_v, v, soft):
+def _xent_partial_kernel(x_ref, lab_ref, *out_refs, bv, n_v, v, soft, eps):
     """Grid step (row-block, vocab-block): online-logsumexp state (m, l)
     plus the label accumulator(s) in fp32 VMEM scratch, carried across the
     (sequential, minormost) vocab dimension — VMEM holds one [br, bv]
@@ -113,8 +123,10 @@ def _xent_partial_kernel(x_ref, lab_ref, *out_refs, bv, n_v, v, soft):
     loss, so one kernel serves both the single-device path (finalized in
     four trivial [R, 1] jnp ops) and the tp-sharded path (finalized after
     a cross-shard max/sum exchange).  ``a`` is the picked-logit sum (hard)
-    or ``sum(y * logits)`` (soft); ``b`` (soft only) is ``sum(y)``."""
-    if soft:
+    or ``sum(y * logits)`` (soft); ``b`` is ``sum(y)`` (soft) or, for hard
+    labels smoothed by ``eps > 0``, the row sum of the logits, which is all
+    the uniform share of the target needs (``_finalize_loss``)."""
+    if soft or eps:
         m_out, l_out, a_out, b_out, m_ref, l_ref, a_ref, b_ref = out_refs
     else:
         m_out, l_out, a_out, m_ref, l_ref, a_ref = out_refs
@@ -136,10 +148,13 @@ def _xent_partial_kernel(x_ref, lab_ref, *out_refs, bv, n_v, v, soft):
         # ints promote to i64, which Mosaic's index ops reject
         cols = j * jnp.int32(bv) + lax.broadcasted_iota(
             jnp.int32, x.shape, 1)
+    xs = x                          # what the smoothed form's row sum adds
     if ragged:
         # the last vocab block hangs over the array's edge and reads
         # unspecified values there: they must not reach max/sum
         live = cols < jnp.int32(v)
+        if eps:
+            xs = jnp.where(live, x, 0.0)
         x = jnp.where(live, x, jnp.float32(NEG_INF))
     m = m_ref[:]
     m_new = jnp.maximum(m, jnp.max(x, axis=1, keepdims=True))
@@ -159,6 +174,8 @@ def _xent_partial_kernel(x_ref, lab_ref, *out_refs, bv, n_v, v, soft):
         lab = lab_ref[...]                           # [br, 1] int32
         a_ref[:] = a_ref[:] + jnp.sum(
             jnp.where(cols == lab, x, 0.0), axis=1, keepdims=True)
+        if eps:
+            b_ref[:] = b_ref[:] + jnp.sum(xs, axis=1, keepdims=True)
 
     @pl.when(j == n_v - 1)
     def _flush():
@@ -170,13 +187,15 @@ def _xent_partial_kernel(x_ref, lab_ref, *out_refs, bv, n_v, v, soft):
 
 
 def _xent_bwd_kernel(x_ref, lab_ref, lse_ref, g1_ref, g2_ref, dx_ref, *,
-                     bv, soft):
+                     bv, soft, eps, v):
     """Backward grid step — tiles are independent (no carry): recompute
     ``P = exp(x - lse)`` for this [br, bv] tile from the saved logsumexp
     and emit ``dx = g1 * P - g2 * target`` where target is the one-hot
-    (hard) or the soft-label tile.  ``g1``/``g2`` are per-row coefficients
-    precomputed on the host side of the trace (they fold the incoming loss
-    cotangent, the ignore mask, ``sum(y)`` and any lse cotangent)."""
+    (hard), ``(1 - eps) * onehot + eps / v`` (hard, smoothed over the
+    ``v`` classes of the whole row) or the soft-label tile.  ``g1``/``g2``
+    are per-row coefficients precomputed on the host side of the trace
+    (they fold the incoming loss cotangent, the ignore mask, ``sum(y)``
+    and any lse cotangent)."""
     j = pl.program_id(1)
     x = x_ref[...].astype(jnp.float32)
     p = jnp.exp(x - lse_ref[...])
@@ -187,7 +206,12 @@ def _xent_bwd_kernel(x_ref, lab_ref, lse_ref, g1_ref, g2_ref, dx_ref, *,
     else:
         cols = j * jnp.int32(bv) + lax.broadcasted_iota(
             jnp.int32, x.shape, 1)
-        tgt = (cols == lab_ref[...]).astype(jnp.float32)
+        if eps:
+            tgt = jnp.where(cols == lab_ref[...],
+                            jnp.float32(1.0 - eps + eps / v),
+                            jnp.float32(eps / v))
+        else:
+            tgt = (cols == lab_ref[...]).astype(jnp.float32)
     dx_ref[...] = (g1 * p - g2 * tgt).astype(dx_ref.dtype)
 
 
@@ -201,9 +225,10 @@ def _row_col(i, j):
     return block_index(i, 0)
 
 
-def _xent_partial(x2, lab2, soft, block_r, block_v, interpret):
+def _xent_partial(x2, lab2, soft, block_r, block_v, interpret, eps):
     """Run the streaming kernel over ``x2 [R, V]``; returns per-row fp32
-    ``(m, l, a, b)`` columns (``b`` is None for hard labels)."""
+    ``(m, l, a, b)`` columns (``b`` is None for hard labels with no
+    smoothing)."""
     from jax.experimental.pallas import tpu as pltpu
 
     r, v = x2.shape
@@ -214,10 +239,10 @@ def _xent_partial(x2, lab2, soft, block_r, block_v, interpret):
     lab_spec = (pl.BlockSpec((br, bv), _tile) if soft
                 else pl.BlockSpec((br, 1), _row_col))
     out_spec = pl.BlockSpec((br, 1), _row_col)
-    n_out = 4 if soft else 3
+    n_out = 4 if soft or eps else 3
     outs = pl.pallas_call(
         functools.partial(_xent_partial_kernel, bv=bv, n_v=n_v, v=v,
-                          soft=soft),
+                          soft=soft, eps=eps),
         out_shape=[col] * n_out,
         grid=(pl.cdiv(r, br), n_v),
         in_specs=[pl.BlockSpec((br, bv), _tile), lab_spec],
@@ -225,7 +250,7 @@ def _xent_partial(x2, lab2, soft, block_r, block_v, interpret):
         scratch_shapes=[pltpu.VMEM((br, 1), jnp.float32)] * n_out,
         interpret=kernel_choice.interpret(interpret),
     )(x2, lab2)
-    if soft:
+    if n_out == 4:
         m, l, a, b = outs
     else:
         (m, l, a), b = outs, None
@@ -233,7 +258,9 @@ def _xent_partial(x2, lab2, soft, block_r, block_v, interpret):
 
 
 def _xent_bwd_call(x2, lab2, lse, g1, g2, soft, block_r, block_v,
-                   interpret):
+                   interpret, eps, v_all):
+    """``v_all`` is the width the smoothing spreads ``eps`` over: the whole
+    row's, which under a tp-sharded vocab is not ``x2``'s."""
     r, v = x2.shape
     br = _fit_block(r, block_r)
     bv = _fit_block(v, block_v)
@@ -241,7 +268,8 @@ def _xent_bwd_call(x2, lab2, lse, g1, g2, soft, block_r, block_v,
                 else pl.BlockSpec((br, 1), _row_col))
     col = pl.BlockSpec((br, 1), _row_col)
     return pl.pallas_call(
-        functools.partial(_xent_bwd_kernel, bv=bv, soft=soft),
+        functools.partial(_xent_bwd_kernel, bv=bv, soft=soft, eps=eps,
+                          v=v_all),
         out_shape=jax.ShapeDtypeStruct((r, v), x2.dtype),
         grid=(pl.cdiv(r, br), pl.cdiv(v, bv)),
         in_specs=[pl.BlockSpec((br, bv), _tile), lab_spec, col, col, col],
@@ -250,12 +278,16 @@ def _xent_bwd_call(x2, lab2, lse, g1, g2, soft, block_r, block_v,
     )(x2, lab2, lse, g1, g2)
 
 
-def _finalize_loss(m, l, a, b, lab2, soft, ignore_index):
+def _finalize_loss(m, l, a, b, lab2, soft, ignore_index, eps, v):
+    """``eps > 0`` (hard labels): the target is ``(1 - eps) * onehot +
+    eps / v`` over the ``v`` classes of the whole row, and ``b`` the row
+    sum of the logits."""
     lse = m + jnp.log(jnp.maximum(l, jnp.float32(1e-30)))
     if soft:
         loss = lse * b - a
     else:
-        loss = lse - a
+        loss = (lse - jnp.float32(1.0 - eps) * a - jnp.float32(eps / v) * b
+                if eps else lse - a)
         if ignore_index >= 0:
             loss = jnp.where(lab2 == jnp.int32(ignore_index), 0.0, loss)
     return loss, lse
@@ -282,42 +314,47 @@ def _label_zeros(label):
     return np.zeros(np.shape(label), jax.dtypes.float0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7))
 def softmax_xent(logits2, label2, soft_label=False, ignore_index=-100,
                  block_r=DEFAULT_BLOCK_R, block_v=DEFAULT_BLOCK_V,
-                 interpret=None):
+                 interpret=None, smooth_epsilon=0.0):
     """Streamed ``softmax_with_cross_entropy`` over ``[R, V]`` logits.
 
     Returns ``(loss [R, 1] fp32, lse [R, 1] fp32)``; the probability
     matrix is never materialized — callers reconstruct softmax lazily as
     ``exp(logits - lse)`` (dead-code-eliminated when unused).  Matches
     ``ops/loss_ops.py:softmax_with_cross_entropy`` semantics: hard integer
-    labels [R, 1] with ``ignore_index``, or soft [R, V] distributions."""
+    labels [R, 1] with ``ignore_index``, or soft [R, V] distributions.
+    ``smooth_epsilon > 0`` (a Python float, hard labels only) makes the
+    target ``(1 - eps) * onehot(label) + eps / V`` without anyone writing
+    that distribution down."""
     loss, lse, _ = _xent_fwd(logits2, label2, soft_label, ignore_index,
-                             block_r, block_v, interpret)
+                             block_r, block_v, interpret, smooth_epsilon)
     return loss, lse
 
 
-def _xent_fwd(logits2, label2, soft, ignore, block_r, block_v, interpret):
+def _xent_fwd(logits2, label2, soft, ignore, block_r, block_v, interpret,
+              eps):
     m, l, a, b = _xent_partial(logits2, label2, soft, block_r, block_v,
-                               interpret)
-    loss, lse = _finalize_loss(m, l, a, b, label2, soft, ignore)
-    return loss, lse, (logits2, label2, lse, b)
+                               interpret, eps)
+    loss, lse = _finalize_loss(m, l, a, b, label2, soft, ignore, eps,
+                               logits2.shape[1])
+    return loss, lse, (logits2, label2, lse, b if soft else None)
 
 
 def _xent_fwd_vjp(logits2, label2, soft, ignore, block_r, block_v,
-                  interpret):
+                  interpret, eps):
     loss, lse, res = _xent_fwd(logits2, label2, soft, ignore, block_r,
-                               block_v, interpret)
+                               block_v, interpret, eps)
     return (loss, lse), res
 
 
-def _xent_bwd_vjp(soft, ignore, block_r, block_v, interpret, res, ct):
+def _xent_bwd_vjp(soft, ignore, block_r, block_v, interpret, eps, res, ct):
     dloss, dlse = ct
     logits2, label2, lse, b = res
     g1, g2 = _bwd_coeffs(label2, b, dloss, dlse, soft, ignore)
     dx = _xent_bwd_call(logits2, label2, lse, g1, g2, soft, block_r,
-                        block_v, interpret)
+                        block_v, interpret, eps, logits2.shape[1])
     return dx, _label_zeros(label2)
 
 
@@ -354,30 +391,34 @@ def _shift_labels(lab_loc, col_ax, vloc, soft):
     return lab_loc - lax.axis_index(col_ax) * jnp.int32(vloc)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7, 8))
 def softmax_xent_sharded(logits2, label2, mesh, soft_label=False,
                          ignore_index=-100, block_r=DEFAULT_BLOCK_R,
-                         block_v=DEFAULT_BLOCK_V, interpret=None):
+                         block_v=DEFAULT_BLOCK_V, interpret=None,
+                         smooth_epsilon=0.0):
     """:func:`softmax_xent` lowered through ``shard_map`` on ``mesh``:
     rows stay dp-sharded, the vocab dim stays tp-sharded through the
     kernel, and the per-shard partial (m, l, a[, b]) state is combined
     with one cross-shard max/sum exchange (psum/pmax over tp) before the
     loss finalizes — the logsumexp exchange of Megatron-style vocab
-    parallelism.  Outputs replicate over tp (loss is a per-row scalar)."""
+    parallelism.  Outputs replicate over tp (loss is a per-row scalar).
+    ``smooth_epsilon`` spreads over the GLOBAL width, and the row sum of
+    the logits it needs is exchanged like the picked logit."""
     loss, lse, _ = _xent_sharded_fwd(logits2, label2, mesh, soft_label,
                                      ignore_index, block_r, block_v,
-                                     interpret)
+                                     interpret, smooth_epsilon)
     return loss, lse
 
 
 def _xent_sharded_fwd(logits2, label2, mesh, soft, ignore, block_r,
-                      block_v, interpret):
+                      block_v, interpret, eps):
     xspec, lspec, cspec, col_ax = _xent_specs(mesh, logits2.shape, soft)
+    v_all = logits2.shape[1]
 
     def body(x_loc, lab_loc):
         lab_k = _shift_labels(lab_loc, col_ax, x_loc.shape[1], soft)
         m, l, a, b = _xent_partial(x_loc, lab_k, soft, block_r, block_v,
-                                   interpret)
+                                   interpret, eps)
         if col_ax is not None:
             m_g = lax.pmax(m, col_ax)
             l = lax.psum(l * jnp.exp(m - m_g), col_ax)
@@ -386,8 +427,9 @@ def _xent_sharded_fwd(logits2, label2, mesh, soft, ignore, block_r,
                 b = lax.psum(b, col_ax)
             m = m_g
         # the ignore mask needs the ORIGINAL (unshifted) label
-        loss, lse = _finalize_loss(m, l, a, b, lab_loc, soft, ignore)
-        if b is None:
+        loss, lse = _finalize_loss(m, l, a, b, lab_loc, soft, ignore, eps,
+                                   v_all)
+        if not soft:
             b = jnp.ones_like(lse)
         return loss, lse, b
 
@@ -398,14 +440,15 @@ def _xent_sharded_fwd(logits2, label2, mesh, soft, ignore, block_r,
 
 
 def _xent_sharded_fwd_vjp(logits2, label2, mesh, soft, ignore, block_r,
-                          block_v, interpret):
+                          block_v, interpret, eps):
     loss, lse, res = _xent_sharded_fwd(logits2, label2, mesh, soft,
-                                       ignore, block_r, block_v, interpret)
+                                       ignore, block_r, block_v, interpret,
+                                       eps)
     return (loss, lse), res
 
 
 def _xent_sharded_bwd_vjp(mesh, soft, ignore, block_r, block_v, interpret,
-                          res, ct):
+                          eps, res, ct):
     dloss, dlse = ct
     logits2, label2, lse, b = res
     g1, g2 = _bwd_coeffs(label2, b if soft else None, dloss, dlse, soft,
@@ -415,7 +458,8 @@ def _xent_sharded_bwd_vjp(mesh, soft, ignore, block_r, block_v, interpret,
     def body(x_loc, lab_loc, lse_loc, g1_loc, g2_loc):
         lab_k = _shift_labels(lab_loc, col_ax, x_loc.shape[1], soft)
         return _xent_bwd_call(x_loc, lab_k, lse_loc, g1_loc, g2_loc, soft,
-                              block_r, block_v, interpret)
+                              block_r, block_v, interpret, eps,
+                              logits2.shape[1])
 
     dx = _shard_map(
         body, mesh=mesh, in_specs=(xspec, lspec, cspec, cspec, cspec),
@@ -441,12 +485,12 @@ def xent_fusable(logits, label, soft) -> bool:
     return True
 
 
-def softmax_xent_op(logits, label, soft, ignore):
+def softmax_xent_op(logits, label, soft, ignore, eps=0.0):
     """The ``softmax_with_cross_entropy`` op lowered through the streaming
     kernels.  The Softmax output slot is reconstructed lazily from the
     logsumexp (``exp(logits - lse)``) so it costs nothing when the program
     never reads it (the common training graph fetches only Loss; XLA DCEs
-    the reconstruction)."""
+    the reconstruction).  ``eps`` is the op's ``smooth_epsilon``."""
     in_dtype = logits.dtype
     v = logits.shape[-1]
     lead = tuple(logits.shape[:-1])
@@ -460,10 +504,13 @@ def softmax_xent_op(logits, label, soft, ignore):
         lab2 = li.astype(jnp.int32).reshape(-1, 1)
     mesh = _active_mesh()
     if mesh is not None:
-        loss2, lse2 = softmax_xent_sharded(x2, lab2, mesh, soft, ignore)
+        loss2, lse2 = softmax_xent_sharded(x2, lab2, mesh, soft, ignore,
+                                           smooth_epsilon=eps)
     else:
-        loss2, lse2 = softmax_xent(x2, lab2, soft, ignore)
-    _note("softmax_xent")
+        loss2, lse2 = softmax_xent(x2, lab2, soft, ignore,
+                                   smooth_epsilon=eps)
+    _note("softmax_xent",
+          target="soft" if soft else "smoothed" if eps else "hard")
     loss = loss2.reshape(lead + (1,))
     lse = lse2.reshape(lead + (1,))
     sm = jnp.exp(logits.astype(jnp.float32) - lse).astype(in_dtype)

@@ -275,10 +275,16 @@ def test_lowered_transformer_step_has_no_64_bit_mask():
     """The tiny Transformer's train step with dropout 0.1 (18 dropout ops).
     What is left of 64-bit types, for the next reader of ROADMAP D13 (the
     parent: i64 125, f64 114, ui64 95 = 334): the int64 ids and labels and
-    the scalars around them (125, of which 9 ``[4,32]`` and 5 ``[4,32,1]``;
-    the rest scalars, threefry's loop counters on the CPU among them), the
-    key split's 11 ``ui64``, and 8 scalar ``f64``: the NaN that
-    ``jnp.var`` (layer_norm) writes as a Python float, folded by XLA."""
+    the scalars around them (163, of which 13 ``[4,32]``, 22 ``[4,32,1]``
+    and 9 ``[4,32,1,1]``; the rest scalars, threefry's loop counters on
+    the CPU among them), the key split's 11 ``ui64``, and 8 scalar
+    ``f64``: the NaN that ``jnp.var`` (layer_norm) writes as a Python
+    float, folded by XLA.  125 of ``i64`` until PR 49: the smoothed loss
+    now reads the int64 labels itself, and on this CPU path XLA's twin
+    picks the label's logit with ``take_along_axis``, which keeps its
+    index arithmetic in 64 bits under the package's x64 mode (+38, as in
+    every decoder's step here; on the chip the kernel takes the int32
+    column and none of them exists)."""
     from paddle_tpu.models import transformer
 
     cfg = transformer.tiny_config()
@@ -298,4 +304,4 @@ def test_lowered_transformer_step_has_no_64_bit_mask():
     exe.run(fluid.default_startup_program())
     found = _wide_types(exe.lower_step(prog, feed, [loss]).as_text())
     _assert_no_wide_mask(found)
-    assert _by_type(found) == {"f64": 8, "i64": 125, "ui64": 11}, found
+    assert _by_type(found) == {"f64": 8, "i64": 163, "ui64": 11}, found

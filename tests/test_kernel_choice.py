@@ -81,6 +81,25 @@ def xent():
             "y": np.zeros((8, 1), "int64")}, [loss]
 
 
+def xent_smoothed():
+    """The reference's way of writing a label-smoothed loss: the layer
+    hands the op the labels and ``smooth_epsilon``."""
+    feed, _ = xent()
+    block = fluid.default_main_program().global_block()
+    smooth = layers.label_smooth(layers.one_hot(block.var("y"), 64),
+                                 epsilon=0.1)
+    return feed, [layers.softmax_with_cross_entropy(block.var("x"), smooth,
+                                                    soft_label=True)]
+
+
+def xent_soft():
+    x = layers.data(name="x", shape=[64], dtype="float32")
+    y = layers.data(name="y", shape=[64], dtype="float32")
+    loss = layers.softmax_with_cross_entropy(x, y, soft_label=True)
+    return {"x": np.ones((8, 64), "float32"),
+            "y": np.full((8, 64), 1 / 64, "float32")}, [loss]
+
+
 def _trained(optimizer):
     x = layers.data(name="x", shape=[128], dtype="float32")
     loss = layers.mean(layers.fc(x, 128, bias_attr=False))
@@ -125,7 +144,13 @@ FAMILIES = {
         'ops.sparse_attention.calls{path="pallas"',
         'ops.sparse_attention.calls{path="xla"'),
     "xent": (xent, "fused", "softmax_with_cross_entropy/pallas_call",
-             "ops.fused.softmax_xent", None),
+             'ops.fused.softmax_xent{target="hard"', None),
+    "xent_smoothed": (xent_smoothed, "fused",
+                      "softmax_with_cross_entropy/pallas_call",
+                      'ops.fused.softmax_xent{target="smoothed"', None),
+    "xent_soft": (xent_soft, "fused",
+                  "softmax_with_cross_entropy/pallas_call",
+                  'ops.fused.softmax_xent{target="soft"', None),
     "adam": (adam, "fused", "adam/~optimizer/pallas_call", "ops.fused.adam",
              None),
     "momentum": (momentum, "fused", "momentum/~optimizer/pallas_call",

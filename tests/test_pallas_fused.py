@@ -148,6 +148,90 @@ def test_xent_blocks_hanging_over_the_edge(soft):
                                rtol=1e-6, atol=1e-6)
 
 
+def _smoothed(lab, v, eps):
+    """The distribution the reference's programs write down:
+    ``label_smooth(one_hot(lab))`` in float32."""
+    return ((1.0 - eps) * jax.nn.one_hot(lab[:, 0], v, dtype=jnp.float32)
+            + eps / v)
+
+
+def _ref_smoothed(x, lab, eps, ignore=-100):
+    y = _smoothed(lab, x.shape[1], eps)
+    loss = -jnp.sum(y * jax.nn.log_softmax(x.astype(jnp.float32), -1), -1,
+                    keepdims=True)
+    if ignore >= 0:
+        loss = jnp.where(lab == ignore, 0.0, loss)
+    return loss
+
+
+@pytest.mark.parametrize("ignore", [-100, 7])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_xent_smoothed_hard_labels(dtype, ignore):
+    """``smooth_epsilon`` over the int32 label column gives what the soft
+    kernel gives when fed the dense ``(1 - eps) * onehot + eps / V``, and
+    what plain float32 jax.numpy gives: loss and dX, at 1,000 classes in
+    blocks of 512 (the last block hangs over the edge, and what is read
+    there must not reach the row sum either), 300 rows in blocks of 256."""
+    rng = np.random.RandomState(21)
+    eps, (r, v) = 0.1, (300, 1000)
+    x = jnp.asarray(3.0 * rng.normal(size=(r, v)), jnp.float32).astype(dtype)
+    lab = jnp.asarray(rng.randint(0, v, size=(r, 1)).astype(np.int32))
+    lab = lab.at[5, 0].set(7)
+
+    def fused(a):
+        return pf.softmax_xent(a, lab, False, ignore, 256, 512, None, eps)[0]
+
+    def dense(a):
+        loss = pf.softmax_xent(a, _smoothed(lab, v, eps), True, -100, 256,
+                               512)[0]
+        # a soft label knows no ignore_index
+        return jnp.where(lab == ignore, 0.0, loss) if ignore >= 0 else loss
+
+    def ref(a):
+        return _ref_smoothed(a, lab, eps, ignore)
+
+    tol = dict(rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(fused(x)), np.asarray(dense(x)),
+                               **tol)
+    np.testing.assert_allclose(np.asarray(fused(x)), np.asarray(ref(x)),
+                               **tol)
+    w = jnp.asarray(rng.uniform(0.5, 1.5, size=(r, 1)).astype(np.float32))
+    g, gd, gr = (jax.grad(lambda a, f=f: jnp.sum(w * f(a)))(x)
+                 for f in (fused, dense, ref))
+    assert g.dtype == x.dtype
+    if ignore >= 0:
+        assert float(fused(x)[5, 0]) == 0.0
+        assert float(jnp.abs(g[5].astype(jnp.float32)).max()) == 0.0
+    # bf16 dX: both kernels round the same float32 value; plain jnp's dX
+    # comes back through the cast of x and rounds the same way
+    gtol = (dict(rtol=1e-6, atol=1e-6) if dtype == "float32"
+            else dict(rtol=1e-2, atol=1e-6))
+    for other in (gd, gr):
+        np.testing.assert_allclose(np.asarray(g.astype(jnp.float32)),
+                                   np.asarray(other.astype(jnp.float32)),
+                                   **gtol)
+
+
+def test_xent_without_smoothing_is_the_hard_call_it_was():
+    """``smooth_epsilon == 0`` adds nothing to the hard-label call: the
+    same jaxpr as a call that never names it (three columns out of the
+    forward kernel, no row sum, the one-hot target backward), which is what
+    the six decoder cells lower.  ``eps > 0`` is another program."""
+    rng = np.random.RandomState(22)
+    x = jnp.asarray(rng.normal(size=(300, 1000)).astype(np.float32))
+    lab = jnp.asarray(rng.randint(0, 1000, size=(300, 1)).astype(np.int32))
+
+    def jaxpr(*eps):
+        return str(jax.make_jaxpr(jax.value_and_grad(lambda a: jnp.sum(
+            pf.softmax_xent(a, lab, False, 3, 256, 512, None, *eps)[0])))(x))
+
+    plain = jaxpr()
+    assert jaxpr(0.0) == plain
+    assert jaxpr(0.1) != plain
+    assert "reduce_sum" in plain          # the picked logit's row reduction
+    assert jaxpr(0.1).count("reduce_sum") > plain.count("reduce_sum")
+
+
 def test_xent_bf16_logits():
     """bf16 logits: fp32 accumulation inside the kernel — operand-rounding
     tolerance only (matches the unfused loss-boundary fp32 cast)."""
@@ -299,7 +383,7 @@ def test_fused_training_matches_unfused(monkeypatch):
         np.testing.assert_allclose(params["1"][k], v, rtol=1e-6,
                                    atol=1e-6, err_msg=k)
     c = fluid.profiler.counters()
-    assert c.get("ops.fused.softmax_xent", 0) > 0
+    assert c.get('ops.fused.softmax_xent{target="hard"}', 0) > 0
     assert c.get("ops.fused.adam", 0) > 0
 
 
@@ -386,7 +470,7 @@ def test_guarded_fp16_scaled_window_fused_matches_unfused(monkeypatch):
         np.testing.assert_allclose(params["1"][k], v, rtol=1e-5,
                                    atol=1e-6, err_msg=k)
     c = fluid.profiler.counters()
-    assert c.get("ops.fused.softmax_xent", 0) > 0
+    assert c.get('ops.fused.softmax_xent{target="hard"}', 0) > 0
     assert c.get("ops.fused.momentum", 0) > 0
 
 
@@ -441,6 +525,43 @@ def test_xent_sharded_matches_single_device():
         lambda x: pf.softmax_xent_sharded(x, y, mesh, True))(x)
     ref_s = -jnp.sum(y * jax.nn.log_softmax(x, -1), -1, keepdims=True)
     np.testing.assert_allclose(np.asarray(loss_s), np.asarray(ref_s),
+                               rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("spec", ["dp2,tp2", "tp4"])
+def test_xent_sharded_smoothing_spreads_over_the_global_width(spec):
+    """Smoothed hard labels under a mesh: ``eps / V`` is over the GLOBAL
+    width although each shard's kernel sees ``V / tp`` columns, and the row
+    sum of the logits is exchanged like the picked logit.  Loss and dX
+    equal the unsharded kernel's and plain jax.numpy's."""
+    from paddle_tpu.parallel import mesh_from_spec
+
+    mesh = mesh_from_spec(spec)
+    rng = np.random.RandomState(9)
+    eps = 0.1
+    x = jnp.asarray(3.0 * rng.normal(size=(8, 64)).astype(np.float32))
+    lab = jnp.asarray(rng.randint(0, 64, size=(8, 1)).astype(np.int32))
+    lab = lab.at[1, 0].set(5)
+
+    def sharded(a):
+        return pf.softmax_xent_sharded(a, lab, mesh, False, 5,
+                                       smooth_epsilon=eps)[0]
+
+    def single(a):
+        return pf.softmax_xent(a, lab, False, 5, smooth_epsilon=eps)[0]
+
+    ref = _ref_smoothed(x, lab, eps, 5)
+    loss = jax.jit(sharded)(x)
+    np.testing.assert_allclose(np.asarray(loss), np.asarray(single(x)),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(loss), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+    g = jax.jit(jax.grad(lambda a: jnp.sum(sharded(a))))(x)
+    gs = jax.grad(lambda a: jnp.sum(single(a)))(x)
+    gr = jax.grad(lambda a: jnp.sum(_ref_smoothed(a, lab, eps, 5)))(x)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(gs),
+                               rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(gr),
                                rtol=1e-6, atol=1e-6)
 
 
@@ -532,7 +653,8 @@ def test_sharded_window_transformer_fused_acceptance(monkeypatch):
                   if s is not None and "tp" in tuple(s)]
     assert tp_sharded
     c = fluid.profiler.counters()
-    assert c.get('ops.fused.softmax_xent{mesh="dp2xtp2"}', 0) > 0
+    assert c.get(
+        'ops.fused.softmax_xent{mesh="dp2xtp2",target="hard"}', 0) > 0
     assert c.get('ops.fused.adam{mesh="dp2xtp2"}', 0) > 0
 
 

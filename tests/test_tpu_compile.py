@@ -84,19 +84,22 @@ def _flash_bwd(causal, bias, t=T):
     return bwd, shapes
 
 
-def _xent(soft):
+def _xent(soft, eps=0.0):
     def fwd(x, lab):
         return pallas_fused.softmax_xent(x, lab, soft, -100, 256, 512,
-                                         False)
+                                         False, eps)
 
-    # the AMP step hands the kernel bf16 logits and fp32 smoothed labels;
-    # the hard-label cases keep fp32 logits (a run without AMP)
-    return fwd, ([((R, V), BF16), ((R, V), F32)] if soft
-                 else [((R, V), F32), ((R, 1), I32)])
+    # the AMP step hands the kernel bf16 logits and its int32 labels with
+    # ``smooth_epsilon`` (fp32 smoothed labels where a program keeps the
+    # distribution); the hard-label cases keep fp32 logits (a run without
+    # AMP)
+    if soft:
+        return fwd, [((R, V), BF16), ((R, V), F32)]
+    return fwd, [((R, V), BF16 if eps else F32), ((R, 1), I32)]
 
 
-def _xent_bwd(soft):
-    fwd, shapes = _xent(soft)
+def _xent_bwd(soft, eps=0.0):
+    fwd, shapes = _xent(soft, eps)
     return (lambda x, lab: jax.grad(lambda a: fwd(a, lab)[0].sum())(x),
             shapes)
 
@@ -248,7 +251,9 @@ CASES = {
     "flash_bwd_causal_two_tiles": (lambda: _flash_bwd(True, False, 512), 3),
     "flash_bwd_key_bias_two_tiles": (lambda: _flash_bwd(False, True, 512),
                                      3),
-    "xent_fwd_soft": (lambda: _xent(True), 1),    # label smoothing: the step's
+    "xent_fwd_smoothed": (lambda: _xent(False, 0.1), 1),   # the step's
+    "xent_bwd_smoothed": (lambda: _xent_bwd(False, 0.1), 2),
+    "xent_fwd_soft": (lambda: _xent(True), 1),    # a distribution kept
     "xent_bwd_soft": (lambda: _xent_bwd(True), 2),
     "xent_fwd_hard": (lambda: _xent(False), 1),
     "xent_bwd_hard": (lambda: _xent_bwd(False), 2),
@@ -637,11 +642,14 @@ def test_keep_mask_partitions_without_traffic(topo):
             assert collective not in text, (spec, collective)
 
 
-def test_sharded_xent_compiles_under_2x2_mesh(topo):
+@pytest.mark.parametrize("soft", [True, False])
+def test_sharded_xent_compiles_under_2x2_mesh(topo, soft):
     """The shard_map lowering of the fused loss head on the four-chip mesh
     ``chip_smoke.py --chips 4`` builds: rows over dp, the vocabulary over
     tp (15,000 a shard — ragged against the 512-wide block), and the
-    cross-shard logsumexp exchange the compiler has to place."""
+    cross-shard logsumexp exchange the compiler has to place.  A
+    distribution kept, and the step's own form: int32 labels smoothed by
+    ``smooth_epsilon`` over the global width."""
     import numpy as np
 
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "tp"))
@@ -649,12 +657,16 @@ def test_sharded_xent_compiles_under_2x2_mesh(topo):
     def fn(x, lab):
         def loss(a):
             return pallas_fused.softmax_xent_sharded(
-                a, lab, mesh, True, -100, 256, 512, False)[0].sum()
+                a, lab, mesh, soft, -100, 256, 512, False,
+                0.0 if soft else 0.1)[0].sum()
 
         return jax.value_and_grad(loss)(x)
 
     spec = NamedSharding(mesh, P("dp", "tp"))
     args = [jax.ShapeDtypeStruct((R, V), F32, sharding=spec)] * 2
+    if not soft:
+        args[1] = jax.ShapeDtypeStruct(
+            (R, 1), I32, sharding=NamedSharding(mesh, P("dp", None)))
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") >= 2
     assert "all-reduce" in text

@@ -96,7 +96,7 @@ def main() -> dict:
     def delta(name):
         return c1.get(name, 0) - c0.get(name, 0)
 
-    xent = delta("ops.fused.softmax_xent")
+    xent = delta('ops.fused.softmax_xent{target="hard"}')
     adam = delta("ops.fused.adam")
     report = {
         "ok": bool(
