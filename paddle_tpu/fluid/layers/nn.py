@@ -437,8 +437,11 @@ def softmax_with_cross_entropy(logits, label, soft_label=False,
     softmax_out = helper.create_variable_for_type_inference(logits.dtype)
     softmax_out.shape = logits.shape
     loss = helper.create_variable_for_type_inference(logits.dtype)
+    # the rows' log-sum-exp, kept for the kernels' backward
+    lse = helper.create_variable_for_type_inference("float32",
+                                                    stop_gradient=True)
     if logits.shape:
-        loss.shape = tuple(logits.shape[:-1]) + (1,)
+        loss.shape = lse.shape = tuple(logits.shape[:-1]) + (1,)
     attrs = {"soft_label": soft_label, "ignore_index": ignore_index}
     smoothing = soft_label and _uniform_smoothing(
         helper.main_program.current_block(), logits, label)
@@ -451,7 +454,8 @@ def softmax_with_cross_entropy(logits, label, soft_label=False,
                  "smooth_epsilon": eps}
     helper.append_op(type="softmax_with_cross_entropy",
                      inputs={"Logits": [logits], "Label": [label]},
-                     outputs={"Softmax": [softmax_out], "Loss": [loss]},
+                     outputs={"Softmax": [softmax_out], "Loss": [loss],
+                              "Lse": [lse]},
                      attrs=attrs)
     if return_softmax:
         return loss, softmax_out

@@ -167,7 +167,8 @@ def infer_softmax_xent(op, ins):
     if logits is None:
         return None
     loss = tuple(logits[0][:-1]) + (1,)
-    return {"Softmax": [logits], "Loss": [(loss, logits[1])]}
+    return {"Softmax": [logits], "Loss": [(loss, logits[1])],
+            "Lse": [(loss, "float32")]}
 
 
 @register_infer("mean")

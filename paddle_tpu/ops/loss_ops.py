@@ -8,7 +8,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .registry import register_op
+from .registry import register_grad, register_op
 
 
 def _hard_xent(probs, label, ignore_index=-100):
@@ -36,19 +36,36 @@ def cross_entropy(ctx):
     return {"Y": _hard_xent(x, label, ctx.attr("ignore_index", -100))}
 
 
-@register_op("softmax_with_cross_entropy", no_grad_inputs=("Label",))
-def softmax_with_cross_entropy(ctx):
-    logits = ctx.input("Logits")
-    label = ctx.input("Label")
-    from ..fluid import amp
+def _xent_operands(ctx):
+    """(logits, label, soft, eps, kernels): ``eps`` is the uniform
+    smoothing of hard labels, ``(1 - eps) * onehot + eps / V``, from the
+    label column alone (set by layers.softmax_with_cross_entropy);
+    ``kernels`` whether this instance takes the streaming Pallas kernels.
+    The forward and the grad op both ask here, so they cannot disagree."""
     from . import kernel_choice, pallas_fused
 
+    logits, label = ctx.input("Logits"), ctx.input("Label")
     soft = ctx.attr("soft_label", False)
-    # hard labels smoothed uniformly: (1 - eps) * onehot + eps / V, from
-    # the label column alone (set by layers.softmax_with_cross_entropy)
     eps = 0.0 if soft else float(ctx.attr("smooth_epsilon", 0.0))
-    if kernel_choice.gate("fused") \
-            and pallas_fused.xent_fusable(logits, label, soft):
+    kernels = kernel_choice.gate("fused") \
+        and pallas_fused.xent_fusable(logits, label, soft)
+    return logits, label, soft, eps, kernels
+
+
+@register_op("softmax_with_cross_entropy", no_grad_inputs=("Label",))
+def softmax_with_cross_entropy(ctx):
+    """Loss ``[..., 1]`` of the softmax of Logits ``[..., V]`` against
+    Label: an integer column (``ignore_index``; smoothed uniformly by the
+    attr ``smooth_epsilon``) or, with ``soft_label``, a distribution.
+    Softmax is the probabilities.  Lse (float32, ``[..., 1]``) is the rows'
+    log-sum-exp: the kernels' own (``m + log(l)``) on the Pallas path, the
+    true ``logsumexp`` on the XLA path, where nothing reads it and XLA
+    drops it.  It is there for the grad op (below)."""
+    from ..fluid import amp
+    from . import pallas_fused
+
+    logits, label, soft, eps, kernels = _xent_operands(ctx)
+    if kernels:
         # streaming Pallas lowering: the [batch, vocab] probability matrix
         # never materializes in HBM; backward recomputes P per tile from
         # the saved logsumexp (ops/pallas_fused.py)
@@ -60,6 +77,7 @@ def softmax_with_cross_entropy(ctx):
         logits = logits.astype(jnp.float32)  # fp32 at the loss boundary
     sm = jax.nn.softmax(logits, axis=-1).astype(in_dtype)
     logp = jax.nn.log_softmax(logits, axis=-1)
+    lse = jax.nn.logsumexp(logits, axis=-1, keepdims=True)
     if soft:
         loss = -jnp.sum(label * logp, -1, keepdims=True)
     else:
@@ -74,7 +92,34 @@ def softmax_with_cross_entropy(ctx):
         ignore = ctx.attr("ignore_index", -100)
         if ignore >= 0:
             loss = jnp.where((li == ignore)[..., None], 0.0, loss)
-    return {"Softmax": sm, "Loss": loss}
+    return {"Softmax": sm, "Loss": loss, "Lse": lse.astype(jnp.float32)}
+
+
+@register_grad("softmax_with_cross_entropy")
+def softmax_with_cross_entropy_grad(ctx):
+    """The backward kernel alone, from the forward's own Lse: the generic
+    vjp would trace, and the chip would run, the whole forward kernel a
+    second time to get those ``[..., 1]`` numbers back.  Taken where the
+    forward took the kernels, the label is a column (hard or smoothed), the
+    op has an Lse output and nothing differentiates Softmax.  Anywhere else
+    the generic vjp: the XLA path, a soft label (its residual also needs
+    ``sum(y)``), a program built or saved without the Lse slot.  Counted as
+    ``ops.softmax_xent.grad_calls{path="from_lse"|"generic"}``."""
+    from . import pallas_fused, registry
+    from .decoder_ops import _count
+
+    logits, label, soft, eps, kernels = _xent_operands(ctx)
+    lse = ctx.input("Lse")
+    from_lse = kernels and not soft and lse is not None \
+        and ctx.input("Softmax@GRAD") is None
+    _count("ops.softmax_xent.grad_calls",
+           path="from_lse" if from_lse else "generic")
+    if not from_lse:
+        return registry.run_grad_generic(
+            registry.get_op_def("softmax_with_cross_entropy"), ctx)
+    return {"Logits@GRAD": pallas_fused.softmax_xent_grad(
+        logits, label, lse, ctx.input("Loss@GRAD"),
+        ctx.attr("ignore_index", -100), eps)}
 
 
 @register_op("sigmoid_cross_entropy_with_logits", no_grad_inputs=("Label",))

@@ -485,23 +485,30 @@ def xent_fusable(logits, label, soft) -> bool:
     return True
 
 
+def _as_rows(logits, label, soft):
+    """``(x2 [R, V], lab2)`` as the kernels take them: a soft label
+    ``[R, V]``, a hard one an int32 column ``[R, 1]``."""
+    v = logits.shape[-1]
+    if soft:
+        return logits.reshape(-1, v), label.reshape(-1, v)
+    li = label
+    if li.ndim == logits.ndim and li.shape[-1] == 1:
+        li = li.reshape(li.shape[:-1])
+    return logits.reshape(-1, v), li.astype(jnp.int32).reshape(-1, 1)
+
+
 def softmax_xent_op(logits, label, soft, ignore, eps=0.0):
     """The ``softmax_with_cross_entropy`` op lowered through the streaming
     kernels.  The Softmax output slot is reconstructed lazily from the
     logsumexp (``exp(logits - lse)``) so it costs nothing when the program
     never reads it (the common training graph fetches only Loss; XLA DCEs
-    the reconstruction).  ``eps`` is the op's ``smooth_epsilon``."""
+    the reconstruction).  ``Lse`` is that logsumexp, float32
+    ``logits.shape[:-1] + (1,)``: what :func:`softmax_xent_grad` takes, so
+    that the grad op need not run the forward kernel again.  ``eps`` is the
+    op's ``smooth_epsilon``."""
     in_dtype = logits.dtype
-    v = logits.shape[-1]
     lead = tuple(logits.shape[:-1])
-    x2 = logits.reshape(-1, v)
-    if soft:
-        lab2 = label.reshape(-1, v)
-    else:
-        li = label
-        if li.ndim == logits.ndim and li.shape[-1] == 1:
-            li = li.reshape(li.shape[:-1])
-        lab2 = li.astype(jnp.int32).reshape(-1, 1)
+    x2, lab2 = _as_rows(logits, label, soft)
     mesh = _active_mesh()
     if mesh is not None:
         loss2, lse2 = softmax_xent_sharded(x2, lab2, mesh, soft, ignore,
@@ -514,7 +521,28 @@ def softmax_xent_op(logits, label, soft, ignore, eps=0.0):
     loss = loss2.reshape(lead + (1,))
     lse = lse2.reshape(lead + (1,))
     sm = jnp.exp(logits.astype(jnp.float32) - lse).astype(in_dtype)
-    return {"Softmax": sm, "Loss": loss}
+    return {"Softmax": sm, "Loss": loss, "Lse": lse}
+
+
+def softmax_xent_grad(logits, label, lse, dloss, ignore, eps=0.0):
+    """``Logits@GRAD`` of :func:`softmax_xent_op` for hard labels (smoothed
+    by ``eps`` or not) from the forward's own ``lse``: the backward kernel
+    alone, through the very function that is the kernels' ``custom_vjp``
+    backward (sharded under an active mesh), handed the residual the
+    forward would have kept.  A soft label's residual also holds ``sum(y)``,
+    which no output of the op carries: that one keeps the generic grad."""
+    x2, lab2 = _as_rows(logits, label, False)
+    res = (x2, lab2, lse.reshape(-1, 1), None)
+    dloss2 = dloss.reshape(-1, 1)
+    ct = (dloss2, jnp.zeros_like(dloss2))  # nobody differentiates Lse
+    blocks = (DEFAULT_BLOCK_R, DEFAULT_BLOCK_V, None)
+    mesh = _active_mesh()
+    if mesh is not None:
+        dx, _ = _xent_sharded_bwd_vjp(mesh, False, ignore, *blocks, eps,
+                                      res, ct)
+    else:
+        dx, _ = _xent_bwd_vjp(False, ignore, *blocks, eps, res, ct)
+    return dx.reshape(logits.shape)
 
 
 # ---------------------------------------------------------------------------

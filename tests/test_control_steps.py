@@ -11,9 +11,10 @@ equal between two trees that lower the same program; .claude/skills/verify)
 is hashed.
 
 The digests are of the tree PR 39 started from (15bda75) and were equal on
-PR 39's; the Transformer's is PR 49's, which meant to change that step (its
-loss reads the labels and ``smooth_epsilon``, not the smoothed
-distribution).  A PR that MEANS to change one of these steps (an op's lowering, a
+PR 39's; the Transformer's is PR 50's, which meant to change that step (its
+loss's grad op takes the forward's ``Lse`` and holds the forward kernel no
+second time; PR 49's, ``a8d0f4d3...``, was of the loss that reads the labels
+and ``smooth_epsilon``).  A PR that MEANS to change one of these steps (an op's lowering, a
 pass, the optimizer) replaces the digest with the one this test prints and
 says so in CHANGES.md; one that does not has moved a control cell.
 """
@@ -35,7 +36,7 @@ from chipbench import plugins  # noqa: E402
 
 STEPS = {
     "transformer_base_wmt":
-        "a8d0f4d3c5e636ee9e2aa0e8231684c9ba6a35f64440e1c13cd1ceec8fdfe623",
+        "4ebf1dc18b6cb71e197b8d94b91e273c0f36cf6db38c62a08f0e67e5888cc225",
     "resnet50_imagenet":
         "b91426762a53d56bcd839804385d83ffaee7d988dd6e31e946c93b9835721a83",
 }

@@ -1353,6 +1353,59 @@ def test_every_kernel_call_traced_counts_its_column_tile(monkeypatch, path):
         for (kernel, width), tn in tiles.items()}
 
 
+@pytest.mark.parametrize("case,losses,per_grad_op", [
+    ("trunk", 1, ["_xent_bwd_kernel"]),
+    ("trunk_and_mtp_head", 2, ["_xent_bwd_kernel"]),
+    # a program from before the op had the slot: the generic grad, which
+    # runs the forward kernel again for the log-sum-exp it had thrown away
+    ("no_lse_slot", 1, ["_xent_partial_kernel", "_xent_bwd_kernel"]),
+])
+def test_the_training_step_runs_the_loss_forward_once(monkeypatch, case,
+                                                      losses, per_grad_op):
+    """The tiny decoder's whole training step, lowered with the loss
+    kernels on: each loss op holds ``_xent_partial_kernel`` once, each grad
+    op ``_xent_bwd_kernel`` once and NO forward kernel, its log-sum-exp
+    being the forward's ``Lse`` (two of each with the multi-token
+    module's head)."""
+    from lowered_kernels import loss_kernel_calls
+    from paddle_tpu.models import decoder_lm
+
+    monkeypatch.setenv("PADDLE_TPU_FUSED", "1")
+    cfg = decoder_lm.tiny_config()
+    if case == "trunk_and_mtp_head":
+        cfg.mtp_depth, cfg.mtp_weight = 1, 0.3
+    seq = 16
+    tokens, labels, loss = decoder_lm.build(cfg, seq_len=seq)
+    main = fluid.default_main_program()
+    loss_ops = [op for op in main.global_block().ops
+                if op.type.startswith("softmax_with_cross_entropy")]
+    assert [op.type for op in loss_ops] == \
+        ["softmax_with_cross_entropy"] * losses + \
+        ["softmax_with_cross_entropy_grad"] * losses
+    for op in loss_ops:
+        slots = op.inputs if op.type.endswith("_grad") else op.outputs
+        lse = main.global_block().var(slots["Lse"][0])
+        assert (tuple(lse.shape[1:]), lse.dtype, lse.stop_gradient) == \
+            ((seq, 1), "float32", True)
+        if case == "no_lse_slot":
+            del slots["Lse"]
+    exe = fluid.Executor(fluid.TPUPlace())
+    exe.run(fluid.default_startup_program())
+    ids = np.random.RandomState(0).randint(
+        0, cfg.vocab_size, size=(1, seq + 2)).astype(np.int64)
+    feed = {"tokens": ids[:, :seq], "labels": ids[:, 1:seq + 1, None]}
+    if cfg.mtp_depth:
+        feed["labels2"] = ids[:, 2:, None]
+    calls = loss_kernel_calls(exe.lower_step(main, feed, [loss]))
+    assert calls == \
+        [("softmax_with_cross_entropy", "_xent_partial_kernel")] * losses \
+        + [("softmax_with_cross_entropy_grad", k) for k in per_grad_op] \
+        * losses
+    path = "generic" if case == "no_lse_slot" else "from_lse"
+    assert counters("ops.softmax_xent.grad_calls") == {
+        f'ops.softmax_xent.grad_calls{{path="{path}"}}': losses}
+
+
 def test_the_training_step_scatters_no_row_under_the_expert_layer():
     """The tiny decoder's whole training step, lowered: under the expert
     layer's two ops (``moe_experts`` and ``moe_experts_grad`` in the
@@ -2063,18 +2116,28 @@ def program_digest():
     below were taken while ``sparse_attention`` and its grad op still
     carried the per-op request ``flash: -1`` (gone in PR 47; nothing read
     any other value): it is put back for the hash, so that they stay the
-    digests of the commits they were taken on."""
+    digests of the commits they were taken on.  Likewise the loss op had
+    no ``Lse`` output, and its grad op no such input, until PR 50: the slot
+    is asserted here and left out of the hash."""
     import hashlib
 
     def attrs(op):
         gone = {"flash": -1} if op.type.startswith("sparse_attention") else {}
         return sorted((k, repr(v)) for k, v in {**op.attrs, **gone}.items())
 
+    def slots(op, named):
+        if op.type == "softmax_with_cross_entropy" + \
+                ("_grad" if named is op.inputs else ""):
+            assert len(named["Lse"]) == 1
+            assert named.get("Lse@GRAD", [""]) == [""]   # nobody's cotangent
+            named = {k: v for k, v in named.items()
+                     if k not in ("Lse", "Lse@GRAD")}
+        return sorted((k, list(v)) for k, v in named.items())
+
     ops = fluid.default_main_program().global_block().ops
     assert not any("flash" in op.attrs or "fused" in op.attrs for op in ops)
     text = "\n".join(repr((
-        op.type, sorted((k, list(v)) for k, v in op.inputs.items()),
-        sorted((k, list(v)) for k, v in op.outputs.items()), attrs(op)))
+        op.type, slots(op, op.inputs), slots(op, op.outputs), attrs(op)))
         for op in ops)
     return ops, (len(ops), hashlib.sha256(text.encode()).hexdigest())
 

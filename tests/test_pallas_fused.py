@@ -280,6 +280,178 @@ def test_xent_softmax_output_path():
 
 
 # ---------------------------------------------------------------------------
+# op-level: the grad op takes the forward's Lse (no second forward kernel)
+# ---------------------------------------------------------------------------
+
+
+def _xent_grad_counts():
+    name = "ops.softmax_xent.grad_calls"
+    return {k[len(name):]: v for k, v in fluid.profiler.counters().items()
+            if k.startswith(name)}
+
+
+def _loss_op_ctx(logits, label, attrs, slots=("Softmax", "Loss", "Lse")):
+    """The grad op's context as the executor makes it: the forward's
+    inputs, the outputs named in ``slots``, ``Loss@GRAD``, and nothing
+    where an output has no gradient."""
+    from paddle_tpu.ops.registry import ExecContext, get_op_def
+
+    fwd = get_op_def("softmax_with_cross_entropy")
+    ins = {"Logits": [logits], "Label": [label]}
+    outs = fwd.fn(ExecContext(fwd.type, dict(ins), {}, attrs))
+    rng = np.random.RandomState(17)
+    dloss = jnp.asarray(rng.normal(size=outs["Loss"].shape), jnp.float32)
+    ins.update({s: [outs[s]] for s in slots})
+    ins.update({"Loss@GRAD": [dloss], "Softmax@GRAD": [None]})
+    return fwd, ExecContext(fwd.type + "_grad", ins,
+                            {"Logits@GRAD": ["dx"]}, attrs)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case,shape,attrs", [
+    ("hard", (24, 96), {}),
+    ("smoothed", (24, 96), {"smooth_epsilon": 0.1}),
+    ("ignore_index", (24, 96), {"ignore_index": 5}),
+    ("smoothed_ignore_index", (24, 96), {"smooth_epsilon": 0.1,
+                                         "ignore_index": 5}),
+    ("ragged_last_column_block", (16, 700), {}),
+    ("ragged_smoothed", (16, 700), {"smooth_epsilon": 0.1}),
+    ("three_dims_ragged_rows", (3, 100, 40), {}),
+])
+def test_loss_grad_from_lse_is_the_generic_grad_bit_for_bit(
+        monkeypatch, case, shape, attrs, dtype):
+    """The registered grad (the backward kernel on the forward's Lse)
+    against ``run_grad_generic`` (the forward kernel again, then the same
+    backward kernel): the same bits, and it is counted as ``from_lse``."""
+    from paddle_tpu.ops import registry
+
+    monkeypatch.setenv("PADDLE_TPU_FUSED", "1")
+    rng = np.random.RandomState(len(case))
+    logits = jnp.asarray(3.0 * rng.normal(size=shape), dtype)
+    label = rng.randint(0, shape[-1], size=shape[:-1] + (1,))
+    label[..., :2, 0] = 5                       # rows that an ignore drops
+    fwd, ctx = _loss_op_ctx(logits, jnp.asarray(label, jnp.int64), attrs)
+    assert ctx.input("Lse").shape == shape[:-1] + (1,)
+    assert ctx.input("Lse").dtype == jnp.float32
+    got = fwd.grad_fn(ctx)
+    assert _xent_grad_counts() == {'{path="from_lse"}': 1}
+    want = registry.run_grad_generic(fwd, ctx)
+    assert set(got) == set(want) == {"Logits@GRAD"}
+    dx, ref = got["Logits@GRAD"], want["Logits@GRAD"][0]
+    assert dx.dtype == ref.dtype == logits.dtype and dx.shape == shape
+    assert np.abs(np.asarray(ref, np.float32)).max() > 0
+    np.testing.assert_array_equal(np.asarray(dx, np.float32),
+                                  np.asarray(ref, np.float32))
+    if "ignore_index" in attrs:
+        assert not np.asarray(dx, np.float32)[..., :2, :].any()
+
+
+def test_lse_is_the_rows_logsumexp_on_both_paths(monkeypatch):
+    """The slot holds the kernels' ``m + log(l)`` on the Pallas path and
+    the true ``logsumexp`` on the XLA path: float32 ``[..., 1]`` both."""
+    from paddle_tpu.ops.registry import ExecContext, get_op_def
+
+    rng = np.random.RandomState(8)
+    logits = jnp.asarray(rng.normal(size=(2, 6, 50)), jnp.bfloat16)
+    label = jnp.asarray(rng.randint(0, 50, size=(2, 6, 1)), jnp.int64)
+    want = jax.nn.logsumexp(logits.astype(jnp.float32), -1, keepdims=True)
+    for switch in ("0", "1"):
+        monkeypatch.setenv("PADDLE_TPU_FUSED", switch)
+        out = get_op_def("softmax_with_cross_entropy").fn(ExecContext(
+            "softmax_with_cross_entropy",
+            {"Logits": [logits], "Label": [label]}, {}, {}))
+        assert out["Lse"].shape == (2, 6, 1)
+        assert out["Lse"].dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(out["Lse"]), np.asarray(want),
+                                   rtol=1e-6)
+
+
+def test_the_loss_ops_infer_rule_and_layer_give_lse():
+    """The rule's third output and the layer's third variable: float32
+    ``logits.shape[:-1] + (1,)`` whatever the logits' type, no gradient;
+    the built program verifies with it."""
+    from paddle_tpu import analysis
+    from paddle_tpu.ops.registry import get_infer_rule
+
+    class Op:
+        type, attrs = "softmax_with_cross_entropy", {}
+
+        def attr(self, name, default=None):
+            return self.attrs.get(name, default)
+
+    rule = get_infer_rule("softmax_with_cross_entropy")
+    logits, label = ((4, 9, 50), "bfloat16"), ((4, 9, 1), "int64")
+    assert rule(Op(), {"Logits": [logits], "Label": [label]}) == {
+        "Softmax": [logits], "Loss": [((4, 9, 1), "bfloat16")],
+        "Lse": [((4, 9, 1), "float32")]}
+    x = fluid.layers.data(name="x", shape=[9, 50], dtype="float32")
+    y = fluid.layers.data(name="y", shape=[9, 1], dtype="int64")
+    loss = fluid.layers.softmax_with_cross_entropy(x, y)
+    op = fluid.default_main_program().global_block().ops[-1]
+    assert sorted(op.outputs) == ["Loss", "Lse", "Softmax"]
+    lse = fluid.default_main_program().global_block().var(op.output("Lse")[0])
+    assert (tuple(lse.shape), lse.dtype, lse.stop_gradient) == \
+        (tuple(loss.shape), "float32", True)
+    report = analysis.verify_program(fluid.default_main_program(),
+                                     fetch_list=[loss, lse])
+    assert not report.errors, report.format()
+
+
+def _trained_loss(kind):
+    """A loss over fed logits with its backward; returns (feed, the
+    gradient the mean loss has in the logits)."""
+    rows, width = 12, 40
+    rng = np.random.RandomState(4)
+    x = fluid.layers.data(name="x", shape=[width], dtype="float32")
+    x.stop_gradient = False
+    ids = rng.randint(0, width, size=(rows, 1)).astype("int64")
+    feed = {"x": rng.normal(size=(rows, width)).astype("float32")}
+    target = np.zeros((rows, width))
+    np.put_along_axis(target, ids, 1.0, axis=-1)
+    if kind == "soft_label":
+        target = 0.9 * target + 0.1 / width
+        y = fluid.layers.data(name="y", shape=[width], dtype="float32")
+        feed["y"] = target.astype("float32")
+    else:
+        y = fluid.layers.data(name="y", shape=[1], dtype="int64")
+        feed["y"] = ids
+    loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
+        x, y, soft_label=kind == "soft_label"))
+    fluid.backward.append_backward(loss)
+    if kind == "no_lse_slot":
+        # a program built, or saved, before the op had the slot
+        for op in fluid.default_main_program().global_block().ops:
+            if op.type == "softmax_with_cross_entropy":
+                del op.outputs["Lse"]
+            elif op.type == "softmax_with_cross_entropy_grad":
+                del op.inputs["Lse"]
+    p = np.exp(feed["x"] - feed["x"].max(-1, keepdims=True))
+    return feed, (p / p.sum(-1, keepdims=True) - target) / rows
+
+
+@pytest.mark.parametrize("kind,switch,path,kernel_traces", [
+    ("hard", "1", "from_lse", 1),
+    ("soft_label", "1", "generic", 2),     # its residual also needs sum(y)
+    ("closed_gate", "0", "generic", 0),    # the XLA twin and its vjp
+    ("no_lse_slot", "1", "generic", 2),
+])
+def test_loss_grad_op_falls_back_where_it_cannot_take_lse(
+        monkeypatch, kind, switch, path, kernel_traces):
+    """Through the executor: every kind trains with the gradient it always
+    had; only hard labels under an open gate with an Lse to read take the
+    backward kernel alone, and the counters say which."""
+    monkeypatch.setenv("PADDLE_TPU_FUSED", switch)
+    feed, want = _trained_loss(kind)
+    exe = fluid.Executor(fluid.CPUPlace())
+    got, = exe.run(feed=feed, fetch_list=["x@GRAD"])
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=1e-8)
+    assert _xent_grad_counts() == {f'{{path="{path}"}}': 1}
+    traced = sum(v for k, v in fluid.profiler.counters().items()
+                 if k.startswith("ops.fused.softmax_xent"))
+    assert traced == kernel_traces
+
+
+# ---------------------------------------------------------------------------
 # kernel-level: fused optimizer sweeps
 # ---------------------------------------------------------------------------
 
@@ -563,6 +735,33 @@ def test_xent_sharded_smoothing_spreads_over_the_global_width(spec):
                                rtol=1e-6, atol=1e-7)
     np.testing.assert_allclose(np.asarray(g), np.asarray(gr),
                                rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("spec", ["dp2,tp2", "tp4"])
+@pytest.mark.parametrize("eps", [0.0, 0.1])
+def test_loss_grad_from_lse_under_a_mesh(monkeypatch, spec, eps):
+    """Under an active mesh the grad op takes the same way out through the
+    sharded backward: the generic grad's bits, the unsharded gradient."""
+    from paddle_tpu.ops import registry
+    from paddle_tpu.parallel import mesh_from_spec, spmd
+
+    monkeypatch.setenv("PADDLE_TPU_FUSED", "1")
+    rng = np.random.RandomState(12)
+    logits = jnp.asarray(3.0 * rng.normal(size=(8, 64)), jnp.float32)
+    label = jnp.asarray(rng.randint(0, 64, size=(8, 1)), jnp.int64)
+    attrs = {"smooth_epsilon": eps, "ignore_index": int(label[1, 0])}
+    fwd, ctx = _loss_op_ctx(logits, label, attrs)
+    single = fwd.grad_fn(ctx)["Logits@GRAD"]
+    with spmd.mesh_scope(mesh_from_spec(spec)):
+        fwd, ctx = _loss_op_ctx(logits, label, attrs)
+        got = jax.jit(lambda: fwd.grad_fn(ctx)["Logits@GRAD"])()
+        want = jax.jit(
+            lambda: registry.run_grad_generic(fwd, ctx)["Logits@GRAD"][0])()
+    assert _xent_grad_counts() == {'{path="from_lse"}': 2}
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(single),
+                               rtol=1e-6, atol=1e-7)
+    assert not np.asarray(got)[1].any() and np.asarray(got)[0].any()
 
 
 def test_flash_sharded_matches_full_attention():

@@ -501,6 +501,54 @@ def test_a_routed_layer_and_its_backward_lower_eleven_calls(
         'custom_call_target="tpu_custom_call"') == 11
 
 
+@pytest.mark.parametrize("cell,rows,width,dtype,eps", [
+    ("transformer", R, V, BF16, 0.1),        # 64 x 256 tokens, smoothed
+    ("keye", 8192, 18992, BF16, 0.0),        # a ragged last column block
+    ("lfm2", 8192, 16384, BF16, 0.0),
+])
+def test_the_loss_op_and_its_grad_op_hold_one_kernel_each(
+        topo, monkeypatch, cell, rows, width, dtype, eps):
+    """``softmax_with_cross_entropy`` and its registered grad at a cell's
+    head, lowered and compiled for the described chip: the forward kernel
+    ONCE (under the generic grad the step held it twice), the backward
+    kernel once on the forward's ``Lse``, in the signatures the benchmark's
+    ``xent_fwd`` / ``xent_bwd`` families count their bytes from."""
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from chipbench import hlo
+
+    monkeypatch.setenv("PADDLE_TPU_FUSED", "1")
+    monkeypatch.setattr(kernel_choice, "interpret", lambda stated=None: False)
+    fwd = registry.get_op_def("softmax_with_cross_entropy")
+    attrs = {"smooth_epsilon": eps} if eps else {}
+
+    def head(logits, label, dloss):
+        ins = {"Logits": [logits], "Label": [label]}
+        outs = fwd.fn(registry.ExecContext(fwd.type, dict(ins), {}, attrs))
+        ins.update(Loss=[outs["Loss"]], Lse=[outs["Lse"]],
+                   **{"Loss@GRAD": [dloss]})
+        grads = fwd.grad_fn(registry.ExecContext(
+            fwd.type + "_grad", ins, {"Logits@GRAD": ["dx"]}, attrs))
+        return outs["Loss"], grads["Logits@GRAD"]
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, t, sharding=chip) for s, t in
+            (((rows, width), dtype), ((rows, 1), jnp.int64),
+             ((rows, 1), F32))]
+    lowered = jax.jit(head).lower(*args)
+    x, col = f"bf16[{rows},{width}]", f"f32[{rows},1]"
+    label = f"s32[{rows},1]"
+    assert [(c.kernel, hlo.signature(c))
+            for c in hlo.custom_calls(lowered.as_text())] == [
+        ("_xent_partial_kernel",
+         ",".join([col] * (4 if eps else 3)) + f"<-{x},{label}"),
+        ("_xent_bwd_kernel", f"{x}<-{x},{label},{col},{col},{col}")]
+    assert lowered.compile().as_text().count(
+        'custom_call_target="tpu_custom_call"') == 2
+
+
 def _momentum_op(p, g, v, lr):
     """The ``momentum`` OP as the executor calls it (gate, suitability,
     kernel or XLA formulas), not the bare kernel."""

@@ -185,20 +185,23 @@ def test_smoothed_hard_labels_equal_the_distribution(monkeypatch, fused):
 
 def test_a_lowering_counts_the_form_its_loss_took(monkeypatch):
     """``ops.fused.softmax_xent{target=...}``: one lowering of the
-    Transformer's training step reads ``smoothed`` twice (the op, and its
-    grad op tracing the forward again) and nothing else; a decoder's reads
-    ``hard``."""
+    Transformer's training step reads ``smoothed`` ONCE (the op; its grad
+    op takes the forward's Lse and traces no forward) and nothing else; a
+    decoder's reads ``hard``.  The lowered step holds the forward kernel
+    once, under the op, and the backward kernel once, under the grad op,
+    which is counted as ``from_lse``."""
+    from lowered_kernels import loss_kernel_calls
     from paddle_tpu.models import decoder_lm
 
     monkeypatch.setenv("PADDLE_TPU_FUSED", "1")
+    names = ("ops.fused.softmax_xent", "ops.softmax_xent.grad_calls")
 
     def grown_by(lower):
-        name = "ops.fused.softmax_xent"
         before = dict(fluid.profiler.counters())
-        lower()
-        return {k[len(name):]: v - before.get(k, 0)
-                for k, v in fluid.profiler.counters().items()
-                if k.startswith(name) and v != before.get(k, 0)}
+        calls = loss_kernel_calls(lower())
+        return calls, {k: v - before.get(k, 0)
+                       for k, v in fluid.profiler.counters().items()
+                       if k.startswith(names) and v != before.get(k, 0)}
 
     cfg = transformer.tiny_config()
     rng = np.random.RandomState(2)
@@ -209,7 +212,7 @@ def test_a_lowering_counts_the_form_its_loss_took(monkeypatch):
             loss = transformer.build(cfg, src_len=8, tgt_len=8)[3]
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(startup)
-        exe.lower_step(main, _feed(rng, cfg, 2, 8, 8), [loss])
+        return exe.lower_step(main, _feed(rng, cfg, 2, 8, 8), [loss])
 
     def lower_decoder():
         main, startup = fluid.Program(), fluid.Program()
@@ -218,10 +221,18 @@ def test_a_lowering_counts_the_form_its_loss_took(monkeypatch):
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(startup)
         tokens = rng.randint(0, 128, size=(2, 16)).astype("int64")
-        exe.lower_step(main, {"tokens": tokens, "labels": tokens}, [loss])
+        return exe.lower_step(main, {"tokens": tokens, "labels": tokens},
+                              [loss])
 
-    assert grown_by(lower_transformer) == {'{target="smoothed"}': 2}
-    assert grown_by(lower_decoder) == {'{target="hard"}': 2}
+    one_of_each = [
+        ("softmax_with_cross_entropy", "_xent_partial_kernel"),
+        ("softmax_with_cross_entropy_grad", "_xent_bwd_kernel")]
+    assert grown_by(lower_transformer) == (one_of_each, {
+        'ops.fused.softmax_xent{target="smoothed"}': 1,
+        'ops.softmax_xent.grad_calls{path="from_lse"}': 1})
+    assert grown_by(lower_decoder) == (one_of_each, {
+        'ops.fused.softmax_xent{target="hard"}': 1,
+        'ops.softmax_xent.grad_calls{path="from_lse"}': 1})
 
 
 def test_transformer_causal_mask():
