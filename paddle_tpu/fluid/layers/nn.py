@@ -1447,7 +1447,9 @@ def sparse_attention(q, k, v, selection=None, scale=None, window=0,
                      name=None):
     """Causal grouped-query attention, optionally over a per-query
     selection of keys (a sibling of ``ring_attention``; ops/decoder_ops.py
-    + ops/pallas_sparse_flash.py).  q: [B, Hq, T, D]; k, v: [B, Hkv, T, D]
+    + ops/pallas_sparse_flash.py).  q: [B, Hq, T, D]; k: [B, Hkv, T, D];
+    v: [B, Hkv, T, Dv], and the result [B, Hq, T, Dv] (the kernels take
+    ``Dv = D`` only, the XLA path any),
     with Hq a multiple of Hkv (query head h reads head h // (Hq / Hkv));
     ``selection``: [B, T, T] int8 from ``sparse_indexer`` or None (every
     key s <= t).  ``window``: 0, or a causal window: key s counts for query
@@ -1458,7 +1460,7 @@ def sparse_attention(q, k, v, selection=None, scale=None, window=0,
     way."""
     helper = LayerHelper("sparse_attention", **locals())
     out = helper.create_variable_for_type_inference(helper.input_dtype("q"))
-    out.shape = tuple(q.shape)
+    out.shape = tuple(q.shape[:3]) + (v.shape[3],)
     # the kernels' log-sum-exp, kept for their backward
     lse = helper.create_variable_for_type_inference("float32",
                                                     stop_gradient=True)
@@ -1618,7 +1620,9 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64, scale=None, norm_eps=0.0,
     beta: [B, T, Hv].  Returns [B, T, Hv, dv].  ``chunk``: the tokens worked
     at once between two steps of the state; ``scale`` multiplies q (None:
     ``dk ** -0.5``); ``norm_eps`` > 0 l2-norms q and k per head first.
-    Every row of the batch starts from a zero state."""
+    Every row of the batch starts from a zero state.  A ``g`` [B, T, Hv,
+    dk] decays every key channel (the state's rows) by a number of its
+    own."""
     helper = LayerHelper("gated_delta_rule", **locals())
     out = helper.create_variable_for_type_inference(helper.input_dtype("v"))
     out.shape = tuple(v.shape)

@@ -41,10 +41,13 @@ field turns on)::
         [c | kr] = a Wkva             L.rank + L.rope
         [k_nope | v] = RMSNorm(c) Wkvb      [T, H, L.nope + L.value]
         k = [k_nope | kr, the same for every head]
-        q = RMSNorm_head(q), k = RMSNorm_head(k)
+        q = RMSNorm_head(q), k = RMSNorm_head(k)      [not where L.head_norm
+                                                       is off]
         q, k = RoPE on their last L.rope columns only, pairs (2i, 2i + 1)
-               where L.interleaved, by the frequencies L.inv_freq
-        o = concat_h softmax_{s <= t}(q_h k_h * L.scale) v_h
+               where L.interleaved, by the frequencies L.inv_freq [not
+               where L.rotary is off: a layer without positions]
+        o = concat_h softmax_{s <= t}(q_h k_h * L.scale) v_h    v_h L.value
+                                      wide, which need not be head_dim
         y = (o [* sigmoid(g)]) Wo
     delta layer (mixers[published index] == "delta"; R = cfg.delta, Hk key
                  heads of dk, Hv value heads of dv):
@@ -59,6 +62,12 @@ field turns on)::
             S_t = S' + k_t u_t^T;   o_t = S_t^T q_t
             (computed R.chunk tokens at a time: ops/delta_rule.py)
         y = (RMSNorm_head(o) * SiLU(z)) Wout      one scale of dv
+        [W = cfg.delta_gates; W.decay_rank r: the decay is a VECTOR along
+            the key, [T, Hv, dk], g = -exp(A_log_h) * softplus((a Wf1) Wf2
+            + dt_bias), Wf1 [hidden, r], Wf2 [r, Hv dk], dt_bias a channel
+            and A_log a head; S' = Diag(exp(g_t)) S_{t-1}; beta = sigmoid(a Wb)]
+        [W.gate_rank r: z = (a Wg1) Wg2, Wg1 [hidden, r], and no z in the
+            projection in; W.gate "sigmoid": sigmoid(z) in SiLU(z)'s place]
     x1 = x + y                      [post_norms: x + RMSNorm(y)]
     m  = RMSNorm(x1)
     dense layer (published index < dense_layers):
@@ -96,8 +105,11 @@ Parameters are created in a fixed order and named ``tok_emb``,
 o_w,post_attn_norm}`` (a conv layer: ``l<i>_{conv_norm,conv_in_w,conv_w,
 conv_out_w,post_attn_norm}`` and none of the others; a latent layer:
 ``l<i>_{attn_norm,q_w,q_norm,kva_w,kv_norm,kvb_w,k_norm,gate_w,o_w,
-post_attn_norm}``; a delta layer: ``l<i>_{attn_norm,qkvz_w,ba_w,conv_w,
-dt_bias,a_log,delta_norm,o_w,post_attn_norm}``), then
+post_attn_norm}``, the head norms only where it has them; a delta layer:
+``l<i>_{attn_norm,qkvz_w,ba_w,conv_w,dt_bias,a_log,delta_norm,o_w,
+post_attn_norm}``, with ``qkv_w,g1_w,g2_w`` in ``qkvz_w``'s place under a
+gate rank and ``b_w`` in ``ba_w``'s, ``f1_w,f2_w`` after ``conv_w`` under a
+decay rank), then
 ``l<i>_{mlp_norm,mlp_w1,mlp_w3,mlp_w2}`` (dense)
 or ``l<i>_{moe_norm,shared_w1,shared_w3,shared_w2,shared_gate_w,router_w,w1,
 w3,w2}`` (routed; ``l<i>_route_bias`` is no parameter), ``l<i>_post_mlp_norm``,
@@ -120,6 +132,7 @@ from paddle_tpu.fluid.param_attr import ParamAttr
 
 
 MIXERS = ("attention", "conv", "latent", "delta")
+GATES = ("silu", "sigmoid")         # of a delta mixer's output
 RESIDUALS = ("sequential", "farskip")
 
 
@@ -129,7 +142,9 @@ class Latent(NamedTuple):
     rotated width of a query's and a key's head (``Config.head_dim`` is
     their sum), the value's width, and the rotary of the rotated part: its
     ``rope // 2`` frequencies (None: ``rope_theta``'s own), its pairing,
-    and the softmax scale where it is not ``head_dim ** -0.5``."""
+    and the softmax scale where it is not ``head_dim ** -0.5``.  ``rotary``
+    off: a layer without positions, both parts as they come; ``head_norm``
+    off: no per-head norm on queries and keys."""
     rank: int
     nope: int
     rope: int
@@ -137,6 +152,8 @@ class Latent(NamedTuple):
     inv_freq: Optional[Tuple[float, ...]] = None
     interleaved: bool = False
     scale: float = 0.0
+    rotary: bool = True
+    head_norm: bool = True
 
 
 class Delta(NamedTuple):
@@ -150,6 +167,19 @@ class Delta(NamedTuple):
     value_dim: int
     taps: int = 4
     chunk: int = 64
+
+
+class DeltaGates(NamedTuple):
+    """Where a ``delta`` mixer's two gates come from, and the second one's
+    form; every default is a column of the mixer's projections in.
+    ``decay_rank`` r > 0: the decay is a VECTOR along the key, made by a
+    pair of products through r columns (0: one number a value head, a
+    column of the projection ``ba``); ``gate_rank`` r > 0: the output's gate
+    comes through such a pair too (0: a chunk of the projection in);
+    ``gate``: its form, one of GATES."""
+    decay_rank: int = 0
+    gate: str = "silu"
+    gate_rank: int = 0
 
 
 class Rotary(NamedTuple):
@@ -209,7 +239,7 @@ class Config:
                  conv_taps=0, tie_head=False, latent=None,
                  residual="sequential", mtp_depth=0, mtp_weight=0.0,
                  delta=None, rotary_dims=0, shared_gate=False,
-                 global_rotary=None):
+                 global_rotary=None, delta_gates=None):
         if num_heads % num_kv_heads:
             raise ValueError(f"{num_heads} query heads do not group over "
                              f"{num_kv_heads} key-value heads")
@@ -232,15 +262,13 @@ class Config:
         if latent is not None:
             latent = Latent(*latent)
             if latent.nope + latent.rope != head_dim or latent.rope % 2 \
-                    or latent.value != head_dim \
-                    or num_kv_heads != num_heads:
+                    or latent.value < 1 or num_kv_heads != num_heads:
                 raise ValueError(
                     f"latent heads of {latent.nope} + {latent.rope} and "
                     f"values of {latent.value} beside head_dim {head_dim} "
                     f"and {num_heads}/{num_kv_heads} heads: a query's and "
                     "a key's two parts add up to head_dim, the rotated one "
-                    "even, every query head has its own key and value, and "
-                    "no attention path takes a value of another width")
+                    "even, and every query head has its own key and value")
         elif "latent" in held or (mtp_depth and mixers
                                   and mixers[-1] == "latent"):
             raise ValueError("a latent layer needs the record `latent`")
@@ -254,6 +282,11 @@ class Config:
         elif "delta" in held or (mtp_depth and mixers
                                  and mixers[-1] == "delta"):
             raise ValueError("a delta layer needs the record `delta`")
+        delta_gates = DeltaGates(*(delta_gates or ()))
+        if delta_gates.gate not in GATES or min(
+                delta_gates.decay_rank, delta_gates.gate_rank) < 0:
+            raise ValueError(f"{delta_gates}: the gate is one of {GATES} "
+                             "and a rank is 0 or more")
         if rotary_dims % 2 or not 0 <= rotary_dims <= head_dim:
             raise ValueError(f"rotary_dims {rotary_dims}: an even part of "
                              f"the head's {head_dim} columns, or 0 for all")
@@ -333,6 +366,10 @@ class Config:
         # scale: a Rotary (or its fields in order); None: the window
         # layers' rope_theta and head_dim ** -0.5
         self.global_rotary = global_rotary
+        # where a "delta" mixer's gates come from: a DeltaGates (given as
+        # one, as its fields in order, or not at all: columns of the
+        # mixer's projections in)
+        self.delta_gates = delta_gates
 
     def layer_mixer(self, i):
         """The kind of held layer ``i``'s token mixer."""
@@ -394,10 +431,11 @@ def _heads(x, seq_len, n, cfg, norm_name=None, rotate=True, inv_freq=None):
     return layers.transpose(x, perm=[0, 2, 1, 3])
 
 
-def _gated_out(ctx, x, cfg, seq_len, p):
+def _gated_out(ctx, x, cfg, seq_len, p, value_dim=0):
     """[B, H, T, Dh] -> the mixer's output: heads side by side, the gate
-    where the model has one, the output projection."""
-    width = cfg.num_heads * cfg.head_dim
+    where the model has one, the output projection.  ``value_dim``: the
+    heads' width where it is not ``head_dim``."""
+    width = cfg.num_heads * (value_dim or cfg.head_dim)
     ctx = layers.reshape(layers.transpose(ctx, perm=[0, 2, 1, 3]),
                          [-1, seq_len, width])
     if cfg.attn_gate:
@@ -440,13 +478,18 @@ def _latent_attention(x, cfg, seq_len, p):
     lat, heads = cfg.latent, cfg.num_heads
 
     def rotated(t):
+        if not lat.rotary:
+            return t
         return layers.rotary_embedding(
             t, theta=cfg.rope_theta, start=lat.nope, dims=lat.rope,
             interleaved=lat.interleaved, inv_freq=lat.inv_freq)
 
-    q = _norm(layers.reshape(_proj(x, heads * cfg.head_dim, f"{p}_q_w"),
-                             [-1, seq_len, heads, cfg.head_dim]),
-              cfg, f"{p}_q_norm")
+    def head_normed(t, name):
+        return _norm(t, cfg, name) if lat.head_norm else t
+
+    q = head_normed(
+        layers.reshape(_proj(x, heads * cfg.head_dim, f"{p}_q_w"),
+                       [-1, seq_len, heads, cfg.head_dim]), f"{p}_q_norm")
     with fluid.name_scope("latent"):
         q = layers.transpose(rotated(q), perm=[0, 2, 1, 3])
         c, kr = layers.split(_proj(x, lat.rank + lat.rope, f"{p}_kva_w"),
@@ -458,13 +501,13 @@ def _latent_attention(x, cfg, seq_len, p):
         k_nope, v = layers.split(kv, [lat.nope, lat.value], dim=-1)
         kr = layers.expand(layers.reshape(kr, [-1, seq_len, 1, lat.rope]),
                            [1, 1, heads, 1])
-        k = rotated(_norm(layers.concat([k_nope, kr], axis=-1), cfg,
-                          f"{p}_k_norm"))
+        k = rotated(head_normed(layers.concat([k_nope, kr], axis=-1),
+                                f"{p}_k_norm"))
         k = layers.transpose(k, perm=[0, 2, 1, 3])
         v = layers.transpose(v, perm=[0, 2, 1, 3])
     ctx = layers.sparse_attention(
         q, k, v, scale=lat.scale or cfg.head_dim ** -0.5)
-    return _gated_out(ctx, x, cfg, seq_len, p)
+    return _gated_out(ctx, x, cfg, seq_len, p, lat.value)
 
 
 def _short_conv(x, cfg, p):
@@ -480,39 +523,68 @@ def _delta_mixer(x, cfg, seq_len, p):
     values and the output's gate side by side; the rule's two gates beside
     them) and one out.  What is not one of those plain products (the
     filter, the gates, the rule, the gated head norm) runs under the name
-    scope ``delta``."""
-    dl = cfg.delta
-    keys, values = dl.key_heads * dl.key_dim, dl.value_heads * dl.value_dim
-    qkvz = _proj(x, 2 * keys + 2 * values, f"{p}_qkvz_w")
-    ba = _proj(x, 2 * dl.value_heads, f"{p}_ba_w")
+    scope ``delta``; under a decay or a gate rank the pairs of products
+    through the rank, with the decay's softplus and the gate's form, run
+    beneath it under ``gates``, and the projections in carry no column of
+    theirs."""
+    dl, dg = cfg.delta, cfg.delta_gates
+    heads = dl.value_heads
+    keys, values = dl.key_heads * dl.key_dim, heads * dl.value_dim
+    form = layers.swish if dg.gate == "silu" else layers.sigmoid
+    qkvz = _proj(x, 2 * keys + values, f"{p}_qkv_w") if dg.gate_rank \
+        else _proj(x, 2 * keys + 2 * values, f"{p}_qkvz_w")
+    ba = _proj(x, heads, f"{p}_b_w") if dg.decay_rank \
+        else _proj(x, 2 * heads, f"{p}_ba_w")
 
-    def gate_param(name, value):
+    def pair(rank, width, name):
+        return _proj(_proj(x, rank, f"{p}_{name}1_w"), width,
+                     f"{p}_{name}2_w")
+
+    def gate_param(name, value, width=heads):
         return layers.create_parameter(
-            [dl.value_heads], "float32", attr=ParamAttr(name=f"{p}_{name}"),
+            [width], "float32", attr=ParamAttr(name=f"{p}_{name}"),
             default_initializer=fluid.initializer.ConstantInitializer(value))
 
     with fluid.name_scope("delta"):
-        qkv, z = layers.split(qkvz, [2 * keys + values, values], dim=-1)
+        if dg.gate_rank:
+            qkv = qkvz
+            with fluid.name_scope("gates"):
+                gate = form(pair(dg.gate_rank, values, "g"))
+        else:
+            qkv, z = layers.split(qkvz, [2 * keys + values, values], dim=-1)
         qkv = layers.short_conv(qkv, dl.taps, gated=False,
                                 param_attr=_attr(f"{p}_conv_w"))
         q, k, v = layers.split(qkv, [keys, keys, values], dim=-1)
         # the gates in float32: their sums along a chunk are exponents
-        b, al = layers.split(layers.cast(ba, "float32"),
-                             [dl.value_heads, dl.value_heads], dim=-1)
-        decay = layers.elementwise_mul(
-            layers.softplus(layers.elementwise_add(
-                al, gate_param("dt_bias", DT_BIAS_INIT))),
-            layers.exp(gate_param("a_log", 0.0)))
+        if dg.decay_rank:
+            b = layers.cast(ba, "float32")
+            with fluid.name_scope("gates"):
+                al = layers.cast(pair(dg.decay_rank, heads * dl.key_dim,
+                                      "f"), "float32")
+                decay = layers.elementwise_mul(
+                    layers.reshape(layers.softplus(layers.elementwise_add(
+                        al, gate_param("dt_bias", DT_BIAS_INIT,
+                                       heads * dl.key_dim))),
+                        [-1, seq_len, heads, dl.key_dim]),
+                    layers.exp(gate_param("a_log", 0.0)), axis=2)
+        else:
+            b, al = layers.split(layers.cast(ba, "float32"),
+                                 [heads, heads], dim=-1)
+            decay = layers.elementwise_mul(
+                layers.softplus(layers.elementwise_add(
+                    al, gate_param("dt_bias", DT_BIAS_INIT))),
+                layers.exp(gate_param("a_log", 0.0)))
         o = layers.gated_delta_rule(
             layers.reshape(q, [-1, seq_len, dl.key_heads, dl.key_dim]),
             layers.reshape(k, [-1, seq_len, dl.key_heads, dl.key_dim]),
-            layers.reshape(v, [-1, seq_len, dl.value_heads, dl.value_dim]),
+            layers.reshape(v, [-1, seq_len, heads, dl.value_dim]),
             layers.scale(decay, scale=-1.0), layers.sigmoid(b),
             chunk=dl.chunk, scale=dl.key_dim ** -0.5,
             norm_eps=DELTA_NORM_EPS)
         o = layers.elementwise_mul(
             layers.reshape(_norm(o, cfg, f"{p}_delta_norm"),
-                           [-1, seq_len, values]), layers.swish(z))
+                           [-1, seq_len, values]),
+            gate if dg.gate_rank else form(z))
     return _proj(o, cfg.hidden_size, f"{p}_o_w")
 
 
@@ -586,6 +658,15 @@ def _block(stream, cfg, seq_len, p, scope, mixer, window, dense, routers):
     observe.registry().inc("models.decoder.blocks", labels={
         "mixer": mixer, "residual": cfg.residual,
         "where": "mtp" if scope == "mtp" else "trunk"})
+    if mixer == "delta":
+        observe.registry().inc("models.decoder.delta", labels={
+            "decay": "channel" if cfg.delta_gates.decay_rank else "scalar",
+            "gate": cfg.delta_gates.gate})
+    if mixer == "latent":
+        observe.registry().inc("models.decoder.latent", labels={
+            "rotary": str(int(cfg.latent.rotary)),
+            "head_norm": str(int(cfg.latent.head_norm)),
+            "value": str(cfg.latent.value)})
     if own:
         observe.registry().inc("models.decoder.rotary", labels={
             "kind": "global", "table": "given", "scope": scope})
@@ -642,8 +723,9 @@ def _forward(cfg, seq_len):
     ``layer<i>.mixer`` (the attention of any kind with its indexer, the
     short convolution or the delta rule, with projections, norms, gate and
     the residual add; what only a latent mixer has beneath it as
-    ``.latent``, what only a delta mixer has as ``.delta``, and all of a
-    global attention layer that rotates by ``global_rotary`` but the
+    ``.latent``, what only a delta mixer has as ``.delta`` (the pairs of
+    products of a decay or a gate rank beneath that as ``.gates``), and all
+    of a global attention layer that rotates by ``global_rotary`` but the
     residual add as ``.global``),
     ``layer<i>.ffn`` (dense or shared feed-forward, router and routed
     experts, likewise), ``head`` (final norm, product, loss) and, for the

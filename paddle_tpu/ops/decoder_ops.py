@@ -19,7 +19,11 @@ filter and SiLU alone;
 ``gated_delta_rule`` lowered, its backward not counted there but as
 ``ops.delta_rule.grad_calls{chunk,path="by_hand"}`` for every
 ``gated_delta_rule_grad`` lowered (``by_hand``: the backward written out in
-``ops/delta_rule.py``, no autodiff through the walk over the chunks);
+``ops/delta_rule.py``, no autodiff through the walk over the chunks;
+``path="vjp"`` where G is a decay a key channel, whose backward is
+``jax.vjp`` of its chunked forward), and
+``ops.delta_rule.channel_calls{key_heads,dim,chunk,sub}`` beside ``calls``
+for every forward lowered with such a G;
 ``ops.moe.row_moves{pass,how="gather"}``, which ``parallel/moe.py`` counts
 for every ``[N * top_k, D]`` row gather it traces: two
 ``pass="forward"`` for every trace of the layer's forward, of which
@@ -235,6 +239,7 @@ def blocked_attention(q, k, v, sel, scale, block=512, window=0):
     b, hq, t, d = q.shape
     hkv = k.shape[1]
     qg = q.reshape(b, hkv, hq // hkv, t, d)
+    dv = v.shape[-1]        # need not be the key's
 
     @jax.checkpoint
     def tile(qt, kt, vt, keep):
@@ -259,10 +264,10 @@ def blocked_attention(q, k, v, sel, scale, block=512, window=0):
             keep = keep & (sel[:, q0:q1, k0:q1] > 0)
         outs.append(tile(qg[:, :, :, q0:q1], k[:, :, k0:q1], v[:, :, k0:q1],
                          keep))
-    return jnp.concatenate(outs, axis=3).reshape(b, hq, t, d).astype(q.dtype)
+    return jnp.concatenate(outs, axis=3).reshape(b, hq, t, dv).astype(q.dtype)
 
 
-def _attention_path(ctx, q, k, sel, window, count):
+def _attention_path(ctx, q, k, v, sel, window, count):
     """'pallas' where the flash gate is open (``kernel_choice.gate``: the
     environment switch where set, else the platform; the op states no
     wish) and the kernels take the operands, else 'xla'; counted where
@@ -272,7 +277,7 @@ def _attention_path(ctx, q, k, sel, window, count):
 
     path = "xla"
     if kernel_choice.gate("flash"):
-        why = psf.supported(q, k, sel, window)
+        why = psf.supported(q, k, sel, window, v)
         if not why:
             path = "pallas"
         elif count:
@@ -303,15 +308,16 @@ def sparse_attention_op(ctx):
     """Causal grouped-query attention, optionally over a per-query
     selection and, with the attr ``window`` (0: none), over the last
     ``window`` keys only: key s counts for query t iff ``0 <= t - s <
-    window``.  Q: [B, Hq, T, D]; K, V: [B, Hkv, T, D]; Sel: [B, T, T] int8
-    or absent.  The Pallas kernels where the flash gate is open and they
-    take the operands, else the blocked XLA path.  Lse ([B, Hq, T, 1]
+    window``.  Q: [B, Hq, T, D]; K: [B, Hkv, T, D]; V: [B, Hkv, T, Dv] (Out:
+    [B, Hq, T, Dv]); Sel: [B, T, T] int8 or absent.  The Pallas kernels
+    where the flash gate is open and they take the operands (``Dv = D``
+    among the rest), else the blocked XLA path.  Lse ([B, Hq, T, 1]
     float32) is the kernels' log-sum-exp, kept for their backward; zeros on
     the XLA path, whose backward is the generic vjp."""
     from . import pallas_sparse_flash as psf
 
     q, k, v, sel, scale, window = _attention_operands(ctx)
-    if _attention_path(ctx, q, k, sel, window, count=True) == "pallas":
+    if _attention_path(ctx, q, k, v, sel, window, count=True) == "pallas":
         out, lse = psf.forward(q, k, v, sel, scale, window=window)
         return {"Out": out, "Lse": lse}
     return {"Out": blocked_attention(q, k, v, sel, scale, window=window),
@@ -327,7 +333,7 @@ def sparse_attention_grad(ctx):
     from . import registry
 
     q, k, v, sel, scale, window = _attention_operands(ctx)
-    if _attention_path(ctx, q, k, sel, window, count=False) != "pallas":
+    if _attention_path(ctx, q, k, v, sel, window, count=False) != "pallas":
         return registry.run_grad_generic(
             registry.get_op_def("sparse_attention"), ctx)
     dq, dk, dv = psf.backward(q, k, v, sel, ctx.input("Out"),
@@ -422,26 +428,33 @@ def gated_delta_rule_op(ctx):
     Beta (its step): [B, T, Hv]; Out: [B, T, Hv, dv].  ``scale`` multiplies
     Q (0: ``dk ** -0.5``); ``norm_eps`` > 0: Q and K are l2-normed per head
     first.  The state starts at zero in every row of the batch and nothing
-    crosses from one row to the next."""
+    crosses from one row to the next.  G [B, T, Hv, dk]: a decay a key
+    channel, the state's rows each by their own."""
+    from . import delta_rule
+
     rule, operands = _delta_rule(ctx)
-    q, v = operands[0], operands[2]
+    q, v, g = operands[0], operands[2], operands[3]
+    chunk = int(ctx.attr("chunk", 64))
     _count("ops.delta_rule.calls", key_heads=q.shape[2],
-           value_heads=v.shape[2], dim=v.shape[3],
-           chunk=int(ctx.attr("chunk", 64)), path="xla")
+           value_heads=v.shape[2], dim=v.shape[3], chunk=chunk, path="xla")
+    if g.ndim == 4:
+        _count("ops.delta_rule.channel_calls", key_heads=q.shape[2],
+               dim=q.shape[3], chunk=chunk, sub=delta_rule.sub_block(chunk))
     return {"Out": rule(*operands)}
 
 
 @register_grad("gated_delta_rule")
 def gated_delta_rule_grad(ctx):
     """From the op's five inputs alone, by the backward ``delta_rule.chunked``
-    carries (written by hand, nothing differentiates through its walk):
+    carries (written by hand, nothing differentiates through its walk; under
+    a decay a key channel, ``jax.vjp`` of the chunked forward):
     everything a chunk needs (decays, the inverse, what each token writes)
     and the state at every chunk's start are made again, the outputs are
     not, then the chunks are walked backwards; nothing but the inputs is
     kept from the forward."""
     rule, operands = _delta_rule(ctx)
     _count("ops.delta_rule.grad_calls", chunk=int(ctx.attr("chunk", 64)),
-           path="by_hand")
+           path="vjp" if operands[3].ndim == 4 else "by_hand")
     # behind a barrier with the cotangent in it, as ``jax.checkpoint`` puts
     # one: without it XLA finds the second forward to be the first and
     # keeps a gigabyte a layer (every chunk's state, inverse and writes)

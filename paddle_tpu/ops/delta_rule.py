@@ -77,6 +77,32 @@ exponent is ever positive: ``exp`` sees ``G_i - G_j`` for ``i >= j`` only,
 the rest is masked to ``-inf`` BEFORE the exponential, so a decay that
 underflows inside a chunk gives zeros and never ``inf * 0``, in the
 cotangents too.
+
+A decay that is a VECTOR along the key (``g`` [B, T, Hv, dk]: one number a
+key channel; ``_channel_rule``).  ``exp(g_t)`` scales the state's ROWS,
+``S' = Diag(exp(g_t)) S_{t-1}``, and the rest of the recurrence stands.
+Chunked, ``G`` [C, dk] and every decay moves to the KEY side::
+
+    A_ij = beta_i sum_c k_ic k_jc exp(G_ic - G_jc)       for i > j, else 0
+    P_ij =        sum_c q_ic k_jc exp(G_ic - G_jc)       for i >= j, else 0
+    U    = T (beta * v),   W = T (beta * exp(G) * k)
+    V'   = U - W S
+    S   <- Diag(exp(G_C)) S + (k * exp(G_C - G))^T V'
+    O    = (q * exp(G)) S + P V'
+
+``exp(G_i - G_j)`` is [C, C, dk] and is never made for a whole chunk, nor
+split into ``exp(G_i) exp(-G_j)`` (the second overflows).  It splits about
+a point BETWEEN the two (``_pair_scores``): the chunk is cut into
+SUB_BLOCKS sub-blocks of ``sub = C / SUB_BLOCKS`` tokens (``sub_block``);
+for i in sub-block a and j in an earlier one, with s the last token before
+a, ``G_i - G_j = (G_i - G_s) + (G_s - G_j)``, both <= 0, so those tiles are
+products of ``x * exp(G - G_s)`` rows with ``k * exp(G_s - G)`` rows; the
+SUB_BLOCKS diagonal tiles alone are made element by element, [sub, sub, dk]
+each, masked before the exponential.
+Scores and diagonal tiles are a checkpoint: the backward makes them again
+and keeps neither.  This path's backward is ``jax.vjp`` of the chunked
+forward (a scan's transpose for the walk, the inverse's levels
+differentiated as they are); the scalar path above is untouched by it.
 """
 
 from __future__ import annotations
@@ -88,6 +114,18 @@ import jax.numpy as jnp
 from jax import lax
 
 _exact = functools.partial(jnp.matmul, precision=lax.Precision.HIGHEST)
+
+#: the sub-blocks a chunk's scores are made in under a decay a key channel
+SUB_BLOCKS = 4
+
+
+def sub_block(chunk):
+    """The tokens of one of a chunk's SUB_BLOCKS sub-blocks."""
+    if chunk % SUB_BLOCKS:
+        raise ValueError(f"delta rule: a chunk of {chunk} tokens is not cut "
+                         f"into {SUB_BLOCKS} sub-blocks, as a decay a key "
+                         f"channel needs it")
+    return chunk // SUB_BLOCKS
 
 
 def _dot(low, spec, a, b):
@@ -268,12 +306,81 @@ def _rule_bwd(low, operands, dout):
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
+def _pair_scores(low, xs, kc, gsum):
+    """``sum_c x_ic k_jc exp(G_ic - G_jc)`` where ``i >= j``, else 0, for
+    every ``x`` of ``xs``: [..., C, C] each from x, kc, gsum [..., C, dk].
+    No exponent is positive and nothing [C, C, dk] is made: the module's
+    docstring has the split."""
+    c, dk = kc.shape[-2:]
+    ns, sub = SUB_BLOCKS, sub_block(c)
+    lead = kc.shape[:-2]
+    at = jnp.arange(c)
+    # G at the last token before each sub-block (0 before the first)
+    edge = jnp.concatenate([jnp.zeros_like(gsum[..., :1, :]),
+                            gsum[..., sub - 1:c - 1:sub, :]], -2)
+    to_edge = jnp.exp(gsum - jnp.repeat(edge, sub, -2))         # [.., C, dk]
+    earlier = at[None, :] < (jnp.arange(ns) * sub)[:, None]     # [ns, C]
+    right = kc[..., None, :, :] * jnp.exp(jnp.where(
+        earlier[:, :, None],
+        edge[..., :, None, :] - gsum[..., None, :, :], -jnp.inf))
+    tiles = lead + (ns, sub, dk)
+    gd, kd = gsum.reshape(tiles), kc.reshape(tiles)
+    i, j = jnp.arange(sub)[:, None, None], jnp.arange(sub)[None, :, None]
+    within = jnp.exp(jnp.where(
+        i >= j, gd[..., :, None, :] - gd[..., None, :, :], -jnp.inf))
+    eye = jnp.eye(ns, dtype=jnp.float32)
+    out = []
+    for x in xs:
+        off = _dot(low, "...aid,...ajd->...aij",
+                   (x * to_edge).reshape(tiles), right)
+        diag = jnp.sum(x.reshape(tiles)[..., :, None, :]
+                       * kd[..., None, :, :] * within, -1)
+        out.append(off.reshape(lead + (c, c)) + jnp.einsum(
+            "...aij,ab->...aibj", diag, eye).reshape(lead + (c, c)))
+    return out
+
+
+def _channel_rule(low, qc, kc, vc, gc, bc):
+    """The rule under a decay a key channel.  qc, kc, gc: [n,B,H,C,dk]; vc:
+    [n,B,H,C,dv]; bc: [n,B,H,C]; all float32 -> [n,B,H,C,dv] float32."""
+    c = qc.shape[-2]
+    gsum = jnp.cumsum(gc, -2)
+    gamma = jnp.exp(gsum)
+    kk, scores = jax.checkpoint(
+        lambda q, k, g: _pair_scores(low, (k, q), k, g))(qc, kc, gsum)
+    at = jnp.arange(c)
+    inv = unit_lower_inverse(jnp.where(at[:, None] > at[None, :],
+                                       bc[..., None] * kk, 0.0))
+    u = _exact(inv, bc[..., None] * vc)
+    w = _exact(inv, bc[..., None] * gamma * kc)
+
+    def step(state, xs):
+        u_i, w_i, e_i, a_i = xs
+        start = _cast(low, state)
+        wrote = u_i - _dot(low, "bhck,bhkv->bhcv", w_i, start)
+        state = a_i[..., None] * state + _dot(low, "bhck,bhcv->bhkv", e_i,
+                                              wrote)
+        return state, (wrote, start)
+
+    # what is left of each token's write (on its key), and of the state's
+    # rows, at the chunk's end
+    to_end = kc * jnp.exp(gsum[..., -1:, :] - gsum)
+    _, (wrote, starts) = lax.scan(
+        step, jnp.zeros(vc.shape[1:3] + (kc.shape[-1], vc.shape[-1]),
+                        jnp.float32),
+        (u, _cast(low, w), _cast(low, to_end), gamma[..., -1, :]))
+    return _dot(low, "nbhck,nbhkv->nbhcv", qc * gamma, starts) \
+        + _dot(low, "nbhcj,nbhjv->nbhcv", scores, wrote)
+
+
 def chunked(q, k, v, g, beta, chunk=64, scale=0.0, norm_eps=0.0):
     """q, k: [B, T, Hk, dk]; v: [B, T, Hv, dv]; g, beta: [B, T, Hv] ->
     [B, T, Hv, dv] in v's type.  ``scale`` multiplies q (0: ``dk ** -0.5``);
     ``norm_eps`` > 0: q and k are l2-normed per head first, with that
     epsilon.  ``T`` need not be a multiple of ``chunk``: the tail is padded
-    with tokens that write nothing (beta 0) and decay nothing (g 0)."""
+    with tokens that write nothing (beta 0) and decay nothing (g 0).  A
+    ``g`` [B, T, Hv, dk] is a decay a key channel (``_channel_rule``), whose
+    chunk is a multiple of SUB_BLOCKS."""
     from ..fluid import amp
 
     b, t, hk, dk = q.shape
@@ -289,6 +396,13 @@ def chunked(q, k, v, g, beta, chunk=64, scale=0.0, norm_eps=0.0):
         q, k, v, g, beta = (jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (
             a.ndim - 2)) for a in (q, k, v, g, beta))
     n = (t + pad) // chunk
+    if g.ndim == 4:
+        # every value head its own decayed keys: a key head is repeated
+        q, k = (jnp.repeat(a, rep, 2) if rep > 1 else a for a in (q, k))
+        out = _channel_rule(amp.compute_dtype(), *(
+            _chunks(a.astype(f32), n, chunk) for a in (q, k, v, g, beta)))
+        out = jnp.transpose(out, (1, 0, 3, 2, 4)).reshape(b, t + pad, hv, dv)
+        return out[:, :t].astype(v.dtype)
 
     def per_value(a):       # [B, T, Hv, ...] -> [n, B, Hk, R, C, ...]
         a = a.astype(f32).reshape((b, t + pad, hk, rep) + a.shape[3:])

@@ -538,19 +538,20 @@ def infer_sparse_indexer(op, ins):
 
 @register_infer("sparse_attention")
 def infer_sparse_attention(op, ins):
-    """Out mirrors Q (never evaluated abstractly, as ring_attention's);
-    the grouped heads and the selection's shape are checked here, where
-    the vars have names."""
+    """Out mirrors Q at V's width (never evaluated abstractly, as
+    ring_attention's); the grouped heads and the selection's shape are
+    checked here, where the vars have names."""
     q, k, v, sel = (_in(ins, s) for s in ("Q", "K", "V", "Sel"))
     lse = None if q is None else (tuple(q[0][:3]) + (1,), "float32")
     if q is None or k is None:
         return {"Out": [q], "Lse": [lse]}
-    if len(q[0]) != 4 or len(k[0]) != 4 or (v is not None
-                                            and tuple(v[0]) != tuple(k[0])):
+    if len(q[0]) != 4 or len(k[0]) != 4 or (
+            v is not None and (len(v[0]) != 4
+                               or tuple(v[0][:3]) != tuple(k[0][:3]))):
         raise InferMismatch(
             f"sparse_attention: {_names(op, 'Q')} {list(q[0])}, "
             f"{_names(op, 'K')} {list(k[0])} and V must be [B, H, T, D] "
-            f"with K and V alike")
+            f"with K and V alike but for their width")
     if q[0][1] % k[0][1] or q[0][2:] != k[0][2:]:
         raise InferMismatch(
             f"sparse_attention: {q[0][1]} query heads of {_names(op, 'Q')} "
@@ -566,7 +567,8 @@ def infer_sparse_attention(op, ins):
         raise InferMismatch(
             f"sparse_attention: window {op.attr('window')} is negative "
             f"(0: none; else the last `window` keys s <= t)")
-    return {"Out": [q], "Lse": [lse]}
+    out = q if v is None else (tuple(q[0][:3]) + (v[0][3],), q[1])
+    return {"Out": [out], "Lse": [lse]}
 
 
 @register_infer("moe_experts")
@@ -652,12 +654,16 @@ def infer_gated_delta_rule(op, ins):
             f"be alike, [B, T, key heads, dk], and {_names(op, 'V')} "
             f"{list(v[0])} [B, T, value heads, dv] over the same tokens "
             f"with the value heads a multiple of the key heads")
-    for name, gate in (("G", g), ("Beta", beta)):
-        if gate is not None and tuple(gate[0]) != tuple(v[0][:3]):
+    by_head = tuple(v[0][:3])
+    by_channel = by_head + (q[0][3],)
+    for name, gate, takes in (("G", g, (by_head, by_channel)),
+                              ("Beta", beta, (by_head,))):
+        if gate is not None and tuple(gate[0]) not in takes:
             raise InferMismatch(
                 f"gated_delta_rule: {_names(op, name)} {list(gate[0])} "
-                f"must be {list(v[0][:3])}, one number a token and value "
-                f"head")
+                f"must be {list(by_head)}, one number a token and value "
+                f"head" + (f", or {list(by_channel)}, one a key channel"
+                           if by_channel in takes else ""))
     if int(op.attr("chunk", 64)) < 1:
         raise InferMismatch(
             f"gated_delta_rule: chunk {op.attr('chunk')} is not positive")

@@ -102,13 +102,19 @@ def band_tiles(window, blk, n):
     return min(-(-(window - 1) // blk) + 1, n)
 
 
-def supported(q, k, sel, window=0) -> str:
-    """'' when the kernels take these operands, else why not."""
+def supported(q, k, sel, window=0, v=None) -> str:
+    """'' when the kernels take these operands, else why not.  ``v`` (None:
+    as ``k``) has k's batch, heads and length, and k's width too: one head
+    width is what the kernels' tiles are cut to."""
     b, hq, t, d = q.shape
     if window and sel is not None:
         return "window_selection"
     if k.shape[0] != b or k.shape[2] != t or k.shape[3] != d:
         return "shape"
+    if v is not None and v.shape[:3] != k.shape[:3]:
+        return "shape"
+    if v is not None and v.shape[3] != d:
+        return "value_width"
     if hq % k.shape[1]:
         return "heads"
     if sel is not None and tuple(sel.shape) != (b, t, t):

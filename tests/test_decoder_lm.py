@@ -4,7 +4,6 @@ float32 reference of ``chipbench/configs/keye_vl_2_0_30b_a3b`` at a tiny
 size, on the CPU, with seeded random weights."""
 
 import json
-import math
 import os
 import sys
 
@@ -1767,862 +1766,8 @@ def test_infer_rule_of_the_short_convolution():
     assert not report.errors, report.format()
 
 
-# == latent attention with a gated output and a partial interleaved YaRN  ==
-# == rotary, the FarSkip residual, two shared experts and a multi-token   ==
-# == module that shares the embedding and the head: the program against   ==
-# == the reference of ``chipbench/configs/instella_moe_16b_a3b``          ==
-
-INSTELLA = "configs/instella_moe_16b_a3b"
-I_BUILD = plugins.load(INSTELLA, "build")
-I_REF = plugins.load(INSTELLA, "reference")
-
-
-def instella_sizes(**over):
-    sizes = json.load(open(os.path.join(ROOT, "chipbench", INSTELLA,
-                                        "config.json")))
-    return {**sizes, **sizes["tiny"], **over}
-
-
-def seeded_program(build, ref, sizes, seed=5):
-    """(built, names, weights): the program with the reference's weights
-    from ``seed`` in the scope."""
-    built = build.build(fluid, sizes)
-    names = build.trainable_names(fluid.default_main_program())
-    spec = ref.param_spec(sizes)
-    assert [n for n, _, _ in spec] == names
-    fluid.Executor(fluid.TPUPlace()).run(fluid.default_startup_program())
-    scope = fluid.global_scope()
-    weights = ref.init_params(seed, sizes)
-    for (_, shape, _), name, w in zip(spec, names, weights):
-        assert tuple(np.shape(scope.get(name))) == tuple(shape), name
-        scope.set(name, jnp.array(w))
-    return built, names, weights
-
-
-@pytest.mark.parametrize("flash", ["xla", "pallas"])
-def test_latent_program_equals_the_reference_adam_step_and_bias(
-        monkeypatch, flash):
-    """Both losses' sum, every gradient, every parameter after one Adam
-    step and every router's bias after its rule, through ``fluid.Executor``
-    with ``optimizer.minimize``: the leading dense layer, three routed
-    layers and the multi-token module's block, five latent mixers under
-    the FarSkip rule."""
-    monkeypatch.setenv("PADDLE_TPU_FLASH", "1" if flash == "pallas" else "0")
-    monkeypatch.setattr(psf, "BLOCK", 16)
-    sizes = instella_sizes()
-    assert sizes["n_routed_experts"] < sizes["published"]["n_routed_experts"]
-    built, names, weights = seeded_program(I_BUILD, I_REF, sizes)
-    main, scope = fluid.default_main_program(), fluid.global_scope()
-    # one embedding, one head, each used twice; the module's own parameters
-    assert names.count("tok_emb") == names.count("lm_head_w") == 1
-    block = main.global_block()
-    assert sum(op.type == "lookup_table" for op in block.ops) == 2
-    assert sum(op.type == "mul" and "lm_head_w" in op.inputs["Y"]
-               for op in block.ops) == 2
-    assert {"mtp_h_norm", "mtp_e_norm", "mtp_merge_w", "mtp_kva_w",
-            "mtp_router_w", "mtp_norm"} <= set(names)
-    for p in ["l0", "l1", "l2", "l3", "mtp"]:
-        mine = {n[len(p) + 1:] for n in names if n.startswith(p + "_")}
-        assert {"attn_norm", "q_w", "q_norm", "kva_w", "kv_norm", "kvb_w",
-                "k_norm", "gate_w", "o_w"} <= mine
-        assert not {"k_w", "v_w", "conv_w"} & mine
-        assert ("mlp_w1" in mine) == (p == "l0")
-        assert ("shared_w1" in mine) == ("router_w" in mine) == (p != "l0")
-    routers = ["l1_route_bias", "l2_route_bias", "l3_route_bias",
-               "mtp_route_bias"]
-    for name in routers:
-        assert not np.any(np.asarray(scope.get(name)))
-        assert not block.has_var(name + "@GRAD") and name not in names
-    feed = I_BUILD.make_feed(sizes, 2, np.random.RandomState(3))
-    assert set(feed) == {"tokens", "labels", "labels2"}
-    np.testing.assert_array_equal(feed["labels"][:, 1:, 0],
-                                  feed["labels2"][:, :-1, 0])
-    exe = fluid.Executor(fluid.TPUPlace())
-    outs = exe.run(main, feed=feed, fetch_list=[built["loss"]]
-                   + [n + "@GRAD" for n in names])
-    ref_loss, ref_grads = I_REF.loss_and_grads(weights, feed, sizes)
-    assert float(outs[0].reshape(-1)[0]) == pytest.approx(float(ref_loss),
-                                                          rel=1e-5)
-    # the module's loss is in it: the trunk's alone is smaller by 0.3 of a
-    # loss near ln(vocabulary)
-    assert float(ref_loss) > 1.2 * np.log(sizes["vocab_size"])
-    for name, g, r in zip(names, outs[1:], ref_grads):
-        g = np.asarray(g).reshape(r.shape)
-        assert np.abs(g - r).max() <= 2e-4 * np.abs(r).max() + 1e-7, name
-        assert np.abs(r).max() > 0, name
-    # one Adam step of every parameter, from the summed gradients
-    for name, w, r in zip(names, weights, ref_grads):
-        np.testing.assert_allclose(
-            np.asarray(scope.get(name)).reshape(w.shape),
-            I_REF.optimizer_step(w, r, sizes), atol=2e-6, err_msg=name)
-    after = I_REF.biases_after_step(weights, feed, sizes)
-    rate = sizes["assumed"]["bias_update_rate"]
-    assert len(after) == len(routers)
-    for name, want in zip(routers, after):
-        got = np.asarray(scope.get(name))
-        np.testing.assert_allclose(got, want, atol=1e-9)
-        assert set(np.round(np.abs(got) / rate)) <= {0.0, 1.0} \
-            and np.any(got > 0) and np.any(got < 0)
-    # what ran, as the counters say it
-    per = 1 if flash == "pallas" else 2
-    assert counters("ops.sparse_attention.calls") == {
-        f'ops.sparse_attention.calls{{path="{flash}",seq="64",'
-        f'topk="0"}}': 5 * per}
-    assert not counters("ops.sparse_attention.declined")
-    assert counters("ops.rotary.calls") == {
-        'ops.rotary.calls{dims="8",pairing="interleaved",scaled="1"}': 10}
-    assert counters("models.decoder.blocks") == {
-        'models.decoder.blocks{mixer="latent",residual="farskip",'
-        'where="trunk"}': 4,
-        'models.decoder.blocks{mixer="latent",residual="farskip",'
-        'where="mtp"}': 1}
-    (key, n), = counters("ops.moe.calls").items()
-    assert 'score="sigmoid"' in key and 'routed="8"' in key \
-        and 'held="4"' in key and n == 2 * 4
-    assert counters("ops.moe.bias_updates") == {"ops.moe.bias_updates": 4}
-    # every op under a name, the latent's and the module's among them
-    scopes = {op.attrs.get("op_namescope", "") for op in main.all_ops()}
-    assert "" not in scopes
-    assert {"embed", "head", "mtp.merge", "mtp.mixer", "mtp.mixer.latent",
-            "mtp.ffn", "mtp.head"} | {
-        f"layer{i}.{part}" for i in range(4)
-        for part in ("mixer", "mixer.latent", "ffn")} == scopes
-    update = [op for op in block.ops if op.type == "moe_bias_update"]
-    assert [op.attrs["op_namescope"] for op in update] == [
-        "layer1.ffn", "layer2.ffn", "layer3.ffn", "mtp.ffn"]
-
-
-def test_both_uses_of_the_embedding_and_of_the_head_reach_their_gradient():
-    """``tok_emb`` is looked up twice (the tokens, and the next tokens in
-    the module's merge) and ``lm_head_w`` multiplied twice (the trunk's
-    logits and the module's): the program's gradient of each is the sum of
-    the two parts that the reference gives with the two uses told apart,
-    and neither part alone."""
-    sizes = instella_sizes()
-    built, names, weights = seeded_program(I_BUILD, I_REF, sizes)
-    feed = I_BUILD.make_feed(sizes, 2, np.random.RandomState(3))
-    outs = fluid.Executor(fluid.TPUPlace()).run(
-        fluid.default_main_program(), feed=feed,
-        fetch_list=["tok_emb@GRAD", "lm_head_w@GRAD"])
-    at = {n: names.index(n) for n in ("tok_emb", "lm_head_w")}
-
-    def told_apart(emb, emb2, head, head2):
-        params = list(weights)
-        params[at["tok_emb"]], params[at["lm_head_w"]] = emb, head
-        return I_REF.loss_and_counts(params, feed, sizes, emb2=emb2,
-                                     head2=head2)[0]
-
-    emb, head = weights[at["tok_emb"]], weights[at["lm_head_w"]]
-    with jax.default_matmul_precision("highest"):
-        parts = jax.grad(told_apart, (0, 1, 2, 3))(emb, emb, head, head)
-    for got, (first, second) in zip(outs, (parts[:2], parts[2:])):
-        got, both = np.asarray(got), np.asarray(first + second)
-        big = np.abs(both).max()
-        assert np.abs(got - both).max() <= 2e-4 * big
-        for part in (first, second):
-            assert np.abs(got - np.asarray(part)).max() > 0.05 * big
-    # a lookup's part is rows: untouched ids have none
-    first, second = (np.asarray(p) for p in parts[:2])
-    assert not np.any(first[np.setdiff1d(np.arange(sizes["vocab_size"]),
-                                         np.unique(feed["tokens"]))])
-    assert not np.any(second[np.setdiff1d(np.arange(sizes["vocab_size"]),
-                                          np.unique(feed["labels"]))])
-
-
-def sub_block_inputs(main, names):
-    """The variable each named norm reads: the input of its sub-block."""
-    by_scale = {op.inputs["Scale"][0]: op.inputs["X"][0]
-                for op in main.global_block().ops if op.type == "rms_norm"}
-    return [by_scale[n] for n in names]
-
-
-@pytest.mark.parametrize("residual", ["farskip", "sequential"])
-def test_a_sub_block_reads_the_stream_the_residual_rule_names(residual):
-    """Sub-blocks j = 1.. are layer 0's mixer and feed-forward, layer 1's
-    mixer, ...  Changing sub-block 2's output (layer 0's down projection)
-    under FarSkip leaves what sub-block 3 reads (s_1) bit-equal and changes
-    what sub-block 4 reads (s_2); under the sequential rule sub-block 3
-    reads s_2 and changes at once.  Sub-block 1 reads the embedding either
-    way, and under FarSkip so does sub-block 2."""
-    sizes = instella_sizes(farskip=residual == "farskip")
-    built, names, weights = seeded_program(I_BUILD, I_REF, sizes)
-    main = fluid.default_main_program()
-    norms = ["l0_attn_norm", "l0_mlp_norm", "l1_attn_norm", "l1_moe_norm"]
-    reads = sub_block_inputs(main, norms)
-    embedded = next(op.outputs["Out"][0] for op in main.global_block().ops
-                    if op.type == "lookup_table")
-    assert reads[0] == embedded
-    assert (reads[1] == embedded) == (residual == "farskip")
-    assert len(set(reads)) == (3 if residual == "farskip" else 4)
-    feed = I_BUILD.make_feed(sizes, 2, np.random.RandomState(3))
-    # forward only: a program without the optimizer, same names and scope
-    fwd = fluid.Program()
-    with fluid.program_guard(fwd, fluid.Program()), \
-            fluid.unique_name.guard():
-        from paddle_tpu.models import decoder_lm
-        decoder_lm.forward(I_BUILD.config_of(sizes), sizes["seq_len"])
-    reads = sub_block_inputs(fwd, norms)
-    exe = fluid.Executor(fluid.TPUPlace())
-    before = exe.run(fwd, feed=feed, fetch_list=reads[2:])
-    scope = fluid.global_scope()
-    scope.set("l0_mlp_w2", 1.5 * jnp.asarray(scope.get("l0_mlp_w2")))
-    after = exe.run(fwd, feed=feed, fetch_list=reads[2:])
-    third_moved = not np.array_equal(before[0], after[0])
-    assert third_moved == (residual == "sequential")
-    assert not np.array_equal(before[1], after[1])
-    # and the reference's rule is the same one
-    ref_loss, _ = I_REF.loss_and_grads(weights, feed, sizes)
-    other, _ = I_REF.loss_and_grads(
-        weights, feed, {**sizes, "farskip": not sizes["farskip"]})
-    assert abs(float(ref_loss) - float(other)) > 1e-4
-
-
-def complex_rotary(x, start, inv_freq):
-    """x [B, T, H, D] float64: the columns from ``start`` on as complex
-    numbers (2i, 2i+1) -> (real, imaginary), each times exp(i t f_i)."""
-    x = np.asarray(x, np.float64)
-    part = x[..., start:]
-    z = part[..., 0::2] + 1j * part[..., 1::2]
-    ang = np.arange(x.shape[1])[:, None] * np.asarray(inv_freq, np.float64)
-    z = z * np.exp(1j * ang)[None, :, None, :]
-    out = x.copy()
-    out[..., start::2], out[..., start + 1::2] = z.real, z.imag
-    return out
-
-
-def test_partial_interleaved_yarn_rotary_is_a_complex_rotation():
-    """``rotary_embedding`` on the last 8 of 24 columns, pairs (2i, 2i+1),
-    with a YaRN table whose ramp is neither all 0 nor all 1: the op through
-    the executor equals the complex-number rotation, its gradient the
-    rotation by the opposite angles, and the columns outside pass."""
-    from paddle_tpu.models import decoder_lm
-
-    table = decoder_lm.yarn_inv_freq(8, 100.0, 4.0, 64, beta_fast=4,
-                                     beta_slow=1)
-    plain = [100.0 ** (-2 * i / 8) for i in range(4)]
-    np.testing.assert_allclose(
-        np.asarray(table) / plain, [1, 1 - 0.75 / 3, 1 - 0.75 * 2 / 3, 0.25])
-    np.testing.assert_allclose(
-        table, I_REF.yarn_inv_freq(8, 100, instella_sizes()["rope_scaling"]),
-        rtol=1e-6)
-    # the published table: 3 pairs as they are, 4 blended, 9 over 40
-    full = np.asarray(decoder_lm.yarn_inv_freq(32, 8e6, 40, 4096))
-    ratio = full / 8e6 ** (-np.arange(16) / 16)
-    np.testing.assert_allclose(ratio[:4], 1)
-    assert np.all(np.diff(ratio[3:8]) < 0)
-    np.testing.assert_allclose(ratio[7:], 1 / 40)
-    assert decoder_lm.yarn_softmax_scale(128, 40) == pytest.approx(
-        128 ** -0.5 * (0.1 * np.log(40) + 1) ** 2)
-
-    x = layers.data(name="x", shape=[13, 3, 24], dtype="float32")
-    x.stop_gradient = False
-    out = layers.rotary_embedding(x, start=16, dims=8, interleaved=True,
-                                  inv_freq=table)
-    w = layers.data(name="w", shape=[13, 3, 24], dtype="float32")
-    loss = layers.reduce_sum(layers.elementwise_mul(out, w))
-    fluid.backward.append_backward(loss)
-    rng = np.random.RandomState(0)
-    xv, wv = (rng.randn(2, 13, 3, 24).astype(np.float32) for _ in range(2))
-    got, dx = fluid.Executor(fluid.TPUPlace()).run(
-        fluid.default_main_program(), feed={"x": xv, "w": wv},
-        fetch_list=[out, "x@GRAD"])
-    np.testing.assert_allclose(got, complex_rotary(xv, 16, table), atol=2e-6)
-    np.testing.assert_array_equal(got[..., :16], xv[..., :16])
-    np.testing.assert_allclose(
-        dx, complex_rotary(wv, 16, -np.asarray(table)), atol=2e-6)
-    assert counters("ops.rotary.calls") == {
-        'ops.rotary.calls{dims="8",pairing="interleaved",scaled="1"}': 1}
-
-
-def test_rotary_with_default_attrs_is_bit_equal_to_the_rotate_half_op():
-    """No new attr written into the op, the whole head in rotate-half
-    pairs by theta's own table: bit for bit the function as it stood
-    before the attrs (copied here), forward and gradient, eager and
-    jitted."""
-    def before(x, theta):
-        t, d = x.shape[1], x.shape[-1]
-        inv = jnp.float32(theta) ** (
-            -jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-        ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
-        cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[None, :, None]
-        sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[None, :, None]
-        xf = x.astype(jnp.float32)
-        x1, x2 = xf[..., :d // 2], xf[..., d // 2:]
-        return (xf * cos + jnp.concatenate([-x2, x1], -1) * sin).astype(
-            x.dtype)
-
-    x = layers.data(name="x", shape=[11, 2, 16], dtype="float32")
-    x.stop_gradient = False
-    out = layers.rotary_embedding(x, theta=1e6)
-    op = fluid.default_main_program().global_block().ops[-1]
-    assert set(op.attrs) - {"op_role", "op_namescope"} == {"theta"}
-    fluid.backward.append_backward(layers.reduce_sum(
-        layers.elementwise_mul(out, out)))
-    xv = np.random.RandomState(1).randn(3, 11, 2, 16).astype(np.float32)
-    got, dx = fluid.Executor(fluid.TPUPlace()).run(
-        fluid.default_main_program(), feed={"x": xv},
-        fetch_list=[out, "x@GRAD"])
-    # through the executor the step is one jitted program, whose fusions
-    # round apart from an eager call's: close here, bit-equal below where
-    # both run the same way
-    want, vjp = jax.vjp(lambda a: before(a, 1e6), jnp.asarray(xv))
-    np.testing.assert_allclose(got, want, atol=1e-6)
-    np.testing.assert_allclose(dx, vjp(2 * want)[0], atol=4e-6)
-    for dtype in (jnp.bfloat16, jnp.float32):
-        z = jnp.asarray(xv, dtype)
-        for run in (lambda f: f, jax.jit):
-            now, back_now = jax.vjp(run(
-                lambda a: decoder_ops.rotary(a, 1e4)), z)
-            was, back_was = jax.vjp(run(lambda a: before(a, 1e4)), z)
-            np.testing.assert_array_equal(np.asarray(now, np.float32),
-                                          np.asarray(was, np.float32))
-            np.testing.assert_array_equal(
-                np.asarray(back_now(now)[0], np.float32),
-                np.asarray(back_was(was)[0], np.float32))
-    assert counters("ops.rotary.calls") == {
-        'ops.rotary.calls{dims="16",pairing="half",scaled="0"}': 1}
-
-
-def test_infer_rule_of_a_partial_rotary_and_its_table():
-    from paddle_tpu.ops.registry import get_infer_rule
-
-    class Op:
-        def __init__(self, **attrs):
-            self.attrs, self.type = attrs, "rotary_embedding"
-            self.inputs = {"X": ["x"]}
-
-        def attr(self, name, default=None):
-            return self.attrs.get(name, default)
-
-    rule = get_infer_rule("rotary_embedding")
-    x = ((2, 16, 4, 128), "bfloat16")
-    assert rule(Op(), {"X": [x]}) == {"Out": [x]}
-    assert rule(Op(start=96, dims=32, interleaved=True,
-                   inv_freq=[0.5] * 16), {"X": [x]}) == {"Out": [x]}
-    for attrs, said in (
-            (dict(start=100, dims=32), "the 32 columns from 100 on"),
-            (dict(start=96, dims=31), "the 31 columns from 96 on"),
-            (dict(start=96, dims=32, inv_freq=[0.5] * 8),
-             "8 frequencies for the 16 pairs")):
-        with pytest.raises(registry.InferMismatch, match=said):
-            rule(Op(**attrs), {"X": [x]})
-    with pytest.raises(registry.InferMismatch, match="an even head width"):
-        rule(Op(), {"X": [((2, 16, 4, 127), "float32")]})
-
-
-def program_digest():
-    """(ops, (how many, sha256 of every op's type, inputs, outputs and
-    attrs in order)) of the default main program's block 0.  The digests
-    below were taken while ``sparse_attention`` and its grad op still
-    carried the per-op request ``flash: -1`` (gone in PR 47; nothing read
-    any other value): it is put back for the hash, so that they stay the
-    digests of the commits they were taken on.  Likewise the loss op had
-    no ``Lse`` output, and its grad op no such input, until PR 50: the slot
-    is asserted here and left out of the hash."""
-    import hashlib
-
-    def attrs(op):
-        gone = {"flash": -1} if op.type.startswith("sparse_attention") else {}
-        return sorted((k, repr(v)) for k, v in {**op.attrs, **gone}.items())
-
-    def slots(op, named):
-        if op.type == "softmax_with_cross_entropy" + \
-                ("_grad" if named is op.inputs else ""):
-            assert len(named["Lse"]) == 1
-            assert named.get("Lse@GRAD", [""]) == [""]   # nobody's cotangent
-            named = {k: v for k, v in named.items()
-                     if k not in ("Lse", "Lse@GRAD")}
-        return sorted((k, list(v)) for k, v in named.items())
-
-    ops = fluid.default_main_program().global_block().ops
-    assert not any("flash" in op.attrs or "fused" in op.attrs for op in ops)
-    text = "\n".join(repr((
-        op.type, slots(op, op.inputs), slots(op, op.outputs), attrs(op)))
-        for op in ops)
-    return ops, (len(ops), hashlib.sha256(text.encode()).hexdigest())
-
-
-#: the three decoder programs at their tiny sizes, as they stood before the
-#: latent mixer, the residual rule and the multi-token module: (ops,
-#: sha256 of every op's type, inputs, outputs and attrs in order)
-PROGRAMS_BEFORE = {
-    "keye_vl_2_0_30b_a3b": (142, "fedca0735a9850ad39adeb9f10ea447ed"
-                                 "78f623517c6b1043c32b501d9c4a405"),
-    "trinity_mini": (448, "0d4c886bcacc9d824b54bb8baa6ae189"
-                          "dfed068f077aa22ea78aa6daca7e28bc"),
-    "lfm2_8b_a1b": (193, "7ddcf33f4599cbcf97621110842376aa"
-                         "f2c49015fc494c29eda5e67e69c7b9e0"),
-}
-
-
-@pytest.mark.parametrize("config", sorted(PROGRAMS_BEFORE))
-def test_programs_without_the_new_fields_are_op_for_op_what_they_were(
-        config):
-    """``latent``, ``residual``, ``mtp_depth`` unset: the builder appends
-    the ops it appended before this kind existed, names, attrs and name
-    scopes included (a digest taken on the commit before)."""
-    sizes = json.load(open(os.path.join(ROOT, "chipbench", "configs", config,
-                                        "config.json")))
-    plugins.load(f"configs/{config}", "build").build(
-        fluid, {**sizes, **sizes["tiny"]})
-    ops, digest = program_digest()
-    assert digest == PROGRAMS_BEFORE[config]
-    assert not {"split", "concat", "expand", "cast"} & {o.type for o in ops}
-    assert not any(set(op.attrs) & {"start", "dims", "interleaved",
-                                    "inv_freq"} for op in ops)
-    assert not counters("models.decoder.blocks{mixer=\"latent\"")
-
-
-def test_config_refuses_a_latent_layer_it_cannot_build():
-    from paddle_tpu.models import decoder_lm
-
-    base = dict(vocab_size=128, hidden_size=64, num_layers=2, num_heads=4,
-                num_kv_heads=4, head_dim=16, expert_width=32, num_routed=8,
-                experts_held=4, experts_per_token=2)
-    latent = decoder_lm.Latent(rank=32, nope=8, rope=8, value=16)
-    cfg = decoder_lm.Config(**base, mixers=["latent"] * 2, latent=latent)
-    assert cfg.latent == latent and cfg.mtp_mixer() == "latent"
-    assert decoder_lm.Config(**base).mtp_mixer() == "attention"
-    assert decoder_lm.Config(**base, latent=tuple(latent)).latent == latent
-    with pytest.raises(ValueError, match="needs the record `latent`"):
-        decoder_lm.Config(**base, mixers=["latent"] * 2)
-    with pytest.raises(ValueError, match="needs the record `latent`"):
-        decoder_lm.Config(**base, mixers=["attention", "attention",
-                                          "latent"], mtp_depth=1)
-    for wrong in (latent._replace(nope=4), latent._replace(value=32),
-                  latent._replace(nope=9, rope=7)):
-        with pytest.raises(ValueError, match="add up to head_dim"):
-            decoder_lm.Config(**base, mixers=["latent"] * 2, latent=wrong)
-    with pytest.raises(ValueError, match="its own key and value"):
-        decoder_lm.Config(**{**base, "num_kv_heads": 2},
-                          mixers=["latent"] * 2, latent=latent)
-    with pytest.raises(ValueError, match="residual 'skip'"):
-        decoder_lm.Config(**base, residual="skip")
-    with pytest.raises(ValueError, match="one module after the trunk"):
-        decoder_lm.Config(**base, mtp_depth=2)
-    assert "latent" in decoder_lm.MIXERS
-
-
-# == three gated-delta-rule mixers to one gated attention layer with a    ==
-# == rotary on a quarter of the head, a softmax router and a gated shared ==
-# == expert: the program against the reference of                         ==
-# == ``chipbench/configs/qwen3_next_80b_a3b``                             ==
-
-QWEN = "configs/qwen3_next_80b_a3b"
-Q_BUILD = plugins.load(QWEN, "build")
-Q_REF = plugins.load(QWEN, "reference")
-
-
-def qwen_sizes(**over):
-    sizes = json.load(open(os.path.join(ROOT, "chipbench", QWEN,
-                                        "config.json")))
-    return {**sizes, **sizes["tiny"], **over}
-
-
-@pytest.mark.parametrize("flash", ["xla", "pallas"])
-def test_delta_program_equals_the_reference_and_its_adam_step(
-        monkeypatch, flash):
-    """Loss, every gradient and every parameter after one Adam step through
-    ``fluid.Executor`` with ``optimizer.minimize``: the CHUNKED rule (four
-    chunks of 16) in three layers against the reference's token-by-token
-    recurrence, then the attention layer with its partial rotary, and in
-    every layer the gated shared expert beside the routed share."""
-    monkeypatch.setenv("PADDLE_TPU_FLASH", "1" if flash == "pallas" else "0")
-    monkeypatch.setattr(psf, "BLOCK", 16)
-    sizes = qwen_sizes()
-    assert sizes["seq_len"] == 4 * sizes["delta_chunk"]
-    assert sizes["num_experts"] < sizes["published"]["num_experts"]
-    built, names, weights = seeded_program(Q_BUILD, Q_REF, sizes)
-    main, scope = fluid.default_main_program(), fluid.global_scope()
-    for i in range(4):
-        mine = {n[3:] for n in names if n.startswith(f"l{i}_")}
-        delta = {"qkvz_w", "ba_w", "conv_w", "dt_bias", "a_log",
-                 "delta_norm"}
-        plain = {"q_w", "q_norm", "k_w", "k_norm", "v_w", "gate_w"}
-        assert (delta <= mine, bool(plain & mine)) == (i < 3, i == 3), i
-        assert {"attn_norm", "o_w", "moe_norm", "shared_w1", "shared_w3",
-                "shared_w2", "shared_gate_w", "router_w", "w1", "w3",
-                "w2"} <= mine
-    feed = Q_BUILD.make_feed(sizes, 2, np.random.RandomState(3))
-    exe = fluid.Executor(fluid.TPUPlace())
-    outs = exe.run(main, feed=feed, fetch_list=[built["loss"]]
-                   + [n + "@GRAD" for n in names])
-    ref_loss, ref_grads = Q_REF.loss_and_grads(weights, feed, sizes)
-    assert float(outs[0].reshape(-1)[0]) == pytest.approx(float(ref_loss),
-                                                          rel=1e-5)
-    for name, g, r in zip(names, outs[1:], ref_grads):
-        g = np.asarray(g).reshape(r.shape)
-        assert np.abs(g - r).max() <= 3e-4 * np.abs(r).max() + 1e-7, name
-        assert np.abs(r).max() > 0, name
-    # one Adam step of every parameter, from the program's own gradient
-    # (where a gradient is near Adam's epsilon the step follows its last
-    # digits, which the two algorithms do not share)
-    for name, w, g in zip(names, weights, outs[1:]):
-        np.testing.assert_allclose(
-            np.asarray(scope.get(name)).reshape(w.shape),
-            Q_REF.optimizer_step(w, jnp.asarray(g).reshape(w.shape), sizes),
-            atol=2e-6, err_msg=name)
-    # what ran, as the counters say it
-    per = 1 if flash == "pallas" else 2
-    assert counters("ops.sparse_attention.calls") == {
-        f'ops.sparse_attention.calls{{path="{flash}",seq="64",'
-        f'topk="0"}}': per}
-    assert not counters("ops.sparse_attention.declined")
-    assert counters("ops.rotary.calls") == {
-        'ops.rotary.calls{dims="4",pairing="half",scaled="0"}': 2}
-    assert counters("ops.delta_rule.calls") == {
-        'ops.delta_rule.calls{chunk="16",dim="8",key_heads="2",path="xla",'
-        'value_heads="4"}': 3}
-    assert counters("ops.short_conv.calls") == {
-        'ops.short_conv.calls{channels="64",gated="0",path="xla",'
-        'taps="4"}': 3}
-    assert counters("models.decoder.blocks") == {
-        'models.decoder.blocks{mixer="delta",residual="sequential",'
-        'where="trunk"}': 3,
-        'models.decoder.blocks{mixer="attention",residual="sequential",'
-        'where="trunk"}': 1}
-    (key, n), = counters("ops.moe.calls").items()
-    assert "score" not in key and 'routed="8"' in key and 'held="4"' in key \
-        and n == 2 * 4
-    # every op under a name; a delta mixer's own under ``.delta``, its two
-    # plain products not
-    scopes = {op.attrs.get("op_namescope", "") for op in main.all_ops()}
-    assert {"embed", "head"} | {f"layer{i}.{part}" for i in range(4)
-                                for part in ("mixer", "ffn")} | {
-        f"layer{i}.mixer.delta" for i in range(3)} == scopes
-    block = main.global_block()
-    for op in block.ops:
-        if op.type in ("short_conv", "gated_delta_rule"):
-            assert op.attrs["op_namescope"].endswith(".mixer.delta")
-        if op.type == "mul" and op.inputs["Y"][0].endswith(
-                ("_qkvz_w", "_ba_w", "_o_w")):
-            assert op.attrs["op_namescope"].endswith(".mixer")
-
-
-def test_the_fp8_control_of_the_delta_cell_misses_what_float32_meets():
-    """The reference with float8 contraction inputs (the state's two reads
-    among them) in the program's place: far outside the tiny limits; the
-    same comparison of the reference with itself reads zero."""
-    from chipbench import check
-
-    sizes = qwen_sizes()
-    weights = Q_REF.init_params(7, sizes)
-    feed = Q_BUILD.make_feed(sizes, 2, np.random.RandomState(3))
-    numbers = check.control(Q_REF, sizes, weights, feed, jnp.float8_e4m3fn)
-    assert check.decide(numbers, sizes["limits"]) is False
-    assert numbers["grad_rel"] > 3 * sizes["limits"]["grad_rel"]
-
-
-@pytest.mark.parametrize("routed,held,k", [(32, 4, 5), (16, 8, 3)])
-def test_the_shares_and_the_gated_shared_expert_once_add_up_to_the_layer(
-        routed, held, k):
-    """A softmax router ``routed`` wide with ``k`` a token, ``held`` by
-    each of ``routed / held`` chips: the program's shares, and the shared
-    expert behind its sigmoid gate counted ONCE, add up to what the cell's
-    reference gives for the UNCUT layer (``experts`` with every expert
-    held)."""
-    rng = np.random.RandomState(2)
-    x, wr, w1, w3, w2 = moe_weights(rng, 48, 16, 8, routed)
-    s1, s3, s2 = (jnp.asarray(0.3 * rng.randn(*s), jnp.float32)
-                  for s in ((16, 8), (16, 8), (8, 16)))
-    wsg = jnp.asarray(rng.randn(16, 1), jnp.float32)
-    c = {"k": k, "offset": 0}
-    with jax.default_matmul_precision("highest"):
-        whole = Q_REF.experts(x, (s1, s3, s2, wsg, wr, w1, w3, w2), c,
-                              lambda a: a)
-        gated = jax.nn.sigmoid(x @ wsg) * Q_REF.feed_forward(x, s1, s3, s2)
-        total = gated
-        for off in range(0, routed, held):
-            part = moe.routed_experts(
-                x, wr, w1[off:off + held], w3[off:off + held],
-                w2[off:off + held], top_k=k, expert_offset=off)
-            mine = Q_REF.routed(x, wr, w1[off:off + held],
-                                w3[off:off + held], w2[off:off + held], k,
-                                off)
-            np.testing.assert_allclose(part, mine, atol=1e-5)
-            total = total + part
-    np.testing.assert_allclose(total, whole, atol=3e-5)
-    assert float(jnp.abs(whole - gated).max()) > 0.1 \
-        and float(jnp.abs(gated).max()) > 0.1
-
-
-#: the two other decoder programs at their tiny sizes as they stood before
-#: the delta mixer: Instella's, and ``decoder_lm.build()``'s own default
-MORE_PROGRAMS_BEFORE = {
-    "instella_moe_16b_a3b": (504, "7a603a8af2c505857474e1823bd3d412"
-                                  "9de0ef9bfad2e68a66506c8b9c8ae455"),
-    "tiny_config": (142, "ea22fd7171f49fe2ec0ac217b545cb95"
-                         "affd0975690a1a2a9e51a1e8662ed90f"),
-}
-
-
-@pytest.mark.parametrize("config", sorted(MORE_PROGRAMS_BEFORE))
-def test_programs_without_delta_rotary_dims_and_shared_gate_are_unchanged(
-        config):
-    """``delta``, ``rotary_dims``, ``shared_gate`` unset: with the three
-    programs of ``PROGRAMS_BEFORE`` (whose digests hold too), the five
-    older programs are op for op what they were on the commit before."""
-    from paddle_tpu.models import decoder_lm
-
-    if config == "tiny_config":
-        decoder_lm.build()
-    else:
-        sizes = json.load(open(os.path.join(ROOT, "chipbench", "configs",
-                                            config, "config.json")))
-        plugins.load(f"configs/{config}", "build").build(
-            fluid, {**sizes, **sizes["tiny"]})
-    ops, digest = program_digest()
-    assert digest == MORE_PROGRAMS_BEFORE[config]
-    assert not {"gated_delta_rule"} & {o.type for o in ops}
-    assert not any("gated" in op.attrs for op in ops)
-    assert not counters("models.decoder.blocks{mixer=\"delta\"")
-
-
-def test_config_refuses_a_delta_layer_it_cannot_build():
-    from paddle_tpu.models import decoder_lm
-
-    base = dict(vocab_size=128, hidden_size=64, num_layers=2, num_heads=4,
-                num_kv_heads=2, head_dim=16, expert_width=32, num_routed=8,
-                experts_held=4, experts_per_token=2)
-    delta = decoder_lm.Delta(key_heads=2, value_heads=4, key_dim=8,
-                             value_dim=8)
-    cfg = decoder_lm.Config(**base, mixers=["delta", "attention"],
-                            delta=delta, rotary_dims=4, shared_width=32,
-                            shared_gate=True)
-    assert cfg.delta == delta and (delta.taps, delta.chunk) == (4, 64)
-    assert [cfg.layer_mixer(i) for i in range(2)] == ["delta", "attention"]
-    assert decoder_lm.Config(**base, delta=tuple(delta)).delta == delta
-    plain = decoder_lm.Config(**base)
-    assert (plain.delta, plain.rotary_dims, plain.shared_gate) == (
-        None, 0, False)
-    with pytest.raises(ValueError, match="needs the record `delta`"):
-        decoder_lm.Config(**base, mixers=["delta"] * 2)
-    with pytest.raises(ValueError, match="a multiple of the key"):
-        decoder_lm.Config(**base, mixers=["delta"] * 2,
-                          delta=delta._replace(value_heads=3))
-    with pytest.raises(ValueError, match="hold a token at least"):
-        decoder_lm.Config(**base, mixers=["delta"] * 2,
-                          delta=delta._replace(chunk=0))
-    for wrong in (3, 18, -2):
-        with pytest.raises(ValueError, match="an even part of the head"):
-            decoder_lm.Config(**base, rotary_dims=wrong)
-    with pytest.raises(ValueError, match="needs shared_width"):
-        decoder_lm.Config(**base, shared_gate=True)
-    assert "delta" in decoder_lm.MIXERS
-
-
-# == three window layers to one global layer whose rotary is a YaRN table ==
-# == of its own at a softmax scale of its own, a softmax router and no    ==
-# == other feed-forward: the program against the reference of             ==
-# == ``chipbench/configs/mellum2_12b_a2_5b``                              ==
-
-MELLUM = "configs/mellum2_12b_a2_5b"
-M_BUILD = plugins.load(MELLUM, "build")
-M_REF = plugins.load(MELLUM, "reference")
-
-
-def mellum_sizes(**over):
-    sizes = json.load(open(os.path.join(ROOT, "chipbench", MELLUM,
-                                        "config.json")))
-    return {**sizes, **sizes["tiny"], **over}
-
-
-@pytest.mark.parametrize("flash", ["xla", "pallas"])
-def test_rotary_by_layer_kind_program_equals_the_reference_and_its_adam_step(
-        monkeypatch, flash):
-    """Loss, every gradient and every parameter after one Adam step through
-    ``fluid.Executor`` with ``optimizer.minimize``: three window layers of
-    16 keys over 64 tokens (a band of 3 tiles of 8, one interior, as 1,024
-    over tiles of 512) that rotate by ``rope_theta``, then the global layer
-    that rotates by the YaRN table at the YaRN scale; the reference makes
-    both tables itself.  With the two tables SWAPPED in the reference the
-    same gradients are far off."""
-    monkeypatch.setenv("PADDLE_TPU_FLASH", "1" if flash == "pallas" else "0")
-    monkeypatch.setattr(psf, "BLOCK", 8)
-    sizes = mellum_sizes()
-    assert sizes["seq_len"] == 4 * sizes["sliding_window"]
-    assert sizes["num_experts"] < sizes["published"]["num_experts"]
-    assert sizes["layer_types"][:4] == ["sliding_attention"] * 3 \
-        + ["full_attention"]
-    built, names, weights = seeded_program(M_BUILD, M_REF, sizes)
-    main, scope = fluid.default_main_program(), fluid.global_scope()
-    assert {n[3:] for n in names if n.startswith("l3_")} == {
-        "attn_norm", "q_w", "q_norm", "k_w", "k_norm", "v_w", "o_w",
-        "moe_norm", "router_w", "w1", "w3", "w2"}
-    feed = M_BUILD.make_feed(sizes, 2, np.random.RandomState(3))
-    outs = fluid.Executor(fluid.TPUPlace()).run(
-        main, feed=feed, fetch_list=[built["loss"]]
-        + [n + "@GRAD" for n in names])
-    ref_loss, ref_grads = M_REF.loss_and_grads(weights, feed, sizes)
-    assert float(outs[0].reshape(-1)[0]) == pytest.approx(float(ref_loss),
-                                                          rel=1e-5)
-    for name, g, r in zip(names, outs[1:], ref_grads):
-        g = np.asarray(g).reshape(r.shape)
-        assert np.abs(g - r).max() <= 3e-4 * np.abs(r).max() + 1e-7, name
-        assert np.abs(r).max() > 0, name
-    for name, w, g in zip(names, weights, outs[1:]):
-        np.testing.assert_allclose(
-            np.asarray(scope.get(name)).reshape(w.shape),
-            M_REF.optimizer_step(w, jnp.asarray(g).reshape(w.shape), sizes),
-            atol=2e-6, err_msg=name)
-    # the tables the other way round: no such model
-    rope = sizes["rope_parameters"]
-    swapped = {**sizes, "rope_parameters": {
-        "full_attention": rope["sliding_attention"],
-        "sliding_attention": rope["full_attention"]}}
-    from chipbench import check
-
-    def grad_rel(loss, grads):      # the number that decides ``correct``
-        return float(check._errors(outs[0], outs[1:], loss,
-                                   grads)["grad_rel"])
-
-    assert grad_rel(ref_loss, ref_grads) < 1e-4
-    assert grad_rel(*M_REF.loss_and_grads(weights, feed, swapped)) > 0.1
-    # what ran, as the counters say it
-    per = 1 if flash == "pallas" else 2
-    assert counters("ops.sparse_attention.calls") == {
-        f'ops.sparse_attention.calls{{path="{flash}",seq="64",topk="0",'
-        f'window="16"}}': 3 * per,
-        f'ops.sparse_attention.calls{{path="{flash}",seq="64",'
-        f'topk="0"}}': per}
-    assert not counters("ops.sparse_attention.declined")
-    assert counters("ops.rotary.calls") == {
-        'ops.rotary.calls{dims="16",pairing="half",scaled="0"}': 6,
-        'ops.rotary.calls{dims="16",pairing="half",scaled="1"}': 2}
-    assert counters("models.decoder.rotary") == {
-        'models.decoder.rotary{kind="global",scope="layer3",'
-        'table="given"}': 1}
-    assert counters("models.decoder.blocks") == {
-        'models.decoder.blocks{mixer="attention",residual="sequential",'
-        'where="trunk"}': 4}
-    (key, n), = counters("ops.moe.calls").items()
-    assert "score" not in key and 'routed="8"' in key and 'held="4"' in key \
-        and n == 2 * 4
-    if flash == "pallas":       # 8 rows of tiles: 1 + 2 + 6 x 3, 7 interior
-        tiles = counters("ops.sparse_attention.tiles")
-        for kernel in ("fwd", "dq", "dkv"):
-            assert [tiles[f'ops.sparse_attention.tiles{{kernel="window_flash_'
-                          f'{kernel}",kind="{kind}"}}']
-                    for kind in ("interior", "edge")] == [3 * 7, 3 * 14]
-    # every op under a name; all of the global layer's mixer but the
-    # residual add under ``.global``, no window layer's
-    scopes = {op.attrs.get("op_namescope", "") for op in main.all_ops()}
-    assert {"embed", "head", "layer3.mixer.global"} | {
-        f"layer{i}.{part}" for i in range(4)
-        for part in ("mixer", "ffn")} == scopes
-
-
-def test_window_layers_lower_theta_and_the_global_layer_the_record():
-    """A window layer's two rotaries carry ``theta`` and no table, its
-    attention ``head_dim ** -0.5``; the global layer's carry the record's
-    frequencies, its attention the record's scale."""
-    sizes = mellum_sizes()
-    M_BUILD.build(fluid, sizes)
-    record = M_BUILD.config_of(sizes).global_rotary
-    assert record == M_BUILD.global_rotary(sizes)
-    block = fluid.default_main_program().global_block()
-    seen = {"window": 0, "global": 0}
-    for op in block.ops:
-        if op.type not in ("rotary_embedding", "sparse_attention"):
-            continue
-        own = op.attrs["op_namescope"] == "layer3.mixer.global"
-        seen["global" if own else "window"] += 1
-        if op.type == "rotary_embedding":
-            assert op.attrs["theta"] == 100.0
-            assert op.attrs.get("inv_freq") == (
-                list(record.inv_freq) if own else None)
-        else:
-            assert op.attrs.get("window", 0) == (0 if own else 16)
-            assert op.attrs["scale"] == pytest.approx(
-                record.scale if own else 16 ** -0.5, rel=1e-12)
-    assert seen == {"window": 9, "global": 3}
-    assert record.scale == pytest.approx(0.25 * 1.63139, rel=1e-5)
-
-
-def test_the_yarn_table_by_hand():
-    """Mellum2's ``full_attention`` table at the published sizes: pairs
-    0-18 turn more than 32 times over 8,192 positions and stay as they are,
-    pairs 35-63 turn less than once and are divided by 16, a linear blend
-    between; the temperature is ``(0.1 ln 16 + 1) ** 2`` on ``128 **
-    -0.5``.  The builder's record and the reference's own table agree."""
-    sizes = json.load(open(os.path.join(ROOT, "chipbench", MELLUM,
-                                        "config.json")))
-    record = M_BUILD.config_of(sizes).global_rotary
-    plain = [500000.0 ** (-2.0 * j / 128) for j in range(64)]
-    assert len(record.inv_freq) == 64
-    np.testing.assert_allclose(record.inv_freq[:19], plain[:19], rtol=1e-12)
-    np.testing.assert_allclose(record.inv_freq[35:],
-                               [f / 16 for f in plain[35:]], rtol=1e-12)
-    for j in range(19, 35):
-        r = (j - 18) / 17
-        assert record.inv_freq[j] == pytest.approx(
-            plain[j] / 16 * r + plain[j] * (1 - r), rel=1e-12)
-        assert plain[j] / 16 < record.inv_freq[j] < plain[j]
-    m = 1.2772588722239782
-    assert m == pytest.approx(0.1 * math.log(16) + 1, rel=1e-15)
-    assert m * m == pytest.approx(1.63139, rel=1e-5)
-    assert record.scale == pytest.approx(128 ** -0.5 * m * m, rel=1e-12)
-    table, factor = M_REF.rotary_table(
-        sizes["rope_parameters"]["full_attention"], 128)
-    np.testing.assert_allclose(table, record.inv_freq, rtol=1e-12)
-    assert factor == m
-    assert M_REF.rotary_table(
-        sizes["rope_parameters"]["sliding_attention"], 128) == (
-            tuple(plain), 1.0)
-    rope = sizes["rope_parameters"]
-    with pytest.raises(ValueError, match="is not the 0.1 ln"):
-        M_BUILD.config_of({**sizes, "rope_parameters": {
-            **rope, "full_attention": {**rope["full_attention"],
-                                       "attention_factor": 1.2}}})
-    with pytest.raises(ValueError, match="a plain table for the window"):
-        M_BUILD.config_of({**sizes, "rope_parameters": {
-            **rope, "sliding_attention": rope["full_attention"]}})
-
-
-def test_config_refuses_a_global_rotary_it_cannot_build():
-    from paddle_tpu.models import decoder_lm
-
-    base = dict(vocab_size=128, hidden_size=64, num_layers=4, num_heads=4,
-                num_kv_heads=2, head_dim=16, expert_width=32, num_routed=8,
-                experts_held=4, experts_per_token=2, window=16,
-                global_every=4)
-    record = decoder_lm.Rotary(inv_freq=tuple(0.5 ** j for j in range(8)),
-                               scale=0.3)
-    cfg = decoder_lm.Config(**base, global_rotary=record)
-    assert cfg.global_rotary == record
-    assert decoder_lm.Config(
-        **base, global_rotary=(list(record.inv_freq), 0.3)
-    ).global_rotary == record
-    assert decoder_lm.Rotary(record.inv_freq).scale == 0.0
-    assert decoder_lm.Config(**base).global_rotary is None
-    with pytest.raises(ValueError, match="goes without positions"):
-        decoder_lm.Config(**base, rope_global=False, global_rotary=record)
-    with pytest.raises(ValueError, match="each of the 8 pairs"):
-        decoder_lm.Config(**base, global_rotary=record._replace(
-            inv_freq=record.inv_freq[:4]))
-    assert decoder_lm.Config(
-        **base, rotary_dims=8, global_rotary=record._replace(
-            inv_freq=record.inv_freq[:4])).global_rotary.scale == 0.3
-
-
-@pytest.mark.parametrize("config", sorted(PROGRAMS_BEFORE)
-                         + sorted(MORE_PROGRAMS_BEFORE)
-                         + ["qwen3_next_80b_a3b"])
-def test_programs_without_a_global_rotary_have_no_scope_or_counter_of_it(
-        config):
-    """``global_rotary`` unset: no op under ``.global``, no rotary from a
-    table in a plain attention layer, no ``models.decoder.rotary`` (the six
-    older programs' digests, above, hold too)."""
-    from paddle_tpu.models import decoder_lm
-
-    if config == "tiny_config":
-        decoder_lm.build()
-    else:
-        sizes = json.load(open(os.path.join(ROOT, "chipbench", "configs",
-                                            config, "config.json")))
-        plugins.load(f"configs/{config}", "build").build(
-            fluid, {**sizes, **sizes["tiny"]})
-    ops = list(fluid.default_main_program().all_ops())
-    assert ops and not any(
-        op.attrs.get("op_namescope", "").endswith(".global") for op in ops)
-    assert not counters("models.decoder.rotary")
+# the references that ``reference_router`` names and whose sections are in
+# tests/test_decoder_lm_mixers.py (split off so that no one file holds half
+# of tier-1's time: xdist hands out whole files)
+I_REF = plugins.load("configs/instella_moe_16b_a3b", "reference")
+M_REF = plugins.load("configs/mellum2_12b_a2_5b", "reference")

@@ -1,7 +1,8 @@
 """The gated delta rule (ops/delta_rule.py, the op ``gated_delta_rule`` and
-its grad op) and the ungated form of ``short_conv``: the chunked
-computation against the token-by-token recurrence, value and every
-cotangent, in float32 on the CPU."""
+its grad op), under a decay a value head and under one a key channel, and
+the ungated form of ``short_conv``: the chunked computation against the
+token-by-token recurrence, value and every cotangent, in float32 on the
+CPU."""
 
 import contextlib
 
@@ -336,18 +337,20 @@ def test_the_grad_op_equals_jax_grad_of_the_forward(t):
         'ops.delta_rule.grad_calls{chunk="16",path="by_hand"}': 1}
 
 
+class Op:
+    """What an infer rule reads of the op ``gated_delta_rule``."""
+
+    def __init__(self, **attrs):
+        self.attrs, self.type = attrs, "gated_delta_rule"
+        self.inputs = {s: [s.lower()] for s in ("Q", "K", "V", "G", "Beta")}
+
+    def attr(self, name, default=None):
+        return self.attrs.get(name, default)
+
+
 def test_infer_rule_and_layer_of_the_delta_rule():
     from paddle_tpu import analysis
     from paddle_tpu.ops.registry import get_infer_rule
-
-    class Op:
-        def __init__(self, **attrs):
-            self.attrs, self.type = attrs, "gated_delta_rule"
-            self.inputs = {s: [s.lower()] for s in ("Q", "K", "V", "G",
-                                                    "Beta")}
-
-        def attr(self, name, default=None):
-            return self.attrs.get(name, default)
 
     rule = get_infer_rule("gated_delta_rule")
     q, v = ((2, 64, 16, 128), "bfloat16"), ((2, 64, 32, 128), "bfloat16")
@@ -371,6 +374,197 @@ def test_infer_rule_and_layer_of_the_delta_rule():
     op = fluid.default_main_program().global_block().ops[-1]
     assert op.type == "gated_delta_rule" and "norm_eps" not in op.attrs \
         and op.attrs["chunk"] == 16 and op.attrs["scale"] == 0.0
+
+
+# -- a decay that is a vector along the key ---------------------------------
+
+def channel_recurrence(q, k, v, g, beta, scale):
+    """The rule token by token with ``g`` [B, T, H, dk]: the state's ROWS
+    decay, each key channel by its own number.  q, k: [B, T, H, dk]."""
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+
+    def token(state, x):
+        q_t, k_t, v_t, g_t, b_t = x
+        state = jnp.exp(g_t)[..., None] * state
+        u = b_t[..., None] * (v_t - jnp.einsum("bhkv,bhk->bhv", state, k_t))
+        state = state + jnp.einsum("bhk,bhv->bhkv", k_t, u)
+        return state, jnp.einsum("bhkv,bhk->bhv", state, q_t * scale)
+
+    xs = tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta))
+    _, out = jax.lax.scan(token, jnp.zeros((b, h, dk, dv), jnp.float32), xs)
+    return jnp.moveaxis(out, 0, 1)
+
+
+def channel_operands(t, decay, seed=0, b=2, h=3, dk=8, dv=12):
+    q, k, v, _, beta = operands(t, seed=seed, b=b, hk=h, hv=h, dk=dk, dv=dv)
+    g = -decay * np.random.RandomState(seed + 1).rand(b, t, h, dk)
+    return q, k, v, jnp.asarray(g, jnp.float32), beta
+
+
+#: (tokens, chunk, decay): g down to -decay a token and channel
+CHANNEL_CASES = {
+    "whole_64_by_16": (64, 16, 0.5),
+    "ragged_53_by_16": (53, 16, 0.5),
+    "published_chunk_and_sub": (150, 64, 0.5),
+    "sub_blocks_of_two": (40, 8, 0.5),
+    "sub_blocks_of_one": (21, 4, 0.5),
+    # a chunk's G reaches -240 and -3,000: exp(-G) is inf in float32 (over
+    # 88.7), so no form that divides by a decay could give these
+    "exp_of_minus_g_overflows": (64, 16, 30.0),
+    "exp_of_minus_g_overflows_ragged_by_64": (150, 64, 100.0),
+}
+
+
+@pytest.mark.parametrize("case", CHANNEL_CASES)
+def test_channel_decay_equals_the_recurrence_and_all_five_cotangents(case):
+    """The chunked form under a decay a key channel, its sub-block scores
+    and ``jax.vjp`` of it against the recurrence and ``jax.grad`` of that,
+    at a chunk that does and does not divide T."""
+    t, chunk, decay = CHANNEL_CASES[case]
+    xs = channel_operands(t, decay, seed=t)
+    if decay > 1:
+        worst = float(jnp.min(jnp.cumsum(xs[3][:, :chunk], 1)))
+        assert not np.isfinite(np.exp(np.float32(-worst)))
+
+    def rule(*a):
+        return delta_rule.chunked(*a, chunk=chunk, scale=0.3)
+
+    def stated(*a):
+        return channel_recurrence(*a, 0.3)
+
+    want, got = jax.jit(stated)(*xs), jax.jit(rule)(*xs)
+    wants = jax.jit(jax.grad(weighted_sum(stated), range(5)))(*xs)
+    grads = jax.jit(jax.grad(weighted_sum(rule), range(5)))(*xs)
+    assert got.shape == want.shape and got.dtype == xs[2].dtype
+    assert [g.shape for g in grads] == [a.shape for a in xs]
+    for g in (got,) + grads:
+        assert bool(jnp.isfinite(g).all())
+    near = 2e-4 if decay > 1 else 2e-5
+    np.testing.assert_allclose(got, want, atol=near * float(
+        jnp.abs(want).max()))
+    for name, g, w in zip("q k v g beta".split(), grads, wants):
+        np.testing.assert_allclose(g, w, atol=near * float(
+            jnp.abs(w).max()) + 1e-6, err_msg=name)
+
+
+@pytest.mark.parametrize("hk,hv", [(2, 2), (2, 4)])
+def test_a_channel_decay_constant_along_the_key_is_the_scalar_path(hk, hv):
+    """``g`` [B, T, Hv] spread over the key channels: the channel path
+    gives what the scalar path gives, value and cotangents (g's summed over
+    the channels), a key head serving two value heads too."""
+    q, k, v, g, beta = operands(53, seed=3, hk=hk, hv=hv)
+    spread = jnp.broadcast_to(g[..., None], g.shape + (q.shape[-1],))
+
+    def scalar(*a):
+        return delta_rule.chunked(*a, chunk=16)
+
+    def channel(q, k, v, g, beta):
+        return delta_rule.chunked(q, k, v, g, beta, chunk=16)
+
+    np.testing.assert_allclose(jax.jit(channel)(q, k, v, spread, beta),
+                               jax.jit(scalar)(q, k, v, g, beta), atol=5e-6)
+    want = jax.jit(jax.grad(weighted_sum(scalar), range(5)))(
+        q, k, v, g, beta)
+    got = jax.jit(jax.grad(weighted_sum(channel), range(5)))(
+        q, k, v, spread, beta)
+    got = got[:3] + (jnp.sum(got[3], -1), got[4])
+    for name, a, w in zip("q k v g beta".split(), got, want):
+        np.testing.assert_allclose(a, w, atol=2e-5 * float(
+            jnp.abs(w).max()) + 1e-6, err_msg=name)
+
+
+def test_no_tensor_of_a_whole_chunks_pairs_by_channel_is_ever_made():
+    """Forward and backward at the published chunk, whose sub-blocks are
+    the published 16: the largest thing with a key-channel axis beside two
+    token axes is the diagonal tiles' [C / sub, sub, sub, dk]; nothing is
+    [C, C, dk] a chunk, and a chunk that is not four sub-blocks is refused
+    under a channel decay alone."""
+    c, dk = 64, 8
+    sub = delta_rule.sub_block(c)
+    assert sub == 16
+    xs = channel_operands(2 * c, 0.5, b=1, h=2, dk=dk, dv=4)
+
+    def rule(*a):
+        return delta_rule.chunked(*a, chunk=c)
+
+    def shapes(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            out.update(tuple(v.aval.shape) for v in eqn.outvars)
+            for sub_jaxpr in jax.core.jaxprs_in_params(eqn.params):
+                shapes(sub_jaxpr, out)
+        return out
+
+    seen = shapes(jax.make_jaxpr(jax.grad(weighted_sum(rule), range(5)))(
+        *xs).jaxpr, set())
+    assert any(s[-4:] == (c // sub, sub, sub, dk) for s in seen)
+    assert not any(len(s) >= 3 and s[-3:] == (c, c, dk) for s in seen)
+    assert not any(len(s) >= 4 and s[-4:-1] == (c // sub, sub, c)
+                   and s[-1] == dk for s in seen)
+    with pytest.raises(ValueError, match="is not cut into 4 sub-blocks"):
+        delta_rule.chunked(*xs, chunk=6)
+    scalar = operands(53, seed=1)
+    assert delta_rule.chunked(*scalar, chunk=6).shape == scalar[2].shape
+
+
+@pytest.mark.parametrize("t", [16, 53])
+def test_the_op_and_its_grad_op_under_a_channel_decay(t):
+    """The op with G [B, T, Hv, dk] through the executor against the
+    recurrence and ``jax.grad`` of it; counted as a call, a channel call
+    and a backward that is ``jax.vjp`` of the chunked forward."""
+    names = ("q", "k", "v", "g", "beta")
+    shapes = ([t, 3, 8], [t, 3, 8], [t, 3, 12], [t, 3, 8], [t, 3])
+    data = [layers.data(name=n, shape=s, dtype="float32")
+            for n, s in zip(names, shapes)]
+    for d in data:
+        d.stop_gradient = False
+    out = layers.gated_delta_rule(*data, chunk=16, scale=0.3)
+    assert tuple(out.shape[1:]) == (t, 3, 12)
+    op = fluid.default_main_program().global_block().ops[-1]
+    assert "sub" not in op.attrs and op.attrs["chunk"] == 16
+    weights = np.cos(np.arange(12, dtype="float32"))
+    loss = layers.reduce_sum(layers.elementwise_mul(
+        out, layers.assign(weights)))
+    fluid.backward.append_backward(loss)
+    xs = channel_operands(t, 0.5, seed=t)
+    got = fluid.Executor(fluid.TPUPlace()).run(
+        feed={n: np.asarray(x) for n, x in zip(names, xs)},
+        fetch_list=[out] + [n + "@GRAD" for n in names])
+    np.testing.assert_allclose(got[0], channel_recurrence(*xs, 0.3),
+                               atol=1e-5)
+    want = jax.jit(jax.grad(
+        lambda *a: jnp.sum(channel_recurrence(*a, 0.3) * weights),
+        range(5)))(*xs)
+    for name, g, w in zip(names, got[1:], want):
+        np.testing.assert_allclose(g, w, atol=2e-5 * float(
+            jnp.abs(w).max()) + 1e-6, err_msg=name)
+    assert {k: v for k, v in fluid.profiler.counters().items()
+            if k.startswith("ops.delta_rule")} == {
+        'ops.delta_rule.calls{chunk="16",dim="12",key_heads="3",path="xla",'
+        'value_heads="3"}': 1,
+        'ops.delta_rule.channel_calls{chunk="16",dim="8",key_heads="3",'
+        'sub="4"}': 1,
+        'ops.delta_rule.grad_calls{chunk="16",path="vjp"}': 1}
+
+
+def test_infer_rule_takes_a_decay_a_key_channel():
+    from paddle_tpu.ops.registry import get_infer_rule
+
+    rule = get_infer_rule("gated_delta_rule")
+    q, v = ((2, 64, 16, 128), "bfloat16"), ((2, 64, 32, 96), "bfloat16")
+    ins = {"Q": [q], "K": [q], "V": [v],
+           "G": [((2, 64, 32, 128), "float32")],
+           "Beta": [((2, 64, 32), "float32")]}
+    assert rule(Op(chunk=64), ins) == {"Out": [v]}
+    assert rule(Op(), ins) == {"Out": [v]}
+    for wrong in ((2, 64, 32, 96), (2, 64, 16, 128), (2, 64, 32, 1)):
+        with pytest.raises(registry.InferMismatch,
+                           match="or \\[2, 64, 32, 128\\], one a key channel"):
+            rule(Op(), {**ins, "G": [(wrong, "float32")]})
+    with pytest.raises(registry.InferMismatch, match="one number a token"):
+        rule(Op(), {**ins, "Beta": [((2, 64, 32, 128), "float32")]})
+    with pytest.raises(registry.InferMismatch, match="is not positive"):
+        rule(Op(chunk=0), ins)
 
 
 # -- the filter alone, followed by SiLU -------------------------------------
