@@ -19,9 +19,9 @@ filter and SiLU alone;
 ``gated_delta_rule`` lowered, its backward not counted there but as
 ``ops.delta_rule.grad_calls{chunk,path="by_hand"}`` for every
 ``gated_delta_rule_grad`` lowered (``by_hand``: the backward written out in
-``ops/delta_rule.py``, no autodiff through the walk over the chunks;
-``path="vjp"`` where G is a decay a key channel, whose backward is
-``jax.vjp`` of its chunked forward), and
+``ops/delta_rule.py``, no autodiff through the walk over the chunks, the
+inverse or the scores, under a decay a value head and under one a key
+channel alike), and
 ``ops.delta_rule.channel_calls{key_heads,dim,chunk,sub}`` beside ``calls``
 for every forward lowered with such a G;
 ``ops.moe.row_moves{pass,how="gather"}``, which ``parallel/moe.py`` counts
@@ -446,15 +446,15 @@ def gated_delta_rule_op(ctx):
 @register_grad("gated_delta_rule")
 def gated_delta_rule_grad(ctx):
     """From the op's five inputs alone, by the backward ``delta_rule.chunked``
-    carries (written by hand, nothing differentiates through its walk; under
-    a decay a key channel, ``jax.vjp`` of the chunked forward):
-    everything a chunk needs (decays, the inverse, what each token writes)
-    and the state at every chunk's start are made again, the outputs are
-    not, then the chunks are walked backwards; nothing but the inputs is
-    kept from the forward."""
+    carries (a ``jax.custom_vjp`` written by hand under either kind of
+    decay, which ``jax.vjp`` below meets: nothing differentiates through
+    the walk, the inverse or the scores): everything a chunk needs (decays,
+    the inverse, what each token writes) and the state at every chunk's
+    start are made again, the outputs are not, then the chunks are walked
+    backwards; nothing but the inputs is kept from the forward."""
     rule, operands = _delta_rule(ctx)
     _count("ops.delta_rule.grad_calls", chunk=int(ctx.attr("chunk", 64)),
-           path="vjp" if operands[3].ndim == 4 else "by_hand")
+           path="by_hand")
     # behind a barrier with the cotangent in it, as ``jax.checkpoint`` puts
     # one: without it XLA finds the second forward to be the first and
     # keeps a gigabyte a layer (every chunk's state, inverse and writes)

@@ -99,10 +99,52 @@ a, ``G_i - G_j = (G_i - G_s) + (G_s - G_j)``, both <= 0, so those tiles are
 products of ``x * exp(G - G_s)`` rows with ``k * exp(G_s - G)`` rows; the
 SUB_BLOCKS diagonal tiles alone are made element by element, [sub, sub, dk]
 each, masked before the exponential.
-Scores and diagonal tiles are a checkpoint: the backward makes them again
-and keeps neither.  This path's backward is ``jax.vjp`` of the chunked
-forward (a scan's transpose for the walk, the inverse's levels
-differentiated as they are); the scalar path above is untouched by it.
+
+Its backward (``_channel_rule``'s ``jax.custom_vjp``, PR 53) is the scalar
+one with every decay moved to the key side: the five operands alone are
+kept, ``_channel_system`` and ``_channel_walk`` are made again (scores and
+diagonal tiles with them: nothing of them is kept), ``P^T dO`` and
+``(q * gamma)^T dO`` are made for all chunks at once, and ONE walk from the
+last chunk to the first carries ``dS``, two products a step
+(``E = k * exp(G_C - G)``, what is left of each token's key at the chunk's
+end)::
+
+    dV'  = P^T dO + E dS
+    dS  <- (q * gamma)^T dO + Diag(exp(G_C)) dS - W^T dV'
+
+and the rest is made for all chunks at once::
+
+    dU   = dV',   dW = -dV' S^T
+    d(beta * v) = T^T dU,   d(beta * gamma * k) = T^T dW
+    dA   = -d(beta * v) U^T - d(beta * gamma * k) W^T    kept where i > j
+    dkk_ij = beta_i dA_ij,   dP = dO V'^T                (its upper half
+                                                         is never read)
+    for M_ij = sum_c x_ic k_jc exp(G_ic - G_jc), x = k (kk) and x = q (P):
+      dx_ic = sum_j dM_ij k_jc exp(G_ic - G_jc)
+      dk_jc = sum_i dM_ij x_ic exp(G_ic - G_jc)
+      dG_ic += x_ic dx_ic,   dG_jc -= k_jc dk_jc
+    dq   = dx(P) + gamma * (dO S^T)
+    dk   = dx(kk) + dk(kk) + dk(P) + exp(G_C - G) * de
+           + beta * gamma * d(beta gamma k),             de = V' dS^T
+    dv   = beta * d(beta * v)
+    dbeta_i = d(beta v)_i . v_i + (gamma * d(beta gamma k))_i . k_i
+              + sum_j dA_ij kk_ij
+    dG  += q * gamma * (dO S^T) + beta * gamma * k * d(beta gamma k)
+           - E * de
+    dG_C += sum_i (E * de)_i + exp(G_C) * sum_v dS * S   by state row
+    dg   = the running sum of dG from the chunk's end
+
+``dx`` and ``dk`` go through the split the scores were made by
+(``_pair_scores_bwd``): off the diagonal tiles ``dx = exp(G - G_s) * (dM
+right)`` with the same ``right`` rows, and ``dk`` of a token of an earlier
+sub-block is ``dM^T (x * exp(G - G_s))`` times that token's own
+``exp(G_s - G)``; the SUB_BLOCKS diagonal tiles element by element,
+[sub, sub, dk] each, masked before the exponential.  ``G``'s cotangent
+needs no pass of its own: every term that holds an ``exp`` of ``G`` comes
+back multiplied by that same ``exp``, nothing is divided by a decay and no
+exponent is positive.  The two rules share the inverse, its closed-form
+cotangent and the helpers, and no walk: the scalar path above is untouched
+by this one.
 """
 
 from __future__ import annotations
@@ -306,29 +348,45 @@ def _rule_bwd(low, operands, dout):
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
-def _pair_scores(low, xs, kc, gsum):
-    """``sum_c x_ic k_jc exp(G_ic - G_jc)`` where ``i >= j``, else 0, for
-    every ``x`` of ``xs``: [..., C, C] each from x, kc, gsum [..., C, dk].
-    No exponent is positive and nothing [C, C, dk] is made: the module's
-    docstring has the split."""
+def _split(kc, gsum):
+    """A chunk's decays about its sub-blocks' edges, from kc, gsum
+    [..., C, dk]: ``to_edge`` [..., C, dk], each token's decay since the
+    last token before its sub-block; ``from_edge`` [..., ns, C, dk], what
+    is left at that edge of sub-block a of a token of an EARLIER sub-block
+    (0 for every other token); ``within`` [..., ns, sub, sub, dk],
+    ``exp(G_i - G_j)`` inside a sub-block where ``i >= j``, else 0; and the
+    shape [..., ns, sub, dk] of a chunk cut into its sub-blocks.  Every
+    mask comes before its exponential."""
     c, dk = kc.shape[-2:]
     ns, sub = SUB_BLOCKS, sub_block(c)
-    lead = kc.shape[:-2]
     at = jnp.arange(c)
     # G at the last token before each sub-block (0 before the first)
     edge = jnp.concatenate([jnp.zeros_like(gsum[..., :1, :]),
                             gsum[..., sub - 1:c - 1:sub, :]], -2)
     to_edge = jnp.exp(gsum - jnp.repeat(edge, sub, -2))         # [.., C, dk]
     earlier = at[None, :] < (jnp.arange(ns) * sub)[:, None]     # [ns, C]
-    right = kc[..., None, :, :] * jnp.exp(jnp.where(
+    from_edge = jnp.exp(jnp.where(
         earlier[:, :, None],
         edge[..., :, None, :] - gsum[..., None, :, :], -jnp.inf))
-    tiles = lead + (ns, sub, dk)
-    gd, kd = gsum.reshape(tiles), kc.reshape(tiles)
+    tiles = kc.shape[:-2] + (ns, sub, dk)
+    gd = gsum.reshape(tiles)
     i, j = jnp.arange(sub)[:, None, None], jnp.arange(sub)[None, :, None]
     within = jnp.exp(jnp.where(
         i >= j, gd[..., :, None, :] - gd[..., None, :, :], -jnp.inf))
-    eye = jnp.eye(ns, dtype=jnp.float32)
+    return to_edge, from_edge, within, tiles
+
+
+def _pair_scores(low, xs, kc, gsum):
+    """``sum_c x_ic k_jc exp(G_ic - G_jc)`` where ``i >= j``, else 0, for
+    every ``x`` of ``xs``: [..., C, C] each from x, kc, gsum [..., C, dk].
+    No exponent is positive and nothing [C, C, dk] is made: the module's
+    docstring has the split."""
+    c = kc.shape[-2]
+    lead = kc.shape[:-2]
+    to_edge, from_edge, within, tiles = _split(kc, gsum)
+    right = kc[..., None, :, :] * from_edge
+    kd = kc.reshape(tiles)
+    eye = jnp.eye(SUB_BLOCKS, dtype=jnp.float32)
     out = []
     for x in xs:
         off = _dot(low, "...aid,...ajd->...aij",
@@ -340,20 +398,64 @@ def _pair_scores(low, xs, kc, gsum):
     return out
 
 
-def _channel_rule(low, qc, kc, vc, gc, bc):
-    """The rule under a decay a key channel.  qc, kc, gc: [n,B,H,C,dk]; vc:
-    [n,B,H,C,dv]; bc: [n,B,H,C]; all float32 -> [n,B,H,C,dv] float32."""
+def _pair_scores_bwd(low, xs, kc, gsum, dms):
+    """``_pair_scores``'s cotangents through the same split: for every
+    ``x`` of ``xs`` and its ``dm`` [..., C, C] (the cotangent of its
+    scores; what it holds above the diagonal is never read, ON the diagonal
+    it counts), ``(every dx, dk, dgsum)`` with ``dk`` and ``dgsum`` summed
+    over ``xs``.  Off the diagonal tiles ``dx = to_edge * (dm right)`` and
+    ``dk`` is ``dm^T (x * to_edge)`` times the token's own decay to the
+    sub-block's edge; the diagonal tiles go element by element.  ``G``'s
+    needs no pass of its own: ``x * dx`` by row less ``k * dk`` by column,
+    every exponential coming back times itself."""
+    c = kc.shape[-2]
+    ns, sub = SUB_BLOCKS, sub_block(c)
+    lead = kc.shape[:-2]
+    to_edge, from_edge, within, tiles = _split(kc, gsum)
+    right = kc[..., None, :, :] * from_edge
+    kd = kc.reshape(tiles)
+    dxs, dk_off, dk_diag = [], 0.0, 0.0
+    for x, dm in zip(xs, dms):
+        rows = dm.reshape(lead + (ns, sub, c))
+        dx_off = to_edge * _dot(low, "...aij,...ajd->...aid", rows,
+                                right).reshape(x.shape)
+        dk_off += _dot(low, "...aij,...aid->...ajd", rows,
+                       (x * to_edge).reshape(tiles))
+        tile = jnp.stack([dm[..., a * sub:(a + 1) * sub,
+                             a * sub:(a + 1) * sub] for a in range(ns)],
+                         -3)[..., None] * within       # [.., ns,sub,sub,dk]
+        dxs.append(dx_off + jnp.sum(tile * kd[..., None, :, :], -2
+                                    ).reshape(x.shape))
+        dk_diag += tile * x.reshape(tiles)[..., :, None, :]
+    dk = jnp.sum(dk_off * from_edge, -3) + jnp.sum(dk_diag, -3).reshape(
+        kc.shape)
+    return dxs, dk, sum(x * dx for x, dx in zip(xs, dxs)) - kc * dk
+
+
+def _channel_system(low, qc, kc, vc, gc, bc):
+    """Everything of every chunk that no state enters, under a decay a key
+    channel.  qc, kc, gc: [n,B,H,C,dk]; vc: [n,B,H,C,dv]; bc: [n,B,H,C];
+    all float32."""
     c = qc.shape[-2]
     gsum = jnp.cumsum(gc, -2)
     gamma = jnp.exp(gsum)
-    kk, scores = jax.checkpoint(
-        lambda q, k, g: _pair_scores(low, (k, q), k, g))(qc, kc, gsum)
+    kk, scores = _pair_scores(low, (kc, qc), kc, gsum)
     at = jnp.arange(c)
-    inv = unit_lower_inverse(jnp.where(at[:, None] > at[None, :],
-                                       bc[..., None] * kk, 0.0))
-    u = _exact(inv, bc[..., None] * vc)
-    w = _exact(inv, bc[..., None] * gamma * kc)
+    below = at[:, None] > at[None, :]
+    inv = unit_lower_inverse(jnp.where(below, bc[..., None] * kk, 0.0))
+    # what is left of each token's write, and of the state's rows, at the
+    # chunk's end
+    left = jnp.exp(gsum[..., -1:, :] - gsum)
+    return dict(
+        gsum=gsum, gamma=gamma, kk=kk, below=below, inv=inv, scores=scores,
+        u=_exact(inv, bc[..., None] * vc),
+        w=_exact(inv, bc[..., None] * gamma * kc),
+        left=left, to_end=kc * left, kept=gamma[..., -1, :])
 
+
+def _channel_walk(low, m):
+    """The forward walk: (what each token writes, the state each chunk
+    starts from), the states in the type the products against them take."""
     def step(state, xs):
         u_i, w_i, e_i, a_i = xs
         start = _cast(low, state)
@@ -362,15 +464,83 @@ def _channel_rule(low, qc, kc, vc, gc, bc):
                                               wrote)
         return state, (wrote, start)
 
-    # what is left of each token's write (on its key), and of the state's
-    # rows, at the chunk's end
-    to_end = kc * jnp.exp(gsum[..., -1:, :] - gsum)
+    u, w = m["u"], m["w"]                       # [n,B,H,C,dv], [n,B,H,C,dk]
     _, (wrote, starts) = lax.scan(
-        step, jnp.zeros(vc.shape[1:3] + (kc.shape[-1], vc.shape[-1]),
+        step, jnp.zeros(u.shape[1:3] + (w.shape[-1], u.shape[-1]),
                         jnp.float32),
-        (u, _cast(low, w), _cast(low, to_end), gamma[..., -1, :]))
-    return _dot(low, "nbhck,nbhkv->nbhcv", qc * gamma, starts) \
-        + _dot(low, "nbhcj,nbhjv->nbhcv", scores, wrote)
+        (u, _cast(low, w), _cast(low, m["to_end"]), m["kept"]))
+    return wrote, starts
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _channel_rule(low, qc, kc, vc, gc, bc):
+    """The rule under a decay a key channel over chunked float32 operands
+    (``_channel_system``'s) -> [n,B,H,C,dv] float32; ``low``: the AMP
+    type's name or None."""
+    m = _channel_system(low, qc, kc, vc, gc, bc)
+    wrote, starts = _channel_walk(low, m)
+    return _dot(low, "nbhck,nbhkv->nbhcv", qc * m["gamma"], starts) \
+        + _dot(low, "nbhcj,nbhjv->nbhcv", m["scores"], wrote)
+
+
+def _channel_rule_fwd(low, *operands):
+    return _channel_rule(low, *operands), operands
+
+
+def _channel_rule_bwd(low, operands, dout):
+    """The five cotangents from the five operands and ``dout`` alone (the
+    module's docstring has the equations)."""
+    qc, kc, vc, gc, bc = operands
+    m = _channel_system(low, *operands)
+    wrote, starts = _channel_walk(low, m)
+    gamma, inv, to_end = m["gamma"], m["inv"], m["to_end"]
+    reads = qc * gamma
+    from_out = _dot(low, "nbhij,nbhiv->nbhjv", m["scores"], dout)
+
+    def step(dstate, xs):
+        w_i, e_i, a_i, out_i, read_i = xs
+        dwrote = out_i + _dot(low, "bhck,bhkv->bhcv", e_i, dstate)
+        before = read_i + a_i[..., None] * dstate - _dot(
+            low, "bhck,bhcv->bhkv", w_i, dwrote)
+        return before, (dwrote, dstate)
+
+    _, (dwrote, dnext) = lax.scan(
+        step, jnp.zeros(starts.shape[1:], jnp.float32),
+        (_cast(low, m["w"]), _cast(low, to_end), m["kept"], from_out,
+         _dot(low, "nbhck,nbhcv->nbhkv", reads, dout)), reverse=True)
+
+    # the inverse and its two products, float32 at the highest precision
+    dw = -_dot(low, "nbhcv,nbhkv->nbhck", dwrote, starts)
+    dxu, da_u = solve_cotangents(inv, m["u"], dwrote)
+    dxw, da_w = solve_cotangents(inv, m["w"], dw)
+    da = jnp.where(m["below"], da_u + da_w, 0.0)
+    # both score matrices; what dP holds above the diagonal is not read
+    (dk_rows, dq), dk, dgsum = _pair_scores_bwd(
+        low, (kc, qc), kc, m["gsum"],
+        (bc[..., None] * da,
+         _dot(low, "nbhiv,nbhjv->nbhij", dout, wrote)))
+    # the read of the state, the write's decay to the chunk's end, W's
+    # operand
+    dreads = _dot(low, "nbhcv,nbhkv->nbhck", dout, starts)
+    dto_end = _dot(low, "nbhcv,nbhkv->nbhck", wrote, dnext)
+    dstep = gamma * dxw                                 # of beta * k
+    written = to_end * dto_end
+    dq = dq + gamma * dreads
+    dk = dk + dk_rows + m["left"] * dto_end + bc[..., None] * dstep
+    dbeta = jnp.sum(dxu * vc, -1) + jnp.sum(kc * dstep, -1) \
+        + jnp.sum(da * m["kk"], -1)
+    # every exponential of G: gamma (read and W), G_i - G_j (scores and A,
+    # above), G_C - G (the write's decay to the chunk's end), G_C (the
+    # state's rows)
+    dgsum = dgsum + reads * dreads + bc[..., None] * kc * dstep - written
+    dgsum = dgsum.at[..., -1, :].add(
+        jnp.sum(written, -2) + m["kept"] * jnp.sum(
+            dnext * starts.astype(jnp.float32), -1))
+    dg = lax.cumsum(dgsum, dgsum.ndim - 2, reverse=True)
+    return dq, dk, bc[..., None] * dxu, dg, dbeta
+
+
+_channel_rule.defvjp(_channel_rule_fwd, _channel_rule_bwd)
 
 
 def chunked(q, k, v, g, beta, chunk=64, scale=0.0, norm_eps=0.0):

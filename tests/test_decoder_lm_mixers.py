@@ -963,7 +963,7 @@ def test_channel_decay_and_a_narrow_value_program_equals_the_reference(
         'value_heads="4"}': 4,
         'ops.delta_rule.channel_calls{chunk="16",dim="8",key_heads="4",'
         'sub="4"}': 4,
-        'ops.delta_rule.grad_calls{chunk="16",path="vjp"}': 4}
+        'ops.delta_rule.grad_calls{chunk="16",path="by_hand"}': 4}
     assert counters("ops.sparse_attention") == {
         'ops.sparse_attention.calls{path="xla",seq="64",topk="0"}': 2,
         'ops.sparse_attention.declined{why="value_width"}': 2}

@@ -154,6 +154,36 @@ def products(jaxpr):
     return [e for e in eqns_of(jaxpr) if e.primitive.name == "dot_general"]
 
 
+def walks_and_ladder(xs, chunk, chunks):
+    """(the backward's jaxpr, what a [C, C] x [C, C] product is, how many
+    the ladder holds) of ``chunked`` over ``xs``, after the assertions
+    both kinds of decay share: one walk forward, two backward
+    (the second reversed), two products in each, and no [C, C] x [C, C]
+    product outside the ladder that makes the inverse."""
+    def rule(*a):
+        return delta_rule.chunked(*a, chunk=chunk)
+
+    forward = jax.make_jaxpr(rule)(*xs)
+    out, vjp = jax.vjp(rule, *xs)
+    backward = jax.make_jaxpr(vjp)(out)
+    for jaxpr, walks in ((forward, 1), (backward, 2)):
+        scans = [e for e in eqns_of(jaxpr) if e.primitive.name == "scan"]
+        assert len(scans) == walks
+        for scan in scans:
+            assert len(products(scan.params["jaxpr"])) == 2
+            assert scan.params["length"] == chunks
+        assert [scan.params["reverse"] for scan in scans] == \
+            [False, True][:walks]
+
+    def square(eqn):
+        return all(v.aval.shape[-2:] == (chunk, chunk) for v in eqn.invars)
+
+    ladder = 2 * (int(np.log2(chunk)) - 1)
+    assert len([e for e in products(forward) if square(e)]) == ladder
+    assert len([e for e in products(backward) if square(e)]) == ladder
+    return backward, square, ladder
+
+
 def test_each_walk_holds_two_products_and_the_inverse_goes_back_in_none():
     """The mechanism, read off the jaxprs at a small size: the forward is
     one ``scan`` whose body holds exactly two ``dot_general``; the backward
@@ -167,28 +197,7 @@ def test_each_walk_holds_two_products_and_the_inverse_goes_back_in_none():
     fails here."""
     chunk = 16          # no other width of the operands is 16
     xs = operands(48, seed=0, b=1, hk=2, hv=4, dk=8, dv=12)
-
-    def rule(*a):
-        return delta_rule.chunked(*a, chunk=chunk)
-
-    forward = jax.make_jaxpr(rule)(*xs)
-    out, vjp = jax.vjp(rule, *xs)
-    backward = jax.make_jaxpr(vjp)(out)
-    for jaxpr, walks in ((forward, 1), (backward, 2)):
-        scans = [e for e in eqns_of(jaxpr) if e.primitive.name == "scan"]
-        assert len(scans) == walks
-        for scan in scans:
-            assert len(products(scan.params["jaxpr"])) == 2
-            assert scan.params["length"] == 3
-        assert [scan.params["reverse"] for scan in scans] == \
-            [False, True][:walks]
-
-    def square(eqn):
-        return all(v.aval.shape[-2:] == (chunk, chunk) for v in eqn.invars)
-
-    ladder = 2 * (int(np.log2(chunk)) - 1)
-    assert len([e for e in products(forward) if square(e)]) == ladder
-    assert len([e for e in products(backward) if square(e)]) == ladder
+    _, square, ladder = walks_and_ladder(xs, chunk, 3)
     through_the_ladder = jax.make_jaxpr(
         jax.vjp(delta_rule.unit_lower_inverse,
                 jnp.zeros((chunk, chunk)))[1])(jnp.zeros((chunk, chunk)))
@@ -419,8 +428,8 @@ CHANNEL_CASES = {
 @pytest.mark.parametrize("case", CHANNEL_CASES)
 def test_channel_decay_equals_the_recurrence_and_all_five_cotangents(case):
     """The chunked form under a decay a key channel, its sub-block scores
-    and ``jax.vjp`` of it against the recurrence and ``jax.grad`` of that,
-    at a chunk that does and does not divide T."""
+    and the backward it carries (written by hand) against the recurrence
+    and ``jax.grad`` of that, at a chunk that does and does not divide T."""
     t, chunk, decay = CHANNEL_CASES[case]
     xs = channel_operands(t, decay, seed=t)
     if decay > 1:
@@ -511,7 +520,7 @@ def test_no_tensor_of_a_whole_chunks_pairs_by_channel_is_ever_made():
 def test_the_op_and_its_grad_op_under_a_channel_decay(t):
     """The op with G [B, T, Hv, dk] through the executor against the
     recurrence and ``jax.grad`` of it; counted as a call, a channel call
-    and a backward that is ``jax.vjp`` of the chunked forward."""
+    and a backward written by hand."""
     names = ("q", "k", "v", "g", "beta")
     shapes = ([t, 3, 8], [t, 3, 8], [t, 3, 12], [t, 3, 8], [t, 3])
     data = [layers.data(name=n, shape=s, dtype="float32")
@@ -544,7 +553,90 @@ def test_the_op_and_its_grad_op_under_a_channel_decay(t):
         'value_heads="3"}': 1,
         'ops.delta_rule.channel_calls{chunk="16",dim="8",key_heads="3",'
         'sub="4"}': 1,
-        'ops.delta_rule.grad_calls{chunk="16",path="vjp"}': 1}
+        'ops.delta_rule.grad_calls{chunk="16",path="by_hand"}': 1}
+
+
+def test_under_a_channel_decay_each_walk_holds_two_products_too():
+    """The channel path's mechanism, read off the jaxprs as the scalar
+    path's is: one walk forward, two backward, two products each, no
+    [C, C] x [C, C] product outside the ladder.  A scan's transpose (a
+    third and fourth product in the reverse walk, stacked residuals), a
+    differentiated ladder (four such products a level) or a closed form
+    taken as ``-T^T dT T^T`` fails here; and from the forward the backward
+    keeps the five chunked operands and nothing else."""
+    chunk = 16          # no other width of the operands is 16; sub is 4
+    xs = channel_operands(48, 0.5, b=1, h=2, dk=8, dv=12)
+    backward, _, _ = walks_and_ladder(xs, chunk, 3)
+    n, (b, _, h, dk), dv = 3, xs[0].shape, xs[2].shape[-1]
+    assert sorted(v.aval.shape for v in backward.jaxpr.constvars
+                  if v.aval.shape) == sorted(
+        [(n, b, h, chunk, dk)] * 3 + [(n, b, h, chunk, dv),
+                                      (n, b, h, chunk)])
+
+
+@pytest.mark.parametrize("case", ["whole", "overflows", "bf16"])
+def test_the_scores_cotangents_alone_are_autodiffs(case):
+    """``_pair_scores_bwd`` against ``jax.vjp`` of ``_pair_scores``: both
+    score matrices at once, a cotangent that is dense (what it holds above
+    the diagonal is not read), every cotangent: each ``x``'s, the keys',
+    the running sums'.  Where ``exp(-G)`` overflows too, and with the
+    products' inputs in bf16 (the same roundings on both sides but for the
+    order of two sums)."""
+    c, dk = 16, 8
+    decay, low = {"whole": (0.5, None), "overflows": (30.0, None),
+                  "bf16": (0.5, "bfloat16")}[case]
+    rng = np.random.RandomState(7)
+    q, k = (jnp.asarray(rng.randn(2, 3, c, dk), jnp.float32)
+            for _ in range(2))
+    gsum = jnp.cumsum(jnp.asarray(-decay * rng.rand(2, 3, c, dk),
+                                  jnp.float32), -2)
+    if decay > 1:
+        assert float(gsum.min()) < -89      # exp(-G) is inf in float32
+    dms = tuple(jnp.asarray(rng.randn(2, 3, c, c), jnp.float32)
+                for _ in range(2))
+    scores, vjp = jax.vjp(
+        lambda x0, x1, k, g: delta_rule._pair_scores(low, (x0, x1), k, g),
+        k, q, k, gsum)
+    assert not np.any(np.triu(np.asarray(scores[0]), 1))
+    want_x0, want_x1, want_k, want_g = vjp(list(dms))
+    (got_x0, got_x1), got_k, got_g = delta_rule._pair_scores_bwd(
+        low, (k, q), k, gsum, dms)
+    near = 2e-2 if low else 2e-5
+    for name, got, want in (("x0", got_x0, want_x0), ("x1", got_x1, want_x1),
+                            ("k", got_k, want_k), ("gsum", got_g, want_g)):
+        assert bool(jnp.isfinite(got).all())
+        np.testing.assert_allclose(got, want, atol=near * float(
+            jnp.abs(want).max()), err_msg=name)
+
+
+@pytest.mark.parametrize("t,chunk", [(53, 16), (128, 64)])
+def test_channel_decay_under_amp_stays_close_to_the_recurrence(t, chunk):
+    """bf16 q, k, v under AMP and a decay a key channel, as the cell runs
+    it: every contraction but the inverse's and the diagonal tiles' in
+    bf16, value and all five cotangents the recurrence's to bf16's
+    rounding, the gates' cotangents float32."""
+    xs = channel_operands(t, 0.5, seed=t)
+    xs = tuple(a.astype(jnp.bfloat16) for a in xs[:3]) + xs[3:]
+    exact = tuple(a.astype(jnp.float32) for a in xs)
+
+    def rule(*a):
+        return delta_rule.chunked(*a, chunk=chunk, scale=0.3).astype(
+            jnp.float32)
+
+    def stated(*a):
+        return channel_recurrence(*a, 0.3)
+
+    want = stated(*exact)
+    wants = jax.grad(weighted_sum(stated), range(5))(*exact)
+    with fluid.amp.amp_guard("bfloat16", keep_activations=True):
+        got = delta_rule.chunked(*xs, chunk=chunk, scale=0.3)
+        grads = jax.grad(weighted_sum(rule), range(5))(*xs)
+    assert got.dtype == jnp.bfloat16
+    assert [g.dtype for g in grads] == [a.dtype for a in xs]
+    assert rel(got, want) < 0.02
+    for name, g, w in zip("q k v g beta".split(), grads, wants):
+        assert bool(jnp.isfinite(g).all())
+        assert rel(g, w) < 0.03, name
 
 
 def test_infer_rule_takes_a_decay_a_key_channel():
