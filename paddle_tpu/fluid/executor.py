@@ -182,7 +182,7 @@ def scope_guard(scope):
 
 def build_window_fn(program: Program, plan: "BlockPlan", guard, n_user: int,
                     n_steps: int, feed_per_step: bool,
-                    trace=None, finalize=None):
+                    trace=None, finalize=None, gauges="run_steps"):
     """Build the fused-window step function ``kfn(feed_vals, const_state,
     mut_state, sentinel)`` — a ``lax.scan`` over the traced step with the
     mutable state (plus, when guarded, the aggregated health record) riding
@@ -191,24 +191,31 @@ def build_window_fn(program: Program, plan: "BlockPlan", guard, n_user: int,
     sharded path scans the EXACT same body the single-device oracle tests
     pin down.
 
-    ``trace(feed, state)`` overrides the default ``trace_block`` call
-    (the sharded runner wraps it in a ``mesh_scope``); ``finalize(last,
+    ``trace(feed, state, gauges)`` overrides the default ``trace_block``
+    call (the sharded runner wraps it in a ``mesh_scope``); ``finalize(last,
     mut_final, agg)`` post-processes the outputs inside the trace (the
-    sharded runner pins shardings there; ``agg`` is None unguarded).
+    sharded runner pins shardings there; ``agg`` is None unguarded).  A
+    window carries no vector of step gauges out of its scan: what its ops
+    publish is counted once a lowering as
+    ``observe.step_gauges.dropped{path=gauges}``.
     """
     import jax.numpy as _jnp
     from jax import lax as _lax
 
     from . import guardian as _guardian
+    from ..observe.gauges import Collector
 
     if trace is None:
-        def trace(feed_vals, state_vals):
-            return trace_block(program, 0, plan, feed_vals, state_vals)
+        def trace(feed_vals, state_vals, gauges):
+            return trace_block(program, 0, plan, feed_vals, state_vals,
+                               gauges=gauges)
     if finalize is None:
         def finalize(last, mut_final, agg):
             return last, mut_final, agg
 
     def kfn(feed_vals, const_state, mut_state, sentinel):
+        dropping = Collector(drop=gauges)
+
         def body(carry, xs):
             if guard is not None:
                 mut, _prev_fetch, agg = carry
@@ -224,7 +231,7 @@ def build_window_fn(program: Program, plan: "BlockPlan", guard, n_user: int,
                              "loss_mul": xs["loss_mul"]}
                 step_feed[_guardian.LOSS_SEED_MUL] = \
                     _guardian.seed_multiplier(guard, state, step_sent)
-            fetches, new_state = trace(step_feed, state)
+            fetches, new_state = trace(step_feed, state, dropping)
             # fetches ride the carry: only the LAST step's values
             # survive, with no (n_steps, ...) stacking buffer
             if guard is not None:
@@ -241,7 +248,7 @@ def build_window_fn(program: Program, plan: "BlockPlan", guard, n_user: int,
             {k: v[0] for k, v in feed_vals.items()}
             if feed_per_step else feed_vals)
         fetch0, state0 = jax.eval_shape(
-            lambda st: trace(first_feed, {**const_state, **st}),
+            lambda st: trace(first_feed, {**const_state, **st}, dropping),
             mut_state)
         fetch0 = [_jnp.zeros(t.shape, t.dtype)
                   for t in fetch0[:n_user]]
@@ -280,7 +287,8 @@ def trace_block(program: Program, block_idx: int, plan: BlockPlan,
                 feed_vals: Dict[str, jnp.ndarray],
                 state_vals: Dict[str, jnp.ndarray],
                 static_env: Optional[Dict[str, object]] = None,
-                lod_box: Optional[Dict[str, object]] = None):
+                lod_box: Optional[Dict[str, object]] = None,
+                gauges=None):
     """Run every op in the block symbolically; returns (fetches, new_state).
 
     ``static_env`` carries compile-time-constant entries — notably
@@ -290,6 +298,12 @@ def trace_block(program: Program, block_idx: int, plan: BlockPlan,
     data keeps a static [sum_len, ...] shape and the offsets are baked into
     the trace, so XLA sees fully static programs.  ``lod_box``, if given,
     receives the lod of every fetch/state name produced by the trace.
+
+    ``gauges`` is the ``observe.gauges.Collector`` of the step's device
+    gauges, which the caller made and finishes (one with ``drop`` set only
+    counts what a path that carries no vector leaves behind).  It is open
+    while a FORWARD op of THIS block runs and nowhere else: ``run_op`` hands
+    it on to nothing.
     """
     env: Dict[str, object] = {}
     if static_env:
@@ -300,7 +314,7 @@ def trace_block(program: Program, block_idx: int, plan: BlockPlan,
     if plan.needs_rng:
         rng_box = [state_vals[RNG_STATE_VAR]]
     for op in plan.ops:
-        run_op(op, env, rng_box)
+        run_op(op, env, rng_box, gauges)
     return _block_outputs(plan, env, rng_box, lod_box)
 
 
@@ -318,8 +332,12 @@ def _block_outputs(plan, env, rng_box, lod_box):
     return fetches, new_state
 
 
-def run_op(op, env: Dict[str, object], rng_box=None):
-    """Execute one IR op against a trace environment."""
+def run_op(op, env: Dict[str, object], rng_box=None, gauges=None):
+    """Execute one IR op against a trace environment.  ``gauges``: the
+    step's collector of device gauges (``trace_block``), open around a
+    forward op's function only: a grad op traces its forward a second time
+    under ``jax.vjp`` and a sub-block runs inside a loop's trace, and a
+    value published there would be an inner trace's tracer."""
     from . import control_flow_exec
 
     if op.type in control_flow_exec.HANDLERS:
@@ -382,6 +400,9 @@ def run_op(op, env: Dict[str, object], rng_box=None):
                 raw = opdef.grad_fn(ctx)
             else:
                 raw = _reg.run_grad_generic(opdef, ctx)
+        elif gauges is not None:
+            with gauges.op(path):
+                raw = opdef.fn(ctx)
         else:
             raw = opdef.fn(ctx)
 
@@ -699,17 +720,20 @@ class Executor:
             root.set(fresh=fresh)
             if fresh:
                 with _trace.span("fluid.run.build"):
-                    lod_box = {}
+                    # filled by the step's trace: the lods, and the
+                    # layout of its vector of step gauges (None: none)
+                    lod_box, gauge_box = {}, [None]
                     entry, probe = self._build_entry(
                         "run", program, feed_arrays, fetch_names, guard,
                         extra,
                         lambda plan, guard: self._build(
                             program, plan, {**state_lods, **feed_lods},
-                            lod_box, guard=guard, n_user=len(fetch_names)))
-                    entry += (lod_box,)
+                            lod_box, guard=guard, n_user=len(fetch_names),
+                            gauge_box=gauge_box))
+                    entry += (lod_box, gauge_box)
                 if use_program_cache:
                     self._cache[key] = entry
-            plan, fn, guard, lod_box = entry
+            plan, fn, guard, lod_box, gauge_box = entry
 
         with _trace.span("fluid.run.state"):
             d = _step.Dispatch(program, scope, plan, guard)
@@ -748,6 +772,8 @@ class Executor:
                     out.append(LoDTensor(v, lod_box.get(n)))
 
         with _trace.span("fluid.run.observe"):
+            # the step's device gauges stay the device array they are
+            d.keep_gauges(gauge_box[0], root.span_id, t)
             d.report(fetches, new_state, health, t, (t, call_s), fresh,
                      probe=probe,
                      meta={"kind": "run", "ops": len(plan.ops),
@@ -892,10 +918,28 @@ class Executor:
         return dev_arr
 
     def _build(self, program, plan, feed_lods=None, lod_box=None,
-               guard=None, n_user=None):
+               guard=None, n_user=None, gauge_box=None):
+        """The jitted step ``(feed, const_state, mut_state[, sentinel]) ->
+        (fetches, new_state[, health][, gauges])``.  ``gauges`` is there
+        only where the block's forward ops published step gauges
+        (``observe.step_gauge``): one float32 vector, whose static layout
+        the trace leaves in ``gauge_box`` as ``lod_box`` gets the lods.  A
+        step that publishes nothing returns what it always did."""
+        from ..observe.gauges import Collector
+
         donate = _step.donate_argnums(program)
         static_env = {k + LOD_SUFFIX: lod
                       for k, lod in (feed_lods or {}).items()}
+
+        def traced(feed_vals, state):
+            gauges = Collector()
+            fetches, new_state = trace_block(
+                program, 0, plan, feed_vals, state, static_env=static_env,
+                lod_box=lod_box, gauges=gauges)
+            vector, layout = gauges.finish()
+            if gauge_box is not None:
+                gauge_box[:] = [layout]
+            return fetches, new_state, (() if vector is None else (vector,))
 
         if guard is not None:
             from . import guardian as _g
@@ -908,21 +952,19 @@ class Executor:
                 # consumed by the __loss_seed__-tagged op in run_op
                 feed_vals[_g.LOSS_SEED_MUL] = _g.seed_multiplier(
                     guard, state, sentinel)
-                fetches, new_state = trace_block(
-                    program, 0, plan, feed_vals, state,
-                    static_env=static_env, lod_box=lod_box)
+                fetches, new_state, gauges = traced(feed_vals, state)
                 new_state, health = _g.fold_health(
                     guard, fetches[n_user:], new_state, mut_state, state,
                     sentinel)
-                return fetches[:n_user], new_state, health
+                return (fetches[:n_user], new_state, health) + gauges
 
             return jax.jit(gfn, donate_argnums=donate)
 
         def fn(feed_vals, const_state, mut_state):
             state = dict(const_state)
             state.update(mut_state)
-            return trace_block(program, 0, plan, feed_vals, state,
-                               static_env=static_env, lod_box=lod_box)
+            fetches, new_state, gauges = traced(feed_vals, state)
+            return (fetches, new_state) + gauges
 
         if plan.needs_eager:
             # programs with data-dependent ops (beam search, mask split):

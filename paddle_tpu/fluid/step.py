@@ -411,7 +411,7 @@ class Dispatch:
         self.guard, self.n_steps, self.mesh = guard, n_steps, mesh
         self.training = program._params_grads is not None
         self.start = step_boundary(n_steps or 1) if self.training else 0
-        self.g = self.sentinel = self.dump_state = None
+        self.g = self.sentinel = self.dump_state = self.gauges = None
         if guard is not None:
             from . import guardian as _guardian
 
@@ -458,13 +458,28 @@ class Dispatch:
 
     def call(self, fn, feed_dev, const_state, mut_state):
         """``(fetches, new_state, health)`` of the built step.  A per-step
-        unguarded step takes no sentinel; every other one does.  Nothing
+        unguarded step takes no sentinel; every other one does.  A step
+        whose ops published device gauges returns their vector last; it is
+        held here as the device array it is until ``keep_gauges``.  Nothing
         here waits on the device, profiling or not."""
         args = (feed_dev, const_state, mut_state)
         if self.guard is not None or self.n_steps is not None:
             args += (self.sentinel,)
-        fetches, new_state, *health = fn(*args)
-        return fetches, new_state, (health[0] if health else None)
+        fetches, new_state, *rest = fn(*args)
+        health = rest.pop(0) if rest and self.guard is not None else None
+        self.gauges = rest[0] if rest else None
+        return fetches, new_state, health
+
+    def keep_gauges(self, layout, span_id, t):
+        """Hand the step's vector of device gauges (``layout``: where each
+        lies, from the step's trace) to ``observe.gauges``, unread: with the
+        index of the step, the id of its ``fluid.run`` root and the host
+        clock at its call."""
+        if self.gauges is not None and layout is not None:
+            from ..observe import gauges as _gauges
+
+            _gauges.keep(self.start, span_id, t, self.gauges, layout)
+        self.gauges = None
 
     def report(self, fetches, new_state, health, t0, call, fresh=False,
                compile_s=None, probe=None, meta=None, feed_per_step=False,

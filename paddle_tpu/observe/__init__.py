@@ -47,7 +47,7 @@ __all__ = [
     "MetricsRegistry", "EventLog", "registry", "get_sink", "configure",
     "disable", "reset", "emit", "span", "note_step", "note_program",
     "note_mesh", "note_commit_step", "current_step", "current_program",
-    "current_mesh", "current_commit_step",
+    "current_mesh", "current_commit_step", "step_gauge", "step_gauges",
     "http_server", "ENV_DIR", "ENV_FLUSH", "ENV_PORT",
     # submodules re-exported for discoverability: observe.trace (the span
     # primitive + the ring), observe.watchdog (SLO breaches),
@@ -278,10 +278,12 @@ def reset() -> None:
     # clear their state with it
     from . import goodput as _goodput
     from . import memory as _memory
+    from . import gauges as _gauges
     from . import trace as _trace
     from . import watchdog as _watchdog
 
     _trace.reset()
+    _gauges.reset()
     _watchdog.reset()
     _memory.reset()
     _goodput.reset()
@@ -318,3 +320,7 @@ from . import goodput, memory, trace, watchdog  # noqa: E402,F401  (re-export)
 
 #: the one span primitive, under its short name
 span = trace.span
+
+# device-valued gauges of a compiled step (observe/gauges.py): an op's
+# lowering publishes with ``step_gauge``, ``step_gauges`` reads them late
+from .gauges import step_gauge, step_gauges  # noqa: E402

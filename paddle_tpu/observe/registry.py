@@ -115,6 +115,15 @@ class MetricsRegistry:
             self._gauges[key] = value
             self._sample(key, value)
 
+    def set_gauges(self, rendered: Dict[str, float]) -> None:
+        """Many gauges under ONE hold of the lock, by their rendered names
+        (``render_name``): a step's worth of step gauges, a step."""
+        with self.lock:
+            self._gauges.update(rendered)
+            if self._samples is not None:
+                for key, value in rendered.items():
+                    self._sample(key, value)
+
     def observe(self, name: str, value: float,
                 labels: Optional[dict] = None) -> None:
         """One histogram observation."""

@@ -226,7 +226,11 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
     # experts is held and the router is even (Keye's 16 of 128).  What
     # that costs is the products' and the gathers' time over rows (no
     # scatter is left): 8 products, 3 weights' gradients and 5 row gathers
-    # a layer and step.  What dropping it can buy in the resident cells is
+    # a layer and step.  THE RECORD of what the padding costs is the step
+    # gauges published below: ``ops.moe.live_rows`` of ``ops.moe.rows``
+    # a layer and step is the share of what is walked that is work (the
+    # benchmark's ``moe_live_rows_pct``), read late and never waited for.
+    # What dropping it can buy in the resident cells is
     # what PR 30 read with XLA's ``ragged_dot`` (a call of which took 2.0
     # ms at 8,192 and at 65,536 live rows; the Pallas kernels that stand
     # since PR 37 take 1.2-1.5 ms over all rows and have not been read
@@ -236,6 +240,7 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
     # which drifts toward the experts held as it trains without the absent
     # ones; nothing else depends on it.
     sizes = counts[:e].at[e - 1].add(counts[e])
+    _publish_load(first[e], n * top_k, jnp.max(counts[:e]))
     gate = jnp.where(held, vals, 0.0)
     # the layer's ONE choice of kernels or XLA's grouped product, part of
     # the plan: the tables that tell the kernels' grid steps row tiles and
@@ -362,6 +367,20 @@ def _rows_home(rows, back, held, which: str):
     _count_row_move(which)
     return jnp.where(held[..., None],
                      _permuted(rows, back).reshape(held.shape + (-1,)), 0)
+
+
+def _publish_load(live, rows: int, fullest):
+    # the layer's load as step gauges (``observe.step_gauge``; the label
+    # ``scope`` comes from the op), from what the plan already holds: the
+    # assignments that chose an expert held here, the rows walked, the
+    # fullest held expert (with ``live_rows / held`` the operator's max
+    # over mean).  Device values that leave the step unfetched;
+    # ``step_gauge`` never fails the trace it measures.
+    from .. import observe
+
+    observe.step_gauge("ops.moe.live_rows", live)
+    observe.step_gauge("ops.moe.rows", rows)
+    observe.step_gauge("ops.moe.fullest_group", fullest)
 
 
 def _count_row_move(which: str):

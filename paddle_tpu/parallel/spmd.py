@@ -39,6 +39,7 @@ from ..fluid import step as _step
 from ..fluid.executor import (BlockPlan, build_window_fn, global_scope,
                               trace_block)
 from ..fluid.framework import Parameter, Program, RNG_STATE_VAR
+from ..observe.gauges import Collector
 from .mesh import mesh_label
 
 
@@ -415,9 +416,14 @@ class ShardedTrainStep:
         plan = self.plan
         specs = self.specs
 
-        def fn(feed_vals, state_vals):
+        def fn(feed_vals, state_vals, gauges=None):
+            # the per-step sharded path carries no vector of step gauges:
+            # what its ops publish is counted, once a lowering
+            if gauges is None:
+                gauges = Collector(drop="sharded_step")
             with mesh_scope(mesh), param_spec_scope(specs):
-                return trace_block(program, 0, plan, feed_vals, state_vals)
+                return trace_block(program, 0, plan, feed_vals, state_vals,
+                                   gauges=gauges)
 
         self._trace = fn
 
@@ -735,7 +741,8 @@ class ShardedWindowRunner:
 
         kfn = build_window_fn(program, plan, guard, self.n_user,
                               self.n_steps, self.feed_per_step,
-                              trace=self.step._trace, finalize=finalize)
+                              trace=self.step._trace, finalize=finalize,
+                              gauges="sharded_window")
         self._jit = jax.jit(kfn,
                             donate_argnums=(2,) if self.donate else ())
         self._compiled = None
