@@ -32,6 +32,7 @@ exporter maps dots to underscores.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Dict, Iterable, Optional, Tuple
@@ -93,6 +94,8 @@ class MetricsRegistry:
         self._samples: Optional[list] = None
         self._samples_t0 = 0.0
         self._samples_cap = 200_000
+        # the open ``tape()``s of each thread
+        self._tapes = threading.local()
 
     # ------------------------------------------------------------------
     # writing
@@ -102,11 +105,38 @@ class MetricsRegistry:
             labels: Optional[dict] = None) -> float:
         """Add ``value`` to a counter; returns the new total."""
         key = render_name(name, _label_key(labels))
+        for tape in getattr(self._tapes, "open", ()):
+            tape.append((key, value))
         with self.lock:
             new = self._counters.get(key, 0) + value
             self._counters[key] = new
             self._sample(key, new)
         return new
+
+    @contextlib.contextmanager
+    def tape(self):
+        """A list that takes ``(rendered name, value)`` of every ``inc``
+        this thread makes while the context is open, beside the counters
+        themselves.  For code whose counters count a TRACE and whose trace
+        one call makes for many (an inlined ``jax.jit``): the calls that the
+        kept trace serves ``replay`` what it counted."""
+        taken: list = []
+        opened = getattr(self._tapes, "open", ())
+        self._tapes.open = opened + (taken,)
+        try:
+            yield taken
+        finally:
+            self._tapes.open = opened
+
+    def replay(self, taken) -> None:
+        """Count again what a ``tape()`` took."""
+        with self.lock:
+            for key, value in taken:
+                new = self._counters.get(key, 0) + value
+                self._counters[key] = new
+                self._sample(key, new)
+        for tape in getattr(self._tapes, "open", ()):
+            tape.extend(taken)
 
     def set_gauge(self, name: str, value: float,
                   labels: Optional[dict] = None) -> None:

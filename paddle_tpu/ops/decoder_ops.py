@@ -10,7 +10,9 @@ Each is a pure JAX function; gradients go through the generic vjp path
 ``moe_experts`` count which path each call took at lowering
 (``ops.sparse_attention.calls{topk,seq,path}`` and, under a causal window,
 ``window``; ``ops.moe.calls{held,routed,path}`` and, for a router that is
-not the softmax one, ``score``; ``...declined{why}`` for every fallback;
+not the softmax one, ``score``, and, where the layer walks its sorted rows
+in slabs of fewer than all (``parallel/moe.slab_rows``), ``slab``;
+``...declined{why}`` for every fallback;
 ``ops.moe.bias_updates`` for every ``moe_bias_update`` lowered;
 ``ops.short_conv.calls{channels,taps,path}`` for every ``short_conv``
 lowered, its backward not counted, with ``gated="0"`` where the op is the
@@ -25,7 +27,8 @@ channel alike), and
 ``ops.delta_rule.channel_calls{key_heads,dim,chunk,sub}`` beside ``calls``
 for every forward lowered with such a G;
 ``ops.moe.row_moves{pass,how="gather"}``, which ``parallel/moe.py`` counts
-for every ``[N * top_k, D]`` row gather it traces: two
+for every row gather it traces (a walk's, whether the layer walks every
+``N * top_k`` row at once or a slab of them a trip of its loop): two
 ``pass="forward"`` for every trace of the layer's forward, of which
 ``moe_experts`` makes one and ``moe_experts_grad`` another that only its
 routing plan outlives, and three ``pass="backward"`` for every
@@ -491,9 +494,11 @@ def moe_experts_op(ctx):
         raise ValueError(f"moe_experts: a selection bias {bias.shape} for "
                          f"num_routed={routed}")
     top_k = int(ctx.attr("top_k"))
-    _count("ops.moe.calls", held=held, routed=routed,
-           path=moe.product_path(ctx.input("X"), w1, ctx.input("W2"), top_k),
-           **({} if score == "softmax" else {"score": score}))
+    path, rows, slab = moe.walk_of(ctx.input("X"), ctx.input("RouterW"), w1,
+                                   ctx.input("W2"), top_k, bias)
+    _count("ops.moe.calls", held=held, routed=routed, path=path,
+           **({} if score == "softmax" else {"score": score}),
+           **({} if slab == rows else {"slab": slab}))
     out = moe.routed_experts(
         ctx.input("X"), ctx.input("RouterW"), w1, ctx.input("W3"),
         ctx.input("W2"), top_k=top_k,

@@ -17,8 +17,10 @@ transformer blocks do).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
+import threading
 from typing import Tuple
 
 import jax
@@ -172,9 +174,14 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
     computed, one to an absent expert is left out (its chip adds it in a
     deployment; nothing stands in for it here).  There is no capacity and
     nothing is dropped: the ``N * top_k`` assignments are sorted by expert
-    (absent ones last, as zero rows) and multiplied as grouped products
-    (``grouped_product``), so a step in which every token chose held experts
-    only is as right as any other.
+    (absent ones last) and the sorted rows are walked a SLAB at a time
+    (``slab_rows`` of them, as grouped products, ``grouped_product``): one
+    trip of a ``lax.while_loop`` while the assignments to the experts held
+    fit a slab, as many more as they need beyond it, so a step in which
+    every token chose held experts only is as right as any other.  Where
+    the slab is every row (an eighth of the experts or more held; for now
+    also a router with no balancing ``bias``: ``slab_rows``' DEBT) no loop is
+    built.
 
     The routing plan (the router's weights and choices, the sort by expert
     and its inverse, the group sizes) is made once and kept for the
@@ -183,11 +190,13 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
     hidden products again (a gather, two products) and NOT the last
     product, whose one reader there was the gate's cotangent (that is
     ``<dy @ w2^T, h>`` over a row, and the backward has both).  Rows move
-    by gathers in both passes and nothing is scattered: two ``[N * top_k,
-    D]`` gathers forward, three backward; the combine's cotangent goes out
-    to the sorted rows from ``[N, D]`` and meets the gate on the hidden
-    side, never as ``[N, top_k, D]``.  A layer and step: 8 products and 3
-    weights' gradients.
+    by gathers in both passes and nothing is scattered: a walk gathers
+    twice forward and three times backward (out to the walk's sorted rows,
+    ``[slab, D]``; home to the assignments, ``[N * top_k, D]``); the
+    combine's cotangent goes out to the sorted rows from ``[N, D]`` and
+    meets the gate on the hidden side, never as ``[N, top_k, D]``.  A walk
+    of both passes: 8 products and 3 weights' gradients, traced ONCE a
+    lowering whatever the trips.
     """
     shape = x.shape
     e = w1.shape[0]
@@ -218,45 +227,254 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
                             + jnp.cumsum(chose, axis=0, dtype=i32)),
                    axis=1, dtype=i32)
     order = jnp.argsort(group, stable=True).astype(jnp.int32)
-    live = (jnp.arange(order.shape[0], dtype=i32) < first[e])[:, None]
-    # DEBT (ROADMAP S11, PERF.md section 7): the absent experts'
-    # assignments ride along as zero rows at the end of the LAST held
-    # group, so all N * top_k rows are gathered, multiplied and gathered
-    # back, forward and backward: 7 of every 8 where an eighth of the
-    # experts is held and the router is even (Keye's 16 of 128).  What
-    # that costs is the products' and the gathers' time over rows (no
-    # scatter is left): 8 products, 3 weights' gradients and 5 row gathers
-    # a layer and step.  THE RECORD of what the padding costs is the step
-    # gauges published below: ``ops.moe.live_rows`` of ``ops.moe.rows``
-    # a layer and step is the share of what is walked that is work (the
-    # benchmark's ``moe_live_rows_pct``), read late and never waited for.
-    # What dropping it can buy in the resident cells is
-    # what PR 30 read with XLA's ``ragged_dot`` (a call of which took 2.0
-    # ms at 8,192 and at 65,536 live rows; the Pallas kernels that stand
-    # since PR 37 take 1.2-1.5 ms over all rows and have not been read
-    # with fewer): 414.3 -> 394.6-405.6 ms a step with the cell's spread
-    # lost.  Without this line (sizes = counts[:e]) the products skip the
-    # row tiles past the last group and a step's time follows the router,
-    # which drifts toward the experts held as it trains without the absent
-    # ones; nothing else depends on it.
-    sizes = counts[:e].at[e - 1].add(counts[e])
-    _publish_load(first[e], n * top_k, jnp.max(counts[:e]))
+    # the layer's ONE choice of kernels or XLA's grouped product, and the
+    # rows a trip walks: both from the operands alone
+    path, rows, slab = walk_of(x, router_w, w1, w2, top_k, bias)
+    kernels = path == "pallas"
+    if slab == rows:
+        # every row in ONE walk and no loop: the absent experts'
+        # assignments ride as zero rows at the end of the LAST held group
+        # (were the sizes to stop at the last held assignment, the products
+        # would skip the row tiles past it and a step's time follow the
+        # router)
+        live = (jnp.arange(rows, dtype=i32) < first[e])[:, None]
+        sizes = counts[:e].at[e - 1].add(counts[e])
+        trips = None
+    else:
+        # at least one trip, so that a step's gauges always have rows
+        trips = jnp.maximum((first[e] + i32(slab - 1)) // i32(slab), i32(1))
+    _publish_load(first[e], rows if trips is None else trips * i32(slab),
+                  jnp.max(counts[:e]), trips)
     gate = jnp.where(held, vals, 0.0)
-    # the layer's ONE choice of kernels or XLA's grouped product, part of
-    # the plan: the tables that tell the kernels' grid steps row tiles and
-    # groups, for all eleven calls of the layer, or None
-    tables = None
-    if product_path(x, w1, w2, top_k) == "pallas":
-        from ..ops import pallas_grouped
-
-        tables = pallas_grouped.plan(sizes, n * top_k)
-
-    y = _share(top_k, xt, gate, w1, w3, w2,
-               (held, sizes, order, back, live, tables))
+    if trips is None:
+        plan = (held, sizes, order, back, live, _tables(sizes, rows, kernels))
+    else:
+        plan = _slabs(held, first, order, back, trips, slab, kernels)
+    y = _share(top_k, xt, gate, w1, w3, w2, plan)
     y = y.astype(x.dtype).reshape(shape)
     if with_counts:
         return y, assignment_counts(idx, router_w.shape[-1])
     return y
+
+
+#: The rows a trip of the layer walks, over the rows an EVEN router sends to
+#: the experts held.  A chip that holds ``held`` of ``routed`` experts sees
+#: that share of the ``N * top_k`` assignments and the rest are absent:
+#: walked as zero rows they cost the products', the gathers' and the
+#: elementwise passes' time over rows, forward and backward (ledger, PR 54:
+#: with 10.45% of the rows live in ``trinity_mini.resident`` and 3.14% in
+#: ``kimi_linear_48b_a3b.resident`` the expert layer was 99 and 37 ms of
+#: their steps; ledger, PR 55: a slab of 24,576 of Trinity's 49,152 rows
+#: gave back 46 ms a step, one of 4,096 of Kimi-Linear's 16,384 rows 17
+#: ms; my chip runs, PR 56: 46 and 13).  WHY 8: a router trained on one
+#: chip's share drifts toward the experts it holds, and the slab needs room
+#: for it, since a trip beyond the first costs the whole slab's time again
+#: (8 products, 3 weights' gradients and 5 row gathers over ``slab`` rows,
+#: and the carried sums read and written once more).  The largest live
+#: share a layer read inside a window (PERF.md section 6, PRs 54 to 56) is
+#: 2.0 times the even share in Trinity (7,646 rows, 31% of its slab) and
+#: 1.6 in Kimi-Linear (19% of its slab), both falling through the window
+#: under their balancing rule; without one Qwen3-Next climbs to 3.6 times
+#: (11.3% of all rows, 45% of what would be its slab) and has not stopped at
+#: the window's last step; and however far a router drifts, a layer in slabs
+#: walks less than one slab more than the one walk does (``ceil(live /
+#: slab)`` trips).  An eighth of the experts or more held is every row.
+SLAB_OVER_EVEN = 8
+
+
+def slab_rows(rows: int, held: int, routed: int, kernels: bool,
+              balanced: bool = True) -> int:
+    """How many of a layer's ``rows`` (``N * top_k``) sorted rows one trip
+    walks where ``held`` of ``routed`` experts are here: ``SLAB_OVER_EVEN``
+    times the even share of them, in whole row tiles where the Pallas
+    ``kernels`` multiply; all of them where that is no fewer.  From the
+    operands alone: no argument of the layer, attribute, environment
+    variable or configuration's name chooses.
+
+    DEBT, ``balanced`` (the router has a selection bias that
+    ``balance_bias`` moves): a layer without one walks all its rows.  It
+    stands for ONE reading and is no rule of routers: the step of the one
+    cell that the shapes would give a slab and that has no bias,
+    ``qwen3_next_80b_a3b.resident`` (20,480 of 81,920 rows), reserves 5,571
+    MB at the parent and 6,723 MB with its eight loops in it (my
+    described-chip compiles, PR 56; 5,574 MB with the forward's four alone),
+    1.07 GiB of ``peak_hbm_gib`` where the bound is 0.14: XLA then schedules
+    every Adam update after the last backward op, as it already does in
+    Kimi-Linear's step on both sides, and all the gradients wait.  The
+    drift of such a router (3.6 times the even share at the end of that
+    cell's window, still climbing) is NO reason: a layer in slabs never
+    walks more than one slab over what the one walk does.  What takes this
+    argument out is the step's schedule held in place (ROADMAP S11 (b)),
+    then that cell's own pairs on the chip; a configuration with a bias
+    whose step XLA schedules the same way would lose memory as that cell
+    does, and one without a bias goes without the slab until then."""
+    from ..ops.pallas_grouped import ROW_TILE
+
+    if not balanced:
+        return rows
+    tile = ROW_TILE if kernels else 1
+    return min(rows, tile * -(-SLAB_OVER_EVEN * rows * held
+                              // (routed * tile)))
+
+
+def walk_of(x, router_w, w1, w2, top_k: int, bias):
+    """``(path, rows, slab)`` of ``routed_experts`` on these operands: the
+    product path (``product_path``), the ``N * top_k`` sorted rows, and how
+    many of them one trip walks (``slab_rows``; ``rows`` where the layer
+    walks them all at once and builds no loop)."""
+    path = product_path(x, w1, w2, top_k)
+    rows = x.size // x.shape[-1] * top_k
+    return path, rows, slab_rows(rows, w1.shape[0], router_w.shape[-1],
+                                 path == "pallas", bias is not None)
+
+
+def _tables(sizes, m: int, kernels: bool):
+    # the kernels' visit tables of a walk over ``m`` rows in groups of
+    # ``sizes``, for all eleven calls of the walk, or None where XLA's
+    # grouped product multiplies
+    if not kernels:
+        return None
+    from ..ops import pallas_grouped
+
+    return pallas_grouped.plan(sizes, m)
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["held", "sizes", "order", "back", "live",
+                                "trips"], meta_fields=["kernels"])
+@dataclasses.dataclass(frozen=True)
+class _Slabs:
+    """The routing plan of a layer that walks its sorted rows in slabs of
+    ``order.shape[1]``: what every slab's walk is made of, made once
+    (``_slabs``) and kept for the backward as the one walk's plan is.
+    ``walk(t)`` is trip ``t``'s, in the form of the one walk over all
+    rows."""
+    held: jnp.ndarray       # [N, top_k] bool: the assignment is to an
+    #                         expert held here
+    sizes: jnp.ndarray      # [slabs, E] int32: each slab's rows by group
+    order: jnp.ndarray      # [slabs, slab] int32: sorted row -> assignment
+    back: jnp.ndarray       # [N * top_k] int32: assignment -> sorted row
+    live: jnp.ndarray       # int32: the sorted rows that hold an
+    #                         assignment to an expert held here, the first
+    trips: jnp.ndarray      # int32: the slabs that hold one of them, >= 1
+    kernels: bool           # the layer's choice of product (static)
+
+    def walk(self, t):
+        i32 = jnp.int32
+        slab = self.order.shape[1]
+        lo = t * i32(slab)
+        sizes = lax.dynamic_index_in_dim(self.sizes, t, keepdims=False)
+        # home come only the assignments whose sorted row lies in this
+        # slab, from their row inside it; a row that no trip walks is an
+        # absent assignment's
+        here = (self.back >= lo) & (self.back < lo + i32(slab))
+        return (self.held & here.reshape(self.held.shape), sizes,
+                lax.dynamic_index_in_dim(self.order, t, keepdims=False),
+                jnp.clip(self.back - lo, 0, i32(slab - 1)),
+                (lo + jnp.arange(slab, dtype=i32) < self.live)[:, None],
+                _slab_tables(sizes, slab) if self.kernels else None)
+
+
+def _slabs(held, first, order, back, trips, slab: int, kernels: bool):
+    """``_Slabs`` of the sorted rows ``[t * slab, (t + 1) * slab)`` for
+    every ``t``: the groups' rows clipped to the slab and what is left of
+    it added to the last group, so that a slab's sizes sum to ``slab`` and
+    every walked row is multiplied, as in the one walk (a trip's time does
+    not follow the router); the last slab may run past the rows there are,
+    into rows that no assignment holds (``order`` 0 there, never live)."""
+    i32 = jnp.int32
+    e = first.shape[0] - 1
+    n_slabs = -(-order.shape[0] // slab)
+    lo = (jnp.arange(n_slabs, dtype=i32) * i32(slab))[:, None]
+    at = jnp.clip(first[None, :], lo, lo + i32(slab)) - lo
+    sizes = (at[:, 1:] - at[:, :-1]).at[:, e - 1].add(i32(slab) - at[:, e])
+    order = jnp.pad(order, (0, n_slabs * slab - order.shape[0]))
+    return _Slabs(held, sizes, order.reshape(n_slabs, slab), back, first[e],
+                  trips, kernels)
+
+
+# a trip's tables are made in the loop's body, from its own sizes (as many
+# table gathers a layer and step as the one walk's plan, which the op makes
+# once and its grad op again); jitted and inlined, so that the hundred small
+# operations are traced once a process and not once a body
+@functools.partial(jax.jit, static_argnums=(1,), inline=True)
+def _slab_tables(sizes, slab: int):
+    return _tables(sizes, slab, True)
+
+
+def _walks(walk, top_k, operands, plan, sums):
+    """``walk(top_k, operands, plan of a walk, types, looped)`` summed over
+    the trips of the layer.  ``sums``: for each result ``(operand whose
+    shape and type it leaves in, type the trips are summed in)``; ``types``
+    is what a walk hands its results over in, ``looped`` whether it is a
+    loop's body.  One slab of every row: the walk itself, handed over in
+    the operands' types.  Else ``_looped``."""
+    if not isinstance(plan, _Slabs):
+        return walk(top_k, operands, plan,
+                    tuple(of.dtype for of, _ in sums), False)
+    from .. import observe
+    from ..ops import kernel_choice
+
+    leaves, tree = jax.tree_util.tree_flatten((operands, plan))
+    key = (walk, top_k, tuple((of.shape, jnp.dtype(of.dtype), jnp.dtype(to))
+                              for of, to in sums),
+           plan.kernels and kernel_choice.interpret(), tree,
+           tuple((t.shape, t.dtype, t.weak_type)
+                 for t in map(jax.typeof, leaves)))
+    made = getattr(_TRACES, "made", 0)
+    total = _looped(key, operands, plan)
+    if getattr(_TRACES, "made", 0) == made:
+        # the kept trace served this call: its counters count a trace
+        # (``ops.moe.row_moves``, ``ops.moe.column_tiles``), so a layer
+        # and pass count what they counted while it was made
+        with _COUNTED_LOCK:
+            counted = _COUNTED.get(key, ())
+        observe.registry().replay(counted)
+    return total
+
+
+#: the traces of ``_looped`` a thread has made, and what each counted while
+#: it was made, by what decides a trace (``_walks``' ``key``)
+_TRACES = threading.local()
+_COUNTED: dict = {}
+_COUNTED_LOCK = threading.Lock()
+
+
+@functools.partial(jax.jit, static_argnums=(0,), inline=True)
+def _looped(key, operands, plan):
+    """ONE ``lax.while_loop`` whose body is the walk: every result of a
+    walk is a sum over sorted rows and a sorted row lies in one slab, so the
+    trips' results add up, from zeros of the body's own types (a carry of
+    another type, or a weak one, would have the body traced a second time
+    to promote it).  An inlined ``jax.jit``: the layers of a model, the op
+    and its grad op, and the programs of a process that walk operands of
+    equal types share ONE trace of the loop and its body and each lowers
+    its own copy (``ops/pallas_grouped``'s kernels do the same a call; on
+    the v5e's host a body costs 0.14 s to trace, PERF.md section 6, PR 56,
+    and a step of four routed layers and its comparison held 24).  ``key``
+    is all that decides the trace."""
+    from .. import observe
+
+    walk, top_k, sums = key[:3]
+    types = tuple(to for _, _, to in sums)
+    with observe.registry().tape() as counted:
+        def trip(carry):
+            t, so_far = carry
+            return t + jnp.int32(1), tuple(a + b for a, b in zip(
+                so_far, walk(top_k, operands, plan.walk(t), types, True)))
+
+        _, total = lax.while_loop(
+            lambda carry: carry[0] < plan.trips, trip,
+            (jnp.int32(0), tuple(jnp.zeros(shape, to)
+                                 for shape, _, to in sums)))
+    with _COUNTED_LOCK:
+        _COUNTED[key] = tuple(counted)
+    _TRACES.made = getattr(_TRACES, "made", 0) + 1
+    return tuple(a.astype(dtype) for a, (_, dtype, _) in zip(total, sums))
+
+
+def _wide(of):
+    return jnp.promote_types(of.dtype, jnp.float32)
 
 
 # XLA's grouped product on the TPU leaves the rows outside every group
@@ -270,24 +488,42 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
 # rows anyway.  The sorted rows themselves need none: a row without a held
 # assignment is some token's row, read by products whose results are
 # selected away and met by exact zeros in the weights' gradients.  Right
-# whatever ``sizes`` covers.
-def _sorted_and_hidden(top_k, xt, w1, w3, w2, plan, which):
-    """What both passes of ``_share`` make first: the tokens' rows sorted by
-    expert, ``xs`` [rows, D] in AMP's type; the two hidden products of
-    them, float32 [rows, F], selected by ``live`` (``_gated`` of the two is
-    the experts' hidden rows); and the three weights in AMP's type."""
-    from ..fluid import amp
-
+# whatever ``sizes`` covers, and for every slab: a sorted row that no trip
+# walks is an absent assignment's.
+def _sorted_and_hidden(top_k, low, weights, plan, which):
+    """What a walk of either pass of ``_share`` makes first: the tokens'
+    rows ``low`` [N, D] sorted by expert, ``xs`` [rows, D]; and the two
+    hidden products of them with ``weights[:2]``, float32 [rows, F],
+    selected by ``live`` (``_gated`` of the two is the experts' hidden
+    rows).  Operands in AMP's type (``_low``)."""
     _, sizes, order, _, live, tables = plan
-    low, *weights, _ = amp.cast_operands(xt, w1, w3, w2)
     xs = _rows_out(low, order, top_k, which)
     a, b = (jnp.where(live, grouped_product(xs, w, sizes, tables), 0)
             .astype(jnp.float32) for w in weights[:2])
-    return xs, a, b, weights
+    return xs, a, b
+
+
+def _low(xt, w1, w3, w2):
+    # the tokens' rows and the three weights in AMP's type, once a pass
+    from ..fluid import amp
+
+    low, *weights, _ = amp.cast_operands(xt, w1, w3, w2)
+    return low, weights
 
 
 def _gated(a, b):
     return jax.nn.silu(a) * b
+
+
+def _forward_walk(top_k, operands, plan, types, looped):
+    low, weights, gate = operands
+    held, sizes, _, back, _, tables = plan
+    xs, a, b = _sorted_and_hidden(top_k, low, weights, plan, "forward")
+    ys = grouped_product(_gated(a, b).astype(xs.dtype), weights[2],
+                         sizes, tables)  # [rows, D]
+    # back to assignment order, weighted, summed over a token's choices
+    return jnp.einsum("nk,nkd->nd", gate, _rows_home(
+        ys, back, held, "forward").astype(jnp.float32)).astype(types[0]),
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -295,15 +531,12 @@ def _share(top_k, xt, gate, w1, w3, w2, plan):
     """[N, D] float32: the held experts' rows of ``xt`` [N, D], weighted by
     ``gate`` [N, top_k] (0 where an assignment is absent) and summed over a
     token's choices; ``plan``: what ``routed_experts`` made of the router's
-    choices.  Its backward is its own (``_share_bwd``)."""
-    held, sizes, _, back, _, tables = plan
-    xs, a, b, (_, _, a2) = _sorted_and_hidden(top_k, xt, w1, w3, w2, plan,
-                                              "forward")
-    ys = grouped_product(_gated(a, b).astype(xs.dtype), a2, sizes,
-                         tables)  # [N*k, D]
-    # back to assignment order, weighted, summed over a token's choices
-    return jnp.einsum("nk,nkd->nd", gate, _rows_home(
-        ys, back, held, "forward").astype(jnp.float32))
+    choices, the one walk's or a ``_Slabs``.  Its backward is its own
+    (``_share_bwd``)."""
+    low, weights = _low(xt, w1, w3, w2)
+    y = jax.ShapeDtypeStruct(xt.shape, jnp.float32)
+    return _walks(_forward_walk, top_k, (low, tuple(weights), gate), plan,
+                  ((y, jnp.float32),))[0]
 
 
 def _share_fwd(top_k, xt, gate, w1, w3, w2, plan):
@@ -313,6 +546,48 @@ def _share_fwd(top_k, xt, gate, w1, w3, w2, plan):
             (xt, gate, w1, w3, w2, plan))
 
 
+def _backward_walk(top_k, operands, plan, types, looped):
+    low, (a1, a3, a2), gate, dy = operands
+    held, sizes, order, back, live, tables = plan
+    xs, a, b = _sorted_and_hidden(top_k, low, (a1, a3), plan, "backward")
+    h, silu_bwd = jax.vjp(_gated, a, b)
+    # the combine's cotangent goes out to the sorted rows from [N, D], as
+    # the tokens' rows do, and meets the gate on the hidden side
+    dy_s = _rows_out(dy.astype(low.dtype), order, top_k, "backward")
+    gate_s = _permuted(gate.reshape(-1), order)[:, None]
+    hg = (gate_s * h).astype(low.dtype)
+    to_h, to_w2 = product_transposes(hg, a2, sizes, tables)
+    u = jnp.where(live, to_h(dy_s), 0).astype(jnp.float32)
+    # <dy, ys> over a row is <dy @ w2^T, h> over the same row
+    dgate_s = jnp.sum(u * h, axis=1)
+    da, db = (d.astype(low.dtype) for d in silu_bwd(gate_s * u))
+    to_xs1, to_w1 = product_transposes(xs, a1, sizes, tables)
+    to_xs3, to_w3 = product_transposes(xs, a3, sizes, tables)
+
+    def weights_gradients():
+        return (to_w1(xs, da).astype(types[2]),
+                to_w3(xs, db).astype(types[3]),
+                to_w2(hg, dy_s).astype(types[4]))
+
+    if looped:
+        # in a loop's body the weights' gradients come FIRST, behind a
+        # barrier: the compiler schedules a body by itself and put them
+        # last, with the sorted rows, the sorted cotangent and the hidden
+        # rows alive across the rows' cotangents and their gather home, the
+        # body's fullest point and the step's (my described-chip compiles,
+        # PR 56: 4,946 MB of reserved memory in Trinity's step without the
+        # barrier, 4,813 MB with it, 4,864 MB at the parent)
+        dws = weights_gradients()
+        da, db, dws = lax.optimization_barrier((da, db, dws))
+    dxt = jnp.sum(_rows_home(to_xs1(da) + to_xs3(db), back, held,
+                             "backward"), axis=1,
+                  dtype=jnp.promote_types(types[0], jnp.float32))
+    dgate = jnp.where(held, _permuted(dgate_s, back).reshape(held.shape), 0)
+    if not looped:
+        dws = weights_gradients()
+    return dxt.astype(types[0]), dgate.astype(types[1]), *dws
+
+
 def _share_bwd(top_k, kept, dy):
     # behind a barrier, as ``jax.checkpoint`` puts one: without it XLA
     # finds the sorted rows and the hidden products below to be the
@@ -320,30 +595,21 @@ def _share_bwd(top_k, kept, dy):
     # cotangent in it, or XLA makes them as soon as the layer's inputs are
     # there, before the loss, and they lie across the step's fullest point
     (xt, gate, w1, w3, w2, plan), dy = lax.optimization_barrier((kept, dy))
-    held, sizes, order, back, live, tables = plan
-    xs, a, b, (a1, a3, a2) = _sorted_and_hidden(top_k, xt, w1, w3, w2, plan,
-                                                "backward")
-    low = xs.dtype
-    h, silu_bwd = jax.vjp(_gated, a, b)
-    # the combine's cotangent goes out to the sorted rows from [N, D], as
-    # the tokens' rows do, and meets the gate on the hidden side
-    dy_s = _rows_out(dy.astype(low), order, top_k, "backward")
-    gate_s = _permuted(gate.reshape(-1), order)[:, None]
-    hg = (gate_s * h).astype(low)
-    to_h, to_w2 = product_transposes(hg, a2, sizes, tables)
-    u = jnp.where(live, to_h(dy_s), 0).astype(jnp.float32)
-    # <dy, ys> over a row is <dy @ w2^T, h> over the same row
-    dgate_s = jnp.sum(u * h, axis=1)
-    da, db = (d.astype(low) for d in silu_bwd(gate_s * u))
-    to_xs1, to_w1 = product_transposes(xs, a1, sizes, tables)
-    to_xs3, to_w3 = product_transposes(xs, a3, sizes, tables)
-    dxt = jnp.sum(_rows_home(to_xs1(da) + to_xs3(db), back, held,
-                             "backward"),
-                  axis=1, dtype=jnp.promote_types(xt.dtype, jnp.float32))
-    dgate = jnp.where(held, _permuted(dgate_s, back).reshape(held.shape), 0)
-    return (dxt.astype(xt.dtype), dgate.astype(gate.dtype),
-            to_w1(xs, da).astype(w1.dtype), to_w3(xs, db).astype(w3.dtype),
-            to_w2(hg, dy_s).astype(w2.dtype), None)
+    low, (a1, a3, a2) = _low(xt, w1, w3, w2)
+    # the rows' cotangents are summed over the trips in float32; a weights'
+    # gradient in the type its product hands it over in (AMP's: the kernel
+    # sums a group's rows in float32 and rounds ONCE a call, as in the one
+    # walk).  An expert's rows are consecutive, so its gradient is one
+    # trip's and exact zeros from the others, unless its rows lie across a
+    # slab's edge: then two rounded parts are added and rounded once more.
+    # Summed in float32 the three [E, D, F] gradients would wait for the
+    # optimizer at twice their bytes, where the one walk keeps them in
+    # AMP's type until the sweep reads them (PERF.md section 6, PR 55: 1.2%
+    # of Trinity's peak memory, 0.6 GB of Qwen3-Next's step)
+    return (*_walks(_backward_walk, top_k, (low, (a1, a3, a2), gate, dy),
+                    plan, ((xt, _wide(xt)), (gate, _wide(gate)),
+                           (w1, a1.dtype), (w3, a3.dtype), (w2, a2.dtype))),
+            None)
 
 
 _share.defvjp(_share_fwd, _share_bwd)
@@ -369,23 +635,28 @@ def _rows_home(rows, back, held, which: str):
                      _permuted(rows, back).reshape(held.shape + (-1,)), 0)
 
 
-def _publish_load(live, rows: int, fullest):
+def _publish_load(live, walked, fullest, trips=None):
     # the layer's load as step gauges (``observe.step_gauge``; the label
     # ``scope`` comes from the op), from what the plan already holds: the
-    # assignments that chose an expert held here, the rows walked, the
-    # fullest held expert (with ``live_rows / held`` the operator's max
-    # over mean).  Device values that leave the step unfetched;
+    # assignments that chose an expert held here, the rows walked (``slab *
+    # trips``, a device value; all ``N * top_k``, a constant, where no loop
+    # is built), the fullest held expert (with ``live_rows / held`` the
+    # operator's max over mean) and, where the layer walks in slabs, the
+    # trips of its loop: 1 in an ordinary step, more once the router has
+    # drifted onto this chip.  Device values that leave the step unfetched;
     # ``step_gauge`` never fails the trace it measures.
     from .. import observe
 
     observe.step_gauge("ops.moe.live_rows", live)
-    observe.step_gauge("ops.moe.rows", rows)
+    observe.step_gauge("ops.moe.rows", walked)
     observe.step_gauge("ops.moe.fullest_group", fullest)
+    if trips is not None:
+        observe.step_gauge("ops.moe.slab_trips", trips)
 
 
 def _count_row_move(which: str):
-    # one for every [N * top_k, D] gather traced: two in a forward, three
-    # in a backward
+    # one for every row gather traced: two in a walk of the forward, three
+    # in one of the backward, and a pass traces ONE walk whatever its trips
     try:
         from .. import observe
 

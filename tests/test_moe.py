@@ -143,3 +143,474 @@ def test_moe_expert_param_specs():
     for n in expert_params:
         assert specs[n] is not None and tuple(specs[n])[0] == "ep", \
             (n, specs[n])
+
+
+# == the routed share walks its sorted rows a slab at a time ================
+# (``parallel/moe.py`` ``slab_rows``, ``_Slabs``, ``_walks``, ``_looped``): ``8 *
+# held / routed`` of the ``N * top_k`` rows a trip of ONE ``lax.while_loop``
+# a pass, one trip while the assignments to the experts held fit a slab,
+# exact beyond it, and the one walk with no loop where that is every row.
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+
+from paddle_tpu.ops import pallas_grouped  # noqa: E402
+from paddle_tpu.parallel import moe  # noqa: E402
+
+#: 512 tokens x 4 choices = 2,048 rows, 4 of 128 experts held from expert 8
+#: on: a slab of 8 * 2,048 * 4 / 128 = 512 rows, four of them at most
+TOKENS, ROUTED, HELD, TOP_K, OFFSET = 512, 128, 4, 4, 8
+ROWS, SLAB = TOKENS * TOP_K, 512
+#: the selection bias of each regime (``route_top_k``: it chooses and does
+#: not weigh): ``(held experts every token takes, whether the other held
+#: experts are shut, trips)``
+REGIMES = {"under_one_slab": (0, False, 1), "exactly_one_slab": (1, True, 1),
+           "two_trips": (1, False, 2), "three_trips": (3, True, 3),
+           "every_assignment_held": (4, False, 4), "no_live_row": (0, True, 1)}
+
+
+def slab_counters(prefix):
+    return {k: v for k, v in fluid.profiler.counters().items()
+            if k.startswith(prefix)}
+
+
+def slab_operands(regime, width, seed=0):
+    rng = np.random.RandomState(seed)
+    x = jnp.asarray(rng.randn(TOKENS, width), jnp.float32)
+    wr = jnp.asarray(rng.randn(width, ROUTED) / np.sqrt(width), jnp.float32)
+    w1, w3, w2 = (jnp.asarray(rng.randn(HELD, width, width) / np.sqrt(width),
+                              jnp.float32) for _ in range(3))
+    taken, shut, trips = REGIMES[regime]
+    bias = jnp.zeros(ROUTED, jnp.float32)
+    if shut:
+        bias = bias.at[OFFSET:OFFSET + HELD].set(-50.0)
+    bias = bias.at[OFFSET:OFFSET + taken].set(50.0)
+    mix = jnp.asarray(rng.randn(TOKENS, width), jnp.float32)
+    return (x, wr, w1, w3, w2), dict(top_k=TOP_K, expert_offset=OFFSET,
+                                     bias=bias), mix, trips
+
+
+def unsorted_share(x, wr, w1, w3, w2, top_k, expert_offset, bias):
+    """The share with nothing sorted and nothing grouped, in float32: every
+    token through every held expert, weighted by what the router gave that
+    expert (0 where it was not chosen), differentiated by jax."""
+    vals, idx = moe.route_top_k(x, wr, top_k, bias=bias)
+    high = jax.lax.Precision.HIGHEST
+    y = 0.0
+    for j in range(w1.shape[0]):
+        weight = jnp.sum(jnp.where(idx == expert_offset + j, vals, 0.0), 1)
+        hidden = jax.nn.silu(jnp.matmul(x, w1[j], precision=high)) \
+            * jnp.matmul(x, w3[j], precision=high)
+        y = y + weight[:, None] * jnp.matmul(hidden, w2[j], precision=high)
+    return y
+
+
+def value_and_cotangents(fn, args, kw, mix):
+    """(the layer's result [N, D], its five cotangents under ``mix``)."""
+    def mixed(*a):
+        y = fn(*a, **kw)
+        return jnp.sum(mix * y), y
+
+    (_, y), grads = jax.value_and_grad(mixed, range(5), has_aux=True)(*args)
+    return y, grads
+
+
+@pytest.mark.parametrize("n,top_k,held,routed,balanced,slab", [
+    (6144, 8, 8, 128, True, 24576),     # trinity_mini: 48 tiles of 49,152
+    (2048, 8, 8, 256, True, 4096),      # kimi_linear_48b_a3b: of 16,384
+    (8192, 10, 16, 512, False, 81920),  # qwen3_next_80b_a3b: no bias, all
+    (8192, 8, 16, 128, False, 65536),   # keye_vl_2_0_30b_a3b: every row
+    (8192, 4, 8, 32, True, 32768),      # lfm2_8b_a1b: every row
+    (8192, 6, 8, 64, True, 49152),      # instella_moe_16b_a3b: every row
+    (8192, 8, 8, 64, False, 65536),     # mellum2_12b_a2_5b: every row
+])
+def test_the_slab_of_each_cell(n, top_k, held, routed, balanced, slab):
+    """The rows a trip walks in the seven MoE cells, from ``(N, top_k, held,
+    routed)`` and whether the router has a balancing bias: a slab in
+    Trinity and Kimi-Linear; every row where an eighth of the experts or
+    more is held, and for now in a layer without a bias (``slab_rows``'
+    DEBT: Qwen3-Next's shapes alone give 20,480 of its 81,920, and its step
+    with those loops in it reserves 1.07 GiB more)."""
+    assert moe.SLAB_OVER_EVEN == 8
+    assert moe.slab_rows(n * top_k, held, routed, True, balanced) == slab
+    assert slab % pallas_grouped.ROW_TILE == 0
+    assert moe.slab_rows(8192 * 10, 16, 512, True) == 20480
+    # all experts held, or fewer rows than a row tile: every row
+    assert moe.slab_rows(n * top_k, routed, routed, True) == n * top_k
+    assert moe.slab_rows(6, 1, 64, True) == 6
+    # XLA's grouped product takes any count: the even share's, rounded up
+    assert moe.slab_rows(100, 1, 64, False) == 13
+
+
+@pytest.mark.parametrize("regime", sorted(REGIMES))
+@pytest.mark.parametrize("path", ["ragged_dot", "pallas"])
+def test_slabs_equal_the_one_walk_and_the_unsorted_share(monkeypatch, path,
+                                                         regime):
+    """Value and all five cotangents (x, router, w1, w3, w2) of the layer in
+    slabs of 512 of its 2,048 rows against the one walk over all rows (the
+    constant out of the way) and against the float32 share that sorts
+    nothing, to the tolerance of the hand-written backward's own tests:
+    live rows under one slab, exactly one slab, two to four trips up to
+    every assignment held, and no live row; under a selection bias, with
+    ``Counts``."""
+    width = 128 if path == "pallas" else 16
+    args, kw, mix, trips = slab_operands(regime, width)
+    if path == "ragged_dot":
+        monkeypatch.setattr(pallas_grouped, "supported", lambda *a: "off")
+    assert moe.product_path(args[0], args[2], args[4], TOP_K) == path
+    assert moe.slab_rows(ROWS, HELD, ROUTED, path == "pallas") == SLAB
+    _, landed = moe.routed_experts(*args, **kw, with_counts=True)
+    np.testing.assert_array_equal(landed, moe.assignment_counts(
+        moe.route_top_k(args[0], args[1], TOP_K, bias=kw["bias"])[1], ROUTED))
+    live = int(landed[OFFSET:OFFSET + HELD].sum())
+    assert max(1, -(-live // SLAB)) == trips
+    assert (live == SLAB) == (regime == "exactly_one_slab")
+    assert (live == ROWS) == (regime == "every_assignment_held")
+    assert (live == 0) == (regime == "no_live_row")
+
+    got = value_and_cotangents(moe.routed_experts, args, kw, mix)
+    want = value_and_cotangents(unsorted_share, args, kw, mix)
+    monkeypatch.setattr(moe, "SLAB_OVER_EVEN", ROUTED)
+    assert moe.slab_rows(ROWS, HELD, ROUTED, path == "pallas") == ROWS
+    whole = value_and_cotangents(moe.routed_experts, args, kw, mix)
+    for other in (whole, want):
+        for name, g, r in zip("y x router w1 w3 w2".split(),
+                              (got[0], *got[1]), (other[0], *other[1])):
+            assert g.dtype == r.dtype == jnp.float32
+            assert np.abs(g - r).max() <= 1e-5 * np.abs(r).max(), name
+            assert bool(np.any(np.asarray(r))) == (live > 0), name
+
+
+def test_a_tokens_choices_straddle_two_slabs():
+    """Every token takes the held experts 8 and 9 and no other held one:
+    expert 8's 512 assignments are the first slab and expert 9's the
+    second, so EVERY token's sum over its choices, and its cotangent, is
+    made of two trips' parts."""
+    args, kw, mix, _ = slab_operands("exactly_one_slab", 16)
+    kw["bias"] = kw["bias"].at[OFFSET + 1].set(50.0)
+    _, idx = moe.route_top_k(args[0], args[1], TOP_K, bias=kw["bias"])
+    took = np.asarray(idx)
+    for expert in range(OFFSET, OFFSET + HELD):
+        assert (took == expert).any(axis=1).all() == (expert < OFFSET + 2)
+        assert (took == expert).any() == (expert < OFFSET + 2)
+    assert moe.slab_rows(ROWS, HELD, ROUTED, False) == SLAB == TOKENS
+    got = value_and_cotangents(moe.routed_experts, args, kw, mix)
+    want = value_and_cotangents(unsorted_share, args, kw, mix)
+    for name, g, r in zip("y x router w1 w3 w2".split(),
+                          (got[0], *got[1]), (want[0], *want[1])):
+        assert np.abs(g - r).max() <= 1e-5 * np.abs(r).max(), name
+        assert np.any(np.asarray(r)), name
+    # the experts 10 and 11 are held and chosen by no token
+    assert not np.any(np.asarray(got[1][2][2:]))
+
+
+def test_every_held_assignment_lies_in_one_slab_and_sizes_sum_to_it():
+    """``_slabs`` alone, with values: a slab's sizes are the groups' rows
+    clipped to it with what is left in the last group, its live rows the
+    first ``live`` sorted rows, and home come only the assignments sorted
+    into it, from their row inside it; the last slab runs past the rows
+    there are."""
+    rng = np.random.RandomState(3)
+    e, slab, rows = 3, 8, 28
+    group = jnp.asarray(rng.randint(0, e + 1, rows), jnp.int32)
+    counts = jnp.bincount(group, length=e + 1).astype(jnp.int32)
+    first = jnp.cumsum(counts, dtype=jnp.int32) - counts
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    back = jnp.argsort(order).astype(jnp.int32)
+    held = (group < e).reshape(rows // 2, 2)
+    plan = moe._slabs(held, first, order, back, jnp.int32(4), slab, False)
+    assert plan.order.shape == (4, slab) and not plan.kernels
+    assert plan.sizes.dtype == jnp.int32
+    padded = np.asarray(plan.order).reshape(-1)
+    np.testing.assert_array_equal(padded[:rows], order)
+    live_rows, seen = int(first[e]), np.zeros(rows, int)
+    for t in range(4):
+        held_t, sizes, order_t, back_t, live, tables = plan.walk(jnp.int32(t))
+        assert tables is None and int(sizes.sum()) == slab
+        at = np.arange(t * slab, (t + 1) * slab)
+        np.testing.assert_array_equal(np.asarray(live)[:, 0], at < live_rows)
+        mine = np.asarray(group)[padded[at]]
+        for g in range(e):
+            assert int(sizes[g]) == int(np.sum(mine[at < live_rows] == g)) \
+                + (int(np.sum(at >= live_rows)) if g == e - 1 else 0)
+        flat = np.asarray(held_t).reshape(-1)
+        seen += flat
+        # an assignment that comes home reads the row it was sorted to
+        np.testing.assert_array_equal(
+            np.asarray(order_t)[np.asarray(back_t)[flat]],
+            np.arange(rows)[flat])
+    np.testing.assert_array_equal(seen, np.asarray(held).reshape(-1))
+
+
+def equations(jaxpr, looped=False):
+    """``(equation, whether it lies inside a ``while``)`` for every equation
+    of a jaxpr and of its inner jaxprs, a kernel's own body left out (its
+    chunk loop is no loop of the layer's)."""
+    for eqn in jaxpr.eqns:
+        yield eqn, looped
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from equations(
+                        sub, looped or eqn.primitive.name == "while")
+
+
+def loops(jaxpr):
+    return [eqn for eqn, _ in equations(jaxpr)
+            if eqn.primitive.name == "while"]
+
+
+def kernel_calls(jaxpr, inside_loops):
+    """How often each Pallas kernel stands in a jaxpr, by name, in or out
+    of the layer's loops, and the rows its operands have."""
+    calls, rows = {}, set()
+    for eqn, looped in equations(jaxpr):
+        if eqn.primitive.name == "pallas_call" and looped == inside_loops:
+            calls[eqn.params["name"]] = calls.get(eqn.params["name"], 0) + 1
+            rows |= {v.aval.shape[0] for v in eqn.invars if v.aval.ndim == 2}
+    return calls, rows
+
+
+@pytest.fixture
+def fresh_traces():
+    """The process's kept traces dropped before and after: a test that
+    watches a walk being traced, or swaps a function under it, must not be
+    served a trace an earlier test made (``moe._looped``,
+    ``pallas_grouped._matmul``: inlined ``jax.jit``s)."""
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("regime", ["under_one_slab", "three_trips"])
+def test_a_pass_traces_its_walk_once(monkeypatch, fresh_traces, regime):
+    """Whatever the trips, the first lowering of the layer and its backward
+    traces the walk ONCE a pass, as the one walk over all rows is traced:
+    two row gathers forward and three backward, 8 ``grouped_matmul`` and 3
+    ``grouped_matmul_t`` with their column tiles counted once each, all in
+    the bodies of the two loops (one a pass), over the slab's rows
+    (``test_every_row_in_one_slab_lowers_to_the_parents_text`` counts the
+    ``while``s of a lowered text, where no kernel is interpreted).  And a
+    PROCESS traces it once: the backward's forward, the next layer of equal
+    operand types and the next program are served the kept trace
+    (``moe._looped``), lower their own copy of it, and count what it
+    counted."""
+    args, kw, mix, _ = slab_operands(regime, 128)
+    assert moe.product_path(args[0], args[2], args[4], TOP_K) == "pallas"
+    walks = []
+    walk = moe._sorted_and_hidden
+    monkeypatch.setattr(moe, "_sorted_and_hidden", lambda *a: (
+        walks.append(a[-1]), walk(*a))[1])
+
+    def layer(*a):
+        return jnp.sum(mix * moe.routed_experts(*a, **kw))
+
+    forward = jax.make_jaxpr(layer)(*args).jaxpr
+    assert walks == ["forward"]
+    assert kernel_calls(forward, True) == ({"grouped_matmul": 3}, {SLAB})
+    assert len(loops(forward)) == 1
+    assert slab_counters("ops.moe.row_moves") == {
+        'ops.moe.row_moves{how="gather",pass="forward"}': 2}
+    tiles = sum(slab_counters("ops.moe.column_tiles").values())
+    assert tiles == 3
+    for lowering in (1, 2):
+        del walks[:]
+        both = jax.make_jaxpr(jax.grad(layer, range(5)))(*args).jaxpr
+        # the forward's walk is the kept one; so is the backward's the
+        # second time
+        assert walks == (["backward"] if lowering == 1 else [])
+        assert kernel_calls(both, True) == (
+            {"grouped_matmul": 8, "grouped_matmul_t": 3}, {SLAB})
+        assert kernel_calls(both, False) == ({}, set())
+        assert len(loops(both)) == 2
+        assert slab_counters("ops.moe.row_moves") == {
+            'ops.moe.row_moves{how="gather",pass="forward"}': 2 + 2 * lowering,
+            'ops.moe.row_moves{how="gather",pass="backward"}': 3 * lowering}
+        assert sum(slab_counters("ops.moe.column_tiles").values()
+                   ) == 3 + 11 * lowering
+    # a layer of other operand types is another trace
+    del walks[:]
+    jax.make_jaxpr(layer)(args[0].astype(jnp.bfloat16), *args[1:])
+    assert walks == ["forward"]
+
+
+def test_a_kept_trace_lowers_to_the_text_of_a_fresh_one(fresh_traces):
+    """What a call served by the kept trace lowers to is what the call
+    that made the trace lowered to, character for character, and two
+    layers of one program each hold their own loops."""
+    args, kw, mix, _ = slab_operands("two_trips", 64)
+
+    def layers(*a):
+        y = moe.routed_experts(*a, **kw)
+        return jnp.sum(mix * moe.routed_experts(y, *a[1:], **kw))
+
+    def step():
+        return jax.jit(jax.value_and_grad(layers, range(5)))
+
+    fresh = step().lower(*args).as_text()
+    assert fresh.count("stablehlo.while") == 4
+    assert step().lower(*args).as_text() == fresh
+
+
+def all_rows_share(x, router_w, w1, w3, w2, top_k, expert_offset=0,
+                   norm_topk=True, score="softmax", bias=None, norm_eps=0.0,
+                   scale=1.0, with_counts=False):
+    """THE PARENT'S FORMULA of ``moe.routed_experts``, kept: the plan of one
+    walk over all ``N * top_k`` rows as it stood before the layer walked in
+    slabs (the absent experts' assignments as zero rows at the end of the
+    last held group), its three gauges, handed to ``moe._share`` as it
+    is."""
+    shape = x.shape
+    e = w1.shape[0]
+    xt = x.reshape((-1, shape[-1]))
+    n = xt.shape[0]
+    vals, idx = moe.route_top_k(xt, router_w, top_k, norm_topk, score, bias,
+                                norm_eps, scale)
+    local = idx - jnp.int32(expert_offset)
+    held = (local >= 0) & (local < e)
+    group = jnp.where(held, local, e).reshape(-1)
+    i32 = jnp.int32
+    chose = (group[:, None] == jnp.arange(e + 1, dtype=i32)).astype(i32)
+    counts = jnp.sum(chose, axis=0, dtype=i32)
+    first = jnp.cumsum(counts, dtype=i32) - counts
+    back = jnp.sum(chose * (first[None, :] - 1
+                            + jnp.cumsum(chose, axis=0, dtype=i32)),
+                   axis=1, dtype=i32)
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    live = (jnp.arange(order.shape[0], dtype=i32) < first[e])[:, None]
+    sizes = counts[:e].at[e - 1].add(counts[e])
+    moe._publish_load(first[e], n * top_k, jnp.max(counts[:e]))
+    gate = jnp.where(held, vals, 0.0)
+    tables = None
+    if moe.product_path(x, w1, w2, top_k) == "pallas":
+        tables = pallas_grouped.plan(sizes, n * top_k)
+    y = moe._share(top_k, xt, gate, w1, w3, w2,
+                   (held, sizes, order, back, live, tables))
+    y = y.astype(x.dtype).reshape(shape)
+    if with_counts:
+        return y, moe.assignment_counts(idx, router_w.shape[-1])
+    return y
+
+
+@pytest.mark.parametrize("program", ["decoder", "decoder_with_bias",
+                                     "no_routed_layer"])
+def test_a_step_with_every_row_in_one_slab_lowers_to_the_parents(
+        monkeypatch, program):
+    """Whole training steps, lowered through ``Executor.lower_step``: the
+    tiny decoder (4 of 8 experts held, softmax router) and the same with a
+    sigmoid router under a balancing bias lower, with the gauges in the
+    step, to the text they lower to with the parent's formula in the
+    layer's place, and hold no ``while``; a program with no routed layer
+    never reaches the layer."""
+    from paddle_tpu.fluid import layers
+    from paddle_tpu.models import decoder_lm
+
+    if program == "no_routed_layer":
+        x = layers.data(name="x", shape=[16, 32], dtype="float32")
+        loss = layers.mean(layers.square(layers.fc(x, size=32)))
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+        feed = {"x": np.random.RandomState(0).randn(2, 16, 32)
+                .astype("float32")}
+    else:
+        cfg = decoder_lm.tiny_config()
+        if program == "decoder_with_bias":
+            cfg.router_score, cfg.route_bias_coeff = "sigmoid", 1e-3
+        assert 8 * cfg.experts_held >= cfg.num_routed
+        _, _, loss = decoder_lm.build(cfg, seq_len=16)
+        ids = np.random.RandomState(0).randint(
+            0, cfg.vocab_size, size=(1, 17)).astype(np.int64)
+        feed = {"tokens": ids[:, :-1], "labels": ids[:, 1:, None]}
+    exe = fluid.Executor(fluid.TPUPlace())
+    exe.run(fluid.default_startup_program())
+    main = fluid.default_main_program()
+    mine = exe.lower_step(main, feed, [loss]).as_text()
+    called = []
+    monkeypatch.setattr(moe, "routed_experts", lambda *a, **kw: (
+        called.append(1), all_rows_share(*a, **kw))[1])
+    parents = exe.lower_step(main, feed, [loss]).as_text()
+    assert mine == parents
+    assert bool(called) == (program != "no_routed_layer")
+    assert "stablehlo.while" not in mine
+
+
+@pytest.mark.parametrize("held,routed,offset,width,bias", [
+    (16, 128, 8, 16, True), (8, 8, 0, 16, True), (4, 16, 4, 128, True),
+    (2, 128, 8, 16, False), (2, 128, 8, 16, True)])
+def test_every_row_in_one_slab_lowers_to_the_parents_text(held, routed,
+                                                          offset, width,
+                                                          bias):
+    """An eighth of the experts held or more (all of them; a quarter, on
+    the kernels), or a sixty-fourth under a router with no balancing bias:
+    the slab is every row, the decision is static, no ``while`` is lowered
+    in either pass and the text of the layer and its backward equals, byte
+    for byte, the text of the parent's formula kept above.  Two of 128 held
+    under a bias: the same layer in slabs lowers another text, with one
+    ``while`` a pass."""
+    n, k = 256, 2
+    rng = np.random.RandomState(5)
+    x = jnp.asarray(rng.randn(n, width), jnp.float32)
+    wr = jnp.asarray(rng.randn(width, routed), jnp.float32)
+    w1, w3, w2 = (jnp.asarray(0.2 * rng.randn(held, width, width),
+                              jnp.float32) for _ in range(3))
+    kw = dict(top_k=k, expert_offset=offset,
+              bias=jnp.zeros(routed, jnp.float32) if bias else None)
+    kernels = moe.product_path(x, w1, w2, k) == "pallas"
+    assert kernels == (width == 128)
+    every_row = 8 * held >= routed or not bias
+    assert (moe.slab_rows(n * k, held, routed, kernels, bias) == n * k) \
+        == every_row
+
+    def lowered(share):
+        return jax.jit(jax.grad(lambda *a: jnp.sum(share(*a, **kw) ** 2),
+                                range(5))).lower(x, wr, w1, w3, w2).as_text()
+
+    mine, parents = lowered(moe.routed_experts), lowered(all_rows_share)
+    assert (mine == parents) == every_row
+    assert mine.count("stablehlo.while") \
+        == parents.count("stablehlo.while") + (0 if every_row else 2)
+    if not kernels:
+        assert "stablehlo.while" not in parents
+
+
+def test_under_amp_a_weights_gradient_is_summed_in_amps_type(monkeypatch):
+    """What the loops carry under the cells' AMP: the rows' sums (the
+    result, the tokens' and the gate's cotangents) in float32, the three
+    weights' gradients in bfloat16, the type their products hand them over
+    in and the one walk keeps them in until the optimizer reads them; no
+    carry is weakly typed (the body would be traced again to promote it);
+    at three trips every cotangent stays within bfloat16's rounding of the
+    one walk's."""
+    from paddle_tpu.fluid import amp
+
+    args, kw, mix, trips = slab_operands("three_trips", 128)
+    args = (args[0].astype(jnp.bfloat16),) + args[1:]
+
+    def layer(*a):
+        return jnp.sum(mix * moe.routed_experts(*a, **kw))
+
+    amp.enable("bfloat16", keep_activations=True)
+    try:
+        jaxpr = jax.make_jaxpr(jax.grad(layer, range(5)))(*args).jaxpr
+        got = jax.grad(layer, range(5))(*args)
+        monkeypatch.setattr(moe, "SLAB_OVER_EVEN", ROUTED)
+        whole = jax.grad(layer, range(5))(*args)
+    finally:
+        amp.disable()
+    forward, backward = loops(jaxpr)
+    assert not any(v.aval.weak_type for loop in (forward, backward)
+                   for v in loop.outvars)
+    carried = [[(v.aval.shape, str(v.aval.dtype)) for v in loop.outvars
+                if v.aval.ndim >= 2] for loop in (forward, backward)]
+    assert carried[0] == [((TOKENS, 128), "float32")]
+    assert carried[1] == [((TOKENS, 128), "float32"),
+                          ((TOKENS, TOP_K), "float32")] + \
+        [((HELD, 128, 128), "bfloat16")] * 3
+    for name, g, r in zip("x router w1 w3 w2".split(), got, whole):
+        assert g.dtype == r.dtype
+        g, r = (np.asarray(v, np.float32) for v in (g, r))
+        assert np.abs(g - r).max() <= 2.0 ** -6 * np.abs(r).max(), name
+        assert np.any(r), name

@@ -109,6 +109,33 @@ def test_registry_labels_histograms_and_flat_view():
     assert abs(h["sum"] - 5.555) < 1e-9
 
 
+def test_a_tape_takes_a_threads_increments_and_replay_counts_them_again():
+    """``MetricsRegistry.tape`` / ``replay``: what a thread counts while a
+    tape is open is counted as ever and kept, labels rendered; another
+    thread's increments are not taken; a replay adds the same again and is
+    itself taken by a tape that is open around it."""
+    reg = MetricsRegistry()
+    with reg.tape() as outer:
+        reg.inc("a.calls", labels={"kind": "x"})
+        with reg.tape() as inner:
+            reg.inc("a.calls", 2, labels={"kind": "x"})
+            other = threading.Thread(target=reg.inc, args=("a.other",))
+            other.start()
+            other.join()
+        reg.inc("a.plain")
+    reg.inc("a.after")
+    assert inner == [('a.calls{kind="x"}', 2)]
+    assert outer == [('a.calls{kind="x"}', 1), ('a.calls{kind="x"}', 2),
+                     ("a.plain", 1)]
+    assert reg.flat()['a.calls{kind="x"}'] == 3
+    with reg.tape() as again:
+        reg.replay(outer)
+    assert again == outer
+    assert reg.flat()['a.calls{kind="x"}'] == 6
+    assert reg.flat()["a.plain"] == 2
+    assert reg.flat()["a.other"] == reg.flat()["a.after"] == 1
+
+
 def test_prometheus_round_trip():
     """Registry -> exposition text -> parse -> the same values (the CI
     oracle for the exporter, including labeled metrics and histograms)."""

@@ -91,6 +91,73 @@ def test_gauges_equal_the_assignment_counts_step_by_step():
     assert not dropped()
 
 
+#: a routed layer that walks in slabs: 2 x 256 tokens x top-2 = 1,024 rows,
+#: 2 of 64 experts held, so ``moe.slab_rows`` gives 8 * 1,024 * 2 / 64 = 256
+SLAB_TOKENS, SLAB_ROUTED, SLAB_HELD, SLAB = 256, 64, 2, 256
+
+
+def slabbed_program():
+    x = layers.data(name="x", shape=[SLAB_TOKENS, WIDTH], dtype="float32")
+    with fluid.name_scope("layer0"), fluid.name_scope("ffn"):
+        out, _, counts = layers.moe_experts(
+            x, num_routed=SLAB_ROUTED, experts_held=SLAB_HELD,
+            hidden_size=WIDTH, top_k=TOP_K, expert_offset=OFFSET, name="moe",
+            select_bias=True)
+    loss = layers.mean(layers.square(x + out))
+    fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    return loss, counts
+
+
+def slabbed_feed(drifted, batch=2, steps=None):
+    """``drifted``: how many of a sequence's 256 tokens carry the feature
+    that ``drift_the_router`` turns toward the two experts held."""
+    shape = (batch, SLAB_TOKENS, WIDTH) if steps is None else \
+        (steps, batch, SLAB_TOKENS, WIDTH)
+    x = np.random.RandomState(3).randn(*shape).astype("float32")
+    x[..., 0] = 0.0
+    x[..., :drifted, 0] = 1.0
+    return {"x": x}
+
+
+def drift_the_router():
+    scope = executor.global_scope()
+    w = np.array(scope.get("moe_router_w"))
+    w[0, OFFSET:OFFSET + SLAB_HELD] = 60.0
+    scope.set("moe_router_w", w)
+
+
+def test_rows_are_the_rows_walked_and_the_trips_are_gauged():
+    """Through ``Executor.run``: ``ops.moe.rows`` is ``slab * trips`` and
+    ``ops.moe.slab_trips`` the trips of the layer's forward loop, 1 while
+    the assignments to the experts held fit a slab and 3 for a feed that
+    sends 144 tokens of every 256 to both of them; the static label
+    ``slab`` of ``ops.moe.calls`` is the slab's rows."""
+    from paddle_tpu.parallel import moe
+
+    rows = 2 * SLAB_TOKENS * TOP_K
+    assert moe.slab_rows(rows, SLAB_HELD, SLAB_ROUTED, False) == SLAB < rows
+    loss, counts = slabbed_program()
+    exe = started()
+    drift_the_router()
+    for drifted, trips in ((0, 1), (144, 3)):
+        _, landed = exe.run(framework.default_main_program(),
+                            feed=slabbed_feed(drifted),
+                            fetch_list=[loss, counts])
+        live = int(landed[OFFSET:OFFSET + SLAB_HELD].sum())
+        assert max(1, -(-live // SLAB)) == trips
+        entry = observe.step_gauges(wait=True)[-1]
+        assert len(entry.values) == 4
+        assert of(entry.values, "ops.moe.live_rows", "layer0.ffn") == live
+        assert of(entry.values, "ops.moe.rows", "layer0.ffn") == trips * SLAB
+        assert of(entry.values, "ops.moe.slab_trips", "layer0.ffn") == trips
+    calls = {k: v for k, v in fluid.profiler.counters().items()
+             if k.startswith("ops.moe.calls")}
+    assert calls == {
+        f'ops.moe.calls{{held="{SLAB_HELD}",path="ragged_dot",'
+        f'routed="{SLAB_ROUTED}",slab="{SLAB}"}}': 2}
+    assert not dropped()
+
+
 def test_a_program_without_a_routed_layer_lowers_as_without_the_collector(
         monkeypatch):
     x = layers.data(name="x", shape=[TOKENS, WIDTH], dtype="float32")
@@ -258,14 +325,26 @@ def test_an_array_gauge_takes_an_index_and_a_twin_a_call_label():
     assert gauges.Collector().finish() == (None, None)
 
 
+@pytest.mark.parametrize("walk", ["every_row", "slabs"])
 @pytest.mark.parametrize("entry", ["run", "run_steps", "sharded_step",
                                    "sharded_window"])
-def test_every_entry_point_runs_the_routed_program(entry):
+def test_every_entry_point_runs_the_routed_program(entry, walk):
     """``Executor.run`` carries the vector; the three other entry points
     install a collector that only counts what it leaves behind, once a
-    lowering.  None breaks and none leaks a tracer."""
-    loss, _ = routed_program()
+    lowering.  None breaks and none leaks a tracer, with the layer's loops
+    over its slabs in the program (three trips a step: a ``while`` inside
+    ``run_steps``' scan and under the sharded entry points) as without."""
+    if walk == "slabs":
+        loss, _ = slabbed_program()
+
+        def feed_of(seed, batch=2, steps=None):
+            return slabbed_feed(144, batch, steps)
+    else:
+        loss, _ = routed_program()
+        feed_of = globals()["feed_of"]
     exe = started()
+    if walk == "slabs":
+        drift_the_router()
     main = framework.default_main_program()
     with jax.checking_leaks():
         if entry == "run":

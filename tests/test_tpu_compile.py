@@ -187,14 +187,18 @@ def _delta_layer(t=8192, hk=16, hv=32, d=128, taps=4, chunk=64):
                 ((2 * keys + values, taps), F32), gate, gate]
 
 
-#: the decoder cells' grouped products: rows (tokens x top_k), hidden width,
-#: expert width, experts held (``chipbench/configs/<cell>/config.json``)
+#: the decoder cells' grouped products: rows of a walk (the slab of tokens x
+#: top_k, ``parallel/moe.slab_rows``: half of Trinity's 49,152, a quarter of
+#: Kimi-Linear's 16,384, all of the others'), hidden width, expert width,
+#: experts held
+#: (``chipbench/configs/<cell>/config.json``)
 GROUPED_CELLS = {"keye": (65536, 2048, 768, 16),
-                 "trinity": (49152, 2048, 1024, 8),
+                 "trinity": (24576, 2048, 1024, 8),
                  "lfm2": (32768, 2048, 1792, 8),
                  "instella": (49152, 2048, 1408, 8),
                  "qwen3_next": (81920, 2048, 512, 16),
-                 "mellum2": (65536, 2304, 896, 8)}
+                 "mellum2": (65536, 2304, 896, 8),
+                 "kimi_linear": (4096, 2304, 1024, 8)}
 #: no cell's: an expert width of 13 lane rows, whose only dividing tile is
 #: one lane row as at Instella's 11 (ragged tiles of 896 + 768 and 384 x 4 +
 #: 128 where the result is that wide: ``plain`` and ``weights_gradient`` of
@@ -382,7 +386,8 @@ GROUPED_TILES = {
     "qwen3_next": {"up": 512, "down": 2048, "up_t": 256, "down_t": 1024},
     # the first hidden width that is not 2,048: 18 lane rows in two tiles;
     # 896 is 7 lane rows, ragged as 512 + 384 and as 256 x 3 + 128
-    "mellum2": {"up": 512, "down": 1152, "up_t": 256, "down_t": 1152}}
+    "mellum2": {"up": 512, "down": 1152, "up_t": 256, "down_t": 1152},
+    "kimi_linear": {"up": 512, "down": 1152, "up_t": 256, "down_t": 768}}
 
 
 def _mosaic_bodies(stablehlo_text):
@@ -436,11 +441,16 @@ def test_grouped_grid_is_the_tiles(topo, cell, form, which, key):
                           else [pallas_grouped.ROW_TILE, tn])
 
 
-#: a cell's routed layer: tokens a step, choices a token, routed experts
+#: a cell's routed layer: tokens a step, choices a token, routed experts,
+#: whether its router has a balancing bias (a slab is walked only under one)
 #: (``GROUPED_CELLS`` has the rows, the widths and the experts held)
-GROUPED_LAYERS = {"keye": (8192, 8, 128), "trinity": (6144, 8, 128),
-                  "lfm2": (8192, 4, 32), "instella": (8192, 6, 64),
-                  "qwen3_next": (8192, 10, 512), "mellum2": (8192, 8, 64)}
+GROUPED_LAYERS = {"keye": (8192, 8, 128, False),
+                  "trinity": (6144, 8, 128, True),
+                  "lfm2": (8192, 4, 32, True),
+                  "instella": (8192, 6, 64, True),
+                  "qwen3_next": (8192, 10, 512, False),
+                  "mellum2": (8192, 8, 64, False),
+                  "kimi_linear": (2048, 8, 256, True)}
 
 
 @pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))
@@ -452,7 +462,9 @@ def test_a_routed_layer_and_its_backward_lower_eleven_calls(
     and three rows' cotangents backward, NOT the last product again) and 3
     ``grouped_matmul_t``, in the six signatures that
     ``test_grouped_signatures_are_the_benchmarks`` pins one product at a
-    time, each counted as ``2 * M * D * F`` FLOPs by the benchmark's files;
+    time, each counted as ``2 * M * D * F`` FLOPs by the benchmark's files,
+    M the rows of a walk: the slab's in Trinity and Kimi-Linear, whose
+    eleven calls stand ONCE, in the bodies of the layer's two loops;
     XLA drops none and adds none."""
     import sys
 
@@ -464,13 +476,14 @@ def test_a_routed_layer_and_its_backward_lower_eleven_calls(
     from paddle_tpu.parallel import moe
 
     m, d, f, g = GROUPED_CELLS[cell]
-    tokens, top_k, routed = GROUPED_LAYERS[cell]
-    assert tokens * top_k == m
+    tokens, top_k, routed, balanced = GROUPED_LAYERS[cell]
+    assert moe.slab_rows(tokens * top_k, g, routed, True, balanced) == m
+    bias = jnp.zeros((routed,), F32) if balanced else None
     monkeypatch.setattr(kernel_choice, "interpret", lambda stated=None: False)
 
     def layer(x, wr, w1, w3, w2):
-        return moe.routed_experts(x, wr, w1, w3, w2, top_k=top_k).astype(
-            F32).sum()
+        return moe.routed_experts(x, wr, w1, w3, w2, top_k=top_k,
+                                  bias=bias).astype(F32).sum()
 
     chip = SingleDeviceSharding(topo.devices[0])
     args = [jax.ShapeDtypeStruct(s, t, sharding=chip) for s, t in
