@@ -9,7 +9,8 @@ float32 reference (one line each); for every control seed the reference
 with float8 (e4m3) contraction inputs stands in the program's place.  The
 limits in a configuration's ``config.json`` are set from these two
 readings: above the program's largest, below the control's smallest.  Not
-part of a benchmark run; set-up is paid once.
+part of a benchmark run; set-up is paid once.  Each seed lets the state of
+the one before go, seeds again and compares, as a run does after its window.
 """
 
 from __future__ import annotations

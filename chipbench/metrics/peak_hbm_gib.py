@@ -1,6 +1,8 @@
-"""Peak HBM set aside on the fullest of the cell's devices, in GiB: arrays
-at their high-water mark plus the temporaries of the largest program that
-ran (``run.py`` ``device_record`` says why the two are added)."""
+"""Peak HBM set aside on the fullest of the cell's devices, in GiB, read as
+the window closes: the arrays at their high-water mark (state, feed,
+fetches in flight) plus the temporaries of the step (``run.py``
+``device_record`` says why the two are added).  The comparison that decides
+``correct`` runs after the reading, so nothing of the harness's is in it."""
 
 
 def value(run):

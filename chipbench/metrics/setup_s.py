@@ -1,5 +1,6 @@
 """Process start to window open: import, device init, build, startup,
-weights, reference check, first call and warm-up."""
+weights, first call and warm-up: what a trainer pays after every restart.
+The comparison that decides ``correct`` runs after the window."""
 
 
 def value(run):

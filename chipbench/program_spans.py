@@ -168,11 +168,13 @@ def compile_seconds(run, names: Sequence[str]) -> Optional[float]:
                 n, t = by.get(s.name, (0, 0.0))
                 by[s.name] = (n + 1, t + s.t1 - s.t0)
         t = run["times"]
+        # the comparison runs after the window: its spans end after
+        # ``t_open`` and its seconds are no part of ``times``
         outside = sum(t.get(k, 0.0) for k in (
-            "startup_s", "reference_check_s", "first_call_s", "warmup_s"))
+            "startup_s", "first_call_s", "warmup_s"))
         print("set-up from inside, before the window: " + ", ".join(
             f"{n} x{c} {s:.3f} s" for n, (c, s) in sorted(by.items()))
-            + f"; from outside startup + reference_check + first_call + "
+            + f"; from outside startup + first_call + "
             f"warmup {outside:.3f} s", flush=True)
     # the union is unit-free: seconds in, seconds out
     return trace_reduce.union_ns(got)

@@ -8,9 +8,13 @@ One new process per run.  It finds the cell in ``BENCHMARK.json``, its
 configuration under ``chipbench/configs/<config>/`` and its traffic mix in
 ``chipbench/traffic/<traffic>.json``; builds the ``Program`` through the
 normal entry points with bf16 AMP and every kernel gate in AUTO; makes the
-weights and the feed from ``--seed``; compares one step with the
-configuration's float32 reference; warms up the cell's own shapes; measures
-for ``--seconds``; prints the contract's JSON object as the last line.
+weights and the feed from ``--seed``; warms up the cell's own shapes;
+measures for ``--seconds``; reads the device's memory as the window closes;
+then, and only then, lets the window's state go, seeds again and compares
+one step with the configuration's float32 reference; prints the contract's
+JSON object as the last line.  Nothing of the comparison exists in the
+process before the memory is read, so ``peak_hbm_gib`` and ``setup_s`` are
+the trainer's own.
 
 No chip is a failure, never a fallback.  ``--rehearse`` (CPU, the
 configuration's ``tiny`` sizes, Pallas interpreted, four virtual devices)
@@ -117,7 +121,9 @@ def counters(fluid, *names):
 
 
 class Cell:
-    """One built cell: programs, executor, weights from the seed."""
+    """One built cell: programs, executor, state from the seed.  It keeps
+    no array: the state lives in the scope alone, and the reference makes
+    its weights from the seed again when the comparison needs them."""
 
     def __init__(self, cell, config_entry, traffic, sizes, rehearse):
         import jax
@@ -159,37 +165,55 @@ class Cell:
         self.names = self.builder.trainable_names(self.main)
         if self.names != self.builder.trainable_names(self.check_main):
             raise SystemExit("the two builds name their parameters apart")
+        # what the startup program sets, and sets again when it runs again
+        self.startup_names = sorted({
+            n for op in self.startup.global_block().ops
+            for n in op.output_arg_names if n})
         self.times["build_s"] = time.perf_counter() - t0
 
     # -- state from the seed ------------------------------------------
-    def seed_state(self, seed: int) -> None:
+    def seed_state(self, seed: int) -> dict:
         """Startup program (optimizer state, statistics), then the weights
-        the REFERENCE makes from the seed, put into the scope by name."""
+        the REFERENCE makes from the seed, put into the scope by name.  No
+        second copy of the parameters is alive at any time: an earlier
+        state is let go before the startup program runs, the startup
+        program's own parameters before the reference makes its, and the
+        reference's arrays ARE the scope's.  The step donates them, so
+        nothing keeps the list: ``check`` makes it from the seed again.
+        Returns the seconds it took, by part."""
         fluid, jax = self.fluid, self.jax
         t0 = time.perf_counter()
         self.main.random_seed = self.startup.random_seed = seed
         self.check_main.random_seed = seed
+        scope = fluid.global_scope()
+        # an earlier state goes first: its slots stay, empty, until the
+        # startup program fills them again
+        for name in self.startup_names:
+            if scope.has(name):
+                scope.set(name, None)
         fluid.Executor(fluid.TPUPlace()).run(self.startup)
-        self.times["startup_s"] = time.perf_counter() - t0
+        took = {"startup_s": time.perf_counter() - t0}
         t0 = time.perf_counter()
         spec = self.reference.param_spec(self.sizes)
-        self.weights = self.reference.init_params(seed, self.sizes)
-        # a copy for the scope, made in one call: the step donates its
-        # state, and the reference keeps its own
-        for_scope = jax.jit(lambda ws: [w.copy() for w in ws])(self.weights)
-        scope = fluid.global_scope()
         if len(spec) != len(self.names):
             raise SystemExit(f"reference has {len(spec)} parameters, the "
                              f"program {len(self.names)}")
-        for (ref_name, shape, _), name, w in zip(spec, self.names,
-                                                 for_scope):
+        for (ref_name, shape, _), name in zip(spec, self.names):
             have = tuple(self.np.shape(scope.get(name)))
             if have != tuple(shape):
                 raise SystemExit(f"{name} is {have}, reference "
                                  f"{ref_name} is {tuple(shape)}")
+            scope.set(name, None)
+        # as they come, like the startup program's own: arrays that jax has
+        # not committed to a device, which is why a trainer's SECOND call
+        # lowers its step again (`relowerings` 1; PERF.md, Open questions)
+        weights = self.reference.init_params(seed, self.sizes)
+        for name, w in zip(self.names, weights):
             scope.set(name, w)
-        jax.block_until_ready(self.weights)
-        self.times["weights_s"] = time.perf_counter() - t0
+        del weights
+        jax.block_until_ready([scope.get(n) for n in self.names])
+        took["weights_s"] = time.perf_counter() - t0
+        return took
 
     def feed(self, seed: int, batch: int, stream: int = 0):
         rng = self.np.random.RandomState((seed + 7919 * stream) % (2 ** 32))
@@ -207,15 +231,18 @@ class Cell:
         """One deterministic step of the program on a small seeded batch
         against the float32 reference (or, with ``matmul_dtype``, the
         CONTROL in the program's place).  Must run right after
-        ``seed_state``: the optimizer comparison is of the first step."""
+        ``seed_state(seed)``: the optimizer comparison is of the first
+        step.  The reference makes its weights from the seed here, its own
+        copy beside the scope's, and they go when this returns."""
         from chipbench import check, loop
 
         fluid = self.fluid
         feed = self.feed(seed, self.sizes["check_batch"] * self.chips
                          if self.traffic["entry"] != "executor"
                          else self.sizes["check_batch"], stream=1)
+        weights = self.reference.init_params(seed, self.sizes)
         if matmul_dtype is not None:
-            return check.control(self.reference, self.sizes, self.weights,
+            return check.control(self.reference, self.sizes, weights,
                                  feed, matmul_dtype)
         grads = [n + "@GRAD" for n in self.names]
         outs = loop.device_arrays(dispatch(
@@ -225,24 +252,30 @@ class Cell:
         after = [scope.get(n) for n in self.names]
         # loss, gradients and parameters go in as the device arrays they
         # are: nothing is copied to the host and sent back
-        return check.program(self.reference, self.sizes, self.weights, feed,
+        return check.program(self.reference, self.sizes, weights, feed,
                              outs[0], outs[1:], after)
 
 
 def print_arrays_peak(devices, when):
     """``memory after <when>: arrays <peak_bytes_in_use> ...`` of the
-    fullest chip, so that a run's record says in which phase the arrays'
-    high-water mark (half of ``peak_hbm_gib``) was reached: the value only
-    ever rises, so the first line that shows the final value names it."""
+    fullest chip, one line a phase in the order the phases run: weights,
+    warm-up, window (where ``device_record`` takes the reading), comparison.
+    Both marks only ever rise, so the first line that shows a value names
+    the phase that reached it; up to the window's line they are the timed
+    path's, and what the comparison's line shows above them is the
+    harness's and is reported nowhere.  Returns the two marks added."""
     stats = [d.memory_stats() or {} for d in devices]
     if not any(stats):
         print(f"memory after {when}: this backend reports none", flush=True)
-        return
-    full = max(stats, key=lambda s: int(s.get("peak_bytes_in_use", 0)))
+        return 0
+    full = max(stats, key=lambda s: int(s.get("peak_bytes_in_use", 0))
+               + int(s.get("peak_bytes_reserved", 0)))
     print(f"memory after {when}: arrays {full.get('peak_bytes_in_use')} at "
           f"the peak, {full.get('bytes_in_use')} now, program temporaries "
           f"{full.get('peak_bytes_reserved')} of {full.get('bytes_limit')} "
           "bytes", flush=True)
+    return int(full.get("peak_bytes_in_use", 0)) \
+        + int(full.get("peak_bytes_reserved", 0))
 
 
 def device_record(jax, devices):
@@ -250,6 +283,11 @@ def device_record(jax, devices):
     fullest of the cell's chips: ``peak_bytes_in_use`` (arrays: weights,
     optimizer state, feeds, fetches) plus ``peak_bytes_reserved`` (the
     temporaries of the largest program that ran: activations, workspace).
+    ``main`` calls it as the window closes, before the traced stretch and
+    before anything of the comparison exists, so both marks belong to the
+    timed path and to one time: the arrays' mark is the state, the feed,
+    the fetches in flight and whatever seeding held beside them (at most
+    one parameter tensor), the other is the step's temporaries.
 
     On the v5e the two do not overlap and the second is no fixed pool
     (chip run, PR 26): it is 0 in a new process and, after a program has
@@ -315,22 +353,10 @@ def main(argv=None) -> int:
     built = Cell(cell, config_entry, traffic, sizes, args.rehearse)
     fluid = built.fluid
     built.times["import_and_device_s"] = t_import
-    built.seed_state(args.seed)
+    built.times.update(built.seed_state(args.seed))
     print_arrays_peak(used, "weights")
     feed = built.feed(args.seed, built.batch)
     dispatch, finish, lower = built.make_step(feed)
-
-    t0 = time.perf_counter()
-    numbers = built.check(args.seed, dispatch)
-    # the reference's weights have done their work: the window runs
-    # beside the program's own state and nothing of the comparison's
-    built.weights = None
-    limits = sizes.get("limits", {})
-    verdict = check.decide(numbers, limits)
-    built.times["reference_check_s"] = time.perf_counter() - t0
-    compared = list(check.report(numbers, limits))
-    print("\n".join(compared), flush=True)
-    print_arrays_peak(used, "comparison")
 
     t0 = time.perf_counter()
     first_loss = finish(dispatch())
@@ -358,7 +384,7 @@ def main(argv=None) -> int:
         "stamps": window["stamps"], "dispatch_s": window["dispatch_s"],
         "units_per_step": built.units_per_step, "setup_s": setup_s,
         "samples_per_step": built.batch,
-        "times": built.times, "steps": len(window["stamps"]) - 1,
+        "times": dict(built.times), "steps": len(window["stamps"]) - 1,
         "dispatches": after["executor.dispatches"]
         - before["executor.dispatches"],
         "dispatched_steps": window["attempted"],
@@ -368,6 +394,11 @@ def main(argv=None) -> int:
     }
     losses = [first_loss] + warm["losses"] + window["losses"]
     attempted = 1 + warm["attempted"] + window["attempted"]
+    # the reading, as the window closes: state, feed, fetches, the loaded
+    # step and its temporaries, and nothing of the comparison's
+    print_arrays_peak(used, "window")
+    device = device_record(jax, used)
+    run["memory_peak_bytes"] = read = device["memory_peak_bytes"]
 
     if args.trace:
         from chipbench import tracing
@@ -378,8 +409,27 @@ def main(argv=None) -> int:
         losses += run.pop("traced_losses")
         attempted += run["steps_traced"]
     failed = sum(1 for v in losses if v != v or abs(v) == float("inf"))
-    device = device_record(jax, used)
-    run["memory_peak_bytes"] = device["memory_peak_bytes"]
+
+    # the comparison, last: the window's state goes, the seed makes it
+    # again, and the first step from it is set against the reference
+    t_compare = time.perf_counter()
+    after_window = {"reseed_s": sum(built.seed_state(args.seed).values())}
+    t0 = time.perf_counter()
+    numbers = built.check(args.seed, dispatch)
+    limits = sizes.get("limits", {})
+    verdict = check.decide(numbers, limits)
+    after_window["reference_check_s"] = time.perf_counter() - t0
+    compared = list(check.report(numbers, limits))
+    print("\n".join(compared), flush=True)
+    print(f"comparison: began {t_compare - T_START:.3f} s after the "
+          f"process, which opened its window at {setup_s:.3f} s",
+          flush=True)
+    needs = print_arrays_peak(used, "comparison")
+    if needs:
+        print(f"the comparison's own need: {needs - read} bytes over the "
+              f"reading of {read} (arrays at their peak + program "
+              "temporaries, while `correct` was decided, less the same as "
+              "the window closed); reported nowhere", flush=True)
 
     correct = bool(verdict and failed == 0
                    and run["compiles_in_window"] == 0)
@@ -398,7 +448,9 @@ def main(argv=None) -> int:
         metrics[m["name"]] = {"value": v, "unit": m["unit"]}
 
     print("set-up: " + ", ".join(f"{k} {v:.3f}"
-                                 for k, v in built.times.items()),
+                                 for k, v in run["times"].items())
+          + "; after the window, in no metric: "
+          + ", ".join(f"{k} {v:.3f}" for k, v in after_window.items()),
           flush=True)
     if run.get("step_memory"):
         print("step memory (compiler): " + json.dumps(run["step_memory"]),
@@ -427,8 +479,14 @@ def main(argv=None) -> int:
         print("rehearsal counts: " + json.dumps(
             {"steps": run["steps"], "dispatches": run["dispatches"],
              "trace": run.get("trace_counts")}), flush=True)
-    # the numbers compared, beside their limits, as the last lines of the
-    # errors too: of a run that is not correct the driver keeps their end
+    # the numbers compared, beside their limits, as the line's last key and
+    # as the last lines of the errors too: of a run that is not correct the
+    # driver keeps the end of each
+    # (a number that is not finite goes as its name: JSON has no NaN)
+    out["compared"] = {
+        k: {"value": v if v is None or abs(v) < float("inf") else repr(v),
+            "limit": limits.get(k)}
+        for k in check.KEYS for v in [numbers.get(k)]}
     print("\n".join(compared), file=sys.stderr, flush=True)
     print(json.dumps(out), flush=True)
     return 0

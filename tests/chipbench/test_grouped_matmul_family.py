@@ -152,7 +152,7 @@ def test_reader_gives_nothing_where_there_is_nothing_to_read(name):
 @pytest.mark.parametrize("name", NEW)
 def test_each_new_metric_lists_the_three_decoder_cells_by_name(name):
     entry, = [m for m in BENCH["per_layer"] if m["name"] == name]
-    assert set(entry["workloads"]) == DECODER_CELLS
+    assert DECODER_CELLS <= set(entry["workloads"])
     assert entry["layer"] == "expert layer"
     assert entry["moves"] == "step_ms_p95" and entry["unit"] == "%"
     assert entry["source"] == "device_trace"

@@ -21,20 +21,31 @@ from chipbench import cuts  # noqa: E402
 BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 
 
-def rehearse(root, workload, trace, seed=2147489999):
-    """Run the one command in rehearsal mode; returns (lines, last)."""
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+def own_environment():
+    """This process's, less what would pin a child's devices."""
+    return {k: v for k, v in os.environ.items()
+            if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+
+
+def rehearsal(root, workload, trace, seed):
+    """The one command in rehearsal mode, run to its end with exit code 0."""
     p = subprocess.run(
         [sys.executable, os.path.join(root, "chipbench", "run.py"),
          "--workload", workload, "--seed", str(seed), "--seconds", "1",
          "--trace", str(trace), "--rehearse"],
-        cwd=root, env=env, capture_output=True, text=True, timeout=900)
+        cwd=root, env=own_environment(), capture_output=True, text=True,
+        timeout=900)
     assert p.returncode == 0, p.stderr[-3000:]
-    lines = p.stdout.strip().splitlines()
+    return p
+
+
+def rehearse(root, workload, trace, seed=2147489999):
+    """Run the one command in rehearsal mode; returns (lines, last)."""
+    lines = rehearsal(root, workload, trace, seed).stdout.strip().splitlines()
     return lines, json.loads(lines[-1])
 
 
@@ -579,3 +590,66 @@ def test_comparison_takes_device_arrays_as_they_are(monkeypatch):
     assert seen["args"][2] is loss
     assert 3 * one_copy <= seen["bytes"] <= 3.25 * one_copy, \
         (seen["bytes"], one_copy)
+
+
+# -- the run's order: seed, warm up, window, reading, comparison last ------
+
+def at(lines, start):
+    found = [i for i, l in enumerate(lines) if l.startswith(start)]
+    assert len(found) == 1, (start, found)
+    return found[0]
+
+
+def test_phases_come_in_the_order_weights_warm_up_window_comparison(
+        transformer_rehearsal):
+    lines, _ = transformer_rehearsal
+    order = [at(lines, s) for s in (
+        "memory after weights:", "memory after warm-up:",
+        "memory after window:", "memory 0:", "compare grad_rel:",
+        "compare update_rel:", "memory after comparison:", "set-up:")]
+    assert order == sorted(order), order
+    # the traced stretch lies between the reading and the comparison
+    assert order[3] < at(lines, "flops per sample:") < order[4]
+
+
+def test_setup_ends_before_the_comparison_begins(transformer_rehearsal):
+    lines, _ = transformer_rehearsal
+    said = lines[at(lines, "comparison: began ")]
+    began, opened = (float(x) for x in re.findall(r"(\d+\.\d+) s", said))
+    assert opened + 1.0 <= began, said     # the window's second lies between
+    setup, after = lines[at(lines, "set-up:")].split(
+        "; after the window, in no metric: ")
+    assert "reference_check_s" not in setup and "reseed_s" not in setup
+    assert "first_call_s" in setup and "weights_s" in setup
+    assert "reference_check_s" in after and "reseed_s" in after
+    parts = dict(p.rsplit(" ", 1) for p in setup[len("set-up: "):].split(", "))
+    assert sum(float(v) for v in parts.values()) <= opened
+
+
+def test_a_comparison_that_fails_ends_not_correct_with_its_numbers_last(
+        tmp_path):
+    """Limits of 0 in a temporary copy: the window has run and the reading
+    is taken when the comparison fails, and the run still ends ``correct:
+    false``, exit code 0, the compared numbers its last lines of stderr."""
+    root = temporary_checkout(tmp_path)
+    config = changed(TOY_CONFIG, limits__grad_rel=0.0, limits__update_rel=0.0)
+    json.dump(add_toy(root, config),
+              open(os.path.join(root, "BENCHMARK.json"), "w"))
+    p = rehearsal(root, "toy_mlp.resident", trace=0, seed=2147483900)
+    last = json.loads(p.stdout.strip().splitlines()[-1])
+    assert last["correct"] is False and last["failed"] == 0
+    assert last["attempted"] > 0
+    said = p.stderr.strip().splitlines()[-3:]
+    assert said[0].startswith("compare grad_rel:") \
+        and said[0].endswith("limit 0.0 NOT WITHIN"), said
+    assert said[1].startswith("compare update_rel:") and " limit 0.0 " \
+        in said[1]
+    assert said[2].startswith("not compared: loss_rel")
+    assert said == [l for l in p.stdout.splitlines()
+                    if l.startswith(("compare ", "not compared:"))]
+    # and in the result's line, under a key of its own that comes last
+    assert list(last)[-1] == "compared"
+    assert set(last["compared"]) == {"grad_rel", "update_rel"}
+    for k, line in zip(("grad_rel", "update_rel"), said):
+        assert last["compared"][k]["limit"] == 0.0
+        assert repr(last["compared"][k]["value"]) in line

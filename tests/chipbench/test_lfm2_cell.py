@@ -299,13 +299,13 @@ def test_every_metric_of_the_cell_has_its_reader_and_lists_the_cell():
                 "flash_fwd_roofline"} & listed
     for m in BENCH["per_layer"]:
         if m["name"] in NEW:
-            assert m["workloads"] == [CELL] and m["layer"] == "token mixers"
+            assert CELL in m["workloads"] and m["layer"] == "token mixers"
             assert plugins.load("layer_metrics", m["name"]) is not None
     cell = [w for w in BENCH["workloads"] if w["name"] == CELL]
     assert len(cell) == 1 and cell[0]["chips"] == 1 \
         and cell[0]["traffic"] == "resident" \
         and cell[0]["config"] == "lfm2_8b_a1b"
-    assert len(BENCH["workloads"]) == len(BENCH["configs"]) == 5
+    assert [c["name"] for c in BENCH["configs"]].count("lfm2_8b_a1b") == 1
 
 
 @pytest.mark.parametrize("name", sorted(NEW))

@@ -107,7 +107,8 @@ def test_layer_metric_on_a_hand_made_ring(name, monkeypatch, capsys):
         assert "longest interval: 1500.000 ms, of which 99.000 ms" in line
     else:
         assert "fluid.compile.lower x2 16.000 s" in line
-        assert "warmup 45.000 s" in line
+        # the comparison's seconds are no set-up: it runs after the window
+        assert "startup + first_call + warmup 25.000 s" in line
 
 
 def test_longest_interval_says_how_much_of_it_was_inside_run():
@@ -375,8 +376,12 @@ def rehearse(tmp_path, workload, seed):
 
 
 @pytest.mark.parametrize("workload,expected", [
-    ("transformer_base_wmt.resident", 1.0),    # the uncommitted RNG key
-    ("resnet50_imagenet.resident", 0.0)])
+    # the startup program's arrays are not committed to a device and the
+    # first step's are, so the SECOND call lowers the step again (in the
+    # Transformer the RNG key too, in the same call).  Until PR 57 the
+    # comparison's step ran first and took that on itself: ResNet read 0.
+    ("transformer_base_wmt.resident", 1.0),
+    ("resnet50_imagenet.resident", 1.0)])
 def test_rehearsal_prints_relowerings(tmp_path, workload, expected):
     lines, last = rehearse(tmp_path, workload, seed=2147483777)
     assert last["correct"]

@@ -213,7 +213,10 @@ def test_every_metric_of_the_models_blocks_has_its_reader_and_cells(model):
     for name in names + sorted(everywhere):
         m = entry(name)
         want = everywhere.get(name, cells)
-        assert set(m["workloads"]) == want and cells <= want | {RESNET}
+        # of the cells this file knows by name, exactly those; cells that
+        # later PRs add may be listed beside them
+        assert set(m["workloads"]) & set(CELLS) == want
+        assert cells <= want | {RESNET}
         assert m["layer"] == "model blocks" and m["moves"] == "step_ms_p95"
         counter = name == "ops_without_scope"
         assert m["source"] == ("program_counter" if counter
@@ -229,8 +232,11 @@ def test_the_new_entries_are_the_files_last_ones():
                            BY_MODEL["decoder_lm"][1]) for n in names]
     new += ["head_time_pct", "head_mfu_pct", "scoped_time_pct",
             "ops_without_scope"]
-    assert sorted(m["name"] for m in BENCH["per_layer"][-len(new):]) \
-        == sorted(new)
+    # one run of the file, each once, wherever later PRs' entries put it
+    names = [m["name"] for m in BENCH["per_layer"]]
+    at = sorted(names.index(n) for n in new)
+    assert all(names.count(n) == 1 for n in new)
+    assert at == list(range(at[0], at[0] + len(new)))
 
 
 def test_readers_take_the_paths_the_models_give():
