@@ -36,6 +36,7 @@ __all__ = [
     "ring_attention", "moe_ffn", "gpipe_mlp_stack",
     "rms_norm", "rotary_embedding", "sparse_indexer", "sparse_attention",
     "moe_experts", "moe_bias_update", "short_conv", "gated_delta_rule",
+    "ssd_scan",
     "kv_cache_update", "kv_cache_scatter", "token_select",
     "paged_attention", "spec_accept",
     "transformer_encoder_stack", "transformer_decoder_stack", "cos_sim",
@@ -1354,19 +1355,28 @@ def ring_attention(q, k, v, causal=False, scale=None, sp_axis="sp",
                "sp_axis": sp_axis})
     return out
 
-def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
+def rms_norm(input, epsilon=1e-6, param_attr=None, name=None, groups=1):
     """x * rsqrt(mean(x^2) + epsilon) * scale over the LAST axis, with one
     scale of that width (init 1): the per-row norm of a [B, T, D] stream and
-    the per-head norm of [B, T, H, Dh] alike.  Statistics in float32."""
+    the per-head norm of [B, T, H, Dh] alike.  Statistics in float32.
+    ``groups`` n > 1: the mean runs over each of the last axis's n equal
+    groups of columns by itself; the scale stays one of the whole width."""
     helper = LayerHelper("rms_norm", **locals())
     dtype = helper.input_dtype()
+    if int(groups) < 1 or int(input.shape[-1]) % int(groups):
+        raise ValueError(f"rms_norm: {input.shape[-1]} columns do not "
+                         f"divide into {groups} groups")
     scale = helper.create_parameter(
         attr=helper.param_attr, shape=[int(input.shape[-1])], dtype=dtype,
         default_initializer=ConstantInitializer(1.0))
     out = helper.create_variable_for_type_inference(dtype)
     out.shape = tuple(input.shape)
+    # only what departs from the norm over the whole axis is written
+    attrs = {"epsilon": float(epsilon)}
+    if int(groups) > 1:
+        attrs["groups"] = int(groups)
     helper.append_op(type="rms_norm", inputs={"X": [input], "Scale": [scale]},
-                     outputs={"Y": [out]}, attrs={"epsilon": float(epsilon)})
+                     outputs={"Y": [out]}, attrs=attrs)
     return out
 
 
@@ -1483,7 +1493,7 @@ def sparse_attention(q, k, v, selection=None, scale=None, window=0,
 def moe_experts(input, num_routed, experts_held, hidden_size, top_k,
                 expert_offset=0, norm_topk=True, param_attr=None, name=None,
                 score="softmax", select_bias=False, norm_eps=0.0,
-                route_scale=1.0):
+                route_scale=1.0, gated=True):
     """The share of a routed expert layer that ``experts_held`` of its
     ``num_routed`` experts give (parallel/moe.py ``routed_experts``): the
     router is ``num_routed`` wide and every token picks its ``top_k`` over
@@ -1492,7 +1502,8 @@ def moe_experts(input, num_routed, experts_held, hidden_size, top_k,
     [held, D, hidden], ``_w2`` down [held, hidden, D], ``_router_w``
     [D, num_routed]).  No capacity and no dropped assignment; what absent
     experts would add is left out.  ``held = num_routed`` is the whole
-    layer.  Unlike ``moe_ffn`` (dense [N, E, C] dispatch with a capacity
+    layer.  Not ``gated``: experts of TWO matrices about a squared ReLU,
+    ``W2 relu(W1 m)^2``, and no ``_w3``.  Unlike ``moe_ffn`` (dense [N, E, C] dispatch with a capacity
     that drops, ReLU experts with biases, every expert held) this sorts the
     assignments by expert and multiplies them as grouped products.
 
@@ -1516,21 +1527,19 @@ def moe_experts(input, num_routed, experts_held, hidden_size, top_k,
     router = helper.create_parameter(attr=ar, shape=[d, num_routed],
                                      dtype=dtype)
     up = XavierInitializer(fan_in=d, fan_out=hidden_size)
-    w1 = helper.create_parameter(attr=a1, shape=[experts_held, d,
-                                                 hidden_size],
-                                 dtype=dtype, default_initializer=up)
-    w3 = helper.create_parameter(attr=a3, shape=[experts_held, d,
-                                                 hidden_size],
-                                 dtype=dtype, default_initializer=up)
+    ups = {slot: helper.create_parameter(
+        attr=attr, shape=[experts_held, d, hidden_size], dtype=dtype,
+        default_initializer=up)
+        for slot, attr in (("W1", a1), ("W3", a3)) if gated or slot == "W1"}
     w2 = helper.create_parameter(
         attr=a2, shape=[experts_held, hidden_size, d], dtype=dtype,
         default_initializer=XavierInitializer(fan_in=hidden_size, fan_out=d))
-    for p in (w1, w3, w2):
+    for p in (*ups.values(), w2):
         p.dist_hint = "ep"
     out = helper.create_variable_for_type_inference(dtype)
     out.shape = tuple(input.shape)
-    inputs = {"X": [input], "RouterW": [router], "W1": [w1], "W3": [w3],
-              "W2": [w2]}
+    inputs = {"X": [input], "RouterW": [router],
+              **{slot: [w] for slot, w in ups.items()}, "W2": [w2]}
     outputs = {"Out": [out]}
     attrs = {"num_routed": int(num_routed),
              "experts_held": int(experts_held),
@@ -1576,7 +1585,8 @@ def moe_bias_update(bias, counts, coeff, name=None):
     return bias
 
 
-def short_conv(input, taps, param_attr=None, name=None, gated=True):
+def short_conv(input, taps, param_attr=None, name=None, gated=True,
+               bias_attr=None):
     """A short convolution over the sequence (ops/decoder_ops.py
     ``short_conv``): one causal filter of ``taps`` weights a channel
     (``w`` [channels, taps], no bias), zero before position 0.  ``gated``
@@ -1585,7 +1595,9 @@ def short_conv(input, taps, param_attr=None, name=None, gated=True):
     order, as one projection makes them)
     ``out[t] = C[t] * sum_j w[:, j] * (B * u)[t - (taps - 1) + j]``.  Not
     ``gated``: ``out = SiLU(filter(input))`` over ``input``'s own channels,
-    the form in front of a linear attention (``gated_delta_rule``).  Unlike
+    the form in front of a linear attention (``gated_delta_rule``) or a
+    state-space scan (``ssd_scan``), with ``bias_attr`` (a ParamAttr, or
+    True) a bias a channel, zeros at first, added before the SiLU.  Unlike
     ``row_conv`` it looks back and never ahead and takes a dense
     [batch, T, ...] tensor: every row of the batch is a sequence of its
     own and nothing crosses from one to the next."""
@@ -1597,13 +1609,19 @@ def short_conv(input, taps, param_attr=None, name=None, gated=True):
         raise ValueError(f"short_conv: {taps} taps over an input "
                          f"{tuple(input.shape)} that is not 3 * channels "
                          f"wide")
+    if gated and bias_attr:
+        raise ValueError("short_conv: the gated form has no bias")
     w = helper.create_parameter(attr=helper.param_attr,
                                 shape=[channels, int(taps)], dtype=dtype)
+    inputs = {"X": [input], "Filter": [w]}
+    if bias_attr:
+        inputs["Bias"] = [helper.create_parameter(
+            attr=helper.bias_attr, shape=[channels], dtype=dtype,
+            is_bias=True)]
     out = helper.create_variable_for_type_inference(dtype)
     out.shape = tuple(input.shape[:-1]) + (channels,)
     # only what departs from the gated form is written into the op
-    helper.append_op(type="short_conv",
-                     inputs={"X": [input], "Filter": [w]},
+    helper.append_op(type="short_conv", inputs=inputs,
                      outputs={"Out": [out]},
                      attrs=None if gated else {"gated": False})
     return out
@@ -1633,6 +1651,28 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64, scale=None, norm_eps=0.0,
         type="gated_delta_rule",
         inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]},
         outputs={"Out": [out]}, attrs=attrs)
+    return out
+
+
+def ssd_scan(u, delta, a, b, c, d, chunk=128, groups=1, name=None):
+    """A selective state-space scan (ops/decoder_ops.py ``ssd_scan``,
+    ops/ssd.py), the recurrence of a Mamba-2 mixer: every head keeps a
+    [P, N] state that each token decays by ``exp(delta_t a)``, adds
+    ``delta_t u_t b_t^T`` to and reads along ``c_t``, and ``d u_t`` passes
+    beside it.  u: [B, T, H, P]; delta (> 0): [B, T, H]; a (< 0) and d:
+    [H]; b, c: [B, T, groups * N], head h reading group ``h // (H //
+    groups)``.  Returns [B, T, H, P].  ``chunk``: the tokens worked at once
+    between two steps of the state.  Every row of the batch starts from a
+    zero state."""
+    helper = LayerHelper("ssd_scan", **locals())
+    out = helper.create_variable_for_type_inference(helper.input_dtype("u"))
+    out.shape = tuple(u.shape)
+    helper.append_op(
+        type="ssd_scan",
+        inputs={"U": [u], "Delta": [delta], "A": [a], "B": [b], "C": [c],
+                "D": [d]},
+        outputs={"Out": [out]},
+        attrs={"chunk": int(chunk), "groups": int(groups)})
     return out
 
 

@@ -2,12 +2,15 @@
 token mixer is grouped-query attention (over a learned per-query selection
 of keys, under a causal window, or plain causal; window layers beside
 global ones) or, layer by layer, a gated short convolution, attention
-whose keys and values come from a low-rank latent, or a gated delta rule
-(linear attention with a matrix state a head), and a feed-forward that is
-dense in the leading layers and elsewhere a routed expert layer of which
-this program holds a stated share, RMS norms, rotary positions, a head of
-its own or the embedding's transpose, next-token loss and, where the model
-has one, a multi-token module's loss beside it.
+whose keys and values come from a low-rank latent, a gated delta rule
+(linear attention with a matrix state a head) or a selective state-space
+scan (a Mamba-2 mixer), and a feed-forward that is dense in the leading
+layers and elsewhere a routed expert layer of which this program holds a
+stated share (SiLU-gated experts of three matrices, or squared-ReLU ones of
+two), RMS norms, rotary positions, a head of its own or the embedding's
+transpose, next-token loss and, where the model has one, a multi-token
+module's loss beside it.  A published layer is a mixer and then a
+feed-forward, or, where ``sub_blocks`` says so, one of the two alone.
 
 Everything is configuration (``Config``); nothing here is specific to one
 model or to the benchmark.  The layer, for x = one sequence [T, hidden] and
@@ -22,7 +25,8 @@ field turns on)::
         y  = (C * c) Wout
     attention layer (every layer where mixers is None), down to ``y``:
     q  = a Wq, k = a Wk, v = a Wv  [g = a Wg]
-    q  = RMSNorm_head(q), k = RMSNorm_head(k)        per head
+    q  = RMSNorm_head(q), k = RMSNorm_head(k)        per head [not where
+                                                     qk_norm is off]
     window layer: q, k = RoPE(q, k) [on the head's first rotary_dims
                   columns only]; key s counts for query t iff
                   0 <= t - s < window
@@ -68,10 +72,27 @@ field turns on)::
             and A_log a head; S' = Diag(exp(g_t)) S_{t-1}; beta = sigmoid(a Wb)]
         [W.gate_rank r: z = (a Wg1) Wg2, Wg1 [hidden, r], and no z in the
             projection in; W.gate "sigmoid": sigmoid(z) in SiLU(z)'s place]
+    ssm layer (mixers[published index] == "ssm"; M = cfg.ssm, H heads of P
+               columns, G groups of N state columns):
+        [z | xBC | dt] = a Win            H P | H P + 2 G N | H wide
+        xBC = SiLU(filter(xBC) [+ b_conv])    one causal M.taps-tap filter a
+                                          channel, zero before 0 [M.conv_bias]
+        [u | B | C] = xBC                 H P | G N | G N; head h reads group
+                                          h // (H / G)
+        delta = softplus(dt + dt_bias);  A = -exp(A_log)   [H], float32
+        per head h, S_0 = 0 [P, N]:
+            S_t = exp(delta_t A) S_{t-1} + delta_t u_t B_t^T
+            o_t = S_t C_t + D u_t
+            (computed M.chunk tokens at a time: ops/ssd.py)
+        y = RMSNorm_groups(o * SiLU(z)) Wout    the gate FIRST, the mean over
+                                          each of G groups of H P / G columns,
+                                          one scale of H P
     x1 = x + y                      [post_norms: x + RMSNorm(y)]
     m  = RMSNorm(x1)
     dense layer (published index < dense_layers):
         f = W2(silu(W1 m) * W3 m)                     width dense_width
+        [expert_gate off, here, in Shared and in every routed expert: two
+            matrices about a squared ReLU, W2 relu(W1 m)^2, and no W3]
     routed layer:
         s = softmax(m Wr) [or sigmoid(m Wr)] over all num_routed experts
         E = top experts_per_token of s [+ b: a bias that chooses only]
@@ -81,6 +102,9 @@ field turns on)::
         Shared: the dense feed-forward at width shared_width [shared_gate:
             times sigmoid(m w_sg), one number a token]
     x2 = x1 + f                     [post_norms: x1 + RMSNorm(f)]
+    [sub_blocks[published index] "mixer": the layer ends at x1, it has no
+        m and no f; "ffn": it has no a and no y, m = RMSNorm(x) and
+        x2 = x + f: one norm and one residual add a published layer]
     [residual "farskip": a sub-block reads the stream WITHOUT the sub-block
         just before it.  With s_0 = h0 = s_{-1} and the sub-blocks F_j
         numbered mixer, feed-forward, mixer, ...:
@@ -102,17 +126,20 @@ field turns on)::
 
 Parameters are created in a fixed order and named ``tok_emb``,
 ``l<i>_{attn_norm,q_w,q_norm,k_w,k_norm,v_w,idx_q_w,idx_k_w,idx_w_w,gate_w,
-o_w,post_attn_norm}`` (a conv layer: ``l<i>_{conv_norm,conv_in_w,conv_w,
+o_w,post_attn_norm}`` (the head norms only where ``qk_norm``; a conv layer: ``l<i>_{conv_norm,conv_in_w,conv_w,
 conv_out_w,post_attn_norm}`` and none of the others; a latent layer:
 ``l<i>_{attn_norm,q_w,q_norm,kva_w,kv_norm,kvb_w,k_norm,gate_w,o_w,
 post_attn_norm}``, the head norms only where it has them; a delta layer:
 ``l<i>_{attn_norm,qkvz_w,ba_w,conv_w,dt_bias,a_log,delta_norm,o_w,
 post_attn_norm}``, with ``qkv_w,g1_w,g2_w`` in ``qkvz_w``'s place under a
 gate rank and ``b_w`` in ``ba_w``'s, ``f1_w,f2_w`` after ``conv_w`` under a
-decay rank), then
+decay rank; an ssm layer: ``l<i>_{ssm_norm,ssm_in_w,conv_w,conv_b,dt_bias,
+a_log,ssm_d,gate_norm,o_w,post_attn_norm}``), then
 ``l<i>_{mlp_norm,mlp_w1,mlp_w3,mlp_w2}`` (dense)
 or ``l<i>_{moe_norm,shared_w1,shared_w3,shared_w2,shared_gate_w,router_w,w1,
-w3,w2}`` (routed; ``l<i>_route_bias`` is no parameter), ``l<i>_post_mlp_norm``,
+w3,w2}`` (routed; ``l<i>_route_bias`` is no parameter; no ``w3`` of any kind
+where ``expert_gate`` is off), ``l<i>_post_mlp_norm`` (a layer of one
+sub-block has that sub-block's names and none of the other's),
 ``final_norm``, ``lm_head_w`` (not with ``tie_head``), then the module's
 ``mtp_{h_norm,e_norm,merge_w}``, its block's as a routed layer's under
 ``mtp_`` for ``l<i>_``, and ``mtp_norm``; ``i`` counts the layers held,
@@ -131,7 +158,8 @@ from paddle_tpu.fluid import layers
 from paddle_tpu.fluid.param_attr import ParamAttr
 
 
-MIXERS = ("attention", "conv", "latent", "delta")
+MIXERS = ("attention", "conv", "latent", "delta", "ssm")
+SUB_BLOCKS = ("both", "mixer", "ffn")   # what a published layer is made of
 GATES = ("silu", "sigmoid")         # of a delta mixer's output
 RESIDUALS = ("sequential", "farskip")
 
@@ -167,6 +195,21 @@ class Delta(NamedTuple):
     value_dim: int
     taps: int = 4
     chunk: int = 64
+
+
+class Ssm(NamedTuple):
+    """What an ``ssm`` mixer needs, none of it the model's attention heads:
+    its heads and their width, the groups that share a B and a C and the
+    width of those (the state's columns), the taps of the causal filter in
+    front of the scan, the tokens the scan works at once, and whether the
+    filter has a bias."""
+    heads: int
+    head_dim: int
+    groups: int
+    state: int
+    taps: int = 4
+    chunk: int = 128
+    conv_bias: bool = True
 
 
 class DeltaGates(NamedTuple):
@@ -239,7 +282,8 @@ class Config:
                  conv_taps=0, tie_head=False, latent=None,
                  residual="sequential", mtp_depth=0, mtp_weight=0.0,
                  delta=None, rotary_dims=0, shared_gate=False,
-                 global_rotary=None, delta_gates=None):
+                 global_rotary=None, delta_gates=None, ssm=None,
+                 sub_blocks=None, expert_gate=True, qk_norm=True):
         if num_heads % num_kv_heads:
             raise ValueError(f"{num_heads} query heads do not group over "
                              f"{num_kv_heads} key-value heads")
@@ -248,12 +292,25 @@ class Config:
                              "model: no layer kind is defined for both")
         if dense_layers > layer_offset and not dense_width:
             raise ValueError("a leading dense layer needs dense_width")
+        parts = (sub_blocks or ())[layer_offset:layer_offset + num_layers]
+        if sub_blocks is not None and (
+                len(parts) != num_layers or set(parts) - set(SUB_BLOCKS)
+                or mixers is None or mtp_depth):
+            raise ValueError(
+                f"sub_blocks names {list(parts)} for the {num_layers} "
+                f"layers from {layer_offset} on: one of {SUB_BLOCKS} each, "
+                "beside `mixers`, and with no multi-token module (its block "
+                "is the last published layer's, both sub-blocks)")
         held = (mixers or ())[layer_offset:layer_offset + num_layers]
-        if mixers is not None and (
-                len(held) != num_layers or set(held) - set(MIXERS)):
+        # a layer that is a feed-forward alone names no mixer
+        takes = [(None,) if part == "ffn" else MIXERS
+                 for part in parts or ["both"] * len(held)]
+        if mixers is not None and (len(held) != num_layers or any(
+                m not in ok for m, ok in zip(held, takes))):
             raise ValueError(
                 f"mixers names {list(held)} for the {num_layers} layers "
-                f"from {layer_offset} on: one of {MIXERS} each")
+                f"from {layer_offset} on: one of {MIXERS} each (None where "
+                "sub_blocks says the layer is a feed-forward alone)")
         if "conv" in held and conv_taps < 1:
             raise ValueError("a conv layer needs conv_taps")
         if mtp_depth not in (0, 1):
@@ -282,6 +339,18 @@ class Config:
         elif "delta" in held or (mtp_depth and mixers
                                  and mixers[-1] == "delta"):
             raise ValueError("a delta layer needs the record `delta`")
+        if ssm is not None:
+            ssm = Ssm(*ssm)
+            if ssm.heads % ssm.groups or min(ssm.head_dim, ssm.state,
+                                             ssm.taps, ssm.chunk) < 1:
+                raise ValueError(
+                    f"{ssm}: the heads are a multiple of the groups, and a "
+                    "head, a state, a filter and a chunk hold a column or a "
+                    "token at least")
+        if "ssm" in held and ssm is None or (
+                mtp_depth and mixers and mixers[-1] == "ssm"):
+            raise ValueError("an ssm layer needs the record `ssm`, and no "
+                             "multi-token module is defined on one")
         delta_gates = DeltaGates(*(delta_gates or ()))
         if delta_gates.gate not in GATES or min(
                 delta_gates.decay_rank, delta_gates.gate_rank) < 0:
@@ -301,9 +370,9 @@ class Config:
                     f"global layers' own table, one frequency for each of "
                     f"the {pairs} pairs they rotate, and no such layer goes "
                     "without positions")
-        if shared_gate and not shared_width:
+        if shared_gate and not (shared_width and expert_gate):
             raise ValueError("a gate on the shared expert needs "
-                             "shared_width")
+                             "shared_width, and gated experts")
         if residual not in RESIDUALS:
             raise ValueError(f"residual {residual!r}: one of {RESIDUALS}")
         self.vocab_size = vocab_size
@@ -370,9 +439,26 @@ class Config:
         # one, as its fields in order, or not at all: columns of the
         # mixer's projections in)
         self.delta_gates = delta_gates
+        # what an "ssm" mixer needs: an Ssm (or its fields in order)
+        self.ssm = ssm
+        # what every PUBLISHED layer is made of, one of SUB_BLOCKS (None:
+        # both sub-blocks everywhere); read at layer_offset + i
+        self.sub_blocks = None if sub_blocks is None else tuple(sub_blocks)
+        # off: every feed-forward (dense, shared, routed) is two matrices
+        # about a squared ReLU, not three about a SiLU gate
+        self.expert_gate = bool(expert_gate)
+        # off: a plain attention layer's queries and keys go unnormed (a
+        # latent layer has its own switch, ``Latent.head_norm``)
+        self.qk_norm = bool(qk_norm)
+
+    def layer_parts(self, i):
+        """What held layer ``i`` is made of, one of SUB_BLOCKS."""
+        if self.sub_blocks is None:
+            return "both"
+        return self.sub_blocks[self.layer_offset + i]
 
     def layer_mixer(self, i):
-        """The kind of held layer ``i``'s token mixer."""
+        """The kind of held layer ``i``'s token mixer (None: it has none)."""
         if self.mixers is None:
             return "attention"
         return self.mixers[self.layer_offset + i]
@@ -417,17 +503,16 @@ def _norm(x, cfg, name):
                            param_attr=ParamAttr(name=name))
 
 
-def _heads(x, seq_len, n, cfg, norm_name=None, rotate=True, inv_freq=None):
+def _heads(x, seq_len, n, cfg, norm_name=None, rotate=False, inv_freq=None):
     """[B, T, n*Dh] -> [B, n, T, Dh]; normed per head where ``norm_name``
-    names the norm's scale (q and k; v is neither), and then rotated where
-    ``rotate``: by ``rope_theta``'s table, or by ``inv_freq``."""
+    names the norm's scale, and then rotated where ``rotate``: by
+    ``rope_theta``'s table, or by ``inv_freq`` (q and k; v is neither)."""
     x = layers.reshape(x, [-1, seq_len, n, cfg.head_dim])
     if norm_name is not None:
         x = _norm(x, cfg, norm_name)
-        if rotate:
-            x = layers.rotary_embedding(x, theta=cfg.rope_theta,
-                                        dims=cfg.rotary_dims,
-                                        inv_freq=inv_freq)
+    if rotate:
+        x = layers.rotary_embedding(x, theta=cfg.rope_theta,
+                                    dims=cfg.rotary_dims, inv_freq=inv_freq)
     return layers.transpose(x, perm=[0, 2, 1, 3])
 
 
@@ -450,10 +535,12 @@ def _attention(x, cfg, seq_len, p, window, own=None):
     rotate = bool(window) or cfg.rope_global
     table = own.inv_freq if own else None
     width = cfg.num_heads * cfg.head_dim
+    q_norm, k_norm = (f"{p}_{n}_norm" if cfg.qk_norm else None
+                      for n in "qk")
     q = _heads(_proj(x, width, f"{p}_q_w"),
-               seq_len, cfg.num_heads, cfg, f"{p}_q_norm", rotate, table)
+               seq_len, cfg.num_heads, cfg, q_norm, rotate, table)
     k = _heads(_proj(x, cfg.num_kv_heads * cfg.head_dim, f"{p}_k_w"),
-               seq_len, cfg.num_kv_heads, cfg, f"{p}_k_norm", rotate, table)
+               seq_len, cfg.num_kv_heads, cfg, k_norm, rotate, table)
     v = _heads(_proj(x, cfg.num_kv_heads * cfg.head_dim, f"{p}_v_w"),
                seq_len, cfg.num_kv_heads, cfg)
     sel = None
@@ -518,6 +605,14 @@ def _short_conv(x, cfg, p):
     return _proj(y, cfg.hidden_size, f"{p}_conv_out_w")
 
 
+def _head_param(p, name, value, width):
+    """A float32 parameter a head (or channel) of a recurrent mixer, one
+    constant at first."""
+    return layers.create_parameter(
+        [width], "float32", attr=ParamAttr(name=f"{p}_{name}"),
+        default_initializer=fluid.initializer.ConstantInitializer(value))
+
+
 def _delta_mixer(x, cfg, seq_len, p):
     """The gated delta rule between one projection in (queries, keys,
     values and the output's gate side by side; the rule's two gates beside
@@ -541,9 +636,7 @@ def _delta_mixer(x, cfg, seq_len, p):
                      f"{p}_{name}2_w")
 
     def gate_param(name, value, width=heads):
-        return layers.create_parameter(
-            [width], "float32", attr=ParamAttr(name=f"{p}_{name}"),
-            default_initializer=fluid.initializer.ConstantInitializer(value))
+        return _head_param(p, name, value, width)
 
     with fluid.name_scope("delta"):
         if dg.gate_rank:
@@ -588,9 +681,51 @@ def _delta_mixer(x, cfg, seq_len, p):
     return _proj(o, cfg.hidden_size, f"{p}_o_w")
 
 
+def _ssm_mixer(x, cfg, seq_len, p):
+    """The selective state-space scan between one projection in (the
+    output's gate, the filter's input and the step side by side) and one
+    out.  What is not one of those plain products (the filter, the step and
+    the decay, the scan, the gated group norm) runs under the name scope
+    ``ssm``."""
+    sm = cfg.ssm
+    inner, bc = sm.heads * sm.head_dim, sm.groups * sm.state
+    proj = _proj(x, 2 * inner + 2 * bc + sm.heads, f"{p}_ssm_in_w")
+    observe.registry().inc("models.decoder.ssm", labels={
+        "heads": str(sm.heads), "groups": str(sm.groups),
+        "state": str(sm.state), "conv_bias": str(int(sm.conv_bias))})
+    with fluid.name_scope("ssm"):
+        z, xbc, dt = layers.split(proj, [inner, inner + 2 * bc, sm.heads],
+                                  dim=-1)
+        xbc = layers.short_conv(
+            xbc, sm.taps, gated=False, param_attr=_attr(f"{p}_conv_w"),
+            bias_attr=ParamAttr(name=f"{p}_conv_b") if sm.conv_bias
+            else None)
+        u, b, c = layers.split(xbc, [inner, bc, bc], dim=-1)
+        # the step and the decay in float32: their sums along a chunk are
+        # exponents
+        delta = layers.softplus(layers.elementwise_add(
+            layers.cast(dt, "float32"),
+            _head_param(p, "dt_bias", DT_BIAS_INIT, sm.heads)))
+        a = layers.scale(layers.exp(_head_param(p, "a_log", 0.0, sm.heads)),
+                         scale=-1.0)
+        o = layers.ssd_scan(
+            layers.reshape(u, [-1, seq_len, sm.heads, sm.head_dim]), delta,
+            a, b, c, _head_param(p, "ssm_d", 1.0, sm.heads), chunk=sm.chunk,
+            groups=sm.groups)
+        o = layers.rms_norm(
+            layers.elementwise_mul(layers.reshape(o, [-1, seq_len, inner]),
+                                   layers.swish(z)),
+            epsilon=cfg.rms_eps, param_attr=ParamAttr(name=f"{p}_gate_norm"),
+            groups=sm.groups)
+    return _proj(o, cfg.hidden_size, f"{p}_o_w")
+
+
 def _feed_forward(x, cfg, width, p):
     """W2(silu(W1 x) * W3 x), no bias: ``<p>_w1`` gate, ``_w3`` up, ``_w2``
-    down."""
+    down; ``expert_gate`` off: W2 relu(W1 x)^2."""
+    if not cfg.expert_gate:
+        h = layers.square(layers.relu(_proj(x, width, f"{p}_w1")))
+        return _proj(h, cfg.hidden_size, f"{p}_w2")
     h = layers.elementwise_mul(layers.swish(_proj(x, width, f"{p}_w1")),
                                _proj(x, width, f"{p}_w3"))
     return _proj(h, cfg.hidden_size, f"{p}_w2")
@@ -610,7 +745,8 @@ def _experts(x, cfg, p, routers):
         cfg.experts_per_token, expert_offset=cfg.expert_offset,
         norm_topk=cfg.norm_topk, name=p, param_attr=_attr(None),
         score=cfg.router_score, select_bias=bool(cfg.route_bias_coeff),
-        norm_eps=cfg.route_norm_eps, route_scale=cfg.route_scale)
+        norm_eps=cfg.route_norm_eps, route_scale=cfg.route_scale,
+        gated=cfg.expert_gate)
     if cfg.route_bias_coeff:
         out, bias, counts = out
         routers.append((fluid.framework.current_name_scope(), bias, counts))
@@ -625,9 +761,11 @@ def _sub_block(stream, cfg, make):
     return h, layers.elementwise_add(h, y)
 
 
-def _block(stream, cfg, seq_len, p, scope, mixer, window, dense, routers):
+def _block(stream, cfg, seq_len, p, scope, mixer, window, dense, routers,
+           parts="both"):
     """One layer's two sub-blocks, under ``<scope>.mixer`` and
-    ``<scope>.ffn``; its parameters start with ``p``."""
+    ``<scope>.ffn``, or the one that ``parts`` names; its parameters start
+    with ``p``."""
     def mix(x):
         if mixer == "conv":
             y = _short_conv(_norm(x, cfg, f"{p}_conv_norm"), cfg, p)
@@ -637,6 +775,8 @@ def _block(stream, cfg, seq_len, p, scope, mixer, window, dense, routers):
         elif mixer == "delta":
             y = _delta_mixer(_norm(x, cfg, f"{p}_attn_norm"), cfg, seq_len,
                              p)
+        elif mixer == "ssm":
+            y = _ssm_mixer(_norm(x, cfg, f"{p}_ssm_norm"), cfg, seq_len, p)
         else:
             with fluid.name_scope("global") if own \
                     else contextlib.nullcontext():
@@ -656,8 +796,9 @@ def _block(stream, cfg, seq_len, p, scope, mixer, window, dense, routers):
     # one: told from the window layers in the device trace by ``global``
     own = cfg.global_rotary if mixer == "attention" and not window else None
     observe.registry().inc("models.decoder.blocks", labels={
-        "mixer": mixer, "residual": cfg.residual,
-        "where": "mtp" if scope == "mtp" else "trunk"})
+        "mixer": mixer or "none", "residual": cfg.residual,
+        "where": "mtp" if scope == "mtp" else "trunk",
+        **({} if parts == "both" else {"parts": parts})})
     if mixer == "delta":
         observe.registry().inc("models.decoder.delta", labels={
             "decay": "channel" if cfg.delta_gates.decay_rank else "scalar",
@@ -670,8 +811,11 @@ def _block(stream, cfg, seq_len, p, scope, mixer, window, dense, routers):
     if own:
         observe.registry().inc("models.decoder.rotary", labels={
             "kind": "global", "table": "given", "scope": scope})
-    with fluid.name_scope(f"{scope}.mixer"):
-        stream = _sub_block(stream, cfg, mix)
+    if parts != "ffn":
+        with fluid.name_scope(f"{scope}.mixer"):
+            stream = _sub_block(stream, cfg, mix)
+    if parts == "mixer":
+        return stream
     with fluid.name_scope(f"{scope}.ffn"):
         return _sub_block(stream, cfg, feed)
 
@@ -721,14 +865,16 @@ def _multi_token(h, loss, cfg, seq_len, labels, routers):
 def _forward(cfg, seq_len):
     """Named for the device trace (``fluid.name_scope``): ``embed``,
     ``layer<i>.mixer`` (the attention of any kind with its indexer, the
-    short convolution or the delta rule, with projections, norms, gate and
-    the residual add; what only a latent mixer has beneath it as
-    ``.latent``, what only a delta mixer has as ``.delta`` (the pairs of
-    products of a decay or a gate rank beneath that as ``.gates``), and all
+    short convolution, the delta rule or the state-space scan, with
+    projections, norms, gate and the residual add; what only a latent mixer
+    has beneath it as ``.latent``, what only a delta mixer has as ``.delta``
+    (the pairs of products of a decay or a gate rank beneath that as
+    ``.gates``), what only an ssm mixer has as ``.ssm``, and all
     of a global attention layer that rotates by ``global_rotary`` but the
     residual add as ``.global``),
     ``layer<i>.ffn`` (dense or shared feed-forward, router and routed
-    experts, likewise), ``head`` (final norm, product, loss) and, for the
+    experts, likewise; a layer of one sub-block has that one's scope and
+    not the other's), ``head`` (final norm, product, loss) and, for the
     multi-token module, ``mtp.merge``, ``mtp.mixer``, ``mtp.ffn``,
     ``mtp.head``."""
     tokens = layers.data(name="tokens", shape=[seq_len], dtype="int64")
@@ -740,7 +886,7 @@ def _forward(cfg, seq_len):
     for i in range(cfg.num_layers):
         stream = _block(stream, cfg, seq_len, f"l{i}", f"layer{i}",
                         cfg.layer_mixer(i), cfg.layer_window(i),
-                        cfg.layer_is_dense(i), routers)
+                        cfg.layer_is_dense(i), routers, cfg.layer_parts(i))
     with fluid.name_scope("head"):
         logits, loss = _head_loss(stream[1], cfg, "final_norm", labels)
     if cfg.mtp_depth:

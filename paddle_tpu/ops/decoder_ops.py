@@ -2,8 +2,10 @@
 sparse attention: RMS norm, rotary positions, the indexer that selects each
 query's keys, grouped-query attention over that selection, the short
 convolution (between two gates where it is a layer's whole mixer, or
-followed by SiLU in front of a linear attention), the gated delta rule, and
-the share of a routed expert layer that the experts held here give.
+followed by SiLU, a bias a channel before it where the op has one, in front
+of a linear attention or a state-space scan), the gated delta rule, the
+selective state-space scan, and the share of a routed expert layer that the
+experts held here give.
 
 Each is a pure JAX function; gradients go through the generic vjp path
 (``ops/registry.py``) except where noted.  ``sparse_attention`` and
@@ -26,6 +28,11 @@ inverse or the scores, under a decay a value head and under one a key
 channel alike), and
 ``ops.delta_rule.channel_calls{key_heads,dim,chunk,sub}`` beside ``calls``
 for every forward lowered with such a G;
+``ops.ssd.scans{heads,dim,groups,state,chunk,path}`` for every ``ssd_scan``
+lowered and ``ops.ssd.grad_scans{chunk,path="by_hand"}`` for every
+``ssd_scan_grad`` (the backward written out in ``ops/ssd.py``);
+``ops.moe.ungated_layers`` beside ``ops.moe.calls`` for every
+``moe_experts`` lowered whose experts are two matrices about a squared ReLU;
 ``ops.moe.row_moves{pass,how="gather"}``, which ``parallel/moe.py`` counts
 for every row gather it traces (a walk's, whether the layer walks every
 ``N * top_k`` row at once or a slab of them a trip of its loop): two
@@ -66,12 +73,18 @@ def _count(name, value=1, **labels):
 def rms_norm_op(ctx):
     """x * rsqrt(mean(x^2, last axis) + eps) * scale; statistics in float32
     whatever the input's type.  Scale: [x.shape[-1]], so the same op is the
-    per-row norm ([B, T, D]) and the per-head one ([B, T, H, Dh])."""
+    per-row norm ([B, T, D]) and the per-head one ([B, T, H, Dh]).  The attr
+    ``groups`` n > 1: the mean runs over each of the last axis's n equal
+    groups of columns by itself, under the one scale."""
     x, scale = ctx.input("X"), ctx.input("Scale")
     xf = x.astype(jnp.float32)
+    groups = int(ctx.attr("groups", 1))
+    if groups > 1:
+        xf = xf.reshape(x.shape[:-1] + (groups, x.shape[-1] // groups))
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True)
                            + jnp.float32(ctx.attr("epsilon", 1e-6)))
-    return {"Y": (y * scale.astype(jnp.float32)).astype(x.dtype)}
+    return {"Y": (y.reshape(x.shape) * scale.astype(jnp.float32))
+            .astype(x.dtype)}
 
 
 def rotary(x, theta, start=0, dims=0, interleaved=False, inv_freq=None):
@@ -371,17 +384,28 @@ def gated_short_conv(x, w):
     return (x[..., c:2 * c].astype(jnp.float32) * acc).astype(x.dtype)
 
 
-def silu_short_conv(x, w):
-    """``y = SiLU(causal_filter(x))`` for x [batch, T, channels]: the filter
-    alone, as it stands in front of a linear attention; filter and SiLU in
-    float32."""
-    return jax.nn.silu(causal_filter(x, w)).astype(x.dtype)
+def silu_short_conv(x, w, bias=None):
+    """``y = SiLU(causal_filter(x) [+ bias])`` for x [batch, T, channels]:
+    the filter alone, as it stands in front of a linear attention or a
+    state-space scan, ``bias`` [channels] where the filter has one; filter,
+    bias and SiLU in float32."""
+    acc = causal_filter(x, w)
+    if bias is not None:
+        acc = acc + bias.astype(jnp.float32)
+    return jax.nn.silu(acc).astype(x.dtype)
 
 
 def _short_conv_form(ctx):
-    """(the op's form as a function of X and Filter, is it the gated one)."""
+    """(the op's form as a function of its operands, is it the gated one,
+    the operands: X and Filter, and Bias where the op has one)."""
     gated = bool(ctx.attr("gated", True))
-    return (gated_short_conv if gated else silu_short_conv), gated
+    operands = [ctx.input("X"), ctx.input("Filter")]
+    if ctx.has_input("Bias"):
+        if gated:
+            raise ValueError("short_conv: a bias on the gated form, which "
+                             "has none")
+        operands.append(ctx.input("Bias"))
+    return (gated_short_conv if gated else silu_short_conv), gated, operands
 
 
 @register_op("short_conv")
@@ -390,23 +414,25 @@ def short_conv_op(ctx):
     forms.  ``gated`` (the default), the token mixer of a layer without
     attention: both gates around the filter (``gated_short_conv``), X:
     [B, T, 3C]; Filter: [C, L]; Out: [B, T, C].  Not ``gated``: the filter
-    and SiLU (``silu_short_conv``), X and Out [B, T, C].  No state crosses
-    sequences: each row of the batch is padded on its own."""
-    x, w = ctx.input("X"), ctx.input("Filter")
-    form, gated = _short_conv_form(ctx)
+    and SiLU (``silu_short_conv``), X and Out [B, T, C], with the input
+    Bias [C] where the filter has one.  No state crosses sequences: each
+    row of the batch is padded on its own."""
+    form, gated, operands = _short_conv_form(ctx)
+    w = operands[1]
     _count("ops.short_conv.calls", channels=w.shape[0], taps=w.shape[1],
-           path="xla", **({} if gated else {"gated": 0}))
-    return {"Out": form(x, w)}
+           path="xla", **({} if gated else {"gated": 0}),
+           **({"bias": 1} if len(operands) == 3 else {}))
+    return {"Out": form(*operands)}
 
 
 @register_grad("short_conv")
 def short_conv_grad(ctx):
-    """From X and Filter alone: the filter's input and its sum are made
-    again, nothing but the op's inputs is kept from the forward."""
-    x = ctx.input("X")
-    _, vjp = jax.vjp(_short_conv_form(ctx)[0], x, ctx.input("Filter"))
-    dx, dw = vjp(ctx.input("Out@GRAD").astype(x.dtype))
-    grads = {"X@GRAD": dx, "Filter@GRAD": dw}
+    """From X and Filter (and Bias) alone: the filter's input and its sum
+    are made again, nothing but the op's inputs is kept from the forward."""
+    form, _, operands = _short_conv_form(ctx)
+    _, vjp = jax.vjp(form, *operands)
+    grads = dict(zip(("X@GRAD", "Filter@GRAD", "Bias@GRAD"),
+                     vjp(ctx.input("Out@GRAD").astype(operands[0].dtype))))
     return {s: g for s, g in grads.items() if s in ctx.outputs_spec}
 
 
@@ -470,12 +496,64 @@ def gated_delta_rule_grad(ctx):
     return {s: g for s, g in grads.items() if s in ctx.outputs_spec}
 
 
+def _ssd_scan(ctx):
+    """(the scan as a function of the op's six inputs, the inputs)."""
+    from . import ssd
+
+    def scan(u, delta, a, b, c, d):
+        return ssd.chunked(u, delta, a, b, c, d,
+                           chunk=int(ctx.attr("chunk", 128)),
+                           groups=int(ctx.attr("groups", 1)))
+
+    return scan, [ctx.input(s) for s in ("U", "Delta", "A", "B", "C", "D")]
+
+
+@register_op("ssd_scan")
+def ssd_scan_op(ctx):
+    """A selective state-space scan over the sequence (``ops/ssd.py``), in
+    chunks of ``chunk`` tokens.  U: [B, T, H, P]; Delta (each token's step,
+    > 0): [B, T, H]; A (< 0) and D: [H]; B and C: [B, T, groups * N], head
+    h reading group ``h // (H // groups)``; Out: [B, T, H, P].  Every head
+    keeps a [P, N] state that a token decays by ``exp(Delta A)``, adds
+    ``Delta u B^T`` to and reads along C; ``D u`` passes beside it.  The
+    state starts at zero in every row of the batch and nothing crosses from
+    one row to the next."""
+    scan, operands = _ssd_scan(ctx)
+    u, groups = operands[0], int(ctx.attr("groups", 1))
+    _count("ops.ssd.scans", heads=u.shape[2], dim=u.shape[3], groups=groups,
+           state=operands[3].shape[-1] // groups,
+           chunk=int(ctx.attr("chunk", 128)), path="xla")
+    return {"Out": scan(*operands)}
+
+
+@register_grad("ssd_scan")
+def ssd_scan_grad(ctx):
+    """From the op's six inputs alone, by the backward ``ssd.chunked``
+    carries (a ``jax.custom_vjp`` written by hand, which ``jax.vjp`` below
+    meets: nothing differentiates through the walk): the chunks' decays,
+    scores and writes and the state at every chunk's start are made again,
+    the outputs are not, then the chunks are walked backwards."""
+    scan, operands = _ssd_scan(ctx)
+    _count("ops.ssd.grad_scans", chunk=int(ctx.attr("chunk", 128)),
+           path="by_hand")
+    # behind a barrier with the cotangent in it, as the delta rule's: XLA
+    # must not find the second forward to be the first and keep every
+    # chunk's decays, scores and states from the forward pass to here
+    operands, dout = jax.lax.optimization_barrier(
+        (operands, ctx.input("Out@GRAD")))
+    _, vjp = jax.vjp(scan, *operands)
+    grads = dict(zip(("U@GRAD", "Delta@GRAD", "A@GRAD", "B@GRAD", "C@GRAD",
+                      "D@GRAD"), vjp(dout.astype(operands[0].dtype))))
+    return {s: g for s, g in grads.items() if s in ctx.outputs_spec}
+
+
 @register_op("moe_experts", no_grad_inputs=("Bias",))
 def moe_experts_op(ctx):
     """``parallel/moe.routed_experts`` as an op.  With the input ``Bias``
     ([num_routed], a selection bias that chooses and does not weigh) the
     op also gives ``Counts`` ([num_routed] int32, the step's assignments to
-    every routed expert), which ``moe_bias_update`` reads."""
+    every routed expert), which ``moe_bias_update`` reads.  Without the
+    input ``W3`` the experts are two matrices about a squared ReLU."""
     from ..parallel import moe
 
     w1 = ctx.input("W1")
@@ -499,8 +577,11 @@ def moe_experts_op(ctx):
     _count("ops.moe.calls", held=held, routed=routed, path=path,
            **({} if score == "softmax" else {"score": score}),
            **({} if slab == rows else {"slab": slab}))
+    w3 = ctx.input("W3") if ctx.has_input("W3") else None
+    if w3 is None:
+        _count("ops.moe.ungated_layers")
     out = moe.routed_experts(
-        ctx.input("X"), ctx.input("RouterW"), w1, ctx.input("W3"),
+        ctx.input("X"), ctx.input("RouterW"), w1, w3,
         ctx.input("W2"), top_k=top_k,
         expert_offset=offset, norm_topk=bool(ctx.attr("norm_topk", True)),
         score=score, bias=bias,

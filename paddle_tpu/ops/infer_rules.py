@@ -486,6 +486,11 @@ def infer_rms_norm(op, ins):
             f"rms_norm: scale {_names(op, 'Scale')} {list(scale[0])} must "
             f"be [{x[0][-1]}], the last dim of {_names(op, 'X')} "
             f"{list(x[0])}")
+    groups = int(op.attr("groups", 1))
+    if groups < 1 or (x is not None and x[0][-1] % groups):
+        raise InferMismatch(
+            f"rms_norm: the last dim of {_names(op, 'X')} {list(x[0])} "
+            f"does not divide into {groups} groups")
     return {"Y": [x]}
 
 
@@ -620,6 +625,13 @@ def infer_short_conv(op, ins):
     x, w = _in(ins, "X"), _in(ins, "Filter")
     if x is None:
         return None
+    bias = _in(ins, "Bias")
+    if bias is not None and (bool(op.attr("gated", True))
+                             or tuple(bias[0]) != (x[0][-1],)):
+        raise InferMismatch(
+            f"short_conv: bias {_names(op, 'Bias')} {list(bias[0])} must be "
+            f"[{x[0][-1]}], one a channel of the filter's input alone "
+            f"(the gated form has none)")
     if not bool(op.attr("gated", True)):
         if len(x[0]) != 3 or (w is not None and (
                 len(w[0]) != 2 or w[0][0] != x[0][-1])):
@@ -668,3 +680,39 @@ def infer_gated_delta_rule(op, ins):
         raise InferMismatch(
             f"gated_delta_rule: chunk {op.attr('chunk')} is not positive")
     return {"Out": [v]}
+
+
+@register_infer("ssd_scan")
+def infer_ssd_scan(op, ins):
+    u, delta = _in(ins, "U"), _in(ins, "Delta")
+    if u is None:
+        return None
+    groups = int(op.attr("groups", 1))
+    if len(u[0]) != 4 or groups < 1 or u[0][2] % groups or (
+            delta is not None and tuple(delta[0]) != tuple(u[0][:3])):
+        raise InferMismatch(
+            f"ssd_scan: {_names(op, 'U')} {list(u[0])} must be [B, T, "
+            f"heads, head width] with the heads a multiple of the {groups} "
+            f"groups, and {_names(op, 'Delta')} "
+            f"{list(delta[0]) if delta is not None else '?'} one step a "
+            f"token and head")
+    for name in ("A", "D"):
+        one = _in(ins, name)
+        if one is not None and tuple(one[0]) != (u[0][2],):
+            raise InferMismatch(
+                f"ssd_scan: {_names(op, name)} {list(one[0])} must be "
+                f"[{u[0][2]}], one number a head")
+    b, c = _in(ins, "B"), _in(ins, "C")
+    for name, one in (("B", b), ("C", c)):
+        if one is not None and (len(one[0]) != 3
+                                or tuple(one[0][:2]) != tuple(u[0][:2])
+                                or one[0][2] % groups
+                                or (b is not None and one[0] != b[0])):
+            raise InferMismatch(
+                f"ssd_scan: {_names(op, name)} {list(one[0])} must be "
+                f"[B, T, groups * state] over U's tokens, {groups} groups, "
+                f"B and C alike")
+    if int(op.attr("chunk", 128)) < 1:
+        raise InferMismatch(
+            f"ssd_scan: chunk {op.attr('chunk')} is not positive")
+    return {"Out": [u]}

@@ -20,6 +20,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import operator
 import threading
 from typing import Tuple
 
@@ -169,7 +170,10 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
 
     x: [..., D]; router_w: [D, R] over all R routed experts; w1, w3:
     [E, D, F] and w2: [E, F, D], the E experts ``[expert_offset,
-    expert_offset + E)`` with SiLU-gated feed-forwards and no bias.  Every
+    expert_offset + E)`` with SiLU-gated feed-forwards and no bias
+    (``W2(silu(W1 m) * W3 m)``); ``w3`` None: experts of TWO matrices about
+    a squared ReLU, ``W2 relu(W1 m)^2``, one hidden product a walk in
+    either pass and no third gradient.  Every
     token routes over all R; an assignment to an expert held here is
     computed, one to an absent expert is left out (its chip adds it in a
     deployment; nothing stands in for it here).  There is no capacity and
@@ -494,24 +498,62 @@ def _sorted_and_hidden(top_k, low, weights, plan, which):
     """What a walk of either pass of ``_share`` makes first: the tokens'
     rows ``low`` [N, D] sorted by expert, ``xs`` [rows, D]; and the two
     hidden products of them with ``weights[:2]``, float32 [rows, F],
-    selected by ``live`` (``_gated`` of the two is the experts' hidden
-    rows).  Operands in AMP's type (``_low``)."""
+    selected by ``live`` (``_hidden`` of the two is the experts' hidden
+    rows; the second is None where the experts have no ``w3``).  Operands
+    in AMP's type (``_low``)."""
     _, sizes, order, _, live, tables = plan
     xs = _rows_out(low, order, top_k, which)
-    a, b = (jnp.where(live, grouped_product(xs, w, sizes, tables), 0)
+    a, b = (None if w is None else
+            jnp.where(live, grouped_product(xs, w, sizes, tables), 0)
             .astype(jnp.float32) for w in weights[:2])
     return xs, a, b
 
 
-def _low(xt, w1, w3, w2):
+def _lane_rows(width: int) -> int:
+    """``width`` in whole lane rows: the hidden width the Pallas kernels
+    multiply at."""
+    from ..ops.pallas_grouped import LANE
+
+    return -(-width // LANE) * LANE
+
+
+def _low(xt, w1, w3, w2, kernels=False):
     # the tokens' rows and the three weights in AMP's type, once a pass
+    # (``w3`` None stays None: experts of two matrices).  ``kernels``: an
+    # expert width that is not whole lane rows (1,856 = 14.5 x 128) is
+    # filled up with ZERO columns of w1 and w3 and zero rows of w2, in the
+    # pass that casts them: the hidden columns they give are exact zeros
+    # in both passes (silu(0) * 0, relu(0)^2, and their cotangents), the
+    # parameters and what is kept for the backward stay as they are, and
+    # ``_share_bwd`` cuts the weights' gradients back
     from ..fluid import amp
 
-    low, *weights, _ = amp.cast_operands(xt, w1, w3, w2)
+    if w3 is None:
+        low, a1, a2, _ = amp.cast_operands(xt, w1, w2)
+        weights = [a1, None, a2]
+    else:
+        low, *weights, _ = amp.cast_operands(xt, w1, w3, w2)
+    fill = _lane_rows(w2.shape[1]) - w2.shape[1] if kernels else 0
+    if fill:
+        a1, a3, a2 = weights
+        columns, rows = ((0, 0), (0, 0), (0, fill)), ((0, 0), (0, fill),
+                                                      (0, 0))
+        weights = [jnp.pad(a1, columns),
+                   a3 if a3 is None else jnp.pad(a3, columns),
+                   jnp.pad(a2, rows)]
     return low, weights
 
 
-def _gated(a, b):
+def _by_kernels(plan) -> bool:
+    # the layer's one choice of product, as its plan carries it
+    return plan.kernels if isinstance(plan, _Slabs) else plan[5] is not None
+
+
+def _hidden(a, b):
+    # an expert's hidden row from its hidden products: SiLU-gated, or the
+    # squared ReLU of the one product where there is no second
+    if b is None:
+        return jnp.square(jax.nn.relu(a))
     return jax.nn.silu(a) * b
 
 
@@ -519,7 +561,7 @@ def _forward_walk(top_k, operands, plan, types, looped):
     low, weights, gate = operands
     held, sizes, _, back, _, tables = plan
     xs, a, b = _sorted_and_hidden(top_k, low, weights, plan, "forward")
-    ys = grouped_product(_gated(a, b).astype(xs.dtype), weights[2],
+    ys = grouped_product(_hidden(a, b).astype(xs.dtype), weights[2],
                          sizes, tables)  # [rows, D]
     # back to assignment order, weighted, summed over a token's choices
     return jnp.einsum("nk,nkd->nd", gate, _rows_home(
@@ -531,9 +573,9 @@ def _share(top_k, xt, gate, w1, w3, w2, plan):
     """[N, D] float32: the held experts' rows of ``xt`` [N, D], weighted by
     ``gate`` [N, top_k] (0 where an assignment is absent) and summed over a
     token's choices; ``plan``: what ``routed_experts`` made of the router's
-    choices, the one walk's or a ``_Slabs``.  Its backward is its own
-    (``_share_bwd``)."""
-    low, weights = _low(xt, w1, w3, w2)
+    choices, the one walk's or a ``_Slabs``; ``w3`` None: experts of two
+    matrices.  Its backward is its own (``_share_bwd``)."""
+    low, weights = _low(xt, w1, w3, w2, _by_kernels(plan))
     y = jax.ShapeDtypeStruct(xt.shape, jnp.float32)
     return _walks(_forward_walk, top_k, (low, tuple(weights), gate), plan,
                   ((y, jnp.float32),))[0]
@@ -550,7 +592,7 @@ def _backward_walk(top_k, operands, plan, types, looped):
     low, (a1, a3, a2), gate, dy = operands
     held, sizes, order, back, live, tables = plan
     xs, a, b = _sorted_and_hidden(top_k, low, (a1, a3), plan, "backward")
-    h, silu_bwd = jax.vjp(_gated, a, b)
+    h, hidden_bwd = jax.vjp(_hidden, a, b)
     # the combine's cotangent goes out to the sorted rows from [N, D], as
     # the tokens' rows do, and meets the gate on the hidden side
     dy_s = _rows_out(dy.astype(low.dtype), order, top_k, "backward")
@@ -560,14 +602,18 @@ def _backward_walk(top_k, operands, plan, types, looped):
     u = jnp.where(live, to_h(dy_s), 0).astype(jnp.float32)
     # <dy, ys> over a row is <dy @ w2^T, h> over the same row
     dgate_s = jnp.sum(u * h, axis=1)
-    da, db = (d.astype(low.dtype) for d in silu_bwd(gate_s * u))
-    to_xs1, to_w1 = product_transposes(xs, a1, sizes, tables)
-    to_xs3, to_w3 = product_transposes(xs, a3, sizes, tables)
+    # the hidden products there are (one where the experts have no w3) and
+    # their cotangents, in step
+    ups = [w for w in (a1, a3) if w is not None]
+    cots = [d.astype(low.dtype) for d in hidden_bwd(gate_s * u)
+            if d is not None]
+    to_xs, to_w = zip(*(product_transposes(xs, w, sizes, tables)
+                        for w in ups))
 
     def weights_gradients():
-        return (to_w1(xs, da).astype(types[2]),
-                to_w3(xs, db).astype(types[3]),
-                to_w2(hg, dy_s).astype(types[4]))
+        return (*(t(xs, d).astype(typ)
+                  for t, d, typ in zip(to_w, cots, types[2:])),
+                to_w2(hg, dy_s).astype(types[-1]))
 
     if looped:
         # in a loop's body the weights' gradients come FIRST, behind a
@@ -578,9 +624,10 @@ def _backward_walk(top_k, operands, plan, types, looped):
         # PR 56: 4,946 MB of reserved memory in Trinity's step without the
         # barrier, 4,813 MB with it, 4,864 MB at the parent)
         dws = weights_gradients()
-        da, db, dws = lax.optimization_barrier((da, db, dws))
-    dxt = jnp.sum(_rows_home(to_xs1(da) + to_xs3(db), back, held,
-                             "backward"), axis=1,
+        cots, dws = lax.optimization_barrier((cots, dws))
+    dxs = functools.reduce(operator.add,
+                           (t(d) for t, d in zip(to_xs, cots)))
+    dxt = jnp.sum(_rows_home(dxs, back, held, "backward"), axis=1,
                   dtype=jnp.promote_types(types[0], jnp.float32))
     dgate = jnp.where(held, _permuted(dgate_s, back).reshape(held.shape), 0)
     if not looped:
@@ -595,7 +642,7 @@ def _share_bwd(top_k, kept, dy):
     # cotangent in it, or XLA makes them as soon as the layer's inputs are
     # there, before the loss, and they lie across the step's fullest point
     (xt, gate, w1, w3, w2, plan), dy = lax.optimization_barrier((kept, dy))
-    low, (a1, a3, a2) = _low(xt, w1, w3, w2)
+    low, (a1, a3, a2) = _low(xt, w1, w3, w2, _by_kernels(plan))
     # the rows' cotangents are summed over the trips in float32; a weights'
     # gradient in the type its product hands it over in (AMP's: the kernel
     # sums a group's rows in float32 and rounds ONCE a call, as in the one
@@ -606,10 +653,20 @@ def _share_bwd(top_k, kept, dy):
     # optimizer at twice their bytes, where the one walk keeps them in
     # AMP's type until the sweep reads them (PERF.md section 6, PR 55: 1.2%
     # of Trinity's peak memory, 0.6 GB of Qwen3-Next's step)
-    return (*_walks(_backward_walk, top_k, (low, (a1, a3, a2), gate, dy),
-                    plan, ((xt, _wide(xt)), (gate, _wide(gate)),
-                           (w1, a1.dtype), (w3, a3.dtype), (w2, a2.dtype))),
-            None)
+    held = [(w, a) for w, a in ((w1, a1), (w3, a3), (w2, a2))
+            if w is not None]
+    dxt, dgate, *dws = _walks(
+        _backward_walk, top_k, (low, (a1, a3, a2), gate, dy), plan,
+        ((xt, _wide(xt)), (gate, _wide(gate)),
+         *((jax.ShapeDtypeStruct(a.shape, w.dtype), a.dtype)
+           for w, a in held)))
+    # a width that ``_low`` filled up to whole lane rows: cut back
+    dws = [d if d.shape == w.shape else
+           d[tuple(slice(0, n) for n in w.shape)]
+           for d, (w, _) in zip(dws, held)]
+    if w3 is None:
+        dws.insert(1, None)
+    return (dxt, dgate, *dws, None)
 
 
 _share.defvjp(_share_fwd, _share_bwd)
@@ -717,6 +774,10 @@ def product_path(x, w1, w2, top_k: int) -> str:
 
     low, a1, a2 = jax.eval_shape(lambda *a: amp.cast_operands(*a)[:-1],
                                  x, w1, w2)
+    # the kernels multiply at the hidden width in whole lane rows (``_low``)
+    wide = _lane_rows(a1.shape[2])
+    a1 = jax.ShapeDtypeStruct(a1.shape[:2] + (wide,), a1.dtype)
+    a2 = jax.ShapeDtypeStruct((a2.shape[0], wide, a2.shape[2]), a2.dtype)
     m = x.size // x.shape[-1] * top_k
     return "ragged_dot" if _declined(m, low.dtype, a1, a2) else "pallas"
 

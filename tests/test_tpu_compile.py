@@ -203,7 +203,14 @@ GROUPED_CELLS = {"keye": (65536, 2048, 768, 16),
 #: one lane row as at Instella's 11 (ragged tiles of 896 + 768 and 384 x 4 +
 #: 128 where the result is that wide: ``plain`` and ``weights_gradient`` of
 #: ``up``, ``transposed`` of ``down``); compiled alone, not as a layer
-GROUPED_WIDTHS = {**GROUPED_CELLS, "width_1664": (49152, 2048, 1664, 8)}
+GROUPED_WIDTHS = {**GROUPED_CELLS, "width_1664": (49152, 2048, 1664, 8),
+                  # ``nemotron_twotower_30b_a3b``'s (PR 58): a slab of half
+                  # its 49,152 rows, the first hidden width of 21 lane rows,
+                  # and an expert width of 1,856 = 14.5 lane rows that
+                  # ``parallel/moe._low`` fills up to 15 (two matrices an
+                  # expert: its layer has four of these six products twice
+                  # and none a third time); compiled alone
+                  "nemotron": (24576, 2688, 1920, 8)}
 
 
 def _grouped(cell, form, which):
