@@ -183,7 +183,7 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
     trip of a ``lax.while_loop`` while the assignments to the experts held
     fit a slab, as many more as they need beyond it, so a step in which
     every token chose held experts only is as right as any other.  Where
-    the slab is every row (an eighth of the experts or more held; for now
+    the slab is every row (a quarter of the experts or more held; for now
     also a router with no balancing ``bias``: ``slab_rows``' DEBT) no loop is
     built.
 
@@ -268,22 +268,35 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
 #: elementwise passes' time over rows, forward and backward (ledger, PR 54:
 #: with 10.45% of the rows live in ``trinity_mini.resident`` and 3.14% in
 #: ``kimi_linear_48b_a3b.resident`` the expert layer was 99 and 37 ms of
-#: their steps; ledger, PR 55: a slab of 24,576 of Trinity's 49,152 rows
-#: gave back 46 ms a step, one of 4,096 of Kimi-Linear's 16,384 rows 17
-#: ms; my chip runs, PR 56: 46 and 13).  WHY 8: a router trained on one
-#: chip's share drifts toward the experts it holds, and the slab needs room
-#: for it, since a trip beyond the first costs the whole slab's time again
-#: (8 products, 3 weights' gradients and 5 row gathers over ``slab`` rows,
-#: and the carried sums read and written once more).  The largest live
-#: share a layer read inside a window (PERF.md section 6, PRs 54 to 56) is
-#: 2.0 times the even share in Trinity (7,646 rows, 31% of its slab) and
-#: 1.6 in Kimi-Linear (19% of its slab), both falling through the window
-#: under their balancing rule; without one Qwen3-Next climbs to 3.6 times
-#: (11.3% of all rows, 45% of what would be its slab) and has not stopped at
-#: the window's last step; and however far a router drifts, a layer in slabs
-#: walks less than one slab more than the one walk does (``ceil(live /
-#: slab)`` trips).  An eighth of the experts or more held is every row.
-SLAB_OVER_EVEN = 8
+#: their steps; ledger, PR 56: a slab of 24,576 of Trinity's 49,152 rows
+#: gave back 46 ms a step, one of 4,096 of Kimi-Linear's 16,384 rows 13;
+#: ledger, PR 58: at 8 times the even share 80-87% of the rows walked were
+#: still zero rows, 51 / 87 / 19.5 ms of expert layer a step in Trinity,
+#: Nemotron and Kimi-Linear, and Instella, 8 of 64 held, had no slab at
+#: all: 134 ms).  WHY 4: a router trained on one chip's share drifts toward
+#: the experts it holds, and the slab needs room for it, since a trip
+#: beyond the first costs the whole slab's time again (8 products, 3
+#: weights' gradients and 5 row gathers over ``slab`` rows, and the carried
+#: sums read and written once more: a step in two trips walks what the
+#: factor of 8 walked in one and gains nothing, it does not lose).  The
+#: LARGEST live count a layer showed in one step of a timed window, over
+#: every step of every window PR 59 ran on the chip (PERF.md section 6, PR
+#: 59; a window opens with the router's first swing toward the experts
+#: held, which the balancing rule pulls back within some thirty steps),
+#: beside the slab at 4: Trinity 9,776 rows (3.2 times its even share of
+#: 3,072; 80% of 12,288), Nemotron 10,033 (3.3 times 3,072; 82% of
+#: 12,288), Kimi-Linear 951 (1.9 times 512; 46% of 2,048), Instella 18,361
+#: (3.0 times 6,144; 75% of 24,576): one trip in every step read, with 1.2
+#: to 2.2 times room, where the accounts of PRs 54 to 58, which print a
+#: layer's first, median and last step, had read 7,646 (2.5 times even,
+#: not the 2.0 written here before) / 6,267 / 778 / 12,288.  At 3 the
+#: first two would take a second trip in those steps.  Without such a
+#: rule Qwen3-Next climbs to 3.6 times (11.3% of all rows) and has not
+#: stopped at the window's last step; and however far a router drifts, a
+#: layer in slabs walks less than one slab more than the one walk does
+#: (``ceil(live / slab)`` trips).  A quarter of the experts or more held
+#: is every row.
+SLAB_OVER_EVEN = 4
 
 
 def slab_rows(rows: int, held: int, routed: int, kernels: bool,

@@ -146,7 +146,7 @@ def test_moe_expert_param_specs():
 
 
 # == the routed share walks its sorted rows a slab at a time ================
-# (``parallel/moe.py`` ``slab_rows``, ``_Slabs``, ``_walks``, ``_looped``): ``8 *
+# (``parallel/moe.py`` ``slab_rows``, ``_Slabs``, ``_walks``, ``_looped``): ``4 *
 # held / routed`` of the ``N * top_k`` rows a trip of ONE ``lax.while_loop``
 # a pass, one trip while the assignments to the experts held fit a slab,
 # exact beyond it, and the one walk with no loop where that is every row.
@@ -158,9 +158,10 @@ import pytest  # noqa: E402
 from paddle_tpu.ops import pallas_grouped  # noqa: E402
 from paddle_tpu.parallel import moe  # noqa: E402
 
-#: 512 tokens x 4 choices = 2,048 rows, 4 of 128 experts held from expert 8
-#: on: a slab of 8 * 2,048 * 4 / 128 = 512 rows, four of them at most
-TOKENS, ROUTED, HELD, TOP_K, OFFSET = 512, 128, 4, 4, 8
+#: 512 tokens x 4 choices = 2,048 rows, 8 of 128 experts held from expert 8
+#: on: a slab of 4 * 2,048 * 8 / 128 = 512 rows, ONE row tile, four of them
+#: at most
+TOKENS, ROUTED, HELD, TOP_K, OFFSET = 512, 128, 8, 4, 8
 ROWS, SLAB = TOKENS * TOP_K, 512
 #: the selection bias of each regime (``route_top_k``: it chooses and does
 #: not weigh): ``(held experts every token takes, whether the other held
@@ -216,31 +217,91 @@ def value_and_cotangents(fn, args, kw, mix):
     return y, grads
 
 
+#: a cell's routed layer: ``(N, top_k, held, routed)``
+CELLS = {"trinity_mini": (6144, 8, 8, 128),
+         "nemotron_twotower_30b_a3b": (8192, 6, 8, 128),
+         "kimi_linear_48b_a3b": (2048, 8, 8, 256),
+         "instella_moe_16b_a3b": (8192, 6, 8, 64)}
+
+
 @pytest.mark.parametrize("n,top_k,held,routed,balanced,slab", [
-    (6144, 8, 8, 128, True, 24576),     # trinity_mini: 48 tiles of 49,152
-    (2048, 8, 8, 256, True, 4096),      # kimi_linear_48b_a3b: of 16,384
+    (*CELLS["trinity_mini"], True, 12288),  # 24 tiles of 49,152 rows
+    (*CELLS["kimi_linear_48b_a3b"], True, 2048),    # of 16,384
     (8192, 10, 16, 512, False, 81920),  # qwen3_next_80b_a3b: no bias, all
     (8192, 8, 16, 128, False, 65536),   # keye_vl_2_0_30b_a3b: every row
-    (8192, 4, 8, 32, True, 32768),      # lfm2_8b_a1b: every row
-    (8192, 6, 8, 64, True, 49152),      # instella_moe_16b_a3b: every row
+    (8192, 4, 8, 32, True, 32768),      # lfm2_8b_a1b: a quarter held, all
+    (*CELLS["instella_moe_16b_a3b"], True, 24576),  # of 49,152
     (8192, 8, 8, 64, False, 65536),     # mellum2_12b_a2_5b: every row
+    (*CELLS["nemotron_twotower_30b_a3b"], True, 12288),     # of 49,152
 ])
 def test_the_slab_of_each_cell(n, top_k, held, routed, balanced, slab):
-    """The rows a trip walks in the seven MoE cells, from ``(N, top_k, held,
+    """The rows a trip walks in the eight MoE cells, from ``(N, top_k, held,
     routed)`` and whether the router has a balancing bias: a slab in
-    Trinity and Kimi-Linear; every row where an eighth of the experts or
-    more is held, and for now in a layer without a bias (``slab_rows``'
-    DEBT: Qwen3-Next's shapes alone give 20,480 of its 81,920, and its step
-    with those loops in it reserves 1.07 GiB more)."""
-    assert moe.SLAB_OVER_EVEN == 8
+    Trinity, Nemotron, Kimi-Linear and Instella; every row where a quarter
+    of the experts or more is held, and for now in a layer without a bias
+    (``slab_rows``' DEBT: Qwen3-Next's shapes alone give 10,240 of its
+    81,920, and its step with those loops in it reserved 1.07 GiB more)."""
+    assert moe.SLAB_OVER_EVEN == 4
     assert moe.slab_rows(n * top_k, held, routed, True, balanced) == slab
     assert slab % pallas_grouped.ROW_TILE == 0
-    assert moe.slab_rows(8192 * 10, 16, 512, True) == 20480
+    assert moe.slab_rows(8192 * 10, 16, 512, True) == 10240
     # all experts held, or fewer rows than a row tile: every row
     assert moe.slab_rows(n * top_k, routed, routed, True) == n * top_k
     assert moe.slab_rows(6, 1, 64, True) == 6
     # XLA's grouped product takes any count: the even share's, rounded up
-    assert moe.slab_rows(100, 1, 64, False) == 13
+    assert moe.slab_rows(100, 1, 64, False) == 7
+
+
+#: the LARGEST live count a layer of the cell showed in one step of a timed
+#: window on the chip: ``(as ISSUE 59 had it from PERF.md's accounts of PRs
+#: 54, 56 and 58, which print a layer's first, median and last step; over
+#: EVERY step of every window PR 59 ran, PERF.md section 6, PR 59)``
+LARGEST = {"trinity_mini": (7646, 9776),
+           "nemotron_twotower_30b_a3b": (6267, 10033),
+           "kimi_linear_48b_a3b": (778, 951),
+           "instella_moe_16b_a3b": (12288, 18361)}
+
+
+@pytest.mark.parametrize("read", ["before_pr_59", "in_pr_59", "one_over"])
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_the_largest_load_read_is_one_trip(monkeypatch, cell, read):
+    """What ``SLAB_OVER_EVEN`` rests on, as numbers a change of it has to
+    argue against: the LARGEST live count any layer of the four cells in
+    slabs showed in a step of a window on the chip (``LARGEST``) is ONE
+    trip of its layer, and one row over a slab is two.  Through the layer's
+    own plan at the cell's ``(N, top_k, held, routed)``: a router that
+    sends exactly so many assignments to the experts held, the products
+    left out."""
+    n, top_k, held, routed = CELLS[cell]
+    slab = moe.slab_rows(n * top_k, held, routed, True)
+    before, since = LARGEST[cell]
+    assert max(before, since) <= slab < n * top_k
+    live = {"before_pr_59": before, "in_pr_59": since,
+            "one_over": slab + 1}[read]
+    over = int(live > slab)
+    # token i takes ``taken[i]`` held experts (the last ``held`` of the
+    # routed) and its other choices from the first experts
+    taken = np.clip(live - np.arange(n) * top_k, 0, top_k)
+    x = np.zeros((n, 256), np.float32)
+    choice = np.arange(top_k)[None, :]
+    x[np.arange(n)[:, None], np.where(
+        choice < taken[:, None], routed - held + choice, choice)] = 10.0
+    plans = []
+    slabs = moe._slabs
+    monkeypatch.setattr(moe, "_slabs", lambda *a: (
+        plans.append(slabs(*a)), plans[-1])[1])
+    monkeypatch.setattr(moe, "_share", lambda top_k, xt, *a: xt)
+    w = jnp.zeros((held, 256, 128), jnp.float32)
+    moe.routed_experts(jnp.asarray(x), jnp.eye(256, routed, dtype=jnp.float32),
+                       w, w, w.transpose(0, 2, 1), top_k=top_k,
+                       expert_offset=routed - held,
+                       bias=jnp.zeros(routed, jnp.float32))
+    plan, = plans
+    assert plan.order.shape == (n * top_k // slab, slab) and plan.kernels
+    assert int(plan.live) == live
+    assert int(plan.trips) == 1 + over
+    walked = [int(plan.walk(jnp.int32(t))[4].sum()) for t in range(2)]
+    assert walked == [min(live, slab), over]
 
 
 @pytest.mark.parametrize("regime", sorted(REGIMES))
@@ -283,10 +344,10 @@ def test_slabs_equal_the_one_walk_and_the_unsorted_share(monkeypatch, path,
 
 
 def test_a_tokens_choices_straddle_two_slabs():
-    """Every token takes the held experts 8 and 9 and no other held one:
-    expert 8's 512 assignments are the first slab and expert 9's the
-    second, so EVERY token's sum over its choices, and its cotangent, is
-    made of two trips' parts."""
+    """Every token takes the held experts 8 and 9 and no other of the
+    eight held: expert 8's 512 assignments are the first slab and expert
+    9's the second, so EVERY token's sum over its choices, and its
+    cotangent, is made of two trips' parts."""
     args, kw, mix, _ = slab_operands("exactly_one_slab", 16)
     kw["bias"] = kw["bias"].at[OFFSET + 1].set(50.0)
     _, idx = moe.route_top_k(args[0], args[1], TOP_K, bias=kw["bias"])
@@ -301,7 +362,7 @@ def test_a_tokens_choices_straddle_two_slabs():
                           (got[0], *got[1]), (want[0], *want[1])):
         assert np.abs(g - r).max() <= 1e-5 * np.abs(r).max(), name
         assert np.any(np.asarray(r)), name
-    # the experts 10 and 11 are held and chosen by no token
+    # the experts 10 to 15 are held and chosen by no token
     assert not np.any(np.asarray(got[1][2][2:]))
 
 
@@ -519,7 +580,7 @@ def test_a_step_with_every_row_in_one_slab_lowers_to_the_parents(
         cfg = decoder_lm.tiny_config()
         if program == "decoder_with_bias":
             cfg.router_score, cfg.route_bias_coeff = "sigmoid", 1e-3
-        assert 8 * cfg.experts_held >= cfg.num_routed
+        assert moe.SLAB_OVER_EVEN * cfg.experts_held >= cfg.num_routed
         _, _, loss = decoder_lm.build(cfg, seq_len=16)
         ids = np.random.RandomState(0).randint(
             0, cfg.vocab_size, size=(1, 17)).astype(np.int64)
@@ -537,30 +598,38 @@ def test_a_step_with_every_row_in_one_slab_lowers_to_the_parents(
     assert "stablehlo.while" not in mine
 
 
-@pytest.mark.parametrize("held,routed,offset,width,bias", [
-    (16, 128, 8, 16, True), (8, 8, 0, 16, True), (4, 16, 4, 128, True),
-    (2, 128, 8, 16, False), (2, 128, 8, 16, True)])
-def test_every_row_in_one_slab_lowers_to_the_parents_text(held, routed,
-                                                          offset, width,
-                                                          bias):
-    """An eighth of the experts held or more (all of them; a quarter, on
-    the kernels), or a sixty-fourth under a router with no balancing bias:
-    the slab is every row, the decision is static, no ``while`` is lowered
-    in either pass and the text of the layer and its backward equals, byte
-    for byte, the text of the parent's formula kept above.  Two of 128 held
-    under a bias: the same layer in slabs lowers another text, with one
-    ``while`` a pass."""
-    n, k = 256, 2
+def small_layer(n, held, routed, width):
+    """``(x, router_w, w1, w3, w2)`` of a layer of ``n`` tokens, the
+    operands of the benchmark's own slab test (``tests/chipbench/
+    test_decoder_steps_lower_alike.py``)."""
     rng = np.random.RandomState(5)
     x = jnp.asarray(rng.randn(n, width), jnp.float32)
     wr = jnp.asarray(rng.randn(width, routed), jnp.float32)
-    w1, w3, w2 = (jnp.asarray(0.2 * rng.randn(held, width, width),
-                              jnp.float32) for _ in range(3))
+    return (x, wr, *(jnp.asarray(0.2 * rng.randn(held, width, width),
+                                 jnp.float32) for _ in range(3)))
+
+
+@pytest.mark.parametrize("held,routed,offset,width,bias", [
+    (16, 128, 8, 16, True), (8, 8, 0, 16, True), (4, 16, 4, 128, True),
+    (2, 128, 8, 16, False), (2, 128, 8, 16, True), (32, 128, 8, 16, True)])
+def test_every_row_in_one_slab_lowers_to_the_parents_text(held, routed,
+                                                          offset, width,
+                                                          bias):
+    """A quarter of the experts held or more (all of them; a quarter, on
+    the kernels and on XLA's product), or a sixty-fourth under a router
+    with no balancing bias: the slab is every row, the decision is static,
+    no ``while`` is lowered in either pass and the text of the layer and
+    its backward equals, byte for byte, the text of the parent's formula
+    kept above.  Two of 128 held under a bias, or an eighth of them (every
+    row while the factor was 8): the same layer in slabs lowers another
+    text, with one ``while`` a pass."""
+    n, k = 256, 2
+    x, wr, w1, w3, w2 = small_layer(n, held, routed, width)
     kw = dict(top_k=k, expert_offset=offset,
               bias=jnp.zeros(routed, jnp.float32) if bias else None)
     kernels = moe.product_path(x, w1, w2, k) == "pallas"
     assert kernels == (width == 128)
-    every_row = 8 * held >= routed or not bias
+    every_row = 4 * held >= routed or not bias
     assert (moe.slab_rows(n * k, held, routed, kernels, bias) == n * k) \
         == every_row
 
@@ -574,6 +643,69 @@ def test_every_row_in_one_slab_lowers_to_the_parents_text(held, routed,
         == parents.count("stablehlo.while") + (0 if every_row else 2)
     if not kernels:
         assert "stablehlo.while" not in parents
+
+
+def test_a_layer_in_slabs_wires_its_backward_body_through_the_barrier():
+    """The twin of the benchmark's ``test_a_layer_in_slabs_lowers_both_
+    passes_to_the_text_before`` (``tests/chipbench``), on its operands: 2 of
+    128 experts under a bias, 256 tokens, both passes on XLA's grouped
+    product.  That one holds a digest of the lowered text, which carries
+    the slab's SIZE; this one holds what the digest is there to guard,
+    from the jaxpr, whatever the size: one loop a pass, and in the
+    backward's body the three weights' gradients are made BEFORE the one
+    barrier and go through it with the hidden products' cotangents, and
+    the rows' cotangents are products of the barrier's RESULTS: nothing
+    behind the barrier reads a cotangent from before it (with that wiring
+    lost the step still computed the same and Trinity's read 0.4% faster
+    and 65 MB smaller on the chip, PERF.md section 6, PR 58: not what the
+    parent lowers)."""
+    n, k, held, routed, width = 256, 2, 2, 128, 16
+    x, wr, w1, w3, w2 = small_layer(n, held, routed, width)
+    bias = jnp.zeros(routed, jnp.float32)
+    path, rows, slab = moe.walk_of(x, wr, w1, w2, k, bias)
+    assert path == "ragged_dot" and slab < rows == n * k
+
+    def loss(x, wr, w1, w3, w2):
+        return jnp.sum(moe.routed_experts(
+            x, wr, w1, w3, w2, top_k=k, expert_offset=8, bias=bias) ** 2)
+
+    grad = jax.grad(loss, range(5))
+    assert jax.jit(grad).lower(x, wr, w1, w3, w2).as_text().count(
+        "stablehlo.while") == 2
+    forward, backward = loops(jax.make_jaxpr(grad)(x, wr, w1, w3, w2).jaxpr)
+    assert not [e for e, _ in equations(forward.params["body_jaxpr"].jaxpr)
+                if e.primitive.name == "optimization_barrier"]
+    body = backward.params["body_jaxpr"].jaxpr
+    at, = (i for i, e in enumerate(body.eqns)
+           if e.primitive.name == "optimization_barrier")
+    barrier = body.eqns[at]
+    shapes = [v.aval.shape for v in barrier.invars]
+    assert shapes == [(slab, width)] * 2 + [(held, width, width)] * 3
+    made_by = {v: e for e in body.eqns[:at] for v in e.outvars}
+    for gradient in barrier.invars[2:]:
+        # a weights' gradient: rows x rows -> [E, D, F], before the barrier
+        product = made_by[gradient]
+        assert product.primitive.name == "ragged_dot_general"
+        assert [v.aval.shape for v in product.invars[:2]] \
+            == [(slab, width)] * 2
+    behind = body.eqns[at + 1:]
+
+    def readers_of(var):
+        return [e for e in behind if any(v is var for v in e.invars)]
+
+    for cotangent in barrier.invars[:2]:
+        assert not readers_of(cotangent)
+    for cotangent in barrier.outvars[:2]:
+        # the rows' cotangents: a barrier's result x weights -> [slab, D]
+        readers = readers_of(cotangent)
+        assert readers and all(
+            e.primitive.name == "ragged_dot_general"
+            and e.outvars[0].aval.shape == (slab, width) for e in readers)
+    # and the trips' sums of the weights' gradients add the barrier's
+    for gradient in barrier.outvars[2:]:
+        reader, = readers_of(gradient)
+        assert reader.primitive.name == "add"
+        assert any(v is reader.outvars[0] for v in body.outvars)
 
 
 def test_under_amp_a_weights_gradient_is_summed_in_amps_type(monkeypatch):

@@ -92,8 +92,8 @@ def test_gauges_equal_the_assignment_counts_step_by_step():
 
 
 #: a routed layer that walks in slabs: 2 x 256 tokens x top-2 = 1,024 rows,
-#: 2 of 64 experts held, so ``moe.slab_rows`` gives 8 * 1,024 * 2 / 64 = 256
-SLAB_TOKENS, SLAB_ROUTED, SLAB_HELD, SLAB = 256, 64, 2, 256
+#: 2 of 32 experts held, so ``moe.slab_rows`` gives 4 * 1,024 * 2 / 32 = 256
+SLAB_TOKENS, SLAB_ROUTED, SLAB_HELD, SLAB = 256, 32, 2, 256
 
 
 def slabbed_program():

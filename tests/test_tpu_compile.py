@@ -188,29 +188,30 @@ def _delta_layer(t=8192, hk=16, hv=32, d=128, taps=4, chunk=64):
 
 
 #: the decoder cells' grouped products: rows of a walk (the slab of tokens x
-#: top_k, ``parallel/moe.slab_rows``: half of Trinity's 49,152, a quarter of
-#: Kimi-Linear's 16,384, all of the others'), hidden width, expert width,
+#: top_k, ``parallel/moe.slab_rows``: a quarter of Trinity's 49,152, an eighth
+#: of Kimi-Linear's 16,384, half of Instella's 49,152, all of the others'),
+#: hidden width, expert width,
 #: experts held
 #: (``chipbench/configs/<cell>/config.json``)
 GROUPED_CELLS = {"keye": (65536, 2048, 768, 16),
-                 "trinity": (24576, 2048, 1024, 8),
+                 "trinity": (12288, 2048, 1024, 8),
                  "lfm2": (32768, 2048, 1792, 8),
-                 "instella": (49152, 2048, 1408, 8),
+                 "instella": (24576, 2048, 1408, 8),
                  "qwen3_next": (81920, 2048, 512, 16),
                  "mellum2": (65536, 2304, 896, 8),
-                 "kimi_linear": (4096, 2304, 1024, 8)}
+                 "kimi_linear": (2048, 2304, 1024, 8)}
 #: no cell's: an expert width of 13 lane rows, whose only dividing tile is
 #: one lane row as at Instella's 11 (ragged tiles of 896 + 768 and 384 x 4 +
 #: 128 where the result is that wide: ``plain`` and ``weights_gradient`` of
 #: ``up``, ``transposed`` of ``down``); compiled alone, not as a layer
 GROUPED_WIDTHS = {**GROUPED_CELLS, "width_1664": (49152, 2048, 1664, 8),
-                  # ``nemotron_twotower_30b_a3b``'s (PR 58): a slab of half
-                  # its 49,152 rows, the first hidden width of 21 lane rows,
-                  # and an expert width of 1,856 = 14.5 lane rows that
-                  # ``parallel/moe._low`` fills up to 15 (two matrices an
-                  # expert: its layer has four of these six products twice
-                  # and none a third time); compiled alone
-                  "nemotron": (24576, 2688, 1920, 8)}
+                  # ``nemotron_twotower_30b_a3b``'s (PR 58): a slab of a
+                  # quarter of its 49,152 rows, the first hidden width of 21
+                  # lane rows, and an expert width of 1,856 = 14.5 lane rows
+                  # that ``parallel/moe._low`` fills up to 15 (two matrices
+                  # an expert: its layer has four of these six products
+                  # twice and none a third time); compiled alone
+                  "nemotron": (12288, 2688, 1920, 8)}
 
 
 def _grouped(cell, form, which):
@@ -470,8 +471,8 @@ def test_a_routed_layer_and_its_backward_lower_eleven_calls(
     ``grouped_matmul_t``, in the six signatures that
     ``test_grouped_signatures_are_the_benchmarks`` pins one product at a
     time, each counted as ``2 * M * D * F`` FLOPs by the benchmark's files,
-    M the rows of a walk: the slab's in Trinity and Kimi-Linear, whose
-    eleven calls stand ONCE, in the bodies of the layer's two loops;
+    M the rows of a walk: the slab's in Trinity, Kimi-Linear and Instella,
+    whose eleven calls stand ONCE, in the bodies of the layer's two loops;
     XLA drops none and adds none."""
     import sys
 
