@@ -20,9 +20,14 @@ in slabs of fewer than all (``parallel/moe.slab_rows``), ``slab``;
 lowered, its backward not counted, with ``gated="0"`` where the op is the
 filter and SiLU alone;
 ``ops.delta_rule.calls{key_heads,value_heads,dim,chunk,path}`` for every
-``gated_delta_rule`` lowered, its backward not counted there but as
-``ops.delta_rule.grad_calls{chunk,path="by_hand"}`` for every
-``gated_delta_rule_grad`` lowered (``by_hand``: the backward written out in
+``gated_delta_rule`` lowered (``path="pallas"``: the kernels of
+``ops/pallas_delta_rule.py``, where the ``flash`` gate is open and they
+take the operands; ``"xla"``: ``ops/delta_rule.py``, with
+``ops.delta_rule.declined{why}`` where the kernels were asked, would have
+been compiled and not interpreted, and gave a reason), its backward not counted there but as
+``ops.delta_rule.grad_calls{chunk,path}`` for every
+``gated_delta_rule_grad`` lowered (``path="pallas"``: the kernels' own
+backward; ``"by_hand"``: the backward written out in
 ``ops/delta_rule.py``, no autodiff through the walk over the chunks, the
 inverse or the scores, under a decay a value head and under one a key
 channel alike), and
@@ -437,16 +442,20 @@ def short_conv_grad(ctx):
 
 
 def _delta_rule(ctx):
-    """(the rule as a function of the op's five inputs, the inputs)."""
+    """(the rule as a function of the op's five inputs, the inputs, why the
+    Pallas kernels do not take them: ``delta_rule.kernel_declines``)."""
     from . import delta_rule
+
+    chunk = int(ctx.attr("chunk", 64))
 
     def rule(q, k, v, g, beta):
         return delta_rule.chunked(
-            q, k, v, g, beta, chunk=int(ctx.attr("chunk", 64)),
+            q, k, v, g, beta, chunk=chunk,
             scale=float(ctx.attr("scale", 0.0)),
             norm_eps=float(ctx.attr("norm_eps", 0.0)))
 
-    return rule, [ctx.input(s) for s in ("Q", "K", "V", "G", "Beta")]
+    operands = [ctx.input(s) for s in ("Q", "K", "V", "G", "Beta")]
+    return rule, operands, delta_rule.kernel_declines(*operands[:4], chunk)
 
 
 @register_op("gated_delta_rule")
@@ -459,13 +468,20 @@ def gated_delta_rule_op(ctx):
     first.  The state starts at zero in every row of the batch and nothing
     crosses from one row to the next.  G [B, T, Hv, dk]: a decay a key
     channel, the state's rows each by their own."""
-    from . import delta_rule
+    from . import delta_rule, kernel_choice
 
-    rule, operands = _delta_rule(ctx)
+    rule, operands, why = _delta_rule(ctx)
     q, v, g = operands[0], operands[2], operands[3]
     chunk = int(ctx.attr("chunk", 64))
+    # a refusal is counted where the kernels would have been compiled: off
+    # the TPU they are interpreted, a correctness tool, and what the tests
+    # and the benchmark's rehearsals run (chunks of 16, heads of 8) is no
+    # rule they are for; ``calls{path}`` says which path ran there too
+    if why and not kernel_choice.interpret():
+        _count("ops.delta_rule.declined", why=why)
     _count("ops.delta_rule.calls", key_heads=q.shape[2],
-           value_heads=v.shape[2], dim=v.shape[3], chunk=chunk, path="xla")
+           value_heads=v.shape[2], dim=v.shape[3], chunk=chunk,
+           path="pallas" if why == "" else "xla")
     if g.ndim == 4:
         _count("ops.delta_rule.channel_calls", key_heads=q.shape[2],
                dim=q.shape[3], chunk=chunk, sub=delta_rule.sub_block(chunk))
@@ -481,9 +497,9 @@ def gated_delta_rule_grad(ctx):
     the inverse, what each token writes) and the state at every chunk's
     start are made again, the outputs are not, then the chunks are walked
     backwards; nothing but the inputs is kept from the forward."""
-    rule, operands = _delta_rule(ctx)
+    rule, operands, why = _delta_rule(ctx)
     _count("ops.delta_rule.grad_calls", chunk=int(ctx.attr("chunk", 64)),
-           path="by_hand")
+           path="pallas" if why == "" else "by_hand")
     # behind a barrier with the cotangent in it, as ``jax.checkpoint`` puts
     # one: without it XLA finds the second forward to be the first and
     # keeps a gigabyte a layer (every chunk's state, inverse and writes)

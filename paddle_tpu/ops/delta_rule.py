@@ -3,7 +3,13 @@ that every token decays, corrects and reads, in the chunked form a training
 step needs, with its backward written by hand.  The mathematics of the op
 ``gated_delta_rule`` and of its grad op (``ops/decoder_ops.py``), plain
 ``jax.numpy`` with one ``lax.scan`` over the chunks forward and two
-backward: the XLA lowering, and what the CPU runs.
+backward: the XLA lowering.  Under a decay a value head it is the TWIN of
+the Pallas kernels beside it (``ops/pallas_delta_rule.py``, the same
+equations with a chunk's arrays in VMEM), which ``chunked`` calls where the
+``flash`` gate is open (``ops/kernel_choice.py``: a TPU, or the switch) and
+``pallas_delta_rule.supported`` gives no reason against
+(``kernel_declines``); it is what the CPU runs, what a decay a key channel
+runs everywhere, and the oracle of the kernels' tests.
 
 For one value head (its key head is ``h // (Hv // Hk)``: key head j serves
 the value heads ``j * Hv / Hk`` and the ``Hv / Hk - 1`` after it), with
@@ -543,14 +549,28 @@ def _channel_rule_bwd(low, operands, dout):
 _channel_rule.defvjp(_channel_rule_fwd, _channel_rule_bwd)
 
 
+def kernel_declines(q, k, v, g, chunk):
+    """Why the Pallas kernels (``ops/pallas_delta_rule.py``) do not take
+    these operands of ``chunked``: '' where they do, and None where the
+    ``flash`` gate is closed and nothing was asked of them."""
+    from . import kernel_choice, pallas_delta_rule
+
+    if not kernel_choice.gate("flash"):
+        return None
+    return pallas_delta_rule.supported(q, k, v, g, chunk)
+
+
 def chunked(q, k, v, g, beta, chunk=64, scale=0.0, norm_eps=0.0):
     """q, k: [B, T, Hk, dk]; v: [B, T, Hv, dv]; g, beta: [B, T, Hv] ->
     [B, T, Hv, dv] in v's type.  ``scale`` multiplies q (0: ``dk ** -0.5``);
     ``norm_eps`` > 0: q and k are l2-normed per head first, with that
-    epsilon.  ``T`` need not be a multiple of ``chunk``: the tail is padded
-    with tokens that write nothing (beta 0) and decay nothing (g 0).  A
+    epsilon.  ``T`` need not be a multiple of ``chunk`` (for the kernels: of
+    a grid step's tokens): the tail is padded with tokens that write
+    nothing (beta 0) and decay nothing (g 0).  A
     ``g`` [B, T, Hv, dk] is a decay a key channel (``_channel_rule``), whose
-    chunk is a multiple of SUB_BLOCKS."""
+    chunk is a multiple of SUB_BLOCKS.  Norm, scale and padding are made
+    here for either path; then the Pallas kernels where they are asked and
+    take the operands (``kernel_declines``), else ``_rule``."""
     from ..fluid import amp
 
     b, t, hk, dk = q.shape
@@ -561,11 +581,26 @@ def chunked(q, k, v, g, beta, chunk=64, scale=0.0, norm_eps=0.0):
         q, k = l2norm(q, norm_eps), l2norm(k, norm_eps)
     q = q.astype(f32) * f32(scale or dk ** -0.5)
     k = k.astype(f32)
-    pad = -t % chunk
+    takes, whole = kernel_declines(q, k, v, g, chunk) == "", chunk
+    if takes:
+        from . import pallas_delta_rule
+
+        whole = pallas_delta_rule.TOKENS    # a grid step: a few chunks
+    pad = -t % whole
     if pad:
         q, k, v, g, beta = (jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (
             a.ndim - 2)) for a in (q, k, v, g, beta))
     n = (t + pad) // chunk
+    if takes:
+        out = pallas_delta_rule.rule(amp.compute_dtype(), q, k, v,
+                                     g.astype(f32), beta.astype(f32))
+        # behind a barrier: where the norm after the rule reads the kernel's
+        # result as it lies, XLA keeps that norm's statistic broadcast to
+        # the result's shape from the forward pass to the backward, 134 MB
+        # a layer of Qwen3-Next (read from ``preallocated-temp`` of a
+        # described-chip compile: 5.89 GB without, 5.17 with, 5.57 on the
+        # XLA path)
+        return lax.optimization_barrier(out[:, :t])
     if g.ndim == 4:
         # every value head its own decayed keys: a key head is repeated
         q, k = (jnp.repeat(a, rep, 2) if rep > 1 else a for a in (q, k))

@@ -127,6 +127,19 @@ def paged():
             [layers.paged_attention(*args, scale=0.25)])
 
 
+def delta_rule():
+    """One chunk of one key head's two value heads, heads of 128: the
+    least the scalar rule's kernels take."""
+    t, d = 64, 128
+    shapes = {"q": [t, 1, d], "k": [t, 1, d], "v": [t, 2, d], "g": [t, 2],
+              "beta": [t, 2]}
+    args = [layers.data(name=n, shape=s, dtype="float32")
+            for n, s in shapes.items()]
+    feed = {n: np.full([1] + s, -0.5 if n == "g" else 0.5, "float32")
+            for n, s in shapes.items()}
+    return feed, [layers.gated_delta_rule(*args, chunk=64)]
+
+
 #: family -> (its program, its group, the kernel's name stack in the
 #: lowered text, the counter that says a kernel ran, the one that says its
 #: twin did)
@@ -143,6 +156,12 @@ FAMILIES = {
         "sparse_attention/window_flash_fwd/pallas_call",
         'ops.sparse_attention.calls{path="pallas"',
         'ops.sparse_attention.calls{path="xla"'),
+    "delta_rule": (
+        delta_rule, "flash", "gated_delta_rule/delta_rule_fwd/pallas_call",
+        'ops.delta_rule.calls{chunk="64",dim="128",key_heads="1",'
+        'path="pallas"',
+        'ops.delta_rule.calls{chunk="64",dim="128",key_heads="1",'
+        'path="xla"'),
     "xent": (xent, "fused", "softmax_with_cross_entropy/pallas_call",
              'ops.fused.softmax_xent{target="hard"', None),
     "xent_smoothed": (xent_smoothed, "fused",

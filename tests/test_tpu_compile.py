@@ -291,6 +291,31 @@ def test_kernel_compiles_for_v5e(topo, name):
     assert compiled.as_text().count("tpu_custom_call") >= n_calls
 
 
+@pytest.mark.parametrize("hk,hv", [(16, 32), (16, 16)])
+def test_the_delta_rules_kernels_compile_for_v5e(topo, monkeypatch, hk, hv):
+    """``delta_layer`` again with the flash gate open: the scalar rule's
+    three kernels (``ops/pallas_delta_rule``) at the cell's shapes, two
+    value heads a key head as Qwen3-Next has them and one, under bf16 AMP.
+    The grad of the layer holds the states pass and the backward walk and
+    NOT the forward walk, whose output nothing reads; each call declares
+    the operands ``chipbench/kernels/delta_rule_*.py`` count from."""
+    monkeypatch.setenv(kernel_choice.SWITCHES["flash"], "1")
+    monkeypatch.setattr(kernel_choice, "interpret",
+                        lambda stated=None: False)
+    fn, shapes = _delta_layer(hk=hk, hv=hv)
+    chip = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for kernel, there in (("delta_rule_states", True),
+                          ("delta_rule_bwd", True),
+                          ("delta_rule_fwd", False)):
+        assert (f"({kernel}))/pallas_call" in text) is there, kernel
+        assert (kernel in text) is there, kernel
+    states = f"bf16[1,{hv // 2},128,2,128,128]"
+    assert states in text
+
+
 #: what ``chipbench/kernels/flash_*.py`` count FLOPs from and what
 #: ``chipbench/trace_reduce.kernel_roofline`` matches trace events by: family
 #: -> (kernel, contractions, plain operands, results).  The benchmark's files
