@@ -21,10 +21,13 @@ lowered, its backward not counted, with ``gated="0"`` where the op is the
 filter and SiLU alone;
 ``ops.delta_rule.calls{key_heads,value_heads,dim,chunk,path}`` for every
 ``gated_delta_rule`` lowered (``path="pallas"``: the kernels of
-``ops/pallas_delta_rule.py``, where the ``flash`` gate is open and they
+``ops/pallas_delta_rule.py``, the scalar rule's or, under a decay a key
+channel, the channel rule's, where the ``flash`` gate is open and they
 take the operands; ``"xla"``: ``ops/delta_rule.py``, with
-``ops.delta_rule.declined{why}`` where the kernels were asked, would have
-been compiled and not interpreted, and gave a reason), its backward not counted there but as
+``ops.delta_rule.declined{why}`` (``why``: ``chunk``, ``width`` or
+``heads``, of either family) where the kernels were asked, would have
+been compiled and not interpreted, and gave a reason), its backward not
+counted there but as
 ``ops.delta_rule.grad_calls{chunk,path}`` for every
 ``gated_delta_rule_grad`` lowered (``path="pallas"``: the kernels' own
 backward; ``"by_hand"``: the backward written out in
@@ -32,7 +35,7 @@ backward; ``"by_hand"``: the backward written out in
 inverse or the scores, under a decay a value head and under one a key
 channel alike), and
 ``ops.delta_rule.channel_calls{key_heads,dim,chunk,sub}`` beside ``calls``
-for every forward lowered with such a G;
+for every forward lowered with such a G, whichever path it took;
 ``ops.ssd.scans{heads,dim,groups,state,chunk,path}`` for every ``ssd_scan``
 lowered and ``ops.ssd.grad_scans{chunk,path="by_hand"}`` for every
 ``ssd_scan_grad`` (the backward written out in ``ops/ssd.py``);

@@ -3,13 +3,15 @@ that every token decays, corrects and reads, in the chunked form a training
 step needs, with its backward written by hand.  The mathematics of the op
 ``gated_delta_rule`` and of its grad op (``ops/decoder_ops.py``), plain
 ``jax.numpy`` with one ``lax.scan`` over the chunks forward and two
-backward: the XLA lowering.  Under a decay a value head it is the TWIN of
-the Pallas kernels beside it (``ops/pallas_delta_rule.py``, the same
-equations with a chunk's arrays in VMEM), which ``chunked`` calls where the
-``flash`` gate is open (``ops/kernel_choice.py``: a TPU, or the switch) and
+backward: the XLA lowering.  Under either kind of decay (a number a value
+head, ``_rule``; a vector along the key, ``_channel_rule``) it is the TWIN
+of the Pallas kernels beside it (``ops/pallas_delta_rule.py``, the same
+equations with a chunk's arrays in VMEM: ``rule`` and ``channel_rule``),
+which ``chunked`` calls where the ``flash`` gate is open
+(``ops/kernel_choice.py``: a TPU, or the switch) and
 ``pallas_delta_rule.supported`` gives no reason against
-(``kernel_declines``); it is what the CPU runs, what a decay a key channel
-runs everywhere, and the oracle of the kernels' tests.
+(``kernel_declines``); it is what the CPU runs, what operands the kernels
+refuse run everywhere, and the oracle of the kernels' tests.
 
 For one value head (its key head is ``h // (Hv // Hk)``: key head j serves
 the value heads ``j * Hv / Hk`` and the ``Hv / Hk - 1`` after it), with
@@ -550,9 +552,12 @@ _channel_rule.defvjp(_channel_rule_fwd, _channel_rule_bwd)
 
 
 def kernel_declines(q, k, v, g, chunk):
-    """Why the Pallas kernels (``ops/pallas_delta_rule.py``) do not take
-    these operands of ``chunked``: '' where they do, and None where the
-    ``flash`` gate is closed and nothing was asked of them."""
+    """Why the Pallas kernels (``ops/pallas_delta_rule.py``: the scalar
+    rule's for ``g`` [B, T, Hv], the channel rule's for ``g`` [B, T, Hv,
+    dk]) do not take these operands of ``chunked``: '' where they do, else
+    ``pallas_delta_rule.supported``'s reason ('chunk', 'width', 'heads'),
+    and None where the ``flash`` gate is closed and nothing was asked of
+    them."""
     from . import kernel_choice, pallas_delta_rule
 
     if not kernel_choice.gate("flash"):
@@ -569,8 +574,9 @@ def chunked(q, k, v, g, beta, chunk=64, scale=0.0, norm_eps=0.0):
     nothing (beta 0) and decay nothing (g 0).  A
     ``g`` [B, T, Hv, dk] is a decay a key channel (``_channel_rule``), whose
     chunk is a multiple of SUB_BLOCKS.  Norm, scale and padding are made
-    here for either path; then the Pallas kernels where they are asked and
-    take the operands (``kernel_declines``), else ``_rule``."""
+    here for either path; then the Pallas kernels of that kind of decay
+    where they are asked and take the operands (``kernel_declines``), else
+    ``_rule`` or ``_channel_rule``."""
     from ..fluid import amp
 
     b, t, hk, dk = q.shape
@@ -592,8 +598,10 @@ def chunked(q, k, v, g, beta, chunk=64, scale=0.0, norm_eps=0.0):
             a.ndim - 2)) for a in (q, k, v, g, beta))
     n = (t + pad) // chunk
     if takes:
-        out = pallas_delta_rule.rule(amp.compute_dtype(), q, k, v,
-                                     g.astype(f32), beta.astype(f32))
+        rule = pallas_delta_rule.rule if g.ndim == 3 \
+            else pallas_delta_rule.channel_rule
+        out = rule(amp.compute_dtype(), q, k, v, g.astype(f32),
+                   beta.astype(f32))
         # behind a barrier: where the norm after the rule reads the kernel's
         # result as it lies, XLA keeps that norm's statistic broadcast to
         # the result's shape from the forward pass to the backward, 134 MB

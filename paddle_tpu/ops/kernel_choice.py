@@ -7,7 +7,7 @@ is its environment switch where that is set (``0`` / ``false`` closed, ``1``
 / ``true`` open, whatever the platform), else the platform: open on a TPU
 backend, closed elsewhere.  ``flash`` (``PADDLE_TPU_FLASH``) is
 ``ring_attention``, the transformer stacks' attention,
-``sparse_attention`` and the scalar ``gated_delta_rule``
+``sparse_attention`` and ``gated_delta_rule`` under either kind of decay
 (``ops/pallas_delta_rule.py``: a linear attention, so the attention
 kernels' gate); ``fused`` (``PADDLE_TPU_FUSED``) is softmax
 cross-entropy, the Adam and momentum sweeps and ``paged_attention``.  The

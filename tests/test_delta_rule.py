@@ -771,42 +771,52 @@ KERNEL_CASES = {
 _KERNEL_RUNS = {}
 
 
-def kernel_runs(case):
-    """(operands, {path: (out, five cotangents)}) of a case through
-    ``chunked`` with the flash gate closed ('xla': ``_rule``) and open
-    ('pallas': the kernels, interpreted on this CPU), made once a case."""
+def both_paths(xs, low, eps):
+    """{path: (out, five cotangents)} of ``xs`` through ``chunked`` with
+    the flash gate closed ('xla': the twin) and open ('pallas': the
+    kernels, interpreted on this CPU)."""
     import os
 
     from paddle_tpu.ops import kernel_choice
 
+    runs, name = {}, kernel_choice.SWITCHES["flash"]
+    before = os.environ.get(name)
+    try:
+        for path, flag in (("xla", "0"), ("pallas", "1")):
+            os.environ[name] = flag
+
+            def rule(*a):       # a path its own: jax keeps a trace
+                return delta_rule.chunked(*a, chunk=64, norm_eps=eps)
+
+            with fluid.amp.amp_guard(low, keep_activations=True) \
+                    if low else contextlib.nullcontext():
+                jaxpr = jax.make_jaxpr(rule)(*xs)
+                assert any(e.primitive.name == "pallas_call"
+                           for e in eqns_of(jaxpr)) is (path == "pallas")
+
+                def loss(*a):       # the value beside it: one forward
+                    out = rule(*a)
+                    return weighted_sum(
+                        lambda: out.astype(jnp.float32))(), out
+
+                (_, out), grads = jax.value_and_grad(
+                    loss, range(5), has_aux=True)(*xs)
+                runs[path] = (out, grads)
+    finally:
+        os.environ.pop(name) if before is None \
+            else os.environ.__setitem__(name, before)
+    return runs
+
+
+def kernel_runs(case):
+    """(operands, ``both_paths``) of a case, made once a case."""
     if case not in _KERNEL_RUNS:
         t, b, hk, hv, low, decay, eps = KERNEL_CASES[case]
         xs = operands(t, seed=t, b=b, hk=hk, hv=hv, dk=128, dv=128,
                       decay=decay)
         if low:
             xs = tuple(a.astype(low) for a in xs[:3]) + xs[3:]
-
-        runs, name = {}, kernel_choice.SWITCHES["flash"]
-        before = os.environ.get(name)
-        try:
-            for path, flag in (("xla", "0"), ("pallas", "1")):
-                os.environ[name] = flag
-
-                def rule(*a):       # a path its own: jax keeps a trace
-                    return delta_rule.chunked(*a, chunk=64, norm_eps=eps)
-
-                with fluid.amp.amp_guard(low, keep_activations=True) \
-                        if low else contextlib.nullcontext():
-                    jaxpr = jax.make_jaxpr(rule)(*xs)
-                    assert any(e.primitive.name == "pallas_call"
-                               for e in eqns_of(jaxpr)) is (path == "pallas")
-                    runs[path] = (rule(*xs), jax.grad(
-                        weighted_sum(lambda *a: rule(*a).astype(jnp.float32)),
-                        range(5))(*xs))
-        finally:
-            os.environ.pop(name) if before is None \
-                else os.environ.__setitem__(name, before)
-        _KERNEL_RUNS[case] = xs, runs
+        _KERNEL_RUNS[case] = xs, both_paths(xs, low, eps)
     return _KERNEL_RUNS[case]
 
 
@@ -860,7 +870,12 @@ REFUSALS = {
     "value_width": (1, 2, 128, 8, 64, False),
     "heads": (1, 4, 128, 128, 64, False),
     "odd_heads": (1, 1, 128, 128, 64, False),
-    "channel_decay": (2, 2, 128, 128, 64, True),
+    # a decay a key channel: its kernels' own reasons
+    "channel_chunk": (2, 2, 128, 128, 32, True),
+    "channel_width": (2, 2, 64, 128, 64, True),
+    "channel_wide_keys": (2, 2, 256, 128, 64, True),
+    "channel_heads": (1, 2, 128, 128, 64, True),
+    "channel_odd_heads": (3, 3, 128, 128, 64, True),
 }
 
 
@@ -874,7 +889,9 @@ def test_what_the_kernels_refuse_is_counted_and_the_xla_rule_runs(
     from paddle_tpu.ops import kernel_choice, pallas_delta_rule
 
     hk, hv, dk, dv, chunk, channel = REFUSALS[case]
-    why = {"value_width": "width", "odd_heads": "heads"}.get(case, case)
+    why = case.removeprefix("channel_")
+    why = {"value_width": "width", "wide_keys": "width",
+           "odd_heads": "heads"}.get(why, why)
     t = 40
     monkeypatch.setenv(kernel_choice.SWITCHES["flash"], "1")
     monkeypatch.setattr(kernel_choice, "interpret",

@@ -140,6 +140,19 @@ def delta_rule():
     return feed, [layers.gated_delta_rule(*args, chunk=64)]
 
 
+def delta_channel():
+    """One chunk of two heads of 128 whose decay is a vector along the key:
+    the least the channel rule's kernels take."""
+    t, d = 64, 128
+    shapes = {"q": [t, 2, d], "k": [t, 2, d], "v": [t, 2, d],
+              "g": [t, 2, d], "beta": [t, 2]}
+    args = [layers.data(name=n, shape=s, dtype="float32")
+            for n, s in shapes.items()]
+    feed = {n: np.full([1] + s, -0.5 if n == "g" else 0.5, "float32")
+            for n, s in shapes.items()}
+    return feed, [layers.gated_delta_rule(*args, chunk=64)]
+
+
 #: family -> (its program, its group, the kernel's name stack in the
 #: lowered text, the counter that says a kernel ran, the one that says its
 #: twin did)
@@ -161,6 +174,13 @@ FAMILIES = {
         'ops.delta_rule.calls{chunk="64",dim="128",key_heads="1",'
         'path="pallas"',
         'ops.delta_rule.calls{chunk="64",dim="128",key_heads="1",'
+        'path="xla"'),
+    "delta_channel": (
+        delta_channel, "flash",
+        "gated_delta_rule/delta_channel_fwd/pallas_call",
+        'ops.delta_rule.calls{chunk="64",dim="128",key_heads="2",'
+        'path="pallas"',
+        'ops.delta_rule.calls{chunk="64",dim="128",key_heads="2",'
         'path="xla"'),
     "xent": (xent, "fused", "softmax_with_cross_entropy/pallas_call",
              'ops.fused.softmax_xent{target="hard"', None),

@@ -316,6 +316,50 @@ def test_the_delta_rules_kernels_compile_for_v5e(topo, monkeypatch, hk, hv):
     assert states in text
 
 
+def test_the_channel_delta_rules_kernels_compile_for_v5e(topo, monkeypatch):
+    """The rule under a decay a key channel at ``kimi_linear_48b_a3b``'s
+    shapes (one sequence of 2,048 tokens, 32 heads of 128, chunks of 64)
+    under bf16 AMP with the flash gate open: the grad holds the states pass
+    and the backward walk and NOT the forward walk; each call declares the
+    operands ``chipbench/kernels/delta_channel_*.py`` count from (q, k, g
+    ``[B, T, H * dk]`` float32, ``beta``'s columns, the transposed states),
+    and within Mosaic's default VMEM."""
+    from paddle_tpu.fluid import amp
+    from paddle_tpu.ops import delta_rule
+
+    monkeypatch.setenv(kernel_choice.SWITCHES["flash"], "1")
+    monkeypatch.setattr(kernel_choice, "interpret",
+                        lambda stated=None: False)
+    t, h, d = 2048, 32, 128
+
+    def rule(q, k, v, g, beta):
+        with amp.amp_guard("bfloat16", keep_activations=True):
+            return delta_rule.chunked(q, k, v, g, beta, chunk=64,
+                                      norm_eps=1e-6)
+
+    def grads(*xs):
+        return jax.grad(lambda *a: rule(*a).astype(F32).sum(),
+                        range(5))(*xs)
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    head = ((1, t, h, d), BF16)
+    args = [jax.ShapeDtypeStruct(s, ty, sharding=chip) for s, ty in (
+        head, head, head, ((1, t, h, d), F32), ((1, t, h), F32))]
+    text = jax.jit(rule).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "/delta_channel_fwd/pallas_call" in text
+    text = jax.jit(grads).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for kernel, there in (("delta_channel_states", True),
+                          ("delta_channel_bwd", True),
+                          ("delta_channel_fwd", False)):
+        assert (f"({kernel}))/pallas_call" in text) is there, kernel
+    wide, cols = f"f32[1,{t},{h * d}]", f"f32[1,{h // 2},{t * 2},128]"
+    states = f"bf16[1,{h // 2},{t // 64},2,{d},{d}]"
+    for declared in (wide, cols, states):
+        assert declared in text, declared
+
+
 #: what ``chipbench/kernels/flash_*.py`` count FLOPs from and what
 #: ``chipbench/trace_reduce.kernel_roofline`` matches trace events by: family
 #: -> (kernel, contractions, plain operands, results).  The benchmark's files
