@@ -112,9 +112,29 @@ trainer = fluid.Trainer(
 resume_step = cfg.step_id
 steps = []
 
+def peers_committed():
+    import glob
+    return all(glob.glob(os.path.join(workdir, "ckpt_r%d" % r, "**",
+                                      "_SUCCESS"), recursive=True)
+               for r in range(spec.N_PROC))
+
 def handler(ev):
     if isinstance(ev, fluid.EndStepEvent):
         steps.append(ev.step)
+        # the ranks train unsynchronized and the supervisor tears the
+        # generation down when the FIRST of them dies at the kill step: a
+        # rank that has not committed a serial by then resumes at step 0,
+        # rightly, and the oracle below has nothing to say about it.  So
+        # no rank enters the kill's window before every rank has committed.
+        if gen == 0 and ev.step == spec.KILL_STEP - spec.SPD:
+            import time
+            deadline = time.monotonic() + 120.0
+            while not peers_committed():
+                assert time.monotonic() < deadline, \
+                    "rank %d waited 120 s at step %d for a committed " \
+                    "serial (_SUCCESS) of every rank under %s" % (
+                        rank, ev.step, workdir)
+                time.sleep(0.05)
 
 trainer.train(num_epochs=1, event_handler=handler, reader=pipe,
               feed_order=["x", "y"])

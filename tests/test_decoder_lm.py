@@ -3,10 +3,6 @@ parallel/moe.routed_experts, models/decoder_lm.py) against the plain
 float32 reference of ``chipbench/configs/keye_vl_2_0_30b_a3b`` at a tiny
 size, on the CPU, with seeded random weights."""
 
-import json
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,25 +15,13 @@ from paddle_tpu.ops import pallas_sparse_flash as psf
 from paddle_tpu.parallel import moe
 from paddle_tpu.parallel.ring_attention import full_attention
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
+import decoder_reference
+from decoder_reference import (compiled, counters, dense, moe_weights,
+                               reference_step, seeded_program)
 
-from chipbench import plugins  # noqa: E402
-
-CONFIG = "configs/keye_vl_2_0_30b_a3b"
-BUILD = plugins.load(CONFIG, "build")
-REF = plugins.load(CONFIG, "reference")
-
-
-def tiny_sizes(**over):
-    sizes = json.load(open(os.path.join(ROOT, "chipbench", CONFIG,
-                                        "config.json")))
-    return {**sizes, **sizes["tiny"], **over}
-
-
-def counters(prefix):
-    return {k: v for k, v in fluid.profiler.counters().items()
-            if k.startswith(prefix)}
+BUILD, REF, tiny_sizes = decoder_reference.load("keye_vl_2_0_30b_a3b")
+# the window layers' section is tests/test_decoder_lm_window.py
+T_BUILD, _, trinity_sizes = decoder_reference.load("trinity_mini")
 
 
 # -- (a) the program against the reference ------------------------------
@@ -52,22 +36,12 @@ def test_program_equals_the_reference_where_the_selection_cuts(
     sizes = tiny_sizes()
     assert sizes["seq_len"] >= 4 * sizes["sa_config"]["topk"]
     assert sizes["num_experts"] < sizes["published"]["num_experts"]
-    built = BUILD.build(fluid, sizes)
-    main = fluid.default_main_program()
-    names = BUILD.trainable_names(main)
-    spec = REF.param_spec(sizes)
-    assert [n for n, _, _ in spec] == names
-    exe = fluid.Executor(fluid.TPUPlace())
-    exe.run(fluid.default_startup_program())
-    scope = fluid.global_scope()
-    weights = REF.init_params(5, sizes)
-    for (_, shape, _), name, w in zip(spec, names, weights):
-        assert tuple(np.shape(scope.get(name))) == tuple(shape), name
-        scope.set(name, jnp.array(w))
+    built, names, weights = seeded_program(BUILD, REF, sizes)
     feed = BUILD.make_feed(sizes, 2, np.random.RandomState(3))
-    outs = exe.run(main, feed=feed, fetch_list=[built["loss"]]
-                   + [n + "@GRAD" for n in names])
-    ref_loss, ref_grads = REF.loss_and_grads(weights, feed, sizes)
+    outs = fluid.Executor(fluid.TPUPlace()).run(
+        fluid.default_main_program(), feed=feed,
+        fetch_list=[built["loss"]] + [n + "@GRAD" for n in names])
+    ref_loss, ref_grads, _ = reference_step(REF, sizes, weights, feed)
     assert float(outs[0].reshape(-1)[0]) == pytest.approx(float(ref_loss),
                                                           rel=1e-5)
     for name, g, r in zip(names, outs[1:], ref_grads):
@@ -133,15 +107,6 @@ def test_selecting_every_key_equals_causal_attention_through_ring_attention(
 
 # -- (c) the share test ---------------------------------------------------
 
-def moe_weights(rng, n, d, f, routed):
-    x = jnp.asarray(rng.randn(n, d), jnp.float32)
-    wr = jnp.asarray(rng.randn(d, routed), jnp.float32)
-    w1, w3 = (jnp.asarray(0.3 * rng.randn(routed, d, f), jnp.float32)
-              for _ in range(2))
-    w2 = jnp.asarray(0.3 * rng.randn(routed, f, d), jnp.float32)
-    return x, wr, w1, w3, w2
-
-
 def test_the_eight_shares_add_up_to_the_uncut_layer():
     """128 routed experts, 8 per token, 16 held by each of 8 chips
     (``expert_offset`` 0, 16, ..., 112): the parts add up to what the
@@ -186,10 +151,10 @@ def test_every_token_choosing_held_experts_only_loses_no_assignment():
     def reference(x, w1, w2):
         return jnp.sum(REF.moe_layer(x, wr, w1, w3, w2, k, off) ** 2)
 
-    np.testing.assert_allclose(program(x, w1, w2), reference(x, w1, w2),
-                               rtol=1e-5)
-    for g, r in zip(jax.grad(program, (0, 1, 2))(x, w1, w2),
-                    jax.grad(reference, (0, 1, 2))(x, w1, w2)):
+    got = jax.jit(jax.value_and_grad(program, (0, 1, 2)))(x, w1, w2)
+    want = jax.jit(jax.value_and_grad(reference, (0, 1, 2)))(x, w1, w2)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for g, r in zip(got[1], want[1]):
         np.testing.assert_allclose(g, r, atol=1e-4 * float(jnp.abs(r).max()))
 
 
@@ -213,9 +178,11 @@ def test_every_row_of_a_grouped_product_lies_in_a_group(monkeypatch):
         return jnp.where(rows, plain(lhs, rhs, sizes, **kw), jnp.nan)
 
     monkeypatch.setattr(moe.lax, "ragged_dot", poisoned)
-    y = moe.routed_experts(x, wr, w1, w3, w2, top_k=k)
-    np.testing.assert_allclose(y, REF.moe_layer(x, wr, w1, w3, w2, k, 0),
-                               atol=1e-5)
+    y = jax.jit(lambda *a: moe.routed_experts(*a, top_k=k))(
+        x, wr, w1, w3, w2)
+    np.testing.assert_allclose(
+        y, jax.jit(lambda *a: REF.moe_layer(*a, k, 0))(x, wr, w1, w3, w2),
+        atol=1e-5)
     # a row outside the groups would have made the result NaN
     assert seen == [(n * k, jnp.int32)] * 3      # the TPU refuses int64
     _, idx = moe.route_top_k(x, wr, k)
@@ -259,13 +226,14 @@ def test_rows_without_a_held_assignment_poison_nothing(monkeypatch):
     def reference(x, w1, w3, w2):
         return jnp.sum(REF.moe_layer(x, wr, w1, w3, w2, k, 0) ** 2)
 
-    want = jax.value_and_grad(reference, (0, 1, 2, 3))(x, w1, w3, w2)
+    want = jax.jit(jax.value_and_grad(reference, (0, 1, 2, 3)))(
+        x, w1, w3, w2)
     monkeypatch.setattr(moe.lax, "ragged_dot", poisoned)
 
     def program(x, w1, w3, w2):
         return jnp.sum(moe.routed_experts(x, wr, w1, w3, w2, top_k=k) ** 2)
 
-    got = jax.value_and_grad(program, (0, 1, 2, 3))(x, w1, w3, w2)
+    got = jax.jit(jax.value_and_grad(program, (0, 1, 2, 3)))(x, w1, w3, w2)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
     for g, r in zip(got[1], want[1]):
         np.testing.assert_allclose(g, r, atol=1e-4 * float(jnp.abs(r).max()))
@@ -284,20 +252,6 @@ def test_moe_experts_op_refuses_a_share_outside_the_router():
 
 
 # -- (e) grouped-query flash against full_attention -----------------------
-
-def dense(q, k, v, sel):
-    g = q.shape[1] // k.shape[1]
-    t = q.shape[2]
-    bias = None
-    if sel is not None:
-        bias = jnp.where(sel > 0, 0.0, -jnp.inf)[:, None]
-    kr, vr = jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
-    if bias is None:
-        return full_attention(q, kr, vr, causal=True)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, kr) * q.shape[-1] ** -0.5 + bias
-    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
-    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), vr)
-
 
 @pytest.mark.parametrize("selected", [False, True])
 @pytest.mark.parametrize("block", [64, 16])
@@ -324,16 +278,17 @@ def test_grouped_query_flash_forward_and_backward_at_head_width_128(
     def plain(q, k, v):
         return jnp.sum(dense(q, k, v, sel) * w)
 
+    want = jax.jit(lambda *a: dense(*a, sel))(q, k, v)
     np.testing.assert_allclose(
-        psf.sparse_flash_attention(q, k, v, sel, None, True),
-        dense(q, k, v, sel), atol=2e-5)
-    for g, r in zip(jax.grad(kernel, (0, 1, 2))(q, k, v),
-                    jax.grad(plain, (0, 1, 2))(q, k, v)):
+        jax.jit(lambda *a: psf.sparse_flash_attention(
+            *a, sel, None, True))(q, k, v), want, atol=2e-5)
+    for g, r in zip(jax.jit(jax.grad(kernel, (0, 1, 2)))(q, k, v),
+                    jax.jit(jax.grad(plain, (0, 1, 2)))(q, k, v)):
         np.testing.assert_allclose(g, r, atol=2e-4)
     # the blocked XLA path is the same function
     np.testing.assert_allclose(
-        decoder_ops.blocked_attention(q, k, v, sel, d ** -0.5, block=block),
-        dense(q, k, v, sel), atol=2e-5)
+        jax.jit(lambda *a: decoder_ops.blocked_attention(
+            *a, sel, d ** -0.5, block=block))(q, k, v), want, atol=2e-5)
 
 
 def test_operands_the_kernels_do_not_take_are_declined_with_a_reason(
@@ -382,11 +337,15 @@ def test_selection_of_the_program_is_the_references():
     x = jnp.asarray(rng.randn(1, t, d), jnp.float32)
     wq, wk, ww = (jnp.asarray(0.1 * rng.randn(d, n), jnp.float32)
                   for n in (c["hi"] * c["di"], c["di"], c["hi"]))
-    got = decoder_ops.index_select(x, wq, wk, ww, c["hi"], c["topk"],
-                                   c["theta"])
-    qi = REF.rope((x[0] @ wq).reshape(t, c["hi"], c["di"]), c["theta"])
-    ki = REF.rope((x[0] @ wk).reshape(t, 1, c["di"]), c["theta"])[:, 0]
-    want = REF.selection(qi, ki, x[0] @ ww, c["topk"], 0)
+    got = jax.jit(lambda *a: decoder_ops.index_select(
+        *a, c["hi"], c["topk"], c["theta"]))(x, wq, wk, ww)
+
+    def stated(x, wq, wk, ww):
+        qi = REF.rope((x[0] @ wq).reshape(t, c["hi"], c["di"]), c["theta"])
+        ki = REF.rope((x[0] @ wk).reshape(t, 1, c["di"]), c["theta"])[:, 0]
+        return REF.selection(qi, ki, x[0] @ ww, c["topk"], 0)
+
+    want = jax.jit(stated)(x, wq, wk, ww)
     assert np.array_equal(np.asarray(got[0]) > 0, np.asarray(want))
     assert np.asarray(got[0]).sum(1).tolist() == \
         [min(i + 1, c["topk"]) for i in range(t)]
@@ -431,489 +390,6 @@ def test_infer_rules_give_the_shapes_of_the_new_ops():
     with pytest.raises(registry.InferMismatch, match="do not fit a router"):
         get_infer_rule("moe_experts")(
             Op(num_routed=8, experts_held=4, expert_offset=6, top_k=2), {})
-
-
-# == window layers beside global ones, a sigmoid router with a balancing ==
-# == bias beside a shared expert and a leading dense layer: the program   ==
-# == against the reference of ``chipbench/configs/trinity_mini``          ==
-
-TRINITY = "configs/trinity_mini"
-T_BUILD = plugins.load(TRINITY, "build")
-T_REF = plugins.load(TRINITY, "reference")
-
-
-def trinity_sizes(**over):
-    sizes = json.load(open(os.path.join(ROOT, "chipbench", TRINITY,
-                                        "config.json")))
-    return {**sizes, **sizes["tiny"], **over}
-
-
-def windowed(q, k, v, window):
-    """Dense float32: key s counts for query t iff 0 <= t - s < window."""
-    g, t = q.shape[1] // k.shape[1], q.shape[2]
-    back = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, g, axis=1)) \
-        * q.shape[-1] ** -0.5
-    s = jnp.where((back >= 0) & (back < window), s, -jnp.inf)
-    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1),
-                      jnp.repeat(v, g, axis=1))
-
-
-@pytest.mark.parametrize("flash", ["xla", "pallas"])
-def test_window_program_equals_the_reference_and_moves_the_bias(
-        monkeypatch, flash):
-    """Loss, every gradient and every router's bias after the step through
-    ``fluid.Executor`` with ``optimizer.minimize``: published layers 1-5 (a
-    dense window layer, then window, global, window, window routed ones),
-    ``seq_len`` four windows; the bias is state without a gradient."""
-    monkeypatch.setenv("PADDLE_TPU_FLASH", "1" if flash == "pallas" else "0")
-    monkeypatch.setattr(psf, "BLOCK", 16)       # a band of 2 tiles of 4
-    sizes = trinity_sizes()
-    assert sizes["seq_len"] == 4 * sizes["sliding_window"]
-    assert sizes["num_experts"] < sizes["published"]["num_experts"]
-    built = T_BUILD.build(fluid, sizes)
-    main = fluid.default_main_program()
-    names = T_BUILD.trainable_names(main)
-    spec = T_REF.param_spec(sizes)
-    assert [n for n, _, _ in spec] == names
-    exe = fluid.Executor(fluid.TPUPlace())
-    exe.run(fluid.default_startup_program())
-    scope = fluid.global_scope()
-    weights = T_REF.init_params(5, sizes)
-    for (_, shape, _), name, w in zip(spec, names, weights):
-        assert tuple(np.shape(scope.get(name))) == tuple(shape), name
-        scope.set(name, jnp.array(w))
-    routers = [f"l{i}_route_bias" for i in range(1, 5)]
-    block = main.global_block()
-    for name in routers:
-        assert not np.any(np.asarray(scope.get(name)))
-        assert not block.has_var(name + "@GRAD") and name not in names
-    feed = T_BUILD.make_feed(sizes, 2, np.random.RandomState(3))
-    outs = exe.run(main, feed=feed, fetch_list=[built["loss"]]
-                   + [n + "@GRAD" for n in names])
-    ref_loss, ref_grads = T_REF.loss_and_grads(weights, feed, sizes)
-    assert float(outs[0].reshape(-1)[0]) == pytest.approx(float(ref_loss),
-                                                          rel=1e-5)
-    for name, g, r in zip(names, outs[1:], ref_grads):
-        g = np.asarray(g).reshape(r.shape)
-        assert np.abs(g - r).max() <= 2e-4 * np.abs(r).max() + 1e-7, name
-    # the rule moved every bias as the reference's does: by +-coeff, up for
-    # the experts that got fewer assignments than the mean
-    after = T_REF.biases_after_step(weights, feed, sizes)
-    for name, want in zip(routers, after):
-        got = np.asarray(scope.get(name))
-        np.testing.assert_allclose(got, want, atol=1e-9)
-        assert set(np.round(np.abs(got) / sizes["load_balance_coeff"])) \
-            <= {0.0, 1.0} and np.any(got > 0) and np.any(got < 0)
-    # which path each layer took: the window label on 4 layers, none on 1
-    calls = counters("ops.sparse_attention.calls")
-    per = 1 if flash == "pallas" else 2
-    assert calls == {
-        f'ops.sparse_attention.calls{{path="{flash}",seq="64",topk="0",'
-        f'window="16"}}': 4 * per,
-        f'ops.sparse_attention.calls{{path="{flash}",seq="64",'
-        f'topk="0"}}': per}
-    (key, n), = counters("ops.moe.calls").items()
-    assert 'score="sigmoid"' in key and 'routed="8"' in key and n == 2 * 4
-    assert counters("ops.moe.bias_updates") == {"ops.moe.bias_updates": 4}
-    assert not counters("ops.sparse_attention.declined")
-
-
-@pytest.mark.parametrize("stated", [-1, 1])
-def test_a_saved_program_that_states_a_wish_runs_as_one_that_does_not(
-        monkeypatch, stated):
-    """Programs saved before PR 47 carry ``"flash"`` (-1 from every model,
-    0 / 1 from a layer's argument) in the descs of their attention ops and
-    of the grad ops made from them.  The attribute loads and is not read:
-    same loss, same gradients, same path as the program without it."""
-    monkeypatch.delenv("PADDLE_TPU_FLASH", raising=False)
-    b, hq, hkv, t, d = 2, 4, 2, 32, 16
-    q = layers.data(name="q", shape=[hq, t, d], dtype="float32")
-    k = layers.data(name="k", shape=[hkv, t, d], dtype="float32")
-    q.stop_gradient = k.stop_gradient = False
-    mixed = layers.elementwise_add(
-        layers.sparse_attention(q, k, k, window=8),
-        layers.ring_attention(q, q, q, causal=True))
-    loss = layers.reduce_sum(layers.elementwise_mul(mixed, mixed))
-    fluid.backward.append_backward(loss)
-    main = fluid.default_main_program()
-    rng = np.random.RandomState(1)
-    feed = {"q": rng.randn(b, hq, t, d).astype("float32"),
-            "k": rng.randn(b, hkv, t, d).astype("float32")}
-    fetch = [loss.name, "q@GRAD", "k@GRAD"]
-    exe = fluid.Executor(fluid.TPUPlace())
-    want = exe.run(main, feed=feed, fetch_list=fetch)
-    first = counters("ops.sparse_attention.calls")
-
-    old = fluid.Program.parse_from_string(main.serialize_to_string())
-    touched = [op for op in old.global_block().ops
-               if op.type.split("_grad")[0] in ("sparse_attention",
-                                                "ring_attention")]
-    assert len(touched) == 4
-    for op in touched:
-        assert not op.has_attr("flash")
-        op._set_attr("flash", stated)
-    old = fluid.Program.parse_from_string(old.serialize_to_string())
-    assert all(op.attr("flash") == stated for op in old.global_block().ops
-               if op.type in ("sparse_attention", "ring_attention"))
-    got = exe.run(old, feed=feed, fetch_list=fetch)
-    for g, w in zip(got, want):
-        np.testing.assert_array_equal(g, w)
-    assert first and all('path="xla"' in key for key in first)
-    assert counters("ops.sparse_attention.calls") == {
-        key: 2 * n for key, n in first.items()}
-
-
-@pytest.mark.parametrize("window", [16, 24, 40, 64, 100])
-@pytest.mark.parametrize("flash", [False, True])
-def test_window_op_on_both_paths(monkeypatch, flash, window):
-    """window < T on and off the tile (16), and window >= T, which is the
-    global path: the op against dense float32, through the executor."""
-    monkeypatch.setattr(psf, "BLOCK", 16)
-    monkeypatch.setenv("PADDLE_TPU_FLASH", "1" if flash else "0")
-    b, hq, hkv, t, d = 2, 4, 2, 64, 16
-    rng = np.random.RandomState(window)
-    q = layers.data(name="q", shape=[hq, t, d], dtype="float32")
-    k = layers.data(name="k", shape=[hkv, t, d], dtype="float32")
-    v = layers.data(name="v", shape=[hkv, t, d], dtype="float32")
-    q.stop_gradient = k.stop_gradient = v.stop_gradient = False
-    out = layers.sparse_attention(q, k, v, window=window)
-    plain = layers.sparse_attention(q, k, v)
-    w = layers.assign(np.cos(np.arange(d, dtype="float32")))
-    loss = layers.reduce_sum(layers.elementwise_mul(out, w))
-    fluid.backward.append_backward(loss)
-    feed = {n: rng.randn(b, h, t, d).astype("float32")
-            for n, h in (("q", hq), ("k", hkv), ("v", hkv))}
-    got = fluid.Executor(fluid.TPUPlace()).run(
-        feed=feed, fetch_list=[out, plain, "q@GRAD", "k@GRAD", "v@GRAD"])
-    args = [jnp.asarray(feed[n]) for n in "qkv"]
-    np.testing.assert_allclose(got[0], windowed(*args, window), atol=2e-5)
-    if window >= t:
-        np.testing.assert_array_equal(got[0], got[1])
-    want = jax.grad(lambda *a: jnp.sum(windowed(*a, window)
-                                       * jnp.cos(jnp.arange(d))),
-                    (0, 1, 2))(*args)
-    for g, r in zip(got[2:], want):
-        np.testing.assert_allclose(g, r, atol=2e-4)
-    path = "pallas" if flash else "xla"
-    assert any(f'path="{path}"' in key and f'window="{window}"' in key
-               for key in counters("ops.sparse_attention.calls"))
-
-
-@pytest.mark.parametrize("window,block,tiles", [
-    (16, 16, 2), (17, 16, 2), (18, 16, 3), (5, 16, 2), (1, 16, 1),
-    (40, 16, 4), (50, 16, 4), (32, 64, 1)])
-def test_window_kernels_equal_blocked_attention_gradients_too(
-        monkeypatch, window, block, tiles):
-    """The three kernels, interpreted, 8 query heads over 2 key-value heads
-    of width 128, against the XLA path; the band is ``tiles`` wide."""
-    monkeypatch.setattr(psf, "BLOCK", block)
-    b, hq, hkv, t, d = 1, 8, 2, 64, 128
-    assert psf.band_tiles(window, block, t // block) == tiles
-    rng = np.random.RandomState(window)
-    q, k, v = (jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
-               for h in (hq, hkv, hkv))
-    w = jnp.cos(jnp.arange(d, dtype=jnp.float32))
-
-    def kernel(q, k, v):
-        return jnp.sum(psf.sparse_flash_attention(q, k, v, None, None, True,
-                                                  window) * w)
-
-    def blocked(q, k, v):
-        return jnp.sum(decoder_ops.blocked_attention(
-            q, k, v, None, d ** -0.5, block=block, window=window) * w)
-
-    np.testing.assert_allclose(
-        psf.sparse_flash_attention(q, k, v, None, None, True, window),
-        windowed(q, k, v, window), atol=2e-5)
-    for g, r in zip(jax.grad(kernel, (0, 1, 2))(q, k, v),
-                    jax.grad(blocked, (0, 1, 2))(q, k, v)):
-        np.testing.assert_allclose(g, r, atol=2e-4)
-
-
-def _against_blocked(q, k, v, sel, window, block):
-    """The three kernels, interpreted, against ``blocked_attention`` at the
-    flash tests' tolerances: output and dQ / dK / dV."""
-    d = q.shape[-1]
-    w = jnp.cos(jnp.arange(d, dtype=jnp.float32))
-
-    def kernel(q, k, v):
-        return jnp.sum(psf.sparse_flash_attention(q, k, v, sel, None, True,
-                                                  window) * w)
-
-    def blocked(q, k, v):
-        return jnp.sum(decoder_ops.blocked_attention(
-            q, k, v, sel, d ** -0.5, block=block, window=window) * w)
-
-    np.testing.assert_allclose(
-        psf.sparse_flash_attention(q, k, v, sel, None, True, window),
-        decoder_ops.blocked_attention(q, k, v, sel, d ** -0.5, block=block,
-                                      window=window), atol=2e-5)
-    for g, r in zip(jax.grad(kernel, (0, 1, 2))(q, k, v),
-                    jax.grad(blocked, (0, 1, 2))(q, k, v)):
-        np.testing.assert_allclose(g, r, atol=2e-4)
-
-
-@pytest.mark.parametrize("d,group,window,tiles", [
-    (64, 1, 0, (0, 10)), (64, 4, 32, (3, 6)), (128, 8, 40, (3, 7)),
-    (128, 4, 33, (3, 6)), (256, 8, 0, (0, 10)), (256, 1, 48, (5, 5)),
-    (192, 4, 0, (0, 10)), (192, 1, 32, (3, 6))])
-def test_interior_and_edge_tiles_equal_blocked_attention(
-        monkeypatch, d, group, window, tiles):
-    """Four tiles of 16 a row, so a row's tiles are interior (no positional
-    mask made), diagonal and, under a window, the band's far edge: a window
-    that is a multiple of the tile (32, 48) and one that is not (33, 40),
-    head widths 64 / 128 / 256 and one that is no multiple of a register's
-    lanes (192), groups of 1 / 4 / 8.  Without a window (and without a
-    selection) one body masks every live tile: none is interior."""
-    monkeypatch.setattr(psf, "BLOCK", 16)
-    t, hq = 64, 8
-    assert psf.tile_counts(t, window) == tiles
-    rng = np.random.RandomState(d + group + window)
-    q, k, v = (jnp.asarray(rng.randn(1, h, t, d), jnp.float32)
-               for h in (hq, hq // group, hq // group))
-    _against_blocked(q, k, v, None, window, 16)
-
-
-@pytest.mark.parametrize("case", ["no_key_of_an_interior_tile",
-                                  "no_key_of_the_first_tile", "not_causal"])
-def test_a_selection_cuts_interior_tiles_and_the_diagonal_stays_causal(
-        monkeypatch, case):
-    """An interior tile reads its mask from the selection alone; the
-    diagonal tile still ANDs the causal rule, whatever ``Sel`` holds: a
-    selection that is not causal gives causal-AND-selection, as
-    ``blocked_attention`` does."""
-    monkeypatch.setattr(psf, "BLOCK", 16)
-    b, hq, hkv, t, d = 1, 8, 2, 64, 128
-    rng = np.random.RandomState(11)
-    q, k, v = (jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
-               for h in (hq, hkv, hkv))
-    keep = (rng.rand(b, t, t) < 0.3) | np.eye(t, dtype=bool)
-    if case == "no_key_of_an_interior_tile":
-        keep[:, 40:, 16:32] = False
-    elif case == "no_key_of_the_first_tile":
-        keep[:, 40:, :16] = False
-    if case != "not_causal":
-        keep = np.tril(keep)
-    else:
-        assert np.triu(keep[0], 1).any()
-    sel = jnp.asarray(keep.astype(np.int8))
-    _against_blocked(q, k, v, sel, 0, 16)
-    np.testing.assert_allclose(
-        psf.sparse_flash_attention(q, k, v, sel, None, True),
-        dense(q, k, v, jnp.asarray(np.tril(keep).astype(np.int8))),
-        atol=2e-5)
-
-
-@pytest.mark.parametrize("t,window,selected,interior,edge", [
-    (64, 0, True, 6, 4),        # n (n - 1) / 2 and n at n = 4
-    (64, 0, False, 0, 10),      # one compare to drop: one body, all edge
-    (192, 64, False, 30, 20),   # trinity_mini's band in small: 12 tiles, 5 wide
-    (192, 0, True, 66, 12),
-    (64, 40, False, 3, 7),      # a ragged window: two far tiles a row are edge
-    (16, 0, True, 0, 1)])       # a single tile: the diagonal one
-def test_every_kernel_call_lowered_counts_its_tiles_by_kind(
-        monkeypatch, t, window, selected, interior, edge):
-    """``ops.sparse_attention.tiles{kernel,kind}``: the live tiles one head
-    walks in each of the three calls lowered, interior (a body of their own
-    with no positional mask: only under a selection or a window) and edge."""
-    monkeypatch.setattr(psf, "BLOCK", 16)
-    q = jnp.ones((1, 4, t, 128), jnp.bfloat16)
-    k = jnp.ones((1, 2, t, 128), jnp.bfloat16)
-    sel = jnp.ones((1, t, t), jnp.int8) if selected else None
-    jax.jit(jax.grad(lambda q: psf.sparse_flash_attention(
-        q, k, k, sel, None, True, window).astype(jnp.float32).sum())
-        ).lower(q)
-    family = "window_flash" if window else "sparse_flash"
-    assert counters("ops.sparse_attention.tiles") == {
-        f'ops.sparse_attention.tiles{{kernel="{family}_{kernel}",'
-        f'kind="{kind}"}}': count
-        for kernel in ("fwd", "dq", "dkv")
-        for kind, count in (("interior", interior), ("edge", edge))}
-    n = t // 16
-    if selected:
-        assert (interior, edge) == (n * (n - 1) // 2, n)
-    assert interior + edge == sum(
-        min(j + 1, psf.band_tiles(window, 16, n) if window else n)
-        for j in range(n))
-
-
-def test_a_window_lowers_no_t_by_t_operand(monkeypatch):
-    """The window is a static band: the lowered calls hold its table
-    [tiles, band] and nothing [.., T, T]; a selection beside a window is
-    declined to the XLA path, which masks both."""
-    monkeypatch.setattr(psf, "BLOCK", 64)
-    b, hq, hkv, t, d = 1, 4, 2, 256, 128
-    q = jnp.ones((b, hq, t, d), jnp.bfloat16)
-    k = jnp.ones((b, hkv, t, d), jnp.bfloat16)
-    text = jax.jit(lambda q, k: jax.grad(
-        lambda q: psf.sparse_flash_attention(q, k, k, None, None, True, 32)
-        .astype(jnp.float32).sum())(q)).lower(q, k).as_text()
-    assert f"{t}x{t}x" not in text and "tensor<4x2xi32>" in text
-    assert psf.supported(q, k, jnp.ones((b, t, t), jnp.int8), 32) \
-        == "window_selection"
-    assert psf.supported(q, k, None, 32) == ""
-
-
-def reference_router(case):
-    """A cell's reference's router as ``routed(x, wr, bias, w1, w3, w2,
-    top_k, scale, offset)``, whatever its own argument list."""
-    ref, eps = {"trinity": (T_REF, None), "lfm2": (L_REF, 1e-6),
-                "instella": (I_REF, 1e-20), "mellum2": (M_REF, 0.0)}[case]
-    if eps is None:         # the norm's epsilon is a constant of the file
-        return ref, ref.routed
-    if case == "mellum2":   # a softmax router: no bias, scale or counts
-        return ref, lambda x, wr, bias, w1, w3, w2, k, scale, off=0: (
-            ref.routed(x, wr, w1, w3, w2, k, off), None)
-    return ref, lambda x, wr, bias, w1, w3, w2, k, scale, off=0: ref.routed(
-        x, wr, bias, w1, w3, w2, k, scale, eps, off)
-
-
-@pytest.mark.parametrize("case,routed,held,k,scale,eps,shared", [
-    ("trinity", 128, 8, 8, 2.826, 1e-20, 8),
-    ("lfm2", 32, 8, 4, 1.0, 1e-6, 0),
-    ("instella", 64, 8, 6, 2.5, 1e-20, 16),
-    ("mellum2", 64, 8, 8, 1.0, 0.0, 0)])
-def test_the_shares_and_the_shared_expert_once_add_up_to_the_layer(
-        case, routed, held, k, scale, eps, shared):
-    """A sigmoid router under a selection bias, ``k`` per token over all
-    ``routed``, ``held`` by each of ``routed / held`` chips (Trinity: 16
-    shares of 128 with top-8; LFM2: 4 shares of 32 with top-4 and no shared
-    expert; Instella: 8 shares of 64 with top-6, scale 2.5 and two shared
-    experts as one of twice the width; Mellum2: 8 shares of 64 with top-8,
-    experts 8c..8c+7 on chip c, under a SOFTMAX router without a bias and
-    no shared expert): the shares, and the shared expert counted ONCE, add
-    up to what the cell's reference gives for the uncut layer; every share
-    reports the same assignments, over all ``routed``."""
-    rng = np.random.RandomState(1)
-    x, wr, w1, w3, w2 = moe_weights(rng, 48, 16, 8, routed)
-    softmax = case == "mellum2"
-    bias = None if softmax \
-        else jnp.asarray(0.1 * rng.randn(routed), jnp.float32)
-    ref, ref_routed = reference_router(case)
-    whole, n_whole = ref_routed(x, wr, bias, w1, w3, w2, k, scale)
-    if softmax:             # the reference counts nothing: the op's own
-        n_whole = moe.routed_experts(x, wr, w1, w3, w2, top_k=k,
-                                     with_counts=True)[1]
-    assert int(n_whole.sum()) == 48 * k
-    total = 0.0
-    if shared:
-        s1, s3, s2 = (jnp.asarray(0.3 * rng.randn(*s), jnp.float32)
-                      for s in ((16, shared), (16, shared), (shared, 16)))
-        total = ref.feed_forward(x, s1, s3, s2)
-        whole = whole + total
-    for off in range(0, routed, held):
-        part, n = moe.routed_experts(
-            x, wr, w1[off:off + held], w3[off:off + held],
-            w2[off:off + held], top_k=k, expert_offset=off,
-            score="softmax" if softmax else "sigmoid", bias=bias,
-            norm_eps=eps, scale=scale, with_counts=True)
-        mine, _ = ref_routed(x, wr, bias, w1[off:off + held],
-                             w3[off:off + held], w2[off:off + held], k,
-                             scale, off)
-        np.testing.assert_allclose(part, mine, atol=1e-5)
-        np.testing.assert_array_equal(n, n_whole)
-        total = total + part
-    np.testing.assert_allclose(total, whole, atol=3e-5)
-    assert float(jnp.abs(whole).max()) > 0.1
-
-
-def test_the_bias_chooses_and_does_not_weigh():
-    """A large bias on one expert puts it into every token's choice; the
-    weights stay those of the scores alone, renormalized and scaled, and
-    the bias gets no gradient."""
-    rng = np.random.RandomState(7)
-    x = jnp.asarray(rng.randn(40, 16), jnp.float32)
-    wr = jnp.asarray(rng.randn(16, 12), jnp.float32)
-    none, idx0 = moe.route_top_k(x, wr, 3, True, "sigmoid", jnp.zeros(12),
-                                 1e-20, 2.0)
-    bias = jnp.zeros(12).at[5].set(10.0)
-    vals, idx = moe.route_top_k(x, wr, 3, True, "sigmoid", bias, 1e-20, 2.0)
-    assert bool(jnp.all(jnp.any(idx == 5, -1)))
-    assert not bool(jnp.all(jnp.any(idx0 == 5, -1)))
-    scores = jax.nn.sigmoid(jnp.matmul(x, wr, precision="highest"))
-    chosen = jnp.take_along_axis(scores, idx, -1)
-    np.testing.assert_allclose(
-        vals, 2.0 * chosen / chosen.sum(-1, keepdims=True), rtol=1e-6)
-    np.testing.assert_allclose(vals.sum(-1), 2.0, rtol=1e-6)
-    # the choice without a bias is the top of the scores themselves
-    np.testing.assert_array_equal(idx0, jax.lax.top_k(scores, 3)[1])
-    grad = jax.grad(lambda b: moe.route_top_k(
-        x, wr, 3, True, "sigmoid", b, 1e-20, 2.0)[0][:, 0].sum())(bias)
-    assert not np.any(np.asarray(grad))
-    with pytest.raises(ValueError, match="neither 'softmax' nor 'sigmoid'"):
-        moe.route_top_k(x, wr, 3, score="tanh")
-
-
-def test_the_balancing_rule_moves_toward_the_mean():
-    counts = jnp.asarray([0, 4, 8, 4], jnp.int32)
-    bias = jnp.asarray([0.5, 0.0, 0.0, -0.25], jnp.float32)
-    np.testing.assert_allclose(
-        moe.balance_bias(bias, counts, 0.001),
-        [0.501, 0.0, -0.001, -0.25], atol=1e-9)
-    np.testing.assert_array_equal(
-        moe.assignment_counts(jnp.asarray([[0, 2], [2, 3]]), 5),
-        [1, 0, 2, 1, 0])
-
-
-def test_infer_rules_of_the_window_and_the_bias():
-    from paddle_tpu.ops.registry import get_infer_rule
-
-    class Op:
-        def __init__(self, **attrs):
-            self.attrs, self.inputs, self.type = attrs, {}, "t"
-
-        def attr(self, name, default=None):
-            return self.attrs.get(name, default)
-
-    x = ((2, 16, 32), "float32")
-    ins = {"X": [x], "RouterW": [((32, 8), "float32")],
-           "W1": [((4, 32, 8), "float32")], "Bias": [((8,), "float32")]}
-    op = Op(num_routed=8, experts_held=4, expert_offset=4, top_k=2,
-            score="sigmoid")
-    assert get_infer_rule("moe_experts")(op, ins) == {
-        "Out": [x], "Counts": [((8,), "int32")]}
-    with pytest.raises(registry.InferMismatch, match="one per routed"):
-        get_infer_rule("moe_experts")(op, {**ins,
-                                           "Bias": [((4,), "float32")]})
-    with pytest.raises(registry.InferMismatch, match="neither 'softmax'"):
-        get_infer_rule("moe_experts")(
-            Op(num_routed=8, experts_held=4, top_k=2, score="tanh"), {})
-    assert get_infer_rule("moe_bias_update")(
-        Op(coeff=0.001), {"Bias": [((8,), "float32")],
-                          "Counts": [((8,), "int32")]}) == {
-        "BiasOut": [((8,), "float32")]}
-    with pytest.raises(registry.InferMismatch, match="one float and one"):
-        get_infer_rule("moe_bias_update")(
-            Op(coeff=0.001), {"Bias": [((8,), "float32")],
-                              "Counts": [((8,), "float32")]})
-    q = ((1, 4, 16, 8), "float32")
-    with pytest.raises(registry.InferMismatch, match="is negative"):
-        get_infer_rule("sparse_attention")(
-            Op(window=-1), {"Q": [q], "K": [q], "V": [q]})
-
-
-def test_layer_kinds_follow_the_published_index():
-    from paddle_tpu.models import decoder_lm
-
-    cfg = T_BUILD.config_of(trinity_sizes())
-    assert [bool(cfg.layer_window(i)) for i in range(5)] == [
-        True, True, False, True, True]
-    assert [cfg.layer_is_dense(i) for i in range(5)] == [
-        True, False, False, False, False]
-    whole = decoder_lm.Config(
-        128, 64, 8, 4, 2, 16, 32, 8, 4, 2, window=16, global_every=4,
-        dense_layers=2, dense_width=96)
-    assert [whole.layer_window(i) for i in range(8)] == [
-        16, 16, 16, 0, 16, 16, 16, 0]
-    assert [whole.layer_is_dense(i) for i in range(8)] == [True] * 2 \
-        + [False] * 6
-    with pytest.raises(ValueError, match="no layer kind is defined"):
-        decoder_lm.Config(128, 64, 2, 4, 2, 16, 32, 8, 4, 2, window=16,
-                          index_topk=8)
-    with pytest.raises(ValueError, match="needs dense_width"):
-        decoder_lm.Config(128, 64, 2, 4, 2, 16, 32, 8, 4, 2,
-                          dense_layers=1)
 
 
 # -- the expert layer's backward: rows go back through the kept plan ------
@@ -981,8 +457,8 @@ def test_gradients_of_the_share_equal_a_per_expert_loop(score, off, held,
             kw.get("norm_eps", 0.0), kw.get("scale", 1.0)))
 
     args = (x, wr, w1, w3, w2)
-    got = jax.value_and_grad(program, range(5))(*args)
-    want = jax.value_and_grad(plain, range(5))(*args)
+    got = jax.jit(jax.value_and_grad(program, range(5)))(*args)
+    want = jax.jit(jax.value_and_grad(plain, range(5)))(*args)
     assert got[1][0].dtype == jnp.float64
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-12)
     for name, g, r in zip("x router w1 w3 w2".split(), got[1], want[1]):
@@ -1064,10 +540,10 @@ def test_the_handwritten_backward_equals_autodiff_of_the_plain_share(
     if path == "ragged_dot":
         monkeypatch.setattr(pallas_grouped, "supported", lambda *a: "off")
     assert moe.product_path(args[0], args[2], args[4], kw["top_k"]) == path
-    got = jax.value_and_grad(lambda *a: jnp.sum(
-        mix * moe.routed_experts(*a, **kw)), range(5))(*args)
-    want = jax.value_and_grad(lambda *a: jnp.sum(
-        mix * plain_share(*a, **kw)), range(5))(*args)
+    got = jax.jit(jax.value_and_grad(lambda *a: jnp.sum(
+        mix * moe.routed_experts(*a, **kw)), range(5)))(*args)
+    want = jax.jit(jax.value_and_grad(lambda *a: jnp.sum(
+        mix * plain_share(*a, **kw)), range(5)))(*args)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
     for name, g, r in zip("x router w1 w3 w2".split(), got[1], want[1]):
         assert g.dtype == r.dtype == jnp.float32
@@ -1099,12 +575,16 @@ def test_the_gates_cotangent_is_dy_against_the_last_product(monkeypatch,
         monkeypatch.setattr(moe, "route_top_k", lambda *a, **k: (vals, idx))
         return jnp.sum(dy * moe.routed_experts(x, wr, w1, w3, w2, **kw))
 
-    got = jax.grad(weighted)(vals)
+    got = jax.jit(jax.grad(weighted))(vals)
     off = kw["expert_offset"]
-    ys = jnp.stack([(jax.nn.silu(x @ w1[j]) * (x @ w3[j])) @ w2[j]
-                    for j in range(w1.shape[0])], 1)          # [N, held, D]
-    dots = jnp.einsum("nd,njd->nj", dy, ys,
-                      precision=jax.lax.Precision.HIGHEST)
+
+    def outright(x, w1, w3, w2):
+        ys = jnp.stack([(jax.nn.silu(x @ w1[j]) * (x @ w3[j])) @ w2[j]
+                        for j in range(w1.shape[0])], 1)      # [N, held, D]
+        return jnp.einsum("nd,njd->nj", dy, ys,
+                          precision=jax.lax.Precision.HIGHEST)
+
+    dots = jax.jit(outright)(x, w1, w3, w2)
     held = (idx >= off) & (idx < off + w1.shape[0])
     want = jnp.where(held, jnp.take_along_axis(
         dots, jnp.clip(idx - off, 0, w1.shape[0] - 1), 1), 0.0)
@@ -1178,10 +658,10 @@ def test_the_chosen_scores_cotangent_lands_where_lax_top_k_puts_it():
     np.testing.assert_array_equal(vals, own_vals)
     tied = np.asarray(idx[:, 0] == 0)
     assert tied.any() and np.all(np.asarray(idx[:, 1])[tied] == 1)
-    got = jax.grad(lambda w: jnp.sum(mix * moe.route_top_k(
-        x, w, 2, False, "sigmoid")[0]))(wr)
-    want = jax.grad(lambda w: jnp.sum(mix * jax.lax.top_k(
-        scores_of(w), 2)[0]))(wr)
+    got = jax.jit(jax.grad(lambda w: jnp.sum(mix * moe.route_top_k(
+        x, w, 2, False, "sigmoid")[0])))(wr)
+    want = jax.jit(jax.grad(lambda w: jnp.sum(mix * jax.lax.top_k(
+        scores_of(w), 2)[0])))(wr)
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
     # a sigmoid's score is its own column's: columns 2 and 5 tie with 0
     # and 1 in every row and are never the first two
@@ -1453,15 +933,7 @@ def test_the_training_step_scatters_no_row_under_the_expert_layer():
 # == 64, a tied head, a 32-way sigmoid router with top-4 and a bias: the  ==
 # == program against the reference of ``chipbench/configs/lfm2_8b_a1b``   ==
 
-LFM2 = "configs/lfm2_8b_a1b"
-L_BUILD = plugins.load(LFM2, "build")
-L_REF = plugins.load(LFM2, "reference")
-
-
-def lfm2_sizes(**over):
-    sizes = json.load(open(os.path.join(ROOT, "chipbench", LFM2,
-                                        "config.json")))
-    return {**sizes, **sizes["tiny"], **over}
+L_BUILD, L_REF, lfm2_sizes = decoder_reference.load("lfm2_8b_a1b")
 
 
 def three_shift_sum(x, w):
@@ -1486,11 +958,8 @@ def test_conv_program_equals_the_reference_tied_head_and_bias(
     monkeypatch.setattr(psf, "BLOCK", 16)
     sizes = lfm2_sizes()
     assert sizes["num_experts"] < sizes["published"]["num_experts"]
-    built = L_BUILD.build(fluid, sizes)
-    main = fluid.default_main_program()
-    names = L_BUILD.trainable_names(main)
-    spec = L_REF.param_spec(sizes)
-    assert [n for n, _, _ in spec] == names
+    built, names, weights = seeded_program(L_BUILD, L_REF, sizes)
+    main, scope = fluid.default_main_program(), fluid.global_scope()
     # a conv layer has no attention parameter, the attention layer no
     # filter; the head is no parameter of its own
     assert names.count("tok_emb") == 1 and "lm_head_w" not in names
@@ -1499,13 +968,6 @@ def test_conv_program_equals_the_reference_tied_head_and_bias(
         attn = {"attn_norm", "q_w", "q_norm", "k_w", "k_norm", "v_w", "o_w"}
         conv = {"conv_norm", "conv_in_w", "conv_w", "conv_out_w"}
         assert (mine & (attn | conv)) == (conv if kind == "conv" else attn)
-    exe = fluid.Executor(fluid.TPUPlace())
-    exe.run(fluid.default_startup_program())
-    scope = fluid.global_scope()
-    weights = L_REF.init_params(5, sizes)
-    for (_, shape, _), name, w in zip(spec, names, weights):
-        assert tuple(np.shape(scope.get(name))) == tuple(shape), name
-        scope.set(name, jnp.array(w))
     routers = [f"l{i}_route_bias" for i in range(1, 5)]
     block = main.global_block()
     for name in routers:
@@ -1514,9 +976,10 @@ def test_conv_program_equals_the_reference_tied_head_and_bias(
     assert sum(1 for op in block.ops if op.type == "adam"
                and "tok_emb" in op.inputs["Param"]) == 1
     feed = L_BUILD.make_feed(sizes, 2, np.random.RandomState(3))
-    outs = exe.run(main, feed=feed, fetch_list=[built["loss"]]
-                   + [n + "@GRAD" for n in names])
-    ref_loss, ref_grads = L_REF.loss_and_grads(weights, feed, sizes)
+    outs = fluid.Executor(fluid.TPUPlace()).run(
+        main, feed=feed, fetch_list=[built["loss"]]
+        + [n + "@GRAD" for n in names])
+    ref_loss, ref_grads, after = reference_step(L_REF, sizes, weights, feed)
     assert float(outs[0].reshape(-1)[0]) == pytest.approx(float(ref_loss),
                                                           rel=1e-5)
     for name, g, r in zip(names, outs[1:], ref_grads):
@@ -1537,8 +1000,8 @@ def test_conv_program_equals_the_reference_tied_head_and_bias(
         return total / 2
 
     with jax.default_matmul_precision("highest"):
-        rows, dense_part = jax.grad(told_apart, (0, 1))(weights[0],
-                                                         weights[0])
+        rows, dense_part = jax.jit(jax.grad(told_apart, (0, 1)))(
+            weights[0], weights[0])
     untouched = np.setdiff1d(np.arange(sizes["vocab_size"]),
                              np.unique(feed["tokens"]))
     assert untouched.size and not np.any(np.asarray(rows)[untouched])
@@ -1550,10 +1013,9 @@ def test_conv_program_equals_the_reference_tied_head_and_bias(
         > 0.1 * np.abs(both).max()
 
     # one Adam update of the embedding, from the summed gradient
-    want = L_REF.optimizer_step(weights[0], ref_grads[0], sizes)
+    want = compiled(L_REF, "optimizer_step", sizes)(weights[0], ref_grads[0])
     np.testing.assert_allclose(np.asarray(scope.get("tok_emb")), want,
                                atol=2e-6)
-    after = L_REF.biases_after_step(weights, feed, sizes)
     rate = sizes["assumed"]["bias_update_rate"]
     for name, want in zip(routers, after):
         got = np.asarray(scope.get(name))
@@ -1597,9 +1059,10 @@ def test_short_conv_op_equals_the_three_shift_sum_gradients_too(t):
     feed = {"x": rng.randn(b, t, 3 * c).astype("float32")}
     got = exe.run(feed=feed, fetch_list=[out, "x@GRAD", "filter@GRAD"])
     xs = jnp.asarray(feed["x"])
-    np.testing.assert_allclose(got[0], three_shift_sum(xs, filt), atol=1e-6)
-    want = jax.grad(lambda x, f: jnp.sum(
-        three_shift_sum(x, f) * jnp.cos(jnp.arange(c))), (0, 1))(xs, filt)
+    np.testing.assert_allclose(got[0], jax.jit(three_shift_sum)(xs, filt),
+                               atol=1e-6)
+    want = jax.jit(jax.grad(lambda x, f: jnp.sum(
+        three_shift_sum(x, f) * jnp.cos(jnp.arange(c))), (0, 1)))(xs, filt)
     np.testing.assert_allclose(got[1], want[0], atol=1e-5)
     np.testing.assert_allclose(got[2], want[1], atol=1e-5)
     # the backward is the op's own and is not counted as a call
@@ -1698,13 +1161,15 @@ def test_grouped_query_flash_at_head_width_64_group_of_four(monkeypatch,
         return jnp.sum(decoder_ops.blocked_attention(
             q, k, v, None, d ** -0.5, block=block) * w)
 
-    out = psf.sparse_flash_attention(q, k, v, None, None, True)
-    np.testing.assert_allclose(out, dense(q, k, v, None), atol=2e-5)
+    out = jax.jit(lambda *a: psf.sparse_flash_attention(
+        *a, None, None, True))(q, k, v)
     np.testing.assert_allclose(
-        out, decoder_ops.blocked_attention(q, k, v, None, d ** -0.5,
-                                           block=block), atol=2e-5)
-    for g, r in zip(jax.grad(kernel, (0, 1, 2))(q, k, v),
-                    jax.grad(blocked, (0, 1, 2))(q, k, v)):
+        out, jax.jit(lambda *a: dense(*a, None))(q, k, v), atol=2e-5)
+    np.testing.assert_allclose(
+        out, jax.jit(lambda *a: decoder_ops.blocked_attention(
+            *a, None, d ** -0.5, block=block))(q, k, v), atol=2e-5)
+    for g, r in zip(jax.jit(jax.grad(kernel, (0, 1, 2)))(q, k, v),
+                    jax.jit(jax.grad(blocked, (0, 1, 2)))(q, k, v)):
         np.testing.assert_allclose(g, r, atol=2e-4)
 
 
@@ -1764,10 +1229,3 @@ def test_infer_rule_of_the_short_convolution():
     report = analysis.verify_program(fluid.default_main_program(),
                                      fetch_list=[out])
     assert not report.errors, report.format()
-
-
-# the references that ``reference_router`` names and whose sections are in
-# tests/test_decoder_lm_mixers.py (split off so that no one file holds half
-# of tier-1's time: xdist hands out whole files)
-I_REF = plugins.load("configs/instella_moe_16b_a3b", "reference")
-M_REF = plugins.load("configs/mellum2_12b_a2_5b", "reference")

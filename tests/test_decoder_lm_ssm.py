@@ -1,13 +1,10 @@
 """The decoder path's state-space mixer, layers of one sub-block and experts
 of two matrices (PR 58) against the plain float32 reference of
 ``chipbench/configs/nemotron_twotower_30b_a3b``, at tiny sizes on the CPU.
-A file of its own, beside ``tests/test_decoder_lm.py`` and
-``tests/test_decoder_lm_mixers.py``, so that the three run on three
-workers; ``counters`` and ``seeded_program`` are those files', imported."""
-
-import json
-import os
-import sys
+A file of its own beside ``tests/test_decoder_lm.py`` and
+``tests/test_decoder_lm_mixers.py`` (no file of ``tests/`` is more than
+300 s of one worker: docs/COVERAGE.md); what the three share is
+``tests/decoder_reference.py``'s."""
 
 import jax
 import jax.numpy as jnp
@@ -19,12 +16,9 @@ from paddle_tpu.fluid import layers
 from paddle_tpu.ops import pallas_sparse_flash as psf
 from paddle_tpu.parallel import moe
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
-
-from chipbench import plugins  # noqa: E402
-from test_decoder_lm import counters  # noqa: E402
-from test_decoder_lm_mixers import seeded_program  # noqa: E402
+import decoder_reference
+from decoder_reference import (compiled, counters, reference_step,
+                               seeded_program)
 
 
 # == three state-space mixers, three routed layers of two-matrix experts  ==
@@ -32,15 +26,8 @@ from test_decoder_lm_mixers import seeded_program  # noqa: E402
 # == published layer ONE sub-block: the program against the reference of  ==
 # == ``chipbench/configs/nemotron_twotower_30b_a3b``                      ==
 
-NEMOTRON = "configs/nemotron_twotower_30b_a3b"
-N_BUILD = plugins.load(NEMOTRON, "build")
-N_REF = plugins.load(NEMOTRON, "reference")
-
-
-def nemotron_sizes(**over):
-    sizes = json.load(open(os.path.join(ROOT, "chipbench", NEMOTRON,
-                                        "config.json")))
-    return {**sizes, **sizes["tiny"], **over}
+N_BUILD, N_REF, nemotron_sizes = decoder_reference.load(
+    "nemotron_twotower_30b_a3b")
 
 
 def test_ssm_program_equals_the_reference_adam_step_and_bias(monkeypatch):
@@ -79,7 +66,7 @@ def test_ssm_program_equals_the_reference_adam_step_and_bias(monkeypatch):
     exe = fluid.Executor(fluid.TPUPlace())
     outs = exe.run(main, feed=feed, fetch_list=[built["loss"]]
                    + [n + "@GRAD" for n in names])
-    ref_loss, ref_grads = N_REF.loss_and_grads(weights, feed, sizes)
+    ref_loss, ref_grads, after = reference_step(N_REF, sizes, weights, feed)
     assert float(outs[0].reshape(-1)[0]) == pytest.approx(float(ref_loss),
                                                           rel=1e-5)
     for name, g, r in zip(names, outs[1:], ref_grads):
@@ -89,13 +76,13 @@ def test_ssm_program_equals_the_reference_adam_step_and_bias(monkeypatch):
     # one Adam step of every parameter, from the program's own gradient
     # (an out-projection's, at a fifth of the other matrices' scale, has
     # entries near Adam's epsilon, where the step follows the last bit)
+    adam = compiled(N_REF, "optimizer_step", sizes)
     for name, w, g in zip(names, weights, outs[1:]):
         np.testing.assert_allclose(
             np.asarray(scope.get(name)).reshape(w.shape),
-            N_REF.optimizer_step(w, jnp.asarray(g).reshape(w.shape), sizes),
-            atol=2e-6, err_msg=name)
-    for name, want in zip(routers, N_REF.biases_after_step(weights, feed,
-                                                           sizes)):
+            adam(w, jnp.asarray(g).reshape(w.shape)), atol=2e-6,
+            err_msg=name)
+    for name, want in zip(routers, after):
         np.testing.assert_allclose(np.asarray(scope.get(name)), want,
                                    atol=1e-7, err_msg=name)
     assert counters("models.decoder.blocks") == {
@@ -275,8 +262,8 @@ def test_two_matrix_experts_and_their_backward_by_hand(slabs):
                                             0.0, 2)[0])
 
     with jax.default_matmul_precision("highest"):
-        got = jax.value_and_grad(mine, range(4))(x, wr, w1, w2)
-        want = jax.value_and_grad(dense, range(4))(x, wr, w1, w2)
+        got = jax.jit(jax.value_and_grad(mine, range(4)))(x, wr, w1, w2)
+        want = jax.jit(jax.value_and_grad(dense, range(4)))(x, wr, w1, w2)
     assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
     for name, g, w in zip(("x", "router", "w1", "w2"), got[1], want[1]):
         np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5, err_msg=name)
@@ -316,8 +303,9 @@ def test_the_filters_bias_and_the_grouped_norm_through_a_program():
 
     got = exe.run(feed={"x": xv}, fetch_list=[
         z, "x@GRAD", "f_w@GRAD", "f_b@GRAD", "g_w@GRAD"])
-    np.testing.assert_allclose(got[0], plain(xv, w, fb, g), atol=2e-6)
-    want = jax.grad(lambda *a: jnp.sum(plain(*a) ** 2), range(4))(
+    np.testing.assert_allclose(got[0], jax.jit(plain)(xv, w, fb, g),
+                               atol=2e-6)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(plain(*a) ** 2), range(4)))(
         jnp.asarray(xv), w, fb, g)
     for name, m, r in zip("x w b g".split(), got[1:], want):
         np.testing.assert_allclose(m, r, rtol=1e-4, atol=1e-5, err_msg=name)
@@ -370,8 +358,8 @@ def test_an_expert_width_off_the_lane_rows_takes_the_kernels_filled_up(
 
     args = (x, wr, w1, w2, w3)
     with jax.default_matmul_precision("highest"):
-        got = jax.value_and_grad(mine, range(5))(*args)
-        want = jax.value_and_grad(dense, range(5))(*args)
+        got = jax.jit(jax.value_and_grad(mine, range(5)))(*args)
+        want = jax.jit(jax.value_and_grad(dense, range(5)))(*args)
     assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
     for name, g, w in zip(("x", "router", "w1", "w2", "w3"), got[1],
                           want[1]):

@@ -4,8 +4,9 @@
 against ``delta_rule._channel_rule`` (their twin), against the rule token by
 token, against the scalar rule's kernels where the decay is constant along
 the key, and through the op and its grad op.  A file of its own beside
-``test_delta_rule.py`` (whose helpers it uses) so that the two run on two
-workers: a case interprets and compiles for ten to forty seconds."""
+``test_delta_rule_kernels.py``: together the two are more than the 300 s of
+one worker that a file of ``tests/`` may take (docs/COVERAGE.md); a case
+interprets and compiles for ten to thirty seconds."""
 
 import re
 
@@ -17,9 +18,9 @@ import pytest
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
 from paddle_tpu.ops import delta_rule
-from test_delta_rule import (  # noqa: F401  (exact_products: autouse)
+from delta_rule_reference import (  # noqa: F401  (exact_products: autouse)
     both_paths, build_rule, channel_operands, channel_recurrence,
-    exact_products, operands, rel, weighted_sum)
+    cotangents, exact_products, operands, rel)
 
 
 
@@ -97,8 +98,8 @@ def test_the_channel_kernels_equal_the_recurrence_and_all_five_cotangents(
             q, k = delta_rule.l2norm(q, eps), delta_rule.l2norm(k, eps)
         return channel_recurrence(q, k, *rest, 128 ** -0.5)
 
-    want = stated(*exact)
-    wants = jax.grad(weighted_sum(stated), range(5))(*exact)
+    want = jax.jit(stated)(*exact)
+    wants = cotangents(stated)(*exact)
     got, grads = runs["pallas"]
     near = 0.03 if low else 1e-3 if decay > 1 else 5e-5
     assert rel(got, want) < near
@@ -123,10 +124,14 @@ def test_a_decay_constant_along_the_key_is_the_scalar_kernels(monkeypatch,
     for xs, kernel in (((q, k, v, g, beta), "delta_rule_fwd"),
                        ((q, k, v, spread, beta), "delta_channel_fwd")):
         assert kernel in str(jax.make_jaxpr(rule)(*xs))
-    np.testing.assert_allclose(rule(q, k, v, spread, beta),
-                               rule(q, k, v, g, beta), atol=5e-6)
-    want = jax.grad(weighted_sum(rule), range(5))(q, k, v, g, beta)
-    got = jax.grad(weighted_sum(rule), range(5))(q, k, v, spread, beta)
+    def out_and_grads(*a):      # one program an operand set
+        out, vjp = jax.vjp(rule, *a)
+        weights = jnp.cos(jnp.arange(out.size, dtype=jnp.float32))
+        return out, vjp(weights.reshape(out.shape))
+
+    out, want = jax.jit(out_and_grads)(q, k, v, g, beta)
+    spread_out, got = jax.jit(out_and_grads)(q, k, v, spread, beta)
+    np.testing.assert_allclose(spread_out, out, atol=5e-6)
     got = got[:3] + (jnp.sum(got[3], -1), got[4])
     for name, a, w in zip("q k v g beta".split(), got, want):
         assert rel(a, w) < 2e-5, name
@@ -177,7 +182,8 @@ def test_the_op_and_its_grad_op_take_the_channel_kernels_and_count_them(
     def forward(*a):
         return delta_rule.chunked(*a, chunk=64, norm_eps=1e-6)
 
-    assert rel(got[0], forward(*xs)) < 2e-5
-    want = jax.grad(lambda *a: jnp.sum(forward(*a) * weights), range(5))(*xs)
+    assert rel(got[0], jax.jit(forward)(*xs)) < 2e-5
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(forward(*a) * weights),
+                            range(5)))(*xs)
     for name, g, w in zip(names, got[1:], want):
         assert rel(g, w) < 2e-5, name

@@ -5,7 +5,6 @@ token-by-token recurrence, value and every cotangent, in float32 on the
 CPU."""
 
 import contextlib
-import re
 
 import jax
 import jax.numpy as jnp
@@ -16,60 +15,9 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
 from paddle_tpu.ops import decoder_ops, delta_rule, registry
 
-
-def recurrence(q, k, v, g, beta, scale, norm_eps=0.0):
-    """The rule as it is stated: one token after another, one [dk, dv]
-    state a value head.  q, k: [B, T, Hk, dk]; v: [B, T, Hv, dv]; g, beta:
-    [B, T, Hv]."""
-    b, t, hk, dk = q.shape
-    hv, dv = v.shape[2:]
-    if norm_eps:
-        q = q / jnp.sqrt(jnp.sum(q * q, -1, keepdims=True) + norm_eps)
-        k = k / jnp.sqrt(jnp.sum(k * k, -1, keepdims=True) + norm_eps)
-    q = jnp.repeat(q, hv // hk, 2) * scale
-    k = jnp.repeat(k, hv // hk, 2)
-
-    def token(state, x):
-        q_t, k_t, v_t, g_t, b_t = x
-        state = jnp.exp(g_t)[..., None, None] * state
-        u = b_t[..., None] * (v_t - jnp.einsum("bhkv,bhk->bhv", state, k_t))
-        state = state + jnp.einsum("bhk,bhv->bhkv", k_t, u)
-        return state, jnp.einsum("bhkv,bhk->bhv", state, q_t)
-
-    xs = tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta))
-    _, out = jax.lax.scan(token, jnp.zeros((b, hv, dk, dv), jnp.float32), xs)
-    return jnp.moveaxis(out, 0, 1)
-
-
-def operands(t, seed=0, b=2, hk=2, hv=4, dk=8, dv=12, decay=0.5):
-    rng = np.random.RandomState(seed)
-    k = rng.randn(b, t, hk, dk)
-    return tuple(jnp.asarray(a, jnp.float32) for a in (
-        rng.randn(b, t, hk, dk), k / np.linalg.norm(k, axis=-1,
-                                                    keepdims=True),
-        rng.randn(b, t, hv, dv), -decay * rng.rand(b, t, hv),
-        rng.rand(b, t, hv)))
-
-
-def weighted_sum(fn):
-    """A scalar of ``fn``'s output that weighs every element differently."""
-    def loss(*xs):
-        out = fn(*xs)
-        return jnp.sum(out * jnp.cos(jnp.arange(out.size, dtype=jnp.float32)
-                                     ).reshape(out.shape))
-    return loss
-
-
-@pytest.fixture(autouse=True)
-def exact_products():
-    with jax.default_matmul_precision("highest"):
-        yield
-
-
-def rel(got, want):
-    """The distance of two arrays as a share of the second's norm."""
-    got, want = (np.asarray(a, np.float64) for a in (got, want))
-    return float(np.linalg.norm(got - want) / (np.linalg.norm(want) + 1e-30))
+from delta_rule_reference import (  # noqa: F401  (exact_products: autouse)
+    build_rule, channel_operands, channel_recurrence, cotangents, eqns_of,
+    exact_products, operands, recurrence, rel, weighted_sum)
 
 
 #: (tokens, chunk, batch, key heads, value heads, AMP type or None, decay)
@@ -115,13 +63,15 @@ def test_chunked_equals_the_recurrence_value_and_all_five_cotangents(case):
     def rule(*a):
         return delta_rule.chunked(*a, chunk=chunk).astype(jnp.float32)
 
-    want = recurrence(*exact, scale)
-    wants = jax.grad(weighted_sum(lambda *a: recurrence(*a, scale)),
-                     range(5))(*exact)
+    def stated(*a):
+        return recurrence(*a, scale)
+
+    want = jax.jit(stated)(*exact)
+    wants = cotangents(stated)(*exact)
     with fluid.amp.amp_guard(low, keep_activations=True) if low \
             else contextlib.nullcontext():
-        got = delta_rule.chunked(*xs, chunk=chunk)
-        grads = jax.grad(weighted_sum(rule), range(5))(*xs)
+        got = jax.jit(lambda *a: delta_rule.chunked(*a, chunk=chunk))(*xs)
+        grads = cotangents(rule)(*xs)
     assert got.shape == want.shape == xs[2].shape
     assert got.dtype == xs[2].dtype
     assert [g.dtype for g in grads] == [a.dtype for a in xs]
@@ -139,16 +89,6 @@ def test_chunked_equals_the_recurrence_value_and_all_five_cotangents(case):
     for name, g, w in zip("q k v g beta".split(), grads, wants):
         np.testing.assert_allclose(g, w, atol=near * float(
             jnp.abs(w).max()) + 1e-6, err_msg=name)
-
-
-def eqns_of(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs inside it."""
-    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
-        yield eqn
-        for value in eqn.params.values():
-            for sub in value if isinstance(value, (list, tuple)) else [value]:
-                if hasattr(getattr(sub, "jaxpr", sub), "eqns"):
-                    yield from eqns_of(sub)
 
 
 def products(jaxpr):
@@ -215,10 +155,15 @@ def test_the_inverses_cotangent_in_closed_form_is_autodiffs():
     a = jnp.asarray(np.tril(rng.randn(3, 64, 64) * 0.2, -1), jnp.float32)
     x = jnp.asarray(rng.randn(3, 64, 24), jnp.float32)
     dz = jnp.asarray(rng.randn(3, 64, 24), jnp.float32)
-    z, vjp = jax.vjp(lambda a, x: delta_rule.unit_lower_inverse(a) @ x, a, x)
-    want_a, want_x = vjp(dz)
-    inv = delta_rule.unit_lower_inverse(a)
-    got_x, got_a = delta_rule.solve_cotangents(inv, z, dz)
+
+    def through_the_levels(a, x, dz):
+        z, vjp = jax.vjp(
+            lambda a, x: delta_rule.unit_lower_inverse(a) @ x, a, x)
+        return z, vjp(dz)
+
+    z, (want_a, want_x) = jax.jit(through_the_levels)(a, x, dz)
+    inv = jax.jit(delta_rule.unit_lower_inverse)(a)
+    got_x, got_a = jax.jit(delta_rule.solve_cotangents)(inv, z, dz)
     np.testing.assert_allclose(got_x, want_x,
                                atol=2e-5 * float(jnp.abs(want_x).max()))
     want_a = np.tril(np.asarray(want_a), -1)
@@ -242,14 +187,18 @@ def test_a_decay_that_underflows_inside_a_chunk_gives_zeros_not_nans():
     g = g * 400.0
     assert float(jnp.cumsum(g, 1).min()) < -1000
     scale = q.shape[-1] ** -0.5
-    want = recurrence(q, k, v, g, beta, scale)
-    got = delta_rule.chunked(q, k, v, g, beta, chunk=16)
+
+    def rule(*a):
+        return delta_rule.chunked(*a, chunk=16)
+
+    def stated(*a):
+        return recurrence(*a, scale)
+
+    want = jax.jit(stated)(q, k, v, g, beta)
+    got = jax.jit(rule)(q, k, v, g, beta)
     np.testing.assert_allclose(got, want, atol=2e-5)
-    grads = jax.grad(weighted_sum(
-        lambda *a: delta_rule.chunked(*a, chunk=16)), range(5))(
-            q, k, v, g, beta)
-    wants = jax.grad(weighted_sum(lambda *a: recurrence(*a, scale)),
-                     range(5))(q, k, v, g, beta)
+    grads = cotangents(rule)(q, k, v, g, beta)
+    wants = cotangents(stated)(q, k, v, g, beta)
     for got_g, want_g in zip(grads, wants):
         assert bool(jnp.isfinite(got_g).all())
         np.testing.assert_allclose(got_g, want_g, atol=2e-5 * float(
@@ -259,9 +208,10 @@ def test_a_decay_that_underflows_inside_a_chunk_gives_zeros_not_nans():
 def test_the_l2_norm_and_a_stated_scale_are_the_recurrences():
     q, k, v, g, beta = operands(40, seed=2)
     k = k * 3.0
-    want = recurrence(q, k, v, g, beta, 0.25, norm_eps=1e-6)
-    got = delta_rule.chunked(q, k, v, g, beta, chunk=16, scale=0.25,
-                             norm_eps=1e-6)
+    want = jax.jit(lambda *a: recurrence(*a, 0.25, norm_eps=1e-6))(
+        q, k, v, g, beta)
+    got = jax.jit(lambda *a: delta_rule.chunked(
+        *a, chunk=16, scale=0.25, norm_eps=1e-6))(q, k, v, g, beta)
     np.testing.assert_allclose(got, want, atol=5e-6)
 
 
@@ -269,7 +219,7 @@ def test_the_l2_norm_and_a_stated_scale_are_the_recurrences():
 def test_unit_lower_inverse_is_the_inverse(c):
     rng = np.random.RandomState(c)
     a = jnp.asarray(np.tril(rng.randn(3, c, c), -1), jnp.float32)
-    inv = delta_rule.unit_lower_inverse(a)
+    inv = jax.jit(delta_rule.unit_lower_inverse)(a)
     want = np.linalg.inv(np.eye(c) + np.asarray(a, np.float64))
     np.testing.assert_allclose(inv, want, atol=1e-4 * np.abs(want).max())
     assert not np.any(np.triu(np.asarray(inv), 1))
@@ -281,8 +231,9 @@ def test_nothing_leaks_from_the_future_or_across_sequences():
     moves outputs in its own chunk and in the chunks after it: the state
     carries it on."""
     q, k, v, g, beta = operands(53, seed=3)
-    base = delta_rule.chunked(q, k, v, g, beta, chunk=16)
-    moved = delta_rule.chunked(q, k, v.at[0, 21].add(1.0), g, beta, chunk=16)
+    rule = jax.jit(lambda *a: delta_rule.chunked(*a, chunk=16))
+    base = rule(q, k, v, g, beta)
+    moved = rule(q, k, v.at[0, 21].add(1.0), g, beta)
     np.testing.assert_array_equal(moved[1], base[1])
     np.testing.assert_array_equal(moved[0, :21], base[0, :21])
     changed = np.any(np.asarray(moved[0] != base[0]), (1, 2))
@@ -293,29 +244,22 @@ def test_low_precision_inputs_give_their_own_type_and_stay_close():
     """bf16 q, k, v under AMP: the output is bf16, gates' cotangents stay
     float32, and the values are the float32 ones to bf16's rounding."""
     q, k, v, g, beta = operands(48, seed=4)
-    want = delta_rule.chunked(q, k, v, g, beta, chunk=16, norm_eps=1e-6)
+
+    def rule(*a):
+        return delta_rule.chunked(*a, chunk=16, norm_eps=1e-6)
+
+    def rule_and_cotangents(*a):
+        got, vjp = jax.vjp(rule, *a)
+        return got, vjp(jnp.ones_like(got))
+
+    want = jax.jit(rule)(q, k, v, g, beta)
     low = [a.astype(jnp.bfloat16) for a in (q, k, v)]
     with fluid.amp.amp_guard("bfloat16", keep_activations=True):
-        got, vjp = jax.vjp(lambda *a: delta_rule.chunked(
-            *a, chunk=16, norm_eps=1e-6), *low, g, beta)
-        grads = vjp(jnp.ones_like(got))
+        got, grads = jax.jit(rule_and_cotangents)(*low, g, beta)
     assert got.dtype == jnp.bfloat16
     assert [x.dtype for x in grads] == [jnp.bfloat16] * 3 + [jnp.float32] * 2
     np.testing.assert_allclose(got.astype(jnp.float32), want, atol=0.06,
                                rtol=0.05)
-
-
-def build_rule(t, hk=2, hv=4, dk=8, dv=12, chunk=16, channel=False,
-               **attrs):
-    names = ("q", "k", "v", "g", "beta")
-    shapes = ([t, hk, dk], [t, hk, dk], [t, hv, dv],
-              [t, hv, dk] if channel else [t, hv], [t, hv])
-    data = [layers.data(name=n, shape=s, dtype="float32")
-            for n, s in zip(names, shapes)]
-    for d in data:
-        d.stop_gradient = False
-    out = layers.gated_delta_rule(*data, chunk=chunk, **attrs)
-    return names, data, out
 
 
 @pytest.mark.parametrize("t", [5, 53])
@@ -338,8 +282,9 @@ def test_the_grad_op_equals_jax_grad_of_the_forward(t):
     def forward(*a):
         return delta_rule.chunked(*a, chunk=16, norm_eps=1e-6)
 
-    np.testing.assert_allclose(got[0], forward(*xs), atol=1e-6)
-    want = jax.grad(lambda *a: jnp.sum(forward(*a) * weights), range(5))(*xs)
+    np.testing.assert_allclose(got[0], jax.jit(forward)(*xs), atol=1e-6)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(forward(*a) * weights),
+                            range(5)))(*xs)
     for name, g, w in zip(names, got[1:], want):
         np.testing.assert_allclose(g, w, atol=1e-5, err_msg=name)
     assert {k: v for k, v in fluid.profiler.counters().items()
@@ -390,30 +335,6 @@ def test_infer_rule_and_layer_of_the_delta_rule():
 
 # -- a decay that is a vector along the key ---------------------------------
 
-def channel_recurrence(q, k, v, g, beta, scale):
-    """The rule token by token with ``g`` [B, T, H, dk]: the state's ROWS
-    decay, each key channel by its own number.  q, k: [B, T, H, dk]."""
-    b, t, h, dk = q.shape
-    dv = v.shape[-1]
-
-    def token(state, x):
-        q_t, k_t, v_t, g_t, b_t = x
-        state = jnp.exp(g_t)[..., None] * state
-        u = b_t[..., None] * (v_t - jnp.einsum("bhkv,bhk->bhv", state, k_t))
-        state = state + jnp.einsum("bhk,bhv->bhkv", k_t, u)
-        return state, jnp.einsum("bhkv,bhk->bhv", state, q_t * scale)
-
-    xs = tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta))
-    _, out = jax.lax.scan(token, jnp.zeros((b, h, dk, dv), jnp.float32), xs)
-    return jnp.moveaxis(out, 0, 1)
-
-
-def channel_operands(t, decay, seed=0, b=2, h=3, dk=8, dv=12):
-    q, k, v, _, beta = operands(t, seed=seed, b=b, hk=h, hv=h, dk=dk, dv=dv)
-    g = -decay * np.random.RandomState(seed + 1).rand(b, t, h, dk)
-    return q, k, v, jnp.asarray(g, jnp.float32), beta
-
-
 #: (tokens, chunk, decay): g down to -decay a token and channel
 CHANNEL_CASES = {
     "whole_64_by_16": (64, 16, 0.5),
@@ -446,8 +367,8 @@ def test_channel_decay_equals_the_recurrence_and_all_five_cotangents(case):
         return channel_recurrence(*a, 0.3)
 
     want, got = jax.jit(stated)(*xs), jax.jit(rule)(*xs)
-    wants = jax.jit(jax.grad(weighted_sum(stated), range(5)))(*xs)
-    grads = jax.jit(jax.grad(weighted_sum(rule), range(5)))(*xs)
+    wants = cotangents(stated)(*xs)
+    grads = cotangents(rule)(*xs)
     assert got.shape == want.shape and got.dtype == xs[2].dtype
     assert [g.shape for g in grads] == [a.shape for a in xs]
     for g in (got,) + grads:
@@ -476,10 +397,8 @@ def test_a_channel_decay_constant_along_the_key_is_the_scalar_path(hk, hv):
 
     np.testing.assert_allclose(jax.jit(channel)(q, k, v, spread, beta),
                                jax.jit(scalar)(q, k, v, g, beta), atol=5e-6)
-    want = jax.jit(jax.grad(weighted_sum(scalar), range(5)))(
-        q, k, v, g, beta)
-    got = jax.jit(jax.grad(weighted_sum(channel), range(5)))(
-        q, k, v, spread, beta)
+    want = cotangents(scalar)(q, k, v, g, beta)
+    got = cotangents(channel)(q, k, v, spread, beta)
     got = got[:3] + (jnp.sum(got[3], -1), got[4])
     for name, a, w in zip("q k v g beta".split(), got, want):
         np.testing.assert_allclose(a, w, atol=2e-5 * float(
@@ -542,8 +461,9 @@ def test_the_op_and_its_grad_op_under_a_channel_decay(t):
     got = fluid.Executor(fluid.TPUPlace()).run(
         feed={n: np.asarray(x) for n, x in zip(names, xs)},
         fetch_list=[out] + [n + "@GRAD" for n in names])
-    np.testing.assert_allclose(got[0], channel_recurrence(*xs, 0.3),
-                               atol=1e-5)
+    np.testing.assert_allclose(
+        got[0], jax.jit(lambda *a: channel_recurrence(*a, 0.3))(*xs),
+        atol=1e-5)
     want = jax.jit(jax.grad(
         lambda *a: jnp.sum(channel_recurrence(*a, 0.3) * weights),
         range(5)))(*xs)
@@ -597,13 +517,19 @@ def test_the_scores_cotangents_alone_are_autodiffs(case):
         assert float(gsum.min()) < -89      # exp(-G) is inf in float32
     dms = tuple(jnp.asarray(rng.randn(2, 3, c, c), jnp.float32)
                 for _ in range(2))
-    scores, vjp = jax.vjp(
-        lambda x0, x1, k, g: delta_rule._pair_scores(low, (x0, x1), k, g),
-        k, q, k, gsum)
+
+    def autodiffs(x0, x1, k, g, dms):
+        scores, vjp = jax.vjp(
+            lambda x0, x1, k, g: delta_rule._pair_scores(
+                low, (x0, x1), k, g), x0, x1, k, g)
+        return scores, vjp(list(dms))
+
+    scores, (want_x0, want_x1, want_k, want_g) = jax.jit(autodiffs)(
+        k, q, k, gsum, dms)
     assert not np.any(np.triu(np.asarray(scores[0]), 1))
-    want_x0, want_x1, want_k, want_g = vjp(list(dms))
-    (got_x0, got_x1), got_k, got_g = delta_rule._pair_scores_bwd(
-        low, (k, q), k, gsum, dms)
+    (got_x0, got_x1), got_k, got_g = jax.jit(
+        lambda xs, k, g, dms: delta_rule._pair_scores_bwd(
+            low, xs, k, g, dms))((k, q), k, gsum, dms)
     near = 2e-2 if low else 2e-5
     for name, got, want in (("x0", got_x0, want_x0), ("x1", got_x1, want_x1),
                             ("k", got_k, want_k), ("gsum", got_g, want_g)):
@@ -629,11 +555,12 @@ def test_channel_decay_under_amp_stays_close_to_the_recurrence(t, chunk):
     def stated(*a):
         return channel_recurrence(*a, 0.3)
 
-    want = stated(*exact)
-    wants = jax.grad(weighted_sum(stated), range(5))(*exact)
+    want = jax.jit(stated)(*exact)
+    wants = cotangents(stated)(*exact)
     with fluid.amp.amp_guard("bfloat16", keep_activations=True):
-        got = delta_rule.chunked(*xs, chunk=chunk, scale=0.3)
-        grads = jax.grad(weighted_sum(rule), range(5))(*xs)
+        got = jax.jit(lambda *a: delta_rule.chunked(
+            *a, chunk=chunk, scale=0.3))(*xs)
+        grads = cotangents(rule)(*xs)
     assert got.dtype == jnp.bfloat16
     assert [g.dtype for g in grads] == [a.dtype for a in xs]
     assert rel(got, want) < 0.02
@@ -698,9 +625,11 @@ def test_ungated_short_conv_is_the_filter_and_silu_gradients_too(t):
     feed = {"x": rng.randn(b, t, c).astype("float32")}
     got = exe.run(feed=feed, fetch_list=[out, "x@GRAD", "filter@GRAD"])
     xs = jnp.asarray(feed["x"])
-    np.testing.assert_allclose(got[0], filter_then_silu(xs, filt), atol=1e-6)
-    want = jax.grad(lambda x, f: jnp.sum(filter_then_silu(x, f) * weights),
-                    (0, 1))(xs, filt)
+    np.testing.assert_allclose(got[0], jax.jit(filter_then_silu)(xs, filt),
+                               atol=1e-6)
+    want = jax.jit(jax.grad(
+        lambda x, f: jnp.sum(filter_then_silu(x, f) * weights),
+        (0, 1)))(xs, filt)
     np.testing.assert_allclose(got[1], want[0], atol=1e-5)
     np.testing.assert_allclose(got[2], want[1], atol=1e-5)
     assert {k: v for k, v in fluid.profiler.counters().items()
@@ -746,251 +675,3 @@ def test_infer_rule_of_the_ungated_short_convolution():
     # the gated form's rule is what it was
     assert rule(Op(), {"X": [x], "Filter": [((32, 3), "float32")]}) == {
         "Out": [((2, 16, 32), "bfloat16")]}
-
-
-# -- the scalar rule's Pallas kernels (ops/pallas_delta_rule.py), interpreted
-
-#: (tokens, batch, key heads, value heads, AMP type or None, decay, norm_eps)
-KERNEL_CASES = {
-    # eight whole chunks, two grid steps of four: the carried state and dS
-    # cross the chunks of a step and the steps
-    "two_value_heads_whole_512": (512, 1, 1, 2, None, 0.5, 1e-6),
-    # 500 = 7 x 64 + 52: the padded tail writes and decays nothing
-    "two_value_heads_ragged_500": (500, 1, 1, 2, None, 0.5, 0.0),
-    # one value head a key head: a pair is two neighbouring key heads;
-    # three chunks are padded to a grid step's four
-    "one_value_head_ragged_150": (150, 1, 2, 2, None, 0.5, 1e-6),
-    # g down to -200 a token: exp(G_i - G_j) underflows within a few tokens
-    "underflow_two_value_heads": (512, 1, 1, 2, None, 200.0, 1e-6),
-    "underflow_one_value_head": (150, 1, 2, 2, None, 200.0, 0.0),
-    # bf16 q, k, v under AMP: every contraction but the inverse's in bf16;
-    # two pairs of value heads, two rows
-    "bf16_two_value_heads": (200, 2, 2, 4, "bfloat16", 0.5, 1e-6),
-    "bf16_one_value_head": (150, 1, 2, 2, "bfloat16", 0.5, 0.0),
-}
-_KERNEL_RUNS = {}
-
-
-def both_paths(xs, low, eps):
-    """{path: (out, five cotangents)} of ``xs`` through ``chunked`` with
-    the flash gate closed ('xla': the twin) and open ('pallas': the
-    kernels, interpreted on this CPU)."""
-    import os
-
-    from paddle_tpu.ops import kernel_choice
-
-    runs, name = {}, kernel_choice.SWITCHES["flash"]
-    before = os.environ.get(name)
-    try:
-        for path, flag in (("xla", "0"), ("pallas", "1")):
-            os.environ[name] = flag
-
-            def rule(*a):       # a path its own: jax keeps a trace
-                return delta_rule.chunked(*a, chunk=64, norm_eps=eps)
-
-            with fluid.amp.amp_guard(low, keep_activations=True) \
-                    if low else contextlib.nullcontext():
-                jaxpr = jax.make_jaxpr(rule)(*xs)
-                assert any(e.primitive.name == "pallas_call"
-                           for e in eqns_of(jaxpr)) is (path == "pallas")
-
-                def loss(*a):       # the value beside it: one forward
-                    out = rule(*a)
-                    return weighted_sum(
-                        lambda: out.astype(jnp.float32))(), out
-
-                (_, out), grads = jax.value_and_grad(
-                    loss, range(5), has_aux=True)(*xs)
-                runs[path] = (out, grads)
-    finally:
-        os.environ.pop(name) if before is None \
-            else os.environ.__setitem__(name, before)
-    return runs
-
-
-def kernel_runs(case):
-    """(operands, ``both_paths``) of a case, made once a case."""
-    if case not in _KERNEL_RUNS:
-        t, b, hk, hv, low, decay, eps = KERNEL_CASES[case]
-        xs = operands(t, seed=t, b=b, hk=hk, hv=hv, dk=128, dv=128,
-                      decay=decay)
-        if low:
-            xs = tuple(a.astype(low) for a in xs[:3]) + xs[3:]
-        _KERNEL_RUNS[case] = xs, both_paths(xs, low, eps)
-    return _KERNEL_RUNS[case]
-
-
-@pytest.mark.parametrize("case", KERNEL_CASES)
-def test_the_kernels_equal_the_xla_rule_value_and_all_five_cotangents(case):
-    """The three kernels against ``_rule``, their twin and oracle: the
-    same operands, types and shapes out, nothing but finite numbers (a
-    decay that underflows inside a chunk gives zeros), and the distance
-    float32's reordering of sums (under AMP, bf16's rounding of products
-    whose operands differ in their last float32 bits)."""
-    xs, runs = kernel_runs(case)
-    (want, wants), (got, grads) = runs["xla"], runs["pallas"]
-    low = KERNEL_CASES[case][4]
-    assert got.shape == want.shape and got.dtype == want.dtype == xs[2].dtype
-    assert [g.dtype for g in grads] == [a.dtype for a in xs]
-    for g in (got,) + grads:
-        assert bool(jnp.isfinite(g).all())
-    near = 0.02 if low else 2e-5
-    assert rel(got, want) < near
-    for name, g, w in zip("q k v g beta".split(), grads, wants):
-        assert g.shape == w.shape, name
-        assert rel(g, w) < near, name
-
-
-@pytest.mark.parametrize("case", KERNEL_CASES)
-def test_the_kernels_equal_the_recurrence_value_and_all_five_cotangents(case):
-    """And against what ``_rule`` itself is held to: the rule token by
-    token and ``jax.grad`` of it, which has no chunk, no inverse and no
-    padded tail."""
-    xs, runs = kernel_runs(case)
-    t, _, _, _, low, decay, eps = KERNEL_CASES[case]
-    exact = tuple(a.astype(jnp.float32) for a in xs)
-    scale = 128 ** -0.5
-    want = recurrence(*exact, scale, eps)
-    wants = jax.grad(weighted_sum(lambda *a: recurrence(*a, scale, eps)),
-                     range(5))(*exact)
-    got, grads = runs["pallas"]
-    # a float32 running sum near -6,000 (64 tokens of g near -100) is exact
-    # to 5e-4, and so is every exp(G_i - G_j) made from it
-    near = 0.03 if low else 1e-3 if decay > 1 else 5e-5
-    assert rel(got, want) < near
-    for name, g, w in zip("q k v g beta".split(), grads, wants):
-        assert rel(g, w) < near, name
-
-
-#: what ``supported`` refuses: the reason, then (key heads, value heads,
-#: dk, dv, chunk, channel decay)
-REFUSALS = {
-    "chunk": (1, 2, 128, 128, 32, False),
-    "width": (1, 2, 64, 128, 64, False),
-    "value_width": (1, 2, 128, 8, 64, False),
-    "heads": (1, 4, 128, 128, 64, False),
-    "odd_heads": (1, 1, 128, 128, 64, False),
-    # a decay a key channel: its kernels' own reasons
-    "channel_chunk": (2, 2, 128, 128, 32, True),
-    "channel_width": (2, 2, 64, 128, 64, True),
-    "channel_wide_keys": (2, 2, 256, 128, 64, True),
-    "channel_heads": (1, 2, 128, 128, 64, True),
-    "channel_odd_heads": (3, 3, 128, 128, 64, True),
-}
-
-
-@pytest.mark.parametrize("case", REFUSALS)
-def test_what_the_kernels_refuse_is_counted_and_the_xla_rule_runs(
-        monkeypatch, case):
-    """With the gate open where a kernel would be compiled, operands the
-    kernels do not take reach ``ops.delta_rule.declined{why}``, the op and
-    its grad op are counted on the XLA path, no ``pallas_call`` is lowered,
-    and the result is the XLA path's with the gate closed."""
-    from paddle_tpu.ops import kernel_choice, pallas_delta_rule
-
-    hk, hv, dk, dv, chunk, channel = REFUSALS[case]
-    why = case.removeprefix("channel_")
-    why = {"value_width": "width", "wide_keys": "width",
-           "odd_heads": "heads"}.get(why, why)
-    t = 40
-    monkeypatch.setenv(kernel_choice.SWITCHES["flash"], "1")
-    monkeypatch.setattr(kernel_choice, "interpret",
-                        lambda stated=None: False)
-    xs = operands(t, seed=1, b=1, hk=hk, hv=hv, dk=dk, dv=dv)
-    if channel:
-        xs = xs[:3] + (jnp.repeat(xs[3][..., None], dk, -1),) + xs[4:]
-    assert pallas_delta_rule.supported(*xs[:4], chunk) == why
-    names, data, out = build_rule(t, hk=hk, hv=hv, dk=dk, dv=dv,
-                                  chunk=chunk, channel=channel)
-    loss = layers.reduce_sum(out)
-    fluid.backward.append_backward(loss)
-    exe = fluid.Executor(fluid.TPUPlace())
-    feed = {n: np.asarray(x) for n, x in zip(names, xs)}
-    text = exe.lower_step(fluid.default_main_program(), feed,
-                          [out, "q@GRAD"]).as_text(debug_info=True)
-    assert "pallas_call" not in text
-    got, _ = exe.run(feed=feed, fetch_list=[out, "q@GRAD"])
-    monkeypatch.setenv(kernel_choice.SWITCHES["flash"], "0")
-    np.testing.assert_allclose(
-        got, delta_rule.chunked(*xs, chunk=chunk), atol=1e-6)
-    counted = {k: v for k, v in fluid.profiler.counters().items()
-               if k.startswith("ops.delta_rule.")
-               and not k.startswith("ops.delta_rule.channel_calls")}
-    assert counted.pop(f'ops.delta_rule.declined{{why="{why}"}}') >= 1
-    assert all('path="xla"' in k or 'path="by_hand"' in k
-               for k in counted), counted
-    assert any(k.startswith("ops.delta_rule.grad_calls") for k in counted)
-
-
-def test_a_refusal_where_the_kernels_are_interpreted_is_not_counted(
-        monkeypatch):
-    """Off the TPU an open gate interprets the kernels, for tests and the
-    benchmark's rehearsals: a rule too small for them (chunks of 16, heads
-    of 8, as the cells' ``tiny`` sizes are) runs the XLA path and says so
-    in ``calls{path}``, and no ``declined`` is counted."""
-    from paddle_tpu.ops import kernel_choice, pallas_delta_rule
-
-    monkeypatch.setenv(kernel_choice.SWITCHES["flash"], "1")
-    assert kernel_choice.interpret()
-    t = 40
-    xs = operands(t, seed=2, b=1, hk=2, hv=4, dk=8, dv=8)
-    assert pallas_delta_rule.supported(*xs[:4], 16) == "chunk"
-    names, _, out = build_rule(t, hk=2, hv=4, dk=8, dv=8, chunk=16)
-    fluid.backward.append_backward(layers.reduce_sum(out))
-    exe = fluid.Executor(fluid.TPUPlace())
-    exe.run(feed={n: np.asarray(x) for n, x in zip(names, xs)},
-            fetch_list=[out, "q@GRAD"])
-    counted = {k for k in fluid.profiler.counters()
-               if k.startswith("ops.delta_rule.")}
-    assert counted == {
-        'ops.delta_rule.calls{chunk="16",dim="8",key_heads="2",path="xla",'
-        'value_heads="4"}',
-        'ops.delta_rule.grad_calls{chunk="16",path="by_hand"}'}
-
-
-def test_the_op_and_its_grad_op_take_the_kernels_and_count_them(monkeypatch):
-    """Through the executor with the gate open: the op lowers
-    ``delta_rule_fwd``, its grad op ``delta_rule_states`` and
-    ``delta_rule_bwd`` and not the forward again, each under the op's own
-    name scope; both are counted ``path="pallas"``, nothing is declined,
-    and the five gradients are the XLA path's."""
-    from paddle_tpu.ops import kernel_choice
-
-    monkeypatch.setenv(kernel_choice.SWITCHES["flash"], "1")
-    t = 70
-    names, _, out = build_rule(t, hk=1, hv=2, dk=128, dv=128, chunk=64,
-                               norm_eps=1e-6)
-    weights = np.cos(np.arange(128, dtype="float32"))
-    loss = layers.reduce_sum(layers.elementwise_mul(
-        out, layers.assign(weights)))
-    fluid.backward.append_backward(loss)
-    exe = fluid.Executor(fluid.TPUPlace())
-    xs = operands(t, seed=t, b=1, hk=1, hv=2, dk=128, dv=128)
-    feed = {n: np.asarray(x) for n, x in zip(names, xs)}
-    fetch = [out] + [n + "@GRAD" for n in names]
-    text = exe.lower_step(fluid.default_main_program(), feed,
-                          fetch).as_text(debug_info=True)
-    for kernel, op in (("delta_rule_fwd", "gated_delta_rule"),
-                       ("delta_rule_states", "gated_delta_rule_grad"),
-                       ("delta_rule_bwd", "gated_delta_rule_grad")):
-        assert f'"jit(fn)/{op}/' in text
-        assert re.search(rf'"jit\(fn\)/{op}/[^"]*{kernel}\)?/pallas_call"',
-                         text), kernel
-    assert not re.search(
-        r'"jit\(fn\)/gated_delta_rule_grad/[^"]*delta_rule_fwd/', text)
-    got = exe.run(feed=feed, fetch_list=fetch)
-    # once for the text above, once for the run
-    assert {k: v for k, v in fluid.profiler.counters().items()
-            if k.startswith("ops.delta_rule")} == {
-        'ops.delta_rule.calls{chunk="64",dim="128",key_heads="1",'
-        'path="pallas",value_heads="2"}': 2,
-        'ops.delta_rule.grad_calls{chunk="64",path="pallas"}': 2}
-    monkeypatch.setenv(kernel_choice.SWITCHES["flash"], "0")
-
-    def forward(*a):
-        return delta_rule.chunked(*a, chunk=64, norm_eps=1e-6)
-
-    assert rel(got[0], forward(*xs)) < 2e-5
-    want = jax.grad(lambda *a: jnp.sum(forward(*a) * weights), range(5))(*xs)
-    for name, g, w in zip(names, got[1:], want):
-        assert rel(g, w) < 2e-5, name

@@ -35,17 +35,17 @@ def test_gpipe_matches_sequential_fwd_and_grad():
         return pl.gpipe(pl.mlp_stage_fn("relu"), params, x, mesh,
                         "pp", m)
 
-    ref = pl.sequential_stack(w, b, x, "relu")
-    out = piped(w, b, x)
+    ref = jax.jit(lambda *a: pl.sequential_stack(*a, "relu"))(w, b, x)
+    out = jax.jit(piped)(w, b, x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 
     # gradients flow back through the scan/ppermute schedule
-    g_pipe = jax.grad(lambda w, b, x: (piped(w, b, x) ** 2).sum(),
-                      argnums=(0, 1))(w, b, x)
-    g_ref = jax.grad(
+    g_pipe = jax.jit(jax.grad(lambda w, b, x: (piped(w, b, x) ** 2).sum(),
+                              argnums=(0, 1)))(w, b, x)
+    g_ref = jax.jit(jax.grad(
         lambda w, b, x: (pl.sequential_stack(w, b, x, "relu") ** 2).sum(),
-        argnums=(0, 1))(w, b, x)
+        argnums=(0, 1)))(w, b, x)
     for gp, gr in zip(g_pipe, g_ref):
         np.testing.assert_allclose(np.asarray(gp), np.asarray(gr),
                                    rtol=1e-4, atol=1e-4)

@@ -85,6 +85,15 @@ def test_declared_env_keys_pass(tmp_path):
     assert findings == [], findings
 
 
+def test_seeded_bare_reference_flagged(tmp_path):
+    call = "REF.loss_and_" + "grads(weights, feed, sizes)\n"  # not flagged
+    (tmp_path / "test_new_decoder.py").write_text("loss, grads = " + call)
+    (tmp_path / "decoder_reference.py").write_text("return " + call)
+    assert [(k, p, l) for k, p, l, _ in
+            repo_lint.check_bare_references(str(tmp_path))] == [
+        ("bare-reference", "tests/test_new_decoder.py", 1)]
+
+
 def test_env_md_matches_generator():
     from paddle_tpu.fluid import envcontract
 

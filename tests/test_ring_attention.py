@@ -33,11 +33,10 @@ def test_ring_matches_full_forward():
     q, k, v = _qkv(rng)
     mesh = _sp_mesh()
     for causal in (False, True):
-        full = np.asarray(ra.full_attention(jnp.asarray(q), jnp.asarray(k),
-                                            jnp.asarray(v), causal))
-        ring = np.asarray(ra.ring_attention(jnp.asarray(q), jnp.asarray(k),
-                                            jnp.asarray(v), mesh,
-                                            causal=causal))
+        full = np.asarray(jax.jit(
+            lambda *a: ra.full_attention(*a, causal))(q, k, v))
+        ring = np.asarray(jax.jit(
+            lambda *a: ra.ring_attention(*a, mesh, causal=causal))(q, k, v))
         np.testing.assert_allclose(ring, full, rtol=2e-5, atol=2e-5,
                                    err_msg=f"causal={causal}")
 
@@ -53,10 +52,8 @@ def test_ring_matches_full_gradients():
     def loss_ring(q, k, v):
         return jnp.sum(ra.ring_attention(q, k, v, mesh, causal=True) ** 2)
 
-    gf = jax.grad(loss_full, argnums=(0, 1, 2))(
-        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v))
-    gr = jax.grad(loss_ring, argnums=(0, 1, 2))(
-        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v))
+    gf = jax.jit(jax.grad(loss_full, argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
     for a, b, n in zip(gf, gr, "qkv"):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                    rtol=5e-4, atol=5e-4, err_msg=n)
@@ -119,8 +116,8 @@ def test_ring_attention_long_sequence_memory_shape():
     rng = np.random.RandomState(7)
     q, k, v = _qkv(rng, b=1, h=1, t=64, d=4)
     mesh = _sp_mesh()
-    full = np.asarray(ra.full_attention(jnp.asarray(q), jnp.asarray(k),
-                                        jnp.asarray(v), causal=True))
-    ring = np.asarray(ra.ring_attention(jnp.asarray(q), jnp.asarray(k),
-                                        jnp.asarray(v), mesh, causal=True))
+    full = np.asarray(jax.jit(
+        lambda *a: ra.full_attention(*a, causal=True))(q, k, v))
+    ring = np.asarray(jax.jit(
+        lambda *a: ra.ring_attention(*a, mesh, causal=True))(q, k, v))
     np.testing.assert_allclose(ring, full, rtol=3e-5, atol=3e-5)

@@ -50,11 +50,12 @@ def both_gradients(ops, groups, chunk, seed=1):
     w = jnp.asarray(np.random.RandomState(seed).randn(*ops[0].shape),
                     jnp.float32)
     with jax.default_matmul_precision("highest"):
-        mine = jax.grad(lambda *o: jnp.sum(
+        mine = jax.jit(jax.grad(lambda *o: jnp.sum(
             ssd.chunked(*o, chunk=chunk, groups=groups) * w),
-            argnums=range(6))(*ops)
-        want = jax.grad(lambda *o: jnp.sum(recurrence(*o, groups) * w),
-                        argnums=range(6))(*ops)
+            argnums=range(6)))(*ops)
+        want = jax.jit(jax.grad(
+            lambda *o: jnp.sum(recurrence(*o, groups) * w),
+            argnums=range(6)))(*ops)
     return mine, want
 
 
@@ -63,8 +64,8 @@ def test_forward_and_every_gradient_equal_the_recurrences(chunk):
     """Fifty tokens: several chunks and a padded tail at either size."""
     ops, g = operands()
     with jax.default_matmul_precision("highest"):
-        y = ssd.chunked(*ops, chunk=chunk, groups=g)
-        want = recurrence(*ops, g)
+        y = jax.jit(lambda *o: ssd.chunked(*o, chunk=chunk, groups=g))(*ops)
+        want = jax.jit(lambda *o: recurrence(*o, g))(*ops)
     assert y.shape == want.shape and y.dtype == jnp.float32
     np.testing.assert_allclose(y, want, atol=2e-5)
     mine, wanted = both_gradients(ops, g, chunk)
@@ -89,10 +90,12 @@ def test_the_backward_by_hand_is_the_chunked_forwards_own_derivative():
             jnp.asarray(rng.randn(g, r), f32))
     w = jnp.asarray(rng.randn(n, bsz, g, r, c, p), f32)
     with jax.default_matmul_precision("highest"):
-        by_hand = jax.grad(lambda *o: jnp.sum(ssd._scan(None, *o) * w),
-                           argnums=range(6))(*args)
-        by_jax = jax.grad(lambda *o: jnp.sum(ssd._scan.fun(None, *o) * w),
-                          argnums=range(6))(*args)
+        by_hand = jax.jit(jax.grad(
+            lambda *o: jnp.sum(ssd._scan(None, *o) * w),
+            argnums=range(6)))(*args)
+        by_jax = jax.jit(jax.grad(
+            lambda *o: jnp.sum(ssd._scan.fun(None, *o) * w),
+            argnums=range(6)))(*args)
     for name, m, want in zip(NAMES, by_hand, by_jax):
         np.testing.assert_allclose(m, want, rtol=2e-5, atol=2e-5,
                                    err_msg=name)
@@ -102,8 +105,9 @@ def test_a_group_shared_by_eight_heads():
     ops, g = operands(seed=4, bsz=1, t=32, h=16, p=4, g=2, n=8)
     assert ops[0].shape[2] // g == 8
     with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(ssd.chunked(*ops, chunk=16, groups=g),
-                                   recurrence(*ops, g), atol=2e-5)
+        np.testing.assert_allclose(
+            jax.jit(lambda *o: ssd.chunked(*o, chunk=16, groups=g))(*ops),
+            jax.jit(lambda *o: recurrence(*o, g))(*ops), atol=2e-5)
     mine, wanted = both_gradients(ops, g, 16)
     for name, m, w in zip(NAMES, mine, wanted):
         assert float(jnp.abs(m - w).max()) <= 2e-5 * float(
@@ -120,8 +124,8 @@ def test_decays_near_zero_and_near_one_stay_finite(a, dt):
     ops, g = operands(seed=5, bsz=1, t=256, h=4, p=4, g=1, n=8,
                       a_range=(a, a), dt_range=(dt, dt))
     with jax.default_matmul_precision("highest"):
-        y = ssd.chunked(*ops, chunk=128, groups=g)
-        want = recurrence(*ops, g)
+        y = jax.jit(lambda *o: ssd.chunked(*o, chunk=128, groups=g))(*ops)
+        want = jax.jit(lambda *o: recurrence(*o, g))(*ops)
     assert bool(jnp.all(jnp.isfinite(y)))
     np.testing.assert_allclose(y, want, atol=3e-5 * float(
         jnp.abs(want).max()) + 1e-5)
@@ -135,11 +139,11 @@ def test_decays_near_zero_and_near_one_stay_finite(a, dt):
 def test_under_amp_the_contractions_round_and_the_state_does_not():
     ops, g = operands(seed=6, bsz=1, t=64, h=4, p=8, g=2, n=8)
     with jax.default_matmul_precision("highest"):
-        want = recurrence(*ops, g)
+        want = jax.jit(lambda *o: recurrence(*o, g))(*ops)
     fluid.amp.enable("bfloat16", keep_activations=True)
     try:
-        low = ssd.chunked(ops[0].astype(jnp.bfloat16), *ops[1:], chunk=16,
-                          groups=g)
+        low = jax.jit(lambda *o: ssd.chunked(*o, chunk=16, groups=g))(
+            ops[0].astype(jnp.bfloat16), *ops[1:])
     finally:
         fluid.amp.disable()
     assert low.dtype == jnp.bfloat16
@@ -175,9 +179,10 @@ def test_the_op_through_a_program_its_grad_op_and_counters():
     with jax.default_matmul_precision("highest"):
         got = exe.run(feed=feed, fetch_list=[out] + [
             n + "@GRAD" for n in NAMES])
-        want = recurrence(*ops, g)
-        grads = jax.grad(lambda *o: jnp.sum(recurrence(*o, g) ** 2),
-                         argnums=range(6))(*ops)
+        want = jax.jit(lambda *o: recurrence(*o, g))(*ops)
+        grads = jax.jit(jax.grad(
+            lambda *o: jnp.sum(recurrence(*o, g) ** 2),
+            argnums=range(6)))(*ops)
     np.testing.assert_allclose(got[0], want, atol=2e-5)
     for name, m, w in zip(NAMES, got[1:], grads):
         np.testing.assert_allclose(np.asarray(m).reshape(w.shape), w,

@@ -13,18 +13,14 @@ under the package's x64 mode, blocks whose lane extent is neither
 Nothing runs, so these say nothing about results (the interpret-mode tests
 do) or times (only a chip run does).  Skipped where the topology cannot be
 described.  jax's persistent compilation cache is off around them: an
-executable compiled for a described chip cannot be read back without one.
+executable compiled for a described chip cannot be read back without one
+(both in ``tests/described_chip.py``).  A cell's whole routed layer is
+``tests/test_tpu_compile_routed_layer.py``'s.
 """
 
-import collections
 import functools
 import math
 import os
-
-os.environ.setdefault("TPU_LOG_DIR", "disabled")
-# compile-only use of libtpu: no chip is held, so parallel test workers may
-# each load it (its lockfile otherwise lets one process in)
-os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
 
 import jax
 import jax.numpy as jnp
@@ -37,32 +33,11 @@ from paddle_tpu.ops import (kernel_choice, pallas_flash, pallas_fused,
                             pallas_grouped, pallas_paged, pallas_sparse_flash,
                             registry)
 
+from described_chip import (  # noqa: F401  (the two fixtures)
+    BF16, F32, GROUPED_CELLS, I32, no_persistent_cache, topo)
+
 B, H, T, D = 64, 8, 256, 64          # attention: [batch, heads, len, d_head]
 R, V = B * T, 30000                  # loss head: [batch*len, vocab]
-F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
-
-
-@pytest.fixture(scope="module")
-def topo():
-    from jax.experimental import topologies
-
-    try:
-        return topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as exc:  # no TPU compiler in this installation
-        pytest.skip(f"cannot describe a v5e topology: {exc}")
-
-
-@pytest.fixture(autouse=True)
-def no_persistent_cache():
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    old = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", old)
-    cc.reset_cache()
 
 
 def _flash(causal, bias, t=T):
@@ -187,19 +162,6 @@ def _delta_layer(t=8192, hk=16, hv=32, d=128, taps=4, chunk=64):
                 ((2 * keys + values, taps), F32), gate, gate]
 
 
-#: the decoder cells' grouped products: rows of a walk (the slab of tokens x
-#: top_k, ``parallel/moe.slab_rows``: a quarter of Trinity's 49,152, an eighth
-#: of Kimi-Linear's 16,384, half of Instella's 49,152, all of the others'),
-#: hidden width, expert width,
-#: experts held
-#: (``chipbench/configs/<cell>/config.json``)
-GROUPED_CELLS = {"keye": (65536, 2048, 768, 16),
-                 "trinity": (12288, 2048, 1024, 8),
-                 "lfm2": (32768, 2048, 1792, 8),
-                 "instella": (24576, 2048, 1408, 8),
-                 "qwen3_next": (81920, 2048, 512, 16),
-                 "mellum2": (65536, 2304, 896, 8),
-                 "kimi_linear": (2048, 2304, 1024, 8)}
 #: no cell's: an expert width of 13 lane rows, whose only dividing tile is
 #: one lane row as at Instella's 11 (ragged tiles of 896 + 768 and 384 x 4 +
 #: 128 where the result is that wide: ``plain`` and ``weights_gradient`` of
@@ -516,79 +478,6 @@ def test_grouped_grid_is_the_tiles(topo, cell, form, which, key):
     contraction = d if key.startswith("up") else f
     assert blocks[-1] == ([1, contraction, tn] if form == "weights_gradient"
                           else [pallas_grouped.ROW_TILE, tn])
-
-
-#: a cell's routed layer: tokens a step, choices a token, routed experts,
-#: whether its router has a balancing bias (a slab is walked only under one)
-#: (``GROUPED_CELLS`` has the rows, the widths and the experts held)
-GROUPED_LAYERS = {"keye": (8192, 8, 128, False),
-                  "trinity": (6144, 8, 128, True),
-                  "lfm2": (8192, 4, 32, True),
-                  "instella": (8192, 6, 64, True),
-                  "qwen3_next": (8192, 10, 512, False),
-                  "mellum2": (8192, 8, 64, False),
-                  "kimi_linear": (2048, 8, 256, True)}
-
-
-@pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))
-def test_a_routed_layer_and_its_backward_lower_eleven_calls(
-        topo, monkeypatch, cell):
-    """``routed_experts`` with its hand-written backward at a cell's
-    sizes, under the cells' AMP, lowered and compiled for the described
-    chip: 8 ``grouped_matmul`` (3 forward; the two hidden products again
-    and three rows' cotangents backward, NOT the last product again) and 3
-    ``grouped_matmul_t``, in the six signatures that
-    ``test_grouped_signatures_are_the_benchmarks`` pins one product at a
-    time, each counted as ``2 * M * D * F`` FLOPs by the benchmark's files,
-    M the rows of a walk: the slab's in Trinity, Kimi-Linear and Instella,
-    whose eleven calls stand ONCE, in the bodies of the layer's two loops;
-    XLA drops none and adds none."""
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from chipbench import hlo
-    from chipbench.plugins import load
-    from paddle_tpu.fluid import amp
-    from paddle_tpu.parallel import moe
-
-    m, d, f, g = GROUPED_CELLS[cell]
-    tokens, top_k, routed, balanced = GROUPED_LAYERS[cell]
-    assert moe.slab_rows(tokens * top_k, g, routed, True, balanced) == m
-    bias = jnp.zeros((routed,), F32) if balanced else None
-    monkeypatch.setattr(kernel_choice, "interpret", lambda stated=None: False)
-
-    def layer(x, wr, w1, w3, w2):
-        return moe.routed_experts(x, wr, w1, w3, w2, top_k=top_k,
-                                  bias=bias).astype(F32).sum()
-
-    chip = SingleDeviceSharding(topo.devices[0])
-    args = [jax.ShapeDtypeStruct(s, t, sharding=chip) for s, t in
-            (((1, tokens, d), BF16), ((d, routed), F32), ((g, d, f), F32),
-             ((g, d, f), F32), ((g, f, d), F32))]
-    amp.enable("bfloat16", keep_activations=True)
-    try:
-        lowered = jax.jit(jax.value_and_grad(layer, range(5))).lower(*args)
-    finally:
-        amp.disable()
-    calls = hlo.custom_calls(lowered.as_text())
-    steps = m // pallas_grouped.ROW_TILE + g - 1
-    tables = f"s32[{g + 1}],s32[{steps}],s32[{steps}]"
-    rows, hidden = f"bf16[{m},{d}]", f"bf16[{m},{f}]"
-    up, down = f"bf16[{g},{d},{f}]", f"bf16[{g},{f},{d}]"
-    for call in calls:
-        assert load("kernels", call.kernel).flops(
-            call.operands, call.results) == 2.0 * m * d * f
-    assert collections.Counter(
-            (call.kernel, hlo.signature(call)) for call in calls) == {
-        ("grouped_matmul", f"{hidden}<-{tables},{rows},{up}"): 4,
-        ("grouped_matmul", f"{rows}<-{tables},{hidden},{down}"): 1,
-        ("grouped_matmul", f"{hidden}<-{tables},{rows},{down}"): 1,
-        ("grouped_matmul", f"{rows}<-{tables},{hidden},{up}"): 2,
-        ("grouped_matmul_t", f"{up}<-{tables},{rows},{hidden}"): 2,
-        ("grouped_matmul_t", f"{down}<-{tables},{hidden},{rows}"): 1}
-    assert lowered.compile().as_text().count(
-        'custom_call_target="tpu_custom_call"') == 11
 
 
 @pytest.mark.parametrize("cell,rows,width,dtype,eps", [

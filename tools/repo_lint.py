@@ -20,6 +20,14 @@ has actually shipped, plus doc drift:
  3. **env-doc-drift** — ``docs/ENV.md`` differs from the generator
     output (``python -m paddle_tpu.fluid.envcontract``).
 
+ 4. **bare-reference** — a file under ``tests/`` (outside
+    ``tests/chipbench/``, the benchmark's) calls a configuration's
+    ``loss_and_grads(`` itself.  ``tests/decoder_reference.py`` is the one
+    place that does, under ``jax.jit`` and once for each (reference, sizes,
+    operands): called bare, a reference dispatches primitive by primitive
+    and compiles each, which is how tier-1 outgrew its time limit (PR 63;
+    docs/COVERAGE.md).
+
 Exit 0 = clean, 1 = findings (printed one per line as
 ``<class>:<file>:<line>: <message>``).
 """
@@ -233,6 +241,23 @@ def check_fault_doc() -> List[Tuple[str, str, int, str]]:
     return []
 
 
+def check_bare_references(tests_dir: str = None
+                          ) -> List[Tuple[str, str, int, str]]:
+    tests_dir = tests_dir or os.path.join(REPO, "tests")
+    out = []
+    for fn in sorted(os.listdir(tests_dir)):
+        if not fn.endswith(".py") or fn == "decoder_reference.py":
+            continue
+        with open(os.path.join(tests_dir, fn)) as f:
+            for lineno, line in enumerate(f, 1):
+                if "loss_and_grads(" in line:
+                    out.append((
+                        "bare-reference", f"tests/{fn}", lineno,
+                        "a configuration's reference is called here: take "
+                        "`reference_step` of tests/decoder_reference.py"))
+    return out
+
+
 def run(root: str = None) -> List[Tuple[str, str, int, str]]:
     sys.path.insert(0, REPO)
     from paddle_tpu.fluid import envcontract
@@ -248,6 +273,7 @@ def run(root: str = None) -> List[Tuple[str, str, int, str]]:
     if os.path.abspath(root) == os.path.join(REPO, "paddle_tpu"):
         findings.extend(check_env_doc())
         findings.extend(check_fault_doc())
+        findings.extend(check_bare_references())
     return findings
 
 
