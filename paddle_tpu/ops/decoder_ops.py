@@ -37,8 +37,14 @@ channel alike), and
 ``ops.delta_rule.channel_calls{key_heads,dim,chunk,sub}`` beside ``calls``
 for every forward lowered with such a G, whichever path it took;
 ``ops.ssd.scans{heads,dim,groups,state,chunk,path}`` for every ``ssd_scan``
-lowered and ``ops.ssd.grad_scans{chunk,path="by_hand"}`` for every
-``ssd_scan_grad`` (the backward written out in ``ops/ssd.py``);
+lowered (``path="pallas"``: the kernels of ``ops/pallas_ssd.py``, where the
+``flash`` gate is open and they take the operands; ``"xla"``:
+``ops/ssd.py``, with ``ops.ssd.declined{why}`` (``why``: ``chunk``,
+``width`` or ``heads``) where the kernels were asked, would have been
+compiled and not interpreted, and gave a reason) and
+``ops.ssd.grad_scans{chunk,path}`` for every ``ssd_scan_grad``
+(``path="pallas"``: the kernels' own backward; ``"by_hand"``: the backward
+written out in ``ops/ssd.py``);
 ``ops.moe.ungated_layers`` beside ``ops.moe.calls`` for every
 ``moe_experts`` lowered whose experts are two matrices about a squared ReLU;
 ``ops.moe.row_moves{pass,how="gather"}``, which ``parallel/moe.py`` counts
@@ -516,50 +522,70 @@ def gated_delta_rule_grad(ctx):
 
 
 def _ssd_scan(ctx):
-    """(the scan as a function of the op's six inputs, the inputs)."""
+    """(the scan as a function of the op's six inputs, the inputs, why the
+    Pallas kernels do not take them: ``ssd.kernel_declines``)."""
     from . import ssd
 
-    def scan(u, delta, a, b, c, d):
-        return ssd.chunked(u, delta, a, b, c, d,
-                           chunk=int(ctx.attr("chunk", 128)),
-                           groups=int(ctx.attr("groups", 1)))
+    chunk, groups = int(ctx.attr("chunk", 128)), int(ctx.attr("groups", 1))
 
-    return scan, [ctx.input(s) for s in ("U", "Delta", "A", "B", "C", "D")]
+    def scan(u, delta, a, b, c, d):
+        return ssd.chunked(u, delta, a, b, c, d, chunk=chunk, groups=groups)
+
+    operands = [ctx.input(s) for s in ("U", "Delta", "A", "B", "C", "D")]
+    u, delta, _, b, c, _ = operands
+    return scan, operands, ssd.kernel_declines(u, delta, b, c, chunk, groups)
 
 
 @register_op("ssd_scan")
 def ssd_scan_op(ctx):
-    """A selective state-space scan over the sequence (``ops/ssd.py``), in
-    chunks of ``chunk`` tokens.  U: [B, T, H, P]; Delta (each token's step,
-    > 0): [B, T, H]; A (< 0) and D: [H]; B and C: [B, T, groups * N], head
-    h reading group ``h // (H // groups)``; Out: [B, T, H, P].  Every head
-    keeps a [P, N] state that a token decays by ``exp(Delta A)``, adds
+    """A selective state-space scan over the sequence (``ops/ssd.py``; its
+    Pallas kernels, ``ops/pallas_ssd.py``, where they take the operands),
+    in chunks of ``chunk`` tokens.  U: [B, T, H, P]; Delta (each token's
+    step, > 0): [B, T, H]; A (< 0) and D: [H]; B and C: [B, T, groups * N],
+    head h reading group ``h // (H // groups)``; Out: [B, T, H, P].  Every
+    head keeps a [P, N] state that a token decays by ``exp(Delta A)``, adds
     ``Delta u B^T`` to and reads along C; ``D u`` passes beside it.  The
     state starts at zero in every row of the batch and nothing crosses from
     one row to the next."""
-    scan, operands = _ssd_scan(ctx)
+    from . import kernel_choice
+
+    scan, operands, why = _ssd_scan(ctx)
     u, groups = operands[0], int(ctx.attr("groups", 1))
+    # a refusal is counted where the kernels would have been compiled, as
+    # the delta rule's is: what the tests and the benchmark's rehearsals
+    # run (chunks of 16, heads of 8) is no scan they are for
+    if why and not kernel_choice.interpret():
+        _count("ops.ssd.declined", why=why)
     _count("ops.ssd.scans", heads=u.shape[2], dim=u.shape[3], groups=groups,
            state=operands[3].shape[-1] // groups,
-           chunk=int(ctx.attr("chunk", 128)), path="xla")
+           chunk=int(ctx.attr("chunk", 128)),
+           path="pallas" if why == "" else "xla")
     return {"Out": scan(*operands)}
 
 
 @register_grad("ssd_scan")
 def ssd_scan_grad(ctx):
     """From the op's six inputs alone, by the backward ``ssd.chunked``
-    carries (a ``jax.custom_vjp`` written by hand, which ``jax.vjp`` below
-    meets: nothing differentiates through the walk): the chunks' decays,
-    scores and writes and the state at every chunk's start are made again,
-    the outputs are not, then the chunks are walked backwards."""
-    scan, operands = _ssd_scan(ctx)
+    carries (a ``jax.custom_vjp`` written by hand on either path, the
+    kernels' in ``ops/pallas_ssd.py`` and the XLA lowering's in
+    ``ops/ssd.py``, which ``jax.vjp`` below meets: nothing differentiates
+    through the walk): the chunks' decays, scores and writes and the state
+    at every chunk's start are made again, the outputs are not, then the
+    chunks are walked backwards."""
+    scan, operands, why = _ssd_scan(ctx)
     _count("ops.ssd.grad_scans", chunk=int(ctx.attr("chunk", 128)),
-           path="by_hand")
-    # behind a barrier with the cotangent in it, as the delta rule's: XLA
-    # must not find the second forward to be the first and keep every
-    # chunk's decays, scores and states from the forward pass to here
-    operands, dout = jax.lax.optimization_barrier(
-        (operands, ctx.input("Out@GRAD")))
+           path="pallas" if why == "" else "by_hand")
+    dout = ctx.input("Out@GRAD")
+    if why != "":
+        # the XLA lowering: behind a barrier with the cotangent in it, as
+        # the delta rule's: XLA must not find the second forward to be the
+        # first and keep every chunk's decays, scores and states from the
+        # forward pass to here.  The kernels make no second forward (their
+        # backward's first pass emits the states alone), and a barrier over
+        # their operands costs 105 MB of the step's reserved region
+        # (Nemotron's step compiled for the described chip: 4.92 GB with,
+        # 4.81 without, 4.73 at the parent)
+        operands, dout = jax.lax.optimization_barrier((operands, dout))
     _, vjp = jax.vjp(scan, *operands)
     grads = dict(zip(("U@GRAD", "Delta@GRAD", "A@GRAD", "B@GRAD", "C@GRAD",
                       "D@GRAD"), vjp(dout.astype(operands[0].dtype))))

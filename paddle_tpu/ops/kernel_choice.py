@@ -7,9 +7,9 @@ is its environment switch where that is set (``0`` / ``false`` closed, ``1``
 / ``true`` open, whatever the platform), else the platform: open on a TPU
 backend, closed elsewhere.  ``flash`` (``PADDLE_TPU_FLASH``) is
 ``ring_attention``, the transformer stacks' attention,
-``sparse_attention`` and ``gated_delta_rule`` under either kind of decay
-(``ops/pallas_delta_rule.py``: a linear attention, so the attention
-kernels' gate); ``fused`` (``PADDLE_TPU_FUSED``) is softmax
+``sparse_attention``, ``gated_delta_rule`` under either kind of decay
+(``ops/pallas_delta_rule.py``) and ``ssd_scan`` (``ops/pallas_ssd.py``:
+linear attentions both, so the attention kernels' gate); ``fused`` (``PADDLE_TPU_FUSED``) is softmax
 cross-entropy, the Adam and momentum sweeps and ``paged_attention``.  The
 switches are for tests and the benchmark's rehearsal on the CPU; nothing
 above ``ops/`` has a say, no layer, model or op attribute.  The expert

@@ -2,7 +2,12 @@
 chunked "state-space dual" form a training step needs, with its backward
 written by hand.  The mathematics of the op ``ssd_scan`` and of its grad op
 (``ops/decoder_ops.py``), plain ``jax.numpy`` with one ``lax.scan`` over the
-chunks forward and one backward: the XLA lowering, and what the CPU runs.
+chunks forward and one backward: the XLA lowering, the twin and the oracle
+of the Pallas kernels beside it (``ops/pallas_ssd.py``, the same equations
+with a chunk's decays, scores and cotangents in VMEM), which ``chunked``
+runs where the ``flash`` gate is open and ``pallas_ssd.supported`` gives no
+reason against (``kernel_declines``); it is what the CPU runs and what
+operands the kernels refuse run everywhere.
 
 For one head h of P columns (it reads the B and C of group ``h // (H //
 G)``: group j serves the heads ``j * H / G`` and the ``H / G - 1`` after
@@ -172,12 +177,26 @@ def _scan_bwd(low, operands, dout):
 _scan.defvjp(_scan_fwd, _scan_bwd)
 
 
+def kernel_declines(u, delta, b, c, chunk, groups):
+    """Why the Pallas kernels (``ops/pallas_ssd.py``) do not take these
+    operands of ``chunked``: '' where they do, else ``pallas_ssd.supported``'s
+    reason ('chunk', 'width', 'heads'), and None where the ``flash`` gate is
+    closed and nothing was asked of them."""
+    from . import kernel_choice, pallas_ssd
+
+    if not kernel_choice.gate("flash"):
+        return None
+    return pallas_ssd.supported(u, delta, b, c, chunk, groups)
+
+
 def chunked(u, delta, a, b, c, d, chunk=128, groups=1):
     """u: [B, T, H, P]; delta: [B, T, H] (> 0); a, d: [H] (a < 0); b, c:
     [B, T, groups * N] -> [B, T, H, P] in u's type.  Head h reads group
-    ``h // (H // groups)``.  ``T`` need not be a multiple of ``chunk``: the
-    tail is padded with tokens whose step is 0, which decay nothing and
-    write nothing."""
+    ``h // (H // groups)``.  ``T`` need not be a multiple of ``chunk`` (for
+    the kernels: of a grid step's tokens): the tail is padded with tokens
+    whose step is 0, which decay nothing and write nothing.  The Pallas
+    kernels where they are asked and take the operands
+    (``kernel_declines``), else ``_scan``."""
     from ..fluid import amp
 
     bsz, t, h, p = u.shape
@@ -186,6 +205,20 @@ def chunked(u, delta, a, b, c, d, chunk=128, groups=1):
                          f"do not divide over {groups} groups")
     rep, state = h // groups, b.shape[-1] // groups
     f32 = jnp.float32
+    if kernel_declines(u, delta, b, c, chunk, groups) == "":
+        from . import pallas_ssd
+
+        pad = -t % pallas_ssd.TOKENS        # a grid step: a few chunks
+        if pad:
+            u, delta, b, c = (jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (
+                x.ndim - 2)) for x in (u, delta, b, c))
+        out = pallas_ssd.scan(amp.compute_dtype(), groups, u,
+                              delta.astype(f32), a.astype(f32), b, c,
+                              d.astype(f32))
+        # behind a barrier, as the delta rule's kernels' result: XLA must
+        # not keep the statistic of the norm that reads it, broadcast to
+        # its shape, from the forward pass to the backward
+        return lax.optimization_barrier(out[:, :t])
     pad = -t % chunk
     n = (t + pad) // chunk
 

@@ -153,6 +153,19 @@ def delta_channel():
     return feed, [layers.gated_delta_rule(*args, chunk=64)]
 
 
+def ssd_scan():
+    """One chunk of one group of two heads of 64 over a state of 128: the
+    least the scan's kernels take."""
+    t, h, p, n = 128, 2, 64, 128
+    shapes = {"u": [1, t, h, p], "delta": [1, t, h], "a": [h],
+              "b": [1, t, n], "c": [1, t, n], "d": [h]}
+    args = [layers.data(name=n_, shape=s, dtype="float32",
+                        append_batch_size=False) for n_, s in shapes.items()]
+    feed = {n_: np.full(s, -0.5 if n_ == "a" else 0.5, "float32")
+            for n_, s in shapes.items()}
+    return feed, [layers.ssd_scan(*args, chunk=128, groups=1)]
+
+
 #: family -> (its program, its group, the kernel's name stack in the
 #: lowered text, the counter that says a kernel ran, the one that says its
 #: twin did)
@@ -181,6 +194,12 @@ FAMILIES = {
         'ops.delta_rule.calls{chunk="64",dim="128",key_heads="2",'
         'path="pallas"',
         'ops.delta_rule.calls{chunk="64",dim="128",key_heads="2",'
+        'path="xla"'),
+    "ssd_scan": (
+        ssd_scan, "flash", "ssd_scan/ssd_scan_fwd/pallas_call",
+        'ops.ssd.scans{chunk="128",dim="64",groups="1",heads="2",'
+        'path="pallas"',
+        'ops.ssd.scans{chunk="128",dim="64",groups="1",heads="2",'
         'path="xla"'),
     "xent": (xent, "fused", "softmax_with_cross_entropy/pallas_call",
              'ops.fused.softmax_xent{target="hard"', None),

@@ -322,6 +322,53 @@ def test_the_channel_delta_rules_kernels_compile_for_v5e(topo, monkeypatch):
         assert declared in text, declared
 
 
+@pytest.mark.parametrize("p", [64, 128])
+def test_the_ssd_scans_kernels_compile_for_v5e(topo, monkeypatch, p):
+    """The selective scan at ``nemotron_twotower_30b_a3b``'s shapes (one
+    sequence of 8,192 tokens, 64 heads of 64 in 8 groups, a state of 128,
+    chunks of 128) under bf16 AMP with the flash gate open, and at heads of
+    128: the op holds ``ssd_scan_fwd``; the grad holds the states pass and
+    the backward walk and NOT the forward walk; each call declares the
+    operands ``chipbench/kernels/ssd_scan_*.py`` count from (u ``[B, T, H *
+    P]``, b and c ``[B, T, G * N]``, the rows, what is as wide as the
+    states, the states), and within Mosaic's default VMEM."""
+    from paddle_tpu.fluid import amp
+    from paddle_tpu.ops import ssd
+
+    monkeypatch.setenv(kernel_choice.SWITCHES["flash"], "1")
+    monkeypatch.setattr(kernel_choice, "interpret",
+                        lambda stated=None: False)
+    t, h, g, n = 8192, 4096 // p, 8, 128
+
+    def scan(*xs):
+        with amp.amp_guard("bfloat16", keep_activations=True):
+            return ssd.chunked(*xs, chunk=128, groups=g)
+
+    def grads(*xs):
+        return jax.grad(lambda *a: scan(*a).astype(F32).sum(),
+                        range(6))(*xs)
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, ty, sharding=chip) for s, ty in (
+        ((1, t, h, p), BF16), ((1, t, h), F32), ((h,), F32),
+        ((1, t, g * n), BF16), ((1, t, g * n), BF16), ((h,), F32))]
+    text = jax.jit(scan).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "/ssd_scan_fwd/pallas_call" in text
+    text = jax.jit(grads).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for kernel, there in (("ssd_scan_states", True), ("ssd_scan_bwd", True),
+                          ("ssd_scan_fwd", False)):
+        assert (f"({kernel}))/pallas_call" in text) is there, kernel
+    rep, wide = h // g, h * p // g
+    for declared in (f"bf16[1,{t},{h * p}]", f"bf16[1,{t},{g * n}]",
+                     f"f32[1,{g},{2 * rep + -2 * rep % 8},{t}]",
+                     f"f32[1,{g},{t // 128},3,{wide}]",
+                     f"bf16[1,{g},{t // 128},{n},{wide}]",
+                     f"f32[1,{g},{rep},{t}]"):
+        assert declared in text, declared
+
+
 #: what ``chipbench/kernels/flash_*.py`` count FLOPs from and what
 #: ``chipbench/trace_reduce.kernel_roofline`` matches trace events by: family
 #: -> (kernel, contractions, plain operands, results).  The benchmark's files
