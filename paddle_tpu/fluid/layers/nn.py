@@ -35,6 +35,7 @@ __all__ = [
     "lod_reset", "prelu", "dice_loss", "log_loss", "huber_loss",
     "ring_attention", "moe_ffn", "gpipe_mlp_stack",
     "rms_norm", "rotary_embedding", "sparse_indexer", "sparse_attention",
+    "weighted_mean",
     "moe_experts", "moe_bias_update", "short_conv", "gated_delta_rule",
     "ssd_scan",
     "kv_cache_update", "kv_cache_scatter", "token_select",
@@ -1381,7 +1382,7 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, name=None, groups=1):
 
 
 def rotary_embedding(input, theta=10000.0, start=0, dims=0, interleaved=False,
-                     inv_freq=None, name=None):
+                     inv_freq=None, period=0, name=None):
     """Rotary positions on [B, T, H, D]: position t, the index along axis
     1, rotates the pair (i, i + n/2) of the head's ``n = dims`` columns
     from ``start`` on (``dims`` 0: to the head's end; the defaults are the
@@ -1389,7 +1390,8 @@ def rotary_embedding(input, theta=10000.0, start=0, dims=0, interleaved=False,
     ``interleaved``: the pair is (2i, 2i + 1).  ``inv_freq``: n/2
     frequencies in ``theta``'s place, for a table that a scaling rule made
     (``models.decoder_lm.yarn_inv_freq``).  Columns outside the part pass
-    unchanged."""
+    unchanged.  ``period`` p > 0: axis 1 holds copies of a sequence of p
+    tokens side by side, and index i has position i mod p."""
     helper = LayerHelper("rotary_embedding", **locals())
     out = helper.create_variable_for_type_inference(helper.input_dtype())
     out.shape = tuple(input.shape)
@@ -1404,6 +1406,8 @@ def rotary_embedding(input, theta=10000.0, start=0, dims=0, interleaved=False,
         attrs["interleaved"] = True
     if inv_freq is not None:
         attrs["inv_freq"] = [float(f) for f in inv_freq]
+    if period:
+        attrs["period"] = int(period)
     helper.append_op(type="rotary_embedding", inputs={"X": [input]},
                      outputs={"Out": [out]}, attrs=attrs)
     return out
@@ -1454,8 +1458,9 @@ def sparse_indexer(input, num_heads, head_dim, topk, theta=10000.0,
 
 
 def sparse_attention(q, k, v, selection=None, scale=None, window=0,
-                     name=None):
-    """Causal grouped-query attention, optionally over a per-query
+                     block_rule=None, name=None):
+    """Grouped-query attention, causal unless ``block_rule`` is given,
+    optionally over a per-query
     selection of keys (a sibling of ``ring_attention``; ops/decoder_ops.py
     + ops/pallas_sparse_flash.py).  q: [B, Hq, T, D]; k: [B, Hkv, T, D];
     v: [B, Hkv, T, Dv], and the result [B, Hq, T, Dv] (the kernels take
@@ -1464,7 +1469,13 @@ def sparse_attention(q, k, v, selection=None, scale=None, window=0,
     ``selection``: [B, T, T] int8 from ``sparse_indexer`` or None (every
     key s <= t).  ``window``: 0, or a causal window: key s counts for query
     t iff ``0 <= t - s < window`` (a static band that the kernels' grids
-    are cut to; never a [B, T, T] mask).  Kernel or twin as for
+    are cut to; never a [B, T, T] mask).  ``block_rule``: None, or (tokens
+    a copy L, block length): T = 2L holds a clean copy of a sequence and
+    then a noised one, and with ``B(i) = (i mod L) // block`` a clean query
+    counts the clean keys of blocks ``<= B(t)`` (its own block whole), a
+    noised one the clean keys of blocks ``< B(t)`` and the noised keys of
+    block ``B(t)``, in one softmax; static, with neither a selection nor a
+    window.  Kernel or twin as for
     ``ring_attention``: the Pallas kernels (the selection as a mask inside
     them) or the blocked XLA path; nothing [Hq, T, T] reaches HBM either
     way."""
@@ -1482,11 +1493,30 @@ def sparse_attention(q, k, v, selection=None, scale=None, window=0,
         # the label of the call's counter: the attr of the op that made it
         topk = next((op.attr("topk", 0) for op in selection.block.ops
                      if selection.name in op.output_arg_names), 0)
+    # refused beside a selection or a window by the op and its infer rule
+    rule = {} if block_rule is None else {
+        "copy_tokens": int(block_rule[0]), "block": int(block_rule[1])}
     helper.append_op(
         type="sparse_attention", inputs=inputs,
         outputs={"Out": [out], "Lse": [lse]},
         attrs={"scale": float(scale or 0.0), "topk": int(topk),
-               **({"window": int(window)} if window else {})})
+               **({"window": int(window)} if window else {}), **rule})
+    return out
+
+
+def weighted_mean(x, weight, name=None):
+    """``sum(x * weight) / x.size`` as a [1] tensor: a mean over ALL of
+    ``x`` in which every value counts by its weight (0: not at all), as a
+    diffusion step's loss over its masked tokens does.  ``weight`` has
+    ``x``'s size and gets no gradient.  The op publishes how many values
+    bear weight (``ops.weighted_mean.live_rows`` of ``.rows``, step
+    gauges)."""
+    helper = LayerHelper("weighted_mean", **locals())
+    out = helper.create_variable_for_type_inference(x.dtype)
+    out.shape = (1,)
+    helper.append_op(type="weighted_mean",
+                     inputs={"X": [x], "Weight": [weight]},
+                     outputs={"Out": [out]})
     return out
 
 

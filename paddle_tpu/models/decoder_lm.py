@@ -9,8 +9,12 @@ layers and elsewhere a routed expert layer of which this program holds a
 stated share (SiLU-gated experts of three matrices, or squared-ReLU ones of
 two), RMS norms, rotary positions, a head of its own or the embedding's
 transpose, next-token loss and, where the model has one, a multi-token
-module's loss beside it.  A published layer is a mixer and then a
-feed-forward, or, where ``sub_blocks`` says so, one of the two alone.
+module's loss beside it; or, where ``block_diffusion`` says so, a step of
+diffusion over blocks: a clean and a noised copy of every sequence, an
+attention that is causal over blocks and bidirectional inside one, and a
+loss on the masked tokens weighted by their block's noise level.  A
+published layer is a mixer and then a feed-forward, or, where
+``sub_blocks`` says so, one of the two alone.
 
 Everything is configuration (``Config``); nothing here is specific to one
 model or to the benchmark.  The layer, for x = one sequence [T, hidden] and
@@ -113,6 +117,21 @@ field turns on)::
     logits = RMSNorm(x_last) Whead [tie_head: Emb^T, one parameter whose
         gradient is the lookup's rows plus the head product's]
     loss = mean next-token cross-entropy
+    [block_diffusion D: the program reads ``tokens`` x [B, L], ``noised``
+        x~ [B, L] (x with some tokens replaced by D.mask_id, block by block
+        of D.block, each block at a level t_b of its own: ``noise``) and
+        ``weights`` [B, L] (m_i / t_B(i), m_i 1 where x~_i is the mask;
+        B(i) = i // D.block).  The trunk runs on 2L rows, [x | x~]; index i
+        and index L + i both have rotary position i; every op but attention
+        acts on each row alone.  Attention, in one softmax a query:
+            a clean query c_i counts the clean keys c_j with B(j) <= B(i)
+                (its own block whole, later tokens of it too);
+            a noised query n_i the clean keys c_j with B(j) < B(i) and the
+                noised keys n_j with B(j) = B(i);
+            no clean query counts a noised key.
+        The head reads the L noised rows alone and position i's logits
+        predict x_i itself (no shift):
+        loss = sum_i weights_i * CE(logits_i, x_i) / (B L)]
     [mtp_depth 1, the multi-token module after the trunk:
         h' = [RMSNorm(x_last) | RMSNorm(Emb[labels])] Wmerge   the SAME Emb
         u  = one more routed block on h' (the mixer of the last published
@@ -236,6 +255,16 @@ class Rotary(NamedTuple):
     scale: float = 0.0
 
 
+class BlockDiffusion(NamedTuple):
+    """Training by diffusion over blocks in next-token training's place:
+    the length of a block (a sequence is a whole number of them) and the id
+    that stands for a masked token, which no data token has.  Every layer
+    is then a plain attention layer without a window or a selection: what
+    a noised token may see is defined for attention alone."""
+    block: int
+    mask_id: int
+
+
 DELTA_NORM_EPS = 1e-6   # the l2 norm of a delta mixer's queries and keys
 DT_BIAS_INIT = -3.0     # softplus(-3) = 0.049: a token forgets a twentieth
 
@@ -283,7 +312,8 @@ class Config:
                  residual="sequential", mtp_depth=0, mtp_weight=0.0,
                  delta=None, rotary_dims=0, shared_gate=False,
                  global_rotary=None, delta_gates=None, ssm=None,
-                 sub_blocks=None, expert_gate=True, qk_norm=True):
+                 sub_blocks=None, expert_gate=True, qk_norm=True,
+                 block_diffusion=None):
         if num_heads % num_kv_heads:
             raise ValueError(f"{num_heads} query heads do not group over "
                              f"{num_kv_heads} key-value heads")
@@ -375,6 +405,19 @@ class Config:
                              "shared_width, and gated experts")
         if residual not in RESIDUALS:
             raise ValueError(f"residual {residual!r}: one of {RESIDUALS}")
+        if block_diffusion is not None:
+            block_diffusion = BlockDiffusion(*block_diffusion)
+            if block_diffusion.block < 1 \
+                    or not 0 <= block_diffusion.mask_id < vocab_size \
+                    or mixers is not None or window or index_topk \
+                    or mtp_depth:
+                raise ValueError(
+                    f"{block_diffusion} beside a vocabulary of "
+                    f"{vocab_size}: a block holds a token at least, the "
+                    "mask is an id of the vocabulary, and every layer is "
+                    "plain attention (no `mixers`, window, selection or "
+                    "multi-token module: what a noised token sees is "
+                    "defined for none of them)")
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.num_layers = num_layers
@@ -450,6 +493,9 @@ class Config:
         # off: a plain attention layer's queries and keys go unnormed (a
         # latent layer has its own switch, ``Latent.head_norm``)
         self.qk_norm = bool(qk_norm)
+        # a BlockDiffusion (or its fields in order): the step is one of
+        # diffusion over blocks; None: next-token training
+        self.block_diffusion = block_diffusion
 
     def layer_parts(self, i):
         """What held layer ``i`` is made of, one of SUB_BLOCKS."""
@@ -506,13 +552,16 @@ def _norm(x, cfg, name):
 def _heads(x, seq_len, n, cfg, norm_name=None, rotate=False, inv_freq=None):
     """[B, T, n*Dh] -> [B, n, T, Dh]; normed per head where ``norm_name``
     names the norm's scale, and then rotated where ``rotate``: by
-    ``rope_theta``'s table, or by ``inv_freq`` (q and k; v is neither)."""
+    ``rope_theta``'s table, or by ``inv_freq`` (q and k; v is neither).
+    Under ``block_diffusion`` T is two copies of a sequence, whose
+    positions are equal."""
     x = layers.reshape(x, [-1, seq_len, n, cfg.head_dim])
     if norm_name is not None:
         x = _norm(x, cfg, norm_name)
     if rotate:
-        x = layers.rotary_embedding(x, theta=cfg.rope_theta,
-                                    dims=cfg.rotary_dims, inv_freq=inv_freq)
+        x = layers.rotary_embedding(
+            x, theta=cfg.rope_theta, dims=cfg.rotary_dims, inv_freq=inv_freq,
+            period=seq_len // 2 if cfg.block_diffusion else 0)
     return layers.transpose(x, perm=[0, 2, 1, 3])
 
 
@@ -551,7 +600,9 @@ def _attention(x, cfg, seq_len, p, window, own=None):
             param_attr=_attr(None))
     ctx = layers.sparse_attention(
         q, k, v, selection=sel, window=window,
-        scale=own.scale if own and own.scale else cfg.head_dim ** -0.5)
+        scale=own.scale if own and own.scale else cfg.head_dim ** -0.5,
+        block_rule=(seq_len // 2, cfg.block_diffusion.block)
+        if cfg.block_diffusion else None)
     return _gated_out(ctx, x, cfg, seq_len, p)
 
 
@@ -820,8 +871,10 @@ def _block(stream, cfg, seq_len, p, scope, mixer, window, dense, routers,
         return _sub_block(stream, cfg, feed)
 
 
-def _head_loss(h, cfg, norm_name, labels):
-    """(logits, mean cross-entropy) of the one head on ``h``."""
+def _head_loss(h, cfg, norm_name, labels, weights=None):
+    """(logits, mean cross-entropy) of the one head on ``h``; with
+    ``weights``, one a row, each row's cross-entropy times its weight in
+    the mean over all rows."""
     h = _norm(h, cfg, norm_name)
     if cfg.tie_head:
         logits = layers.matmul(
@@ -829,8 +882,9 @@ def _head_loss(h, cfg, norm_name, labels):
             transpose_y=True)
     else:
         logits = _proj(h, cfg.vocab_size, "lm_head_w")
-    return logits, layers.mean(
-        layers.softmax_with_cross_entropy(logits, labels))
+    rows = layers.softmax_with_cross_entropy(logits, labels)
+    return logits, layers.mean(rows) if weights is None \
+        else layers.weighted_mean(rows, weights)
 
 
 def _embed(ids, cfg):
@@ -878,28 +932,72 @@ def _forward(cfg, seq_len):
     multi-token module, ``mtp.merge``, ``mtp.mixer``, ``mtp.ffn``,
     ``mtp.head``."""
     tokens = layers.data(name="tokens", shape=[seq_len], dtype="int64")
-    labels = layers.data(name="labels", shape=[seq_len, 1], dtype="int64")
+    diffusion, weights, rows = cfg.block_diffusion, None, seq_len
+    if diffusion:
+        if seq_len % diffusion.block:
+            raise ValueError(f"{seq_len} tokens are no whole number of "
+                             f"blocks of {diffusion.block}")
+        # the second data layer is the noised copy, and the trunk walks
+        # both copies side by side
+        second = layers.data(name="noised", shape=[seq_len], dtype="int64")
+        weights = layers.data(name="weights", shape=[seq_len],
+                              dtype="float32")
+        rows = 2 * seq_len
+    else:
+        second = labels = layers.data(name="labels", shape=[seq_len, 1],
+                                      dtype="int64")
     with fluid.name_scope("embed"):
-        h = _embed(tokens, cfg)
+        h = _embed(layers.concat([tokens, second], axis=1) if diffusion
+                   else tokens, cfg)
     routers = []
     stream = (h, h)
     for i in range(cfg.num_layers):
-        stream = _block(stream, cfg, seq_len, f"l{i}", f"layer{i}",
+        stream = _block(stream, cfg, rows, f"l{i}", f"layer{i}",
                         cfg.layer_mixer(i), cfg.layer_window(i),
                         cfg.layer_is_dense(i), routers, cfg.layer_parts(i))
     with fluid.name_scope("head"):
-        logits, loss = _head_loss(stream[1], cfg, "final_norm", labels)
+        last = stream[1]
+        if diffusion:
+            # the head reads the noised rows alone, and a row's label is
+            # its own clean token
+            last = layers.slice(last, axes=[1], starts=[seq_len],
+                                ends=[rows])
+            labels = layers.reshape(tokens, [-1, seq_len, 1])
+        logits, loss = _head_loss(last, cfg, "final_norm", labels, weights)
     if cfg.mtp_depth:
         loss = _multi_token(stream[1], loss, cfg, seq_len, labels, routers)
-    return tokens, labels, loss, logits, routers
+    return tokens, second, loss, logits, routers
+
+
+def noise(cfg, tokens, rng, floor=1e-3):
+    """(noised, weights) of one step of diffusion over blocks for ``tokens``
+    [B, L] (numpy, no id of it the mask's): every block of
+    ``cfg.block_diffusion.block`` tokens draws a level t uniform on
+    [``floor``, 1] from ``rng`` (a ``numpy.random.RandomState``), each of
+    its tokens is replaced by the mask id with probability t, and a masked
+    token weighs 1 / t, any other 0.  The data pipeline's half of the step:
+    the program reads what this returns."""
+    import numpy as np
+
+    block, mask_id = cfg.block_diffusion
+    b, length = tokens.shape
+    level = np.repeat(rng.uniform(floor, 1.0, size=(b, length // block)),
+                      block, axis=1)
+    masked = rng.uniform(size=(b, length)) < level
+    return (np.where(masked, mask_id, tokens).astype(np.int64),
+            (masked / level).astype(np.float32))
 
 
 def forward(cfg, seq_len):
-    """Data layers, logits and the mean next-token cross-entropy.  Returns
-    (tokens, labels, loss, logits); ``labels[b, t]`` is the token that
-    follows ``tokens[b, t]``.  With the multi-token module the program
+    """Data layers, logits and the loss.  Returns (tokens, labels, loss,
+    logits): the mean next-token cross-entropy, ``labels[b, t]`` the token
+    that follows ``tokens[b, t]``.  With the multi-token module the program
     also reads ``labels2`` (the token after that) and ``loss`` holds the
-    module's share."""
+    module's share.  Under ``cfg.block_diffusion`` the second data layer is
+    ``noised`` (the noised copy of ``tokens``), the program also reads
+    ``weights`` (both from ``noise``), ``logits`` are the noised rows'
+    [B, seq_len, vocabulary] and ``loss`` is the weighted cross-entropy of
+    the masked tokens over all ``B * seq_len``."""
     return _forward(cfg, seq_len)[:4]
 
 
@@ -907,7 +1005,7 @@ def build(cfg=None, seq_len=64, lr=1e-4, beta1=0.9, beta2=0.95,
           epsilon=1e-8):
     """The training graph with Adam and, after it, the balancing rule of
     every router that has a selection bias.  Returns (tokens, labels,
-    loss)."""
+    loss); under ``cfg.block_diffusion`` (tokens, noised, loss)."""
     cfg = cfg or tiny_config()
     tokens, labels, loss, _, routers = _forward(cfg, seq_len)
     fluid.optimizer.Adam(learning_rate=lr, beta1=beta1, beta2=beta2,

@@ -4,14 +4,14 @@ query's keys, grouped-query attention over that selection, the short
 convolution (between two gates where it is a layer's whole mixer, or
 followed by SiLU, a bias a channel before it where the op has one, in front
 of a linear attention or a state-space scan), the gated delta rule, the
-selective state-space scan, and the share of a routed expert layer that the
-experts held here give.
+selective state-space scan, the share of a routed expert layer that the
+experts held here give, and a mean in which every row counts by a weight.
 
 Each is a pure JAX function; gradients go through the generic vjp path
 (``ops/registry.py``) except where noted.  ``sparse_attention`` and
 ``moe_experts`` count which path each call took at lowering
 (``ops.sparse_attention.calls{topk,seq,path}`` and, under a causal window,
-``window``; ``ops.moe.calls{held,routed,path}`` and, for a router that is
+``window``, under the block rule of diffusion over blocks ``block``; ``ops.moe.calls{held,routed,path}`` and, for a router that is
 not the softmax one, ``score``, and, where the layer walks its sorted rows
 in slabs of fewer than all (``parallel/moe.slab_rows``), ``slab``;
 ``...declined{why}`` for every fallback;
@@ -60,7 +60,10 @@ kernel call it traces: the column tile that product took;
 ``ops.sparse_attention.tiles{kernel,kind}``, which
 ``ops/pallas_sparse_flash.py`` counts the same way for every attention
 kernel call it traces: the live tiles one head walks there,
-``kind="interior"`` where no positional mask is made and ``"edge"``).
+``kind="interior"`` where no positional mask is made and ``"edge"``;
+``ops.weighted_mean.live_rows`` and ``.rows``, STEP GAUGES that
+``weighted_mean`` publishes from the weights it is fed: the rows that bear
+weight, of how many).
 """
 
 from __future__ import annotations
@@ -101,24 +104,31 @@ def rms_norm_op(ctx):
             .astype(x.dtype)}
 
 
-def rotary(x, theta, start=0, dims=0, interleaved=False, inv_freq=None):
+def rotary(x, theta, start=0, dims=0, interleaved=False, inv_freq=None,
+           period=0):
     """x: [B, T, H, D]; position t (the index along axis 1) rotates the
     pair (i, i + n/2) of the ``n = dims`` columns from ``start`` on
     (``dims`` 0: to the head's end) by ``t * theta^(-2i/n)``: the
     rotate-half form.  ``interleaved``: the pair is (2i, 2i + 1) instead.
     ``inv_freq``: the n/2 frequencies themselves, in ``theta``'s place (a
-    table that a scaling rule blended).  Columns outside the part pass."""
+    table that a scaling rule blended).  Columns outside the part pass.
+    ``period`` p > 0: axis 1 holds copies of a sequence of p tokens side by
+    side, and index i has position ``i mod p``."""
     d = x.shape[-1]
     n = dims or d - start
     if (start, n) != (0, d):
         part = rotary(x[..., start:start + n], theta, 0, 0, interleaved,
-                      inv_freq)
+                      inv_freq, period)
         return jnp.concatenate(
             [x[..., :start], part, x[..., start + n:]], -1)
     t = x.shape[1]
     inv = jnp.float32(theta) ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d) \
         if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    at = jnp.arange(t, dtype=jnp.float32)
+    if period:
+        at = (jnp.arange(t, dtype=jnp.int32)
+              % jnp.int32(period)).astype(jnp.float32)
+    ang = at[:, None] * inv[None, :]
     if interleaved:
         cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
         pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
@@ -140,7 +150,8 @@ def _rotary_attrs(ctx):
                 start=int(ctx.attr("start", 0)),
                 dims=int(ctx.attr("dims", 0)),
                 interleaved=bool(ctx.attr("interleaved", False)),
-                inv_freq=tuple(table) if table else None)
+                inv_freq=tuple(table) if table else None,
+                period=int(ctx.attr("period", 0)))
 
 
 @register_op("rotary_embedding")
@@ -149,7 +160,8 @@ def rotary_embedding_op(ctx):
     _count("ops.rotary.calls",
            dims=how["dims"] or x.shape[-1] - how["start"],
            pairing="interleaved" if how["interleaved"] else "half",
-           scaled=int(how["inv_freq"] is not None))
+           scaled=int(how["inv_freq"] is not None),
+           **({"period": how["period"]} if how["period"] else {}))
     return {"Out": rotary(x, **how)}
 
 
@@ -257,13 +269,33 @@ def sparse_indexer_grad(ctx):
             for slot in ctx.outputs_spec}
 
 
-def blocked_attention(q, k, v, sel, scale, block=512, window=0):
+def rule_keeps(q0, q1, tokens, block):
+    """The keys that the queries ``[q0, q1)`` of ONE copy count under the
+    block rule, as ``(first key, last key + 1, keep [q1 - q0, keys])``
+    spans of axis T, in the order their columns lie side by side.  With
+    ``B(i) = (i mod tokens) // block``: a clean query counts the clean keys
+    of blocks ``<= B(t)``, its own block whole; a noised one the clean keys
+    of blocks ``< B(t)`` and the noised keys of block ``B(t)``."""
+    noised, at = q0 >= tokens, q0 % tokens
+    qb = ((at + jnp.arange(q1 - q0)) // block)[:, None]
+    end = min(-(-(at + q1 - q0) // block) * block, tokens)
+    kb = (jnp.arange(end) // block)[None]
+    if not noised:
+        return [(0, end, kb <= qb)]
+    own = at // block * block
+    return [(0, end, kb < qb),
+            (tokens + own, tokens + end, kb[:, own:] == qb)]
+
+
+def blocked_attention(q, k, v, sel, scale, block=512, window=0, rule=None):
     """The XLA path of ``sparse_attention``: query tiles against the keys
     up to the tile's end (under a ``window``, from the first key that the
     tile's first query still sees), the selection and the window as masks,
     the window's made from positions and never a [B, T, T] tensor; each
     tile a checkpoint so that the backward holds one tile's [Hq, bq, T]
-    scores at a time."""
+    scores at a time.  ``rule`` = (tokens a copy, block length): T holds a
+    clean and a noised copy of a sequence, a tile lies in one of them, and
+    the keys that count are ``rule_keeps``'s, in one softmax."""
     from ..fluid import amp
 
     b, hq, t, d = q.shape
@@ -279,10 +311,23 @@ def blocked_attention(q, k, v, sel, scale, block=512, window=0):
         p = jax.nn.softmax(s, axis=-1)
         return amp.einsum("bgrqs,bgsd->bgrqd", p.astype(vt.dtype), vt)
 
-    bq = min(block, t)
+    def cut(x, spans):
+        parts = [x[:, :, k0:k1] for k0, k1, _ in spans]
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 2)
+
+    # a copy's tiles end with the copy (without a rule T is the one copy)
+    copy = rule[0] if rule else t
+    bq = min(block, copy)
     outs = []
-    for q0 in range(0, t, bq):
-        q1 = min(q0 + bq, t)
+    for q0, q1 in ((c + a, c + min(a + bq, copy))
+                   for c in range(0, t, copy) for a in range(0, copy, bq)):
+        if rule:
+            spans = rule_keeps(q0, q1, *rule)
+            keep = jnp.concatenate([m for _, _, m in spans], 1)
+            keep = jnp.broadcast_to(keep[None], (b,) + keep.shape)
+            outs.append(tile(qg[:, :, :, q0:q1], cut(k, spans),
+                             cut(v, spans), keep))
+            continue
         k0 = max(0, q0 - window + 1) if window else 0
         qpos = (q0 + jnp.arange(q1 - q0))[:, None]
         kpos = jnp.arange(k0, q1)[None]
@@ -297,7 +342,7 @@ def blocked_attention(q, k, v, sel, scale, block=512, window=0):
     return jnp.concatenate(outs, axis=3).reshape(b, hq, t, dv).astype(q.dtype)
 
 
-def _attention_path(ctx, q, k, v, sel, window, count):
+def _attention_path(ctx, q, k, v, sel, window, rule, count):
     """'pallas' where the flash gate is open (``kernel_choice.gate``: the
     environment switch where set, else the platform; the op states no
     wish) and the kernels take the operands, else 'xla'; counted where
@@ -307,7 +352,7 @@ def _attention_path(ctx, q, k, v, sel, window, count):
 
     path = "xla"
     if kernel_choice.gate("flash"):
-        why = psf.supported(q, k, sel, window, v)
+        why = psf.supported(q, k, sel, window, v, rule)
         if not why:
             path = "pallas"
         elif count:
@@ -317,28 +362,43 @@ def _attention_path(ctx, q, k, v, sel, window, count):
         stated = int(ctx.attr("window", 0))
         _count("ops.sparse_attention.calls", path=path,
                topk=ctx.attr("topk", 0), seq=q.shape[2],
-               **({"window": stated} if stated else {}))
+               **({"window": stated} if stated else {}),
+               **({"block": rule[1]} if rule else {}))
     return path
 
 
 def _attention_operands(ctx):
-    """(q, k, v, sel, scale, window); a window that reaches every key
-    ``s <= t`` is none."""
+    """(q, k, v, sel, scale, window, rule); a window that reaches every key
+    ``s <= t`` is none; ``rule``: None, or (tokens a copy, block length) of
+    the block rule, which goes with neither a selection nor a window."""
     q, k, v = ctx.input("Q"), ctx.input("K"), ctx.input("V")
     sel = ctx.input("Sel") if ctx.has_input("Sel") else None
     window = int(ctx.attr("window", 0))
     if window < 0:
         raise ValueError(f"sparse_attention: window {window} is negative")
+    tokens, block = int(ctx.attr("copy_tokens", 0)), int(ctx.attr("block", 0))
+    rule = (tokens, block) if tokens or block else None
+    if rule and (window or sel is not None or block < 1
+                 or q.shape[2] != 2 * tokens or tokens % block):
+        raise ValueError(
+            f"sparse_attention: the block rule over two copies of {tokens} "
+            f"tokens in blocks of {block} takes {2 * tokens} positions in "
+            f"whole blocks, not {q.shape[2]}, and neither a selection nor a "
+            "window")
     return (q, k, v, sel, ctx.attr("scale", 0.0) or q.shape[-1] ** -0.5,
-            0 if window >= q.shape[2] else window)
+            0 if window >= q.shape[2] else window, rule)
 
 
 @register_op("sparse_attention", no_grad_inputs=("Sel",))
 def sparse_attention_op(ctx):
-    """Causal grouped-query attention, optionally over a per-query
-    selection and, with the attr ``window`` (0: none), over the last
-    ``window`` keys only: key s counts for query t iff ``0 <= t - s <
-    window``.  Q: [B, Hq, T, D]; K: [B, Hkv, T, D]; V: [B, Hkv, T, Dv] (Out:
+    """Grouped-query attention, causal unless the block rule is stated:
+    optionally over a per-query selection and, with the attr ``window``
+    (0: none), over the last ``window`` keys only: key s counts for query t
+    iff ``0 <= t - s < window``.  With the attrs ``copy_tokens`` L and
+    ``block``, T = 2L holds a clean and a noised copy of a sequence side by
+    side and the keys that count are the block rule's (``rule_keeps``),
+    which is NOT causal: a query sees the later tokens of its own block.
+    Q: [B, Hq, T, D]; K: [B, Hkv, T, D]; V: [B, Hkv, T, Dv] (Out:
     [B, Hq, T, Dv]); Sel: [B, T, T] int8 or absent.  The Pallas kernels
     where the flash gate is open and they take the operands (``Dv = D``
     among the rest), else the blocked XLA path.  Lse ([B, Hq, T, 1]
@@ -346,11 +406,12 @@ def sparse_attention_op(ctx):
     the XLA path, whose backward is the generic vjp."""
     from . import pallas_sparse_flash as psf
 
-    q, k, v, sel, scale, window = _attention_operands(ctx)
-    if _attention_path(ctx, q, k, v, sel, window, count=True) == "pallas":
-        out, lse = psf.forward(q, k, v, sel, scale, window=window)
+    q, k, v, sel, scale, window, rule = _attention_operands(ctx)
+    if _attention_path(ctx, q, k, v, sel, window, rule, True) == "pallas":
+        out, lse = psf.forward(q, k, v, sel, scale, window=window, rule=rule)
         return {"Out": out, "Lse": lse}
-    return {"Out": blocked_attention(q, k, v, sel, scale, window=window),
+    return {"Out": blocked_attention(q, k, v, sel, scale, window=window,
+                                     rule=rule),
             "Lse": jnp.zeros(q.shape[:3] + (1,), jnp.float32)}
 
 
@@ -362,16 +423,44 @@ def sparse_attention_grad(ctx):
     from . import pallas_sparse_flash as psf
     from . import registry
 
-    q, k, v, sel, scale, window = _attention_operands(ctx)
-    if _attention_path(ctx, q, k, v, sel, window, count=False) != "pallas":
+    q, k, v, sel, scale, window, rule = _attention_operands(ctx)
+    if _attention_path(ctx, q, k, v, sel, window, rule, False) != "pallas":
         return registry.run_grad_generic(
             registry.get_op_def("sparse_attention"), ctx)
     dq, dk, dv = psf.backward(q, k, v, sel, ctx.input("Out"),
                               ctx.input("Lse"),
                               ctx.input("Out@GRAD").astype(q.dtype), scale,
-                              window=window)
+                              window=window, rule=rule)
     grads = {"Q@GRAD": dq, "K@GRAD": dk, "V@GRAD": dv}
     return {s: g for s, g in grads.items() if s in ctx.outputs_spec}
+
+
+@register_op("weighted_mean", no_grad_inputs=("Weight",))
+def weighted_mean_op(ctx):
+    """``Out = sum(X * Weight) / X.size``: a mean over ALL rows of values
+    that count by a weight each (0: not at all), as a loss over the masked
+    tokens of a diffusion step is.  X and Weight of one size, float32 sums;
+    Out: [1].  Publishes how many rows bear weight and how many there are
+    as the step gauges ``ops.weighted_mean.live_rows`` and ``.rows``."""
+    from .. import observe
+
+    x = ctx.input("X")
+    w = ctx.input("Weight").reshape(x.shape).astype(jnp.float32)
+    observe.step_gauge("ops.weighted_mean.live_rows",
+                       jnp.sum((w > 0).astype(jnp.float32)))
+    observe.step_gauge("ops.weighted_mean.rows", jnp.float32(x.size))
+    return {"Out": (jnp.sum(x.astype(jnp.float32) * w)
+                    / jnp.float32(x.size)).reshape(1).astype(x.dtype)}
+
+
+@register_grad("weighted_mean")
+def weighted_mean_grad(ctx):
+    """``dX = dOut * Weight / X.size``; the weight gets none.  Written out,
+    so that the forward (and its gauges) is not traced a second time."""
+    x = ctx.input("X")
+    w = ctx.input("Weight").reshape(x.shape).astype(jnp.float32)
+    dout = ctx.input("Out@GRAD").astype(jnp.float32).reshape(())
+    return {"X@GRAD": (dout * w / jnp.float32(x.size)).astype(x.dtype)}
 
 
 def causal_filter(z, w):

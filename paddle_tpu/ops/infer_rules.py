@@ -22,6 +22,8 @@ dtype) | None]}`` (None = unknown), or raise ``InferMismatch``.
 
 from __future__ import annotations
 
+import math
+
 import numpy as np
 
 from .registry import InferMismatch, register_infer
@@ -174,6 +176,17 @@ def infer_softmax_xent(op, ins):
 @register_infer("mean")
 def infer_mean(op, ins):
     x = _in(ins, "X")
+    return {"Out": [((1,), x[1]) if x is not None else None]}
+
+
+@register_infer("weighted_mean")
+def infer_weighted_mean(op, ins):
+    x, w = _in(ins, "X"), _in(ins, "Weight")
+    if x is not None and w is not None and \
+            math.prod(x[0]) != math.prod(w[0]):
+        raise InferMismatch(
+            f"weighted_mean: {_names(op, 'Weight')} {list(w[0])} is not a "
+            f"weight for every value of {_names(op, 'X')} {list(x[0])}")
     return {"Out": [((1,), x[1]) if x is not None else None]}
 
 
@@ -509,6 +522,10 @@ def infer_rotary_embedding(op, ins):
             f"[batch, positions, heads, an even head width]"
             + (f", of which it turns the {part} columns from {start} on"
                if start or dims else ""))
+    if int(op.attr("period", 0)) < 0:
+        raise InferMismatch(
+            f"rotary_embedding: period {op.attr('period')} is negative (0: "
+            f"one sequence along axis 1)")
     table = op.attr("inv_freq", None)
     if table and len(table) != part // 2:
         raise InferMismatch(
@@ -572,6 +589,15 @@ def infer_sparse_attention(op, ins):
         raise InferMismatch(
             f"sparse_attention: window {op.attr('window')} is negative "
             f"(0: none; else the last `window` keys s <= t)")
+    tokens, block = int(op.attr("copy_tokens", 0)), int(op.attr("block", 0))
+    if (tokens or block) and (
+            block < 1 or tokens % block or q[0][2] != 2 * tokens
+            or sel is not None or int(op.attr("window", 0))):
+        raise InferMismatch(
+            f"sparse_attention: the block rule over two copies of {tokens} "
+            f"tokens in blocks of {block} takes {_names(op, 'Q')} "
+            f"{list(q[0])} with {2 * tokens} positions in whole blocks, and "
+            f"neither a selection nor a window")
     out = q if v is None else (tuple(q[0][:3]) + (v[0][3],), q[1])
     return {"Out": [out], "Lse": [lse]}
 

@@ -1,9 +1,13 @@
-"""Grouped-query flash attention over a per-query selection of keys, as
-Pallas TPU kernels: forward, dQ and dK/dV.
+"""Grouped-query flash attention over the keys a static rule or a
+per-query selection lets count, as Pallas TPU kernels: forward, dQ and
+dK/dV, in three kinds: causal (under a selection where there is one), causal
+under a window, and the block rule of diffusion over blocks, which is NOT
+causal (the last section below).
 
 A sibling of ``pallas_flash`` (equal head counts, key-padding bias) for the
 decoder path: ``hq`` query heads read ``hkv = hq / group`` key-value heads
-(query head h reads head ``h // group``), attention is causal, and an
+(query head h reads head ``h // group``), attention is causal in the first
+two kinds, and an
 optional selection ``sel`` ([B, T, T] int8, 1 where query t attends key s;
 it already holds causality) masks the scores inside the kernels, so no
 [H, T, T] tensor ever reaches HBM.  K/V are never repeated in HBM either:
@@ -63,9 +67,36 @@ the running maximum's first value, so its exponential is an exact 0 and the
 forward selects once.  The row statistics leave the kernel as the
 ``[.., T, 1]`` log-sum-exp the backward kernels read.
 
+The block rule (``rule`` = (tokens a copy L, block length), PR 65): T = 2L
+holds a clean copy of a sequence and then a noised one, and with ``B(i) = (i
+mod L) // block`` a clean query counts the clean keys of blocks ``<= B(t)``
+(its own block whole, the later tokens of it too), a noised query the clean
+keys of blocks ``< B(t)`` and the noised keys of block ``B(t)``, in one
+softmax; no clean query counts a noised key.  The copies are cut into tiles
+alike (``rule_tiles``), and a block is a power of two that no tile
+straddles, so every tile is dead, INTERIOR (a clean key tile wholly before
+the query tile's place: no mask at all) or one of three EDGE tiles whose
+rows and columns start at the same place of their copies: clean query tile
+j against clean tile j (``B(s) <= B(t)``), noised query tile j against
+clean tile j (``B(s) < B(t)``) and against its own noised tile (``B(s) =
+B(t)``: ``block`` live keys a row).  Only the live tiles are grid steps:
+the grid is (heads, steps) and a scalar-prefetch table ``[5, steps]`` int32
+(``rule_walk``, ``_walk_table``), the call's first operand, names for each
+step the tile that stays, the tile that streams, the mask and whether the
+step is the first or the last of its resident tile, where the accumulators
+start and are written out; dK/dV walks the transpose, and under each key
+tile the query heads of its group in turn.  80 steps a head at 4,096 tokens
+a copy in tiles of 512 (56 interior, 24 edge) where causal attention over
+the 8,192 positions walks 136 and its grid has 256.  One kernel, two
+bodies: the edge body makes its compare from the step's mask (two scalar
+bounds on how many blocks the key lies before the query), so the three
+edge kinds share it.
+
 The kernels carry names of their own (``sparse_flash_fwd``,
 ``sparse_flash_dq``, ``sparse_flash_dkv``; with a window
-``window_flash_fwd``, ``window_flash_dq``, ``window_flash_dkv``).
+``window_flash_fwd``, ``window_flash_dq``, ``window_flash_dkv``; under the
+block rule ``blockdiff_flash_fwd``, ``blockdiff_flash_dq``,
+``blockdiff_flash_dkv``).
 """
 
 from __future__ import annotations
@@ -102,10 +133,12 @@ def band_tiles(window, blk, n):
     return min(-(-(window - 1) // blk) + 1, n)
 
 
-def supported(q, k, sel, window=0, v=None) -> str:
+def supported(q, k, sel, window=0, v=None, rule=None) -> str:
     """'' when the kernels take these operands, else why not.  ``v`` (None:
     as ``k``) has k's batch, heads and length, and k's width too: one head
-    width is what the kernels' tiles are cut to."""
+    width is what the kernels' tiles are cut to.  ``rule``: (tokens a copy,
+    block length) of the block rule; a block is a power of two that no tile
+    of a copy straddles."""
     b, hq, t, d = q.shape
     if window and sel is not None:
         return "window_selection"
@@ -119,6 +152,13 @@ def supported(q, k, sel, window=0, v=None) -> str:
         return "heads"
     if sel is not None and tuple(sel.shape) != (b, t, t):
         return "selection"
+    if rule:
+        tokens, block = rule
+        if window or sel is not None or t != 2 * tokens:
+            return "rule"
+        if block < 1 or block & (block - 1) or _block(tokens) % block:
+            return "block"
+        t = tokens
     if t % 8 or _block(t) % 8:
         return "ragged"
     return ""
@@ -221,6 +261,39 @@ def _probabilities(s, lse, keep):
     return p if keep is None else jnp.where(keep, p, jnp.float32(0.0))
 
 
+def _attend(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, scale, keep_of):
+    """One live tile of the forward: its scores into the running maximum,
+    sum and accumulator.  ``keep_of(shape)``: the tile's mask, or None."""
+    s = _scores(q_ref[0], k_ref[0], scale)
+    keep = keep_of(s.shape)
+    if keep is not None:
+        s = jnp.where(keep, s, jnp.float32(MASKED))
+    m = m_ref[:]
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    # a cut score is under every m_new: its exp is an exact 0
+    p = jnp.exp(s - _lanes(m_new, s.shape[1]))
+    corr = jnp.exp(m - m_new)
+    m_ref[:] = m_new
+    l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[:] = acc_ref[:] * _lanes(corr, acc_ref.shape[1]) \
+        + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+
+def _fwd_init(m_ref, l_ref, acc_ref):
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+
+
+def _fwd_flush(o_ref, lse_ref, m_ref, l_ref, acc_ref):
+    l = jnp.maximum(l_ref[:], jnp.float32(1e-30))
+    o_ref[0] = (acc_ref[:] / _lanes(l, acc_ref.shape[1])).astype(
+        o_ref.dtype)
+    lse_ref[0] = (m_ref[:] + jnp.log(l))[:, :1]
+
+
 def _fwd_kernel(*refs, scale, n_k, has_sel, window=0):
     if window:
         refs = refs[1:]                 # the band's table: the index maps'
@@ -233,37 +306,31 @@ def _fwd_kernel(*refs, scale, n_k, has_sel, window=0):
     ki, offsets, live, dist = _q_side_step(q_ref, n_k, window)
     reach = interior_reach(window, q_ref.shape[1], n_k, has_sel)
 
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    pl.when(ki == 0)(lambda: _fwd_init(m_ref, l_ref, acc_ref))
 
     def attend(offsets):
-        s = _scores(q_ref[0], k_ref[0], scale)
-        keep = _keep(sel_ref, s.shape, offsets, window)
-        if keep is not None:
-            s = jnp.where(keep, s, jnp.float32(MASKED))
-        m = m_ref[:]
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        # a cut score is under every m_new: its exp is an exact 0
-        p = jnp.exp(s - _lanes(m_new, s.shape[1]))
-        corr = jnp.exp(m - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * _lanes(corr, acc_ref.shape[1]) \
-            + jax.lax.dot_general(
-                p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+        _attend(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, scale,
+                lambda shape: _keep(sel_ref, shape, offsets, window))
 
     _by_kind(live, dist, reach, offsets, attend)
 
-    @pl.when(ki == n_k - 1)
-    def _flush():
-        l = jnp.maximum(l_ref[:], jnp.float32(1e-30))
-        o_ref[0] = (acc_ref[:] / _lanes(l, acc_ref.shape[1])).astype(
-            o_ref.dtype)
-        lse_ref[0] = (m_ref[:] + jnp.log(l))[:, :1]
+    pl.when(ki == n_k - 1)(
+        lambda: _fwd_flush(o_ref, lse_ref, m_ref, l_ref, acc_ref))
+
+
+def _dq_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc, scale,
+             keep_of):
+    """One live tile of dQ: ``dq_acc += scale * dS k``."""
+    k = k_ref[0]
+    s = _scores(q_ref[0], k, scale)
+    p = _probabilities(s, lse_ref[0], keep_of(s.shape))
+    dp = jax.lax.dot_general(
+        do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    ds = p * (dp - delta_ref[0])
+    dq_acc[:] += jnp.float32(scale) * jax.lax.dot_general(
+        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 def _dq_kernel(*refs, scale, n_k, has_sel, window=0):
@@ -283,23 +350,33 @@ def _dq_kernel(*refs, scale, n_k, has_sel, window=0):
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     def accum(offsets):
-        k = k_ref[0]
-        s = _scores(q_ref[0], k, scale)
-        p = _probabilities(s, lse_ref[0],
-                           _keep(sel_ref, s.shape, offsets, window))
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0])
-        dq_acc[:] += jnp.float32(scale) * jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        _dq_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc,
+                 scale, lambda shape: _keep(sel_ref, shape, offsets, window))
 
     _by_kind(live, dist, reach, offsets, accum)
 
     @pl.when(ki == n_k - 1)
     def _flush():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+
+def _dkv_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc, dv_acc,
+              scale, keep_of):
+    """One live tile of dK/dV: ``dv_acc += P^T dO``, ``dk_acc += scale *
+    dS^T q``."""
+    q, do = q_ref[0], do_ref[0]
+    s = _scores(q, k_ref[0], scale)                      # [bq, bk]
+    p = _probabilities(s, lse_ref[0], keep_of(s.shape))
+    dv_acc[:] += jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)              # [bk, d]
+    dp = jax.lax.dot_general(
+        do, v_ref[0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    ds = p * (dp - delta_ref[0])
+    dk_acc[:] += jnp.float32(scale) * jax.lax.dot_general(
+        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 def _dkv_kernel(*refs, scale, n_q, n_inner, has_sel, window=0, n_tiles=0):
@@ -331,20 +408,9 @@ def _dkv_kernel(*refs, scale, n_q, n_inner, has_sel, window=0, n_tiles=0):
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     def accum(offsets):
-        q, do = q_ref[0], do_ref[0]
-        s = _scores(q, k_ref[0], scale)                      # [bq, bk]
-        p = _probabilities(s, lse_ref[0],
-                           _keep(sel_ref, s.shape, offsets, window))
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bk, d]
-        dp = jax.lax.dot_general(
-            do, v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0])
-        dk_acc[:] += jnp.float32(scale) * jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        _dkv_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc,
+                  dv_acc, scale,
+                  lambda shape: _keep(sel_ref, shape, offsets, window))
 
     _by_kind(live, qi - kj, reach,
              (qi * jnp.int32(blk), kj * jnp.int32(blk)), accum)
@@ -401,6 +467,166 @@ def _band_tables(n, band):
     j, s = np.arange(n)[:, None], np.arange(band)[None, :]
     return (jnp.asarray(np.maximum(j - (band - 1) + s, 0), jnp.int32),
             jnp.asarray(np.minimum(j + s, n - 1), jnp.int32))
+
+
+# -- block diffusion: two copies of a sequence under one rule ------------
+#
+# A step of the walk is one live tile.  Its mask (``STEP_MASK`` row of the
+# walk's table): INTERIOR, none at all; or one of three compares between
+# the BLOCK of the query and the block of the key.  In every edge tile the
+# rows and the columns start at the same position of their copies, so the
+# blocks are told from the tile's own row and column numbers.
+INTERIOR, EDGE_LE, EDGE_LT, EDGE_EQ = 0, 1, 2, 3
+#: rows of a walk's table [5, steps] int32, the scalar-prefetch operand:
+#: the tile that stays (the query tile of forward and dQ, the key tile of
+#: dK/dV), the tile that streams past it, the query head of the group
+#: (dK/dV; else 0), the step's mask, and FIRST | LAST where the step is
+#: the first or last of its resident tile
+RESIDENT, STREAMED, MEMBER, STEP_MASK, FLAGS = range(5)
+FIRST, LAST = 1, 2
+
+
+def rule_tiles(tokens):
+    """(tile, tiles a copy) under the block rule: the copies are cut alike,
+    so no tile holds positions of both."""
+    blk = _block(tokens)
+    return blk, tokens // blk
+
+
+def rule_walk(n):
+    """[(query tile, key tile, mask)] of the live tiles over two copies of
+    ``n`` tiles each, query tile by query tile: clean tile j reads the clean
+    tiles up to its own (whole blocks of its own: ``B(s) <= B(t)``); noised
+    tile ``n + j`` the clean tiles before ``j``, of clean tile ``j`` the
+    blocks BEFORE the query's, and of its own tile the query's block."""
+    steps = []
+    for j in range(n):
+        steps += [(j, s, INTERIOR) for s in range(j)] + [(j, j, EDGE_LE)]
+    for j in range(n):
+        steps += [(n + j, s, INTERIOR) for s in range(j)] \
+            + [(n + j, j, EDGE_LT), (n + j, n + j, EDGE_EQ)]
+    return steps
+
+
+def rule_tile_counts(tokens):
+    """(interior, edge) live tiles a head under the block rule: of ``n``
+    tiles a copy ``j`` interior ones for clean and for noised query tile j,
+    one edge tile for a clean and two for a noised one; 56 and 24 at 4,096
+    tokens a copy in tiles of 512, where causal attention over the 8,192
+    positions walks 136."""
+    n = rule_tiles(tokens)[1]
+    return n * (n - 1), 3 * n
+
+
+def _walk_table(n, group=1, by_key=False):
+    """The walk as a kernel's table: query-major for forward and dQ; for
+    dK/dV key-major, and under each key tile every query head of the group
+    in turn."""
+    import numpy as np
+
+    walk = rule_walk(n)
+    if not by_key:
+        rows = [(q, k, 0, mask) for q, k, mask in walk]
+    else:
+        rows = [(k, q, g, mask) for tile in range(2 * n)
+                for g in range(group)
+                for q, k, mask in walk if k == tile]
+    table = np.zeros((5, len(rows)), np.int32)
+    table[:4] = np.asarray(rows, np.int32).T
+    stays = table[RESIDENT]
+    table[FLAGS] = FIRST * np.r_[True, stays[1:] != stays[:-1]] \
+        + LAST * np.r_[stays[1:] != stays[:-1], True]
+    return jnp.asarray(table)
+
+
+def _block_keep(shape, mask, shift):
+    """[bq, bk] bool of an edge tile: how many blocks the key's lies before
+    the query's (blocks of ``1 << shift`` positions) against the bounds of
+    the step's ``mask``: 0 or more, 1 or more, exactly 0."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    back = jax.lax.shift_right_logical(rows, jnp.int32(shift)) \
+        - jax.lax.shift_right_logical(cols, jnp.int32(shift))
+    least = jnp.where(mask == EDGE_LT, jnp.int32(1), jnp.int32(0))
+    most = jnp.where(mask == EDGE_EQ, jnp.int32(0), jnp.int32(shape[0]))
+    return jnp.logical_and(back >= least, back <= most)
+
+
+def _by_mask(mask, shift, body):
+    """``body(keep_of)`` in one of two bodies: an interior step makes no
+    mask, an edge step the compare its ``mask`` names."""
+    pl.when(mask == INTERIOR)(lambda: body(lambda shape: None))
+    pl.when(mask != INTERIOR)(
+        lambda: body(lambda shape: _block_keep(shape, mask, shift)))
+
+
+def _rule_fwd_kernel(walk_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
+                     l_ref, acc_ref, *, scale, shift):
+    step = pl.program_id(1)
+    flags = walk_ref[FLAGS, step]
+    pl.when((flags & FIRST) != 0)(lambda: _fwd_init(m_ref, l_ref, acc_ref))
+    _by_mask(walk_ref[STEP_MASK, step], shift, lambda keep_of: _attend(
+        q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, scale, keep_of))
+    pl.when((flags & LAST) != 0)(
+        lambda: _fwd_flush(o_ref, lse_ref, m_ref, l_ref, acc_ref))
+
+
+def _rule_dq_kernel(walk_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                    delta_ref, dq_ref, dq_acc, *, scale, shift):
+    step = pl.program_id(1)
+    flags = walk_ref[FLAGS, step]
+
+    @pl.when((flags & FIRST) != 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    _by_mask(walk_ref[STEP_MASK, step], shift, lambda keep_of: _dq_tile(
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc, scale,
+        keep_of))
+
+    @pl.when((flags & LAST) != 0)
+    def _flush():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+
+def _rule_dkv_kernel(walk_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                     delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
+                     shift):
+    step = pl.program_id(1)
+    flags = walk_ref[FLAGS, step]
+
+    @pl.when((flags & FIRST) != 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    _by_mask(walk_ref[STEP_MASK, step], shift, lambda keep_of: _dkv_tile(
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc, dv_acc,
+        scale, keep_of))
+
+    @pl.when((flags & LAST) != 0)
+    def _flush():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _rule_maps(group):
+    """Index maps of the walked grids (heads, step): the tile that stays
+    and the tile that streams, each of the query's or of the key-value
+    head, the walk's table as the last argument."""
+    def own(row):
+        return lambda i, s, walk: block_index(i, walk[row, s], 0)
+
+    def of_kv_head(row):        # forward, dQ: a query head's key tiles
+        return lambda i, s, walk: block_index(
+            jax.lax.div(i, jnp.int32(group)), walk[row, s], 0)
+
+    def of_member(row):         # dK/dV: the group's query heads in turn
+        return lambda i, s, walk: block_index(
+            i * jnp.int32(group) + walk[MEMBER, s], walk[row, s], 0)
+
+    return own, of_kv_head, of_member
+
 
 
 def _call(kernel, name, table, args, *, grid, in_specs, out_specs,
@@ -551,38 +777,131 @@ def _backward(q, k, v, sel, out, lse, do, scale, interpret, window=0):
             dv.reshape(b, hkv, t, d))
 
 
-def forward(q, k, v, sel=None, scale=None, interpret=None, window=0):
-    """(out, lse [B, Hq, T, 1] float32): what ``backward`` needs kept."""
+def _rule_forward(q, k, v, scale, interpret, rule):
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, hq, t, d = q.shape
+    hkv = k.shape[1]
+    tokens, block = rule
+    blk, n = rule_tiles(tokens)
+    walk = _walk_table(n)
+    own, of_kv_head, _ = _rule_maps(hq // hkv)
+    stays, streams = own(RESIDENT), of_kv_head(STREAMED)
+    out, lse = _call(
+        functools.partial(_rule_fwd_kernel, scale=scale,
+                          shift=block.bit_length() - 1),
+        "blockdiff_flash_fwd", walk,
+        [q.reshape(b * hq, t, d), k.reshape(b * hkv, t, d),
+         v.reshape(b * hkv, t, d)],
+        grid=(b * hq, walk.shape[1]),
+        in_specs=[pl.BlockSpec((1, blk, d), stays),
+                  pl.BlockSpec((1, blk, d), streams),
+                  pl.BlockSpec((1, blk, d), streams)],
+        out_specs=[pl.BlockSpec((1, blk, d), stays),
+                   pl.BlockSpec((1, blk, 1), stays)],
+        out_shape=[jax.ShapeDtypeStruct((b * hq, t, d), q.dtype),
+                   jax.ShapeDtypeStruct((b * hq, t, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((blk, LANE), jnp.float32),
+                        pltpu.VMEM((blk, LANE), jnp.float32),
+                        pltpu.VMEM((blk, d), jnp.float32)],
+        interpret=interpret, tiles=rule_tile_counts(tokens))
+    return out.reshape(b, hq, t, d), lse.reshape(b, hq, t, 1)
+
+
+def _rule_backward(q, k, v, out, lse, do, scale, interpret, rule):
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, hq, t, d = q.shape
+    hkv = k.shape[1]
+    group = hq // hkv
+    tokens, block = rule
+    blk, n = rule_tiles(tokens)
+    shift, tiles = block.bit_length() - 1, rule_tile_counts(tokens)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1, keepdims=True)
+    args = (q.reshape(b * hq, t, d), k.reshape(b * hkv, t, d),
+            v.reshape(b * hkv, t, d), do.reshape(b * hq, t, d),
+            lse.reshape(b * hq, t, 1), delta.reshape(b * hq, t, 1))
+    own, of_kv_head, of_member = _rule_maps(group)
+
+    def specs(q_side, kv_side):
+        return [pl.BlockSpec((1, blk, d), q_side),
+                pl.BlockSpec((1, blk, d), kv_side),
+                pl.BlockSpec((1, blk, d), kv_side),
+                pl.BlockSpec((1, blk, d), q_side),
+                pl.BlockSpec((1, blk, 1), q_side),
+                pl.BlockSpec((1, blk, 1), q_side)]
+
+    walk = _walk_table(n)
+    dq = _call(
+        functools.partial(_rule_dq_kernel, scale=scale, shift=shift),
+        "blockdiff_flash_dq", walk, args, grid=(b * hq, walk.shape[1]),
+        in_specs=specs(own(RESIDENT), of_kv_head(STREAMED)),
+        out_specs=pl.BlockSpec((1, blk, d), own(RESIDENT)),
+        out_shape=jax.ShapeDtypeStruct((b * hq, t, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
+        interpret=interpret, tiles=tiles)
+    # dK/dV: the key tile stays, the tiles of the group's query heads that
+    # read it stream past: the walk's transpose
+    walk = _walk_table(n, group, by_key=True)
+    dk, dv = _call(
+        functools.partial(_rule_dkv_kernel, scale=scale, shift=shift),
+        "blockdiff_flash_dkv", walk, args, grid=(b * hkv, walk.shape[1]),
+        in_specs=specs(of_member(STREAMED), own(RESIDENT)),
+        out_specs=[pl.BlockSpec((1, blk, d), own(RESIDENT)),
+                   pl.BlockSpec((1, blk, d), own(RESIDENT))],
+        out_shape=[jax.ShapeDtypeStruct((b * hkv, t, d), k.dtype),
+                   jax.ShapeDtypeStruct((b * hkv, t, d), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32),
+                        pltpu.VMEM((blk, d), jnp.float32)],
+        interpret=interpret, tiles=tiles)
+    return (dq.reshape(b, hq, t, d), dk.reshape(b, hkv, t, d),
+            dv.reshape(b, hkv, t, d))
+
+
+def forward(q, k, v, sel=None, scale=None, interpret=None, window=0,
+            rule=None):
+    """(out, lse [B, Hq, T, 1] float32): what ``backward`` needs kept.
+    ``rule``: None, or (tokens a copy, block length) of two copies of a
+    sequence side by side along T (then neither ``sel`` nor ``window``)."""
     scale, interpret = resolve(q, scale, interpret)
+    if rule:
+        return _rule_forward(q, k, v, scale, interpret, tuple(rule))
     return _forward(q, k, v, sel, scale, interpret, window)
 
 
 def backward(q, k, v, sel, out, lse, do, scale=None, interpret=None,
-             window=0):
+             window=0, rule=None):
     """(dq, dk, dv) from the forward's own ``out`` and ``lse``."""
     scale, interpret = resolve(q, scale, interpret)
+    if rule:
+        return _rule_backward(q, k, v, out, lse, do, scale, interpret,
+                              tuple(rule))
     return _backward(q, k, v, sel, out, lse, do, scale, interpret, window)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def sparse_flash_attention(q, k, v, sel=None, scale=None, interpret=None,
-                           window=0):
-    """Causal softmax(scale q k^T) v over the keys ``sel`` selects.  q:
-    [B, Hq, T, D]; k, v: [B, Hkv, T, D], Hq a multiple of Hkv; sel: None
-    (every key s <= t) or [B, T, T] int8, non-trainable; ``window``: 0, or
-    the last ``window`` keys ``s <= t`` only (then no ``sel``)."""
-    return forward(q, k, v, sel, scale, interpret, window)[0]
+                           window=0, rule=None):
+    """softmax(scale q k^T) v over the keys that count.  q: [B, Hq, T, D];
+    k, v: [B, Hkv, T, D], Hq a multiple of Hkv.  Causal: every key s <= t,
+    of those the ones ``sel`` selects ([B, T, T] int8, non-trainable; None:
+    all) or, under ``window``, the last ``window`` only (then no ``sel``).
+    Under ``rule`` = (tokens a copy, block length) T holds a clean and a
+    noised copy of a sequence and the keys that count are the block
+    rule's (the module's docstring), which is not causal."""
+    return forward(q, k, v, sel, scale, interpret, window, rule)[0]
 
 
-def _vjp_fwd(q, k, v, sel, scale, interpret, window):
-    out, lse = forward(q, k, v, sel, scale, interpret, window)
+def _vjp_fwd(q, k, v, sel, scale, interpret, window, rule):
+    out, lse = forward(q, k, v, sel, scale, interpret, window, rule)
     return out, (q, k, v, sel, out, lse)
 
 
-def _vjp_bwd(scale, interpret, window, res, do):
+def _vjp_bwd(scale, interpret, window, rule, res, do):
     q, k, v, sel, out, lse = res
     dq, dk, dv = backward(q, k, v, sel, out, lse, do, scale, interpret,
-                          window)
+                          window, rule)
     dsel = None if sel is None else \
         jnp.zeros(sel.shape, jax.dtypes.float0)
     return dq, dk, dv, dsel

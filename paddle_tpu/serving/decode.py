@@ -321,6 +321,11 @@ class DecodeEngine:
                     self._tick()
             with self._cond:
                 self._cond.notify_all()  # drain() watches progress
+            # a lock is not fair: this thread would take the dispatch lock
+            # again before a thread that waits for it (a hot swap's
+            # snapshot) is scheduled, tick after tick, for as long as a
+            # slot is resident; give the interpreter away once a loop
+            time.sleep(0)
         self._fail_leftovers()
 
     def _reap_abandoned(self):

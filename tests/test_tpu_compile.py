@@ -131,6 +131,25 @@ def _sparse_flash(selected, window=0, t=8192, hkv=4, d=128, hq=32):
     return fn, [((1, hq, t, d), BF16), kv, kv, ((1, t, t), jnp.int8)]
 
 
+def _blockdiff_flash(tokens=4096, block=4, hkv=4, d=128, hq=32):
+    """``sdar_30b_a3b_chat``'s attention: a clean and a noised copy of one
+    sequence of 4,096 tokens side by side, 8,192 positions under the block
+    rule in blocks of 4, 32 query heads over 4 key-value heads of width
+    128, bf16; forward and the two backward kernels, each over its walk's
+    table as the scalar-prefetch operand."""
+
+    def fn(q, k, v):
+        def loss(q, k, v):
+            return pallas_sparse_flash.sparse_flash_attention(
+                q, k, v, None, None, False, 0,
+                (tokens, block)).astype(F32).sum()
+
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    kv = ((1, hkv, 2 * tokens, d), BF16)
+    return fn, [((1, hq, 2 * tokens, d), BF16), kv, kv]
+
+
 def _delta_layer(t=8192, hk=16, hv=32, d=128, taps=4, chunk=64):
     """``qwen3_next_80b_a3b``'s delta mixer between its two plain products,
     at the cell's shapes under the cells' AMP: the four-tap filter and SiLU
@@ -216,6 +235,9 @@ CASES = {
     "window_flash_four_windows": (lambda: _sparse_flash(False, 2048), 3),
     # a window that is no multiple of the tile: one more tile in the band
     "window_flash_ragged": (lambda: _sparse_flash(False, 1000), 3),
+    "blockdiff_flash": (lambda: _blockdiff_flash(), 3),
+    "blockdiff_flash_blocks_of_16_group_of_one": (
+        lambda: _blockdiff_flash(2048, 16, 8, 128, 8), 3),
     "flash_fwd_causal": (lambda: _flash(True, False), 1),     # decoder self
     "flash_fwd_key_bias": (lambda: _flash(False, True), 1),   # encoder/cross
     "flash_bwd_causal": (lambda: _flash_bwd(True, False), 3),
@@ -411,6 +433,41 @@ def test_flash_signatures_are_the_benchmarks(topo, causal, bias):
             ",".join(results) + "<-" + ",".join(operands + key_bias), family
         assert module.flops(call.operands, call.results) == \
             2.0 * matmuls * _BH * T * T * D / (2 if causal else 1), family
+
+
+def test_blockdiff_signatures_are_the_benchmarks(topo):
+    """``sdar_30b_a3b_chat``'s attention with its backward, lowered for the
+    described chip: three kernels with names of their own, the walk's
+    table the first operand ([5, 80] for a query head, [5, 640] for a
+    key-value head of a group of 8: one column a live tile), q [b*hq, 2L,
+    d] the second: what ``chipbench/kernels/blockdiff_flash_*.py`` count
+    their pairs from, ``L (L + 1)`` a head."""
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from chipbench import hlo
+    from chipbench.plugins import load
+
+    fn, shapes = _blockdiff_flash()
+    chip = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    calls = {c.kernel: c for c in
+             hlo.custom_calls(jax.jit(fn).lower(*args).as_text())}
+    families = {"blockdiff_flash_fwd": 2, "blockdiff_flash_dq": 3,
+                "blockdiff_flash_dkv": 4}
+    assert sorted(calls) == sorted(families)
+    signatures = set()
+    for name, matmuls in families.items():
+        call, module = calls[name], load("kernels", name)
+        steps = 640 if name.endswith("dkv") else 80
+        assert call.operands[0] == ((5, steps), "i32"), name
+        assert call.operands[1] == ((32, 8192, 128), "bf16"), name
+        assert module.KERNEL == name
+        assert module.flops(call.operands, call.results) == \
+            2.0 * matmuls * 32 * 4096 * 4097 * 128, name
+        signatures.add(hlo.signature(call))
+    assert len(signatures) == 3     # an event is matched to ONE family
 
 
 @pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))

@@ -88,6 +88,13 @@ def main() -> dict:
         # probation traffic (2 completions incl. the mid-flight one)
         after_swap = eng.generate(prompts[1], 6)
         reg.poll_once()  # settles the promotion off-tick if needed
+        # where the mid-flight request finished before the swap took its
+        # baseline (a slow host), probation is one completion short: serve
+        # more until it settles, or stage 3's poll finds it still running
+        deadline = time.perf_counter() + 20
+        while reg.canary_active() and time.perf_counter() < deadline:
+            eng.generate(prompts[1], 6)
+            reg.poll_once()
         report["serial_after_swap"] = reg.serial
         report["new_weights_serving"] = after_swap != base[1]
 
