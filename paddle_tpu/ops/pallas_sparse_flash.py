@@ -17,10 +17,43 @@ sequential grid dimension and sums them in VMEM.
 
 Contractions take their operands in the input dtype (bf16 under AMP) and
 accumulate in float32; softmax statistics and accumulators are float32
-VMEM scratch.  Dead causal tiles are skipped, and their index maps point
-at the nearest live tile so that no DMA is issued for them.  Tiles that
-hold no selected key are NOT skipped yet (a selection learned by an
-indexer leaves few of them empty).
+VMEM scratch.  Tiles that hold no selected key are NOT skipped yet (a
+selection learned by an indexer leaves few of them empty).
+
+The grids.  A grid is (heads, then the steps of a WALK over the tiles),
+and a step of a walk is one tile pair: the tile that STAYS (the query tile
+of forward and dQ, the key tile of dK/dV; its accumulators start at the
+first of its steps and are written out at the last), the tile that STREAMS
+past it (under dK/dV the query tiles of every query head of the group, head
+after head), and the step's kind, which chooses the mask.  There is one
+kernel a family (``_fwd_kernel``, ``_dq_kernel``, ``_dkv_kernel``) over the
+shared tile bodies (``_attend``, ``_dq_tile``, ``_dkv_tile``); a walk
+(``_Walk``) brings the grid, the index maps and its half of the kernel
+(``step``: first, last, and the body under the step's mask), and the three
+kinds are three walks:
+
+- causal (``_causal_walk``): the LIVE tiles alone, ``n (n + 1) / 2`` steps
+  a query head of ``n`` tiles and none dead (136 at 8,192 tokens in tiles of
+  512, where the rectangle ``n x n`` laid 256 until PR 66).  The triangle is
+  folded: the rows of ``p`` and of ``n + 1 - p`` live tiles make ``n + 1``
+  steps together, so the grid is (heads, pairs, steps a pair) and a step's
+  tiles are a compare and two selects away from its grid position
+  (``_fold``; under dK/dV the query head of the group costs a compare a
+  binary digit more).  There is NO table here, although the other two walks
+  have one: a scalar-prefetch operand stands before every other, and the
+  benchmark's ``chipbench/kernels/sparse_flash_*.py`` count the causal half
+  from the declared shape of the call's FIRST operand, q.  ``causal_walk``
+  gives the same walk as a table, for tests and readers.  Each accumulator
+  sums its tiles in the order the rectangle did, so results are the same
+  bits.
+- a band (``_band_walk``, below): (heads, tiles, band steps) under a table
+  ``[tiles, band]``; only the first ``band - 1`` rows hold dead steps.
+- the block rule (``_rule_walk``, the last section): (heads, steps) under a
+  table ``[5, steps]``, the live tiles alone.
+
+``ops.sparse_attention.grid_steps{kernel}`` counts the steps a query head
+of every grid laid, beside ``tiles{kernel,kind}``: equal where no step is
+dead.
 
 A static causal ``window`` (0: none; else key s counts for query t iff
 ``0 <= t - s < window``) shrinks the grids' key (for dK/dV: query) dimension
@@ -28,16 +61,16 @@ to the band: ``ceil((window - 1) / block) + 1`` tiles a row, 70 of the 136
 causal tiles at 8,192 tokens and window 2,048, so a tile wholly outside the
 band is no grid step at all.  Step s of query tile j walks key tile
 ``j - (band - 1) + s``; a negative one is dead (the first rows of the
-band; for dK/dV the query tiles past the last) and maps to the nearest live
-tile, as dead causal tiles do.  The tile of each step comes from a
-scalar-prefetch table ``[tiles, band]`` int32: the call's first operand,
-whose shape states the band to whoever counts the kernel's work from its
-declared shapes.  With a window there is no selection.
+band; for dK/dV the query tiles past the last), runs no body and maps to
+the nearest live tile, which is in VMEM already.  The tile of each step
+comes from a scalar-prefetch table ``[tiles, band]`` int32: the call's
+first operand, whose shape states the band to whoever counts the kernel's
+work from its declared shapes.  With a window there is no selection.
 
 Where a selection or a window makes the mask more than one compare, a live
-tile is INTERIOR or EDGE, told apart from the grid position and the static
-shapes (``interior_reach``, ``_by_kind``), and the kernel holds one body
-for each under ``pl.when``.  Interior: every (query, key) pair of the tile
+tile is INTERIOR or EDGE, told apart from the step's tiles and the static
+shapes (``interior_reach``), and the kernel holds one body for each under
+``pl.when``.  Interior: every (query, key) pair of the tile
 satisfies the causal rule and the band's: the key tile lies wholly before
 the query tile and, under a window, its farthest pair is still inside it
 (at window 2,048 in tiles of 512 the three tiles before the diagonal).  The
@@ -79,18 +112,15 @@ the query tile's place: no mask at all) or one of three EDGE tiles whose
 rows and columns start at the same place of their copies: clean query tile
 j against clean tile j (``B(s) <= B(t)``), noised query tile j against
 clean tile j (``B(s) < B(t)``) and against its own noised tile (``B(s) =
-B(t)``: ``block`` live keys a row).  Only the live tiles are grid steps:
-the grid is (heads, steps) and a scalar-prefetch table ``[5, steps]`` int32
-(``rule_walk``, ``_walk_table``), the call's first operand, names for each
-step the tile that stays, the tile that streams, the mask and whether the
-step is the first or the last of its resident tile, where the accumulators
-start and are written out; dK/dV walks the transpose, and under each key
-tile the query heads of its group in turn.  80 steps a head at 4,096 tokens
+B(t)``: ``block`` live keys a row).  The walk is a scalar-prefetch table
+``[5, steps]`` int32 (``rule_walk``, ``_walk_table``), the call's first
+operand: for each step the tile that stays, the tile that streams, the
+query head of the group, the mask and whether the step is the first or the
+last of its resident tile.  80 steps a head at 4,096 tokens
 a copy in tiles of 512 (56 interior, 24 edge) where causal attention over
-the 8,192 positions walks 136 and its grid has 256.  One kernel, two
-bodies: the edge body makes its compare from the step's mask (two scalar
-bounds on how many blocks the key lies before the query), so the three
-edge kinds share it.
+the 8,192 positions walks 136.  Two bodies: the edge body makes its compare
+from the step's mask (two scalar bounds on how many blocks the key lies
+before the query), so the three edge kinds share it.
 
 The kernels carry names of their own (``sparse_flash_fwd``,
 ``sparse_flash_dq``, ``sparse_flash_dkv``; with a window
@@ -102,6 +132,8 @@ block rule ``blockdiff_flash_fwd``, ``blockdiff_flash_dq``,
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -213,36 +245,6 @@ def tile_counts(t, window=0, selected=False):
     return interior, live - interior
 
 
-def _by_kind(live, dist, reach, offsets, body):
-    """Runs ``body`` in a live tile whose query tile lies ``dist`` tiles
-    after its key tile: ``body(None)`` in an interior one (one of the
-    ``reach`` before the diagonal), ``body(offsets)`` in an edge one (the
-    diagonal tile, the band's far tiles), which masks by position.  One
-    kernel, two bodies; one where nothing is interior."""
-    if not reach:
-        pl.when(live)(lambda: body(offsets))
-        return
-    interior = jnp.logical_and(dist >= 1, dist <= jnp.int32(reach))
-    pl.when(jnp.logical_and(live, interior))(lambda: body(None))
-    pl.when(jnp.logical_and(live, jnp.logical_not(interior)))(
-        lambda: body(offsets))
-
-
-def _q_side_step(q_ref, n_k, window):
-    """(k step, (first query, first key), live, dist) of a grid step of
-    forward and dQ; ``dist``: how many tiles the key tile lies before the
-    query tile.  Without a window step s is key tile s, live up to the
-    diagonal; with one it is key tile ``qi - (n_k - 1) + s`` of the band's
-    ``n_k``, live from tile 0 on."""
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    blk = jnp.int32(q_ref.shape[1])
-    if window:
-        dist = jnp.int32(n_k - 1) - ki
-        kt = qi - dist
-        return ki, (qi * blk, kt * blk), kt >= 0, dist
-    return ki, (qi * blk, ki * blk), ki <= qi, qi - ki
-
-
 def _lanes(x, n):
     """``x`` [rows, w], every lane of a row the same value, as [rows, n]."""
     from jax.experimental.pallas import tpu as pltpu
@@ -294,30 +296,6 @@ def _fwd_flush(o_ref, lse_ref, m_ref, l_ref, acc_ref):
     lse_ref[0] = (m_ref[:] + jnp.log(l))[:, :1]
 
 
-def _fwd_kernel(*refs, scale, n_k, has_sel, window=0):
-    if window:
-        refs = refs[1:]                 # the band's table: the index maps'
-    q_ref, k_ref, v_ref, *rest = refs
-    if has_sel:
-        sel_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
-        sel_ref = None
-    ki, offsets, live, dist = _q_side_step(q_ref, n_k, window)
-    reach = interior_reach(window, q_ref.shape[1], n_k, has_sel)
-
-    pl.when(ki == 0)(lambda: _fwd_init(m_ref, l_ref, acc_ref))
-
-    def attend(offsets):
-        _attend(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, scale,
-                lambda shape: _keep(sel_ref, shape, offsets, window))
-
-    _by_kind(live, dist, reach, offsets, attend)
-
-    pl.when(ki == n_k - 1)(
-        lambda: _fwd_flush(o_ref, lse_ref, m_ref, l_ref, acc_ref))
-
-
 def _dq_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc, scale,
              keep_of):
     """One live tile of dQ: ``dq_acc += scale * dS k``."""
@@ -331,33 +309,6 @@ def _dq_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc, scale,
     dq_acc[:] += jnp.float32(scale) * jax.lax.dot_general(
         ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-
-
-def _dq_kernel(*refs, scale, n_k, has_sel, window=0):
-    if window:
-        refs = refs[1:]
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest = refs
-    if has_sel:
-        sel_ref, dq_ref, dq_acc = rest
-    else:
-        dq_ref, dq_acc = rest
-        sel_ref = None
-    ki, offsets, live, dist = _q_side_step(q_ref, n_k, window)
-    reach = interior_reach(window, q_ref.shape[1], n_k, has_sel)
-
-    @pl.when(ki == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    def accum(offsets):
-        _dq_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc,
-                 scale, lambda shape: _keep(sel_ref, shape, offsets, window))
-
-    _by_kind(live, dist, reach, offsets, accum)
-
-    @pl.when(ki == n_k - 1)
-    def _flush():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _dkv_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc, dv_acc,
@@ -379,64 +330,202 @@ def _dkv_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc, dv_acc,
         preferred_element_type=jnp.float32)
 
 
-def _dkv_kernel(*refs, scale, n_q, n_inner, has_sel, window=0, n_tiles=0):
-    """Grid (b*hkv, k tile, group member x q tile): the K/V tile stays, the
-    query tiles of every query head of the group stream past it: all
-    ``n_q`` of them, or with a window the band's ``n_q`` from the diagonal
-    on (those past the last of the ``n_tiles`` are dead)."""
-    if window:
-        refs = refs[1:]
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest = refs
-    if has_sel:
-        sel_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
-    else:
-        dk_ref, dv_ref, dk_acc, dv_acc = rest
-        sel_ref = None
-    kj, inner = pl.program_id(1), pl.program_id(2)
-    blk = q_ref.shape[1]
-    qi = jax.lax.rem(inner, jnp.int32(n_q))
-    if window:
-        qi = kj + qi
-        live = qi <= jnp.int32(n_tiles - 1)
-    else:
-        live = qi >= kj
-    reach = interior_reach(window, blk, n_q, has_sel)
+# -- the kernels: one a family, whatever walks the tiles -------------------
+#
+# A kernel's grid is laid by a WALK (``_Walk``: causal, band or block rule),
+# and the walk's ``step(table_ref, sel_ref, tile)`` is its half of every
+# kernel: called once in the body, it gives ``first()`` and ``last()``,
+# whether the grid step at hand is the first or the last of the tile that
+# stays (where the accumulators start and are written out), and
+# ``run(body)``, which runs ``body(keep_of)`` under the mask of the step's
+# kind.  All three are called where their scalars are wanted, in that order.
 
-    @pl.when(inner == 0)
+def _fwd_kernel(*refs, scale, walk, has_sel):
+    table_ref, (q_ref, k_ref, v_ref, *rest) = _table_first(walk, refs)
+    sel_ref = rest.pop(0) if has_sel else None
+    o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
+    first, last, run = walk.step(table_ref, sel_ref, q_ref.shape[1])
+
+    pl.when(first())(lambda: _fwd_init(m_ref, l_ref, acc_ref))
+    run(lambda keep_of: _attend(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+                                scale, keep_of))
+    pl.when(last())(
+        lambda: _fwd_flush(o_ref, lse_ref, m_ref, l_ref, acc_ref))
+
+
+def _dq_kernel(*refs, scale, walk, has_sel):
+    table_ref, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest) = \
+        _table_first(walk, refs)
+    sel_ref = rest.pop(0) if has_sel else None
+    dq_ref, dq_acc = rest
+    first, last, run = walk.step(table_ref, sel_ref, q_ref.shape[1])
+
+    @pl.when(first())
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    run(lambda keep_of: _dq_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                 delta_ref, dq_acc, scale, keep_of))
+
+    @pl.when(last())
+    def _flush():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+
+def _dkv_kernel(*refs, scale, walk, has_sel):
+    """The K/V tile stays, and the query tiles of every query head of the
+    group that read it stream past, head after head."""
+    table_ref, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest) = \
+        _table_first(walk, refs)
+    sel_ref = rest.pop(0) if has_sel else None
+    dk_ref, dv_ref, dk_acc, dv_acc = rest
+    first, last, run = walk.step(table_ref, sel_ref, q_ref.shape[1])
+
+    @pl.when(first())
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def accum(offsets):
-        _dkv_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc,
-                  dv_acc, scale,
-                  lambda shape: _keep(sel_ref, shape, offsets, window))
+    run(lambda keep_of: _dkv_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                  delta_ref, dk_acc, dv_acc, scale, keep_of))
 
-    _by_kind(live, qi - kj, reach,
-             (qi * jnp.int32(blk), kj * jnp.int32(blk)), accum)
-
-    @pl.when(inner == n_inner - 1)
+    @pl.when(last())
     def _flush():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _q_side_maps(hq, group):
-    """Index maps of the grids (b*hq, q tile, k tile) of forward and dQ.
-    A dead causal tile (k tile beyond the q tile; the tiles are square)
-    maps to the diagonal one, which is already in VMEM."""
-    def resident(i, j, s):
-        return block_index(i, j, 0)
+class _Walk(NamedTuple):
+    """How one kernel call walks its tiles."""
+    table: object   # the scalar-prefetch operand the index maps read; None
+    steps: tuple    # the grid's dimensions after the heads'
+    step: object    # the kernels' half, above
+    stays: object   # index map of the tile that stays, of the grid's head
+    streams: object     # ... that streams past, of the other side's head
+    sel: object = None  # ... of the selection
 
-    def kv(i, j, s):
-        return block_index(jax.lax.div(i, jnp.int32(group)),
-                           jnp.minimum(s, j), 0)
 
-    def sel(i, j, s):
-        return block_index(jax.lax.div(i, jnp.int32(hq)), j,
-                           jnp.minimum(s, j))
+def _table_first(walk, refs):
+    return (None, refs) if walk.table is None else (refs[0], refs[1:])
 
-    return resident, kv, sel
+
+# -- causal: the triangle, folded (the module's docstring) -----------------
+
+def _fold(n, reps, u, r):
+    """Step ``r`` of pair ``u`` of the folded triangle whose rows hold 1..n
+    tiles, each row walked ``reps`` times over before the next: (tiles of
+    the row the step is in, its place in the row, which of the row's
+    walks, whether it is the first, the last of the row's steps).  A pair
+    is the row of ``u + 1`` tiles and then the row of ``n - u`` (``n`` odd:
+    of ``u`` and of ``n - u``, the longest row alone); ints or arrays."""
+    short = u + 1 - n % 2
+    in_short = r < short * reps
+    at = jnp.where(in_short, r, r - short * reps)
+    length = jnp.where(in_short, short, n - u)
+    first, last = at == 0, at == length * reps - 1
+    walk = jnp.zeros_like(at)
+    bit = 1 << max(reps - 1, 0).bit_length()
+    while bit > 1:                  # at // length, a binary digit a compare
+        bit //= 2
+        over = at >= length * bit
+        at = jnp.where(over, at - length * bit, at)
+        walk = walk + jnp.where(over, jnp.int32(bit), jnp.int32(0))
+    return length, at, walk, first, last
+
+
+def causal_steps(n, reps=1):
+    """The folded grid after its heads: (pairs, steps a pair)."""
+    return (n + 1) // 2, (n + 1 - n % 2) * reps
+
+
+def _causal_tiles(n, group, by_key, u, r):
+    """(query tile, key tile, query head of the group, first, last) of
+    step ``r`` of pair ``u``.  The rows are the query tiles' (tile j reads
+    key tiles 0..j); ``by_key`` the key tiles' (tile j is read by query
+    tiles j..n-1), each walked once for every query head of the group."""
+    length, at, member, first, last = _fold(
+        n, group if by_key else 1, u, r)
+    if by_key:
+        return n - length + at, n - length, member, first, last
+    return length - 1, at, member, first, last
+
+
+def _causal_walk(n, group, hq, by_key=False):
+    """Forward and dQ: grid (b*hq, pairs, steps), the query tile stays.
+    dK/dV (``by_key``): grid (b*hkv, pairs, group x steps), the key tile
+    stays."""
+    reps = group if by_key else 1
+    tiles = functools.partial(_causal_tiles, n, group, by_key)
+
+    def stays(i, u, r):
+        qt, kt = tiles(u, r)[:2]
+        return block_index(i, kt if by_key else qt, 0)
+
+    def streams(i, u, r):
+        qt, kt, member = tiles(u, r)[:3]
+        if by_key:
+            return block_index(i * jnp.int32(group) + member, qt, 0)
+        return block_index(jax.lax.div(i, jnp.int32(group)), kt, 0)
+
+    def sel(i, u, r):
+        qt, kt = tiles(u, r)[:2]
+        return block_index(jax.lax.div(i, jnp.int32(hq // reps)), qt, kt)
+
+    def step(_, sel_ref, blk):
+        qt, kt, _, first, last = tiles(pl.program_id(1), pl.program_id(2))
+        offsets = qt * jnp.int32(blk), kt * jnp.int32(blk)
+
+        def run(body):
+            def edge(shape):
+                return _keep(sel_ref, shape, offsets)
+
+            if sel_ref is None:     # the mask is one compare: one body
+                return body(edge)
+            pl.when(qt != kt)(
+                lambda: body(lambda shape: _keep(sel_ref, shape, None)))
+            pl.when(qt == kt)(lambda: body(edge))
+
+        return lambda: first, lambda: last, run
+
+    return _Walk(None, causal_steps(n, reps), step, stays, streams, sel)
+
+
+def causal_walk(n, group=1, by_key=False, selected=False):
+    """The causal walk as a table [5, steps] of numpy, rows as a block
+    rule's (``RESIDENT`` ..): what the folded grid's index maps and kernels
+    compute for each step, for whoever wants to read it.  The diagonal tile
+    is the edge one (its compare is ``EDGE_LE`` at blocks of one token);
+    the others are interior where they have a body of their own, under a
+    selection."""
+    import numpy as np
+
+    pairs, steps = causal_steps(n, group if by_key else 1)
+    u, r = (x.ravel() for x in np.meshgrid(
+        np.arange(pairs, dtype=np.int32), np.arange(steps, dtype=np.int32),
+        indexing="ij"))
+    qt, kt, member, first, last = (
+        np.asarray(x) for x in _causal_tiles(n, group, by_key, u, r))
+    interior = np.logical_and(selected, qt != kt)
+    return np.stack([kt if by_key else qt, qt if by_key else kt, member,
+                     np.where(interior, INTERIOR, EDGE_LE),
+                     FIRST * first + LAST * last]).astype(np.int32)
+
+
+# -- causal under a window: a band ----------------------------------------
+
+def _by_kind(live, dist, reach, offsets, body):
+    """Runs ``body`` in a live tile whose query tile lies ``dist`` tiles
+    after its key tile: ``body(None)`` in an interior one (one of the
+    ``reach`` before the diagonal), ``body(offsets)`` in an edge one (the
+    diagonal tile, the band's far tiles), which masks by position.  One
+    kernel, two bodies; one where nothing is interior."""
+    if not reach:
+        pl.when(live)(lambda: body(offsets))
+        return
+    interior = jnp.logical_and(dist >= 1, dist <= jnp.int32(reach))
+    pl.when(jnp.logical_and(live, interior))(lambda: body(None))
+    pl.when(jnp.logical_and(live, jnp.logical_not(interior)))(
+        lambda: body(offsets))
 
 
 def _band_maps(group, band):
@@ -467,6 +556,43 @@ def _band_tables(n, band):
     j, s = np.arange(n)[:, None], np.arange(band)[None, :]
     return (jnp.asarray(np.maximum(j - (band - 1) + s, 0), jnp.int32),
             jnp.asarray(np.minimum(j + s, n - 1), jnp.int32))
+
+
+def _band_walk(n, group, window, blk, by_key=False):
+    """Forward and dQ: grid (b*hq, q tile, band step), step s of query tile
+    j is key tile ``j - (band - 1) + s``, live from tile 0 on.  dK/dV
+    (``by_key``): grid (b*hkv, k tile, group member x band step), step s
+    of key tile j is query tile ``j + s``, live up to the last."""
+    band = band_tiles(window, blk, n)
+    resident, kv, q_side = _band_maps(group, band)
+    reach = interior_reach(window, blk, band)
+    steps = (group if by_key else 1) * band
+
+    def step(_, sel_ref, blk):
+        # scalars in the order these kernels have always made them: the
+        # lowered bodies are PR 65's, byte for byte
+        j, s = pl.program_id(1), pl.program_id(2)
+        blk = jnp.int32(blk)
+        if by_key:
+            qt, kt = j + jax.lax.rem(s, jnp.int32(band)), j
+            live = qt <= jnp.int32(n - 1)
+            place = lambda: (qt - kt, (qt * blk, kt * blk))  # noqa: E731
+        else:
+            dist = jnp.int32(band - 1) - s
+            qt, kt = j, j - dist
+            offsets = qt * blk, kt * blk
+            live = kt >= 0
+            place = lambda: (dist, offsets)                  # noqa: E731
+
+        def run(body):
+            dist, offsets = place()
+            _by_kind(live, dist, reach, offsets, lambda offsets: body(
+                lambda shape: _keep(None, shape, offsets, window)))
+
+        return lambda: s == 0, lambda: s == steps - 1, run
+
+    return _Walk(_band_tables(n, band)[by_key], (n, steps), step, resident,
+                 q_side if by_key else kv)
 
 
 # -- block diffusion: two copies of a sequence under one rule ------------
@@ -560,56 +686,6 @@ def _by_mask(mask, shift, body):
         lambda: body(lambda shape: _block_keep(shape, mask, shift)))
 
 
-def _rule_fwd_kernel(walk_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
-                     l_ref, acc_ref, *, scale, shift):
-    step = pl.program_id(1)
-    flags = walk_ref[FLAGS, step]
-    pl.when((flags & FIRST) != 0)(lambda: _fwd_init(m_ref, l_ref, acc_ref))
-    _by_mask(walk_ref[STEP_MASK, step], shift, lambda keep_of: _attend(
-        q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, scale, keep_of))
-    pl.when((flags & LAST) != 0)(
-        lambda: _fwd_flush(o_ref, lse_ref, m_ref, l_ref, acc_ref))
-
-
-def _rule_dq_kernel(walk_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    delta_ref, dq_ref, dq_acc, *, scale, shift):
-    step = pl.program_id(1)
-    flags = walk_ref[FLAGS, step]
-
-    @pl.when((flags & FIRST) != 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    _by_mask(walk_ref[STEP_MASK, step], shift, lambda keep_of: _dq_tile(
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc, scale,
-        keep_of))
-
-    @pl.when((flags & LAST) != 0)
-    def _flush():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
-
-
-def _rule_dkv_kernel(walk_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                     delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
-                     shift):
-    step = pl.program_id(1)
-    flags = walk_ref[FLAGS, step]
-
-    @pl.when((flags & FIRST) != 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    _by_mask(walk_ref[STEP_MASK, step], shift, lambda keep_of: _dkv_tile(
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc, dv_acc,
-        scale, keep_of))
-
-    @pl.when((flags & LAST) != 0)
-    def _flush():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
-
-
 def _rule_maps(group):
     """Index maps of the walked grids (heads, step): the tile that stays
     and the tile that streams, each of the query's or of the key-value
@@ -628,18 +704,39 @@ def _rule_maps(group):
     return own, of_kv_head, of_member
 
 
+def _rule_walk(n, group, shift, by_key=False):
+    """Grid (heads, steps) under the walk's table; dK/dV walks the
+    transpose.  Blocks of ``1 << shift`` positions."""
+    table = _walk_table(n, group, by_key)
+    own, of_kv_head, of_member = _rule_maps(group)
 
-def _call(kernel, name, table, args, *, grid, in_specs, out_specs,
-          out_shape, scratch_shapes, interpret, tiles):
-    """``pallas_call``; with a band's table, as its scalar-prefetch
-    operand.  Counts the call's ``tiles`` (interior, edge) a head."""
+    def step(walk_ref, sel_ref, blk):
+        at = pl.program_id(1)
+        flags = walk_ref[FLAGS, at]
+        return lambda: (flags & FIRST) != 0, lambda: (flags & LAST) != 0, \
+            lambda body: _by_mask(walk_ref[STEP_MASK, at], shift, body)
+
+    return _Walk(table, (table.shape[1],), step, own(RESIDENT),
+                 (of_member if by_key else of_kv_head)(STREAMED))
+
+
+def _call(kernel, name, walk, args, *, heads, in_specs, out_specs,
+          out_shape, scratch_shapes, interpret, tiles, group=1):
+    """``pallas_call`` over ``heads`` times the walk's steps; a walk's
+    table is the scalar-prefetch operand.  Counts, a query head (a grid
+    head of dK/dV walks for the ``group`` of them), the steps of the grid
+    it lays and the call's live ``tiles`` (interior, edge): equal where no
+    step is dead."""
     from jax.experimental.pallas import tpu as pltpu
     from .decoder_ops import _count
 
+    _count("ops.sparse_attention.grid_steps",
+           math.prod(walk.steps) // group, kernel=name)
     for kind, count in zip(("interior", "edge"), tiles):
         _count("ops.sparse_attention.tiles", count, kernel=name, kind=kind)
 
-    if table is None:
+    grid = (heads,) + walk.steps
+    if walk.table is None:
         return pl.pallas_call(
             kernel, out_shape=out_shape, grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=scratch_shapes,
@@ -649,212 +746,107 @@ def _call(kernel, name, table, args, *, grid, in_specs, out_specs,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=scratch_shapes),
-        interpret=interpret, name=name)(table, *args)
+        interpret=interpret, name=name)(walk.table, *args)
 
 
-def _forward(q, k, v, sel, scale, interpret, window=0):
+def _kind(q, k, sel, window, rule):
+    """(the kernels' first name, tile, (interior, edge) tiles a head, the
+    walk of ``by_key``) of a call: the block rule's, a band's, or causal."""
+    hq, t = q.shape[1:3]
+    group = hq // k.shape[1]
+    if rule:
+        tokens, block = rule
+        blk, n = rule_tiles(tokens)
+        return "blockdiff", blk, rule_tile_counts(tokens), functools.partial(
+            _rule_walk, n, group, block.bit_length() - 1)
+    blk = _block(t)
+    tiles = tile_counts(t, window, sel is not None)
+    if window:
+        return "window", blk, tiles, functools.partial(
+            _band_walk, t // blk, group, window, blk)
+    return "sparse", blk, tiles, functools.partial(
+        _causal_walk, t // blk, group, hq)
+
+
+def _forward(q, k, v, sel, scale, interpret, window=0, rule=None):
     from jax.experimental.pallas import tpu as pltpu
 
     b, hq, t, d = q.shape
     hkv = k.shape[1]
-    group = hq // hkv
-    blk = _block(t)
-    n = t // blk
-    n_k, table = n, None
-    if window:
-        n_k = band_tiles(window, blk, n)
-        table = _band_tables(n, n_k)[0]
-        resident, kv, _ = _band_maps(group, n_k)
-    else:
-        resident, kv, sel_map = _q_side_maps(hq, group)
-    in_specs = [pl.BlockSpec((1, blk, d), resident),
-                pl.BlockSpec((1, blk, d), kv),
-                pl.BlockSpec((1, blk, d), kv)]
+    name, blk, tiles, walk_of = _kind(q, k, sel, window, rule)
+    walk = walk_of()
+    in_specs = [pl.BlockSpec((1, blk, d), walk.stays),
+                pl.BlockSpec((1, blk, d), walk.streams),
+                pl.BlockSpec((1, blk, d), walk.streams)]
     args = [q.reshape(b * hq, t, d), k.reshape(b * hkv, t, d),
             v.reshape(b * hkv, t, d)]
     if sel is not None:
-        in_specs.append(pl.BlockSpec((1, blk, blk), sel_map))
+        in_specs.append(pl.BlockSpec((1, blk, blk), walk.sel))
         args.append(sel)
     out, lse = _call(
-        functools.partial(_fwd_kernel, scale=scale, n_k=n_k,
-                          has_sel=sel is not None, window=window),
-        "window_flash_fwd" if window else "sparse_flash_fwd", table, args,
-        grid=(b * hq, n, n_k), in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, blk, d), resident),
-                   pl.BlockSpec((1, blk, 1), resident)],
+        functools.partial(_fwd_kernel, scale=scale, walk=walk,
+                          has_sel=sel is not None),
+        name + "_flash_fwd", walk, args, heads=b * hq, in_specs=in_specs,
+        out_specs=[pl.BlockSpec((1, blk, d), walk.stays),
+                   pl.BlockSpec((1, blk, 1), walk.stays)],
         out_shape=[jax.ShapeDtypeStruct((b * hq, t, d), q.dtype),
                    jax.ShapeDtypeStruct((b * hq, t, 1), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((blk, LANE), jnp.float32),
                         pltpu.VMEM((blk, LANE), jnp.float32),
                         pltpu.VMEM((blk, d), jnp.float32)],
-        interpret=interpret, tiles=tile_counts(t, window, sel is not None))
+        interpret=interpret, tiles=tiles)
     return out.reshape(b, hq, t, d), lse.reshape(b, hq, t, 1)
 
 
-def _backward(q, k, v, sel, out, lse, do, scale, interpret, window=0):
+def _backward(q, k, v, sel, out, lse, do, scale, interpret, window=0,
+              rule=None):
     from jax.experimental.pallas import tpu as pltpu
 
     b, hq, t, d = q.shape
     hkv = k.shape[1]
-    group = hq // hkv
-    blk = _block(t)
-    n = t // blk
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)
-    qr, dor = q.reshape(b * hq, t, d), do.reshape(b * hq, t, d)
-    kr, vr = k.reshape(b * hkv, t, d), v.reshape(b * hkv, t, d)
-    lser, dr = lse.reshape(b * hq, t, 1), delta.reshape(b * hq, t, 1)
+    name, blk, tiles, walk_of = _kind(q, k, sel, window, rule)
     has_sel = sel is not None
-    sel_args = [sel] if has_sel else []
-    tiles = tile_counts(t, window, has_sel)
-    band, k_table, q_table = n, None, None
-    if window:
-        band = band_tiles(window, blk, n)
-        k_table, q_table = _band_tables(n, band)
-        resident, kv, q_side = _band_maps(group, band)
-    else:
-        resident, kv, sel_map = _q_side_maps(hq, group)
-
-    specs = [pl.BlockSpec((1, blk, d), resident),
-             pl.BlockSpec((1, blk, d), kv),
-             pl.BlockSpec((1, blk, d), kv),
-             pl.BlockSpec((1, blk, d), resident),
-             pl.BlockSpec((1, blk, 1), resident),
-             pl.BlockSpec((1, blk, 1), resident)]
-    if has_sel:
-        specs.append(pl.BlockSpec((1, blk, blk), sel_map))
-    dq = _call(
-        functools.partial(_dq_kernel, scale=scale, n_k=band,
-                          has_sel=has_sel, window=window),
-        "window_flash_dq" if window else "sparse_flash_dq", k_table,
-        (qr, kr, vr, dor, lser, dr, *sel_args),
-        grid=(b * hq, n, band), in_specs=specs,
-        out_specs=pl.BlockSpec((1, blk, d), resident),
-        out_shape=jax.ShapeDtypeStruct((b * hq, t, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
-        interpret=interpret, tiles=tiles)
-
-    # dK/dV: grid (b*hkv, k tile, group member x q tile)
-    def k_side(i, j, s, *table):
-        return block_index(i, j, 0)
-
-    if not window:          # with one: q_side of _band_maps, no selection
-        def q_tile(s, j):
-            # a dead tile (q tile before the k tile) maps to the diagonal
-            return jnp.maximum(jax.lax.rem(s, jnp.int32(n)), j)
-
-        def q_side(i, j, s):
-            head = i * jnp.int32(group) + jax.lax.div(s, jnp.int32(n))
-            return block_index(head, q_tile(s, j), 0)
-
-        def sel_side(i, j, s):
-            return block_index(jax.lax.div(i, jnp.int32(hkv)),
-                               q_tile(s, j), j)
-
-    specs = [pl.BlockSpec((1, blk, d), q_side),
-             pl.BlockSpec((1, blk, d), k_side),
-             pl.BlockSpec((1, blk, d), k_side),
-             pl.BlockSpec((1, blk, d), q_side),
-             pl.BlockSpec((1, blk, 1), q_side),
-             pl.BlockSpec((1, blk, 1), q_side)]
-    if has_sel:
-        specs.append(pl.BlockSpec((1, blk, blk), sel_side))
-    dk, dv = _call(
-        functools.partial(_dkv_kernel, scale=scale, n_q=band,
-                          n_inner=group * band, has_sel=has_sel,
-                          window=window, n_tiles=n),
-        "window_flash_dkv" if window else "sparse_flash_dkv", q_table,
-        (qr, kr, vr, dor, lser, dr, *sel_args),
-        grid=(b * hkv, n, group * band), in_specs=specs,
-        out_specs=[pl.BlockSpec((1, blk, d), k_side),
-                   pl.BlockSpec((1, blk, d), k_side)],
-        out_shape=[jax.ShapeDtypeStruct((b * hkv, t, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * hkv, t, d), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32),
-                        pltpu.VMEM((blk, d), jnp.float32)],
-        interpret=interpret, tiles=tiles)
-    return (dq.reshape(b, hq, t, d), dk.reshape(b, hkv, t, d),
-            dv.reshape(b, hkv, t, d))
-
-
-def _rule_forward(q, k, v, scale, interpret, rule):
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, hq, t, d = q.shape
-    hkv = k.shape[1]
-    tokens, block = rule
-    blk, n = rule_tiles(tokens)
-    walk = _walk_table(n)
-    own, of_kv_head, _ = _rule_maps(hq // hkv)
-    stays, streams = own(RESIDENT), of_kv_head(STREAMED)
-    out, lse = _call(
-        functools.partial(_rule_fwd_kernel, scale=scale,
-                          shift=block.bit_length() - 1),
-        "blockdiff_flash_fwd", walk,
-        [q.reshape(b * hq, t, d), k.reshape(b * hkv, t, d),
-         v.reshape(b * hkv, t, d)],
-        grid=(b * hq, walk.shape[1]),
-        in_specs=[pl.BlockSpec((1, blk, d), stays),
-                  pl.BlockSpec((1, blk, d), streams),
-                  pl.BlockSpec((1, blk, d), streams)],
-        out_specs=[pl.BlockSpec((1, blk, d), stays),
-                   pl.BlockSpec((1, blk, 1), stays)],
-        out_shape=[jax.ShapeDtypeStruct((b * hq, t, d), q.dtype),
-                   jax.ShapeDtypeStruct((b * hq, t, 1), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((blk, LANE), jnp.float32),
-                        pltpu.VMEM((blk, LANE), jnp.float32),
-                        pltpu.VMEM((blk, d), jnp.float32)],
-        interpret=interpret, tiles=rule_tile_counts(tokens))
-    return out.reshape(b, hq, t, d), lse.reshape(b, hq, t, 1)
-
-
-def _rule_backward(q, k, v, out, lse, do, scale, interpret, rule):
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, hq, t, d = q.shape
-    hkv = k.shape[1]
-    group = hq // hkv
-    tokens, block = rule
-    blk, n = rule_tiles(tokens)
-    shift, tiles = block.bit_length() - 1, rule_tile_counts(tokens)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)
     args = (q.reshape(b * hq, t, d), k.reshape(b * hkv, t, d),
             v.reshape(b * hkv, t, d), do.reshape(b * hq, t, d),
-            lse.reshape(b * hq, t, 1), delta.reshape(b * hq, t, 1))
-    own, of_kv_head, of_member = _rule_maps(group)
+            lse.reshape(b * hq, t, 1), delta.reshape(b * hq, t, 1)) \
+        + ((sel,) if has_sel else ())
 
-    def specs(q_side, kv_side):
+    def specs(q_side, kv_side, sel_map):
         return [pl.BlockSpec((1, blk, d), q_side),
                 pl.BlockSpec((1, blk, d), kv_side),
                 pl.BlockSpec((1, blk, d), kv_side),
                 pl.BlockSpec((1, blk, d), q_side),
                 pl.BlockSpec((1, blk, 1), q_side),
-                pl.BlockSpec((1, blk, 1), q_side)]
+                pl.BlockSpec((1, blk, 1), q_side)] \
+            + ([pl.BlockSpec((1, blk, blk), sel_map)] if has_sel else [])
 
-    walk = _walk_table(n)
+    walk = walk_of()
     dq = _call(
-        functools.partial(_rule_dq_kernel, scale=scale, shift=shift),
-        "blockdiff_flash_dq", walk, args, grid=(b * hq, walk.shape[1]),
-        in_specs=specs(own(RESIDENT), of_kv_head(STREAMED)),
-        out_specs=pl.BlockSpec((1, blk, d), own(RESIDENT)),
+        functools.partial(_dq_kernel, scale=scale, walk=walk,
+                          has_sel=has_sel),
+        name + "_flash_dq", walk, args, heads=b * hq,
+        in_specs=specs(walk.stays, walk.streams, walk.sel),
+        out_specs=pl.BlockSpec((1, blk, d), walk.stays),
         out_shape=jax.ShapeDtypeStruct((b * hq, t, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
         interpret=interpret, tiles=tiles)
     # dK/dV: the key tile stays, the tiles of the group's query heads that
     # read it stream past: the walk's transpose
-    walk = _walk_table(n, group, by_key=True)
+    walk = walk_of(by_key=True)
     dk, dv = _call(
-        functools.partial(_rule_dkv_kernel, scale=scale, shift=shift),
-        "blockdiff_flash_dkv", walk, args, grid=(b * hkv, walk.shape[1]),
-        in_specs=specs(of_member(STREAMED), own(RESIDENT)),
-        out_specs=[pl.BlockSpec((1, blk, d), own(RESIDENT)),
-                   pl.BlockSpec((1, blk, d), own(RESIDENT))],
+        functools.partial(_dkv_kernel, scale=scale, walk=walk,
+                          has_sel=has_sel),
+        name + "_flash_dkv", walk, args, heads=b * hkv,
+        in_specs=specs(walk.streams, walk.stays, walk.sel),
+        out_specs=[pl.BlockSpec((1, blk, d), walk.stays),
+                   pl.BlockSpec((1, blk, d), walk.stays)],
         out_shape=[jax.ShapeDtypeStruct((b * hkv, t, d), k.dtype),
                    jax.ShapeDtypeStruct((b * hkv, t, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32),
                         pltpu.VMEM((blk, d), jnp.float32)],
-        interpret=interpret, tiles=tiles)
+        interpret=interpret, tiles=tiles, group=hq // hkv)
     return (dq.reshape(b, hq, t, d), dk.reshape(b, hkv, t, d),
             dv.reshape(b, hkv, t, d))
 
@@ -865,19 +857,16 @@ def forward(q, k, v, sel=None, scale=None, interpret=None, window=0,
     ``rule``: None, or (tokens a copy, block length) of two copies of a
     sequence side by side along T (then neither ``sel`` nor ``window``)."""
     scale, interpret = resolve(q, scale, interpret)
-    if rule:
-        return _rule_forward(q, k, v, scale, interpret, tuple(rule))
-    return _forward(q, k, v, sel, scale, interpret, window)
+    return _forward(q, k, v, sel, scale, interpret, window,
+                    tuple(rule) if rule else None)
 
 
 def backward(q, k, v, sel, out, lse, do, scale=None, interpret=None,
              window=0, rule=None):
     """(dq, dk, dv) from the forward's own ``out`` and ``lse``."""
     scale, interpret = resolve(q, scale, interpret)
-    if rule:
-        return _rule_backward(q, k, v, out, lse, do, scale, interpret,
-                              tuple(rule))
-    return _backward(q, k, v, sel, out, lse, do, scale, interpret, window)
+    return _backward(q, k, v, sel, out, lse, do, scale, interpret, window,
+                     tuple(rule) if rule else None)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))

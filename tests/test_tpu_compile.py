@@ -470,6 +470,48 @@ def test_blockdiff_signatures_are_the_benchmarks(topo):
     assert len(signatures) == 3     # an event is matched to ONE family
 
 
+@pytest.mark.parametrize("selected,hq,hkv,d", [
+    (True, 32, 4, 128), (False, 16, 16, 128), (False, 16, 2, 256)])
+def test_causal_grids_are_the_live_tiles_and_q_stands_first(
+        topo, selected, hq, hkv, d):
+    """The causal kernels at a cell's shapes (Keye's under its selection,
+    Instella's group of one, Qwen3-Next's width of 256), lowered for the
+    described chip: each grid is its heads by the folded triangle, 8 pairs
+    of 17 steps at 16 tiles (a group's query heads in turn under dK/dV),
+    136 steps a query head and none dead; and no table stands before q,
+    whose declared shape ``chipbench/kernels/sparse_flash_*.py`` count the
+    causal half from."""
+    import re
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from chipbench import hlo
+    from chipbench.plugins import load
+
+    fn, shapes = _sparse_flash(selected, 0, 8192, hkv, d, hq)
+    chip = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, ty, sharding=chip) for s, ty in shapes]
+    text = jax.jit(fn).lower(*args).as_text()
+    calls = hlo.custom_calls(text)
+    grids = [[int(i) for i in re.search(
+        r"iteration_bounds = array<i64: ([\d, ]+)>", body).group(1).split(",")]
+        for body in _mosaic_bodies(text)]
+    group = hq // hkv
+    want = {"sparse_flash_fwd": (2, [hq, 8, 17]),
+            "sparse_flash_dq": (3, [hq, 8, 17]),
+            "sparse_flash_dkv": (4, [hkv, 8, 17 * group])}
+    assert sorted(c.kernel for c in calls) == sorted(want)
+    for call, grid in zip(calls, grids):
+        matmuls, bounds = want[call.kernel]
+        assert grid == bounds, call.kernel
+        assert call.operands[0] == ((hq, 8192, d), "bf16"), call.kernel
+        assert (call.operands[-1] == ((1, 8192, 8192), "i8")) == selected
+        assert load("kernels", call.kernel).flops(
+            call.operands, call.results) == \
+            2.0 * matmuls * hq * 8192 * 8192 * d / 2, call.kernel
+
+
 @pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))
 def test_grouped_signatures_are_the_benchmarks(topo, monkeypatch, cell):
     """An expert layer's product with its backward at a cell's shapes,
