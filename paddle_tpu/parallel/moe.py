@@ -183,9 +183,9 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
     trip of a ``lax.while_loop`` while the assignments to the experts held
     fit a slab, as many more as they need beyond it, so a step in which
     every token chose held experts only is as right as any other.  Where
-    the slab is every row (a quarter of the experts or more held; for now
-    also a router with no balancing ``bias``: ``slab_rows``' DEBT) no loop is
-    built.
+    the slab is every row (a quarter of the experts or more held, an
+    eighth under a router with no balancing ``bias``: ``slab_rows``) no loop
+    is built.
 
     The routing plan (the router's weights and choices, the sort by expert
     and its inverse, the group sizes) is made once and kept for the
@@ -196,7 +196,8 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
     ``<dy @ w2^T, h>`` over a row, and the backward has both).  Rows move
     by gathers in both passes and nothing is scattered: a walk gathers
     twice forward and three times backward (out to the walk's sorted rows,
-    ``[slab, D]``; home to the assignments, ``[N * top_k, D]``); the
+    ``[slab, D]``; home to the assignments, ``[N * top_k, D]``, in a
+    loop's body a choice at a time, ``_choices_home``); the
     combine's cotangent goes out to the sorted rows from ``[N, D]`` and
     meets the gate on the hidden side, never as ``[N, top_k, D]``.  A walk
     of both passes: 8 products and 3 weights' gradients, traced ONCE a
@@ -291,11 +292,13 @@ def routed_experts(x, router_w, w1, w3, w2, top_k: int,
 #: layer's first, median and last step, had read 7,646 (2.5 times even,
 #: not the 2.0 written here before) / 6,267 / 778 / 12,288.  At 3 the
 #: first two would take a second trip in those steps.  Without such a
-#: rule Qwen3-Next climbs to 3.6 times (11.3% of all rows) and has not
-#: stopped at the window's last step; and however far a router drifts, a
-#: layer in slabs walks less than one slab more than the one walk does
-#: (``ceil(live / slab)`` trips).  A quarter of the experts or more held
-#: is every row.
+#: rule a router drifts further and does not come back (Qwen3-Next 3.4
+#: to 3.6 times, 11.3% of all rows, and not stopped at the window's last
+#: step; Keye 6.0 times: ledger, PR 66), so a layer whose router has no
+#: bias walks TWICE this factor (``slab_rows``' ``balanced``); and however
+#: far a router drifts, a layer in slabs walks less than one slab more
+#: than the one walk does (``ceil(live / slab)`` trips).  A quarter of the
+#: experts or more held is every row; without a bias, an eighth.
 SLAB_OVER_EVEN = 4
 
 
@@ -308,30 +311,36 @@ def slab_rows(rows: int, held: int, routed: int, kernels: bool,
     operands alone: no argument of the layer, attribute, environment
     variable or configuration's name chooses.
 
-    DEBT, ``balanced`` (the router has a selection bias that
-    ``balance_bias`` moves): a layer without one walks all its rows.  It
-    stands for ONE reading and is no rule of routers: the step of the one
-    cell that the shapes would give a slab and that has no bias,
-    ``qwen3_next_80b_a3b.resident`` (20,480 of 81,920 rows), reserves 5,571
-    MB at the parent and 6,723 MB with its eight loops in it (my
-    described-chip compiles, PR 56; 5,574 MB with the forward's four alone),
-    1.07 GiB of ``peak_hbm_gib`` where the bound is 0.14: XLA then schedules
-    every Adam update after the last backward op, as it already does in
-    Kimi-Linear's step on both sides, and all the gradients wait.  The
-    drift of such a router (3.6 times the even share at the end of that
-    cell's window, still climbing) is NO reason: a layer in slabs never
-    walks more than one slab over what the one walk does.  What takes this
-    argument out is the step's schedule held in place (ROADMAP S11 (b)),
-    then that cell's own pairs on the chip; a configuration with a bias
-    whose step XLA schedules the same way would lose memory as that cell
-    does, and one without a bias goes without the slab until then."""
+    ``balanced`` (the router has a selection bias that ``balance_bias``
+    moves) is a rule of ROUTERS, with a reading behind it: a router without
+    a bias has nothing that pulls it back, and on one chip's share of the
+    experts it learns to choose those that are held.  ``moe_live_rows_pct``
+    over the even share (ledger, PR 66): Qwen3-Next 10.57 over 3.125, 3.4
+    times and still climbing at the window's last step (9,257 rows, 11.3%,
+    at most); Mellum2 38.5 over 12.5, 3.1 times; SDAR 62.1 over 12.5, 5.0;
+    Keye 74.7 over 12.5, 6.0.  Four times the even share cannot hold that,
+    so such a layer has TWICE the room, ``2 * SLAB_OVER_EVEN`` times the
+    even share: Qwen3-Next 20,480 of 81,920 rows, one trip in every step
+    read (my chip runs, PR 67: the four layers' mean 49% of the slab at the
+    window's middle, and the fourth layer, which drifts furthest, 18,783
+    rows, 92% of it, at most and climbing at the window's last step; the
+    ledger's 10.57% is the layers' mean, not the fullest layer's; a window
+    four times as long took up to three trips there, exact, 57,764 of the
+    81,920 rows live, and its ``step_ms_p95`` read 244.4 ms for 214.1);
+    Keye, SDAR and Mellum2, an eighth of their
+    experts held, every row and no loop.  (Until PR 67 the argument sent
+    such a router to every row, for memory and not for drift: with its
+    eight loops Qwen3-Next's step reserved more than its bound allows, 279
+    MB over the parent's 5,171.8 at PR 67.  What gave that back, and 157 MB
+    more, is ``_choices_home``, in every looped layer.)  Whether
+    ``balanced`` can go altogether, Mellum2 at half its rows and Keye and
+    SDAR at two trips a step, waits for a reading of what a SECOND trip
+    costs against one walk (ROADMAP S11 (b))."""
     from ..ops.pallas_grouped import ROW_TILE
 
-    if not balanced:
-        return rows
+    over = SLAB_OVER_EVEN if balanced else 2 * SLAB_OVER_EVEN
     tile = ROW_TILE if kernels else 1
-    return min(rows, tile * -(-SLAB_OVER_EVEN * rows * held
-                              // (routed * tile)))
+    return min(rows, tile * -(-over * rows * held // (routed * tile)))
 
 
 def walk_of(x, router_w, w1, w2, top_k: int, bias):
@@ -577,6 +586,9 @@ def _forward_walk(top_k, operands, plan, types, looped):
     ys = grouped_product(_hidden(a, b).astype(xs.dtype), weights[2],
                          sizes, tables)  # [rows, D]
     # back to assignment order, weighted, summed over a token's choices
+    if looped:
+        return _choices_home(ys, back, held, "forward", jnp.float32,
+                             gate).astype(types[0]),
     return jnp.einsum("nk,nkd->nd", gate, _rows_home(
         ys, back, held, "forward").astype(jnp.float32)).astype(types[0]),
 
@@ -640,8 +652,12 @@ def _backward_walk(top_k, operands, plan, types, looped):
         cots, dws = lax.optimization_barrier((cots, dws))
     dxs = functools.reduce(operator.add,
                            (t(d) for t, d in zip(to_xs, cots)))
-    dxt = jnp.sum(_rows_home(dxs, back, held, "backward"), axis=1,
-                  dtype=jnp.promote_types(types[0], jnp.float32))
+    wide = jnp.promote_types(types[0], jnp.float32)
+    if looped:
+        dxt = _choices_home(dxs, back, held, "backward", wide)
+    else:
+        dxt = jnp.sum(_rows_home(dxs, back, held, "backward"), axis=1,
+                      dtype=wide)
     dgate = jnp.where(held, _permuted(dgate_s, back).reshape(held.shape), 0)
     if not looped:
         dws = weights_gradients()
@@ -703,6 +719,36 @@ def _rows_home(rows, back, held, which: str):
     _count_row_move(which)
     return jnp.where(held[..., None],
                      _permuted(rows, back).reshape(held.shape + (-1,)), 0)
+
+
+def _choices_home(rows, back, held, which: str, wide, gate=None):
+    """[N, D] in the type ``wide``: ``_rows_home`` summed over a token's
+    choices, each weighted by its ``gate`` [N, top_k] where one is given,
+    as a loop's body makes it: ONE GATHER OF [N, D] A CHOICE, added up as
+    they come, so that the [N, top_k, D] array of every assignment's row
+    never exists.  In a body it was the fullest point, and with loops in
+    the step the step's: the compiler schedules a body by itself, around
+    whatever the step keeps waiting outside it (with a ``while`` among the
+    backward ops it sinks the weights' gradients and the optimizer's updates
+    behind the last one, and the head's logits wait with them).
+    Qwen3-Next's step with its eight loops reserved 5,450.7 MB with the one
+    gather, 335 MB in a body, and 5,014.9 MB this way, where the parent's
+    every-row walk reserves 5,171.8; Trinity 4,744.5 -> 4,698.0, Nemotron
+    4,964.1 -> 4,917.7, Kimi-Linear 2,296.2 -> 2,287.9, Instella 5,104.3 ->
+    5,097.4 (my described-chip compiles, PR 67).  As many rows are moved
+    either way, and counted as one move; the pass that summed the one
+    gather's rows is gone, 12.6 ms of Qwen3-Next's step (my chip runs, PR
+    67: 63.3 -> 50.7 ms a step for the layer's two ops)."""
+    _count_row_move(which)
+    back = back.reshape(held.shape)
+    total = None
+    for j in range(held.shape[1]):
+        row = jnp.where(held[:, j, None], _permuted(rows, back[:, j]),
+                        0).astype(wide)
+        if gate is not None:
+            row = gate[:, j, None] * row
+        total = row if total is None else total + row
+    return total
 
 
 def _publish_load(live, walked, fullest, trips=None):

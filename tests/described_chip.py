@@ -43,7 +43,8 @@ def no_persistent_cache():
 
 #: the decoder cells' grouped products: rows of a walk (the slab of tokens x
 #: top_k, ``parallel/moe.slab_rows``: a quarter of Trinity's 49,152, an eighth
-#: of Kimi-Linear's 16,384, half of Instella's 49,152, all of the others'),
+#: of Kimi-Linear's 16,384, half of Instella's 49,152, a quarter of
+#: Qwen3-Next's 81,920, all of the others'),
 #: hidden width, expert width,
 #: experts held
 #: (``chipbench/configs/<cell>/config.json``)
@@ -51,6 +52,6 @@ GROUPED_CELLS = {"keye": (65536, 2048, 768, 16),
                  "trinity": (12288, 2048, 1024, 8),
                  "lfm2": (32768, 2048, 1792, 8),
                  "instella": (24576, 2048, 1408, 8),
-                 "qwen3_next": (81920, 2048, 512, 16),
+                 "qwen3_next": (20480, 2048, 512, 16),
                  "mellum2": (65536, 2304, 896, 8),
                  "kimi_linear": (2048, 2304, 1024, 8)}

@@ -225,35 +225,59 @@ def value_and_cotangents(fn, args, kw, mix):
 CELLS = {"trinity_mini": (6144, 8, 8, 128),
          "nemotron_twotower_30b_a3b": (8192, 6, 8, 128),
          "kimi_linear_48b_a3b": (2048, 8, 8, 256),
-         "instella_moe_16b_a3b": (8192, 6, 8, 64)}
+         "instella_moe_16b_a3b": (8192, 6, 8, 64),
+         "qwen3_next_80b_a3b": (8192, 10, 16, 512)}
+#: the cells in slabs whose router has no balancing bias
+NO_BIAS = {"qwen3_next_80b_a3b"}
 
 
 @pytest.mark.parametrize("n,top_k,held,routed,balanced,slab", [
     (*CELLS["trinity_mini"], True, 12288),  # 24 tiles of 49,152 rows
     (*CELLS["kimi_linear_48b_a3b"], True, 2048),    # of 16,384
-    (8192, 10, 16, 512, False, 81920),  # qwen3_next_80b_a3b: no bias, all
-    (8192, 8, 16, 128, False, 65536),   # keye_vl_2_0_30b_a3b: every row
+    (*CELLS["qwen3_next_80b_a3b"], False, 20480),   # of 81,920
+    # keye_vl_2_0_30b_a3b and sdar_30b_a3b_chat, the same shapes: every row
+    (8192, 8, 16, 128, False, 65536),
     (8192, 4, 8, 32, True, 32768),      # lfm2_8b_a1b: a quarter held, all
     (*CELLS["instella_moe_16b_a3b"], True, 24576),  # of 49,152
     (8192, 8, 8, 64, False, 65536),     # mellum2_12b_a2_5b: every row
     (*CELLS["nemotron_twotower_30b_a3b"], True, 12288),     # of 49,152
-])
+], ids=["trinity", "kimi_linear", "qwen3_next", "keye_sdar", "lfm2",
+        "instella", "mellum2", "nemotron"])
 def test_the_slab_of_each_cell(n, top_k, held, routed, balanced, slab):
-    """The rows a trip walks in the eight MoE cells, from ``(N, top_k, held,
-    routed)`` and whether the router has a balancing bias: a slab in
-    Trinity, Nemotron, Kimi-Linear and Instella; every row where a quarter
-    of the experts or more is held, and for now in a layer without a bias
-    (``slab_rows``' DEBT: Qwen3-Next's shapes alone give 10,240 of its
-    81,920, and its step with those loops in it reserved 1.07 GiB more)."""
-    assert moe.SLAB_OVER_EVEN == 4
+    """The rows a trip walks in the nine MoE cells, from ``(N, top_k, held,
+    routed)`` and whether the router has a balancing bias: 4 times the even
+    share under a bias, a slab in Trinity, Nemotron, Kimi-Linear and
+    Instella and every row where a quarter of the experts or more is held;
+    8 times the even share without one, since nothing pulls such a router
+    back from the experts held (``slab_rows``' ``balanced``): a quarter of
+    Qwen3-Next's 81,920 rows, every row where an eighth is held (Keye, SDAR,
+    Mellum2)."""
     assert moe.slab_rows(n * top_k, held, routed, True, balanced) == slab
     assert slab % pallas_grouped.ROW_TILE == 0
-    assert moe.slab_rows(8192 * 10, 16, 512, True) == 10240
-    # all experts held, or fewer rows than a row tile: every row
-    assert moe.slab_rows(n * top_k, routed, routed, True) == n * top_k
-    assert moe.slab_rows(6, 1, 64, True) == 6
+    # all experts held: every row, with a bias or without
+    assert moe.slab_rows(n * top_k, routed, routed, True, balanced) \
+        == n * top_k
+
+
+@pytest.mark.parametrize("rows,held,routed,kernels,balanced,slab", [
+    (81920, 16, 512, True, True, 10240),    # Qwen3-Next's shapes under a
+    (81920, 16, 512, True, False, 20480),   # bias would walk half as many
+    (81920, 32, 512, True, False, 40960),   # twice the experts, twice
+    (81920, 64, 512, True, False, 81920),   # an eighth held: every row
+    (6, 1, 64, True, True, 6),      # fewer rows than a row tile: every row
+    (6, 1, 64, True, False, 6),
     # XLA's grouped product takes any count: the even share's, rounded up
-    assert moe.slab_rows(100, 1, 64, False) == 7
+    (100, 1, 64, False, True, 7),
+    (100, 1, 64, False, False, 13),
+], ids=["biased", "unbiased", "twice_held", "eighth_held", "tiny",
+        "tiny_unbiased", "xla", "xla_unbiased"])
+def test_the_slab_by_the_rule(rows, held, routed, kernels, balanced, slab):
+    """``slab_rows`` by its rule alone: ``SLAB_OVER_EVEN`` times the even
+    share of the rows under a balancing bias and twice that without one, in
+    whole row tiles where the kernels walk them, every row where that is no
+    fewer."""
+    assert moe.SLAB_OVER_EVEN == 4
+    assert moe.slab_rows(rows, held, routed, kernels, balanced) == slab
 
 
 #: the LARGEST live count a layer of the cell showed in one step of a timed
@@ -263,21 +287,29 @@ def test_the_slab_of_each_cell(n, top_k, held, routed, balanced, slab):
 LARGEST = {"trinity_mini": (7646, 9776),
            "nemotron_twotower_30b_a3b": (6267, 10033),
            "kimi_linear_48b_a3b": (778, 951),
-           "instella_moe_16b_a3b": (12288, 18361)}
+           "instella_moe_16b_a3b": (12288, 18361),
+           # no window of PRs 54 to 59 walked this cell in slabs: the most
+           # one layer read in a step of PR 67's pairs on the chip, at the
+           # parent (every row walked, 124 steps a run) and in slabs (160
+           # to 169 steps a run: its router has no bias and drifts on, the
+           # fourth layer furthest, 92% of its slab; the mean of the four
+           # layers is what the ledger's 10.57% at PR 66 was)
+           "qwen3_next_80b_a3b": (15715, 18783)}
 
 
 @pytest.mark.parametrize("read", ["before_pr_59", "in_pr_59", "one_over"])
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_the_largest_load_read_is_one_trip(monkeypatch, cell, read):
     """What ``SLAB_OVER_EVEN`` rests on, as numbers a change of it has to
-    argue against: the LARGEST live count any layer of the four cells in
+    argue against: the LARGEST live count any layer of the five cells in
     slabs showed in a step of a window on the chip (``LARGEST``) is ONE
     trip of its layer, and one row over a slab is two.  Through the layer's
     own plan at the cell's ``(N, top_k, held, routed)``: a router that
     sends exactly so many assignments to the experts held, the products
     left out."""
     n, top_k, held, routed = CELLS[cell]
-    slab = moe.slab_rows(n * top_k, held, routed, True)
+    bias = None if cell in NO_BIAS else jnp.zeros(routed, jnp.float32)
+    slab = moe.slab_rows(n * top_k, held, routed, True, bias is not None)
     before, since = LARGEST[cell]
     assert max(before, since) <= slab < n * top_k
     live = {"before_pr_59": before, "in_pr_59": since,
@@ -286,7 +318,8 @@ def test_the_largest_load_read_is_one_trip(monkeypatch, cell, read):
     # token i takes ``taken[i]`` held experts (the last ``held`` of the
     # routed) and its other choices from the first experts
     taken = np.clip(live - np.arange(n) * top_k, 0, top_k)
-    x = np.zeros((n, 256), np.float32)
+    width = max(256, routed)
+    x = np.zeros((n, width), np.float32)
     choice = np.arange(top_k)[None, :]
     x[np.arange(n)[:, None], np.where(
         choice < taken[:, None], routed - held + choice, choice)] = 10.0
@@ -295,11 +328,11 @@ def test_the_largest_load_read_is_one_trip(monkeypatch, cell, read):
     monkeypatch.setattr(moe, "_slabs", lambda *a: (
         plans.append(slabs(*a)), plans[-1])[1])
     monkeypatch.setattr(moe, "_share", lambda top_k, xt, *a: xt)
-    w = jnp.zeros((held, 256, 128), jnp.float32)
-    moe.routed_experts(jnp.asarray(x), jnp.eye(256, routed, dtype=jnp.float32),
+    w = jnp.zeros((held, width, 128), jnp.float32)
+    moe.routed_experts(jnp.asarray(x),
+                       jnp.eye(width, routed, dtype=jnp.float32),
                        w, w, w.transpose(0, 2, 1), top_k=top_k,
-                       expert_offset=routed - held,
-                       bias=jnp.zeros(routed, jnp.float32))
+                       expert_offset=routed - held, bias=bias)
     plan, = plans
     assert plan.order.shape == (n * top_k // slab, slab) and plan.kernels
     assert int(plan.live) == live
@@ -449,6 +482,69 @@ def fresh_traces():
     jax.clear_caches()
     yield
     jax.clear_caches()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["backward", "forward"])
+@pytest.mark.parametrize("top_k", [1, 2, 6])
+def test_the_choices_come_home_summed(top_k, weighted, dtype):
+    """``moe._choices_home``, what a loop's body sums a token's choices
+    with, a gather of ``[N, D]`` a choice: the sum over the choices of
+    ``_rows_home``'s ``[N, top_k, D]`` rows, as the one walk takes it in
+    either pass (weighted by the gate forward, in float32), an absent
+    assignment's row selected away whatever lies in it; and no value it
+    makes has a row for every assignment."""
+    n, d = 24, 16
+    rng = np.random.RandomState(top_k)
+    rows = jnp.asarray(rng.randn(n * top_k, d), dtype)
+    back = jnp.asarray(rng.permutation(n * top_k), jnp.int32)
+    held = jnp.asarray(rng.rand(n, top_k) < 0.6)
+    # what no held assignment wrote may be anything
+    live = np.zeros(n * top_k, bool)
+    live[np.asarray(back)[np.asarray(held).reshape(-1)]] = True
+    rows = jnp.where(live[:, None], rows, jnp.nan)
+    gate = jnp.asarray(rng.rand(n, top_k), jnp.float32) if weighted else None
+    home = moe._rows_home(rows, back, held, "forward")
+    if weighted:
+        want = jnp.einsum("nk,nkd->nd", gate, home.astype(jnp.float32))
+    else:
+        want = jnp.sum(home, axis=1, dtype=jnp.float32)
+
+    def summed(rows):
+        return moe._choices_home(rows, back, held, "forward", jnp.float32,
+                                 gate)
+
+    got = summed(rows)
+    assert got.dtype == jnp.float32 and got.shape == (n, d)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    assert not any(v.aval.shape[:1] == (n * top_k,) or
+                   v.aval.shape[:2] == (n, top_k) and v.aval.ndim == 3
+                   and v.aval.shape[2] == d
+                   for eqn, _ in equations(jax.make_jaxpr(summed)(rows).jaxpr)
+                   for v in eqn.outvars if top_k > 1)
+
+
+@pytest.mark.parametrize("regime", ["under_one_slab", "three_trips"])
+def test_no_body_holds_a_row_for_every_assignment(fresh_traces, regime):
+    """In the bodies of the layer's two loops no value has the ``N * top_k``
+    rows of every assignment and a layer's width beside them, as
+    ``[N * top_k, D]`` or ``[N, top_k, D]``: the rows come home a choice at
+    a time (``moe._choices_home``).  That array was a body's fullest point,
+    335 MB in Qwen3-Next's, and with its loops in the step the step's."""
+    width = 128
+    args, kw, mix, _ = slab_operands(regime, width)
+
+    def layer(*a):
+        return jnp.sum(mix * moe.routed_experts(*a, **kw))
+
+    both = jax.make_jaxpr(jax.grad(layer, range(5)))(*args).jaxpr
+    assert len(loops(both)) == 2
+    wide = [v.aval.shape for eqn, looped in equations(both) if looped
+            for v in eqn.outvars
+            if v.aval.shape in ((TOKENS * TOP_K, width),
+                                (TOKENS, TOP_K, width))]
+    assert wide == []
 
 
 @pytest.mark.parametrize("regime", ["under_one_slab", "three_trips"])
@@ -615,25 +711,26 @@ def small_layer(n, held, routed, width):
 
 @pytest.mark.parametrize("held,routed,offset,width,bias", [
     (16, 128, 8, 16, True), (8, 8, 0, 16, True), (4, 16, 4, 128, True),
-    (2, 128, 8, 16, False), (2, 128, 8, 16, True), (32, 128, 8, 16, True)])
+    (16, 128, 8, 16, False), (2, 128, 8, 16, False), (2, 128, 8, 16, True),
+    (32, 128, 8, 16, True)])
 def test_every_row_in_one_slab_lowers_to_the_parents_text(held, routed,
                                                           offset, width,
                                                           bias):
     """A quarter of the experts held or more (all of them; a quarter, on
-    the kernels and on XLA's product), or a sixty-fourth under a router
-    with no balancing bias: the slab is every row, the decision is static,
-    no ``while`` is lowered in either pass and the text of the layer and
-    its backward equals, byte for byte, the text of the parent's formula
-    kept above.  Two of 128 held under a bias, or an eighth of them (every
-    row while the factor was 8): the same layer in slabs lowers another
-    text, with one ``while`` a pass."""
+    the kernels and on XLA's product), or an eighth under a router with no
+    balancing bias (Keye's, SDAR's and Mellum2's share): the slab is every
+    row, the decision is static, no ``while`` is lowered in either pass and
+    the text of the layer and its backward equals, byte for byte, the text
+    of the parent's formula kept above.  Two of 128 held, with a bias or
+    without, or an eighth of them under a bias: the same layer in slabs
+    lowers another text, with one ``while`` a pass."""
     n, k = 256, 2
     x, wr, w1, w3, w2 = small_layer(n, held, routed, width)
     kw = dict(top_k=k, expert_offset=offset,
               bias=jnp.zeros(routed, jnp.float32) if bias else None)
     kernels = moe.product_path(x, w1, w2, k) == "pallas"
     assert kernels == (width == 128)
-    every_row = 4 * held >= routed or not bias
+    every_row = (4 if bias else 8) * held >= routed
     assert (moe.slab_rows(n * k, held, routed, kernels, bias) == n * k) \
         == every_row
 

@@ -20,7 +20,8 @@ from described_chip import (  # noqa: F401  (the two fixtures)
 
 
 #: a cell's routed layer: tokens a step, choices a token, routed experts,
-#: whether its router has a balancing bias (a slab is walked only under one)
+#: whether its router has a balancing bias (a slab is 4 times the even share
+#: of the rows under one and 8 times without: ``moe.slab_rows``' ``balanced``)
 #: (``GROUPED_CELLS`` has the rows, the widths and the experts held)
 GROUPED_LAYERS = {"keye": (8192, 8, 128, False),
                   "trinity": (6144, 8, 128, True),
@@ -41,8 +42,9 @@ def test_a_routed_layer_and_its_backward_lower_eleven_calls(
     ``grouped_matmul_t``, in the six signatures that
     ``test_grouped_signatures_are_the_benchmarks`` pins one product at a
     time, each counted as ``2 * M * D * F`` FLOPs by the benchmark's files,
-    M the rows of a walk: the slab's in Trinity, Kimi-Linear and Instella,
-    whose eleven calls stand ONCE, in the bodies of the layer's two loops;
+    M the rows of a walk: the slab's in Trinity, Kimi-Linear, Instella and
+    Qwen3-Next, whose eleven calls stand ONCE, in the bodies of the layer's
+    two loops;
     XLA drops none and adds none."""
     import sys
 
